@@ -106,7 +106,7 @@ class FFConfig:
     profiling: bool = False
     log_instance_creation: bool = False
     # jax.profiler trace directory for utils/profiling.trace()
-    # (TensorBoard-viewable XLA traces); None = /tmp/flexflow_tpu_trace.
+    # (TensorBoard-viewable XLA traces); None = <checkout>/.scratch/trace.
     # --trace-dir.
     trace_dir: Optional[str] = None
 
@@ -216,7 +216,7 @@ class FFConfig:
     # simulator costs keyed by (op signature, axis map, machine-model
     # fingerprint) so repeated searches and mesh-shape sweeps skip
     # re-deriving/re-measuring. cost_cache_file=None uses
-    # ~/.cache/flexflow_tpu/costcache.json (FLEXFLOW_TPU_CACHE root).
+    # costcache.json under utils/cache_dirs.measurement_cache_dir().
     search_cost_cache: bool = True
     cost_cache_file: Optional[str] = None
     import_strategy_file: Optional[str] = None
@@ -447,7 +447,7 @@ class FFConfig:
     # with outcome "deadline_expired", its pages reclaimed.
     serve_request_deadline: float = 0.0
     # bounded retry-with-backoff around the engine's jitted dispatch
-    # for TransientError (injected or tunnel hiccup): up to
+    # for TransientError (injected or a flaky link): up to
     # serve_max_retries re-dispatches, sleeping
     # serve_retry_backoff_s * 2^attempt between them.
     serve_max_retries: int = 3

@@ -186,25 +186,11 @@ def restore_checkpoint(path: str, state: TrainState) -> TrainState:
         partial = {k: v for k, v in target.items() if k != "opt_state"}
         # the PyTree handler reads the Standard layout and supports
         # partial restore (skip the on-disk optimizer slots entirely)
-        import inspect
         pt = ocp.Checkpointer(ocp.PyTreeCheckpointHandler())
-        if "partial_restore" in inspect.signature(
-                ocp.args.PyTreeRestore).parameters:
-            restored = pt.restore(
-                os.path.abspath(path),
-                args=ocp.args.PyTreeRestore(item=partial,
-                                            partial_restore=True))
-        else:
-            # older orbax: no partial_restore kwarg; an empty transforms
-            # dict is the legacy spelling of "restore only the keys in
-            # item", and it requires explicit per-leaf restore_args
-            restored = pt.restore(
-                os.path.abspath(path),
-                args=ocp.args.PyTreeRestore(
-                    item=partial,
-                    restore_args=ocp.checkpoint_utils.
-                    construct_restore_args(partial),
-                    transforms={}))
+        restored = pt.restore(
+            os.path.abspath(path),
+            args=ocp.args.PyTreeRestore(item=partial,
+                                        partial_restore=True))
         pt.close()
         restored["opt_state"] = {}
     else:

@@ -18,7 +18,7 @@ This module factors the discipline into one object:
   ``fn.lower(*args).compile()`` (timed, counted) then dispatch. The
   count is EXACT per family — a compile cannot hide from it the way it
   could from the monitoring snapshot (e.g. compiles triggered inside
-  warmup_handoff / adapter load on a jax without the monitoring module).
+  warmup_handoff / adapter load).
 - ``save(dir)`` / ``load_warm()`` serialize the compiled executables
   (``jax.experimental.serialize_executable``) keyed by a program
   FINGERPRINT folding model arch, lane widths, kv dtype/pool geometry,
@@ -30,10 +30,16 @@ This module factors the discipline into one object:
   cache from an incompatible runtime) is dropped and recompiled with a
   warning, never crashing the engine.
 
-When a cache dir is armed the registry also points JAX's persistent
-compilation cache at ``<dir>/xla`` (best-effort) — the belt under the
-AOT braces: even a program the snapshot missed compiles from the XLA
-disk cache instead of from scratch.
+An executable is bound to the devices it was compiled for, so each
+store entry records those device ids and ``load_warm`` restores onto
+exactly them (``deserialize_and_load`` would otherwise bind to every
+local device and reject the first call on any multi-device host);
+callers fold their device set into the fingerprint, so replicas on
+different chips keep separate stores.
+
+The registry places the serialized executables (``*.ffprog``) only.
+JAX's persistent compilation cache is placed by the entry points
+through ``utils/cache_dirs.arm_compile_cache`` — never from here.
 """
 
 from __future__ import annotations
@@ -48,13 +54,8 @@ from typing import Any, Dict, Optional
 
 import jax
 
-_STORE_VERSION = 1
+_STORE_VERSION = 2   # 2: entries record the devices they execute on
 _STORE_SUFFIX = ".ffprog"
-
-# jax_compilation_cache_dir is process-global config: arm it once, for
-# the first registry that asks, and leave it alone after (two engines
-# with different dirs must not thrash the global)
-_xla_cache_armed = False
 
 
 def fingerprint_hash(fp: Dict[str, Any]) -> str:
@@ -106,20 +107,6 @@ class ProgramRegistry:
         self._restored: Dict[str, int] = {}
         self._compile_s: Dict[str, float] = {}
         self._dirty = False
-        if cache_dir:
-            self._arm_xla_cache(cache_dir)
-
-    @staticmethod
-    def _arm_xla_cache(cache_dir: str) -> None:
-        global _xla_cache_armed
-        if _xla_cache_armed:
-            return
-        try:
-            jax.config.update("jax_compilation_cache_dir",
-                              os.path.join(cache_dir, "xla"))
-            _xla_cache_armed = True
-        except Exception:   # config knob absent on this jax — AOT
-            pass            # serialization still covers warm boot
 
     # ---------------- registration / resolution -----------------------
     def register(self, name: str, *, static_argnums: tuple = ()) -> None:
@@ -172,12 +159,14 @@ class ProgramRegistry:
         dyn = [a for i, a in enumerate(args) if i not in statics]
         try:
             return compiled(*dyn)
-        except (TypeError, ValueError) as e:
+        except (TypeError, ValueError, jax.errors.JaxRuntimeError) as e:
             if key not in self._restored_keys:
                 raise
             # deserialized from a snapshot whose runtime disagrees
-            # with ours in a way the fingerprint did not fold —
-            # reject the stale entry and compile fresh
+            # with ours in a way the fingerprint did not fold (jit's
+            # own argument checks raise TypeError/ValueError; the
+            # runtime's — wrong shard count, foreign device — raise
+            # JaxRuntimeError) — reject the stale entry, compile fresh
             warnings.warn(
                 f"program cache: restored {name!r} executable rejected "
                 f"its first call ({e}); recompiling", stacklevel=2)
@@ -251,6 +240,8 @@ class ProgramRegistry:
             entries[(family, sig)] = {
                 "family": family, "sig": sig,
                 "statics": list(self._statics.get(family, ())),
+                "device_ids": [d.id for d in compiled
+                               .runtime_executable().local_devices()],
                 "payload": payload, "in_tree": in_tree,
                 "out_tree": out_tree,
                 "compile_s": self._compile_s.get(family, 0.0),
@@ -339,13 +330,19 @@ class ProgramRegistry:
             return 0
         from jax.experimental.serialize_executable import \
             deserialize_and_load
+        by_id = {d.id: d for d in jax.devices()}
         n = 0
         for e in doc["entries"]:
             try:
                 family = e["family"]
                 key = (family, e["sig"])
+                # onto the devices it was compiled for: the default
+                # binds to ALL local devices, and the first call then
+                # fails with "expected N shards"
                 compiled = deserialize_and_load(
-                    e["payload"], e["in_tree"], e["out_tree"])
+                    e["payload"], e["in_tree"], e["out_tree"],
+                    execution_devices=[by_id[i]
+                                       for i in e["device_ids"]])
             except Exception as exc:
                 warnings.warn(
                     f"program cache: could not deserialize a "

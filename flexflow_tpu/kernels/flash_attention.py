@@ -34,11 +34,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pallas TPU backend is absent on pure-CPU builds
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
+
+from .paged_ragged_v2 import _vmem_limit
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -61,6 +59,17 @@ def _causal_mask(s, q0, k0, block_q, block_k):
     qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
     kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
     return jnp.where(qpos >= kpos, s, -jnp.inf)
+
+
+def _compiler_params(sq, sk, d, dtype):
+    """No grid step of the flash kernels carries state to the next
+    (each writes its own output block), and the resident whole-head
+    operands need more scoped VMEM than Mosaic's 16 MiB default from
+    about 4k tokens on."""
+    need = _flash_resident_bytes(sq, sk, d, jnp.dtype(dtype).itemsize)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel"),
+        vmem_limit_bytes=_vmem_limit(need + 4 * 2**20))
 
 
 # ---------------------------------------------------------------- forward
@@ -123,7 +132,9 @@ def _fwd_pallas(q, k, v, *, causal, scale, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
         ],
+        compiler_params=_compiler_params(sq, sk, d, q.dtype),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
 
 
@@ -221,7 +232,9 @@ def _bwd_pallas(q, k, v, o, lse, do, *, causal, scale, block_q, block_k,
         ],
         out_specs=pl.BlockSpec((None, block_q, d), blk_q),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+        compiler_params=_compiler_params(sq, sk, d, q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     blk_k = lambda b, j: (b, j, 0)  # noqa: E731
@@ -246,7 +259,9 @@ def _bwd_pallas(q, k, v, o, lse, do, *, causal, scale, block_q, block_k,
             jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
             jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
         ],
+        compiler_params=_compiler_params(sq, sk, d, q.dtype),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -289,6 +304,55 @@ def flash_profitable(b: int, h: int, sq: int, sk: int, d: int) -> bool:
     return (d % 128 == 0 and sk >= 1024) or score_bytes > 2**31
 
 
+# the most one head's resident operands may take: the kernels ask for
+# twice this (double buffering) and a v5e core has 128 MiB of VMEM
+_FLASH_VMEM_BYTES = 24 * 2**20
+
+
+def _flash_resident_bytes(sq: int, sk: int, d_pad: int, itemsize: int) -> int:
+    """VMEM one grid step of the largest of the three kernels keeps
+    resident. Forward and dQ hold a head's WHOLE K and V; dK/dV holds
+    its whole Q and dO plus the (sq, 1) f32 logsumexp and delta
+    columns, which pad to 128 lanes."""
+    kv = 2 * sk * d_pad * itemsize
+    q_do = 2 * sq * d_pad * itemsize + 2 * sq * 128 * 4
+    return max(kv, q_do)
+
+
+def flash_unsupported(sq: int, sk: int, d: int, itemsize: int = 2,
+                      block_q: int = DEFAULT_BLOCK_Q,
+                      block_k: int = DEFAULT_BLOCK_K):
+    """Why flash_attention_bshd cannot take these shapes, or None when
+    it can — the predicate the auto dispatch asks BEFORE choosing the
+    kernel (a kernel that is chosen and then raises, raises)."""
+    if sq % block_q != 0 or sk % block_k != 0:
+        return f"seq ({sq},{sk}) not divisible by block ({block_q},{block_k})"
+    if d > 256:
+        return "head_dim > 256 unsupported"
+    d_pad = max(128, -(-d // 128) * 128)
+    need = _flash_resident_bytes(sq, sk, d_pad, itemsize)
+    if need > _FLASH_VMEM_BYTES:
+        return (f"one head's resident operands ({need / 2**20:.1f} MiB at "
+                f"seq ({sq},{sk})) exceed the kernel's "
+                f"{_FLASH_VMEM_BYTES / 2**20:.0f} MiB VMEM budget")
+    return None
+
+
+def resolve_flash(use_flash, b: int, h: int, sq: int, sk: int, d: int,
+                  itemsize: int = 2) -> bool:
+    """The attention op's tri-state resolved to one decision: True =
+    run the Pallas kernel (and let it raise if it cannot), False = the
+    XLA path. use_flash True forces, False forbids; None is the auto
+    rule — a tpu backend, a shape the kernel takes, and the measured
+    flash_profitable gate. Shared by ops/attention.py and the
+    all-to-all SP lowering (parallel/ulysses.py)."""
+    if use_flash is not None:
+        return bool(use_flash)
+    return (jax.default_backend() == "tpu"
+            and flash_unsupported(sq, sk, d, itemsize) is None
+            and flash_profitable(b, h, sq, sk, d))
+
+
 # ------------------------------------------------- paged attention (serve)
 #
 # The serving path (flexflow_tpu/serve): query tokens attend to their
@@ -310,21 +374,20 @@ def flash_profitable(b: int, h: int, sq: int, sk: int, d: int) -> bool:
 # Each has two implementations with identical semantics:
 #
 #   * _paged_decode_jnp — gather pages with jnp.take, masked online-free
-#     softmax in f32. XLA lowers the gather to dynamic-gather; for
-#     single-query lanes the op is HBM-bound either way, so this is
-#     also a credible TPU path, and it is the reference the Pallas
-#     kernels are tested against bit-for-bit on CPU.
-#   * _paged_decode_pallas / _paged_ragged_pallas — scalar-prefetch
-#     kernels: the page table (and, for ragged, the lane->slot map and
-#     lane lengths) rides in SMEM ahead of the grid so each
-#     (lane, page) grid step DMAs exactly one K and one V page picked
-#     by table[slot[lane], page]; online max/sum rescaling accumulates
-#     across a lane's pages in VMEM scratch, and the output is written
-#     on the lane's last grid step. Never materializes the gathered
-#     (B, max_len, H, D) K/V that the jnp path pays for.
+#     softmax in f32. XLA lowers the gather to dynamic-gather; it is the
+#     reference the Pallas kernel is tested against, and the path the
+#     CPU tests run.
+#   * the ragged v2 Pallas kernel (kernels/paged_ragged_v2.py) — a
+#     scalar-prefetch kernel: the page table, the lane->slot map and
+#     lane lengths ride in SMEM ahead of the grid so each work item
+#     DMAs exactly the pages table[slot[lane], ...] names; online
+#     max/sum rescaling accumulates across a lane's pages in VMEM
+#     scratch. Never materializes the gathered (B, max_len, H, D) K/V
+#     that the jnp path pays for. A decode step is the ragged call with
+#     one lane per sequence, so it runs the same kernel.
 #
-# Both dispatch: Pallas on TPU (or interpret=True), jnp elsewhere — the
-# CPU-fallback story for the whole serve package.
+# paged_ragged_v2.resolve_paged_impl is the one rule that picks between
+# them (Pallas on a tpu backend, jnp elsewhere, either by argument).
 
 
 def _paged_decode_jnp(q, k_pages, v_pages, page_table, seq_lens, scale):
@@ -356,117 +419,6 @@ def _paged_decode_jnp(q, k_pages, v_pages, page_table, seq_lens, scale):
     return (o / l).astype(q.dtype)
 
 
-def _paged_online_page(q, k, v, length, j, m_ref, l_ref, acc_ref, *,
-                       page_size, scale):
-    """One page of one lane's online-softmax accumulation — the body
-    shared by the decode and ragged kernels (they differ only in how
-    the lane's length and page-table row are selected)."""
-    h, _ = q.shape
-    # scores for this page: (H, ps), f32 accumulate on the MXU
-    s = jax.lax.dot_general(
-        q, k, (((1,), (2,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32) * scale
-    # mask positions past the lane's visible length (padding pages are
-    # the sink page; their scores die here)
-    pos = j * page_size + jax.lax.broadcasted_iota(jnp.int32, (h, page_size),
-                                                   1)
-    s = jnp.where(pos < length, s, -jnp.inf)
-
-    m_prev = m_ref[:]               # (H, 1)
-    l_prev = l_ref[:]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)          # (H, ps); fully-masked rows -> 0
-    alpha = jnp.exp(m_prev - m_new)
-    m_ref[:] = m_new
-    l_ref[:] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-    # p stays f32 and v upcasts, matching _paged_decode_jnp exactly —
-    # the implementations must not diverge for bf16 KV pages
-    pv = jax.lax.dot_general(       # (H, D): p (H,ps) . v (ps,H,D) per-head
-        p, v.astype(jnp.float32), (((1,), (0,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32)
-    acc_ref[:] = acc_ref[:] * alpha + pv
-
-
-def _paged_decode_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_ref, l_ref, acc_ref, *, page_size, pages_per_seq,
-                         scale):
-    """Grid (B, pages_per_seq); k_ref/v_ref hold THE page selected by
-    the scalar-prefetched table for this (seq, page) step."""
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    _paged_online_page(q_ref[0], k_ref[0], v_ref[0], sl_ref[b], j,
-                       m_ref, l_ref, acc_ref, page_size=page_size,
-                       scale=scale)
-
-    @pl.when(j == pages_per_seq - 1)
-    def _emit():
-        o_ref[0] = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
-
-
-def _paged_ragged_kernel(pt_ref, ls_ref, ll_ref, q_ref, k_ref, v_ref,
-                         o_ref, m_ref, l_ref, acc_ref, *, page_size,
-                         pages_per_seq, scale):
-    """Grid (T, pages_per_seq) over LANES: lane t's pages come from row
-    ls_ref[t] of the table (several lanes of one sequence share a row)
-    and its causal visibility is its own ll_ref[t] = position + 1."""
-    t = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    _paged_online_page(q_ref[0], k_ref[0], v_ref[0], ll_ref[t], j,
-                       m_ref, l_ref, acc_ref, page_size=page_size,
-                       scale=scale)
-
-    @pl.when(j == pages_per_seq - 1)
-    def _emit():
-        o_ref[0] = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
-
-
-def _paged_decode_pallas(q, k_pages, v_pages, page_table, seq_lens, scale,
-                         interpret):
-    if not _HAS_PLTPU:
-        raise NotImplementedError("pallas TPU backend unavailable")
-    b, h, d = q.shape
-    ps = k_pages.shape[1]
-    pp = page_table.shape[1]
-    kern = functools.partial(_paged_decode_kernel, page_size=ps,
-                             pages_per_seq=pp, scale=scale)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # page_table, seq_lens
-        grid=(b, pp),
-        in_specs=[
-            pl.BlockSpec((1, h, d), lambda b, j, pt, sl: (b, 0, 0)),
-            pl.BlockSpec((1, ps, h, d),
-                         lambda b, j, pt, sl: (pt[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, ps, h, d),
-                         lambda b, j, pt, sl: (pt[b, j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, h, d), lambda b, j, pt, sl: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((h, 1), jnp.float32),   # running max
-            pltpu.VMEM((h, 1), jnp.float32),   # running sum
-            pltpu.VMEM((h, d), jnp.float32),   # output accumulator
-        ],
-    )
-    return pl.pallas_call(
-        kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        interpret=interpret,
-    )(page_table, seq_lens, q, k_pages, v_pages)
-
-
 def paged_attention_decode(q, k_pages, v_pages, page_table, seq_lens, *,
                            scale=None, use_pallas=None, interpret=False):
     """Single-query attention through a page table (decode step).
@@ -476,77 +428,44 @@ def paged_attention_decode(q, k_pages, v_pages, page_table, seq_lens, *,
     physical page ids (0 = sink/padding); seq_lens (B,) int32 tokens
     resident per sequence (positions >= seq_len are masked). Every
     seq_lens entry must be >= 1: a zero-length lane has every score
-    masked to -inf, which NaNs the softmax in both implementations —
-    callers with empty lanes must clamp them to 1 and aim their page
-    table at the sink (serve/engine.py does exactly this). Returns
-    (B, H, D).
+    masked, which NaNs the softmax of the jnp path and leaves garbage
+    in the kernel's — callers with empty lanes must clamp them to 1 and
+    aim their page table at the sink (serve/engine.py does exactly
+    this). Returns (B, H, D).
 
-    use_pallas: None = auto (Pallas kernel on TPU, jnp gather path
-    elsewhere — the CPU fallback that makes the whole serve package run
-    under JAX_PLATFORMS=cpu), True = force (combine with interpret=True
-    off TPU), False = always jnp (wins over interpret).
+    The Pallas path is the ragged v2 kernel with one lane per sequence
+    (lane b reads table row b). use_pallas/interpret pick the
+    implementation by paged_ragged_v2.resolve_paged_impl.
     """
+    from .paged_ragged_v2 import (JNP, paged_attention_ragged_v2,
+                                  resolve_paged_impl)
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    if use_pallas is None:
-        use_pallas = (interpret or (_HAS_PLTPU
-                                    and jax.default_backend() == "tpu"))
-    if use_pallas:
-        return _paged_decode_pallas(q, k_pages, v_pages, page_table,
-                                    seq_lens, scale, interpret)
-    return _paged_decode_jnp(q, k_pages, v_pages, page_table, seq_lens,
-                             scale)
-
-
-def _paged_ragged_pallas(q, k_pages, v_pages, page_tables, lane_slots,
-                         lane_lens, scale, interpret):
-    if not _HAS_PLTPU:
-        raise NotImplementedError("pallas TPU backend unavailable")
-    t, h, d = q.shape
-    ps = k_pages.shape[1]
-    pp = page_tables.shape[1]
-    kern = functools.partial(_paged_ragged_kernel, page_size=ps,
-                             pages_per_seq=pp, scale=scale)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # page_tables, lane_slots, lane_lens
-        grid=(t, pp),
-        in_specs=[
-            pl.BlockSpec((1, h, d), lambda t, j, pt, ls, ll: (t, 0, 0)),
-            pl.BlockSpec((1, ps, h, d),
-                         lambda t, j, pt, ls, ll: (pt[ls[t], j], 0, 0, 0)),
-            pl.BlockSpec((1, ps, h, d),
-                         lambda t, j, pt, ls, ll: (pt[ls[t], j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, h, d), lambda t, j, pt, ls, ll: (t, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((h, 1), jnp.float32),   # running max
-            pltpu.VMEM((h, 1), jnp.float32),   # running sum
-            pltpu.VMEM((h, d), jnp.float32),   # output accumulator
-        ],
-    )
-    return pl.pallas_call(
-        kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, h, d), q.dtype),
-        interpret=interpret,
-    )(page_tables, lane_slots, lane_lens, q, k_pages, v_pages)
+    if resolve_paged_impl(use_pallas, interpret) == JNP:
+        return _paged_decode_jnp(q, k_pages, v_pages, page_table,
+                                 seq_lens, scale)
+    return paged_attention_ragged_v2(
+        q, k_pages, v_pages, page_table,
+        jnp.arange(q.shape[0], dtype=jnp.int32), seq_lens, scale=scale,
+        use_pallas=use_pallas, interpret=interpret)
 
 
 def paged_attention_ragged_v1(q, k_pages, v_pages, page_tables,
                               lane_slots, lane_lens, *, scale=None,
-                              use_pallas=None, interpret=False):
-    """The PR-3 first-cut ragged kernel — grid (T, pages_per_seq), one
-    page per grid step, full masked compute per step. Kept as the
-    bit-equality oracle and A/B baseline for kernel v2
-    (kernels/paged_ragged_v2.py); new code should call
-    paged_attention_ragged, which dispatches v2."""
+                              use_pallas=None):
+    """The PR-3 ragged attention, kept as the bit-equality ORACLE for
+    kernel v2's jnp path (tests/test_kv_quant.py). Its Pallas kernel
+    (grid (T, pages_per_seq), a per-head dot batched over a non-leading
+    axis of a (ps, H, D) block) is a form Mosaic does not compile, so
+    it is gone: use_pallas=True raises by name rather than run as
+    jnp."""
+    if use_pallas:
+        raise NotImplementedError(
+            "paged_attention_ragged_v1 has no Pallas kernel (Mosaic "
+            "cannot compile its batched per-head dot); call "
+            "paged_attention_ragged, which dispatches kernel v2")
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    if use_pallas is None:
-        use_pallas = (interpret or (_HAS_PLTPU
-                                    and jax.default_backend() == "tpu"))
-    if use_pallas:
-        return _paged_ragged_pallas(q, k_pages, v_pages, page_tables,
-                                    lane_slots, lane_lens, scale, interpret)
     lane_tables = jnp.take(page_tables, lane_slots, axis=0)  # (T, pp)
     return _paged_decode_jnp(q, k_pages, v_pages, lane_tables, lane_lens,
                              scale)
@@ -584,8 +503,9 @@ def paged_attention_ragged(q, k_pages, v_pages, page_tables, lane_slots,
     fp32 call is bit-for-bit `paged_attention_decode`, and the op order
     matches the contiguous full-prefill reference exactly (tested in
     tests/test_serve_v2.py; v2-vs-v1 equality in tests/test_kv_quant.py).
-    use_pallas: None = auto (Pallas on TPU), True = force (combine with
-    interpret=True off TPU), False = always jnp.
+    use_pallas/interpret pick the implementation by
+    paged_ragged_v2.resolve_paged_impl (None = Pallas on a tpu backend,
+    jnp elsewhere).
     """
     from .paged_ragged_v2 import paged_attention_ragged_v2
     return paged_attention_ragged_v2(
@@ -599,21 +519,25 @@ def flash_attention_bshd(q, k, v, *, causal=False,
                          interpret=False, pad_lanes=True):
     """softmax(QK^T/sqrt(d))V for (b, s, h, d) tensors via Pallas.
 
-    Raises on unsupported shapes/platform; callers fall back to XLA.
+    Raises on unsupported shapes/platform: callers ask resolve_flash /
+    flash_unsupported first, they do not catch.
 
     pad_lanes=True zero-pads head_dim up to a 128-lane multiple (always
     safe). pad_lanes=False hands Mosaic the raw head_dim (still a
     multiple of 8): halves the kernel's HBM traffic and dot FLOPs for
     d=64, at the cost of relying on Mosaic's sub-128 lane handling.
     """
-    if not interpret and (not _HAS_PLTPU or jax.default_backend() != "tpu"):
-        raise NotImplementedError("pallas flash attention requires TPU")
+    if not interpret and jax.default_backend() != "tpu":
+        raise NotImplementedError(
+            f"pallas flash attention compiles for a tpu backend (this "
+            f"one is {jax.default_backend()!r}); pass interpret=True to "
+            f"run it through the Pallas interpreter")
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    if sq % block_q != 0 or sk % block_k != 0:
-        raise NotImplementedError(f"seq ({sq},{sk}) not divisible by block")
-    if d > 256:
-        raise NotImplementedError("head_dim > 256 unsupported")
+    reason = flash_unsupported(sq, sk, d, jnp.dtype(q.dtype).itemsize,
+                               block_q, block_k)
+    if reason:
+        raise NotImplementedError(f"pallas flash attention: {reason}")
 
     # scale uses the unpadded head_dim
     scale = 1.0 / math.sqrt(d)

@@ -34,12 +34,33 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pallas TPU backend is absent on pure-CPU builds
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
+
+# what the resident recurrent weight and its gradient may take of a
+# core's 128 MiB of VMEM (a v5e's; the time axis carries h/c in scratch)
+_VMEM_BUDGET = 96 * 2**20
+
+
+def _resident_bytes(B: int, H: int, itemsize: int, backward: bool) -> int:
+    """VMEM one grid step keeps: wh single-buffered (constant block
+    index), the backward's f32 dwh output block and accumulator, the
+    double-buffered per-step (B, 4H) / (B, H) slices, and room for the
+    f32 gate temporaries."""
+    wh = H * 4 * H * itemsize
+    if not backward:
+        return (wh + B * 4 * H * (2 * itemsize + 8)
+                + B * H * (8 * itemsize + 24))
+    return (wh + 2 * H * 4 * H * 4 + B * 4 * H * (4 * itemsize + 16)
+            + B * H * (8 * itemsize + 40))
+
+
+def _compiler_params(B, H, dtype, backward):
+    need = _resident_bytes(B, H, jnp.dtype(dtype).itemsize, backward)
+    # the grid axis is TIME: h/c (and dwh) carry from step to step
+    return pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",),
+        vmem_limit_bytes=int(min(max(need + 16 * 2**20, 16 * 2**20),
+                                 _VMEM_BUDGET + 16 * 2**20)))
 
 
 def _prec(dtype):
@@ -98,7 +119,8 @@ def _fwd_pallas(xg, wh, h0, c0, *, interpret):
         grid=(T,),
         in_specs=[
             pl.BlockSpec((None, B, four_h), lambda t: (t, 0, 0)),
-            pl.BlockSpec((H, four_h), lambda t: (0, 0)),  # resident
+            pl.BlockSpec((H, four_h), lambda t: (0, 0),   # resident
+                         pipeline_mode=pl.Buffered(1)),
             pl.BlockSpec((B, H), lambda t: (0, 0)),
             pl.BlockSpec((B, H), lambda t: (0, 0)),
         ],
@@ -111,7 +133,9 @@ def _fwd_pallas(xg, wh, h0, c0, *, interpret):
             jax.ShapeDtypeStruct((T, B, H), jnp.float32),
         ],
         scratch_shapes=scratch,
+        compiler_params=_compiler_params(B, H, wh.dtype, False),
         interpret=interpret,
+        name="lstm_fwd",
     )(xg, wh, h0, c0)
 
 
@@ -190,7 +214,8 @@ def _bwd_pallas(xg, wh, h0, c0, ys, cs, dys, *, interpret):
         grid=(T,),
         in_specs=[
             pl.BlockSpec((None, B, four_h), rev),
-            pl.BlockSpec((H, four_h), const2),  # resident
+            pl.BlockSpec((H, four_h), const2,   # resident
+                         pipeline_mode=pl.Buffered(1)),
             pl.BlockSpec((None, B, H), rev),    # hs_prev
             pl.BlockSpec((None, B, H), rev),    # cs_prev
             pl.BlockSpec((None, B, H), rev),    # cs
@@ -198,7 +223,8 @@ def _bwd_pallas(xg, wh, h0, c0, ys, cs, dys, *, interpret):
         ],
         out_specs=[
             pl.BlockSpec((None, B, four_h), rev),
-            pl.BlockSpec((H, four_h), const2),
+            pl.BlockSpec((H, four_h), const2,
+                         pipeline_mode=pl.Buffered(1)),
             pl.BlockSpec((B, H), const2),
             pl.BlockSpec((B, H), const2),
         ],
@@ -209,7 +235,9 @@ def _bwd_pallas(xg, wh, h0, c0, ys, cs, dys, *, interpret):
             jax.ShapeDtypeStruct((B, H), jnp.float32),
         ],
         scratch_shapes=scratch,
+        compiler_params=_compiler_params(B, H, wh.dtype, True),
         interpret=interpret,
+        name="lstm_bwd",
     )(xg, wh, hs_prev, cs_prev, cs, dys)
     return dxg, dwh, dh0, dc0
 
@@ -266,13 +294,20 @@ def lstm_sequence(xg, wh, h0, c0, *, interpret=False):
     (LSTM use_pallas=True): an explicitly requested but unusable
     kernel must fail loudly, not silently degrade; the DEFAULT LSTM
     path is the scan."""
-    if not _HAS_PLTPU or (not interpret
-                          and jax.default_backend() != "tpu"):
-        raise NotImplementedError("pallas lstm requires TPU (or the "
-                                  "pallas TPU plugin for interpret mode)")
+    if not interpret and jax.default_backend() != "tpu":
+        raise NotImplementedError(
+            f"pallas lstm compiles for a tpu backend (this one is "
+            f"{jax.default_backend()!r}); pass interpret=True to run it "
+            f"through the Pallas interpreter")
     T, B, four_h = xg.shape
     H = four_h // 4
     if B % 8 != 0 or H % 128 != 0:
         raise NotImplementedError(
             f"pallas lstm needs B%8==0 and H%128==0, got B={B} H={H}")
+    need = _resident_bytes(B, H, jnp.dtype(wh.dtype).itemsize, True)
+    if need > _VMEM_BUDGET:
+        raise NotImplementedError(
+            f"pallas lstm keeps wh and its gradient in VMEM: B={B} H={H} "
+            f"{jnp.dtype(wh.dtype).name} needs {need / 2**20:.0f} MiB, "
+            f"over the {_VMEM_BUDGET / 2**20:.0f} MiB budget")
     return _lstm_seq(xg, wh, h0, c0, interpret)

@@ -30,8 +30,9 @@ This module rebuilds the kernel along the paper's lines:
   * HEAD PACKING for small head_dim: page blocks stream as
     (page_size, H*D) rows — the layout is already contiguous in HBM, so
     this is a free reshape that fills 128-lane VMEM tiles where
-    (page_size, H, D) tiling padded D up to 128 — and are unpacked to
-    (page_size, H, D) in-register for the (bit-identical) per-head dots.
+    (page_size, H, D) tiling padded D up to 128 — and STAY packed: the
+    per-head reductions are matmuls with a 0/1 segment matrix (see the
+    kernel section), because Mosaic cannot split a lane dimension.
   * TUNABLE KV-BLOCK SHAPES: `block_kv` (tokens per work item; FFConfig
     serve_attn_block_kv / --serve-attn-block-kv) with an
     autotune-by-shape table supplying defaults — sized so each step's
@@ -41,20 +42,21 @@ This module rebuilds the kernel along the paper's lines:
   * QUANTIZED KV PAGES: int8 K/V pages ride with per-page scale arrays
     (one f32 scale per head per in-page slot — see serve/kv_cache.py
     for why scales are per-slot, not per-whole-page); the kernel DMAs
-    the int8 block + its scale rows and dequantizes in-register before
-    the (otherwise unchanged) online-softmax accumulation. bf16 pages
-    need no scales (values upcast exactly like v1's bf16 handling).
+    the int8 block + its scale rows and applies the scales to the
+    scores and the probabilities (algebraically the dequantized
+    product) inside the online-softmax accumulation. bf16 pages need
+    no scales (values upcast exactly like v1's bf16 handling).
 
 Numerics contract: the jnp fallback is BIT-IDENTICAL to v1's
 (`flash_attention._paged_decode_jnp`) on fp32 — same gather, same
 dot_general dims, same single-pass softmax — so every existing
 bit-equality oracle (full-prefill per lane, one-lane == decode) holds
-verbatim under v2. The Pallas kernel reuses v1's exact per-page
-accumulation ops (`_paged_online_page` math), so it agrees with the jnp
-path to the same f32 tolerance v1 did; for int8 pages both paths
-dequantize identically, so quantized jnp-vs-Pallas agreement is
-unchanged while the QUANTIZATION error itself is gated by the
-bounded-error + greedy-parity tests (tests/test_kv_quant.py).
+verbatim under v2. The Pallas kernel is the same online softmax summed
+in another order, with every product in f32, so it agrees with the jnp
+path to f32 rounding on every page format (2e-6 in the interpreter,
+tests/test_kv_quant.py; on the chip tests_tpu/test_serve_tpu.py and
+chip_smoke.py state their tolerances); the QUANTIZATION error itself is
+gated by the bounded-error + greedy-parity tests (tests/test_kv_quant.py).
 """
 
 from __future__ import annotations
@@ -67,11 +69,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pallas TPU backend is absent on pure-CPU builds
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 
 # --------------------------------------------------------- quantization
@@ -216,46 +214,43 @@ def _ragged_jnp(q, k_pages, v_pages, page_tables, lane_slots, lane_lens,
 
 
 # --------------------------------------------------------- Pallas kernel
-def _online_block(q, k, v, length, kv_base, m_ref, l_ref, acc_ref, *,
-                  scale):
-    """One kv-block of one lane's online-softmax accumulation — v1's
-    `_paged_online_page` ops verbatim (dot dims, f32 stats, p-stays-f32
-    v-upcasts convention) over a (bs, H, D) block instead of a single
-    page, so the f32 agreement with the jnp path carries over."""
-    h = q.shape[0]
-    bs = k.shape[0]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (2,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32) * scale     # (H, bs)
-    pos = kv_base + jax.lax.broadcasted_iota(jnp.int32, (h, bs), 1)
-    s = jnp.where(pos < length, s, -jnp.inf)
-    m_prev = m_ref[:]
-    l_prev = l_ref[:]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    m_ref[:] = m_new
-    l_ref[:] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-    pv = jax.lax.dot_general(
-        p, v.astype(jnp.float32), (((1,), (0,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32)
-    acc_ref[:] = acc_ref[:] * alpha + pv
+# Everything inside the kernel is a 2-D array with the packed H*D axis
+# on the 128-lane dimension: Mosaic refuses to split a lane dimension
+# (reshape (ps, H*D) -> (ps, H, D): "unsupported shape cast") and to
+# batch a matmul over a non-leading axis, which is how a per-head dot
+# over (bs, H, D) blocks has to be written. The per-head reductions
+# are instead matmuls with a 0/1 SEGMENT matrix seg (H*D, H),
+# seg[j, h] = (j // D == h):
+#
+#   scores   s[t, h]  = sum_d q[h, d] k[t, h, d] = ((k * q_row) @ seg)[t, h]
+#   weighted o[h*D+d] = sum_t p[t, h] v[t, h, d] = sum_t ((p @ seg^T) * v)[t, h*D+d]
+#
+# Tokens sit on sublanes and heads on lanes, so the per-(token, head)
+# scales of quantized pages multiply s and p directly and K/V are never
+# dequantized. The segment matmuls run on f32 operands at HIGHEST
+# precision: the kernel's result is f32-accurate for every page format.
+_MASK = -0.5 * float(jnp.finfo(jnp.float32).max)  # finite: exp(_MASK - m)
+#                                 is exactly 0 and never inf - inf = NaN
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _ragged_v2_kernel(pt_ref, ls_ref, ll_ref, *refs, page_size,
-                      pages_per_seq, num_blocks, block_pages, scale,
+def _seg_dot(a, b):
+    return jnp.dot(a, b, precision=_HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
+def _ragged_v2_kernel(pt_ref, ls_ref, ll_ref, q_ref, seg_ref, segt_ref,
+                      *refs, page_size, num_blocks, block_pages, scale,
                       quantized):
     """Flattened-grid kernel body. Grid (T * num_blocks,); work item
     w covers kv positions [blk * block_pages * ps, ...) of lane
     w // num_blocks. Page refs arrive head-PACKED as (1, ps, H*D)
-    blocks (plus (1, ps, H) scale blocks when quantized) and are
-    unpacked in-register; dead items (block start past the lane's
-    visible length) skip their whole accumulation."""
-    n_in = 2 * block_pages * (2 if quantized else 1) + 1
-    q_ref = refs[0]
-    kv_refs = refs[1:n_in]
-    o_ref = refs[n_in]
-    m_ref, l_ref, acc_ref = refs[n_in + 1:]
+    blocks (plus (1, ps, H) scale blocks when quantized); dead items
+    (block start past the lane's visible length) skip their whole
+    accumulation."""
+    per_page = 4 if quantized else 2
+    kv_refs = refs[:per_page * block_pages]
+    o_ref, m_ref, l_ref, acc_ref = refs[per_page * block_pages:]
 
     w = pl.program_id(0)
     t = w // num_blocks
@@ -264,54 +259,93 @@ def _ragged_v2_kernel(pt_ref, ls_ref, ll_ref, *refs, page_size,
 
     @pl.when(blk == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _MASK)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    h, d = q_ref.shape[1], q_ref.shape[2]
     base = blk * block_pages * page_size
+
+    def rows(j):
+        """Page slot j's blocks of this work item, stacked on the
+        token (sublane) axis: (block_pages * ps, ...)."""
+        parts = [kv_refs[per_page * i + j][0] for i in range(block_pages)]
+        return parts[0] if block_pages == 1 else jnp.concatenate(parts, 0)
 
     # dead item: this block starts at or past the lane's visible
     # length (lane_lens >= 1, so block 0 is always live) — skip the
     # entire accumulation. v1 computed the full masked block here.
     @pl.when(base < length)
     def _accumulate():
-        q = q_ref[0]                     # (H, D)
-        for i in range(block_pages):
-            if quantized:
-                kq = kv_refs[4 * i + 0][0]       # (ps, H*D) int8
-                ks = kv_refs[4 * i + 1][0]       # (ps, H) f32
-                vq = kv_refs[4 * i + 2][0]
-                vs = kv_refs[4 * i + 3][0]
-                k = dequantize_kv(kq.reshape(page_size, h, d), ks)
-                v = dequantize_kv(vq.reshape(page_size, h, d), vs)
-            else:
-                k = kv_refs[2 * i + 0][0].reshape(page_size, h, d)
-                v = kv_refs[2 * i + 1][0].reshape(page_size, h, d)
-            _online_block(q, k, v, length, base + i * page_size,
-                          m_ref, l_ref, acc_ref, scale=scale)
+        q = q_ref[0].astype(jnp.float32)                  # (1, H*D)
+        k = rows(0).astype(jnp.float32)                   # (bs, H*D)
+        v = rows(2 if quantized else 1).astype(jnp.float32)
+        s = _seg_dot(k * q, seg_ref[...])                 # (bs, H)
+        if quantized:
+            s = s * rows(1)                               # k scales
+        s = s * scale
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        s = jnp.where(pos < length, s, _MASK)
+        m_prev = m_ref[...]                               # (1, H)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp(s - m_new)                            # masked -> 0
+        alpha = jnp.exp(m_prev - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=0,
+                                                  keepdims=True)
+        if quantized:
+            p = p * rows(3)                               # v scales
+        # ONE pass over seg^T expands both p and the accumulator's
+        # rescale factor from per-head to per-(head, dim) lanes
+        bs = p.shape[0]
+        x = _seg_dot(
+            jnp.concatenate([p, jnp.broadcast_to(alpha, (8, alpha.shape[1]))],
+                            axis=0), segt_ref[...])       # (bs + 8, H*D)
+        acc_ref[...] = acc_ref[...] * x[bs:bs + 1] + jnp.sum(
+            x[:bs] * v, axis=0, keepdims=True)
 
     @pl.when(blk == num_blocks - 1)
     def _emit():
-        o_ref[0] = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
+        l = l_ref[...]
+        lx = _seg_dot(jnp.broadcast_to(l, (8, l.shape[1])), segt_ref[...])
+        o_ref[0] = (acc_ref[...] / lx[0:1]).astype(o_ref.dtype)
+
+
+def _vmem_limit(block_bytes: int) -> int:
+    """Scoped-VMEM request for a kernel whose pipelined blocks, scratch
+    and live intermediates total `block_bytes`: twice that (Pallas
+    double-buffers every block), never under Mosaic's own 16 MiB
+    default, capped at half a v5e core's 128 MiB."""
+    return int(min(max(2 * block_bytes, 16 * 2**20), 64 * 2**20))
 
 
 def _ragged_v2_pallas(q, k_pages, v_pages, page_tables, lane_slots,
                       lane_lens, scale, block_kv_pages, interpret,
                       k_scales=None, v_scales=None):
-    if not _HAS_PLTPU:
-        raise NotImplementedError("pallas TPU backend unavailable")
     t, h, d = q.shape
     npages, ps = k_pages.shape[0], k_pages.shape[1]
     pp = page_tables.shape[1]
     bp = max(1, min(int(block_kv_pages), pp))
     nb = -(-pp // bp)
     quantized = k_scales is not None
+    hd = h * d
 
-    # head packing: page rows stream as (ps, H*D) — contiguous in HBM,
-    # so the reshape is free — and unpack in-register in the kernel
-    kp = k_pages.reshape(npages, ps, h * d)
-    vp = v_pages.reshape(npages, ps, h * d)
+    # head packing: pages stream as (ps, H*D) rows and q / out as
+    # (1, H*D) rows — contiguous in HBM, so the reshapes are free
+    kp = k_pages.reshape(npages, ps, hd)
+    vp = v_pages.reshape(npages, ps, hd)
+    seg = (jnp.arange(hd, dtype=jnp.int32)[:, None] // d
+           == jnp.arange(h, dtype=jnp.int32)[None, :]).astype(jnp.float32)
+
+    def lane_of(w):
+        """Work item -> lane, CLAMPED to the last lane. Mosaic's
+        pipeline also evaluates the index maps for the step after the
+        grid's last one (to prefetch a block it then never uses), and
+        an index map that reads the prefetched scalars at lane T reads
+        SMEM past their end — on a v5e lane_slots[T] happens to be
+        lane_lens[0], and a 2048-token lane made page_tables[2048, .]
+        a bad_smem_address core halt. Every SMEM read in an index map
+        must be in range for ANY w."""
+        return jnp.minimum(w // nb, t - 1)
 
     def page_index(i):
         """Index map for page slot i of each work item: the physical
@@ -320,7 +354,7 @@ def _ragged_v2_pallas(q, k_pages, v_pages, page_tables, lane_slots,
         already resident, so they issue no new DMA (their compute is
         pl.when-skipped anyway)."""
         def imap(w, pt, ls, ll):
-            tt = w // nb
+            tt = lane_of(w)
             col = (w % nb) * bp + i
             # clamp into both the table and the lane's live range so
             # dead items never demand a fresh (sink) page DMA
@@ -329,45 +363,98 @@ def _ragged_v2_pallas(q, k_pages, v_pages, page_tables, lane_slots,
             return (pt[ls[tt], col], 0, 0)
         return imap
 
-    def q_index(w, pt, ls, ll):
-        return (w // nb, 0, 0)
+    def lane_index(w, pt, ls, ll):
+        return (lane_of(w), 0, 0)
 
-    in_specs = [pl.BlockSpec((1, h, d), q_index)]
-    args = [q]
+    def whole(w, pt, ls, ll):
+        return (0, 0)
+
+    in_specs = [pl.BlockSpec((1, 1, hd), lane_index),
+                pl.BlockSpec((hd, h), whole),
+                pl.BlockSpec((h, hd), whole)]
+    args = [q.reshape(t, 1, hd), seg, seg.T]
     for i in range(bp):
         imap = page_index(i)
-        in_specs.append(pl.BlockSpec((1, ps, h * d), imap))
+        in_specs.append(pl.BlockSpec((1, ps, hd), imap))
         args.append(kp)
         if quantized:
             in_specs.append(pl.BlockSpec((1, ps, h), imap))
             args.append(k_scales)
-        in_specs.append(pl.BlockSpec((1, ps, h * d), imap))
+        in_specs.append(pl.BlockSpec((1, ps, hd), imap))
         args.append(vp)
         if quantized:
             in_specs.append(pl.BlockSpec((1, ps, h), imap))
             args.append(v_scales)
     kern = functools.partial(
-        _ragged_v2_kernel, page_size=ps, pages_per_seq=pp,
-        num_blocks=nb, block_pages=bp, scale=scale, quantized=quantized)
+        _ragged_v2_kernel, page_size=ps, num_blocks=nb, block_pages=bp,
+        scale=scale, quantized=quantized)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # page_tables, lane_slots, lane_lens
         grid=(t * nb,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, d), q_index),
+        out_specs=pl.BlockSpec((1, 1, hd), lane_index),
         scratch_shapes=[
-            pltpu.VMEM((h, 1), jnp.float32),   # running max
-            pltpu.VMEM((h, 1), jnp.float32),   # running sum
-            pltpu.VMEM((h, d), jnp.float32),   # output accumulator
+            pltpu.VMEM((1, h), jnp.float32),    # running max
+            pltpu.VMEM((1, h), jnp.float32),    # running sum
+            pltpu.VMEM((1, hd), jnp.float32),   # output accumulator
         ],
     )
-    return pl.pallas_call(
+    bs = bp * ps
+    lanes = -(-h // 128) * 128      # a (.., h) f32 tile pads to 128 lanes
+    block_bytes = (
+        2 * bs * hd * jnp.dtype(k_pages.dtype).itemsize     # K + V pages
+        + (2 * bs * lanes * 4 if quantized else 0)          # their scales
+        + hd * lanes * 4 + max(h, 8) * hd * 4               # seg, seg^T
+        + 5 * (bs + 8) * hd * 4)    # f32 K, V, k*q, p@seg^T, its product
+    out = pl.pallas_call(
         kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((t, 1, hd), q.dtype),
+        # the grid axis carries the online-softmax scratch from one
+        # work item of a lane to the next: it must run in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_vmem_limit(block_bytes)),
         interpret=interpret,
+        name="paged_ragged_v2",
     )(page_tables, lane_slots, lane_lens, *args)
+    return out.reshape(t, h, d)
 
 
 # ------------------------------------------------------------ entry point
+PALLAS = "pallas"                    # compiled by Mosaic (TPU only)
+PALLAS_INTERPRET = "pallas_interpret"  # the Pallas interpreter, any backend
+JNP = "jnp"                          # the gather + XLA path
+
+
+def resolve_paged_impl(use_pallas=None, interpret=False) -> str:
+    """The one rule that picks a paged-attention implementation, so a
+    caller (ServeEngine) can resolve it ONCE, report it, and pass the
+    resolved booleans down:
+
+      use_pallas=False  -> JNP               (asked for by argument)
+      interpret=True    -> PALLAS_INTERPRET  (asked for by argument)
+      use_pallas=None   -> PALLAS on a tpu backend, JNP elsewhere (the
+                           CPU tests' path)
+      use_pallas=True   -> PALLAS; off-TPU that is an error, not a
+                           quiet jnp run
+
+    On a tpu backend nothing but an argument reaches the interpreter
+    or the jnp path."""
+    if use_pallas is False:
+        return JNP
+    if interpret:
+        return PALLAS_INTERPRET
+    on_tpu = jax.default_backend() == "tpu"
+    if use_pallas is None and not on_tpu:
+        return JNP
+    if not on_tpu:
+        raise RuntimeError(
+            f"use_pallas=True needs a tpu backend to compile for (this "
+            f"one is {jax.default_backend()!r}); pass interpret=True to "
+            f"run the kernel through the Pallas interpreter")
+    return PALLAS
+
+
 def paged_attention_ragged_v2(q, k_pages, v_pages, page_tables,
                               lane_slots, lane_lens, *, k_scales=None,
                               v_scales=None, scale=None, block_kv=None,
@@ -385,25 +472,25 @@ def paged_attention_ragged_v2(q, k_pages, v_pages, page_tables,
         pages).
 
     fp32 outputs are bit-identical to v1 on the jnp path (same math);
-    Pallas-vs-jnp agreement is the same f32 tolerance as v1.
+    the Pallas kernel agrees with it to f32 rounding (it sums in a
+    different order). use_pallas/interpret pick the implementation by
+    resolve_paged_impl.
     """
     if (k_scales is None) != (v_scales is None):
         raise ValueError("k_scales and v_scales must be given together")
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    if use_pallas is None:
-        use_pallas = (interpret or (_HAS_PLTPU
-                                    and jax.default_backend() == "tpu"))
-    if use_pallas:
-        ps = k_pages.shape[1]
-        if block_kv is None:
-            block_kv = choose_block_kv(
-                ps, page_tables.shape[1], q.shape[1], q.shape[2],
-                jnp.dtype(k_pages.dtype).itemsize)
-        return _ragged_v2_pallas(
-            q, k_pages, v_pages, page_tables, lane_slots, lane_lens,
-            scale, max(1, int(block_kv) // ps), interpret,
-            k_scales=k_scales, v_scales=v_scales)
-    return _ragged_jnp(q, k_pages, v_pages, page_tables, lane_slots,
-                       lane_lens, scale, k_scales=k_scales,
-                       v_scales=v_scales)
+    impl = resolve_paged_impl(use_pallas, interpret)
+    if impl == JNP:
+        return _ragged_jnp(q, k_pages, v_pages, page_tables, lane_slots,
+                           lane_lens, scale, k_scales=k_scales,
+                           v_scales=v_scales)
+    ps = k_pages.shape[1]
+    if block_kv is None:
+        block_kv = choose_block_kv(
+            ps, page_tables.shape[1], q.shape[1], q.shape[2],
+            jnp.dtype(k_pages.dtype).itemsize)
+    return _ragged_v2_pallas(
+        q, k_pages, v_pages, page_tables, lane_slots, lane_lens,
+        scale, max(1, int(block_kv) // ps), impl == PALLAS_INTERPRET,
+        k_scales=k_scales, v_scales=v_scales)

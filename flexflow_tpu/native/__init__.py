@@ -4,14 +4,19 @@ The reference keeps its runtime (simulator, search loop, data loader) in
 C++ behind a flat C API consumed by Python via cffi
 (python/flexflow_c.h + flexflow_cbinding.py). This package does the
 same with ctypes: `csrc/` holds the C++ sources and `flexflow_tpu_c.h`
-the C API; the shared library is built on first use with g++ (cached by
-source mtime) and every caller has a pure-Python fallback, so the
-framework degrades gracefully on machines without a toolchain.
+the C API; the shared library is built on first use with g++ into the
+git-ignored `_build/`, under a file name that carries a hash of the
+sources and headers — so a library built from other sources (a stale
+checkout, a copy that reset mtimes) is never loaded. Every caller has
+a pure-Python fallback for machines without a toolchain; `status()`
+says which of the two ran and why.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import sys
@@ -23,7 +28,7 @@ from typing import Optional
 _PKG_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _CSRC = os.path.join(_PKG_ROOT, "csrc")
 _BUILD_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_build")
-_LIB_PATH = os.path.join(_BUILD_DIR, "libflexflow_tpu_native.so")
+_LIB_STEM = "libflexflow_tpu_native"
 
 _SOURCES = ("simulator.cc", "mcmc.cc", "dataloader.cc", "embedding_bag.cc")
 _HEADERS = ("flexflow_tpu_c.h", "sim_core.h")
@@ -31,17 +36,23 @@ _HEADERS = ("flexflow_tpu_c.h", "sim_core.h")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
+_status = "not loaded yet"
 
 
-def _needs_build() -> bool:
-    if not os.path.exists(_LIB_PATH):
-        return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
+def source_hash() -> str:
+    """sha256 over the names and bytes of every source and header the
+    library is built from (first 16 hex digits)."""
+    h = hashlib.sha256()
     for f in _SOURCES + _HEADERS:
-        p = os.path.join(_CSRC, f)
-        if os.path.exists(p) and os.path.getmtime(p) > lib_mtime:
-            return True
-    return False
+        h.update(f.encode() + b"\0")
+        with open(os.path.join(_CSRC, f), "rb") as fh:
+            h.update(fh.read())
+    return h.hexdigest()[:16]
+
+
+def lib_path() -> str:
+    """Where the library for the CURRENT sources lives."""
+    return os.path.join(_BUILD_DIR, f"{_LIB_STEM}.{source_hash()}.so")
 
 
 def build(verbose: bool = False) -> str:
@@ -49,9 +60,11 @@ def build(verbose: bool = False) -> str:
 
     Compiles to a process-unique temp path and renames into place so
     concurrent builders (pytest-xdist, multi-process JAX) never expose a
-    half-written library to ctypes.CDLL."""
+    half-written library to ctypes.CDLL. Libraries of other source
+    hashes are removed once the new one is in place."""
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    tmp_path = f"{_LIB_PATH}.{os.getpid()}.tmp"
+    path = lib_path()
+    tmp_path = f"{path}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-fPIC", "-shared", "-std=c++17", "-Wall",
            "-I", _CSRC,
            *(os.path.join(_CSRC, s) for s in _SOURCES),
@@ -60,11 +73,17 @@ def build(verbose: bool = False) -> str:
         print("[native]", " ".join(cmd), file=sys.stderr)
     try:
         subprocess.run(cmd, check=True, capture_output=not verbose)
-        os.replace(tmp_path, _LIB_PATH)
+        os.replace(tmp_path, path)
     finally:
         if os.path.exists(tmp_path):
             os.unlink(tmp_path)
-    return _LIB_PATH
+    for old in glob.glob(os.path.join(_BUILD_DIR, f"{_LIB_STEM}*.so")):
+        if old != path:
+            try:
+                os.unlink(old)
+            except OSError:
+                pass  # another process may have removed it first
+    return path
 
 
 def _declare(lib: ctypes.CDLL) -> None:
@@ -119,9 +138,11 @@ def _declare(lib: ctypes.CDLL) -> None:
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
-    """The native library, building it if stale; None if unavailable
-    (no toolchain / build failure — callers fall back to Python)."""
-    global _lib, _load_failed
+    """The native library for the current sources, building it when
+    `_build/` holds none under their hash; None if unavailable (no
+    toolchain / build failure — callers fall back to Python, and
+    `status()` keeps the reason)."""
+    global _lib, _load_failed, _status
     if _lib is not None or _load_failed:
         return _lib
     with _lock:
@@ -129,13 +150,18 @@ def get_lib() -> Optional[ctypes.CDLL]:
             return _lib
         if os.environ.get("FLEXFLOW_TPU_NO_NATIVE"):
             _load_failed = True
+            _status = "python: FLEXFLOW_TPU_NO_NATIVE is set"
             return None
         try:
-            if _needs_build():
+            path = lib_path()
+            built = not os.path.exists(path)
+            if built:
                 build()
-            lib = ctypes.CDLL(_LIB_PATH)
+            lib = ctypes.CDLL(path)
             _declare(lib)
             _lib = lib
+            _status = (f"native: {os.path.basename(path)} "
+                       f"({'built now' if built else 'found built'})")
         except (OSError, subprocess.CalledProcessError) as e:
             detail = ""
             stderr = getattr(e, "stderr", None)
@@ -146,8 +172,15 @@ def get_lib() -> Optional[ctypes.CDLL]:
             print(f"[flexflow_tpu.native] falling back to Python "
                   f"implementations ({e}){detail}", file=sys.stderr)
             _load_failed = True
+            _status = f"python: native library unavailable ({e})"
     return _lib
 
 
 def available() -> bool:
     return get_lib() is not None
+
+
+def status() -> str:
+    """Which implementation get_lib() resolved to, and why: "native:
+    <file> (built now | found built)" or "python: <reason>"."""
+    return _status

@@ -56,6 +56,9 @@ class MultiHeadAttention(Op):
         self.add_zero_attn = add_zero_attn
         self.causal = causal
         self.use_flash = use_flash
+        # the core-attention implementation the last trace resolved
+        # ("flash" | "xla"; None before the first forward)
+        self.attn_impl = None
         self.q_in = q.shape[-1]
         self.k_in = k.shape[-1]
         self.v_in = v.shape[-1]
@@ -203,24 +206,24 @@ class MultiHeadAttention(Op):
         # flash path handles neither seq_length truncation nor the
         # (now off-block-size) zero-attn row; use XLA for those.
         #
-        # use_flash is tri-state: None = auto (the measured
-        # flash_profitable gate, kernels/flash_attention.py — shared
-        # with the all-to-all SP lowering), True = force the Pallas
-        # kernel whenever shapes allow, False = never. pad_lanes=False
-        # for d=64 showed no consistent win in the same sweep, so it
-        # stays opt-in via flash_attention_bshd.
+        # use_flash is tri-state: None = auto (a tpu backend, a shape
+        # the kernel takes, and the measured flash_profitable gate —
+        # kernels/flash_attention.resolve_flash, shared with the
+        # all-to-all SP lowering), True = force the Pallas kernel,
+        # False = never. The decision is made BEFORE the call: a kernel
+        # that is chosen and then raises, raises. pad_lanes=False for
+        # d=64 showed no consistent win in the same sweep, so it stays
+        # opt-in via flash_attention_bshd.
         b, sq, h, d = q.shape
         sk = k.shape[1]
-        from ..kernels.flash_attention import flash_profitable
-        if ((self.use_flash is True
-             or (self.use_flash is None
-                 and flash_profitable(b, h, sq, sk, d)))
-                and not has_seq_trunc and not self.add_zero_attn):
-            from ..kernels.flash_attention import flash_attention_bshd
-            try:
-                return flash_attention_bshd(q, k, v, causal=self.causal)
-            except Exception:
-                pass  # fall back to the XLA path (e.g. tiny shapes on CPU)
+        from ..kernels.flash_attention import (flash_attention_bshd,
+                                               resolve_flash)
+        self.attn_impl = "flash" if (
+            not has_seq_trunc and not self.add_zero_attn
+            and resolve_flash(self.use_flash, b, h, sq, sk, d,
+                              jnp.dtype(q.dtype).itemsize)) else "xla"
+        if self.attn_impl == "flash":
+            return flash_attention_bshd(q, k, v, causal=self.causal)
         scale = 1.0 / math.sqrt(self.head_dim)
         logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                             preferred_element_type=jnp.float32) * scale

@@ -75,18 +75,7 @@ class LSTM(Op):
               .reshape(b, t, 4 * h) + bias)
         xg = jnp.swapaxes(xg, 0, 1)  # (T, B, 4H) for scan
 
-        use_pallas = self.use_pallas
-        if use_pallas is None:
-            # session-level A/B knob (tools/tpu_session.sh): flip the
-            # undecided default from the environment without editing
-            # model code. Read at TRACE time and baked into the compiled
-            # step — an already-compiled model will NOT pick up a later
-            # env change (jit cache keys don't include env); run each
-            # A/B arm in its own process, as the session script does.
-            import os
-            use_pallas = os.environ.get(
-                "FLEXFLOW_TPU_LSTM_PALLAS", "") == "1"
-        if use_pallas:
+        if self.use_pallas:
             from ..kernels.lstm_scan import lstm_sequence
             ys = lstm_sequence(xg.astype(x.dtype), wh.astype(x.dtype),
                                jnp.zeros((b, h), x.dtype),

@@ -47,7 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from ._compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..op import Op, OpContext

@@ -89,6 +89,19 @@ class MachineSpec:
             num_chips=num_chips, peak_flops=197e12, hbm_bandwidth=8.1e11,
             hbm_capacity=16e9, ici_bandwidth=4.5e10, dcn_bandwidth=25e9)
 
+    @staticmethod
+    def for_device_kind(device_kind: str) -> Optional["MachineSpec"]:
+        """The spec for a TPU `device_kind` string as JAX reports it
+        ("TPU v5 lite" is a v5e chip), or None for a kind the repo
+        holds no numbers for — callers on a TPU backend treat None as
+        an error, never as "price it like a v5e"."""
+        kind = device_kind.lower()
+        if "v5 lite" in kind or "v5e" in kind:
+            return MachineSpec.v5e()
+        if "v5p" in kind:
+            return MachineSpec()  # the dataclass defaults are a v5p's
+        return None
+
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str],
               devices: Optional[Sequence] = None) -> Mesh:
@@ -113,6 +126,28 @@ def default_mesh(num_devices: Optional[int] = None) -> Mesh:
 
 def single_device_mesh() -> Mesh:
     return make_mesh((1,), (DATA,), jax.devices()[:1])
+
+
+def replica_devices(index: int, degree: int,
+                    devices: Optional[Sequence] = None,
+                    platform: Optional[str] = None) -> tuple:
+    """The chips a serving pool's replica `index` of tensor degree
+    `degree` owns: devices [index*degree, (index+1)*degree) — replicas
+    never stack on chip 0. On a tpu platform a replica that reaches past
+    the last chip is an error naming both numbers. The virtual-CPU test
+    platform builds more replicas than it has devices, so there the
+    range wraps (and the pool's report shows the shared devices).
+    `devices` / `platform` default to jax.devices() and its platform."""
+    if devices is None:
+        devices = jax.devices()
+    if platform is None:
+        platform = devices[0].platform
+    lo, hi = int(index) * int(degree), (int(index) + 1) * int(degree)
+    if hi > len(devices) and platform == "tpu":
+        raise ValueError(
+            f"replica {index} at tensor degree {degree} needs chips "
+            f"[{lo}, {hi}) but this host has {len(devices)}")
+    return tuple(devices[j % len(devices)] for j in range(lo, hi))
 
 
 def serve_tensor_mesh(tensor_parallel: int,

@@ -20,7 +20,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ._compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

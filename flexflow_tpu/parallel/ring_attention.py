@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from ._compat import shard_map
+from jax import shard_map
 
 
 def _block_scores(q, k, scale):

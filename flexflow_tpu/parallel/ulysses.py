@@ -29,7 +29,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ._compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 # score-matrix bytes per device above which `auto` falls back to ring
@@ -73,19 +73,15 @@ def _alltoall_attn_local(q, k, v, *, axis_name, causal, scale,
     # s/n x s/n). Same tri-state + measured gate as the unsharded
     # dispatch (ops/attention.py); the kernel bakes in 1/sqrt(d), so a
     # caller-custom scale falls back to the XLA path.
-    from ..kernels.flash_attention import flash_profitable
+    from ..kernels.flash_attention import (flash_attention_bshd,
+                                           resolve_flash)
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    want_flash = (use_flash is True
-                  or (use_flash is None
-                      and flash_profitable(b, h, sq, sk, d)))
-    if want_flash and abs(scale * math.sqrt(d) - 1.0) < 1e-6:
-        try:
-            from ..kernels.flash_attention import flash_attention_bshd
-            out = flash_attention_bshd(q, k, v, causal=causal)
-            return _a2a(out, axis_name, split_axis=1, concat_axis=2)
-        except Exception:
-            pass  # tiny shapes / non-TPU: XLA path below
+    if (abs(scale * math.sqrt(d) - 1.0) < 1e-6
+            and resolve_flash(use_flash, b, h, sq, sk, d,
+                              jnp.dtype(q.dtype).itemsize)):
+        out = flash_attention_bshd(q, k, v, causal=causal)
+        return _a2a(out, axis_name, split_axis=1, concat_axis=2)
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32),
                    preferred_element_type=jnp.float32) * scale

@@ -15,9 +15,10 @@ calibrated efficiency factors, torus/DCN layout, and mesh shape: any
 change to what the cost formulas would see invalidates the entries
 (stale entries for other fingerprints are kept in the file, not used).
 
-Path: ~/.cache/flexflow_tpu/costcache.json by default (root overridable
-via FLEXFLOW_TPU_CACHE like the calibration caches, file overridable
-via FFConfig.cost_cache_file / --cost-cache). One CostCache object per
+Path: costcache.json under utils/cache_dirs.measurement_cache_dir()
+(with the compile cache; root overridable via FLEXFLOW_TPU_CACHE like
+the calibration caches, file overridable via FFConfig.cost_cache_file /
+--cost-cache). One CostCache object per
 path is shared process-wide — parallel annealing chains read and write
 the same store under a lock.
 """
@@ -112,10 +113,8 @@ def machine_fingerprint(mm, mesh=None, precision=None,
 
 
 def default_path() -> str:
-    root = os.environ.get(
-        "FLEXFLOW_TPU_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "flexflow_tpu"))
-    return os.path.join(root, "costcache.json")
+    from ..utils.cache_dirs import measurement_cache_dir
+    return os.path.join(measurement_cache_dir(), "costcache.json")
 
 
 class CostCache:

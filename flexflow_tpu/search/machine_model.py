@@ -237,21 +237,30 @@ def assign_axis_topology(mesh, torus_dims: tuple,
 def default_machine_model(mesh=None, spec: Optional[MachineSpec] = None,
                           machine_file: Optional[str] = None
                           ) -> TPUMachineModel:
-    """Build a model for the current device (v5e single chip by default).
+    """Build a model for the current device. On a TPU the spec comes
+    from the device's `device_kind` (MachineSpec.for_device_kind); a
+    kind the repo holds no peaks for is an error unless `machine_file`
+    describes the machine. Off-TPU (the CPU test platform) the v5e spec
+    stands, as the stated simulation target.
     `machine_file` (FFConfig.machine_model_file) may override MachineSpec
     fields via JSON — the analog of the reference's machine config file
     (machine_config_example). A multi-host run marks the mesh's `data`
     axis as DCN-resident (cross-slice collectives priced at DCN rates)."""
+    import jax
     user_spec = spec is not None
     if spec is None:
         spec = MachineSpec.v5e()
-        try:
-            import jax
-            kind = jax.devices()[0].device_kind.lower()
-            if "v5p" in kind or "v4" in kind:
-                spec = MachineSpec()
-        except Exception:
-            pass
+        dev = jax.devices()[0]
+        if dev.platform == "tpu":
+            known = MachineSpec.for_device_kind(dev.device_kind)
+            if known is not None:
+                spec = known
+            elif not machine_file:
+                raise ValueError(
+                    f"no MachineSpec for TPU device_kind "
+                    f"{dev.device_kind!r}: add it to "
+                    f"parallel/mesh.MachineSpec.for_device_kind or "
+                    f"describe the machine with --machine-model-file")
     file_keys = set()
     file_data: Dict = {}
     if machine_file:
@@ -264,16 +273,12 @@ def default_machine_model(mesh=None, spec: Optional[MachineSpec] = None,
     dcn_axes = ()
     if mesh is not None:
         spec.num_chips = int(mesh.size)
-        try:
-            import jax
-            if jax.process_count() > 1 and "data" in mesh.shape:
-                dcn_axes = ("data",)
-                # autodetected topology must not clobber an explicit
-                # value — from the machine file OR a caller-built spec
-                if "chips_per_host" not in file_keys and not user_spec:
-                    spec.chips_per_host = max(1, jax.local_device_count())
-        except Exception:
-            pass
+        if jax.process_count() > 1 and "data" in mesh.shape:
+            dcn_axes = ("data",)
+            # autodetected topology must not clobber an explicit
+            # value — from the machine file OR a caller-built spec
+            if "chips_per_host" not in file_keys and not user_spec:
+                spec.chips_per_host = max(1, jax.local_device_count())
     # physical-torus layout: machine-file per-axis pins
     # ({"axis_topology": {"data": [4, 4]}}) fully govern the axes they
     # mention — a pin dropped as invalid leaves THAT axis flat-ring, as

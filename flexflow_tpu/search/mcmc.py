@@ -539,12 +539,15 @@ def _optimize_impl(model, budget: int, alpha: float, mesh, seed: int,
     and taskgraph export stay with the caller, so mesh-shape sweeps
     and chains can run this concurrently)."""
     cfg = model.config
+    # which engine annealed and why lands in the stats ("engine")
+    why_python = "use_native=False" if use_native is False else None
     # fused searches must anneal in the Python engine (the native table
     # cannot price fusion folding); optimize() raises on an explicit
     # use_native=True, every other caller (incl. optimize_with_mesh's
     # per-shape runs) gets coerced here
-    if cfg.perform_fusion and use_native is not True:
+    if cfg.perform_fusion and use_native is None:
         use_native = False
+        why_python = "the native table cannot price fusion folding"
     sim = simulator or Simulator(
         model, mesh,
         calibrated_machine_model(mesh,
@@ -556,8 +559,9 @@ def _optimize_impl(model, budget: int, alpha: float, mesh, seed: int,
     # with its pre-bucket sync model)
     if (sim.overlap and sim.bucket_mb > 0
             and int(mesh.shape.get("data", 1)) > 1
-            and use_native is not True):
+            and use_native is None):
         use_native = False
+        why_python = "bucketed grad-sync pricing is Python-only"
 
     cands = {op.name: candidate_maps(op, mesh, cfg, op_index=i)
              for i, op in enumerate(model.ops)}
@@ -565,10 +569,11 @@ def _optimize_impl(model, budget: int, alpha: float, mesh, seed: int,
     trace = None  # per-proposal search tracing (search/trace.py);
     # created once the per-chain budget is known below
 
-    def stats_for(sims, proposals):
+    def stats_for(sims, proposals, engine):
         out: Dict[str, object] = {}
         for s in sims:
             _merge_stats(out, s.search_stats())
+        out["engine"] = engine
         out["proposals"] = proposals
         out["chains"] = len(sims)
         out["wall_s"] = time.perf_counter() - t0
@@ -587,6 +592,7 @@ def _optimize_impl(model, budget: int, alpha: float, mesh, seed: int,
     # staged moves, native speed retained.
     staged = staged_strategies(model, mesh, cfg)
     if use_native is not False:
+        from .. import native
         from .native_search import optimize_native
         found = optimize_native(model, sim, cands, budget, alpha, seed,
                                 verbose=verbose)
@@ -602,9 +608,12 @@ def _optimize_impl(model, budget: int, alpha: float, mesh, seed: int,
                         if verbose:
                             print(f"[search] staged pipeline wins: "
                                   f"{best_cost*1e3:.3f} ms/step")
-            return best, best_cost, sim, stats_for([sim], budget)
+            return best, best_cost, sim, stats_for(
+                [sim], budget, f"native ({native.status()})")
         assert use_native is not True, "native search requested but " \
             "the native library is unavailable"
+        why_python = native.status()
+    engine = f"python ({why_python})"
     _, edges = op_edges(model)
 
     init = (model.strategy or Strategy()).copy()
@@ -622,7 +631,7 @@ def _optimize_impl(model, budget: int, alpha: float, mesh, seed: int,
 
     searchable = [op for op in model.ops if len(cands[op.name]) > 1]
     if not searchable or budget <= 0:
-        return best, best_cost, sim, stats_for([sim], 0)
+        return best, best_cost, sim, stats_for([sim], 0, engine)
 
     # K independent chains over a shared read-only candidate set and
     # one process-wide persistent cost cache; the TOTAL budget is split
@@ -655,7 +664,8 @@ def _optimize_impl(model, budget: int, alpha: float, mesh, seed: int,
     for cb, cc in results:
         if cc < best_cost:
             best, best_cost = cb, cc
-    return best, best_cost, sim, stats_for(sims, per_chain * chains)
+    return best, best_cost, sim, stats_for(sims, per_chain * chains,
+                                          engine)
 
 
 def optimize(model, budget: int = 1000, alpha: float = 0.05,

@@ -251,24 +251,27 @@ class DisaggCluster:
                 f"least one page ({ps} tokens): the decode role "
                 f"recomputes handoff tail chunks through it")
 
-        def role_engine(budget: int) -> ServeEngine:
+        def role_engine(budget: int, index: int) -> ServeEngine:
             role_cfg = dataclasses.replace(
                 cfg, serve_prefill_budget=int(budget),
                 # role engines own no scrape endpoint — the cluster's
                 # caller decides where metrics serve from
                 metrics_port=None)
+            # engine `index` (prefill engines first, then decode) owns
+            # its own chips, like a ReplicaPool replica
             return ServeEngine(
                 model, chunked_prefill=True, prefix_cache=True,
                 spec_tokens=spec_tokens, drafter=drafter,
                 use_pallas=use_pallas, interpret=interpret,
-                telemetry=self.telemetry, config=role_cfg)
+                replica=index, telemetry=self.telemetry, config=role_cfg)
 
         full_budget = int(getattr(cfg, "serve_prefill_budget", 512))
+        n_pre = int(prefill_engines)
         self.prefill: List[ServeEngine] = [
-            role_engine(full_budget) for _ in range(int(prefill_engines))]
+            role_engine(full_budget, i) for i in range(n_pre)]
         self.decode: List[ServeEngine] = [
-            role_engine(self.decode_budget)
-            for _ in range(int(decode_engines))]
+            role_engine(self.decode_budget, n_pre + i)
+            for i in range(int(decode_engines))]
         # prefill-role speculation is moot (max_new=1 never decodes);
         # leave it configured — the scheduler simply never drafts
         self.kv_exact = self.prefill[0].kv_exact

@@ -61,10 +61,12 @@ import numpy as np
 from ..config import CompMode
 from ..kernels.flash_attention import (paged_attention_decode,
                                        paged_attention_ragged)
-from ..kernels.paged_ragged_v2 import (choose_block_kv,
+from ..kernels.paged_ragged_v2 import (JNP, PALLAS_INTERPRET,
+                                       choose_block_kv,
                                        quantize_kv_rows,
-                                       ragged_dispatch_passes)
-from ..parallel.mesh import TENSOR
+                                       ragged_dispatch_passes,
+                                       resolve_paged_impl)
+from ..parallel.mesh import TENSOR, replica_devices, serve_tensor_mesh
 from ..utils.faults import FaultInjector, TransientError, injector_for
 from ..utils.telemetry import (Telemetry, pow2_bucket, serve_metrics,
                                telemetry_for)
@@ -100,13 +102,10 @@ class _CompileEvents:
     @classmethod
     def install(cls) -> bool:
         if cls._installed is None:
-            try:
-                from jax import monitoring
-                monitoring.register_event_duration_secs_listener(
-                    cls._on_event)
-                cls._installed = True
-            except Exception:   # monitoring API absent on this jax
-                cls._installed = False
+            from jax import monitoring
+            monitoring.register_event_duration_secs_listener(
+                cls._on_event)
+            cls._installed = True
         return cls._installed
 
     @staticmethod
@@ -233,6 +232,7 @@ class ServeEngine:
                  spec_tokens: Optional[int] = None,
                  drafter=None, faults: Optional[FaultInjector] = None,
                  mesh=None, tensor_parallel: Optional[int] = None,
+                 replica: Optional[int] = None,
                  telemetry: Optional[Telemetry] = None,
                  host_tier=None, config=None):
         if model.state is None:
@@ -242,8 +242,15 @@ class ServeEngine:
         # DisaggCluster gives each role its own serving knobs (prefill
         # budget, scrape endpoint) over ONE shared model
         self.config = config if config is not None else model.config
-        self._use_pallas = use_pallas
-        self._interpret = interpret
+        # the paged-attention implementation, resolved ONCE from the
+        # arguments and the backend (kernels/paged_ragged_v2.
+        # resolve_paged_impl): "pallas" (Mosaic-compiled),
+        # "pallas_interpret" or "jnp". Every dispatch passes the
+        # resolved choice down; last_stats / boot_stats / the program
+        # fingerprint report it.
+        self.attn_impl = resolve_paged_impl(use_pallas, interpret)
+        self._attn_kw = {"use_pallas": self.attn_impl != JNP,
+                         "interpret": self.attn_impl == PALLAS_INTERPRET}
         self._read_arch(model)
         if max_seq_len is None:
             max_seq_len = self.max_positions
@@ -258,7 +265,7 @@ class ServeEngine:
         # resolves it — "auto" closes the paper's loop for inference by
         # asking the placement search (search/serve_place.optimize_serve)
         # which degree minimizes the simulated decode step.
-        self._resolve_serve_mesh(mesh, tensor_parallel)
+        self._resolve_serve_mesh(mesh, tensor_parallel, replica)
         self.cache_cfg = KVCacheConfig.from_ff(
             self.config, num_layers=self.num_layers,
             num_heads=self.num_heads, head_dim=self.head_dim,
@@ -368,11 +375,13 @@ class ServeEngine:
                 f"program (quantize-on-write lives in the mixed step); "
                 f"the legacy bucket-prefill path supports "
                 f"float32/bfloat16")
-        if self.tp > 1 and not self.chunked_prefill:
+        if (self.tp > 1 or self._home is not None) \
+                and not self.chunked_prefill:
             raise ValueError(
-                "sharded serving (serve_mesh / tensor_parallel > 1) "
-                "shards the ONE mixed program; the legacy bucket-"
-                "prefill path is single-device only")
+                "sharded serving (serve_mesh / tensor_parallel > 1) and "
+                "replica placement apply to the ONE mixed program; the "
+                "legacy bucket-prefill path is single-device only, on "
+                "the default device")
         # ragged kernel v2 kv-block shape: explicit knob, else the
         # autotune-by-shape table (kernels/paged_ragged_v2.py) — sized
         # for the PER-DEVICE head count, which is what the sharded
@@ -491,7 +500,10 @@ class ServeEngine:
             self._mixed_q_jit = jax.jit(self._mixed_q_tp_impl,
                                         donate_argnums=(1, 2, 3, 4))
         else:
-            self._step_params = self.params
+            # a placed replica holds its own copy of the weights on its
+            # chip; an unplaced engine reads the model's arrays in place
+            self._step_params = self.params if self._home is None \
+                else jax.device_put(self.params, self._home)
             self._mixed_jit = jax.jit(self._mixed_impl,
                                       donate_argnums=(1, 2))
             # quantized pools thread the scale arrays through the same
@@ -587,8 +599,8 @@ class ServeEngine:
                 break
             except TransientError:
                 # bounded retry-with-backoff: transient dispatch faults
-                # (injected chaos, a flaky device tunnel) are absorbed
-                # here instead of failing the batch. Only retry while
+                # (injected chaos, a flaky link) are absorbed here
+                # instead of failing the batch. Only retry while
                 # the donated page arrays are still live — a dispatch
                 # that consumed them before dying cannot be redone.
                 attempt += 1
@@ -657,8 +669,10 @@ class ServeEngine:
             "adapter_rank": 0 if ac is None else ac.rank,
             "adapter_slots": 0 if ac is None else ac.num_slots,
             "tp": self.tp,
-            "use_pallas": bool(self._use_pallas),
-            "interpret": bool(self._interpret),
+            # an executable runs only on the devices it was compiled
+            # for: replicas on different chips keep separate stores
+            "device_ids": tuple(int(d.id) for d in self.devices),
+            "attn_impl": self.attn_impl,
         }
 
     # ---------------- model introspection -----------------------------
@@ -697,10 +711,15 @@ class ServeEngine:
         self.ff_dim = int(self.params["layer0_ff1"]["kernel"].shape[1])
 
     # ---------------- tensor-parallel sharding -------------------------
-    def _resolve_serve_mesh(self, mesh, tensor_parallel) -> None:
-        """Resolve (tp, tp_mesh) from the explicit args or
+    def _resolve_serve_mesh(self, mesh, tensor_parallel,
+                            replica=None) -> None:
+        """Resolve (tp, tp_mesh, devices) from the explicit args or
         FFConfig.serve_mesh ('' = single device, 'N' = degree N,
-        'auto' = the placement search picks)."""
+        'auto' = the placement search picks). `replica` (a pool's
+        index for this engine) places it on chips
+        [replica*tp, (replica+1)*tp) — parallel/mesh.replica_devices —
+        instead of the first tp; None keeps a tp=1 engine's arrays
+        uncommitted on the default device."""
         cfg = self.config
         self.serve_placement = None  # set by the 'auto' path below
         if mesh is None and tensor_parallel is None:
@@ -723,9 +742,21 @@ class ServeEngine:
             self.tp = int(mesh.shape[TENSOR])
             self.tp_mesh = mesh if self.tp > 1 else None
         elif tensor_parallel is not None and int(tensor_parallel) > 1:
-            from ..parallel.mesh import serve_tensor_mesh
             self.tp = int(tensor_parallel)
-            self.tp_mesh = serve_tensor_mesh(self.tp)
+            self.tp_mesh = serve_tensor_mesh(
+                self.tp, replica_devices(replica or 0, self.tp))
+        # where this engine's programs run, and (placed tp=1 replicas
+        # only) the sharding its weights, pools and host-built step
+        # inputs commit to
+        self._home = None
+        if self.tp_mesh is not None:
+            self.devices = tuple(self.tp_mesh.devices.flat)
+        elif replica is not None:
+            from jax.sharding import SingleDeviceSharding
+            self.devices = replica_devices(replica, 1)
+            self._home = SingleDeviceSharding(self.devices[0])
+        else:
+            self.devices = (jax.devices()[0],)
         if self.tp > 1 and self.num_heads % self.tp != 0:
             raise ValueError(
                 f"sharded serving needs num_heads ({self.num_heads}) "
@@ -854,9 +885,9 @@ class ServeEngine:
 
     def _page_shardings(self):
         """(page, scale) NamedShardings over the serve mesh's head
-        axis, or (None, None) single-device."""
+        axis; single-device, the placed replica's chip (None unplaced)."""
         if self.tp_mesh is None:
-            return None, None
+            return self._home, self._home
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
         return (NamedSharding(self.tp_mesh,
@@ -928,8 +959,6 @@ class ServeEngine:
             ca = jitted.lower(*args).compile().cost_analysis()
         except (NotImplementedError, jax.errors.JaxRuntimeError):
             return None
-        if isinstance(ca, (list, tuple)):  # older jax: one per device
-            ca = ca[0] if ca else None
         return dict(ca) if ca else None
 
     # ---------------- pure block math ----------------------------------
@@ -1089,8 +1118,8 @@ class ServeEngine:
                                 preferred_element_type=jnp.float32) * scale
             logits = jnp.where(causal, logits, -jnp.inf)
             # probs STAY f32 through the p.v product — the paged
-            # kernels' convention (_paged_online_page: "p stays f32 and
-            # v upcasts") — so a bf16 engine's reference forward and
+            # kernels' convention (p stays f32 and v upcasts) — so a
+            # bf16 engine's reference forward and
             # its paged path diverge only at f32 epsilon, not at bf16
             # prob-rounding scale (which flips greedy argmaxes). For
             # f32 engines this is bit-identical to rounding probs.
@@ -1178,7 +1207,7 @@ class ServeEngine:
         all-gather). check_vma off: the replicated outputs come out of
         collectives, which the static replication checker cannot always
         see through."""
-        from ..parallel._compat import shard_map
+        from jax import shard_map
         ins, outs = self._tp_step_specs(False)
 
         def body(params, kp, vp, tokens, positions, write_pages,
@@ -1206,7 +1235,7 @@ class ServeEngine:
         quantization is per-head — so each device's quantized rows are
         BIT-identical to the unsharded engine's rows for those heads
         (the execution-path-invariance contract transfers verbatim)."""
-        from ..parallel._compat import shard_map
+        from jax import shard_map
         ins, outs = self._tp_step_specs(True)
 
         def body(params, kp, vp, ks, vs, tokens, positions, write_pages,
@@ -1286,8 +1315,7 @@ class ServeEngine:
                     v.astype(v_pages.dtype))
             o = paged_attention_ragged(
                 q, k_pages[i], v_pages[i], page_tables, lane_slots,
-                lane_lens, scale=scale, use_pallas=self._use_pallas,
-                interpret=self._interpret,
+                lane_lens, scale=scale, **self._attn_kw,
                 k_scales=k_scales[i] if quantized else None,
                 v_scales=v_scales[i] if quantized else None,
                 block_kv=self.attn_block_kv)
@@ -1362,7 +1390,7 @@ class ServeEngine:
         # its args, so no duplicated indexing convention to drift)
         import functools
 
-        from ..parallel._compat import shard_map
+        from jax import shard_map
         arrs, rep = self._handoff_specs(n_pools)
         return shard_map(functools.partial(self._export_impl, n_pools),
                          mesh=self.tp_mesh, in_specs=arrs + (rep,),
@@ -1371,7 +1399,7 @@ class ServeEngine:
     def _import_tp_impl(self, n_pools, *args):
         import functools
 
-        from ..parallel._compat import shard_map
+        from jax import shard_map
         arrs, rep = self._handoff_specs(n_pools)
         return shard_map(functools.partial(self._import_impl, n_pools),
                          mesh=self.tp_mesh,
@@ -1410,7 +1438,7 @@ class ServeEngine:
         n = len(pages)
         rows = self._call_counted(
             "export", self._export_jit, self._n_pools,
-            *self._pool_args(), jnp.asarray(self._pad_idx(pages)))
+            *self._pool_args(), self._h2d(self._pad_idx(pages)))
         # copy the real-page slice: a view would pin the whole
         # pages_per_seq-padded gather buffer for the shipment's life
         host = [np.asarray(r)[:, :n].copy() for r in rows]
@@ -1461,10 +1489,10 @@ class ServeEngine:
                            + src.shape[2:], src.dtype)
             for j, (chain_i, _) in enumerate(todo):
                 buf[:, j] = src[:, chain_i]
-            rows.append(jnp.asarray(buf))
+            rows.append(self._h2d(buf))
         pools = self._call_counted(
             "import", self._import_jit, self._n_pools,
-            *self._pool_args(), *rows, jnp.asarray(idx))
+            *self._pool_args(), *rows, self._h2d(idx))
         self._restash_pools(pools)
         return len(todo)
 
@@ -1478,7 +1506,7 @@ class ServeEngine:
         host-laid-out shipment). Returns compile_counts()."""
         self._device_pages()
         c = self.cache_cfg
-        idx = jnp.zeros((c.pages_per_seq,), jnp.int32)
+        idx = self._h2d(np.zeros((c.pages_per_seq,), np.int32))
         self._call_counted(
             "export", self._export_jit, self._n_pools,
             *self._pool_args(), idx)
@@ -1488,7 +1516,7 @@ class ServeEngine:
         if self.kv_quantized:
             scl = val[:-1]
             shapes += [(scl, np.float32), (scl, np.float32)]
-        zero_rows = [jnp.asarray(np.zeros(s, d)) for s, d in shapes]
+        zero_rows = [self._h2d(np.zeros(s, d)) for s, d in shapes]
         pools = self._call_counted(
             "import", self._import_jit, self._n_pools,
             *self._pool_args(), *zero_rows, idx)
@@ -1525,7 +1553,7 @@ class ServeEngine:
             rows = self._call_counted(
                 "export", self._export_jit, self._n_pools,
                 *self._pool_args(),
-                jnp.asarray(self._pad_idx([p for p, _ in batch])))
+                self._h2d(self._pad_idx([p for p, _ in batch])))
             host = [np.asarray(r) for r in rows]
             for j, (_, key) in enumerate(batch):
                 if store.put(key, [h[:, j] for h in host]):
@@ -1618,10 +1646,10 @@ class ServeEngine:
                            + src0.shape[1:], src0.dtype)
             for j, (chain_i, _) in enumerate(todo):
                 buf[:, j] = fetched[chain_i][pool_i]
-            rows_dev.append(jnp.asarray(buf))
+            rows_dev.append(self._h2d(buf))
         pools = self._call_counted(
             "import", self._import_jit, self._n_pools,
-            *self._pool_args(), *rows_dev, jnp.asarray(idx))
+            *self._pool_args(), *rows_dev, self._h2d(idx))
         self._restash_pools(pools)
         n = len(todo)
         decision.update(chose="reload", reloaded_pages=n)
@@ -1679,8 +1707,7 @@ class ServeEngine:
                 v.astype(v_pages.dtype))
             o = paged_attention_decode(
                 q, k_pages[i], v_pages[i], page_tables, seq_lens,
-                scale=scale, use_pallas=self._use_pallas,
-                interpret=self._interpret)
+                scale=scale, **self._attn_kw)
             x = self._attn_out(p, o, x)
             x = self._ffn(params, i, x)
         logits = self._head(params, x)                    # (B, V)
@@ -1714,11 +1741,17 @@ class ServeEngine:
         which owns every serving dispatch: a count increments exactly
         when the registry AOT-compiles a new argument signature, so
         compiles inside warmup_handoff / adapter load can no longer
-        hide from it (the old monitoring-snapshot counter missed them
-        on a jax without the monitoring module). Executables restored
+        hide from it (the old monitoring-snapshot counter missed
+        them). Executables restored
         from --program-cache-dir count ZERO — a warm boot reports no
         compiles, which is the point."""
         return self.programs.compile_counts()
+
+    def _h2d(self, x):
+        """A host-built step input, placed where this engine runs: on a
+        placed replica straight onto its chip (not staged through chip
+        0); otherwise uncommitted on the default device — jnp.asarray."""
+        return jax.device_put(x, self._home)
 
     def _device_pages(self):
         page_sh, scale_sh = self._page_shardings()
@@ -1755,11 +1788,10 @@ class ServeEngine:
             slabs = {}
             for key, shape in self._adapter_slab_shapes().items():
                 dt = jnp.float32 if key == "scale" else self.act_dtype
-                arr = jnp.zeros(shape, dt)
-                if self._adapter_shardings is not None:
-                    arr = jax.device_put(arr,
-                                         self._adapter_shardings[key])
-                slabs[key] = arr
+                slabs[key] = jnp.zeros(
+                    shape, dt,
+                    device=self._home if self._adapter_shardings is None
+                    else self._adapter_shardings[key])
             self._adapter_slabs = slabs
         return self._adapter_slabs
 
@@ -1800,11 +1832,11 @@ class ServeEngine:
         pending = self.adapters.take_pending()
         for slot, tenant in pending:
             w, sc = self.adapters.host_weights(tenant)
-            rows = {k: jnp.asarray(v) for k, v in w.items()}
-            rows["scale"] = jnp.asarray(np.float32(sc))
+            rows = {k: self._h2d(v) for k, v in w.items()}
+            rows["scale"] = self._h2d(np.float32(sc))
             self._adapter_slabs = self._call_counted(
                 "adapter", self._adapter_load_jit,
-                self._device_adapters(), jnp.int32(slot), rows)
+                self._device_adapters(), self._h2d(np.int32(slot)), rows)
             if self.telemetry.enabled:
                 self.telemetry.instant(
                     self._ENGINE_TRACK, "adapter_load",
@@ -1824,7 +1856,7 @@ class ServeEngine:
         trace cost, numerics untouched)."""
         if self.adapters is not None:
             la = lane_adapters if lane_adapters is not None \
-                else jnp.zeros((self.mixed_width,), jnp.int32)
+                else self._h2d(np.zeros((self.mixed_width,), np.int32))
             args = args + (la, self._device_adapters())
         else:
             args = args + (None, None)
@@ -1854,21 +1886,22 @@ class ServeEngine:
         kp, vp = self._device_pages()
         if self.chunked_prefill:
             t = self.mixed_width
-            z = jnp.zeros((t,), jnp.int32)
-            pts = jnp.zeros((c.max_seqs, c.pages_per_seq), jnp.int32)
+            z = self._h2d(np.zeros((t,), np.int32))
+            pts = self._h2d(
+                np.zeros((c.max_seqs, c.pages_per_seq), np.int32))
             _, _, _, kp, vp = self._dispatch_mixed(
-                kp, vp, z, z, z, z, pts, z, jnp.ones((t,), jnp.int32))
+                kp, vp, z, z, z, z, pts, z, self._h2d(np.ones((t,), np.int32)))
             if self.adapters is not None:
                 # compile the adapter-load scatter on an all-zero row
                 # set aimed at the base slot (zeros into zeros — a
                 # no-op on content), host-built f32 exactly like a
                 # real load (the registered host weights are f32) so
                 # the first tenant miss reuses this program
-                rows = {k: jnp.asarray(np.zeros(s[1:], np.float32))
+                rows = {k: self._h2d(np.zeros(s[1:], np.float32))
                         for k, s in self._adapter_slab_shapes().items()}
                 self._adapter_slabs = self._call_counted(
                     "adapter", self._adapter_load_jit,
-                    self._device_adapters(), jnp.int32(0), rows)
+                    self._device_adapters(), self._h2d(np.int32(0)), rows)
             if self.host_tier is not None:
                 # spill/reload traffic runs the handoff programs —
                 # warm them here or the first eviction under load
@@ -1895,6 +1928,7 @@ class ServeEngine:
         rec = self.programs.boot_record()
         rec["boot_s"] = time.perf_counter() - t0
         rec["warm"] = rec["compiles"] == 0 and rec["restored"] > 0
+        rec["attn_impl"] = self.attn_impl
         self.boot_stats = rec
         if self.programs.cache_dir and self.programs._dirty:
             # read-through write-back: the first (cold) engine over
@@ -2792,6 +2826,9 @@ class ServeEngine:
                                if r.t_finish else None)}
                 for r in reqs],
             "mode": "chunked" if self.chunked_prefill else "legacy",
+            # the paged-attention implementation that ran and where
+            "attn_impl": self.attn_impl,
+            "devices": [int(d.id) for d in self.devices],
             "wall_s": wall,
             "total_new_tokens": total_new,
             "tokens_per_sec": total_new / wall if wall > 0 else 0.0,
@@ -3153,6 +3190,9 @@ class ServeSession:
         self.decode_widths: List[int] = []
         self.prefill_times: List[Tuple[int, float]] = []
         self.util: List[float] = []
+        # steps whose packed lanes returned a non-finite top-k logit
+        # (a NaN anywhere upstream of the head reaches them)
+        self.nonfinite_steps = 0
         self._retries0 = engine._retries
         self._rejected_seen = 0   # flight-recorder rejection trigger
         self._t0 = time.perf_counter()
@@ -3342,16 +3382,18 @@ class ServeSession:
         tp = time.perf_counter()
         greedy, topv, topi, _, _ = eng._dispatch_mixed(
             eng._k_pages, eng._v_pages,
-            jnp.asarray(tokens), jnp.asarray(positions),
-            jnp.asarray(write_pages), jnp.asarray(write_offs),
-            jnp.asarray(cache.page_tables), jnp.asarray(lane_slots),
-            jnp.asarray(lane_lens),
+            eng._h2d(tokens), eng._h2d(positions),
+            eng._h2d(write_pages), eng._h2d(write_offs),
+            eng._h2d(cache.page_tables), eng._h2d(lane_slots),
+            eng._h2d(lane_lens),
             lane_adapters=(None if lane_adapters is None
-                           else jnp.asarray(lane_adapters)))
+                           else eng._h2d(lane_adapters)))
         greedy = np.asarray(greedy)
         topv = np.asarray(topv)
         topi = np.asarray(topi)
         dt = time.perf_counter() - tp
+        if not np.isfinite(topv[:lane]).all():
+            self.nonfinite_steps += 1
         self.util.append(1.0 - cache.free_pages / c.usable_pages)
         if eng.telemetry.enabled:
             eng._record_step_telemetry(
@@ -3394,13 +3436,15 @@ class ServeSession:
         """This session's last_stats-shaped dict so far (generate()
         publishes it as engine.last_stats; a ReplicaPool folds it per
         replica via serve_metrics(..., replica=...))."""
-        return self.eng._build_stats(
+        stats = self.eng._build_stats(
             self.reqs, self.sched,
             wall=time.perf_counter() - self._t0,
             steps=len(self.util), retries0=self._retries0,
             decode_times=self.decode_times,
             decode_widths=self.decode_widths,
             prefill_times=self.prefill_times, util=self.util)
+        stats["nonfinite_logit_steps"] = self.nonfinite_steps
+        return stats
 
     def close(self) -> None:
         """Release the session (idempotent): the engine can open a new

@@ -810,38 +810,35 @@ class PagedKVCache:
         callers passed explicit dtypes). `sharding` (a NamedSharding
         over the serve mesh's head axis) places the pool head-sharded
         for tensor-parallel serving — each device holds its H/t heads
-        of every page. Created once per engine; thereafter they only
+        of every page; a SingleDeviceSharding places a one-chip
+        replica's pool on its chip. Created once per engine; thereafter they only
         flow through jitted steps (donated), never through this
         manager. Quantized pools pair with :meth:`alloc_scale_arrays`."""
-        import jax
         import jax.numpy as jnp
         c = self.cfg
         shape = (c.num_layers, c.num_pages, c.page_size, c.num_heads,
                  c.head_dim)
         dt = dtype or c.storage_dtype
-        k, v = jnp.zeros(shape, dt), jnp.zeros(shape, dt)
-        if sharding is not None:
-            k = jax.device_put(k, sharding)
-            v = jax.device_put(v, sharding)
-        return k, v
+        # allocated IN the sharding: a whole pool zero-filled on the
+        # default chip and then resharded would need the unsharded
+        # bytes there first
+        return (jnp.zeros(shape, dt, device=sharding),
+                jnp.zeros(shape, dt, device=sharding))
 
     def alloc_scale_arrays(self, sharding=None):
         """The (k_scales, v_scales) f32 per-page scale arrays for
         quantized (int8/fp8) pools (cfg.scale_shape). Like the page
         arrays they flow functionally through the jitted steps, donated
         — and shard on the same head axis."""
-        import jax
         import jax.numpy as jnp
         if not self.cfg.quantized:
             raise RuntimeError(
                 f"scale arrays exist only for quantized (int8/fp8) "
                 f"pools (kv_dtype={self.cfg.kv_dtype})")
-        ks = jnp.zeros(self.cfg.scale_shape, jnp.float32)
-        vs = jnp.zeros(self.cfg.scale_shape, jnp.float32)
-        if sharding is not None:
-            ks = jax.device_put(ks, sharding)
-            vs = jax.device_put(vs, sharding)
-        return ks, vs
+        return (jnp.zeros(self.cfg.scale_shape, jnp.float32,
+                          device=sharding),
+                jnp.zeros(self.cfg.scale_shape, jnp.float32,
+                          device=sharding))
 
     def register_scale_meta(self, k_scales, v_scales) -> None:
         """Record the scale-array geometry the engine allocated so

@@ -454,9 +454,14 @@ class ReplicaPool:
 
     # ---------------- replica lifecycle --------------------------------
     def _new_engine(self) -> ServeEngine:
+        """Replica i of tensor degree t runs on chips [i*t, (i+1)*t)
+        (parallel/mesh.replica_devices): its own weights copy, page
+        pool and programs, never stacked on chip 0. On a tpu backend a
+        pool that asks for more chips than there are fails here."""
         role_cfg = dataclasses.replace(self.config, metrics_port=None)
         return ServeEngine(self.model, chunked_prefill=True,
                            telemetry=self.telemetry, config=role_cfg,
+                           replica=len(self.replicas),
                            **self._engine_kwargs)
 
     def _activate_replica(self, t_now: float) -> Replica:
@@ -1389,6 +1394,7 @@ class ReplicaPool:
             "scale_events": list(self.scale_events[events0:]),
             "per_replica": [
                 {"replica": r.idx, "live": r.live,
+                 "devices": [int(d.id) for d in r.engine.devices],
                  "assigned": r.assigned, "steps": r.steps,
                  "tokens": r.tokens,
                  "busy_virtual_s": r.busy_s,
@@ -1697,6 +1703,7 @@ class ReplicaPool:
             "scale_events": list(self.scale_events[events0:]),
             "per_replica": [
                 {"replica": r.idx, "live": r.live,
+                 "devices": [int(d.id) for d in r.engine.devices],
                  "assigned": r.assigned, "steps": r.steps,
                  "tokens": r.tokens,
                  "busy_virtual_s": r.busy_s,

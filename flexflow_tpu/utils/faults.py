@@ -65,7 +65,7 @@ from typing import Dict, List, Optional, Tuple
 
 class TransientError(RuntimeError):
     """A retryable injected failure (the analog of a one-off device /
-    tunnel error). Subsystems with a retry policy (the serve engine's
+    link error). Subsystems with a retry policy (the serve engine's
     dispatch wrapper) absorb these up to their retry budget."""
 
 

@@ -218,3 +218,11 @@ def test_table_sharded_finite_on_combined_mesh():
     l_sharded = float(ff.train_batch(bd)["loss"])
     assert np.isfinite(l_ref)
     np.testing.assert_allclose(l_sharded, l_ref, rtol=1e-4)
+    # the step's UPDATE half too: the sparse row scatter is the op that
+    # mis-partitioned under jax 0.9.0 (as vmap(sparse_update), a batched
+    # scatter over the table-sharded operand — core/executor.py
+    # _apply_update now runs one flat scatter), so the tables after the
+    # step must equal the unsharded ones, not just the loss before it
+    np.testing.assert_allclose(
+        ff.get_weights("cat_tables")["kernel"],
+        ref.get_weights("cat_tables")["kernel"], rtol=1e-5, atol=1e-6)

@@ -182,8 +182,8 @@ def test_train_batches_matches_sequential():
 def test_train_batches_unrolled_matches_scan():
     """config.multi_step_unroll=True (the big-param body that avoids the
     TPU scan carry's double-buffering — DLRM 26x1M tables OOM'd the
-    scanned program on v5e, evidence/tpu_session_20260731T101421Z.log)
-    must be bit-compatible with the scanned body."""
+    scanned program on a v5e) must be bit-compatible with the scanned
+    body."""
     import jax
 
     rng = np.random.RandomState(7)

@@ -110,6 +110,61 @@ def test_restored_executables_count_zero(tmp_path):
     assert b.compile_counts()["fam"] == 1
 
 
+def test_restore_binds_to_the_devices_it_was_compiled_for(tmp_path):
+    """The case the three tests above missed on a multi-device host: a
+    program compiled for ONE device of the eight — and not device 0 —
+    restores onto exactly that device (deserialize_and_load's default
+    binds to all local devices and the first call dies with "expected
+    8 shards"), dispatches there, and counts zero compiles."""
+    dev = jax.devices()[3]
+    fp = {"kind": "test", "device_ids": (dev.id,)}
+    f = jax.jit(lambda x: jnp.cumsum(x) * 3)
+    x = jax.device_put(jnp.arange(6, dtype=jnp.float32), dev)
+    a = ProgramRegistry(fp, cache_dir=str(tmp_path))
+    y = a.call("fam", f, x)
+    assert y.devices() == {dev}
+    assert a.save() == 1
+    b = ProgramRegistry(fp, cache_dir=str(tmp_path))
+    assert b.load_warm() == 1
+    (restored,) = b._compiled.values()
+    assert restored.runtime_executable().local_devices() == [dev]
+    y2 = b.call("fam", f, x)
+    assert y2.devices() == {dev}
+    assert np.array_equal(np.asarray(y), np.asarray(y2))
+    assert sum(b.compile_counts().values()) == 0
+
+
+def test_runtime_rejection_of_a_restored_program_recompiles(tmp_path):
+    """"A bad cache costs a compile and a warning, never a crash" must
+    hold for the RUNTIME's rejection too (JaxRuntimeError — what a
+    wrongly bound executable raises), not only jit's own argument
+    checks."""
+    fp = {"kind": "test", "v": 3}
+    f = jax.jit(lambda x: x * 2)
+    x = jnp.arange(4, dtype=jnp.float32)
+    a = ProgramRegistry(fp, cache_dir=str(tmp_path))
+    a.call("fam", f, x)
+    a.save()
+    b = ProgramRegistry(fp, cache_dir=str(tmp_path))
+    assert b.load_warm() == 1
+    (key,) = b._compiled
+
+    def rejects(*args):
+        raise jax.errors.JaxRuntimeError(
+            "INVALID_ARGUMENT: Expected args to have 8 shards, got [1]")
+
+    b._compiled[key] = rejects
+    with pytest.warns(UserWarning, match="rejected its first call"):
+        y = b.call("fam", f, x)
+    assert np.array_equal(np.asarray(y), np.asarray(x) * 2)
+    assert b.compile_counts()["fam"] == 1 and b.restored_counts()["fam"] == 0
+    # a FRESH compile that raises is not swallowed
+    c = ProgramRegistry(fp)
+    c._compile = lambda name, fn, args: rejects
+    with pytest.raises(jax.errors.JaxRuntimeError):
+        c.call("fam", f, x)
+
+
 def test_corrupt_store_warns_and_boots_cold(tmp_path):
     """cost_cache.py discipline: truncated/garbage stores cost a
     warning and a cold compile, never a crash — and save() afterwards

@@ -17,10 +17,11 @@ Layered like the subsystem:
   * reports — serve_report/train_report render FROM the canonical
     metrics fold, so the string numbers equal the exported snapshot.
   * profiling.trace — configurable log dir, returns the path, and
-    degrades to a warning no-op when jax.profiler is unavailable.
+    raises when jax.profiler will not start.
 """
 
 import json
+import os
 
 import numpy as np
 import pytest
@@ -491,45 +492,45 @@ def test_train_report_renders_from_metrics():
 
 
 # --------------------------------------------------------------- trace()
-def test_profiling_trace_resolves_dir_and_degrades(tmp_path,
-                                                   monkeypatch):
-    from flexflow_tpu.utils import profiling
+def test_profiling_trace_resolves_dir_and_raises(tmp_path, monkeypatch):
+    import jax
 
-    # graceful no-op when jax.profiler refuses (e.g. backend without
-    # trace support): one warning, the context still yields the path
+    from flexflow_tpu.utils import profiling
+    started = []
+    monkeypatch.setattr(jax.profiler, "start_trace", started.append)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    with profiling.trace(str(tmp_path / "t")) as got:
+        assert got == str(tmp_path / "t")
+    # config-resolved dir (the --trace-dir satellite)
+    cfg = FFConfig(trace_dir=str(tmp_path / "cfg_dir"))
+    with profiling.trace(config=cfg) as got:
+        assert got == str(tmp_path / "cfg_dir")
+    # default when nothing is configured: inside the checkout's
+    # ignored .scratch/, never /tmp
+    with profiling.trace() as got:
+        assert got == profiling.DEFAULT_TRACE_DIR
+        assert got.endswith(os.path.join(".scratch", "trace"))
+    assert started == [str(tmp_path / "t"), str(tmp_path / "cfg_dir"),
+                       profiling.DEFAULT_TRACE_DIR]
+
+    # a profiler that will not start is an error, not a no-op: a run
+    # asked to trace must not silently come back without one
     def boom(path):
         raise RuntimeError("no profiler on this backend")
 
-    import jax
     monkeypatch.setattr(jax.profiler, "start_trace", boom)
-    with pytest.warns(UserWarning, match="no-op"):
-        with profiling.trace(str(tmp_path / "t")) as got:
-            assert got == str(tmp_path / "t")
-    # config-resolved dir (the --trace-dir satellite)
-    cfg = FFConfig(trace_dir=str(tmp_path / "cfg_dir"))
-    with pytest.warns(UserWarning):
-        with profiling.trace(config=cfg) as got:
-            assert got == str(tmp_path / "cfg_dir")
-    # default when nothing is configured
-    with pytest.warns(UserWarning):
-        with profiling.trace() as got:
-            assert got == profiling.DEFAULT_TRACE_DIR
+    with pytest.raises(RuntimeError, match="no profiler"):
+        with profiling.trace(str(tmp_path / "t")):
+            pass
 
 
 def test_profiling_trace_real_backend(tmp_path):
     """On the CPU backend jax.profiler works: the trace directory is
     created and the path returned."""
-    import os
-    import warnings as w
-
     from flexflow_tpu.utils import profiling
     d = str(tmp_path / "real")
-    with w.catch_warnings(record=True) as rec:
-        w.simplefilter("always")
-        with profiling.trace(d) as got:
-            assert got == d
-    if any("no-op" in str(r.message) for r in rec):
-        pytest.skip("jax.profiler unavailable in this environment")
+    with profiling.trace(d) as got:
+        assert got == d
     assert os.path.isdir(d)
 
 

@@ -1,8 +1,19 @@
-"""Paged decode + ragged attention, COMPILED on-chip (the CPU suite
-only ever runs the jnp fallback and the interpret-mode kernels;
-Mosaic-compiled behavior is proven here), plus an end-to-end
-ServeEngine generate with the Pallas serving path against the
-CPU-identical jnp fallback tokens.
+"""The serving attention kernel and the engine, COMPILED on-chip.
+
+The CPU suite only ever runs the jnp path and the interpreted kernel;
+here `paged_attention_ragged_v2` goes through Mosaic at head sizes 64
+and 128 for every page format (f32, bf16, int8, fp8) and is compared
+with its jnp twin, and a ServeEngine generates end to end with the
+kernel the engine itself resolved.
+
+Tolerances. The kernel multiplies in f32 and sums on the MXU at HIGHEST
+precision, so its result is f32-accurate for every format. The jnp twin
+is only that accurate when XLA is told so: on a TPU the default
+precision of an f32 dot is one bf16 pass, hence the
+`default_matmul_precision("highest")` around every reference. With
+both sides f32-accurate what is left is summation order (2e-5, the
+pre-v2 bound). A bf16 OUTPUT adds its own rounding: half a unit in the
+8th bit of values of magnitude up to ~4, hence 2e-2.
 """
 
 import numpy as np
@@ -11,81 +22,99 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from flexflow_tpu.kernels.flash_attention import (
-    _paged_decode_jnp,
-    paged_attention_decode,
-    paged_attention_ragged,
-)
+from flexflow_tpu.kernels.paged_ragged_v2 import (PALLAS, _ragged_jnp,
+                                                  paged_attention_ragged_v2,
+                                                  quantize_kv_rows)
+
+PAGE = 16
 
 
-def _ragged(batch, seed, h=8, d=128, page_size=16, pages_per_seq=8):
+def _setup(seed, h, d, page_dtype, q_dtype, batch=4, pages_per_seq=8):
+    """A few sequences of ragged length, several lanes per sequence at
+    ragged positions (the mixed step's shape: chunk tokens + tails)."""
     rng = np.random.RandomState(seed)
     num_pages = 1 + batch * pages_per_seq
-    lens = rng.randint(1, pages_per_seq * page_size + 1, size=batch)
-    kp = rng.randn(num_pages, page_size, h, d).astype(np.float32)
-    vp = rng.randn(num_pages, page_size, h, d).astype(np.float32)
+    lens = rng.randint(1, pages_per_seq * PAGE + 1, size=batch)
+    kp = rng.randn(num_pages, PAGE, h, d).astype(np.float32)
+    vp = rng.randn(num_pages, PAGE, h, d).astype(np.float32)
     table = np.zeros((batch, pages_per_seq), np.int32)
     pool = list(rng.permutation(np.arange(1, num_pages)))
-    for b, L in enumerate(lens):
-        for i in range(-(-int(L) // page_size)):
-            table[b, i] = int(pool.pop())
-    q = rng.randn(batch, h, d).astype(np.float32)
-    return (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-            jnp.asarray(table), jnp.asarray(lens.astype(np.int32)))
-
-
-@pytest.mark.parametrize("batch", [1, 4, 8])
-def test_paged_decode_mosaic_matches_jnp(batch):
-    q, kp, vp, table, lens = _ragged(batch, batch)
-    ref = _paged_decode_jnp(q, kp, vp, table, lens, scale=q.shape[-1] ** -0.5)
-    out = jax.jit(lambda *a: paged_attention_decode(
-        *a, use_pallas=True))(q, kp, vp, table, lens)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-
-
-@pytest.mark.parametrize("batch", [1, 4])
-def test_paged_ragged_mosaic_matches_jnp(batch):
-    """The mixed-step kernel (chunked prefill): several lanes per
-    sequence at ragged positions, slot indirection in SMEM."""
-    rng = np.random.RandomState(77 + batch)
-    q1, kp, vp, table, lens = _ragged(batch, 7 + batch)
-    h, d = q1.shape[1], q1.shape[2]
     slots, poss = [], []
-    for s, L in enumerate(np.asarray(lens)):
+    for s, L in enumerate(lens):
+        for i in range(-(-int(L) // PAGE)):
+            table[s, i] = int(pool.pop())
         for p in sorted({int(L) - 1,
-                         *(int(x) for x in rng.randint(0, int(L), 3))}):
+                         *(int(x) for x in rng.randint(0, int(L), 5))}):
             slots.append(s)
             poss.append(p)
-    slots = jnp.asarray(np.asarray(slots, np.int32))
-    lane_lens = jnp.asarray(np.asarray(poss, np.int32) + 1)
-    q = jnp.asarray(rng.randn(len(poss), h, d).astype(np.float32))
-    ref = paged_attention_ragged(q, kp, vp, table, slots, lane_lens,
-                                 use_pallas=False)
-    out = jax.jit(lambda *a: paged_attention_ragged(
-        *a, use_pallas=True))(q, kp, vp, table, slots, lane_lens)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
+    q = jnp.asarray(rng.randn(len(poss), h, d), q_dtype)
+    kp, vp = jnp.asarray(kp), jnp.asarray(vp)
+    scales = {}
+    if jnp.dtype(page_dtype).itemsize == 1:
+        kp, ks = quantize_kv_rows(kp, page_dtype)
+        vp, vs = quantize_kv_rows(vp, page_dtype)
+        scales = {"k_scales": ks, "v_scales": vs}
+    else:
+        kp, vp = kp.astype(page_dtype), vp.astype(page_dtype)
+    return (q, kp, vp, jnp.asarray(table),
+            jnp.asarray(np.asarray(slots, np.int32)),
+            jnp.asarray(np.asarray(poss, np.int32) + 1)), scales
 
 
-def test_engine_pallas_decode_matches_jnp_tokens():
+@pytest.mark.parametrize("h,d", [(32, 64), (16, 128)])
+@pytest.mark.parametrize("page_dtype,q_dtype,tol", [
+    (jnp.float32, jnp.float32, 2e-5),
+    (jnp.bfloat16, jnp.bfloat16, 2e-2),
+    (jnp.int8, jnp.float32, 2e-5),
+    (jnp.float8_e4m3fn, jnp.float32, 2e-5),
+])
+@pytest.mark.parametrize("block_kv", [None, 4 * PAGE])
+def test_ragged_v2_mosaic_matches_jnp(h, d, page_dtype, q_dtype, tol,
+                                      block_kv):
+    args, scales = _setup(h + d, h, d, page_dtype, q_dtype)
+    out = jax.jit(lambda *a: paged_attention_ragged_v2(
+        *a, use_pallas=True, block_kv=block_kv, **scales))(*args)
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(lambda *a: _ragged_jnp(
+            *a, d ** -0.5, **scales))(*args)
+    assert out.dtype == ref.dtype == jnp.dtype(q_dtype)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("kv_dtype", ["float32", "bfloat16", "int8",
+                                      "float8_e4m3"])
+def test_engine_end_to_end_on_the_compiled_kernel(kv_dtype):
+    """generate() through the Mosaic kernel: every request finishes in
+    range, pool invariants hold, nothing compiles after warm-up, the
+    engine REPORTS the compiled kernel — and, with every matmul at full
+    f32 precision so that only summation order separates the two
+    engines, the greedy tokens equal the jnp engine's on exact (f32)
+    pages."""
     from flexflow_tpu.config import FFConfig
     from flexflow_tpu.models.transformer import build_transformer_lm
     from flexflow_tpu.serve import ServeEngine
 
     cfg = FFConfig(batch_size=1, kv_page_size=16, kv_num_pages=65,
-                   serve_max_seqs=4, serve_prefill_budget=64)
+                   serve_max_seqs=4, serve_prefill_budget=64,
+                   kv_dtype=kv_dtype)
     ff = build_transformer_lm(cfg, vocab_size=128, max_seq_len=128,
-                              hidden=128, num_heads=8, num_layers=2,
-                              ff_dim=256)
+                              hidden=256, num_heads=4, num_layers=2,
+                              ff_dim=512)
     rng = np.random.RandomState(0)
-    prompts = [list(rng.randint(1, 128, size=rng.randint(2, 40)))
-               for _ in range(6)]
-    eng_pl = ServeEngine(ff, use_pallas=True)
-    eng_pl.warmup()
-    out_pl = eng_pl.generate(prompts, 8)
-    eng_jnp = ServeEngine(ff, use_pallas=False)
-    out_jnp = eng_jnp.generate(prompts, 8)
-    # greedy argmax over well-separated logits: kernel-order float
-    # differences must not flip any token
-    assert out_pl == out_jnp
+    prompts = [list(rng.randint(1, 128, size=n))
+               for n in (3, 17, 40, 90, 17, 5)]   # 90 > budget: chunks
+    with jax.default_matmul_precision("highest"):
+        eng = ServeEngine(ff)                     # auto: Pallas on tpu
+        assert eng.attn_impl == PALLAS
+        warm = dict(eng.warmup())
+        out = eng.generate(prompts, 8)
+        assert eng.compile_counts() == warm
+        assert eng.last_stats["attn_impl"] == PALLAS
+        eng.cache.check_invariants()
+        assert all(len(o) == 8 and all(0 <= t < 128 for t in o)
+                   for o in out)
+        if kv_dtype == "float32":
+            ref = ServeEngine(ff, use_pallas=False).generate(prompts, 8)
+            assert out == ref

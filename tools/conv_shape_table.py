@@ -15,8 +15,8 @@ are where Inception's MFU lives, and a row whose achieved fraction is
 far below the calibrated conv efficiency is a specific shape worth a
 layout/padding fix or a Pallas kernel.
 
-Writes evidence/conv_shape_table_<platform>.json. On-chip run = step
-4 of tools/tpu_session.sh (CONV_TABLE_PLATFORM=tpu).
+Writes evidence/conv_shape_table_<platform>.json. On the chip:
+CONV_TABLE_PLATFORM=tpu, one process per chip call.
 """
 
 import json
@@ -80,7 +80,7 @@ def main():
            "conv_efficiency_factor": mm.efficiency.get("conv"),
            "models": {}}
     import bench  # the SAME configs the bench measures — no drift
-    # (honors BENCH_BATCH / BENCH_CONV_LAYOUT session knobs too)
+    # (honors the BENCH_BATCH / BENCH_CONV_LAYOUT knobs too)
     for name in ("inception", "alexnet"):
         model, _data = bench.build(name, "full")
         rows = conv_rows(model, mm, repeats)

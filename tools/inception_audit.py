@@ -12,8 +12,8 @@ Prints, for the bench config (299px, bf16):
      analog with per-shape algorithm selection);
   4. measured ms/step per layout when the backend is usable.
 
-Run on TPU (tools/tpu_session.sh step 3 does the timed A/B); on CPU it
-still prints 1-3 with a small image size.
+Run on a TPU for the timed A/B; on CPU it still prints 1-3 with a
+small image size.
 """
 
 import json
@@ -25,8 +25,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 def main():
     import jax
-    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
 
     from flexflow_tpu.utils import profiling
 
@@ -69,8 +67,7 @@ def main():
 
     # ---- 2 + 4. per-layout compiled cost + measured time ----
     # (CPU: one layout only — a second full inception compile takes
-    # minutes and the layout knob is a TPU question; the timed A/B runs
-    # in tools/tpu_session.sh step 3)
+    # minutes and the layout knob is a TPU question)
     results = {}
     for layout in (("NCHW",) if on_cpu else ("NCHW", "NHWC")):
         ffl = ff if layout == "NCHW" else build(layout)[0]

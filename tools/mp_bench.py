@@ -229,6 +229,8 @@ def wallclock(steps=20):
 def main():
     import jax
 
+    from flexflow_tpu.utils.cache_dirs import arm_compile_cache
+    arm_compile_cache()
     smoke = "--smoke" in sys.argv
     out_path = None
     if "-o" in sys.argv:

@@ -108,6 +108,8 @@ def main():
     import jax
 
     from flexflow_tpu import make_mesh
+    from flexflow_tpu.utils.cache_dirs import arm_compile_cache
+    arm_compile_cache()
     from flexflow_tpu.search.cost_cache import machine_fingerprint
     from flexflow_tpu.search.simulator import Simulator
     from flexflow_tpu.utils.profiling import search_report

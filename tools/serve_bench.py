@@ -226,9 +226,10 @@ def main() -> int:
             os.environ["XLA_FLAGS"] = (
                 flag + " " + os.environ.get("XLA_FLAGS", ""))
     import jax
-    if args.cpu or args.smoke:
-        jax.config.update("jax_platforms", "cpu")
     import numpy as np
+
+    from flexflow_tpu.utils.cache_dirs import arm_compile_cache
+    arm_compile_cache()
 
     from flexflow_tpu.config import FFConfig
     from flexflow_tpu.models.transformer import build_transformer_lm
@@ -2022,13 +2023,17 @@ def main() -> int:
         # an AOT executable snapshot for its fingerprint must reach
         # first-token-ready >= 2x faster than a cold one, compile
         # NOTHING (compile_counts() all zero, the warm-boot contract),
-        # and produce token-identical greedy output. The cold arm runs
-        # FIRST so nothing (the registry's jax persistent-cache arming
-        # included) can warm XLA under it.
+        # and produce token-identical greedy output. JAX's persistent
+        # compile cache is off for this workload: a "cold" arm that
+        # hits it measures a disk read, not a compile. The snapshot
+        # dir is deliberately fresh (a temporary name is right for the
+        # *.ffprog store of an A/B, which no later run should find).
         import glob
         import shutil
         import tempfile
         import warnings as _warnings
+
+        jax.config.update("jax_enable_compilation_cache", False)
 
         boot_prompts = [list(rng.randint(1, args.vocab, size=12))
                         for _ in range(4)]
@@ -2097,6 +2102,7 @@ def main() -> int:
             eng_warm.close()
         finally:
             shutil.rmtree(boot_dir, ignore_errors=True)
+            jax.config.update("jax_enable_compilation_cache", True)
         eng_cold.close()
 
         gates.append(f"boot_warm={speedup:.1f}x>=2x "

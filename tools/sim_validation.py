@@ -14,8 +14,8 @@ does not describe the executing hardware, so ANALYTIC error is
 expected to be large — what this table demonstrates on CPU is that
 per-op MEASURED grounding collapses the error (the mechanism VERDICT
 asks for: grounding beats family factors wherever family factors are
-wrong). The TPU leg (tools/tpu_session.sh step 3) produces the on-chip table
-against BASELINE.md's <30% envelope.
+wrong). The TPU leg (SIM_VALIDATION_PLATFORM=tpu) produces the on-chip
+table against BASELINE.md's <30% envelope.
 
 Run: python tools/sim_validation.py [--quick]
 """
@@ -124,7 +124,7 @@ def main():
            "rows": rows,
            "note": ("CPU: analytic TPU-roofline error is expected; the "
                     "table demonstrates measured grounding collapsing "
-                    "it. TPU leg via tools/tpu_session.sh.")}
+                    "it. TPU leg: SIM_VALIDATION_PLATFORM=tpu.")}
     suffix = ""
     if quick:
         # a quick run covers four of the five families — it must not
