@@ -9,9 +9,11 @@ repo's own means. It never sets `jax_platforms`, never retries and never
 falls back: without a TPU whose `device_kind` the repo holds peaks for
 it exits non-zero before building a model and prints no result line.
 One line per phase (`PASS`/`FAIL`, wall seconds split into compile and
-steady state); the exit code is the conjunction; the last line of
-stdout is one JSON object with the device, the phases and the verdict.
-These are observations for CHANGES.md, not benchmark metrics.
+steady state), then a `details: {...}` line of JSON with every phase's
+figures; the exit code is the conjunction; the last line of stdout is
+exactly `{"ok": bool, "device": {"platform", "kind", "count"}}`, the
+device as JAX reports it, and carries no other key (the driver checks
+it). These are observations for CHANGES.md, not benchmark metrics.
 
 Model — the repo's own decoder LM, `models/transformer.
 build_transformer_lm` (learned positions, pre-LN, ReLU feed-forward,
@@ -488,12 +490,13 @@ def run(widths: Widths = OPT_1_3B, *, interpret: bool = False,
             os.makedirs(os.path.dirname(last_path), exist_ok=True)
             with open(last_path, "w") as f:
                 json.dump(total, f)
-        print(json.dumps({"ok": ok, "device": device, "phases": phases,
-                          "total": total,
-                          "previous_compile_s": (previous or {}).get(
-                              "compile_s"),
-                          "cache_dir": cache_dir,
-                          "cache_was_empty": was_empty}), flush=True)
+        say("details: " + json.dumps(
+            {"phases": phases, "total": total,
+             "previous_compile_s": (previous or {}).get("compile_s"),
+             "cache_dir": cache_dir, "cache_was_empty": was_empty}))
+        # the driver's contract: the last line of stdout is exactly this
+        # object — "ok" and "device" (platform, kind, count), no other key
+        say(json.dumps({"ok": ok, "device": device}))
         return 0 if ok else 1
 
     def serve(tp):

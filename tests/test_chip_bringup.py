@@ -327,10 +327,12 @@ def test_smoke_logic_at_a_tiny_width(capsys, monkeypatch, tmp_path,
                         train_batch=1, new_tokens=3)
     rc = smoke.run(tiny, interpret=True, require_tpu=False, max_devices=1)
     lines = capsys.readouterr().out.strip().splitlines()
-    result = json.loads(lines[-1])
-    assert rc == 0 and result["ok"] is True, lines
-    assert result["device"] == {"platform": "cpu", "kind": "cpu",
-                                "count": 1}
+    # the last line is the driver's contract: these keys and no other
+    assert rc == 0 and json.loads(lines[-1]) == {
+        "ok": True,
+        "device": {"platform": "cpu", "kind": "cpu", "count": 1}}, lines
+    assert lines[-2].startswith("details: ")
+    result = json.loads(lines[-2][len("details: "):])
     assert list(result["phases"]) == ["kernel", "train", "serve"]
     assert all(p["ok"] for p in result["phases"].values())
     serve = result["phases"]["serve"]
@@ -350,5 +352,7 @@ def test_smoke_logic_at_a_tiny_width(capsys, monkeypatch, tmp_path,
     out = capsys.readouterr().out.strip().splitlines()
     bad = json.loads(out[-1])
     assert rc == 1 and bad["ok"] is False
-    assert list(bad["phases"]) == ["kernel"]
-    assert "phase kernel: FAIL" in out[-4]
+    assert sorted(bad) == ["device", "ok"]
+    assert list(json.loads(out[-2][len("details: "):])["phases"]) \
+        == ["kernel"]
+    assert "phase kernel: FAIL" in out[-5]
