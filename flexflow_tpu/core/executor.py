@@ -410,6 +410,10 @@ class Executor:
             # with functional state (BN) or aux losses (MoE) are excluded —
             # their ctx side-channel values must not escape the
             # checkpointed trace (tracer leak otherwise).
+            # every branch runs under the op's name as a named scope
+            # (metadata only): a profiler trace then names each device
+            # operation's op, forward `jvp(<op>)` and backward
+            # `transpose(jvp(<op>))` alike (docs/observability.md)
             if op.name in merged_pending:
                 ys = [merged_pending.pop(op.name)]
             elif op.name in self._conv_merge_leader:
@@ -419,25 +423,28 @@ class Executor:
                 # group members share the leader's input and geometry,
                 # so the leader's residency flags speak for the group
                 nin, nout = ctx.nhwc_in, ctx.nhwc_out
-                if self.config.remat:
-                    outs = jax.checkpoint(
-                        lambda ps, x, _g=group, _i=nin, _o=nout:
-                        merged_conv_forward(_g, ps, x, _i, _o))(
-                            plist, xs[0])
-                else:
-                    outs = merged_conv_forward(group, plist, xs[0],
-                                               nin, nout)
+                with jax.named_scope(op.name):
+                    if self.config.remat:
+                        outs = jax.checkpoint(
+                            lambda ps, x, _g=group, _i=nin, _o=nout:
+                            merged_conv_forward(_g, ps, x, _i, _o))(
+                                plist, xs[0])
+                    else:
+                        outs = merged_conv_forward(group, plist, xs[0],
+                                                   nin, nout)
                 for m, y in zip(group[1:], outs[1:]):
                     merged_pending[m.name] = y
                 ys = [outs[0]]
             elif (self.config.remat and op.weight_specs()
                     and not op.state_specs()
                     and not getattr(op, "has_aux_loss", False)):
-                ys = jax.checkpoint(
-                    lambda p, x, _op=op, _ctx=ctx: _op.forward(p, x, _ctx)
-                )(op_params, xs)
+                with jax.named_scope(op.name):
+                    ys = jax.checkpoint(
+                        lambda p, x, _op=op, _ctx=ctx:
+                        _op.forward(p, x, _ctx))(op_params, xs)
             else:
-                ys = op.forward(op_params, xs, ctx)
+                with jax.named_scope(op.name):
+                    ys = op.forward(op_params, xs, ctx)
             if self.mesh is not None and (
                     self._sharding_boundary is None
                     or op.name in self._sharding_boundary):
@@ -531,11 +538,12 @@ class Executor:
             # policy-exempt region (precision.py): a bf16 NLL would
             # round away exactly the signal the parity gate measures
             logits = logits.astype(jnp.float32)
-        loss = jnp.asarray(0.0, jnp.float32)
-        if self.loss_fn is not None and "label" in batch:
-            loss = self.loss_fn(logits, batch["label"])
-        for aux in self._last_aux_losses:
-            loss = loss + aux
+        with jax.named_scope("loss"):
+            loss = jnp.asarray(0.0, jnp.float32)
+            if self.loss_fn is not None and "label" in batch:
+                loss = self.loss_fn(logits, batch["label"])
+            for aux in self._last_aux_losses:
+                loss = loss + aux
         return loss, (logits, new_states)
 
     # ---------------- sparse-table routing ----------------
@@ -629,6 +637,7 @@ class Executor:
             diff_params, states, batch, True, rng, seq_length)
         return loss, logits, new_states, grads, sparse_idx
 
+    @jax.named_scope("optimizer")
     def _apply_update(self, state: TrainState, grads, sparse_idx,
                       new_states, lr_scale=1.0) -> TrainState:
         """Apply the optimizer to dense grads + scatter-apply sparse row

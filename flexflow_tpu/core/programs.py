@@ -54,7 +54,11 @@ from typing import Any, Dict, Optional
 
 import jax
 
-_STORE_VERSION = 2   # 2: entries record the devices they execute on
+# 2: entries record the devices they execute on
+# 3: programs carry named scopes in their operations' metadata — a store
+#    written before that would restore executables a profiler trace
+#    cannot attribute (the fingerprint folds no metadata)
+_STORE_VERSION = 3
 _STORE_SUFFIX = ".ffprog"
 
 

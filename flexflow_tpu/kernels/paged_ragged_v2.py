@@ -67,6 +67,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
@@ -418,6 +419,45 @@ def _ragged_v2_pallas(q, k_pages, v_pages, page_tables, lane_slots,
         name="paged_ragged_v2",
     )(page_tables, lane_slots, lane_lens, *args)
     return out.reshape(t, h, d)
+
+
+def kv_read_bytes(lane_lens, lane_slots, page_tables, *, page_size: int,
+                  num_heads: int, head_dim: int, kv_itemsize: int,
+                  block_kv_pages: int = 1, quantized: bool = False) -> int:
+    """Bytes of K and V pages (and their scale rows on a quantized
+    pool) that ONE call of the kernel above fetches from HBM, for the
+    whole step's lanes (numpy; host side, no device work).
+
+    It mirrors `page_index`: page slot i of work item (lane t, block
+    blk) selects table column min(blk * bp + i, pp - 1, (len_t - 1) //
+    ps) of the lane's row, and Pallas's pipeline fetches a block only
+    when its index differs from the previous grid step's. So a lane
+    fetches each of its live columns once per slot — lanes of one chunk
+    each re-read their sequence's pages — dead tail items fetch
+    nothing, and a lane whose first page is the one the lane before it
+    ended on (the run of inactive lanes on the sink page) fetches
+    nothing for it either. Heads are counted whole: a tensor-parallel
+    step fetches the same bytes summed over its chips."""
+    ll = np.asarray(lane_lens, np.int64)
+    rows = np.asarray(page_tables)[np.asarray(lane_slots, np.int64)]
+    pp = rows.shape[1]
+    bp = max(1, min(int(block_kv_pages), pp))
+    nb = -(-pp // bp)
+    last = np.minimum(np.maximum((ll - 1) // page_size, 0), pp - 1)
+    lanes = np.arange(len(ll))
+    fetches = 0
+    for i in range(bp):
+        # columns blk * bp + i below the clamp are all distinct; the
+        # clamped ones (if any) repeat one column, `last`
+        below = np.clip(-(-(last - i) // bp), 0, nb)
+        fetches += int(np.sum(below + (below < nb)))
+        first = rows[lanes, np.minimum(i, last)]
+        end = rows[lanes, np.minimum((nb - 1) * bp + i, last)]
+        fetches -= int(np.sum(first[1:] == end[:-1]))
+    per_page = 2 * page_size * num_heads * head_dim * kv_itemsize
+    if quantized:
+        per_page += 2 * page_size * num_heads * 4       # f32 scale rows
+    return fetches * per_page
 
 
 # ------------------------------------------------------------ entry point

@@ -610,10 +610,22 @@ class FFModel:
         return self.executor.compile_counts()
 
     def train_batch(self, batch: Dict[str, np.ndarray]):
-        """One optimizer step; returns metrics dict of scalars."""
-        batch = self.executor.shard_batch(batch)
-        self.state, metrics = self.executor.train_step(
-            self.state, batch, self._train_rng())
+        """One optimizer step; returns metrics dict of scalars. The
+        host's part is one phase span, `train_step`, over `shard_batch`,
+        `rng` and `dispatch` (Telemetry.timed: docs/observability.md
+        "Phase spans")."""
+        from .utils.telemetry import telemetry_for
+        tel = self.telemetry if self.telemetry is not None \
+            else telemetry_for()
+        timed, track = tel.timed, ("train", "dispatch")
+        with timed(track, "train_step"):
+            with timed(track, "shard_batch"):
+                batch = self.executor.shard_batch(batch)
+            with timed(track, "rng"):
+                rng = self._train_rng()
+            with timed(track, "dispatch"):
+                self.state, metrics = self.executor.train_step(
+                    self.state, batch, rng)
         return metrics
 
     def train_batches(self, batches: Sequence[Dict[str, np.ndarray]]):

@@ -62,7 +62,7 @@ from ..config import CompMode
 from ..kernels.flash_attention import (paged_attention_decode,
                                        paged_attention_ragged)
 from ..kernels.paged_ragged_v2 import (JNP, PALLAS_INTERPRET,
-                                       choose_block_kv,
+                                       choose_block_kv, kv_read_bytes,
                                        quantize_kv_rows,
                                        ragged_dispatch_passes,
                                        resolve_paged_impl)
@@ -1254,6 +1254,7 @@ class ServeEngine:
             positions, write_pages, write_offs, page_tables, lane_slots,
             lane_lens, lane_adapters, adapters)
 
+    @jax.named_scope("serve_step")
     def _mixed_body(self, params, k_pages, v_pages, k_scales, v_scales,
                     tokens, positions, write_pages, write_offs,
                     page_tables, lane_slots, lane_lens,
@@ -1275,9 +1276,17 @@ class ServeEngine:
         row-parallel projections, and the head all-gathers its vocab
         shards. Exactly one program geometry either way."""
         quantized = k_scales is not None
-        x = (self._embed_tp(params, tokens, positions, tp_axis)
-             if tp_axis else
-             self._embed(params, tokens, positions))     # (T, E)
+        # named scopes (docs/observability.md "Device scopes"): metadata
+        # only — under the root `serve_step` they name each device
+        # operation's phase and layer in a profiler trace and change no
+        # fusion. The f32 masters are cast to the activation dtype
+        # where each weight is used, so every cast falls in the scope
+        # of the phase that does it.
+        scope = jax.named_scope
+        with scope("embed"):
+            x = (self._embed_tp(params, tokens, positions, tp_axis)
+                 if tp_axis else
+                 self._embed(params, tokens, positions))     # (T, E)
         scale = 1.0 / np.sqrt(self.head_dim)
         # multi-tenant adapters (serve/adapters.py): ONE gather pulls
         # each lane's whole (A, B) stack — slab (S, L, ...) rows by
@@ -1287,18 +1296,49 @@ class ServeEngine:
         # each device's local slab shard (replicated lane indices).
         ad = ad_s = None
         if adapters is not None:
-            ad = {key: jnp.take(arr, lane_adapters, axis=0)
-                  for key, arr in adapters.items() if key != "scale"}
-            ad_s = jnp.take(adapters["scale"], lane_adapters, axis=0)
+            with scope("adapters"):
+                ad = {key: jnp.take(arr, lane_adapters, axis=0)
+                      for key, arr in adapters.items() if key != "scale"}
+                ad_s = jnp.take(adapters["scale"], lane_adapters, axis=0)
         for i in range(self.num_layers):
-            p = params[f"layer{i}_attn"]
+            with scope(f"layer{i}"):
+                x, k_pages, v_pages, k_scales, v_scales = \
+                    self._mixed_layer(
+                        params, i, x, k_pages, v_pages, k_scales,
+                        v_scales, write_pages, write_offs, page_tables,
+                        lane_slots, lane_lens, scale,
+                        None if ad is None else
+                        {key: arr[:, i] for key, arr in ad.items()},
+                        ad_s, tp_axis)
+        with scope("head"):
+            logits = (self._head_tp(params, x, tp_axis) if tp_axis
+                      else self._head(params, x))            # (T, V[pad])
+        with scope("sample"):
+            topv, topi = jax.lax.top_k(logits, self.topk_cap)
+            out = (jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                   topv.astype(jnp.float32), topi.astype(jnp.int32))
+        caches = (k_pages, v_pages, k_scales, v_scales) if quantized \
+            else (k_pages, v_pages)
+        return out, caches
+
+    def _mixed_layer(self, params, i, x, k_pages, v_pages, k_scales,
+                     v_scales, write_pages, write_offs, page_tables,
+                     lane_slots, lane_lens, scale, la, ad_s, tp_axis):
+        """Layer `i` of the mixed step, one named scope per phase:
+        `ln`, `qkv`, `kv_write` (quantize and scatter into the pools),
+        `attn` (the ragged paged kernel), `attn_out`, `ffn`. `la` is
+        the lanes' adapter rows of this layer (None: no adapters)."""
+        scope = jax.named_scope
+        quantized = k_scales is not None
+        p = params[f"layer{i}_attn"]
+        with scope("ln"):
             h = _ln(params[f"layer{i}_ln1"], x, self.ln_eps) \
                 if self.layer_norm else x
-            la = None if ad is None else {
-                key: arr[:, i] for key, arr in ad.items()}
+        with scope("qkv"):
             q, k, v = self._attn_qkv(
                 p, h, lora=None if la is None else
                 (la["a_qkv"], la["b_qkv"], ad_s))         # (T, H[/t], D)
+        with scope("kv_write"):
             if quantized:
                 kq, ksc = quantize_kv_rows(k, self._kv_store_dtype)
                 vq, vsc = quantize_kv_rows(v, self._kv_store_dtype)
@@ -1313,29 +1353,25 @@ class ServeEngine:
                     k.astype(k_pages.dtype))
                 v_pages = v_pages.at[i, write_pages, write_offs].set(
                     v.astype(v_pages.dtype))
+        with scope("attn"):
             o = paged_attention_ragged(
                 q, k_pages[i], v_pages[i], page_tables, lane_slots,
                 lane_lens, scale=scale, **self._attn_kw,
                 k_scales=k_scales[i] if quantized else None,
                 v_scales=v_scales[i] if quantized else None,
                 block_kv=self.attn_block_kv)
+        with scope("attn_out"):
             x = self._attn_out(
                 p, o, x, psum_axis=tp_axis,
                 lora=None if la is None else
                 (la["a_wo"], la["b_wo"], ad_s))
+        with scope("ffn"):
             x = self._ffn(
                 params, i, x, psum_axis=tp_axis,
                 lora=None if la is None else
                 (la["a_ff1"], la["b_ff1"], la["a_ff2"], la["b_ff2"],
                  ad_s))
-        logits = (self._head_tp(params, x, tp_axis) if tp_axis
-                  else self._head(params, x))            # (T, V[pad])
-        topv, topi = jax.lax.top_k(logits, self.topk_cap)
-        out = (jnp.argmax(logits, axis=-1).astype(jnp.int32),
-               topv.astype(jnp.float32), topi.astype(jnp.int32))
-        caches = (k_pages, v_pages, k_scales, v_scales) if quantized \
-            else (k_pages, v_pages)
-        return out, caches
+        return x, k_pages, v_pages, k_scales, v_scales
 
     # ---------------- disaggregated page handoff -----------------------
     # Device half of the prefill->decode transfer (serve/disagg.py;
@@ -2286,8 +2322,9 @@ class ServeEngine:
                 evs.append(("e", self._QUEUE_TRACK, "requeue_wait",
                             now, 0.0, ident, None))
                 req._t_requeue = None
-            elif not req.t_admit:
-                req.t_admit = now
+            elif not req.preemptions:
+                # first admission: the wait ended where the scheduler
+                # stamped it, before this step packed a lane
                 evs.append(("b", self._QUEUE_TRACK, "queue_wait",
                             req.t_submit, 0.0, req.rid,
                             {"rid": req.rid, "trace": req.trace_id,
@@ -3116,13 +3153,15 @@ class StepEvents:
     this step)] (speculation can emit several per step), ``finished``
     the requests that completed THIS step, ``ctx_mean`` the mean
     decode-context length (the drift calibrator's pricing regime),
-    ``dispatched`` False for a planning-only iteration (rung-4
+    ``kv_bytes_read`` the K/V page bytes the step's attention kernel
+    calls fetch, ``dispatched`` False for a planning-only iteration (rung-4
     rejections / whole-set preemption under injected pressure — the
     scheduler's forced-progress rule guarantees re-planning
     converges)."""
 
     __slots__ = ("dispatched", "step_index", "plan", "emitted",
-                 "finished", "ctx_mean", "wall_s", "host_reload_s")
+                 "finished", "ctx_mean", "wall_s", "host_reload_s",
+                 "kv_bytes_read")
 
     def __init__(self, plan=None):
         self.dispatched = False
@@ -3136,6 +3175,9 @@ class StepEvents:
         # (the router adds it to the virtual clock; wall mode measures
         # it inside the step wall time naturally)
         self.host_reload_s = 0.0
+        # K/V page (and scale) bytes the paged kernel fetches in this
+        # step, all layers (kernels/paged_ragged_v2.kv_read_bytes)
+        self.kv_bytes_read = 0
 
 
 class ServeSession:
@@ -3298,38 +3340,16 @@ class ServeSession:
         return emitted
 
     # ---------------- the step -----------------------------------------
-    def step(self) -> Optional[StepEvents]:
-        """Advance one engine step. Returns None when the session is
-        drained (no waiting or running requests survive the abort
-        sweep), else a StepEvents."""
+    def _pack(self, plan):
+        """The plan's chunks as the mixed program's host-built lane
+        arrays (mixed_width wide; inactive lanes aim at the sink page
+        with a visible length of 1). -> (arrays in dispatch order,
+        lane_adapters or None, live lanes, emitters, spec_emitters,
+        the K/V bytes the paged kernel fetches for these lanes)."""
         eng = self.eng
-        sched = self.sched
         cache = eng.cache
-        c = eng.cache_cfg
-        # chunk boundary: cancels and expired deadlines leave the
-        # system HERE, before any of this step's chunks exist
-        eng._sweep_aborts(sched)
-        if not sched.has_work():
-            return None
-        plan = sched.schedule()
-        ev = StepEvents(plan)
-        # claim the priced host-tier DMA this plan's admissions spent
-        # (carried even on planning-only iterations)
-        ev.host_reload_s, eng._host_reload_s = eng._host_reload_s, 0.0
-        if sched.stats["rejected"] > self._rejected_seen:
-            # rung-4 structured rejection: the ladder refused service —
-            # exactly the state an operator wants black-boxed (one
-            # bundle per rate-limit window, not one per rejection)
-            self._rejected_seen = sched.stats["rejected"]
-            eng._auto_postmortem("rejection", sched=sched)
-        if not plan.chunks:
-            # every waiting request was rejected (rung 4) or the
-            # running set was preempted whole under injected pressure;
-            # the next step() re-plans (forced progress guarantees
-            # this cannot spin)
-            return ev
         t_w = eng.mixed_width
-        ps = c.page_size
+        ps = eng.cache_cfg.page_size
         tokens = np.zeros((t_w,), np.int32)
         positions = np.zeros((t_w,), np.int32)
         write_pages = np.zeros((t_w,), np.int32)   # sink by default
@@ -3373,47 +3393,113 @@ class ServeSession:
                 emitters.append((ch, lane - 1))
         assert lane <= t_w, (
             f"scheduler packed {lane} lanes into a {t_w}-lane step")
-        # land any adapters this plan admitted BEFORE their lanes
-        # dispatch — the planning-visible load stall, not a recompile
-        eng._drain_adapter_loads()
-        # ship queued evictions to the host tier BEFORE the dispatch
-        # overwrites their pages (the spill-safety window)
-        eng._drain_spills()
+        arrays = (tokens, positions, write_pages, write_offs,
+                  cache.page_tables, lane_slots, lane_lens)
+        # what the paged kernel will fetch for these lanes, all layers
+        # (the count is made where the lanes are made)
+        c = eng.cache_cfg
+        kv_bytes = eng.num_layers * kv_read_bytes(
+            lane_lens, lane_slots, cache.page_tables, page_size=ps,
+            num_heads=eng.num_heads, head_dim=eng.head_dim,
+            kv_itemsize=c.kv_itemsize,
+            block_kv_pages=eng.attn_block_kv // ps,
+            quantized=eng.kv_quantized)
+        return (arrays, lane_adapters, lane, emitters, spec_emitters,
+                kv_bytes)
+
+    def step(self) -> Optional[StepEvents]:
+        """Advance one engine step. Returns None when the session is
+        drained (no waiting or running requests survive the abort
+        sweep), else a StepEvents. The whole step is one phase span,
+        `serve_step`, and each part of it a child span
+        (Telemetry.timed: docs/observability.md "Phase spans")."""
+        eng = self.eng
+        with eng.telemetry.timed(eng._ENGINE_TRACK, "serve_step"):
+            return self._step()
+
+    def _step(self) -> Optional[StepEvents]:
+        eng = self.eng
+        sched = self.sched
+        cache = eng.cache
+        c = eng.cache_cfg
+        timed, track = eng.telemetry.timed, eng._ENGINE_TRACK
+        # chunk boundary: cancels and expired deadlines leave the
+        # system HERE, before any of this step's chunks exist
+        with timed(track, "sweep"):
+            eng._sweep_aborts(sched)
+        if not sched.has_work():
+            return None
+        with timed(track, "schedule"):
+            plan = sched.schedule()
+        ev = StepEvents(plan)
+        # claim the priced host-tier DMA this plan's admissions spent
+        # (carried even on planning-only iterations)
+        ev.host_reload_s, eng._host_reload_s = eng._host_reload_s, 0.0
+        if sched.stats["rejected"] > self._rejected_seen:
+            # rung-4 structured rejection: the ladder refused service —
+            # exactly the state an operator wants black-boxed (one
+            # bundle per rate-limit window, not one per rejection)
+            self._rejected_seen = sched.stats["rejected"]
+            eng._auto_postmortem("rejection", sched=sched)
+        if not plan.chunks:
+            # every waiting request was rejected (rung 4) or the
+            # running set was preempted whole under injected pressure;
+            # the next step() re-plans (forced progress guarantees
+            # this cannot spin)
+            return ev
+        with timed(track, "pack"):
+            (arrays, lane_adapters, lane, emitters, spec_emitters,
+             ev.kv_bytes_read) = self._pack(plan)
+        with timed(track, "drain"):
+            # land any adapters this plan admitted BEFORE their lanes
+            # dispatch — the planning-visible load stall, not a
+            # recompile
+            eng._drain_adapter_loads()
+            # ship queued evictions to the host tier BEFORE the
+            # dispatch overwrites their pages (the spill-safety window)
+            eng._drain_spills()
+        step_idx = len(self.util)
         tp = time.perf_counter()
-        greedy, topv, topi, _, _ = eng._dispatch_mixed(
-            eng._k_pages, eng._v_pages,
-            eng._h2d(tokens), eng._h2d(positions),
-            eng._h2d(write_pages), eng._h2d(write_offs),
-            eng._h2d(cache.page_tables), eng._h2d(lane_slots),
-            eng._h2d(lane_lens),
-            lane_adapters=(None if lane_adapters is None
-                           else eng._h2d(lane_adapters)))
-        greedy = np.asarray(greedy)
-        topv = np.asarray(topv)
-        topi = np.asarray(topi)
+        with timed(track, "upload"):
+            dev = [eng._h2d(a) for a in arrays]
+            dev_adapters = None if lane_adapters is None \
+                else eng._h2d(lane_adapters)
+        with timed(track, "dispatch", {
+                "step": step_idx, "live": lane,
+                "prefill": plan.num_prefill_lanes,
+                "decode": plan.num_decode_lanes,
+                "kv_bytes": ev.kv_bytes_read}):
+            greedy, topv, topi, _, _ = eng._dispatch_mixed(
+                eng._k_pages, eng._v_pages, *dev,
+                lane_adapters=dev_adapters)
+        with timed(track, "fetch"):
+            greedy = np.asarray(greedy)
+            topv = np.asarray(topv)
+            topi = np.asarray(topi)
         dt = time.perf_counter() - tp
-        if not np.isfinite(topv[:lane]).all():
-            self.nonfinite_steps += 1
-        self.util.append(1.0 - cache.free_pages / c.usable_pages)
-        if eng.telemetry.enabled:
-            eng._record_step_telemetry(
-                eng.telemetry, plan, len(self.util) - 1, tp, dt,
-                sched.rung, self.util[-1])
-        # bookkeeping FIRST (page commits hash the context as it was
-        # when the chunk ran), emission second; speculative chunks
-        # verify LAST — their residency bookkeeping is a function of
-        # the tokens they emit
-        for ch in plan.chunks:
-            if not ch.draft_tokens:
-                sched.complete_chunk(ch)
-        dec_tokens = 0
-        for ch, ln in emitters:
-            self._emit(ev, ch, greedy[ln], topv[ln], topi[ln])
-            if ch.is_decode:
-                dec_tokens += 1
-        for ch, ln in spec_emitters:
-            dec_tokens += self._emit_spec(ev, ch, ln, greedy, topv,
-                                          topi)
+        with timed(track, "emit"):
+            if not np.isfinite(topv[:lane]).all():
+                self.nonfinite_steps += 1
+            self.util.append(1.0 - cache.free_pages / c.usable_pages)
+            if eng.telemetry.enabled:
+                eng._record_step_telemetry(
+                    eng.telemetry, plan, step_idx, tp, dt, sched.rung,
+                    self.util[-1])
+            # bookkeeping FIRST (page commits hash the context as it
+            # was when the chunk ran), emission second; speculative
+            # chunks verify LAST — their residency bookkeeping is a
+            # function of the tokens they emit
+            for ch in plan.chunks:
+                if not ch.draft_tokens:
+                    sched.complete_chunk(ch)
+            dec_tokens = 0
+            for ch, ln in emitters:
+                self._emit(ev, ch, greedy[ln], topv[ln], topi[ln])
+                if ch.is_decode:
+                    dec_tokens += 1
+            for ch, ln in spec_emitters:
+                dec_tokens += self._emit_spec(ev, ch, ln, greedy, topv,
+                                              topi)
         if plan.num_decode_lanes:
             self.decode_times.append(dt)
             # width = tokens this step's decode chunks EMITTED
@@ -3423,7 +3509,7 @@ class ServeSession:
         if plan.num_prefill_lanes:
             self.prefill_times.append((plan.num_prefill_lanes, dt))
         ev.dispatched = True
-        ev.step_index = len(self.util) - 1
+        ev.step_index = step_idx
         ev.wall_s = dt
         ctxs = [len(ch.req.prompt) + len(ch.req.out_tokens)
                 for ch in plan.chunks if ch.is_decode] \

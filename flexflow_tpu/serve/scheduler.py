@@ -45,6 +45,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import time
 from collections import deque
 from typing import Deque, Dict, List, Optional, Sequence
 
@@ -148,8 +149,8 @@ class Request:
     _page_keys: List[bytes] = dataclasses.field(default_factory=list,
                                                 repr=False)
     # serving metrics (utils/profiling.serve_report, telemetry queue-
-    # wait spans): wall-clock stamps. t_admit is stamped by the engine
-    # at the first step that plans the request (0.0 until then).
+    # wait spans): wall-clock stamps. t_admit is stamped by schedule()
+    # at the request's first admission (0.0 until then).
     t_submit: float = 0.0
     t_admit: float = 0.0
     t_first_token: float = 0.0
@@ -639,6 +640,10 @@ class ContinuousBatchingScheduler:
             req.num_computed = cached_len
             cache.ensure_capacity(slot, end)
             self.running[slot] = req
+            if not req.t_admit:
+                # always stamped, telemetry on or off: the queue wait
+                # ends HERE, before the admitting step packs or runs
+                req.t_admit = time.perf_counter()
             chunks.append(ChunkPlan(req, cached_len, end, False))
             note_pending(req, cached_len, end)
             admitted.append(req)

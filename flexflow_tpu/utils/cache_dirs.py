@@ -43,11 +43,19 @@ def arm_compile_cache() -> Tuple[str, bool]:
     """Point JAX's persistent compilation cache at
     ``compile_cache_dir()`` and return ``(dir, was_empty)``. With
     ``JAX_COMPILATION_CACHE_DIR`` set JAX has already read it and this
-    only reports."""
+    only reports. Either way the cache's key covers the operations'
+    metadata."""
+    import jax
     path = compile_cache_dir()
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        import jax
         jax.config.update("jax_compilation_cache_dir", path)
+    # JAX strips an operation's metadata (named scopes, source lines)
+    # from the cache key by default, so a cache filled before a scope
+    # existed would hand back an executable whose profiler trace
+    # cannot be attributed (benchmark/lib/program_trace.py reads the
+    # scopes). With the metadata in the key such an entry is a miss.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      True)
     try:
         with os.scandir(path) as it:
             # files only: the measurement caches' subdirectory is not

@@ -56,12 +56,18 @@ import time
 from collections import deque
 from typing import Dict, Iterable, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "MetricsRegistry", "MetricsServer", "Telemetry", "telemetry_for",
     "pct", "pow2_bucket", "serve_metrics", "train_metrics",
     "next_trace_id", "attribute_request", "fold_attribution",
-    "write_json_atomic", "REQUEST_COMPONENTS",
+    "write_json_atomic", "REQUEST_COMPONENTS", "PHASE_PREFIX",
 ]
+
+# prefix of the phase spans Telemetry.timed writes into a profiler
+# trace (the benchmark's own spans are `bench:`)
+PHASE_PREFIX = "ff:"
 
 
 # ---------------------------------------------------------------------------
@@ -140,8 +146,8 @@ _SPAN_CLASS = {"prefill": "prefill", "decode": "decode",
                "spec_decode": "decode", "kv_handoff": "transfer",
                "host_reload": "host_reload", "routing": "routing"}
 # overlap priority (highest wins per elementary segment): compute beats
-# the queue-wait span that legitimately overlaps a request's FIRST
-# chunk (t_admit is stamped after the admitting step's dispatch), a
+# any queue-wait span that overlaps a request's chunk (t_admit is
+# stamped at the admission, before the admitting step dispatches), a
 # host-tier page reload (serve/host_tier.py) likewise happens inside
 # the admitting schedule() pass so it must beat queue, and retry
 # backoff carves time out of the compute span that covers it
@@ -485,25 +491,6 @@ class Telemetry:
                  self.now() if t is None else self._rel(t),
                  0.0, None, args))
 
-    def async_span(self, track: Tuple[str, str], name: str, ident,
-                   t_start: float, t_end: float,
-                   args: Optional[dict] = None) -> None:
-        """Async (b/e) span — the Chrome-trace form for intervals that
-        legitimately overlap on one track (queue-wait of concurrently
-        waiting requests)."""
-        if not self.enabled:
-            return
-        with self._lock:
-            n = len(self.events)
-            if n >= self.max_events:        # both appends evict
-                self.dropped_events += 2
-            elif n == self.max_events - 1:  # the second append evicts
-                self.dropped_events += 1
-            self.events.append(("b", track, name, self._rel(t_start),
-                                0.0, ident, args))
-            self.events.append(("e", track, name, self._rel(t_end),
-                                0.0, ident, None))
-
     def counter(self, track: Tuple[str, str], name: str, value: float,
                 t: Optional[float] = None) -> None:
         """Counter-track sample (Perfetto renders these as a stepped
@@ -542,14 +529,23 @@ class Telemetry:
     @contextlib.contextmanager
     def timed(self, track: Tuple[str, str], name: str,
               args: Optional[dict] = None):
-        if not self.enabled:
-            yield
-            return
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.span(track, name, t0, time.perf_counter(), args)
+        """THE phase-span entry point of the hot loops
+        (ServeSession.step, FFModel.train_batch): a
+        ``jax.profiler.TraceAnnotation`` named ``ff:<name>`` — on the
+        profiler's clock, so it can be laid over the device trace;
+        inactive and near-free unless a profiler session runs — and,
+        when this bus is enabled, the same span on the bus. The
+        disabled shared instance takes the same path minus the ring
+        append (no lock, no record)."""
+        with TraceAnnotation(PHASE_PREFIX + name, **(args or {})):
+            if not self.enabled:
+                yield
+                return
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                self.span(track, name, t0, time.perf_counter(), args)
 
     # ---------------- drift calibration --------------------------------
     def record_drift(self, domain: str, regime: str, predicted_s: float,
