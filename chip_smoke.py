@@ -50,8 +50,14 @@ Phases (the first thing that fails ends the run):
            own geometry (520 lanes, page 16, 128 pages per sequence)
            against `_ragged_jnp` on seeded pages, bf16 and f32. Logits,
            not tokens: with random weights the arg-max flips on rounding.
-           Tolerance: the kernel is f32-accurate (f32 products, HIGHEST
-           MXU sums); the jnp twin is too once XLA's default one-pass
+           Two lane layouts: every lane of another sequence than its
+           neighbour (the longest grid the kernel can be asked for),
+           and a mixed step as the engine packs it (decode lanes, one
+           chunk, an inactive tail) on the engine's own grid bound.
+           Tolerance: the kernel is f32-accurate (f32 operands at
+           HIGHEST MXU precision; bf16 operands that are exact, the
+           probabilities as two bf16 halves, f32 sums);
+           the jnp twin is too once XLA's default one-pass
            bf16 f32-dot is overridden ("highest"), leaving summation
            order: 2e-5. A bf16 output adds half a unit in its 8th bit at
            magnitudes up to ~4: 2e-2. The twin gathers every lane's
@@ -158,7 +164,8 @@ def kernel_phase(w: Widths, interpret: bool) -> dict:
 
     from flexflow_tpu.config import FFConfig
     from flexflow_tpu.kernels.paged_ragged_v2 import (
-        _ragged_jnp, paged_attention_ragged_v2)
+        _ragged_jnp, build_work_list, max_work_items,
+        paged_attention_ragged_v2)
 
     cfg = FFConfig()
     h, d, ps = w.heads, w.hidden // w.heads, w.page_size
@@ -177,34 +184,57 @@ def kernel_phase(w: Widths, interpret: bool) -> dict:
     for s, n in enumerate(lens):
         for i in range(-(-n // ps)):
             table[s, i] = int(free.pop())
+    sub = np.arange(0, lanes, 11)            # the lanes the twin checks
+    # scattered: every lane of another sequence than its neighbour —
+    # one run a lane, the kernel's own (longest) grid
     slots = (np.arange(lanes) % seqs).astype(np.int32)
     pos = np.array([rng.randint(0, lens[s]) for s in slots], np.int32)
     pos[:seqs] = np.array(lens) - 1          # every sequence's tail too
-    sub = np.arange(0, lanes, 11)            # the lanes the twin checks
+    # mixed, as ServeSession._pack lays a step out: a decode lane per
+    # sequence but the first, that one's last tokens as one chunk, an
+    # inactive tail on the sink — on the grid the engine would prove
+    chunk = min(lens[0], (lanes - seqs) * 3 // 4)
+    m_slots = np.zeros(lanes, np.int32)
+    m_pos = np.zeros(lanes, np.int32)
+    m_slots[:seqs - 1] = np.arange(1, seqs)
+    m_pos[:seqs - 1] = np.array(lens[1:]) - 1
+    m_pos[seqs - 1:seqs - 1 + chunk] = lens[0] - chunk + np.arange(chunk)
+    bp = 8                                   # pages a block, mixed
+    layouts = {"scattered": (slots, pos, None),
+               "mixed": (m_slots, m_pos,
+                         max_work_items(lanes, pp, bp, slot_changes=seqs))}
     worst = {}
     for dtype, tol in ((jnp.bfloat16, 2e-2), (jnp.float32, 2e-5)):
         q = jnp.asarray(rng.randn(lanes, h, d), dtype)
         kp = jnp.asarray(rng.randn(pages, ps, h, d), dtype)
         vp = jnp.asarray(rng.randn(pages, ps, h, d), dtype)
         args = (kp, vp, jnp.asarray(table))
-        out = jax.jit(lambda q, kp, vp, t, s, n: paged_attention_ragged_v2(
-            q, kp, vp, t, s, n, use_pallas=True, interpret=interpret))(
-            q, *args, jnp.asarray(slots), jnp.asarray(pos + 1))
-        with jax.default_matmul_precision("highest"):
-            ref = jax.jit(lambda q, kp, vp, t, s, n: _ragged_jnp(
-                q, kp, vp, t, s, n, d ** -0.5))(
-                q[sub], *args, jnp.asarray(slots[sub]),
-                jnp.asarray(pos[sub] + 1))
-        got = np.asarray(out, np.float32)
-        if got.shape != (lanes, h, d) or not np.isfinite(got).all():
-            raise AssertionError(f"{jnp.dtype(dtype).name}: kernel output "
-                                 f"shape {got.shape} / non-finite values")
-        err = float(np.max(np.abs(got[sub] - np.asarray(ref, np.float32))))
-        worst[jnp.dtype(dtype).name] = err
-        if not err <= tol:
-            raise AssertionError(
-                f"{jnp.dtype(dtype).name}: kernel vs jnp max abs error "
-                f"{err:.3g} over tolerance {tol:g}")
+        for layout, (l_slots, l_pos, bound) in layouts.items():
+            def call(q, kp, vp, t, s, n, bound=bound):
+                work = None if bound is None else build_work_list(
+                    t, s, n, page_size=ps, block_pages=bp, max_items=bound)
+                return paged_attention_ragged_v2(
+                    q, kp, vp, t, s, n, work=work, use_pallas=True,
+                    interpret=interpret)
+            out = jax.jit(call)(q, *args, jnp.asarray(l_slots),
+                                jnp.asarray(l_pos + 1))
+            with jax.default_matmul_precision("highest"):
+                ref = jax.jit(lambda q, kp, vp, t, s, n: _ragged_jnp(
+                    q, kp, vp, t, s, n, d ** -0.5))(
+                    q[sub], *args, jnp.asarray(l_slots[sub]),
+                    jnp.asarray(l_pos[sub] + 1))
+            got = np.asarray(out, np.float32)
+            name = f"{jnp.dtype(dtype).name}/{layout}"
+            if got.shape != (lanes, h, d) or not np.isfinite(got).all():
+                raise AssertionError(f"{name}: kernel output shape "
+                                     f"{got.shape} / non-finite values")
+            err = float(np.max(np.abs(got[sub]
+                                      - np.asarray(ref, np.float32))))
+            worst[name] = err
+            if not err <= tol:
+                raise AssertionError(
+                    f"{name}: kernel vs jnp max abs error "
+                    f"{err:.3g} over tolerance {tol:g}")
     return {"impl": "pallas_interpret" if interpret else "pallas",
             "lanes": lanes, "heads": h, "head_dim": d, "page_size": ps,
             "pages_per_seq": pp, "max_abs_err": worst}

@@ -384,7 +384,7 @@ class FFConfig:
     host_tier_mb: float = 0.0
     serve_host_tier: bool = True
     # ragged-attention kv-block shape (kernels/paged_ragged_v2.py): KV
-    # tokens each flattened (lane, kv-block) work item covers (rounded
+    # tokens each (run of lanes, kv-block) work item covers (rounded
     # to whole pages). 0 = the autotune-by-shape table
     # (choose_block_kv). --serve-attn-block-kv.
     serve_attn_block_kv: int = 0

@@ -378,11 +378,13 @@ def resolve_flash(use_flash, b: int, h: int, sq: int, sk: int, d: int,
 #     reference the Pallas kernel is tested against, and the path the
 #     CPU tests run.
 #   * the ragged v2 Pallas kernel (kernels/paged_ragged_v2.py) — a
-#     scalar-prefetch kernel: the page table, the lane->slot map and
-#     lane lengths ride in SMEM ahead of the grid so each work item
-#     DMAs exactly the pages table[slot[lane], ...] names; online
-#     max/sum rescaling accumulates across a lane's pages in VMEM
-#     scratch. Never materializes the gathered (B, max_len, H, D) K/V
+#     scalar-prefetch kernel: a work list of (run of lanes of one
+#     sequence, kv-block) items, built from the page table, the
+#     lane->slot map and the lane lengths, rides in SMEM ahead of the
+#     grid so each work item DMAs exactly the pages its run's table
+#     row names, once for all the run's rows; online max/sum rescaling
+#     accumulates across a tile's items in VMEM scratch. Never
+#     materializes the gathered (B, max_len, H, D) K/V
 #     that the jnp path pays for. A decode step is the ragged call with
 #     one lane per sequence, so it runs the same kernel.
 #
@@ -474,12 +476,13 @@ def paged_attention_ragged_v1(q, k_pages, v_pages, page_tables,
 def paged_attention_ragged(q, k_pages, v_pages, page_tables, lane_slots,
                            lane_lens, *, scale=None, use_pallas=None,
                            interpret=False, k_scales=None, v_scales=None,
-                           block_kv=None):
+                           block_kv=None, work=None):
     """Ragged batched attention through page tables — the chunked
     prefill/mixed-step kernel (serve/engine.py), v2 since PR 8
-    (kernels/paged_ragged_v2.py: one flattened (lane, kv-block) grid
-    with ragged skipping, head packing, and tunable kv-block shapes,
-    per the "Ragged Paged Attention" paper in PAPERS.md).
+    (kernels/paged_ragged_v2.py: a work list of (run of lanes,
+    kv-block) items with ragged skipping, head packing, and tunable
+    kv-block shapes, per the "Ragged Paged Attention" paper in
+    PAPERS.md).
 
     q (T, H, D) — one query token per LANE, where lanes mix prompt-chunk
     tokens from any number of sequences with single decode tokens;
@@ -497,7 +500,9 @@ def paged_attention_ragged(q, k_pages, v_pages, page_tables, lane_slots,
     (num_pages, page_size, H) f32 k_scales/v_scales; the kernel (and
     the fallback) dequantizes at read (serve/kv_cache.py).
     block_kv tunes the kv-block shape (FFConfig.serve_attn_block_kv;
-    None = autotune-by-shape table).
+    None = autotune-by-shape table). work is the step's WorkList
+    (paged_ragged_v2.build_work_list over these lane arrays) where a
+    caller makes one for several calls; None builds it per call.
 
     The jnp fallback runs v1's math verbatim, so a 1-lane-per-sequence
     fp32 call is bit-for-bit `paged_attention_decode`, and the op order
@@ -511,7 +516,8 @@ def paged_attention_ragged(q, k_pages, v_pages, page_tables, lane_slots,
     return paged_attention_ragged_v2(
         q, k_pages, v_pages, page_tables, lane_slots, lane_lens,
         k_scales=k_scales, v_scales=v_scales, scale=scale,
-        block_kv=block_kv, use_pallas=use_pallas, interpret=interpret)
+        block_kv=block_kv, work=work, use_pallas=use_pallas,
+        interpret=interpret)
 
 
 def flash_attention_bshd(q, k, v, *, causal=False,

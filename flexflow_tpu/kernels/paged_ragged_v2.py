@@ -18,27 +18,42 @@ first-cut — three structural costs the mature design removes:
 
 This module rebuilds the kernel along the paper's lines:
 
-  * ONE FLATTENED GRID over (lane, kv-block) work items: grid
-    (T * num_kv_blocks,), item w -> lane w // nb, kv-block w % nb. A
-    kv-block covers `block_kv_pages` pages — several page DMAs land per
-    grid step (one BlockSpec per page slot, so Mosaic pipelines them),
-    and the per-lane step count drops pages_per_seq / block_kv_pages x.
-  * RAGGED SKIPPING: a work item whose kv-block starts past its lane's
-    visible length is DEAD — `pl.when` skips its entire accumulation
-    (v1 computed and masked it), and its page index maps clamp to the
-    lane's last live block so no new DMA is issued for dead tail items.
-  * HEAD PACKING for small head_dim: page blocks stream as
-    (page_size, H*D) rows — the layout is already contiguous in HBM, so
-    this is a free reshape that fills 128-lane VMEM tiles where
-    (page_size, H, D) tiling padded D up to 128 — and STAY packed: the
-    per-head reductions are matmuls with a 0/1 segment matrix (see the
-    kernel section), because Mosaic cannot split a lane dimension.
+  * A WORK LIST instead of a lanes x columns grid. Consecutive lanes
+    [g * Q_ROWS, (g + 1) * Q_ROWS) are a TILE; the lanes of a tile that
+    name one slot are a RUN (a chunk's tokens, a decode lane with its
+    draft tokens, one decode lane); a work item is (run, kv-block). The
+    list is built from the lane arrays on the device, once a step
+    (`build_work_list`; the engine's 24 layers share it), and reaches
+    the kernel by scalar prefetch. The grid's length is a STATIC bound
+    on the list (`max_work_items`: (tiles + slot changes) x kv-blocks
+    for a caller that can bound the changes, lanes x kv-blocks for any
+    other), not lanes x table columns: the steps a call takes follow
+    the plan's shape, and the items past the list's end re-select the
+    blocks before them and do nothing.
+  * SEVERAL PAGES AN ITEM: a kv-block covers `block_kv_pages` pages —
+    one BlockSpec per page slot, so Mosaic pipelines their DMAs — sized
+    so that fetching it takes longer than a grid step costs.
+  * ONE FETCH FOR ALL THE ROWS OF A RUN: the rows of a run attend the
+    same K/V block in one item. Every row is masked by its own
+    `lane_lens` entry; grouping decides what is fetched together, never
+    what a row may see.
+  * RAGGED SKIPPING: a run has items only for the kv-blocks that start
+    below its longest lane, and a page slot past its last live page
+    keeps the page it held (no DMA, positions masked).
+  * HEAD PACKING: page blocks stream as (page_size, H*D) rows, which
+    fills 128-lane VMEM tiles where (page_size, H, D) tiling padded
+    D < 128 up to 128, and STAY packed — Mosaic cannot split a lane
+    dimension. (On a standalone array the reshape is free; on the
+    engine's tiled pool slice XLA makes a copy for it: PERF.md
+    section 5.) The per-head products are MXU matmuls over a run's
+    rows, one 128-lane slab of the packed axis at a time (see the
+    kernel section). Every page format and head size takes this path;
+    only the operands' precision differs (below).
   * TUNABLE KV-BLOCK SHAPES: `block_kv` (tokens per work item; FFConfig
     serve_attn_block_kv / --serve-attn-block-kv) with an
-    autotune-by-shape table supplying defaults — sized so each step's
-    K+V DMA traffic amortizes issue overhead without exceeding a VMEM
-    budget. Measured entries can be registered (tools/flash_sweep.py
-    style) and override the analytic pick.
+    autotune-by-shape table supplying defaults: measured entries for
+    the geometries the repo serves, the analytic rule for the rest
+    (`choose_block_kv`); `register_block_kv` overrides either.
   * QUANTIZED KV PAGES: int8 K/V pages ride with per-page scale arrays
     (one f32 scale per head per in-page slot — see serve/kv_cache.py
     for why scales are per-slot, not per-whole-page); the kernel DMAs
@@ -52,18 +67,23 @@ Numerics contract: the jnp fallback is BIT-IDENTICAL to v1's
 dot_general dims, same single-pass softmax — so every existing
 bit-equality oracle (full-prefill per lane, one-lane == decode) holds
 verbatim under v2. The Pallas kernel is the same online softmax summed
-in another order, with every product in f32, so it agrees with the jnp
-path to f32 rounding on every page format (2e-6 in the interpreter,
-tests/test_kv_quant.py; on the chip tests_tpu/test_serve_tpu.py and
-chip_smoke.py state their tolerances); the QUANTIZATION error itself is
-gated by the bounded-error + greedy-parity tests (tests/test_kv_quant.py).
+in another order, statistics and accumulator in f32. With f32 q or f32
+pages every product is f32 (HIGHEST precision on the MXU); with bf16 q
+and bf16 / int8 / fp8 pages the operands of q.k are exact in bf16 and
+the probabilities go to the MXU as two bf16 halves (16 bits), both
+accumulated in f32. It agrees with the jnp path to f32 rounding on
+every page format (2e-6 in the interpreter, tests/test_kv_quant.py; on
+the chip tests_tpu/test_serve_tpu.py and chip_smoke.py state their
+tolerances); the QUANTIZATION error itself is gated by the
+bounded-error + greedy-parity tests (tests/test_kv_quant.py).
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
-from typing import Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -125,17 +145,37 @@ def dequantize_kv(q, scale):
 
 
 # --------------------------------------------- kv-block shape autotuning
-# Analytic targets for choose_block_kv: each work item should move at
-# least DMA_TARGET_BYTES of K+V so the per-step DMA issue cost is
-# amortized, while the resident K/V (+ scale) blocks stay under
-# VMEM_BUDGET_BYTES (Pallas double-buffers them, hence the /2).
-DMA_TARGET_BYTES = 32 * 1024
-VMEM_BUDGET_BYTES = 512 * 1024
+# One work item is one grid step of a pipelined Mosaic call, and a grid
+# step costs a fixed time whatever it does (measured on a v5e: PERF.md
+# section 6, PR 25). A block is sized so that FETCHING it takes longer
+# than that: STEP_FETCH_BYTES of K+V per item (1 MiB = 1.3 us at 819
+# GB/s), never more than MAX_BLOCK_TOKENS (one page operand per page of
+# the block: the pipeline's bookkeeping grows with them), with the
+# resident K/V (+ scale) blocks under VMEM_BUDGET_BYTES (Pallas
+# double-buffers them, hence the /2).
+STEP_FETCH_BYTES = 1024 * 1024
+MAX_BLOCK_TOKENS = 256
+VMEM_BUDGET_BYTES = 8 * 1024 * 1024
+
+# Query rows of one work item: consecutive lanes [g * Q_ROWS, (g + 1) *
+# Q_ROWS) form a TILE; the lanes of a tile that belong to one sequence
+# share every K/V block they attend. Two packed bf16 tiles of rows: the
+# sweep's best of 8, 16, 32 and 64 at the serving cell's geometry (an
+# item's time is set by moving K and V through the MXU, not by its
+# rows; more rows a tile are fewer tiles, and past 32 the decode
+# lanes' items slow down).
+Q_ROWS = 32
 
 # (page_size, num_heads, head_dim, kv_itemsize, pages_per_seq) ->
-# block_kv tokens. Seeded analytically on first use; measured sweeps
-# (register_block_kv) override — the "autotune-by-shape table".
-_BLOCK_KV_TABLE: Dict[Tuple[int, int, int, int, int], int] = {}
+# block_kv tokens. Measured entries (the sweep of PERF.md section 6,
+# PR 25, at the geometries the repo serves) come first; every other
+# geometry is seeded analytically on first use. register_block_kv
+# overrides either — the "autotune-by-shape table".
+_BLOCK_KV_TABLE: Dict[Tuple[int, int, int, int, int], int] = {
+    # OPT-1.3B on one chip: pages of 16, 32 heads of 64, bf16, 2048
+    # positions (benchmark/configs/opt-1.3b.json)
+    (16, 32, 64, 2, 128): 256,
+}
 
 
 def register_block_kv(page_size: int, num_heads: int, head_dim: int,
@@ -151,9 +191,9 @@ def choose_block_kv(page_size: int, pages_per_seq: int, num_heads: int,
                     head_dim: int, kv_itemsize: int = 4) -> int:
     """KV tokens per work item for a pool geometry: the autotune table
     entry if one is registered, else the analytic pick — the smallest
-    whole-page multiple whose K+V DMA reaches DMA_TARGET_BYTES, capped
-    by the VMEM budget and the table width. Always a multiple of
-    page_size and >= one page."""
+    whole-page multiple whose K+V fetch reaches STEP_FETCH_BYTES,
+    capped by MAX_BLOCK_TOKENS, the VMEM budget and the table width.
+    Always a multiple of page_size and >= one page."""
     key = (page_size, num_heads, head_dim, kv_itemsize, pages_per_seq)
     got = _BLOCK_KV_TABLE.get(key)
     if got is not None:
@@ -161,20 +201,46 @@ def choose_block_kv(page_size: int, pages_per_seq: int, num_heads: int,
     per_tok = 2 * num_heads * head_dim * kv_itemsize  # K + V
     if kv_itemsize == 1:  # quantized (int8/fp8) pages also stream
         per_tok += 2 * num_heads * 4  # their f32 scale rows
-    want = max(1, -(-DMA_TARGET_BYTES // (per_tok * page_size)))
-    cap = max(1, (VMEM_BUDGET_BYTES // 2) // (per_tok * page_size))
-    ppb = min(max(1, want), cap, pages_per_seq)
+    want = -(-STEP_FETCH_BYTES // (per_tok * page_size))
+    cap = min((VMEM_BUDGET_BYTES // 2) // (per_tok * page_size),
+              MAX_BLOCK_TOKENS // page_size)
+    ppb = max(1, min(want, cap, pages_per_seq))
     block = ppb * page_size
     _BLOCK_KV_TABLE[key] = block
     return block
 
 
+def max_work_items(num_lanes: int, pages_per_seq: int,
+                   block_kv_pages: int, q_rows: int = Q_ROWS,
+                   slot_changes: Optional[int] = None) -> int:
+    """The most work items any lane arrays of this geometry can make:
+    the static length of the kernel's grid.
+
+    A RUN is a maximal stretch of consecutive lanes of one tile that
+    name one slot; it has one item per kv-block up to its longest
+    lane, at most ceil(pages_per_seq / block_kv_pages). A run starts at
+    a tile's first lane or where the slot changes from one lane to the
+    next, so there are at most tiles + `slot_changes` of them; a caller
+    that cannot bound the changes (None) gets one run a lane, the safe
+    lanes x blocks."""
+    nb = -(-pages_per_seq // max(1, min(block_kv_pages, pages_per_seq)))
+    tiles = -(-num_lanes // q_rows)
+    runs = tiles * q_rows if slot_changes is None \
+        else min(tiles * q_rows, tiles + slot_changes)
+    return runs * nb
+
+
 def ragged_dispatch_passes(num_lanes: int, pages_per_seq: int,
-                           block_kv_pages: int) -> Dict[str, int]:
+                           block_kv_pages: int, q_rows: int = Q_ROWS,
+                           slot_changes: Optional[int] = None
+                           ) -> Dict[str, int]:
     """Grid-step accounting for the serve bench: the v1 kernel runs one
-    grid step per (lane, page); v2 runs one per (lane, kv-block)."""
-    nb = -(-pages_per_seq // max(1, block_kv_pages))
-    return {"v1": num_lanes * pages_per_seq, "v2": num_lanes * nb}
+    grid step per (lane, page); v2's grid is `max_work_items` long —
+    (tiles + slot changes) x kv-blocks for a caller that bounds the
+    changes, lanes x kv-blocks otherwise."""
+    return {"v1": num_lanes * pages_per_seq,
+            "v2": max_work_items(num_lanes, pages_per_seq,
+                                 block_kv_pages, q_rows, slot_changes)}
 
 
 # ------------------------------------------------------------ jnp paths
@@ -214,101 +280,413 @@ def _ragged_jnp(q, k_pages, v_pages, page_tables, lane_slots, lane_lens,
     return (o / l).astype(q.dtype)
 
 
+# ------------------------------------------------------------ work list
+# The kernel's grid is a LIST of work items, built from the step's lane
+# arrays once (on the device, above the engine's layer loop: every
+# layer's call shares it) and handed to the kernel by scalar prefetch.
+#
+#   tile   lanes [g * q_rows, (g + 1) * q_rows): the rows of q and of
+#          the output one item holds.
+#   run    a maximal stretch of consecutive lanes of one tile that name
+#          one slot — a chunk's tokens, a decode lane with its draft
+#          tokens, a single decode lane, a stretch of inactive lanes.
+#   item   (run, kv-block): the run's rows attend kv positions
+#          [blk * block_kv, (blk + 1) * block_kv) of the run's sequence;
+#          the block is fetched ONCE for all of them. A run has items
+#          for the blocks that start below its LONGEST lane.
+#
+# Items are ordered by run (so by tile), then by block. Grouping only
+# decides what is fetched together: inside an item every row is masked
+# by its OWN lane_lens entry, and the rows of the tile outside the run
+# are masked whole, so any lane arrays give the jnp twin's answer.
+#
+# The list is as long as the caller's bound says (`max_work_items`),
+# not as long as the step's work: the entries past the last live item
+# repeat it with every flag clear — they re-select the blocks already
+# resident and do nothing. A page slot of a live item that lies past
+# the run's last live page likewise repeats the page the slot held in
+# the item before it: nothing is fetched for it, and its positions are
+# masked.
+_FIRST, _LAST, _LIVE = 1 << 16, 1 << 17, 1 << 18     # flags in `meta`
+# The list is a scalar-prefetch operand: 3 + block_pages words an item
+# in SMEM (1 MiB a v5e core). Half of that is the budget a call's list
+# may take; a caller's own bound has to fit it.
+SMEM_LIST_WORDS = 128 * 1024
+
+
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["tile", "blk", "meta", "pages", "lens"],
+    meta_fields=["q_rows", "block_pages"])
+@dataclasses.dataclass(frozen=True)
+class WorkList:
+    """The kernel's scalar-prefetch operands (n = the grid's length;
+    one entry more than that, because Mosaic's pipeline evaluates the
+    index maps one step past the grid's end)."""
+    tile: Any    # (n + 1,) the item's tile
+    blk: Any     # (n + 1,) its kv-block
+    meta: Any    # (n + 1,) row_lo | row_hi << 8 | _FIRST | _LAST | _LIVE
+    pages: Any   # ((n + 1) * block_pages,) physical page per page slot
+    lens: Any    # (tiles * q_rows, 128) lane_lens, along the lanes
+    q_rows: int
+    block_pages: int
+
+
+def _work_arrays(xp, cummax, page_tables, lane_slots, lane_lens, *,
+                 page_size, block_pages, q_rows, max_items):
+    """The work list's arrays, in numpy or jax.numpy (`xp`; `cummax`
+    is its running maximum along axis 0) — ONE definition, so the
+    counters on the host (`work_items`, `kv_read_bytes`) walk exactly
+    the list the device builds. `max_items` None (numpy only) sizes
+    the list by the step's own items: n = their count. -> (tile, blk,
+    meta, pages (n, bp))."""
+    t = lane_slots.shape[0]
+    pp = page_tables.shape[1]
+    bp, qb = block_pages, q_rows
+    tiles = -(-t // qb)
+    pad = tiles * qb - t
+    i32 = xp.int32
+    slots = xp.concatenate([lane_slots.astype(i32), xp.zeros(pad, i32)])
+    lens = xp.concatenate([lane_lens.astype(i32), xp.ones(pad, i32)])
+    lane = xp.arange(tiles * qb, dtype=i32)
+    prev = xp.concatenate([slots[:1], slots[:-1]])
+    starts = (lane % qb == 0) | (slots != prev)
+    # per lane: its run's first row, end row and longest length, by
+    # comparing run ids inside the tile (q_rows x q_rows a tile)
+    rid = xp.cumsum(starts.astype(i32)).reshape(tiles, qb)
+    same = rid[:, :, None] == rid[:, None, :]
+    row = xp.arange(qb, dtype=i32)[None, None, :]
+    run_len = xp.max(xp.where(same, lens.reshape(tiles, 1, qb), 0),
+                     axis=-1).reshape(-1)
+    run_lo = xp.min(xp.where(same, row, qb), axis=-1).reshape(-1)
+    run_hi = xp.max(xp.where(same, row + 1, 0), axis=-1).reshape(-1)
+    bs = bp * page_size
+    nblk = xp.where(starts, -(-run_len // bs), 0).astype(i32)
+    ends = xp.cumsum(nblk).astype(i32)      # items up to and with lane
+    total = ends[-1]
+    n = int(total) if max_items is None else max_items
+    w = xp.arange(n, dtype=i32)
+    live = w < total
+    wc = xp.minimum(w, total - 1)           # past the end: the last one
+    head = xp.searchsorted(ends, wc, side="right").astype(i32)
+    blk = wc - (ends[head] - nblk[head])
+    lo, hi = run_lo[head], run_hi[head]
+    first = live & (blk == 0) & (lo == 0)
+    last = live & (blk == nblk[head] - 1) & (hi == qb)
+    meta = (lo | (hi << 8) | xp.where(first, _FIRST, 0)
+            | xp.where(last, _LAST, 0) | xp.where(live, _LIVE, 0))
+    # page slot i of item w: table column blk * bp + i while that page
+    # holds a position the run can see, else what the slot held before
+    col = blk[:, None] * bp + xp.arange(bp, dtype=i32)[None, :]
+    fresh = live[:, None] & (col * page_size < run_len[head][:, None])
+    page = page_tables.astype(i32)[slots[head][:, None],
+                                   xp.minimum(col, pp - 1)]
+    src = cummax(xp.where(fresh, w[:, None], -1))
+    pages = xp.where(
+        src >= 0, xp.take_along_axis(page, xp.maximum(src, 0), axis=0), 0)
+    return (head // qb).astype(i32), blk, meta.astype(i32), pages
+
+
+def build_work_list(page_tables, lane_slots, lane_lens, *, page_size: int,
+                    block_pages: int, q_rows: int = Q_ROWS,
+                    max_items: Optional[int] = None) -> WorkList:
+    """The step's work list, on the device (jax.numpy; a few small
+    fusions over the lane arrays). `max_items` is the caller's proof of
+    the most items its lane arrays can make (`max_work_items` with the
+    slot changes it can bound); None is the bound that holds for any
+    arrays. A list longer than the bound would lose its tail, so a
+    caller that passes one also checks it where it makes the arrays
+    (`work_items(...)`: "total" <= "grid"; ServeSession._pack does)."""
+    t, pp = lane_slots.shape[0], page_tables.shape[1]
+    bp = max(1, min(int(block_pages), pp))
+    if max_items is None:
+        max_items = max_work_items(t, pp, bp, q_rows)
+    # one entry more than the grid: the step past its end repeats the
+    # last item like every entry past the list's end
+    tile, blk, meta, pages = _work_arrays(
+        jnp, lambda x: jax.lax.cummax(x, axis=0), page_tables,
+        lane_slots, lane_lens, page_size=page_size, block_pages=bp,
+        q_rows=q_rows, max_items=max_items + 1)
+    tiles = -(-t // q_rows)
+    lens = jnp.concatenate([lane_lens.astype(jnp.int32),
+                            jnp.ones(tiles * q_rows - t, jnp.int32)])
+    return WorkList(
+        tile=tile, blk=blk, meta=meta, pages=pages.reshape(-1),
+        lens=jnp.broadcast_to(lens[:, None], (tiles * q_rows, 128)),
+        q_rows=q_rows, block_pages=bp)
+
+
+def kv_page_bytes(page_size, num_heads, head_dim, kv_itemsize, quantized):
+    """Bytes one fetch of a page slot moves: the K and the V page, and
+    their f32 scale rows on a quantized pool."""
+    per_page = 2 * page_size * num_heads * head_dim * kv_itemsize
+    if quantized:
+        per_page += 2 * page_size * num_heads * 4
+    return per_page
+
+
+def work_items(lane_lens, lane_slots, page_tables, *, page_size: int,
+               block_kv_pages: int = 1, q_rows: int = Q_ROWS,
+               max_items: Optional[int] = None,
+               live_lanes: Optional[int] = None) -> Dict[str, int]:
+    """What one call of the kernel has to do for these lanes (numpy;
+    host side, no device work): `grid` the list's static length
+    (`max_items`, else the bound for any arrays), `total` the items of
+    all lanes (more than `grid` would lose work: the caller's bound was
+    wrong), `page_fetches` the (K, V) page pairs the call fetches from
+    HBM, and — among the first `live_lanes` lanes (all when None; the
+    lanes behind them are the step's inactive padding) — `items` and
+    the query `rows` they hold. rows / items is how often sharing
+    engages: 1.0 when every run is one decode lane, near q_rows inside
+    a long chunk.
+
+    `page_fetches` walks the list the kernel is given: page slot i of
+    item w is one pipelined operand whose block index is `pages[w, i]`,
+    and Pallas's pipeline fetches a block only when its index differs
+    from the previous grid step's. So a run fetches each of its live
+    pages once for all of its rows, a slot past the run's last live
+    page and every item past the list's end fetch nothing, and a page
+    that a slot already held (the stretch of inactive tiles on the sink
+    page) is not fetched again."""
+    pt = np.asarray(page_tables)
+    t, pp = len(lane_slots), pt.shape[1]
+    bp = max(1, min(int(block_kv_pages), pp))
+    grid = max_work_items(t, pp, bp, q_rows) if max_items is None \
+        else int(max_items)
+    # numpy sizes the arrays by the step's own items: the entries past
+    # them repeat the last one, fetch nothing and count nothing
+    tile, _, meta, pages = _work_arrays(
+        np, lambda x: np.maximum.accumulate(x, axis=0), pt,
+        np.asarray(lane_slots), np.asarray(lane_lens),
+        page_size=page_size, block_pages=bp, q_rows=q_rows,
+        max_items=None)
+    live = t if live_lanes is None else int(live_lanes)
+    lo, hi = meta & 0xFF, (meta >> 8) & 0xFF    # the run's rows
+    first = tile * q_rows + lo                  # its first lane
+    mine = first < live
+    return {"grid": grid, "total": len(tile),
+            "items": int(np.sum(mine)),
+            "rows": int(np.sum(np.minimum(hi - lo, live - first)[mine])),
+            "page_fetches": bp + int(np.sum(pages[1:] != pages[:-1]))}
+
+
+def kv_read_bytes(lane_lens, lane_slots, page_tables, *, page_size: int,
+                  num_heads: int, head_dim: int, kv_itemsize: int,
+                  block_kv_pages: int = 1, quantized: bool = False,
+                  q_rows: int = Q_ROWS) -> int:
+    """Bytes of K and V pages (and their scale rows on a quantized
+    pool) that ONE call of the kernel fetches from HBM, for the whole
+    step's lanes: `work_items(...)["page_fetches"]` page slots of
+    `kv_page_bytes` each. Heads are counted whole: a tensor-parallel step
+    fetches the same bytes summed over its chips."""
+    return work_items(
+        lane_lens, lane_slots, page_tables, page_size=page_size,
+        block_kv_pages=block_kv_pages, q_rows=q_rows)["page_fetches"] \
+        * kv_page_bytes(page_size, num_heads, head_dim, kv_itemsize,
+                      quantized)
+
+
 # --------------------------------------------------------- Pallas kernel
-# Everything inside the kernel is a 2-D array with the packed H*D axis
-# on the 128-lane dimension: Mosaic refuses to split a lane dimension
-# (reshape (ps, H*D) -> (ps, H, D): "unsupported shape cast") and to
-# batch a matmul over a non-leading axis, which is how a per-head dot
-# over (bs, H, D) blocks has to be written. The per-head reductions
-# are instead matmuls with a 0/1 SEGMENT matrix seg (H*D, H),
-# seg[j, h] = (j // D == h):
+# Everything inside the kernel is a 2-D array: Mosaic refuses to split a
+# lane dimension (reshape (ps, H*D) -> (ps, H, D): "unsupported shape
+# cast") and to batch a matmul over a non-leading axis, which is how a
+# per-head dot over (bs, H, D) blocks has to be written. The per-head
+# products go to the MXU one SLAB of the packed H*D axis at a time: a
+# slab is W = 128 lanes (W = D where D > 128), G = W // D heads wide.
+# The wrapper lays q out as q2 (tile, slab, G * q_rows, W): row
+# g * q_rows + r of a slab holds row r's head g in ITS D lanes and 0 in
+# the others, so ONE product with the slab of K contracts each head
+# with itself alone,
 #
-#   scores   s[t, h]  = sum_d q[h, d] k[t, h, d] = ((k * q_row) @ seg)[t, h]
-#   weighted o[h*D+d] = sum_t p[t, h] v[t, h, d] = sum_t ((p @ seg^T) * v)[t, h*D+d]
+#   s[g*QB + r, t] = sum_c q2[g*QB + r, c] k[t, c] = q[r, head g] . k[t, head g]
 #
-# Tokens sit on sublanes and heads on lanes, so the per-(token, head)
-# scales of quantized pages multiply s and p directly and K/V are never
-# dequantized. The segment matmuls run on f32 operands at HIGHEST
-# precision: the kernel's result is f32-accurate for every page format.
-_MASK = -0.5 * float(jnp.finfo(jnp.float32).max)  # finite: exp(_MASK - m)
-#                                 is exactly 0 and never inf - inf = NaN
+# and tokens land on the lanes: the softmax statistics are per row of
+# s, kept lane-broadcast in scratch. The weighted sum p (G*QB, bs) @
+# v (bs, W) gives head g's output in rows g*QB.. at lanes g*D.., which
+# a select by lane folds to the (QB, W) slab of the accumulator. The
+# per-(token, head) scales of quantized pages arrive tokens-on-sublanes
+# and are turned onto the lanes by a product with the identity; they
+# multiply s and p, so K/V are never dequantized. The slabs are a
+# `fori_loop` inside the item, SLAB_UNROLL of them a trip (a slab's
+# lanes are a dynamic slice at a multiple of W, which Mosaic takes;
+# scratch and output keep the slab as a leading dimension): with all
+# 16 slabs unrolled in Python, tracing and lowering the body cost every
+# process 9 s before it could ask the compile cache (PERF.md section
+# 6, PR 25).
+#
+# Precision: with bf16 q and bf16 / int8 / fp8 pages every operand of
+# q.k is exact in bf16 and the MXU accumulates in f32; p is split into
+# two bf16 halves (p_hi + p_lo, 16 bits of mantissa) stacked in one
+# product with V. With f32 q or f32 pages the operands stay f32 at
+# HIGHEST precision. Either way the result is as accurate as the output
+# dtype can hold.
+# Slabs in one trip of the item's slab loop. 4: within 3-5 % of the
+# whole loop unrolled (0.73 against 0.70 ms for a decode-only call at
+# the serving cell; 1 slab a trip: 0.97) at a quarter of its equations
+SLAB_UNROLL = 4
+_MASK = -0.5 * float(jnp.finfo(jnp.float32).max)  # finite: m stays finite
 _HIGHEST = jax.lax.Precision.HIGHEST
+_NT = (((1,), (1,)), ((), ()))                    # a @ b.T
 
 
-def _seg_dot(a, b):
-    return jnp.dot(a, b, precision=_HIGHEST,
-                   preferred_element_type=jnp.float32)
+def _slab_geometry(num_heads: int, head_dim: int) -> Tuple[int, int]:
+    """(heads a slab, lanes a slab): as many whole heads as fit 128
+    lanes and divide the head count."""
+    g = max(1, min(num_heads, 128 // head_dim))
+    while num_heads % g:
+        g -= 1
+    return g, g * head_dim
 
 
-def _ragged_v2_kernel(pt_ref, ls_ref, ll_ref, q_ref, seg_ref, segt_ref,
-                      *refs, page_size, num_blocks, block_pages, scale,
-                      quantized):
-    """Flattened-grid kernel body. Grid (T * num_blocks,); work item
-    w covers kv positions [blk * block_pages * ps, ...) of lane
-    w // num_blocks. Page refs arrive head-PACKED as (1, ps, H*D)
-    blocks (plus (1, ps, H) scale blocks when quantized); dead items
-    (block start past the lane's visible length) skip their whole
-    accumulation."""
+def _stack(pieces, dtype):
+    """Page pieces (ps, W) stacked on the token (sublane) axis as one
+    (bs, W) operand of `dtype`. Pieces that fill whole packed tiles of
+    their own dtype are stacked as they lie; others go through f32."""
+    native = pieces[0].dtype
+    rows = 8 * 4 // jnp.dtype(native).itemsize       # sublanes a tile
+    via = native if pieces[0].shape[0] % rows == 0 else jnp.float32
+    if jnp.dtype(native).itemsize == 1:
+        via = jnp.float32        # int8 / fp8 widen to f32 first
+    x = [p.astype(via) for p in pieces]
+    x = x[0] if len(x) == 1 else jnp.concatenate(x, axis=0)
+    return x.astype(dtype)
+
+
+def _by_head(x, q_rows, heads, head_dim):
+    """(G * q_rows, W) with head g's rows stacked at g * q_rows ->
+    (q_rows, W) taking lanes [g * D, (g + 1) * D) from head g's rows."""
+    out = x[:q_rows]
+    if heads > 1:
+        lane = jax.lax.broadcasted_iota(jnp.int32, out.shape, 1)
+        for g in range(1, heads):
+            out = jnp.where(lane >= g * head_dim,
+                            x[g * q_rows:(g + 1) * q_rows], out)
+    return out
+
+
+def _ragged_v2_kernel(tile_ref, blk_ref, meta_ref, pages_ref, q2_ref,
+                      lens_ref, *refs, page_size, block_pages, q_rows,
+                      heads, head_dim, slabs, scale, quantized, exact):
+    """One work item: the rows [lo, hi) of a tile attend one kv-block
+    of their sequence. Page refs arrive head-PACKED as (1, ps, H*D)
+    blocks (plus (1, ps, H) scale blocks when quantized). The grid runs
+    in order; m / l / acc carry a tile's online softmax from its first
+    item to its last. The slabs are a loop inside the item (traced
+    once: the body is not unrolled in Python)."""
+    del tile_ref, pages_ref                  # read by the index maps
     per_page = 4 if quantized else 2
-    kv_refs = refs[:per_page * block_pages]
-    o_ref, m_ref, l_ref, acc_ref = refs[per_page * block_pages:]
+    n_kv = per_page * block_pages
+    kv_refs = refs[:n_kv]
+    if quantized:
+        eye_ref = refs[n_kv]
+        o_ref, m_ref, l_ref, acc_ref, ks_ref, vs_ref = refs[n_kv + 1:]
+    else:
+        o_ref, m_ref, l_ref, acc_ref = refs[n_kv:]
+    qb, g, d = q_rows, heads, head_dim
+    w_lanes = g * d
+    bs = block_pages * page_size
+    op_dtype = jnp.float32 if exact else jnp.bfloat16
+    prec = _HIGHEST if exact else None
 
     w = pl.program_id(0)
-    t = w // num_blocks
-    blk = w % num_blocks
-    length = ll_ref[t]
+    meta = meta_ref[w]
 
-    @pl.when(blk == 0)
+    def each_slab(body):
+        """body(slab, its lanes) for every slab: a loop whose body
+        holds SLAB_UNROLL slabs, independent of each other, so that
+        their loads, matmuls and exponentials overlap."""
+        u = math.gcd(slabs, SLAB_UNROLL)
+
+        def step(i, carry):
+            for j in range(u):
+                slab = i * u + j
+                body(slab, pl.ds(pl.multiple_of(slab * w_lanes, w_lanes),
+                                 w_lanes))
+            return carry
+        jax.lax.fori_loop(0, slabs // u, step, 0)
+
+    @pl.when((meta & _FIRST) != 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _MASK)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    base = blk * block_pages * page_size
-
-    def rows(j):
-        """Page slot j's blocks of this work item, stacked on the
-        token (sublane) axis: (block_pages * ps, ...)."""
-        parts = [kv_refs[per_page * i + j][0] for i in range(block_pages)]
-        return parts[0] if block_pages == 1 else jnp.concatenate(parts, 0)
-
-    # dead item: this block starts at or past the lane's visible
-    # length (lane_lens >= 1, so block 0 is always live) — skip the
-    # entire accumulation. v1 computed the full masked block here.
-    @pl.when(base < length)
+    @pl.when((meta & _LIVE) != 0)
     def _accumulate():
-        q = q_ref[0].astype(jnp.float32)                  # (1, H*D)
-        k = rows(0).astype(jnp.float32)                   # (bs, H*D)
-        v = rows(2 if quantized else 1).astype(jnp.float32)
-        s = _seg_dot(k * q, seg_ref[...])                 # (bs, H)
-        if quantized:
-            s = s * rows(1)                               # k scales
-        s = s * scale
-        pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        s = jnp.where(pos < length, s, _MASK)
-        m_prev = m_ref[...]                               # (1, H)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-        p = jnp.exp(s - m_new)                            # masked -> 0
-        alpha = jnp.exp(m_prev - m_new)
-        m_ref[...] = m_new
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=0,
-                                                  keepdims=True)
-        if quantized:
-            p = p * rows(3)                               # v scales
-        # ONE pass over seg^T expands both p and the accumulator's
-        # rescale factor from per-head to per-(head, dim) lanes
-        bs = p.shape[0]
-        x = _seg_dot(
-            jnp.concatenate([p, jnp.broadcast_to(alpha, (8, alpha.shape[1]))],
-                            axis=0), segt_ref[...])       # (bs + 8, H*D)
-        acc_ref[...] = acc_ref[...] * x[bs:bs + 1] + jnp.sum(
-            x[:bs] * v, axis=0, keepdims=True)
+        lo, hi = meta & 0xFF, (meta >> 8) & 0xFF
+        base = blk_ref[w] * bs
+        # each row's visible length; 0 for the tile's rows outside the
+        # run (they belong to other items), stacked once per head
+        row = jax.lax.broadcasted_iota(jnp.int32, (qb, 128), 0)
+        vis = jnp.where((row >= lo) & (row < hi), lens_ref[...], 0)
+        vis = jnp.concatenate([vis] * g, axis=0)[:, :1]      # (G*QB, 1)
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, (g * qb, bs), 1)
+        seen = pos < vis
 
-    @pl.when(blk == num_blocks - 1)
+        def scales_on_lanes(j, out_ref):
+            """Page slot j's (bs, H) scale rows as (Hp, bs): a product
+            with the identity at HIGHEST precision moves them exactly."""
+            sc = _stack([kv_refs[per_page * i + j][0]
+                         for i in range(block_pages)], jnp.float32)
+            out_ref[...] = jax.lax.dot_general(
+                eye_ref[...], sc, _NT, precision=_HIGHEST,
+                preferred_element_type=jnp.float32)
+
+        def head_rows(sc_ref, slab):
+            """Rows slab * G + g of (Hp, bs), each over its q_rows."""
+            return jnp.concatenate(
+                [jnp.broadcast_to(sc_ref[pl.ds(slab * g + i, 1), :],
+                                  (qb, bs)) for i in range(g)], axis=0)
+
+        if quantized:
+            scales_on_lanes(1, ks_ref)
+            scales_on_lanes(3, vs_ref)
+
+        def one_slab(slab, cols):
+            def block(j):
+                return _stack([kv_refs[per_page * i + j][0, :, cols]
+                               for i in range(block_pages)], op_dtype)
+
+            s = jax.lax.dot_general(
+                q2_ref[0, slab], block(0), _NT, precision=prec,
+                preferred_element_type=jnp.float32)      # (G*QB, bs)
+            if quantized:
+                s = s * head_rows(ks_ref, slab)
+            s = jnp.where(seen, s * scale, _MASK)
+            m_prev = m_ref[slab]                         # (G*QB, W)
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(s, axis=1, keepdims=True))
+            # a row that sees nothing here has s == m_new == _MASK:
+            # exp(0) = 1, so the mask zeroes p itself
+            p = jnp.where(seen, jnp.exp(s - m_new[:, :1]), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            m_ref[slab] = m_new
+            l_ref[slab] = l_ref[slab] * alpha + jnp.sum(
+                p, axis=1, keepdims=True)
+            if quantized:
+                p = p * head_rows(vs_ref, slab)
+            v = block(2 if quantized else 1)             # (bs, W)
+            if exact:
+                pv = jnp.dot(p, v, precision=_HIGHEST,
+                             preferred_element_type=jnp.float32)
+            else:
+                p_hi = p.astype(jnp.bfloat16)
+                p_lo = (p - p_hi.astype(jnp.float32)).astype(jnp.bfloat16)
+                pv = jnp.dot(jnp.concatenate([p_hi, p_lo], axis=0), v,
+                             preferred_element_type=jnp.float32)
+                pv = pv[:g * qb] + pv[g * qb:]
+            acc_ref[slab] = (acc_ref[slab] * _by_head(alpha, qb, g, d)
+                             + _by_head(pv, qb, g, d))
+
+        each_slab(one_slab)
+
+    @pl.when((meta & _LAST) != 0)
     def _emit():
-        l = l_ref[...]
-        lx = _seg_dot(jnp.broadcast_to(l, (8, l.shape[1])), segt_ref[...])
-        o_ref[0] = (acc_ref[...] / lx[0:1]).astype(o_ref.dtype)
+        def one_slab(slab, cols):
+            o_ref[0, slab] = (acc_ref[slab] / _by_head(
+                l_ref[slab], qb, g, d)).astype(o_ref.dtype)
+
+        each_slab(one_slab)
 
 
 def _vmem_limit(block_bytes: int) -> int:
@@ -319,61 +697,51 @@ def _vmem_limit(block_bytes: int) -> int:
     return int(min(max(2 * block_bytes, 16 * 2**20), 64 * 2**20))
 
 
-def _ragged_v2_pallas(q, k_pages, v_pages, page_tables, lane_slots,
-                      lane_lens, scale, block_kv_pages, interpret,
-                      k_scales=None, v_scales=None):
+# jitted on its own: the engine's layers make the same call 24 times,
+# and tracing and lowering the kernel body is host time before the
+# compile cache can even be asked — a nested jit pays it once
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
+                      interpret, k_scales=None, v_scales=None):
     t, h, d = q.shape
     npages, ps = k_pages.shape[0], k_pages.shape[1]
-    pp = page_tables.shape[1]
-    bp = max(1, min(int(block_kv_pages), pp))
-    nb = -(-pp // bp)
+    qb, bp = work.q_rows, work.block_pages
+    n = work.tile.shape[0] - 1
+    tiles = work.lens.shape[0] // qb
     quantized = k_scales is not None
     hd = h * d
+    g, w_lanes = _slab_geometry(h, d)
+    slabs = h // g
+    # f32 anywhere among q and the pages: f32 operands at HIGHEST
+    exact = not (q.dtype == jnp.bfloat16
+                 and jnp.dtype(k_pages.dtype).itemsize <= 2)
+    op_dtype = jnp.float32 if exact else jnp.bfloat16
 
-    # head packing: pages stream as (ps, H*D) rows and q / out as
-    # (1, H*D) rows — contiguous in HBM, so the reshapes are free
+    # head packing: pages stream as (ps, H*D) rows. On a standalone
+    # array this reshape is free; on the engine's tiled pool slice XLA
+    # makes a copy for it (PERF.md section 5)
     kp = k_pages.reshape(npages, ps, hd)
     vp = v_pages.reshape(npages, ps, hd)
-    seg = (jnp.arange(hd, dtype=jnp.int32)[:, None] // d
-           == jnp.arange(h, dtype=jnp.int32)[None, :]).astype(jnp.float32)
-
-    def lane_of(w):
-        """Work item -> lane, CLAMPED to the last lane. Mosaic's
-        pipeline also evaluates the index maps for the step after the
-        grid's last one (to prefetch a block it then never uses), and
-        an index map that reads the prefetched scalars at lane T reads
-        SMEM past their end — on a v5e lane_slots[T] happens to be
-        lane_lens[0], and a 2048-token lane made page_tables[2048, .]
-        a bad_smem_address core halt. Every SMEM read in an index map
-        must be in range for ANY w."""
-        return jnp.minimum(w // nb, t - 1)
+    # q2[tile, slab, g * qb + r, g' * D + c] = q[tile * qb + r, slab * G
+    # + g, c] where g' == g, else 0
+    qp = jnp.pad(q, ((0, tiles * qb - t), (0, 0), (0, 0)))
+    qp = qp.reshape(tiles, qb, slabs, g, d).transpose(0, 2, 3, 1, 4)
+    q2 = (qp[:, :, :, :, None, :].astype(op_dtype)
+          * jnp.eye(g, dtype=op_dtype)[None, None, :, None, :, None]
+          ).reshape(tiles, slabs, g * qb, w_lanes)
 
     def page_index(i):
-        """Index map for page slot i of each work item: the physical
-        page at table column blk*bp + i of the item's lane, CLAMPED to
-        the lane's last live column — dead tail items re-select a page
-        already resident, so they issue no new DMA (their compute is
-        pl.when-skipped anyway)."""
-        def imap(w, pt, ls, ll):
-            tt = lane_of(w)
-            col = (w % nb) * bp + i
-            # clamp into both the table and the lane's live range so
-            # dead items never demand a fresh (sink) page DMA
-            live_last = jnp.maximum((ll[tt] - 1) // ps, 0)
-            col = jnp.minimum(jnp.minimum(col, pp - 1), live_last)
-            return (pt[ls[tt], col], 0, 0)
+        def imap(w, tile, blk, meta, pages):
+            return (pages[w * bp + i], 0, 0)
         return imap
 
-    def lane_index(w, pt, ls, ll):
-        return (lane_of(w), 0, 0)
+    def tile_index(w, tile, blk, meta, pages):
+        return (tile[w], 0)
 
-    def whole(w, pt, ls, ll):
-        return (0, 0)
-
-    in_specs = [pl.BlockSpec((1, 1, hd), lane_index),
-                pl.BlockSpec((hd, h), whole),
-                pl.BlockSpec((h, hd), whole)]
-    args = [q.reshape(t, 1, hd), seg, seg.T]
+    in_specs = [pl.BlockSpec((1, slabs, g * qb, w_lanes),
+                             lambda w, tile, *_: (tile[w], 0, 0, 0)),
+                pl.BlockSpec((qb, 128), tile_index)]
+    args = [q2, work.lens]
     for i in range(bp):
         imap = page_index(i)
         in_specs.append(pl.BlockSpec((1, ps, hd), imap))
@@ -386,78 +754,51 @@ def _ragged_v2_pallas(q, k_pages, v_pages, page_tables, lane_slots,
         if quantized:
             in_specs.append(pl.BlockSpec((1, ps, h), imap))
             args.append(v_scales)
+    hp = -(-h // 8) * 8
+    if quantized:
+        in_specs.append(pl.BlockSpec((hp, h), lambda w, *_: (0, 0)))
+        args.append(jnp.eye(hp, h, dtype=jnp.float32))
     kern = functools.partial(
-        _ragged_v2_kernel, page_size=ps, num_blocks=nb, block_pages=bp,
-        scale=scale, quantized=quantized)
+        _ragged_v2_kernel, page_size=ps, block_pages=bp, q_rows=qb,
+        heads=g, head_dim=d, slabs=slabs, scale=scale,
+        quantized=quantized, exact=exact)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # page_tables, lane_slots, lane_lens
-        grid=(t * nb,),
+        num_scalar_prefetch=4,          # the work list
+        grid=(n,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, hd), lane_index),
+        out_specs=pl.BlockSpec((1, slabs, qb, w_lanes),
+                               lambda w, tile, *_: (tile[w], 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((1, h), jnp.float32),    # running max
-            pltpu.VMEM((1, h), jnp.float32),    # running sum
-            pltpu.VMEM((1, hd), jnp.float32),   # output accumulator
-        ],
+            pltpu.VMEM((slabs, g * qb, w_lanes), jnp.float32),  # max
+            pltpu.VMEM((slabs, g * qb, w_lanes), jnp.float32),  # sum
+            pltpu.VMEM((slabs, qb, w_lanes), jnp.float32),  # accumulator
+        ] + ([pltpu.VMEM((hp, bp * ps), jnp.float32)] * 2   # scales^T
+             if quantized else []),
     )
     bs = bp * ps
     lanes = -(-h // 128) * 128      # a (.., h) f32 tile pads to 128 lanes
+    op_size = jnp.dtype(op_dtype).itemsize
     block_bytes = (
         2 * bs * hd * jnp.dtype(k_pages.dtype).itemsize     # K + V pages
-        + (2 * bs * lanes * 4 if quantized else 0)          # their scales
-        + hd * lanes * 4 + max(h, 8) * hd * 4               # seg, seg^T
-        + 5 * (bs + 8) * hd * 4)    # f32 K, V, k*q, p@seg^T, its product
+        + (2 * bs * lanes * 4 + 2 * hp * bs * 4 if quantized else 0)
+        + slabs * g * qb * w_lanes * (op_size + 8)  # q2, max, sum
+        + 2 * qb * hd * 4                           # acc, the output
+        + 2 * bs * max(w_lanes, 128) * (4 + op_size)    # a slab of K, V
+        + 8 * g * qb * max(bs, 128) * 4)            # s, p and their kin
     out = pl.pallas_call(
         kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, 1, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((tiles, slabs, qb, w_lanes),
+                                       q.dtype),
         # the grid axis carries the online-softmax scratch from one
-        # work item of a lane to the next: it must run in order
+        # work item of a tile to the next: it must run in order
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_vmem_limit(block_bytes)),
         interpret=interpret,
         name="paged_ragged_v2",
-    )(page_tables, lane_slots, lane_lens, *args)
-    return out.reshape(t, h, d)
-
-
-def kv_read_bytes(lane_lens, lane_slots, page_tables, *, page_size: int,
-                  num_heads: int, head_dim: int, kv_itemsize: int,
-                  block_kv_pages: int = 1, quantized: bool = False) -> int:
-    """Bytes of K and V pages (and their scale rows on a quantized
-    pool) that ONE call of the kernel above fetches from HBM, for the
-    whole step's lanes (numpy; host side, no device work).
-
-    It mirrors `page_index`: page slot i of work item (lane t, block
-    blk) selects table column min(blk * bp + i, pp - 1, (len_t - 1) //
-    ps) of the lane's row, and Pallas's pipeline fetches a block only
-    when its index differs from the previous grid step's. So a lane
-    fetches each of its live columns once per slot — lanes of one chunk
-    each re-read their sequence's pages — dead tail items fetch
-    nothing, and a lane whose first page is the one the lane before it
-    ended on (the run of inactive lanes on the sink page) fetches
-    nothing for it either. Heads are counted whole: a tensor-parallel
-    step fetches the same bytes summed over its chips."""
-    ll = np.asarray(lane_lens, np.int64)
-    rows = np.asarray(page_tables)[np.asarray(lane_slots, np.int64)]
-    pp = rows.shape[1]
-    bp = max(1, min(int(block_kv_pages), pp))
-    nb = -(-pp // bp)
-    last = np.minimum(np.maximum((ll - 1) // page_size, 0), pp - 1)
-    lanes = np.arange(len(ll))
-    fetches = 0
-    for i in range(bp):
-        # columns blk * bp + i below the clamp are all distinct; the
-        # clamped ones (if any) repeat one column, `last`
-        below = np.clip(-(-(last - i) // bp), 0, nb)
-        fetches += int(np.sum(below + (below < nb)))
-        first = rows[lanes, np.minimum(i, last)]
-        end = rows[lanes, np.minimum((nb - 1) * bp + i, last)]
-        fetches -= int(np.sum(first[1:] == end[:-1]))
-    per_page = 2 * page_size * num_heads * head_dim * kv_itemsize
-    if quantized:
-        per_page += 2 * page_size * num_heads * 4       # f32 scale rows
-    return fetches * per_page
+    )(work.tile, work.blk, work.meta, work.pages, *args)
+    # (tile, slab, row, lane) -> (lane of the step, head, dim)
+    return out.transpose(0, 2, 1, 3).reshape(tiles * qb, h, d)[:t]
 
 
 # ------------------------------------------------------------ entry point
@@ -498,7 +839,8 @@ def resolve_paged_impl(use_pallas=None, interpret=False) -> str:
 def paged_attention_ragged_v2(q, k_pages, v_pages, page_tables,
                               lane_slots, lane_lens, *, k_scales=None,
                               v_scales=None, scale=None, block_kv=None,
-                              use_pallas=None, interpret=False):
+                              work=None, use_pallas=None,
+                              interpret=False):
     """Ragged batched attention through page tables — kernel v2.
 
     Same contract as flash_attention.paged_attention_ragged (q (T,H,D),
@@ -507,9 +849,13 @@ def paged_attention_ragged_v2(q, k_pages, v_pages, page_tables,
       k_scales/v_scales — (num_pages, page_size, H) f32 per-page scale
         arrays for int8 K/V pages (None = unquantized pages; the two
         must be both present or both absent).
-      block_kv — KV tokens per flattened work item (None = the
-        autotune-by-shape table via choose_block_kv; rounded to whole
-        pages).
+      block_kv — KV tokens per work item (None = the autotune-by-shape
+        table via choose_block_kv; rounded to whole pages).
+      work — the step's WorkList (`build_work_list` over these very
+        lane arrays, made once for all the calls that share them: its
+        kv-block shape is the one used); None builds one here, with
+        the grid bound that holds for any lane arrays (and takes the
+        lanes in several calls where one list would not fit SMEM).
 
     fp32 outputs are bit-identical to v1 on the jnp path (same math);
     the Pallas kernel agrees with it to f32 rounding (it sums in a
@@ -526,11 +872,28 @@ def paged_attention_ragged_v2(q, k_pages, v_pages, page_tables,
                            lane_lens, scale, k_scales=k_scales,
                            v_scales=v_scales)
     ps = k_pages.shape[1]
-    if block_kv is None:
-        block_kv = choose_block_kv(
-            ps, page_tables.shape[1], q.shape[1], q.shape[2],
-            jnp.dtype(k_pages.dtype).itemsize)
+    if work is None:
+        if block_kv is None:
+            block_kv = choose_block_kv(
+                ps, page_tables.shape[1], q.shape[1], q.shape[2],
+                jnp.dtype(k_pages.dtype).itemsize)
+        bp = max(1, min(int(block_kv) // ps, page_tables.shape[1]))
+        # the list lives in SMEM: with no bound from the caller, as
+        # many tiles of lanes a call as its budget holds
+        per_tile = max_work_items(Q_ROWS, page_tables.shape[1], bp) \
+            * (3 + bp)
+        step = max(1, SMEM_LIST_WORDS // per_tile) * Q_ROWS
+        if q.shape[0] > step:
+            return jnp.concatenate([
+                paged_attention_ragged_v2(
+                    q[a:a + step], k_pages, v_pages, page_tables,
+                    lane_slots[a:a + step], lane_lens[a:a + step],
+                    k_scales=k_scales, v_scales=v_scales, scale=scale,
+                    block_kv=block_kv, use_pallas=use_pallas,
+                    interpret=interpret)
+                for a in range(0, q.shape[0], step)], axis=0)
+        work = build_work_list(page_tables, lane_slots, lane_lens,
+                               page_size=ps, block_pages=bp)
     return _ragged_v2_pallas(
-        q, k_pages, v_pages, page_tables, lane_slots, lane_lens,
-        scale, max(1, int(block_kv) // ps), impl == PALLAS_INTERPRET,
+        q, k_pages, v_pages, work, scale, impl == PALLAS_INTERPRET,
         k_scales=k_scales, v_scales=v_scales)

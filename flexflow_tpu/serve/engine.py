@@ -61,11 +61,12 @@ import numpy as np
 from ..config import CompMode
 from ..kernels.flash_attention import (paged_attention_decode,
                                        paged_attention_ragged)
-from ..kernels.paged_ragged_v2 import (JNP, PALLAS_INTERPRET,
-                                       choose_block_kv, kv_read_bytes,
+from ..kernels.paged_ragged_v2 import (JNP, PALLAS_INTERPRET, Q_ROWS,
+                                       build_work_list, choose_block_kv,
+                                       kv_page_bytes, max_work_items,
                                        quantize_kv_rows,
                                        ragged_dispatch_passes,
-                                       resolve_paged_impl)
+                                       resolve_paged_impl, work_items)
 from ..parallel.mesh import TENSOR, replica_devices, serve_tensor_mesh
 from ..utils.faults import FaultInjector, TransientError, injector_for
 from ..utils.telemetry import (Telemetry, pow2_bucket, serve_metrics,
@@ -395,6 +396,23 @@ class ServeEngine:
         # the one mixed-step geometry: every prefill-budget token plus
         # one decode lane per slot always fits
         self.mixed_width = self.prefill_budget + self.cache_cfg.max_seqs
+        # the paged kernel's grid: the most work items a plan can make
+        # (kernels/paged_ragged_v2.max_work_items). PROOF of the slot
+        # changes: _pack lays a plan's chunks one after another, each
+        # chunk (with its draft tokens) in consecutive lanes of ONE
+        # slot, then the inactive lanes on slot 0; a plan holds at most
+        # one chunk per running request (Scheduler.schedule: one per
+        # entry of `running`, one per admission, each with a slot of
+        # its own), so at most max_seqs chunks; the slot changes from
+        # a lane to the next only where a chunk ends: at most max_seqs
+        # times. _pack checks every plan against the bound and raises
+        # (tests/test_paged_work_list.py drives a busy session at it).
+        self.attn_block_pages = max(
+            1, self.attn_block_kv // self.cache_cfg.page_size)
+        self.attn_max_items = max_work_items(
+            self.mixed_width, self.cache_cfg.pages_per_seq,
+            self.attn_block_pages, Q_ROWS,
+            slot_changes=self.cache_cfg.max_seqs)
         self.topk_cap = min(self.TOPK_CAP, self.vocab_size)
         # persistent across generate() calls: the prefix cache only
         # pays off if committed pages outlive the batch that wrote them
@@ -1300,13 +1318,23 @@ class ServeEngine:
                 ad = {key: jnp.take(arr, lane_adapters, axis=0)
                       for key, arr in adapters.items() if key != "scale"}
                 ad_s = jnp.take(adapters["scale"], lane_adapters, axis=0)
+        # the paged kernel's work list: from the lane arrays, once for
+        # all the layers (the jnp attention reads the lane arrays)
+        work = None
+        if self.attn_impl != JNP:
+            with scope("work_list"):
+                work = build_work_list(
+                    page_tables, lane_slots, lane_lens,
+                    page_size=self.cache_cfg.page_size,
+                    block_pages=self.attn_block_pages,
+                    max_items=self.attn_max_items)
         for i in range(self.num_layers):
             with scope(f"layer{i}"):
                 x, k_pages, v_pages, k_scales, v_scales = \
                     self._mixed_layer(
                         params, i, x, k_pages, v_pages, k_scales,
                         v_scales, write_pages, write_offs, page_tables,
-                        lane_slots, lane_lens, scale,
+                        lane_slots, lane_lens, work, scale,
                         None if ad is None else
                         {key: arr[:, i] for key, arr in ad.items()},
                         ad_s, tp_axis)
@@ -1323,11 +1351,13 @@ class ServeEngine:
 
     def _mixed_layer(self, params, i, x, k_pages, v_pages, k_scales,
                      v_scales, write_pages, write_offs, page_tables,
-                     lane_slots, lane_lens, scale, la, ad_s, tp_axis):
+                     lane_slots, lane_lens, work, scale, la, ad_s,
+                     tp_axis):
         """Layer `i` of the mixed step, one named scope per phase:
         `ln`, `qkv`, `kv_write` (quantize and scatter into the pools),
-        `attn` (the ragged paged kernel), `attn_out`, `ffn`. `la` is
-        the lanes' adapter rows of this layer (None: no adapters)."""
+        `attn` (the ragged paged kernel over the step's `work` list),
+        `attn_out`, `ffn`. `la` is the lanes' adapter rows of this
+        layer (None: no adapters)."""
         scope = jax.named_scope
         quantized = k_scales is not None
         p = params[f"layer{i}_attn"]
@@ -1359,7 +1389,7 @@ class ServeEngine:
                 lane_lens, scale=scale, **self._attn_kw,
                 k_scales=k_scales[i] if quantized else None,
                 v_scales=v_scales[i] if quantized else None,
-                block_kv=self.attn_block_kv)
+                block_kv=self.attn_block_kv, work=work)
         with scope("attn_out"):
             x = self._attn_out(
                 p, o, x, psum_axis=tp_axis,
@@ -2932,7 +2962,8 @@ class ServeEngine:
                 "attn_dispatch_passes": {
                     k: v * steps for k, v in ragged_dispatch_passes(
                         self.mixed_width, c.pages_per_seq,
-                        max(1, self.attn_block_kv // c.page_size)
+                        self.attn_block_pages, Q_ROWS,
+                        slot_changes=c.max_seqs
                     ).items()} if self.chunked_prefill else None,
             },
             # hierarchical host tier (None unarmed): the shared
@@ -3154,14 +3185,17 @@ class StepEvents:
     the requests that completed THIS step, ``ctx_mean`` the mean
     decode-context length (the drift calibrator's pricing regime),
     ``kv_bytes_read`` the K/V page bytes the step's attention kernel
-    calls fetch, ``dispatched`` False for a planning-only iteration (rung-4
+    calls fetch, ``attn_items`` / ``attn_rows`` the work items ONE of
+    those calls runs for the live lanes and the query rows they hold
+    (rows / items: how often lanes share an item), ``dispatched``
+    False for a planning-only iteration (rung-4
     rejections / whole-set preemption under injected pressure — the
     scheduler's forced-progress rule guarantees re-planning
     converges)."""
 
     __slots__ = ("dispatched", "step_index", "plan", "emitted",
                  "finished", "ctx_mean", "wall_s", "host_reload_s",
-                 "kv_bytes_read")
+                 "kv_bytes_read", "attn_items", "attn_rows")
 
     def __init__(self, plan=None):
         self.dispatched = False
@@ -3176,8 +3210,11 @@ class StepEvents:
         # it inside the step wall time naturally)
         self.host_reload_s = 0.0
         # K/V page (and scale) bytes the paged kernel fetches in this
-        # step, all layers (kernels/paged_ragged_v2.kv_read_bytes)
+        # step, all layers, and one call's live work items and their
+        # query rows (kernels/paged_ragged_v2.work_items)
         self.kv_bytes_read = 0
+        self.attn_items = 0
+        self.attn_rows = 0
 
 
 class ServeSession:
@@ -3345,7 +3382,8 @@ class ServeSession:
         arrays (mixed_width wide; inactive lanes aim at the sink page
         with a visible length of 1). -> (arrays in dispatch order,
         lane_adapters or None, live lanes, emitters, spec_emitters,
-        the K/V bytes the paged kernel fetches for these lanes)."""
+        the paged kernel's work for these lanes: `work_items` of one
+        call plus `kv_bytes`, what all layers' calls fetch)."""
         eng = self.eng
         cache = eng.cache
         t_w = eng.mixed_width
@@ -3395,17 +3433,23 @@ class ServeSession:
             f"scheduler packed {lane} lanes into a {t_w}-lane step")
         arrays = (tokens, positions, write_pages, write_offs,
                   cache.page_tables, lane_slots, lane_lens)
-        # what the paged kernel will fetch for these lanes, all layers
-        # (the count is made where the lanes are made)
+        # what the paged kernel will do for these lanes (the count is
+        # made where the lanes are made), and the proof's check: a plan
+        # whose items passed the grid's bound would lose work
         c = eng.cache_cfg
-        kv_bytes = eng.num_layers * kv_read_bytes(
+        work = work_items(
             lane_lens, lane_slots, cache.page_tables, page_size=ps,
-            num_heads=eng.num_heads, head_dim=eng.head_dim,
-            kv_itemsize=c.kv_itemsize,
-            block_kv_pages=eng.attn_block_kv // ps,
-            quantized=eng.kv_quantized)
-        return (arrays, lane_adapters, lane, emitters, spec_emitters,
-                kv_bytes)
+            block_kv_pages=eng.attn_block_pages,
+            max_items=eng.attn_max_items, live_lanes=lane)
+        if work["total"] > work["grid"]:
+            raise RuntimeError(
+                f"the plan makes {work['total']} attention work items, "
+                f"the kernel's grid holds {work['grid']}")
+        work["kv_bytes"] = (
+            eng.num_layers * work["page_fetches"] * kv_page_bytes(
+                ps, eng.num_heads, eng.head_dim, c.kv_itemsize,
+                eng.kv_quantized))
+        return arrays, lane_adapters, lane, emitters, spec_emitters, work
 
     def step(self) -> Optional[StepEvents]:
         """Advance one engine step. Returns None when the session is
@@ -3449,7 +3493,9 @@ class ServeSession:
             return ev
         with timed(track, "pack"):
             (arrays, lane_adapters, lane, emitters, spec_emitters,
-             ev.kv_bytes_read) = self._pack(plan)
+             work) = self._pack(plan)
+            ev.kv_bytes_read = work["kv_bytes"]
+            ev.attn_items, ev.attn_rows = work["items"], work["rows"]
         with timed(track, "drain"):
             # land any adapters this plan admitted BEFORE their lanes
             # dispatch — the planning-visible load stall, not a
@@ -3468,7 +3514,8 @@ class ServeSession:
                 "step": step_idx, "live": lane,
                 "prefill": plan.num_prefill_lanes,
                 "decode": plan.num_decode_lanes,
-                "kv_bytes": ev.kv_bytes_read}):
+                "kv_bytes": ev.kv_bytes_read,
+                "items": ev.attn_items, "rows": ev.attn_rows}):
             greedy, topv, topi, _, _ = eng._dispatch_mixed(
                 eng._k_pages, eng._v_pages, *dev,
                 lane_adapters=dev_adapters)

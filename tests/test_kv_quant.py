@@ -31,6 +31,7 @@ from flexflow_tpu.kernels.flash_attention import (
     paged_attention_ragged_v1,
 )
 from flexflow_tpu.kernels.paged_ragged_v2 import (
+    Q_ROWS,
     _BLOCK_KV_TABLE,
     choose_block_kv,
     dequantize_kv,
@@ -111,7 +112,7 @@ def test_ragged_v2_jnp_bit_identical_to_v1(seed):
 
 @pytest.mark.parametrize("block_kv", [4, 8, 12, 24])
 def test_ragged_v2_pallas_interpret_matches_jnp(block_kv):
-    """The flattened-grid Pallas kernel agrees with the fallback at f32
+    """The work-list Pallas kernel agrees with the fallback at f32
     tolerance for every kv-block shape (whole pages, ragged tails,
     whole-table blocks)."""
     q, kp, vp, table, slots, lens = _ragged_setup(3, 60)
@@ -218,8 +219,14 @@ def test_choose_block_kv_table_and_dispatch_accounting():
         assert choose_block_kv(16, 16, 8, 64, 4) == 48
     finally:
         _BLOCK_KV_TABLE.pop((16, 8, 64, 4, 16), None)
-    passes = ragged_dispatch_passes(24, 16, 4)
-    assert passes == {"v1": 24 * 16, "v2": 24 * 4}
+    # v2's grid: a run of lanes per (tile, slot change), one item per
+    # kv-block of four pages — a lane its own run when the caller
+    # bounds no slot changes
+    tiles = -(-24 // Q_ROWS)
+    assert ragged_dispatch_passes(24, 16, 4) == {
+        "v1": 24 * 16, "v2": tiles * Q_ROWS * 4}
+    assert ragged_dispatch_passes(24, 16, 4, slot_changes=3) == {
+        "v1": 24 * 16, "v2": (tiles + 3) * 4}
 
 
 # ------------------------------------------------------- engine parity
@@ -398,6 +405,8 @@ def test_kv_pool_stats_and_serve_report_line():
     assert pool["kv_dtype"] == "int8" and not pool["kv_exact"]
     dp = pool["attn_dispatch_passes"]
     assert dp["v1"] > dp["v2"] > 0
+    # v2 is the grid the kernel runs: the engine's bound, every step
+    assert dp["v2"] % eng.attn_max_items == 0
     report = serve_report(eng.last_stats)
     assert "kv pool: int8 pages" in report
     assert "ragged kernel v2" in report

@@ -1,0 +1,348 @@
+"""The paged kernel's work decomposition (kernels/paged_ragged_v2.py).
+
+  * the LIST against a brute-force walk: every (lane, live kv-block)
+    is covered by exactly one item, a tile's first and last items carry
+    the flags that start and emit its online softmax, a live page slot
+    names the page table's page, the entries past the end repeat the
+    last item and do nothing; an idle step is one item a tile; the
+    bound `max_work_items` is reached by the worst arrays it admits and
+    never passed by a plan the scheduler makes.
+  * the KERNEL (Pallas interpreter) against the jnp twin over the lane
+    layouts the engine packs — decode lanes with an inactive tail, a
+    chunk crossing kv-blocks and a tile boundary, a chunk with draft
+    lanes, sequences in neighbouring lanes, lanes of one slot whose
+    lengths do not rise by one — for blocks of 1, 2 and 8 pages, f32 /
+    int8 / fp8 pages and H*D of 256 and 512, at the tolerances
+    tests/test_kv_quant.py states (2e-6 for a format against its own
+    jnp path).
+"""
+
+import numpy as np
+import pytest
+
+import jax.numpy as jnp
+
+from flexflow_tpu.kernels.flash_attention import paged_attention_ragged
+from flexflow_tpu.kernels.paged_ragged_v2 import (_FIRST, _LAST, _LIVE,
+                                                  Q_ROWS, build_work_list,
+                                                  max_work_items,
+                                                  quantize_kv_rows,
+                                                  work_items)
+
+PS = 4          # page size
+PP = 20         # table columns: sequences of up to 80 tokens
+SEQS = 6
+
+
+def _table(rng):
+    pt = np.zeros((SEQS, PP), np.int32)
+    pt[:] = 1 + rng.permutation(SEQS * PP).reshape(SEQS, PP)
+    return pt
+
+
+# ----------------------------------------------------------- lane layouts
+def _decode_tail():
+    """Five decode lanes of five sequences, then inactive lanes."""
+    lens = [9, 33, 1, 80, 17]
+    return list(range(1, 6)), lens, 3 * Q_ROWS
+
+
+def _chunk_across_tiles():
+    """One chunk of sequence 2 at positions 30..30+Q_ROWS+9: several
+    kv-blocks, and it crosses a tile boundary."""
+    n = Q_ROWS + 10
+    return [2] * n, list(range(31, 31 + n)), 2 * Q_ROWS
+
+
+def _chunk_and_drafts():
+    """A chunk of sequence 1, a decode lane of sequence 3 with four
+    draft lanes after it, a decode lane of sequence 4."""
+    slots = [1] * 11 + [3] * 5 + [4]
+    lens = list(range(20, 31)) + list(range(41, 46)) + [7]
+    return slots, lens, 2 * Q_ROWS
+
+
+def _neighbours():
+    """Two sequences lane by lane, then back to the first."""
+    return [1, 2, 1, 2, 2, 1], [5, 9, 6, 10, 11, 7], Q_ROWS
+
+
+def _not_rising():
+    """Lanes of one slot with equal, falling and jumping lengths, and
+    a live lane of slot 0 next to the inactive lanes of slot 0."""
+    slots = [5] * 7 + [0] * 2
+    lens = [40, 40, 13, 77, 2, 2, 50, 30, 1]
+    return slots, lens, Q_ROWS + 3          # not a whole tile
+
+
+LAYOUTS = {"decode_tail": _decode_tail, "chunk_across_tiles":
+           _chunk_across_tiles, "chunk_and_drafts": _chunk_and_drafts,
+           "neighbours": _neighbours, "not_rising": _not_rising}
+
+
+def _lanes(name):
+    slots, lens, width = LAYOUTS[name]()
+    live = len(slots)
+    slots = np.array(slots + [0] * (width - live), np.int32)
+    lens = np.array(lens + [1] * (width - live), np.int32)
+    changes = int(np.sum(slots[1:] != slots[:-1]))
+    return slots, lens, live, changes
+
+
+# ------------------------------------------------------- the list itself
+def _brute_items(lens, slots, pt, bp, qb):
+    """Tile by tile, run by run, block by block: (tile, lo, hi, blk,
+    [the page a slot must hold, None where it sees nothing])."""
+    pad = -len(lens) % qb
+    lens = list(lens) + [1] * pad
+    slots = list(slots) + [0] * pad
+    items = []
+    for tile in range(len(lens) // qb):
+        lo = 0
+        while lo < qb:
+            hi = lo + 1
+            while hi < qb and slots[tile * qb + hi] == slots[tile * qb + lo]:
+                hi += 1
+            longest = max(lens[tile * qb + lo:tile * qb + hi])
+            for blk in range(-(-longest // (bp * PS))):
+                cols = [blk * bp + i for i in range(bp)]
+                items.append((tile, lo, hi, blk, [
+                    int(pt[slots[tile * qb + lo], c])
+                    if c * PS < longest else None for c in cols]))
+            lo = hi
+    return items
+
+
+@pytest.mark.parametrize("bp", [1, 2, 8])
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_work_list_equals_a_brute_force_walk(layout, bp):
+    slots, lens, live, changes = _lanes(layout)
+    pt = _table(np.random.RandomState(bp))
+    bound = max_work_items(len(slots), PP, bp, Q_ROWS, changes)
+    work = build_work_list(jnp.asarray(pt), jnp.asarray(slots),
+                           jnp.asarray(lens), page_size=PS,
+                           block_pages=bp, max_items=bound)
+    tile, blk, meta = (np.asarray(x) for x in (work.tile, work.blk,
+                                               work.meta))
+    pages = np.asarray(work.pages).reshape(-1, bp)
+    assert len(tile) == len(blk) == len(meta) == len(pages) == bound + 1
+    want = _brute_items(lens, slots, pt, bp, Q_ROWS)
+    assert len(want) <= bound
+    held = [0] * bp
+    for w, (t, lo, hi, b, pg) in enumerate(want):
+        assert (tile[w], blk[w]) == (t, b)
+        assert (meta[w] & 0xFF, (meta[w] >> 8) & 0xFF) == (lo, hi)
+        assert meta[w] & _LIVE
+        # the tile's first item starts its softmax, its last emits it
+        assert bool(meta[w] & _FIRST) == (lo == 0 and b == 0)
+        assert bool(meta[w] & _LAST) == (
+            w + 1 == len(want) or want[w + 1][0] != t)
+        # a live slot names its page; a dead one keeps what it held
+        held = [h if p is None else p for h, p in zip(held, pg)]
+        assert list(pages[w]) == held
+    # past the end: the last item again, every flag clear
+    for w in range(len(want), bound + 1):
+        assert (tile[w], blk[w]) == (want[-1][0], want[-1][3])
+        assert meta[w] & (_FIRST | _LAST | _LIVE) == 0
+        assert list(pages[w]) == held
+    # every (lane, live block) lies in exactly one item
+    seen = {}
+    for t, lo, hi, b, _ in want:
+        for r in range(lo, hi):
+            lane = t * Q_ROWS + r
+            if lane < len(lens) and b * bp * PS < lens[lane]:
+                seen[(lane, b)] = seen.get((lane, b), 0) + 1
+    assert seen == {(lane, b): 1 for lane in range(len(lens))
+                    for b in range(-(-int(lens[lane]) // (bp * PS)))}
+    # the host's counters walk the same list
+    got = work_items(lens, slots, pt, page_size=PS, block_kv_pages=bp,
+                     max_items=bound, live_lanes=live)
+    mine = [(t, lo, hi) for t, lo, hi, _, _ in want
+            if t * Q_ROWS + lo < live]
+    assert got["grid"] == bound and got["total"] == len(want)
+    assert got["items"] == len(mine)
+    assert got["rows"] == sum(min(hi, live - t * Q_ROWS) - lo
+                              for t, lo, hi in mine)
+
+
+def test_rows_per_item_says_how_often_lanes_share():
+    pt = _table(np.random.RandomState(0))
+    slots, lens, live, _ = _lanes("decode_tail")
+    got = work_items(lens, slots, pt, page_size=PS, block_kv_pages=2,
+                     live_lanes=live)
+    assert got["rows"] == got["items"] > live      # one row an item
+    slots, lens, live, _ = _lanes("chunk_across_tiles")
+    got = work_items(lens, slots, pt, page_size=PS, block_kv_pages=2,
+                     live_lanes=live)
+    assert got["rows"] / got["items"] > Q_ROWS / 2
+
+
+def test_an_idle_step_is_one_item_a_tile_on_the_sink_page():
+    pt = np.zeros((SEQS, PP), np.int32)
+    width = 3 * Q_ROWS
+    got = work_items(np.ones(width, np.int32), np.zeros(width, np.int32),
+                     pt, page_size=PS, block_kv_pages=2, live_lanes=0)
+    assert got["total"] == 3 and got["page_fetches"] == 2
+    assert got["items"] == got["rows"] == 0
+
+
+@pytest.mark.parametrize("bp", [1, 3, 8])
+def test_the_bound_is_reached_and_not_passed(bp):
+    """`max_work_items` for lanes whose slot changes `c` times: the
+    arrays that change slot c times, away from the tile boundaries,
+    with every lane at the full table, make exactly that many items;
+    and with no bound given every lane may be a run of its own."""
+    pt = _table(np.random.RandomState(1))
+    width, c = 2 * Q_ROWS, 5
+    slots = np.zeros(width, np.int32)
+    for i, at in enumerate((2, 5, 7, Q_ROWS + 1, Q_ROWS + 4)):
+        slots[at:] = i + 1
+    lens = np.full(width, PP * PS, np.int32)
+    nb = -(-PP // bp)
+    bound = max_work_items(width, PP, bp, Q_ROWS, c)
+    assert bound == (2 + c) * nb
+    assert work_items(lens, slots, pt, page_size=PS,
+                      block_kv_pages=bp)["total"] == bound
+    every = (np.arange(width) % SEQS).astype(np.int32)
+    free = max_work_items(width, PP, bp, Q_ROWS)
+    assert free == width * nb == work_items(
+        lens, every, pt, page_size=PS, block_kv_pages=bp)["total"]
+
+
+def test_no_plan_passes_the_engine_s_bound():
+    """A session whose plans mix chunks, decode lanes and draft lanes
+    over every slot: `_pack` checks each plan's items against
+    `attn_max_items` (it raises if one passes), and the slot changes
+    the bound's proof counts stay at or under max_seqs."""
+    from flexflow_tpu.config import FFConfig
+    from flexflow_tpu.models.transformer import build_transformer_lm
+    from flexflow_tpu.serve import ServeEngine
+    from flexflow_tpu.serve.engine import ServeSession
+
+    cfg = FFConfig(batch_size=1, kv_page_size=4, kv_num_pages=65,
+                   serve_max_seqs=4, serve_prefill_budget=12,
+                   serve_spec_decode=True)
+    lm = build_transformer_lm(cfg, vocab_size=61, max_seq_len=64,
+                              hidden=32, num_heads=4, num_layers=1,
+                              ff_dim=64)
+    eng = ServeEngine(lm, use_pallas=False)
+    seen = []
+    pack = ServeSession._pack
+
+    def spy(self, plan):
+        out = pack(self, plan)
+        slots = out[0][5]
+        seen.append((out[-1], int(np.sum(slots[1:] != slots[:-1]))))
+        return out
+
+    ServeSession._pack = spy
+    try:
+        rng = np.random.RandomState(3)
+        # repeated tokens: the prompt-lookup drafter finds drafts
+        prompts = [list(rng.randint(1, 5, size=n))
+                   for n in (30, 3, 17, 40, 9, 25, 2, 33)]
+        eng.generate(prompts, 12)
+    finally:
+        ServeSession._pack = pack
+    assert len(seen) > 10
+    assert max(w["total"] for w, _ in seen) <= eng.attn_max_items
+    assert max(c for _, c in seen) <= eng.cache_cfg.max_seqs
+    assert any(w["rows"] > w["items"] for w, _ in seen)     # a chunk
+    assert any(w["rows"] == w["items"] > 0 for w, _ in seen)  # decodes
+
+
+# -------------------------------------------- the kernel on those layouts
+def _pools(rng, h, d, fmt):
+    num_pages = 1 + SEQS * PP
+    kp = rng.randn(num_pages, PS, h, d).astype(np.float32)
+    vp = rng.randn(num_pages, PS, h, d).astype(np.float32)
+    kp[0] = vp[0] = 0.0                   # the sink page
+    kp, vp = jnp.asarray(kp), jnp.asarray(vp)
+    if fmt == "float32":
+        return kp, vp, {}
+    dtype = jnp.int8 if fmt == "int8" else jnp.float8_e4m3fn
+    kq, ks = quantize_kv_rows(kp, dtype)
+    vq, vs = quantize_kv_rows(vp, dtype)
+    return kq, vq, {"k_scales": ks, "v_scales": vs}
+
+
+@pytest.mark.parametrize("fmt,h,d,bp", [
+    ("float32", 4, 64, 1), ("float32", 4, 64, 2), ("float32", 4, 64, 8),
+    ("float32", 8, 64, 2), ("float32", 4, 128, 2),
+    ("int8", 4, 64, 2), ("int8", 8, 64, 8),
+    ("float8_e4m3", 4, 64, 2), ("float8_e4m3", 4, 128, 1)])
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_kernel_matches_the_jnp_twin_on_every_layout(layout, fmt, h, d,
+                                                     bp):
+    slots, lens, live, changes = _lanes(layout)
+    rng = np.random.RandomState(len(layout) + h + d + bp)
+    pt = jnp.asarray(_table(rng))
+    kp, vp, scales = _pools(rng, h, d, fmt)
+    q = jnp.asarray(rng.randn(len(slots), h, d).astype(np.float32))
+    slots, lens = jnp.asarray(slots), jnp.asarray(lens)
+    ref = paged_attention_ragged(q, kp, vp, pt, slots, lens,
+                                 use_pallas=False, **scales)
+    # once on the caller's proven bound, once on the kernel's own
+    work = build_work_list(
+        pt, slots, lens, page_size=PS, block_pages=bp,
+        max_items=max_work_items(len(slots), PP, bp, Q_ROWS, changes))
+    for kw in ({"work": work}, {"block_kv": bp * PS}):
+        if "block_kv" in kw and (bp == 1 or fmt != "float32"):
+            continue              # the long grid, interpreted: f32 only
+        out = np.asarray(paged_attention_ragged(
+            q, kp, vp, pt, slots, lens, interpret=True, **kw, **scales))
+        assert np.isfinite(out).all()   # the inactive lanes' rows too
+        np.testing.assert_allclose(out, np.asarray(ref), rtol=2e-6,
+                                   atol=2e-6)
+
+
+def test_a_list_too_long_for_smem_is_split_by_lanes(monkeypatch):
+    """With no bound from the caller the list is one run a lane; where
+    that would pass the SMEM budget the lanes go in several calls, and
+    the answer is the same."""
+    from flexflow_tpu.kernels import paged_ragged_v2 as k
+    rng = np.random.RandomState(9)
+    pt = jnp.asarray(_table(rng))
+    kp, vp, _ = _pools(rng, 4, 64, "float32")
+    lanes = 3 * Q_ROWS + 5
+    slots = jnp.asarray(rng.randint(0, SEQS, size=lanes).astype(np.int32))
+    lens = jnp.asarray(rng.randint(1, PP * PS + 1,
+                                   size=lanes).astype(np.int32))
+    q = jnp.asarray(rng.randn(lanes, 4, 64).astype(np.float32))
+    ref = paged_attention_ragged(q, kp, vp, pt, slots, lens,
+                                 use_pallas=False)
+    calls = []
+    real = k._ragged_v2_pallas
+    monkeypatch.setattr(k, "_ragged_v2_pallas",
+                        lambda q, *a, **kw: calls.append(q.shape[0])
+                        or real(q, *a, **kw))
+    # one tile of lanes a call: 32 lanes x 3 blocks x (3 + 8) words
+    monkeypatch.setattr(k, "SMEM_LIST_WORDS",
+                        k.max_work_items(Q_ROWS, PP, 8) * 11)
+    out = paged_attention_ragged(q, kp, vp, pt, slots, lens,
+                                 interpret=True, block_kv=8 * PS)
+    assert calls == [Q_ROWS, Q_ROWS, Q_ROWS, 5]
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-6, atol=2e-6)
+
+
+@pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+def test_the_sharded_engine_builds_its_list_inside_shard_map(kv_dtype):
+    """tensor_parallel=2 with the interpreted kernel: the work list is
+    made per device inside shard_map, each device's call runs its own
+    heads over it, and the tokens equal the jnp engine's."""
+    from flexflow_tpu.config import FFConfig
+    from flexflow_tpu.models.transformer import build_transformer_lm
+    from flexflow_tpu.serve import ServeEngine
+
+    cfg = FFConfig(batch_size=1, kv_page_size=4, kv_num_pages=65,
+                   kv_dtype=kv_dtype, serve_max_seqs=4,
+                   serve_prefill_budget=32, serve_spec_decode=True)
+    lm = build_transformer_lm(cfg, vocab_size=61, max_seq_len=64,
+                              hidden=32, num_heads=4, num_layers=2,
+                              ff_dim=72)
+    rng = np.random.RandomState(0)
+    prompts = [list(rng.randint(1, 61, size=n)) for n in (5, 20, 27, 9, 3)]
+    ref = ServeEngine(lm, use_pallas=False).generate(prompts, 6)
+    eng = ServeEngine(lm, tensor_parallel=2, interpret=True)
+    assert eng.generate(prompts, 6) == ref

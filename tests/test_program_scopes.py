@@ -10,8 +10,9 @@ docs/observability.md "Device scopes" / "Phase spans"):
     recompiles;
   * counters — `Request.t_admit` is stamped at the admission with
     telemetry off, before the admitting step dispatches;
-    `kv_read_bytes` equals a brute-force walk of the kernel's index
-    maps; `StepEvents.kv_bytes_read` carries it;
+    `kv_read_bytes` and `work_items` equal a brute-force walk of the
+    kernel's index maps; `StepEvents` and the `dispatch` span carry
+    them;
   * stores — a program store written before the scopes existed is
     refused.
 """
@@ -24,7 +25,8 @@ import numpy as np
 import pytest
 
 from flexflow_tpu.config import FFConfig
-from flexflow_tpu.kernels.paged_ragged_v2 import kv_read_bytes
+from flexflow_tpu.kernels.paged_ragged_v2 import (Q_ROWS, kv_read_bytes,
+                                                  work_items)
 from flexflow_tpu.serve import ServeEngine
 from flexflow_tpu.utils import telemetry as T
 
@@ -176,6 +178,8 @@ def test_serve_phase_spans_on_off_identical_zero_recompiles(lm):
     for i, e in enumerate(disp):
         a = e[6]
         assert a["step"] == i and a["kv_bytes"] > 0
+        # the kernel's live work items and the query rows they hold
+        assert a["rows"] >= a["items"] >= 1
         # speculation's draft lanes are live beside the two kinds
         assert a["live"] >= a["prefill"] + a["decode"] > 0
     # every phase lies inside its step's `serve_step` span
@@ -274,6 +278,11 @@ def test_t_admit_is_stamped_at_admission_with_telemetry_off(lm):
             for r in ev.plan.admitted:
                 first_step_of.setdefault(r.rid, n)
             assert ev.kv_bytes_read > 0 if ev.dispatched else True
+            if ev.dispatched:
+                assert ev.attn_rows >= ev.attn_items >= 1
+                if ev.plan.num_prefill_lanes == 0:
+                    # a decode-only step: one row an item
+                    assert ev.attn_rows == ev.attn_items
     assert len(first_step_of) == len(reqs) and len(seen) > 1
     assert len(set(first_step_of.values())) > 1     # not all at once
     for r in reqs:
@@ -310,27 +319,48 @@ def test_queue_wait_span_ends_at_the_admission(lm):
         assert first in steps
 
 
-def _walk_index_maps(lane_lens, lane_slots, page_tables, ps, bp, bytes_k,
-                     bytes_s):
-    """The kernel's grid, one work item at a time, as `page_index`
-    writes it: a block is fetched when its index differs from the one
-    the same operand held at the previous grid step."""
-    t, pp = len(lane_lens), page_tables.shape[1]
-    bp = max(1, min(bp, pp))
-    nb = -(-pp // bp)
-    total = 0
-    held = [None] * bp
-    for w in range(t * nb):
-        tt = min(w // nb, t - 1)
-        for i in range(bp):
-            col = (w % nb) * bp + i
-            live_last = max((int(lane_lens[tt]) - 1) // ps, 0)
-            col = min(min(col, pp - 1), live_last)
-            page = int(page_tables[lane_slots[tt], col])
-            if page != held[i]:
-                total += 2 * bytes_k + 2 * bytes_s     # K and V
-                held[i] = page
-    return total
+def _walk_index_maps(lane_lens, lane_slots, page_tables, ps, bp, qb,
+                     bytes_k, bytes_s):
+    """The kernel's grid, one work item at a time, as the work list
+    lays it out (kernels/paged_ragged_v2.py "work list"): tile by tile,
+    run by run (consecutive lanes of a tile that name one slot), one
+    item per kv-block below the run's longest lane. Page slot i of an
+    item is one pipelined operand: its block is the page at table
+    column blk * bp + i while that page holds a position the run sees,
+    else the page the slot held before — and it is fetched when that
+    differs from what the operand held at the previous grid step (at
+    the first step, always). -> (bytes, items, rows)."""
+    pad = -len(lane_lens) % qb
+    lens = list(lane_lens) + [1] * pad
+    slots = list(lane_slots) + [0] * pad
+    total = items = rows = 0
+    held = None
+    for tile in range(len(lens) // qb):
+        lo = 0
+        while lo < qb:
+            hi = lo + 1
+            while hi < qb and slots[tile * qb + hi] == slots[tile * qb + lo]:
+                hi += 1
+            longest = max(lens[tile * qb + lo:tile * qb + hi])
+            for blk in range(-(-longest // (bp * ps))):
+                now = []
+                for i in range(bp):
+                    col = blk * bp + i
+                    if col * ps < longest:
+                        now.append(int(page_tables[slots[tile * qb + lo],
+                                                   col]))
+                    else:
+                        now.append(held[i] if held else 0)
+                for i in range(bp):
+                    if held is None or now[i] != held[i]:
+                        total += 2 * bytes_k + 2 * bytes_s     # K and V
+                held = now
+                items += 1
+                # the lanes past the arrays' end pad the last tile
+                rows += max(0, min(tile * qb + hi, len(lane_lens))
+                            - (tile * qb + lo))
+            lo = hi
+    return total, items, rows
 
 
 @pytest.mark.parametrize("itemsize,quantized", [(4, False), (2, False),
@@ -348,23 +378,36 @@ def test_kv_read_bytes_equals_a_walk_of_the_index_maps(itemsize,
     # one-token lane, then inactive lanes on the sink
     lens = list(range(6, 13)) + [17, pp * ps, 1] + [1, 1, 1]
     slots = [1] * 7 + [2, 3, 4] + [0, 0, 0]
-    got = kv_read_bytes(np.array(lens), np.array(slots), pt,
-                        page_size=ps, num_heads=h, head_dim=d,
-                        kv_itemsize=itemsize, block_kv_pages=bp,
-                        quantized=quantized)
-    want = _walk_index_maps(lens, slots, pt, ps, bp,
-                            ps * h * d * itemsize,
-                            ps * h * 4 if quantized else 0)
-    assert got == want > 0
-    # the chunk's lanes each re-read their sequence's pages: seven
-    # lanes over 2-3 live pages cost more than the pages themselves
+    page = 2 * ps * h * d * itemsize + (2 * ps * h * 4 if quantized
+                                        else 0)
+    for qb in (Q_ROWS, 4):      # one tile; tiles that cut the chunk
+        got = kv_read_bytes(np.array(lens), np.array(slots), pt,
+                            page_size=ps, num_heads=h, head_dim=d,
+                            kv_itemsize=itemsize, block_kv_pages=bp,
+                            quantized=quantized, q_rows=qb)
+        want, items, rows = _walk_index_maps(
+            lens, slots, pt, ps, bp, qb, ps * h * d * itemsize,
+            ps * h * 4 if quantized else 0)
+        assert got == want > 0
+        work = work_items(np.array(lens), np.array(slots), pt,
+                          page_size=ps, block_kv_pages=bp, q_rows=qb)
+        assert (work["total"], work["rows"]) == (items, rows)
+        assert work["page_fetches"] * page == got
+    # the chunk's seven lanes SHARE their fetches: in one tile they
+    # read the sequence's three live pages once, not once a lane, and
+    # the tile's padding lanes read the sink page (in blocks of one
+    # page nothing is fetched twice, nothing dead at all)
     one = kv_read_bytes(np.array(lens[:7]), np.array(slots[:7]), pt,
                         page_size=ps, num_heads=h, head_dim=d,
                         kv_itemsize=itemsize, block_kv_pages=1,
                         quantized=quantized)
-    page = 2 * ps * h * d * itemsize + (2 * ps * h * 4 if quantized
-                                        else 0)
-    assert one == sum(-(-n // ps) for n in lens[:7]) * page > 3 * page
+    assert one == (-(-max(lens[:7]) // ps) + 1) * page == 4 * page
+    # tiles of four cut the chunk in two runs: the second fetches again
+    two = kv_read_bytes(np.array(lens[:7]), np.array(slots[:7]), pt,
+                        page_size=ps, num_heads=h, head_dim=d,
+                        kv_itemsize=itemsize, block_kv_pages=1,
+                        quantized=quantized, q_rows=4)
+    assert two == (3 + 3 + 1) * page    # 9, then 12 tokens; a pad lane
 
 
 def test_kv_read_bytes_of_an_idle_step_is_one_sink_page():

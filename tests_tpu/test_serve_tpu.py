@@ -6,8 +6,10 @@ and 128 for every page format (f32, bf16, int8, fp8) and is compared
 with its jnp twin, and a ServeEngine generates end to end with the
 kernel the engine itself resolved.
 
-Tolerances. The kernel multiplies in f32 and sums on the MXU at HIGHEST
-precision, so its result is f32-accurate for every format. The jnp twin
+Tolerances. With f32 q or f32 pages the kernel's operands are f32 at
+HIGHEST MXU precision; with bf16 q and narrower pages they are exact
+in bf16 and the probabilities go in as two bf16 halves, summed in f32:
+its result is as accurate as the output dtype for every format. The jnp twin
 is only that accurate when XLA is told so: on a TPU the default
 precision of an f32 dot is one bf16 pass, hence the
 `default_matmul_precision("highest")` around every reference. With
@@ -23,6 +25,8 @@ import jax
 import jax.numpy as jnp
 
 from flexflow_tpu.kernels.paged_ragged_v2 import (PALLAS, _ragged_jnp,
+                                                  build_work_list,
+                                                  max_work_items,
                                                   paged_attention_ragged_v2,
                                                   quantize_kv_rows)
 
@@ -80,6 +84,61 @@ def test_ragged_v2_mosaic_matches_jnp(h, d, page_dtype, q_dtype, tol,
     assert out.dtype == ref.dtype == jnp.dtype(q_dtype)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("page_dtype,q_dtype,tol", [
+    (jnp.float32, jnp.float32, 2e-5),
+    (jnp.bfloat16, jnp.bfloat16, 2e-2),
+    (jnp.int8, jnp.bfloat16, 2e-2),
+])
+def test_ragged_v2_mosaic_mixed_step_matches_jnp(page_dtype, q_dtype, tol):
+    """One mixed step at the serving cell's geometry — 576 lanes, 128
+    table columns, 32 heads of 64: 40 decode lanes, a 300-lane chunk
+    that ends at position 1,000 (tiles and kv-blocks crossed, its rows
+    sharing their fetches), an inactive tail — on the grid bound the
+    engine proves for 64 sequences. Checked on every 7th lane (the
+    twin gathers each lane's whole table)."""
+    lanes, pp, seqs, h, d, pages = 576, 128, 64, 32, 64, 769
+    rng = np.random.RandomState(5)
+    table = np.zeros((seqs, pp), np.int32)
+    free = list(rng.permutation(np.arange(1, pages)))
+    lens = [1000] + [int(n) for n in rng.randint(1, 200, size=40)]
+    for s, n in enumerate(lens):
+        for i in range(-(-n // PAGE)):
+            table[s, i] = int(free.pop())
+    slots = np.zeros(lanes, np.int32)
+    vis = np.ones(lanes, np.int32)
+    slots[:40], vis[:40] = np.arange(1, 41), lens[1:]
+    vis[40:340] = np.arange(701, 1001)              # slot 0's chunk
+    kp = rng.randn(pages, PAGE, h, d).astype(np.float32)
+    vp = rng.randn(pages, PAGE, h, d).astype(np.float32)
+    q = jnp.asarray(rng.randn(lanes, h, d), q_dtype)
+    scales = {}
+    if jnp.dtype(page_dtype).itemsize == 1:
+        kp, ks = quantize_kv_rows(jnp.asarray(kp), page_dtype)
+        vp, vs = quantize_kv_rows(jnp.asarray(vp), page_dtype)
+        scales = {"k_scales": ks, "v_scales": vs}
+    else:
+        kp, vp = jnp.asarray(kp, page_dtype), jnp.asarray(vp, page_dtype)
+    table, slots, vis = (jnp.asarray(x) for x in (table, slots, vis))
+
+    def call(q, kp, vp, t, s, n, **sc):
+        work = build_work_list(
+            t, s, n, page_size=PAGE, block_pages=8,
+            max_items=max_work_items(lanes, pp, 8, slot_changes=seqs))
+        return paged_attention_ragged_v2(q, kp, vp, t, s, n, work=work,
+                                         use_pallas=True, **sc)
+
+    out = np.asarray(jax.jit(call)(q, kp, vp, table, slots, vis, **scales),
+                     np.float32)
+    assert np.isfinite(out).all()                   # the inactive rows too
+    sub = np.arange(0, lanes, 7)
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(lambda q, s, n: _ragged_jnp(
+            q, kp, vp, table, s, n, d ** -0.5, **scales))(
+            q[sub], slots[sub], vis[sub])
+    np.testing.assert_allclose(out[sub], np.asarray(ref, np.float32),
                                rtol=tol, atol=tol)
 
 
