@@ -179,6 +179,13 @@ class FFModel:
                        [input], eps, elementwise_affine)
         return self.add_op(op).output
 
+    def rms_norm(self, input: Tensor, eps: float = 1e-5,
+                 name: Optional[str] = None) -> Tensor:
+        from .ops import RMSNorm
+        op = RMSNorm(self, name or self._fresh_name("rms_norm"), [input],
+                     eps)
+        return self.add_op(op).output
+
     def reduce_mean(self, input: Tensor, axis: int, keepdims: bool = False,
                     name: Optional[str] = None) -> Tensor:
         op = Reduce(self, name or self._fresh_name("reduce_mean"),
@@ -218,11 +225,20 @@ class FFModel:
                             causal: bool = False,
                             name: Optional[str] = None,
                             kernel_initializer="glorot",
-                            use_flash=None) -> Tensor:
+                            use_flash=None, positions: Tensor = None,
+                            rotary_theta: float = 0.0,
+                            qk_norm: bool = False,
+                            qk_norm_eps: float = 1e-5) -> Tensor:
+        """`positions` ((batch, seq) int32) with `rotary_theta` > 0
+        rotates q and k per head at those absolute positions;
+        `qk_norm` RMS-normalises the whole q and k projections first."""
+        inputs = [query, key, value] \
+            + ([positions] if positions is not None else [])
         op = MultiHeadAttention(
-            self, name or self._fresh_name("attention"), [query, key, value],
+            self, name or self._fresh_name("attention"), inputs,
             embed_dim, num_heads, kdim, vdim, dropout, bias, add_bias_kv,
-            add_zero_attn, causal, kernel_initializer, use_flash)
+            add_zero_attn, causal, kernel_initializer, use_flash,
+            rotary_theta, qk_norm, qk_norm_eps)
         return self.add_op(op).output
 
     # elementwise unary (model.h exp/relu/sigmoid/tanh/elu/scalar ops)
@@ -345,12 +361,18 @@ class FFModel:
                 hidden_dim: int, out_dim: int = None,
                 capacity_factor: float = 1.25, activation="relu",
                 aux_loss_weight: float = 1e-2,
-                name: Optional[str] = None) -> Tensor:
+                name: Optional[str] = None, norm_topk: bool = True,
+                dropless: bool = False) -> Tensor:
         """Fused expert-parallel MoE FFN (TPU-first EP; the composable
-        reference path softmax+topk+group_by+aggregate also exists)."""
+        reference path softmax+topk+group_by+aggregate also exists).
+        `dropless`: bias-free gated experts, (act(x wg) * (x wu)) wd,
+        and every token reaches all its k experts whatever the load (no
+        capacity); `norm_topk=False` keeps the k router probabilities
+        as they are."""
         op = MoEFFN(self, name or self._fresh_name("moe_ffn"), [input],
                     num_experts, k, hidden_dim, out_dim, capacity_factor,
-                    activation, aux_loss_weight)
+                    activation, aux_loss_weight, norm_topk=norm_topk,
+                    dropless=dropless)
         return self.add_op(op).output
 
 

@@ -9,6 +9,7 @@ from .dlrm import build_dlrm
 from .moe import build_moe_fused, build_moe_reference
 from .candle_uno import build_candle_uno
 from .nmt_lstm import build_nmt_lstm, build_nmt_seq2seq
+from .olmoe import build_olmoe_lm
 
 __all__ = [
     "build_alexnet",
@@ -19,6 +20,7 @@ __all__ = [
     "build_dlrm",
     "build_moe_reference",
     "build_moe_fused",
+    "build_olmoe_lm",
     "build_candle_uno",
     "build_nmt_lstm",
     "build_nmt_seq2seq",

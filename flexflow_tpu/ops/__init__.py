@@ -7,7 +7,7 @@ kernels under flexflow_tpu/kernels/.
 
 from .linear import Linear
 from .conv import Conv2D, Pool2D, BatchNorm, Flat
-from .elementwise import ElementUnary, ElementBinary, Dropout, LayerNorm, Reduce, Softmax
+from .elementwise import ElementUnary, ElementBinary, Dropout, LayerNorm, RMSNorm, Reduce, Softmax
 from .tensor_ops import (
     Concat,
     Split,
@@ -36,6 +36,7 @@ __all__ = [
     "Dropout",
     "Softmax",
     "LayerNorm",
+    "RMSNorm",
     "Concat",
     "Split",
     "Reshape",

@@ -30,6 +30,7 @@ from ..op import (
     WeightSpec,
     register_op,
 )
+from .common import rms_norm, rotary
 
 
 @register_op
@@ -41,9 +42,19 @@ class MultiHeadAttention(Op):
                  use_bias: bool = False, add_bias_kv: bool = False,
                  add_zero_attn: bool = False, causal: bool = False,
                  kernel_initializer: str = "glorot",
-                 use_flash=None):
+                 use_flash=None, rotary_theta: float = 0.0,
+                 qk_norm: bool = False, qk_norm_eps: float = 1e-5):
         super().__init__(model, name, inputs)
-        q, k, v = inputs
+        # a fourth input, (batch, seq) int32 absolute positions, turns
+        # the rotary embedding on (rotary_theta > 0 needs it)
+        q, k, v = inputs[:3]
+        self.rotary_theta = float(rotary_theta)
+        self.qk_norm = bool(qk_norm)
+        self.qk_norm_eps = float(qk_norm_eps)
+        if (self.rotary_theta > 0) != (len(inputs) == 4):
+            raise ValueError(
+                f"{name}: rotary attention takes q, k, v AND positions "
+                f"(rotary_theta={rotary_theta}, {len(inputs)} inputs)")
         self.embed_dim = int(embed_dim)
         self.num_heads = int(num_heads)
         self.kdim = int(kdim) if kdim > 0 else self.embed_dim
@@ -77,6 +88,9 @@ class MultiHeadAttention(Op):
         self.attrs = {"embed_dim": embed_dim, "num_heads": num_heads,
                       "dropout": dropout, "use_bias": use_bias,
                       "causal": causal}
+        if self.rotary_theta > 0 or self.qk_norm:
+            self.attrs.update(rotary_theta=self.rotary_theta,
+                              qk_norm=self.qk_norm)
 
     def output_shapes(self):
         q = self.inputs[0]
@@ -103,6 +117,13 @@ class MultiHeadAttention(Op):
         if self.use_bias:
             specs["bo"] = WeightSpec((self.embed_dim,), initializer="zeros",
                                      axes=(CHANNEL_OUT,))
+        if self.qk_norm:
+            # one RMSNorm over the WHOLE projection (all heads), before
+            # the split into heads: OLMoE's q_norm / k_norm
+            specs["q_norm"] = WeightSpec((h, d), initializer="ones",
+                                         axes=(HEAD, None))
+            specs["k_norm"] = WeightSpec((h, d), initializer="ones",
+                                         axes=(HEAD, None))
         if self.add_bias_kv:
             # one learned extra kv position (torch MultiheadAttention
             # bias_k/bias_v semantics)
@@ -113,7 +134,7 @@ class MultiHeadAttention(Op):
         return specs
 
     def forward(self, params, xs, ctx: OpContext):
-        q_in, k_in, v_in = xs
+        q_in, k_in, v_in = xs[:3]
         if self._fused_qkv:
             # self-attention: ONE fused (E, 3·H·D) projection GEMM
             # instead of three E x H·D GEMMs — same math, wider MXU
@@ -140,6 +161,12 @@ class MultiHeadAttention(Op):
                                params["wk"].astype(k_in.dtype))
                 v = jnp.einsum("bse,ehd->bshd", v_in,
                                params["wv"].astype(v_in.dtype))
+        if self.qk_norm:
+            q = rms_norm(q, params["q_norm"], self.qk_norm_eps)
+            k = rms_norm(k, params["k_norm"], self.qk_norm_eps)
+        if self.rotary_theta > 0:
+            q = rotary(q, xs[3], self.rotary_theta)
+            k = rotary(k, xs[3], self.rotary_theta)
         if self.add_bias_kv:
             b = k.shape[0]
             bk = jnp.broadcast_to(params["bias_k"].astype(k.dtype),
@@ -244,7 +271,8 @@ class MultiHeadAttention(Op):
         return [(SAMPLE, SEQ, CHANNEL_OUT)]
 
     def input_axes(self):
-        return [(SAMPLE, SEQ, CHANNEL_IN)] * 3
+        return [(SAMPLE, SEQ, CHANNEL_IN)] * 3 \
+            + [(SAMPLE, SEQ)] * (len(self.inputs) - 3)
 
     def flops(self) -> float:
         b, lq = self.inputs[0].shape[:2]

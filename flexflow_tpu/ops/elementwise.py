@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from ..op import SAMPLE, CHANNEL, SEQ, Op, OpContext, WeightSpec, register_op
+from .common import rms_norm
 
 
 def _passthrough_axes(shape):
@@ -292,3 +293,32 @@ class LayerNorm(PassthroughAxesMixin, Op):
 
     def flops(self) -> float:
         return 8.0 * self.inputs[0].num_elements
+
+
+@register_op
+class RMSNorm(PassthroughAxesMixin, Op):
+    """x * rsqrt(mean(x^2) + eps) * scale over the LAST dim: no mean
+    subtracted, no bias (the modern decoder block's norm). Statistics
+    in f32 regardless of activation dtype, like LayerNorm here."""
+
+    op_type = "rms_norm"
+
+    def __init__(self, model, name, inputs, eps: float = 1e-5):
+        super().__init__(model, name, inputs)
+        self.eps = float(eps)
+        self.num_channels = inputs[0].shape[-1]
+        self.attrs = {"eps": eps}
+
+    def output_shapes(self):
+        return [tuple(self.inputs[0].shape)]
+
+    def weight_specs(self):
+        return {"scale": WeightSpec((self.num_channels,),
+                                    initializer="ones", axes=(CHANNEL,))}
+
+    def forward(self, params, xs, ctx: OpContext):
+        (x,) = xs
+        return [rms_norm(x, params["scale"], self.eps)]
+
+    def flops(self) -> float:
+        return 4.0 * self.inputs[0].num_elements

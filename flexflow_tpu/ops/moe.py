@@ -117,6 +117,71 @@ def use_sorted_dispatch(model, n_slots: int, n_experts: int,
     return n_slots * n_experts * capacity > DENSE_MASK_ELEMENT_LIMIT
 
 
+# ---- dropless routing: every slot reaches its expert, whatever the load
+def route_top_k(tokens: jax.Array, gate_w: jax.Array, k: int,
+                norm_topk: bool):
+    """The router: tokens (N, D) -> (probabilities (N, E) f32, the k
+    largest (N, k) f32, their expert ids (N, k) int32). Logits
+    accumulate in f32 and are never rounded; softmax and top-k run in
+    f32. `norm_topk` renormalises the k weights to sum to 1."""
+    logits = jnp.dot(tokens, gate_w.astype(tokens.dtype),
+                     preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate_vals, assign = jax.lax.top_k(probs, k)
+    if norm_topk:
+        gate_vals = gate_vals / jnp.clip(
+            jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+    return probs, gate_vals, assign.astype(jnp.int32)
+
+
+def dropless_dispatch(tokens: jax.Array, assign: jax.Array,
+                      n_experts: int, live=None):
+    """Sort the N*k slots by expert (stable; slot s belongs to token
+    s // k). `live` (N,) bool: slots of tokens that are not live route
+    NOWHERE — they sort behind every expert's rows and no expert counts
+    them. -> (rows (S, D) in expert order, order (S,), counts (E,)
+    int32 live slots per expert: the grouped matmul's group sizes)."""
+    k = assign.shape[1]
+    flat = assign.reshape(-1)
+    if live is not None:
+        flat = jnp.where(jnp.repeat(live, k), flat, n_experts)
+    order = jnp.argsort(flat)
+    counts = jnp.zeros((n_experts,), jnp.int32).at[flat].add(
+        1, mode="drop")
+    return jnp.take(tokens, order // k, axis=0), order, counts
+
+
+def grouped_ffn(rows: jax.Array, counts: jax.Array, wg, wu, wd,
+                activation) -> jax.Array:
+    """The gated bias-free expert over rows sorted by expert:
+    (act(rows wg_e) * (rows wu_e)) wd_e, each product one grouped
+    matmul (`jax.lax.ragged_dot`, f32 accumulation) whose group e is
+    the `counts[e]` rows of expert e. Rows past sum(counts) belong to
+    no expert and come out zero."""
+    from .common import apply_activation
+    dt = rows.dtype
+
+    def gmm(a, w):
+        return jax.lax.ragged_dot(
+            a, w.astype(dt), counts,
+            preferred_element_type=jnp.float32).astype(dt)
+
+    h = apply_activation(gmm(rows, wg), activation) * gmm(rows, wu)
+    y = gmm(h, wd)
+    routed = jnp.arange(rows.shape[0]) < jnp.sum(counts)
+    return jnp.where(routed[:, None], y, jnp.zeros_like(y))
+
+
+def dropless_combine(ys: jax.Array, order: jax.Array,
+                     gate_vals: jax.Array) -> jax.Array:
+    """Expert-ordered outputs (S, O) back to their tokens: unsort,
+    weight each slot by its router weight and sum a token's k slots, in
+    f32. -> (N, O) f32."""
+    n, k = gate_vals.shape
+    slots = jnp.take(ys, jnp.argsort(order), axis=0).astype(jnp.float32)
+    return jnp.sum(slots.reshape(n, k, -1) * gate_vals[..., None], axis=1)
+
+
 @register_op
 class GroupBy(Op):
     """inputs: (data (B, D), assign (B, k)); outputs: n tensors (cap, D)."""

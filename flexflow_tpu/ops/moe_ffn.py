@@ -9,6 +9,13 @@ GSPMD turns the dispatch/combine einsums into all-to-alls over ICI.
 
 GShard-style: top-k gating, capacity-bounded dense dispatch masks, and a
 load-balancing auxiliary loss added to the objective.
+
+`dropless=True` is the modern layer: the experts are bias-free gated
+units, (act(x wg) * (x wu)) wd (SwiGLU with activation "silu"), and
+there is no capacity: slots are sorted by expert and run through
+grouped matmuls (ops/moe.py), so an overloaded expert changes no
+token's mathematics. `norm_topk=False` keeps the k router probabilities
+unnormalised. OLMoE's layer is both (models/olmoe.py).
 """
 
 from __future__ import annotations
@@ -21,6 +28,10 @@ from .common import AC_MODE_RELU, apply_activation
 from .moe import (
     dispatch_indices,
     dispatch_mask,
+    dropless_combine,
+    dropless_dispatch,
+    grouped_ffn,
+    route_top_k,
     sorted_combine,
     sorted_dispatch,
     use_sorted_dispatch,
@@ -39,8 +50,11 @@ class MoEFFN(Op):
                  hidden_dim: int, out_dim: int = None,
                  capacity_factor: float = 1.25,
                  activation=AC_MODE_RELU, aux_loss_weight: float = 1e-2,
-                 kernel_initializer: str = "glorot"):
+                 kernel_initializer: str = "glorot",
+                 norm_topk: bool = True, dropless: bool = False):
         super().__init__(model, name, inputs)
+        self.norm_topk = bool(norm_topk)
+        self.dropless = bool(dropless)
         self.num_experts = int(num_experts)
         self.k = int(k)
         self.hidden_dim = int(hidden_dim)
@@ -54,21 +68,34 @@ class MoEFFN(Op):
         for s in inputs[0].shape[:-1]:
             n_tokens *= s
         self.n_tokens = n_tokens
+        # dropless: no buffer; `capacity` is then the MEAN load of an
+        # expert, which is what the search prices (cost_model's EP
+        # all-to-all, flops() below)
         self.capacity = max(
-            1, int(self.capacity_factor * self.k * n_tokens
-                   / self.num_experts))
+            1, int((1.0 if self.dropless else self.capacity_factor)
+                   * self.k * n_tokens / self.num_experts))
         self.attrs = {"num_experts": num_experts, "k": k,
                       "hidden_dim": hidden_dim, "out_dim": self.out_dim,
                       "capacity": self.capacity}
+        if self.dropless:
+            self.attrs.update(dropless=True, norm_topk=self.norm_topk)
 
     def output_shapes(self):
         return [tuple(self.inputs[0].shape[:-1]) + (self.out_dim,)]
 
     def weight_specs(self):
         e, d, h, o = self.num_experts, self.in_dim, self.hidden_dim, self.out_dim
+        gate = WeightSpec((d, e), initializer=self.kernel_initializer,
+                          axes=(CHANNEL, None))
+        if self.dropless:
+            def w(shape, fi, fo):
+                return WeightSpec(shape, axes=(EXPERT, None, None),
+                                  initializer=self.kernel_initializer,
+                                  fan_in=fi, fan_out=fo)
+            return {"gate": gate, "wg": w((e, d, h), d, h),
+                    "wu": w((e, d, h), d, h), "wd": w((e, h, o), h, o)}
         return {
-            "gate": WeightSpec((d, e), initializer=self.kernel_initializer,
-                               axes=(CHANNEL, None)),
+            "gate": gate,
             "w1": WeightSpec((e, d, h), initializer=self.kernel_initializer,
                              axes=(EXPERT, None, None), fan_in=d, fan_out=h),
             "b1": WeightSpec((e, h), initializer="zeros",
@@ -87,13 +114,16 @@ class MoEFFN(Op):
         n = tokens.shape[0]
         e, cap, k = self.num_experts, self.capacity, self.k
 
-        logits = jnp.dot(tokens, params["gate"].astype(tokens.dtype),
-                         preferred_element_type=jnp.float32)  # (N, E)
-        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-        gate_vals, assign = jax.lax.top_k(probs, k)  # (N, k)
-        # renormalize the selected gates
-        gate_vals = gate_vals / jnp.clip(
-            jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+        probs, gate_vals, assign = route_top_k(
+            tokens, params["gate"], k, self.norm_topk)  # (N, E), (N, k) x 2
+        if self.dropless:
+            rows, order, counts = dropless_dispatch(tokens, assign, e)
+            ys = grouped_ffn(rows, counts, params["wg"], params["wu"],
+                             params["wd"], self.activation)
+            out = dropless_combine(ys, order, gate_vals)
+            self._aux_loss(ctx, assign, probs)
+            return [out.astype(x.dtype).reshape(
+                orig_shape[:-1] + (self.out_dim,))]
 
         xrep = jnp.repeat(tokens, k, axis=0)  # (N*k, D) slot-major
         sorted_path = use_sorted_dispatch(
@@ -128,17 +158,21 @@ class MoEFFN(Op):
         combined = combined.reshape(n, k, self.out_dim)
         out = jnp.sum(combined * gate_vals[..., None], axis=1)
 
-        if ctx.training:
-            # GShard load-balancing loss: E * sum_e f_e * p_e where f_e is
-            # the fraction of tokens whose top-1 goes to e and p_e the mean
-            # gate probability of e.
-            top1 = jax.nn.one_hot(assign[:, 0], e, dtype=jnp.float32)
-            f = jnp.mean(top1, axis=0)
-            p = jnp.mean(probs, axis=0)
-            ctx.aux_loss = (self.aux_loss_weight * e
-                            * jnp.sum(f * p)).astype(jnp.float32)
-
+        self._aux_loss(ctx, assign, probs)
         return [out.astype(x.dtype).reshape(orig_shape[:-1] + (self.out_dim,))]
+
+    def _aux_loss(self, ctx: OpContext, assign, probs) -> None:
+        """GShard load-balancing loss: E * sum_e f_e * p_e where f_e is
+        the fraction of tokens whose top-1 goes to e and p_e the mean
+        gate probability of e."""
+        if not ctx.training:
+            return
+        e = self.num_experts
+        top1 = jax.nn.one_hot(assign[:, 0], e, dtype=jnp.float32)
+        f = jnp.mean(top1, axis=0)
+        p = jnp.mean(probs, axis=0)
+        ctx.aux_loss = (self.aux_loss_weight * e
+                        * jnp.sum(f * p)).astype(jnp.float32)
 
     def output_axes(self):
         n = len(self.outputs[0].shape)
@@ -151,10 +185,14 @@ class MoEFFN(Op):
     input_axes = output_axes
 
     def flops(self) -> float:
-        # gate + 2 FFN GEMMs over dispatched capacity
+        # gate + the FFN GEMMs over what is dispatched: the capacity
+        # buffers or, dropless, exactly k experts a token (three GEMMs
+        # each) and a sort in place of the dispatch masks
         gate = 2.0 * self.n_tokens * self.in_dim * self.num_experts
-        ffn = (2.0 * self.num_experts * self.capacity
-               * (self.in_dim * self.hidden_dim
-                  + self.hidden_dim * self.out_dim))
+        up = (2 if self.dropless else 1) * self.in_dim * self.hidden_dim
+        per_row = 2.0 * (up + self.hidden_dim * self.out_dim)
+        if self.dropless:
+            return gate + self.n_tokens * self.k * per_row
+        ffn = self.num_experts * self.capacity * per_row
         dispatch = 2.0 * self.n_tokens * self.k * self.num_experts * self.capacity
         return gate + ffn + dispatch

@@ -71,6 +71,7 @@ from ..parallel.mesh import TENSOR, replica_devices, serve_tensor_mesh
 from ..utils.faults import FaultInjector, TransientError, injector_for
 from ..utils.telemetry import (Telemetry, pow2_bucket, serve_metrics,
                                telemetry_for)
+from .arch import _dense, describe
 from .kv_cache import KVCacheConfig, PagedKVCache, kv_storage_dtype
 from .scheduler import (ChunkPlan, ContinuousBatchingScheduler, Request,
                         RequestOutcome, RequestState, SampleParams)
@@ -113,35 +114,6 @@ class _CompileEvents:
     def _on_event(event: str, duration: float, **kwargs) -> None:
         if event == "/jax/core/compile/backend_compile_duration":
             _CompileEvents.count += 1
-
-
-def _ln(p, x, eps):
-    """LayerNorm with f32 statistics — must mirror ops/elementwise.py
-    LayerNorm.forward exactly (the reference-parity contract)."""
-    xf = x.astype(jnp.float32)
-    mean = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.var(xf, axis=-1, keepdims=True)
-    y = (xf - mean) * jax.lax.rsqrt(var + eps)
-    y = y * p["scale"].astype(jnp.float32) + p["bias"].astype(jnp.float32)
-    return y.astype(x.dtype)
-
-
-def _dense(p, x, activation=None, psum_axis=None):
-    """Dense layer. `psum_axis` is the tensor-parallel row-parallel
-    hook: under sharding the kernel's CONTRACTION dim is sharded, so
-    each device's matmul is a partial sum that all-reduces over the
-    axis BEFORE the (replicated) bias — exactly the Megatron pattern
-    the cost model prices. None (single device) is the unchanged
-    bit-exact path."""
-    y = jnp.dot(x, p["kernel"].astype(x.dtype),
-                preferred_element_type=jnp.float32).astype(x.dtype)
-    if psum_axis is not None:
-        y = jax.lax.psum(y, psum_axis)
-    if "bias" in p:
-        y = y + p["bias"].astype(x.dtype)
-    if activation == "relu":
-        y = jax.nn.relu(y)
-    return y
 
 
 def probe_serve_arch(model, config=None, context=None):
@@ -280,6 +252,10 @@ class ServeEngine:
             getattr(cfg, "serve_prefix_cache", True)
             if prefix_cache is None else prefix_cache) \
             and self.chunked_prefill
+        # what this model is not served on raises HERE, by name
+        self.arch.refuse(
+            tp=self.tp, chunked=self.chunked_prefill,
+            adapters=int(getattr(cfg, "adapter_rank", 0) or 0) > 0)
         self.prefill_budget = int(getattr(cfg, "serve_prefill_budget", 512))
         self.admit_watermark = float(
             getattr(cfg, "serve_admit_watermark", 0.02))
@@ -659,6 +635,8 @@ class ServeEngine:
         ac = self.adapter_cfg
         return {
             "kind": "serve",
+            "arch": self.arch.kind,
+            "experts": (self.arch.experts, self.arch.experts_per_token),
             "jax": jax.__version__,
             "backend": jax.default_backend(),
             "devices": jax.device_count(),
@@ -695,38 +673,21 @@ class ServeEngine:
 
     # ---------------- model introspection -----------------------------
     def _read_arch(self, model) -> None:
-        ops = {op.name: op for op in model.ops}
-        for required in ("tok_embed", "pos_embed", "lm_head"):
-            if required not in ops:
-                raise ValueError(
-                    f"ServeEngine needs a build_transformer_lm-shaped "
-                    f"model (missing op {required!r})")
-        self.vocab_size = ops["tok_embed"].num_entries
-        self.max_positions = ops["pos_embed"].num_entries
-        self.layer_norm = "layer0_ln1" in ops
-        self.num_layers = 0
-        while f"layer{self.num_layers}_attn" in ops:
-            self.num_layers += 1
-        if self.num_layers == 0:
-            raise ValueError("model has no layer{i}_attn blocks")
-        attn0 = ops[f"layer{0}_attn"]
-        if not attn0.causal:
-            raise ValueError("serving needs causal attention blocks")
-        self.num_heads = attn0.num_heads
-        self.head_dim = attn0.head_dim
-        self.hidden = attn0.embed_dim
-        self.ln_eps = ops["layer0_ln1"].eps if self.layer_norm else 1e-5
-        # serving activation dtype = whatever the LM graph's embeddings
-        # emit (build_transformer_lm wires FFConfig.compute_dtype here):
-        # every block below follows its input dtype, so a bf16 LM
-        # serves bf16 end-to-end — and generate_reference embeds
-        # through the SAME cast, so the greedy parity oracle holds at
-        # the engine's own precision. KV pages keep their configured
-        # (f32) dtype: bf16 K/V upcasts exactly, so cached and
-        # recomputed attention stay bit-identical.
-        self.act_dtype = jnp.dtype(ops["tok_embed"].out_dtype)
+        """Ask serve/arch.py what this model is; the engine keeps the
+        description (`self.arch`: the block's math) and copies the
+        dimensions its own geometry is built from."""
+        self.arch = a = describe(model)
+        self.vocab_size = a.vocab_size
+        self.max_positions = a.max_positions
+        self.layer_norm = a.layer_norm
+        self.num_layers = a.num_layers
+        self.num_heads = a.num_heads
+        self.head_dim = a.head_dim
+        self.hidden = a.hidden
+        self.ln_eps = a.ln_eps
+        self.act_dtype = a.act_dtype
+        self.ff_dim = a.ff_dim
         self.params = model.state.params  # live references, not copies
-        self.ff_dim = int(self.params["layer0_ff1"]["kernel"].shape[1])
 
     # ---------------- tensor-parallel sharding -------------------------
     def _resolve_serve_mesh(self, mesh, tensor_parallel,
@@ -792,6 +753,13 @@ class ServeEngine:
         steady-state context defaulting to 3/4 of the serveable length,
         KV traffic at the configured page format's itemsize."""
         from ..search.cost_model import ServeArch
+        if self.arch.experts:
+            raise NotImplementedError(
+                f"serve placement prices a dense feed-forward: the "
+                f"{self.arch.kind} expert layer ({self.arch.experts} "
+                f"experts, {self.arch.experts_per_token} a token) has no "
+                f"ServeArch yet, so serve_mesh='auto' cannot place it; "
+                f"serve it on one device")
         cfg = self.config
         kv_name = str(getattr(cfg, "kv_dtype", "float32"))
         from .kv_cache import QUANTIZED_KV_DTYPES
@@ -979,96 +947,6 @@ class ServeEngine:
             return None
         return dict(ca) if ca else None
 
-    # ---------------- pure block math ----------------------------------
-    def _embed(self, params, tokens, positions):
-        # mode="clip": padded lanes/positions past the learned tables
-        # must read SOME finite row — they are masked or never read
-        # back, but jnp.take's "fill" OOB default yields NaN, and a
-        # NaN K/V poisons every lane that softmax-weights it (0 * NaN
-        # = NaN survives the causal mask's zeroed probability). Bit
-        # for bit identical for all in-range indices. (The same OOB
-        # trap as ops/embedding's flat slot-offset gather, PR 2.)
-        te = jnp.take(params["tok_embed"]["kernel"], tokens, axis=0,
-                      mode="clip")
-        pe = jnp.take(params["pos_embed"]["kernel"], positions, axis=0,
-                      mode="clip")
-        return (te + pe).astype(self.act_dtype)
-
-    def _attn_qkv(self, p, h, lora=None):
-        """h (..., E) -> q, k, v (..., H, D). `lora` (mixed step only,
-        h is (T, E)) is the lanes' gathered per-layer adapter rows
-        (a_qkv (T, 3, E, r), b_qkv (T, 3, r, H[/t], D), scale (T,)):
-        each lane adds ITS tenant's low-rank delta; slot-0 lanes gather
-        the zero slab and their delta is exactly 0.0."""
-        q = jnp.einsum("...e,ehd->...hd", h, p["wq"].astype(h.dtype))
-        k = jnp.einsum("...e,ehd->...hd", h, p["wk"].astype(h.dtype))
-        v = jnp.einsum("...e,ehd->...hd", h, p["wv"].astype(h.dtype))
-        if lora is not None:
-            aq, bq, s = lora
-            u = jnp.einsum("te,tjer->tjr", h, aq.astype(h.dtype))
-            d = jnp.einsum("tjr,tjrhd->tjhd", u, bq.astype(h.dtype))
-            d = d * s.astype(h.dtype)[:, None, None, None]
-            q = q + d[:, 0]
-            k = k + d[:, 1]
-            v = v + d[:, 2]
-        return q, k, v
-
-    def _attn_out(self, p, o, x, psum_axis=None, lora=None):
-        y = jnp.einsum("...hd,hde->...e", o, p["wo"].astype(o.dtype))
-        if lora is not None:
-            # a_wo contracts the (sharded) head dim, so under tp the
-            # delta is a local partial the psum below completes —
-            # exact by linearity
-            a, b, s = lora
-            u = jnp.einsum("thd,thdr->tr", o, a.astype(o.dtype))
-            y = y + jnp.einsum("tr,tre->te", u, b.astype(o.dtype)) \
-                * s.astype(o.dtype)[:, None]
-        if psum_axis is not None:
-            # head-row-parallel wo: each device contracted its H/t
-            # heads; the all-reduce completes the sum (Megatron)
-            y = jax.lax.psum(y, psum_axis)
-        if "bo" in p:
-            y = y + p["bo"].astype(y.dtype)
-        return x + y
-
-    def _ffn(self, params, i, x, psum_axis=None, lora=None):
-        h = _ln(params[f"layer{i}_ln2"], x, self.ln_eps) \
-            if self.layer_norm else x
-        if lora is None:
-            h = _dense(params[f"layer{i}_ff1"], h, activation="relu")
-            h = _dense(params[f"layer{i}_ff2"], h, psum_axis=psum_axis)
-            return x + h
-        # adapted FFN: ff1's delta lands PRE-activation (the merged
-        # reference folds A@B into the kernel, which relu then sees)
-        # and ff2's delta is a pre-psum local partial like wo's
-        a1, b1, a2, b2, s = lora
-        s = s.astype(h.dtype)
-        p1 = params[f"layer{i}_ff1"]
-        z = jnp.dot(h, p1["kernel"].astype(h.dtype),
-                    preferred_element_type=jnp.float32).astype(h.dtype)
-        u1 = jnp.einsum("te,ter->tr", h, a1.astype(h.dtype))
-        z = z + jnp.einsum("tr,trf->tf", u1, b1.astype(h.dtype)) \
-            * s[:, None]
-        if "bias" in p1:
-            z = z + p1["bias"].astype(z.dtype)
-        h2 = jax.nn.relu(z)
-        p2 = params[f"layer{i}_ff2"]
-        y = jnp.dot(h2, p2["kernel"].astype(h2.dtype),
-                    preferred_element_type=jnp.float32).astype(h2.dtype)
-        u2 = jnp.einsum("tf,tfr->tr", h2, a2.astype(h2.dtype))
-        y = y + jnp.einsum("tr,tre->te", u2, b2.astype(h2.dtype)) \
-            * s[:, None]
-        if psum_axis is not None:
-            y = jax.lax.psum(y, psum_axis)
-        if "bias" in p2:
-            y = y + p2["bias"].astype(y.dtype)
-        return x + y
-
-    def _head(self, params, x):
-        if self.layer_norm:
-            x = _ln(params["final_ln"], x, self.ln_eps)
-        return _dense(params["lm_head"], x)
-
     # ---------------- sharded block math (inside shard_map) ------------
     def _embed_tp(self, params, tokens, positions, axis):
         """Vocab-row-sharded token embedding: each device gathers the
@@ -1098,9 +976,8 @@ class ServeEngine:
         for the argmax/top-k tail. This is the program's only
         all-gather — the 'sharded vocab, gather only at the final
         logits' contract."""
-        if self.layer_norm:
-            x = _ln(params["final_ln"], x, self.ln_eps)
-        local = _dense(params["lm_head"], x)           # (T, Vp/t)
+        local = _dense(params["lm_head"],
+                       self.arch.final_norm(params, x))  # (T, Vp/t)
         return jax.lax.all_gather(local, axis, axis=1, tiled=True)
 
     # ---------------- full-sequence forward (prefill + reference) ------
@@ -1115,7 +992,8 @@ class ServeEngine:
         ps = self.cache_cfg.page_size
         s = tokens.shape[1]
         positions = jnp.arange(s, dtype=jnp.int32)[None, :]
-        x = self._embed(params, tokens, positions)        # (1, S, E)
+        arch = self.arch
+        x = arch.embed(params, tokens, positions)         # (1, S, E)
         if kv is not None:
             k_pages, v_pages, pt_row = kv
             pages = jnp.take(pt_row, positions[0] // ps)  # (S,)
@@ -1123,10 +1001,8 @@ class ServeEngine:
         scale = 1.0 / np.sqrt(self.head_dim)
         causal = jnp.tril(jnp.ones((s, s), dtype=bool))
         for i in range(self.num_layers):
-            p = params[f"layer{i}_attn"]
-            h = _ln(params[f"layer{i}_ln1"], x, self.ln_eps) \
-                if self.layer_norm else x
-            q, k, v = self._attn_qkv(p, h)                # (1, S, H, D)
+            q, k, v = arch.qkv(params, i, arch.norm1(params, i, x),
+                               positions)                 # (1, S, H, D)
             if kv is not None:
                 k_pages = k_pages.at[i, pages, offs].set(
                     k[0].astype(k_pages.dtype))
@@ -1146,9 +1022,9 @@ class ServeEngine:
                            v.astype(jnp.float32),
                            preferred_element_type=jnp.float32
                            ).astype(x.dtype)
-            x = self._attn_out(p, o, x)
-            x = self._ffn(params, i, x)
-        logits = self._head(params, x)                    # (1, S, V)
+            x = arch.attn_out(params, i, o, x)
+            x, _ = arch.ffn(params, i, x)
+        logits = arch.head(params, x)                     # (1, S, V)
         last = jnp.take(logits[0], length - 1, axis=0)    # (V,)
         return last, (None if kv is None else (k_pages, v_pages))
 
@@ -1304,7 +1180,7 @@ class ServeEngine:
         with scope("embed"):
             x = (self._embed_tp(params, tokens, positions, tp_axis)
                  if tp_axis else
-                 self._embed(params, tokens, positions))     # (T, E)
+                 self.arch.embed(params, tokens, positions))  # (T, E)
         scale = 1.0 / np.sqrt(self.head_dim)
         # multi-tenant adapters (serve/adapters.py): ONE gather pulls
         # each lane's whole (A, B) stack — slab (S, L, ...) rows by
@@ -1328,45 +1204,56 @@ class ServeEngine:
                     page_size=self.cache_cfg.page_size,
                     block_pages=self.attn_block_pages,
                     max_items=self.attn_max_items)
+        # an expert layer routes only the step's live lanes (inactive
+        # lanes aim their K/V at the sink page 0, _pack), and the step
+        # returns each layer's live slots per expert beside the tokens
+        live = write_pages != 0 if self.arch.experts else None
+        expert_counts = []
         for i in range(self.num_layers):
             with scope(f"layer{i}"):
-                x, k_pages, v_pages, k_scales, v_scales = \
+                x, k_pages, v_pages, k_scales, v_scales, counts = \
                     self._mixed_layer(
-                        params, i, x, k_pages, v_pages, k_scales,
-                        v_scales, write_pages, write_offs, page_tables,
-                        lane_slots, lane_lens, work, scale,
+                        params, i, x, positions, live, k_pages, v_pages,
+                        k_scales, v_scales, write_pages, write_offs,
+                        page_tables, lane_slots, lane_lens, work, scale,
                         None if ad is None else
                         {key: arr[:, i] for key, arr in ad.items()},
                         ad_s, tp_axis)
+                expert_counts.append(counts)
         with scope("head"):
             logits = (self._head_tp(params, x, tp_axis) if tp_axis
-                      else self._head(params, x))            # (T, V[pad])
+                      else self.arch.head(params, x))        # (T, V[pad])
         with scope("sample"):
             topv, topi = jax.lax.top_k(logits, self.topk_cap)
             out = (jnp.argmax(logits, axis=-1).astype(jnp.int32),
                    topv.astype(jnp.float32), topi.astype(jnp.int32))
+        if self.arch.experts:
+            out += (jnp.stack(expert_counts),)           # (layers, E)
         caches = (k_pages, v_pages, k_scales, v_scales) if quantized \
             else (k_pages, v_pages)
         return out, caches
 
-    def _mixed_layer(self, params, i, x, k_pages, v_pages, k_scales,
-                     v_scales, write_pages, write_offs, page_tables,
-                     lane_slots, lane_lens, work, scale, la, ad_s,
-                     tp_axis):
+    def _mixed_layer(self, params, i, x, positions, live, k_pages,
+                     v_pages, k_scales, v_scales, write_pages, write_offs,
+                     page_tables, lane_slots, lane_lens, work, scale, la,
+                     ad_s, tp_axis):
         """Layer `i` of the mixed step, one named scope per phase:
-        `ln`, `qkv`, `kv_write` (quantize and scatter into the pools),
+        `ln`, `qkv` (the description's projections at the lanes'
+        positions), `kv_write` (quantize and scatter into the pools),
         `attn` (the ragged paged kernel over the step's `work` list),
-        `attn_out`, `ffn`. `la` is the lanes' adapter rows of this
-        layer (None: no adapters)."""
+        `attn_out`, then the description's feed-forward under its own
+        scopes (`ffn`, or `router`, `moe_dispatch`, `experts`,
+        `moe_combine`). `la` is the lanes' adapter rows of this layer
+        (None: no adapters). Returns the layer's live slots per expert
+        last (None without an expert layer)."""
         scope = jax.named_scope
         quantized = k_scales is not None
-        p = params[f"layer{i}_attn"]
+        arch = self.arch
         with scope("ln"):
-            h = _ln(params[f"layer{i}_ln1"], x, self.ln_eps) \
-                if self.layer_norm else x
+            h = arch.norm1(params, i, x)
         with scope("qkv"):
-            q, k, v = self._attn_qkv(
-                p, h, lora=None if la is None else
+            q, k, v = arch.qkv(
+                params, i, h, positions, lora=None if la is None else
                 (la["a_qkv"], la["b_qkv"], ad_s))         # (T, H[/t], D)
         with scope("kv_write"):
             if quantized:
@@ -1391,17 +1278,15 @@ class ServeEngine:
                 v_scales=v_scales[i] if quantized else None,
                 block_kv=self.attn_block_kv, work=work)
         with scope("attn_out"):
-            x = self._attn_out(
-                p, o, x, psum_axis=tp_axis,
+            x = arch.attn_out(
+                params, i, o, x, psum_axis=tp_axis,
                 lora=None if la is None else
                 (la["a_wo"], la["b_wo"], ad_s))
-        with scope("ffn"):
-            x = self._ffn(
-                params, i, x, psum_axis=tp_axis,
-                lora=None if la is None else
-                (la["a_ff1"], la["b_ff1"], la["a_ff2"], la["b_ff2"],
-                 ad_s))
-        return x, k_pages, v_pages, k_scales, v_scales
+        x, counts = arch.ffn(
+            params, i, x, live=live, psum_axis=tp_axis,
+            lora=None if la is None else
+            (la["a_ff1"], la["b_ff1"], la["a_ff2"], la["b_ff2"], ad_s))
+        return x, k_pages, v_pages, k_scales, v_scales, counts
 
     # ---------------- disaggregated page handoff -----------------------
     # Device half of the prefill->decode transfer (serve/disagg.py;
@@ -1759,14 +1644,13 @@ class ServeEngine:
         sees keys 0..i). Non-decoding lanes compute garbage the host
         never reads. Returns (next_tokens (B,), top-k values, top-k
         ids, k_pages, v_pages)."""
-        x = self._embed(params, tokens, positions)        # (B, E)
+        arch = self.arch
+        x = arch.embed(params, tokens, positions)         # (B, E)
         pages, offs = write_pages, write_offs
         scale = 1.0 / np.sqrt(self.head_dim)
         for i in range(self.num_layers):
-            p = params[f"layer{i}_attn"]
-            h = _ln(params[f"layer{i}_ln1"], x, self.ln_eps) \
-                if self.layer_norm else x
-            q, k, v = self._attn_qkv(p, h)                # (B, H, D)
+            q, k, v = arch.qkv(params, i, arch.norm1(params, i, x),
+                               positions)                 # (B, H, D)
             k_pages = k_pages.at[i, pages, offs].set(
                 k.astype(k_pages.dtype))
             v_pages = v_pages.at[i, pages, offs].set(
@@ -1774,9 +1658,9 @@ class ServeEngine:
             o = paged_attention_decode(
                 q, k_pages[i], v_pages[i], page_tables, seq_lens,
                 scale=scale, **self._attn_kw)
-            x = self._attn_out(p, o, x)
-            x = self._ffn(params, i, x)
-        logits = self._head(params, x)                    # (B, V)
+            x = arch.attn_out(params, i, o, x)
+            x, _ = arch.ffn(params, i, x)
+        logits = arch.head(params, x)                     # (B, V)
         topv, topi = jax.lax.top_k(logits, self.topk_cap)
         return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
                 topv.astype(jnp.float32), topi.astype(jnp.int32),
@@ -1912,8 +1796,9 @@ class ServeEngine:
     def _dispatch_mixed(self, kp, vp, *args, lane_adapters=None):
         """One mixed-step dispatch through the right jitted program for
         the pool format, threading (and re-capturing) the donated scale
-        arrays on quantized pools. Returns (greedy, topv, topi, kp, vp);
-        the page AND scale arrays are re-stashed on self each step so a
+        arrays on quantized pools. Returns (greedy, topv, topi, kp, vp,
+        expert counts: the step's (layers, experts) live slots per
+        expert on a model with an expert layer, else None); the page AND scale arrays are re-stashed on self each step so a
         mid-run audit (check_kv_scales from an `on_step` callback, when
         sequences are actually resident) reads THIS step's content, not
         the pre-run allocation. On an adapter-armed engine the lanes'
@@ -1927,16 +1812,18 @@ class ServeEngine:
         else:
             args = args + (None, None)
         if self.kv_quantized:
-            greedy, topv, topi, kp, vp, ks, vs = self._call_counted(
+            *out, kp, vp, ks, vs = self._call_counted(
                 "mixed", self._mixed_q_jit, self._step_params, kp, vp,
                 self._k_scales, self._v_scales, *args)
             self._k_scales, self._v_scales = ks, vs
         else:
-            greedy, topv, topi, kp, vp = self._call_counted(
+            *out, kp, vp = self._call_counted(
                 "mixed", self._mixed_jit, self._step_params, kp, vp,
                 *args)
         self._k_pages, self._v_pages = kp, vp
-        return greedy, topv, topi, kp, vp
+        greedy, topv, topi = out[:3]
+        return (greedy, topv, topi, kp, vp,
+                out[3] if self.arch.experts else None)
 
     def warmup(self) -> Dict[str, int]:
         """Ready the active path's programs once, on throwaway inputs
@@ -1955,7 +1842,7 @@ class ServeEngine:
             z = self._h2d(np.zeros((t,), np.int32))
             pts = self._h2d(
                 np.zeros((c.max_seqs, c.pages_per_seq), np.int32))
-            _, _, _, kp, vp = self._dispatch_mixed(
+            _, _, _, kp, vp, _ = self._dispatch_mixed(
                 kp, vp, z, z, z, z, pts, z, self._h2d(np.ones((t,), np.int32)))
             if self.adapters is not None:
                 # compile the adapter-load scatter on an all-zero row
@@ -3187,7 +3074,20 @@ class StepEvents:
     ``kv_bytes_read`` the K/V page bytes the step's attention kernel
     calls fetch, ``attn_items`` / ``attn_rows`` the work items ONE of
     those calls runs for the live lanes and the query rows they hold
-    (rows / items: how often lanes share an item), ``dispatched``
+    (rows / items: how often lanes share an item), ``topv`` / ``topi``
+    the step's fetched (lanes, k) top-k logits and their token ids
+    and ``emit_lanes`` the first lane of each entry of ``emitted``
+    (an entry's tokens come from that lane and the ones after it:
+    what a check against a reference reads, the engine's logits
+    through the cache); on a model with
+    an expert layer ``expert_counts`` is the step's (layers, experts)
+    live slots per expert as the device counted them,
+    ``expert_slots`` the slots the live lanes asked for (live lanes x
+    experts a token x layers), ``expert_dropped`` the slots no expert
+    counted (0: the layer is dropless), ``experts_touched`` the
+    (layer, expert) pairs with at least one slot, ``expert_bytes``
+    their weights' bytes (what the expert phase reads) and
+    ``expert_load_max`` the fullest expert's slots; ``dispatched``
     False for a planning-only iteration (rung-4
     rejections / whole-set preemption under injected pressure — the
     scheduler's forced-progress rule guarantees re-planning
@@ -3195,7 +3095,10 @@ class StepEvents:
 
     __slots__ = ("dispatched", "step_index", "plan", "emitted",
                  "finished", "ctx_mean", "wall_s", "host_reload_s",
-                 "kv_bytes_read", "attn_items", "attn_rows")
+                 "kv_bytes_read", "attn_items", "attn_rows", "topv", "topi",
+                 "emit_lanes",
+                 "expert_counts", "expert_slots", "expert_dropped",
+                 "experts_touched", "expert_bytes", "expert_load_max")
 
     def __init__(self, plan=None):
         self.dispatched = False
@@ -3215,6 +3118,14 @@ class StepEvents:
         self.kv_bytes_read = 0
         self.attn_items = 0
         self.attn_rows = 0
+        self.topv = self.topi = None
+        self.emit_lanes: List[int] = []
+        self.expert_counts = None
+        self.expert_slots = 0
+        self.expert_dropped = 0
+        self.experts_touched = 0
+        self.expert_bytes = 0
+        self.expert_load_max = 0
 
 
 class ServeSession:
@@ -3272,6 +3183,10 @@ class ServeSession:
         # steps whose packed lanes returned a non-finite top-k logit
         # (a NaN anywhere upstream of the head reaches them)
         self.nonfinite_steps = 0
+        # running totals of the expert layer's counters (expert_stats)
+        self.expert_totals = {"steps": 0, "slots": 0, "dropped": 0,
+                              "touched": 0, "bytes": 0}
+        self.expert_counts_total = None     # (layers, experts) int64
         self._retries0 = engine._retries
         self._rejected_seen = 0   # flight-recorder rejection trigger
         self._t0 = time.perf_counter()
@@ -3372,6 +3287,7 @@ class ServeSession:
                       "drafted": k, "accepted": matched,
                       "emitted": emitted})
         ev.emitted.append((req, emitted))
+        ev.emit_lanes.append(lane0)
         if req.is_done():
             self._finish(ev, req)
         return emitted
@@ -3451,6 +3367,41 @@ class ServeSession:
                 eng.kv_quantized))
         return arrays, lane_adapters, lane, emitters, spec_emitters, work
 
+    def _count_experts(self, ev: StepEvents, counts: np.ndarray,
+                       live: int) -> None:
+        """The step's expert counters from its (layers, experts) live
+        slots per expert, and the session's running totals."""
+        arch = self.eng.arch
+        ev.expert_counts = counts
+        ev.expert_slots = live * arch.experts_per_token * counts.shape[0]
+        ev.expert_dropped = ev.expert_slots - int(counts.sum())
+        assert ev.expert_dropped == 0, (
+            f"{ev.expert_dropped} of {ev.expert_slots} expert slots of "
+            f"the step's {live} live lanes reached no expert")
+        ev.experts_touched = int((counts > 0).sum())
+        ev.expert_bytes = ev.experts_touched * arch.expert_bytes
+        ev.expert_load_max = int(counts.max())
+        tot = self.expert_totals
+        tot["steps"] += 1
+        tot["slots"] += ev.expert_slots
+        tot["dropped"] += ev.expert_dropped
+        tot["touched"] += ev.experts_touched
+        tot["bytes"] += ev.expert_bytes
+        if self.expert_counts_total is None:
+            self.expert_counts_total = np.zeros(counts.shape, np.int64)
+        self.expert_counts_total += counts
+
+    def expert_stats(self) -> Optional[dict]:
+        """Running totals of the expert layer over this session's
+        steps (None without one): slots asked for and dropped, (layer,
+        expert) pairs touched and their weight bytes, and `counts`, the
+        (layers, experts) slots each expert has taken."""
+        if not self.eng.arch.experts:
+            return None
+        counts = self.expert_counts_total
+        return {**self.expert_totals,
+                "counts": None if counts is None else counts.copy()}
+
     def step(self) -> Optional[StepEvents]:
         """Advance one engine step. Returns None when the session is
         drained (no waiting or running requests survive the abort
@@ -3516,15 +3467,21 @@ class ServeSession:
                 "decode": plan.num_decode_lanes,
                 "kv_bytes": ev.kv_bytes_read,
                 "items": ev.attn_items, "rows": ev.attn_rows}):
-            greedy, topv, topi, _, _ = eng._dispatch_mixed(
+            greedy, topv, topi, _, _, counts = eng._dispatch_mixed(
                 eng._k_pages, eng._v_pages, *dev,
                 lane_adapters=dev_adapters)
         with timed(track, "fetch"):
             greedy = np.asarray(greedy)
             topv = np.asarray(topv)
             topi = np.asarray(topi)
+            ev.topv, ev.topi = topv, topi
+            if counts is not None:
+                self._count_experts(ev, np.asarray(counts), lane)
         dt = time.perf_counter() - tp
-        with timed(track, "emit"):
+        with timed(track, "emit", None if not eng.arch.experts else {
+                "step": step_idx, "expert_slots": ev.expert_slots,
+                "experts_touched": ev.experts_touched,
+                "expert_bytes": ev.expert_bytes}):
             if not np.isfinite(topv[:lane]).all():
                 self.nonfinite_steps += 1
             self.util.append(1.0 - cache.free_pages / c.usable_pages)
@@ -3542,6 +3499,7 @@ class ServeSession:
             dec_tokens = 0
             for ch, ln in emitters:
                 self._emit(ev, ch, greedy[ln], topv[ln], topi[ln])
+                ev.emit_lanes.append(ln)
                 if ch.is_decode:
                     dec_tokens += 1
             for ch, ln in spec_emitters:
@@ -3577,6 +3535,8 @@ class ServeSession:
             decode_widths=self.decode_widths,
             prefill_times=self.prefill_times, util=self.util)
         stats["nonfinite_logit_steps"] = self.nonfinite_steps
+        if self.eng.arch.experts:
+            stats["experts"] = self.expert_stats()
         return stats
 
     def close(self) -> None:
