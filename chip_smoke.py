@@ -367,7 +367,7 @@ def serve_phase(w: Widths, lm, clock: CompileClock, interpret: bool,
                 "prefix_hit_tokens": hits,
                 "compile_counts": eng.compile_counts(),
                 "param_devices": device_sets(eng._step_params),
-                "pool_devices": device_sets(eng._pool_args())}
+                "pool_devices": device_sets(eng.pool)}
         if eng.attn_impl != want or st["attn_impl"] != want:
             raise AssertionError(f"attention ran as {eng.attn_impl!r}, "
                                  f"not {want!r}")
@@ -419,7 +419,7 @@ def pool_phase(w: Widths, lm, interpret: bool, n: int) -> dict:
         info = {"replicas": n, "replica_devices": [list(o) for o in owned],
                 "boot_s": round(boot_s, 2), "run_s": round(run_s, 2),
                 "completed": res["completed"],
-                "pool_devices": [device_sets(r.engine._pool_args())
+                "pool_devices": [device_sets(r.engine.pool)
                                  for r in pool.replicas]}
         if len(set(owned)) != n or any(len(o) != 1 for o in owned):
             raise AssertionError(f"replicas do not own distinct chips: "

@@ -403,18 +403,15 @@ class FFConfig:
     # tokens of prefill work (FCFS; long prompts chunk across steps).
     serve_max_seqs: int = 8
     serve_prefill_budget: int = 512
-    # chunked prefill (serve/engine.py): pack prompt chunks from any
-    # number of requests together with every running decode token into
-    # ONE fixed-shape program of serve_prefill_budget + serve_max_seqs
-    # lanes — zero per-bucket recompiles, decode never stalls behind a
-    # long prompt. --no-chunked-prefill falls back to the per-bucket
-    # prefill + full-width decode pair.
-    serve_chunked_prefill: bool = True
+    # The engine's step is chunked prefill (serve/engine.py): prompt
+    # chunks from any number of requests pack together with every
+    # running decode token into ONE fixed-shape program of
+    # serve_prefill_budget + serve_max_seqs lanes — zero per-bucket
+    # recompiles, decode never stalls behind a long prompt.
     # prefix caching (serve/kv_cache.py): completed KV pages are
     # content-hashed and shared copy-free across sequences via per-page
     # refcounts, so a prompt whose prefix is already resident skips
-    # those tokens at prefill. Requires chunked prefill (the legacy
-    # prefill program re-scatters every position). --no-prefix-cache.
+    # those tokens at prefill. --no-prefix-cache.
     serve_prefix_cache: bool = True
     # admission watermark (fraction of the page pool that must stay
     # reclaimable after admitting a request's first chunk): with
@@ -538,7 +535,7 @@ class FFConfig:
     # the HBM-resident adapter pool — fixed rank-padded (A, B) slab
     # pairs, one slot per resident tenant, gathered per lane inside
     # the ONE mixed program so tenant-heterogeneous batches decode in
-    # one fixed-shape step (zero recompiles; needs chunked prefill).
+    # one fixed-shape step (zero recompiles).
     # adapter_pool_mb sizes the slot count by per-device byte budget
     # (the kv_pool_mb idiom; 0 = 1 + serve_max_seqs slots).
     # tenant_adapters is the synthetic tenant count traffic mixes and
@@ -654,11 +651,6 @@ class FFConfig:
             raise ValueError(
                 f"tenant_adapters must be >= 0, got "
                 f"{self.tenant_adapters}")
-        if self.adapter_rank > 0 and not self.serve_chunked_prefill:
-            raise ValueError(
-                "adapter_rank > 0 needs chunked prefill (the per-lane "
-                "adapter gather lives in the ONE mixed program); drop "
-                "--no-chunked-prefill")
         if not 0.0 <= self.serve_admit_watermark < 1.0:
             raise ValueError(
                 f"serve_admit_watermark must be in [0, 1), got "
@@ -889,7 +881,6 @@ class FFConfig:
         "--no-sibling-conv-fusion": "sibling_conv_fusion",
         "--no-delta-sim": "search_delta_sim",
         "--no-cost-cache": "search_cost_cache",
-        "--no-chunked-prefill": "serve_chunked_prefill",
         "--no-prefix-cache": "serve_prefix_cache",
         "--no-host-tier": "serve_host_tier",
         "--no-spec-decode": "serve_spec_decode",

@@ -353,173 +353,6 @@ def resolve_flash(use_flash, b: int, h: int, sq: int, sk: int, d: int,
             and flash_profitable(b, h, sq, sk, d))
 
 
-# ------------------------------------------------- paged attention (serve)
-#
-# The serving path (flexflow_tpu/serve): query tokens attend to their
-# sequence's K/V history, which lives in fixed-size PAGES addressed
-# through a per-sequence page table (serve/kv_cache.py — the "Ragged
-# Paged Attention" layout, PAPERS.md). Two entry points over the same
-# math:
-#
-#   * paged_attention_decode — ONE query token per sequence (the
-#     classic decode step): rows of the page table are sequences.
-#   * paged_attention_ragged — one query token per LANE, where a lane
-#     is any (sequence, position) pair: a chunked-prefill step packs
-#     prompt chunks from several sequences plus every running decode
-#     token into one call. Lanes pick their sequence's page-table row
-#     through a slot index and mask at their own position+1, so a
-#     prefill token at position p sees exactly keys 0..p even though
-#     later chunk tokens' K/V are already scattered into the pages.
-#
-# Each has two implementations with identical semantics:
-#
-#   * _paged_decode_jnp — gather pages with jnp.take, masked online-free
-#     softmax in f32. XLA lowers the gather to dynamic-gather; it is the
-#     reference the Pallas kernel is tested against, and the path the
-#     CPU tests run.
-#   * the ragged v2 Pallas kernel (kernels/paged_ragged_v2.py) — a
-#     scalar-prefetch kernel: a work list of (run of lanes of one
-#     sequence, kv-block) items, built from the page table, the
-#     lane->slot map and the lane lengths, rides in SMEM ahead of the
-#     grid so each work item DMAs exactly the pages its run's table
-#     row names, once for all the run's rows; online max/sum rescaling
-#     accumulates across a tile's items in VMEM scratch. Never
-#     materializes the gathered (B, max_len, H, D) K/V
-#     that the jnp path pays for. A decode step is the ragged call with
-#     one lane per sequence, so it runs the same kernel.
-#
-# paged_ragged_v2.resolve_paged_impl is the one rule that picks between
-# them (Pallas on a tpu backend, jnp elsewhere, either by argument).
-
-
-def _paged_decode_jnp(q, k_pages, v_pages, page_table, seq_lens, scale):
-    """q (B,H,D); k/v_pages (P, ps, H, D); page_table (B, pp) int32;
-    seq_lens (B,) int32 -> (B, H, D).
-
-    Padding page-table entries point at the sink page 0; every position
-    >= seq_len is masked to -inf before the softmax, so sink contents
-    are never observed. All statistics in f32."""
-    b, h, d = q.shape
-    ps = k_pages.shape[1]
-    pp = page_table.shape[1]
-    k = jnp.take(k_pages, page_table, axis=0)  # (B, pp, ps, H, D)
-    v = jnp.take(v_pages, page_table, axis=0)
-    k = k.reshape(b, pp * ps, h, d)
-    v = v.reshape(b, pp * ps, h, d)
-    # batch over (seq, head): s[b,h,t] = q[b,h,:] . k[b,t,h,:]
-    s = jax.lax.dot_general(
-        q, k, (((2,), (3,)), ((0, 1), (0, 2))),
-        preferred_element_type=jnp.float32) * scale     # (B, H, pp*ps)
-    pos = jax.lax.broadcasted_iota(jnp.int32, (b, 1, pp * ps), 2)
-    s = jnp.where(pos < seq_lens[:, None, None], s, -jnp.inf)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)                                  # (B, H, pp*ps) f32
-    l = jnp.sum(p, axis=-1, keepdims=True)
-    o = jax.lax.dot_general(                            # (B, H, D)
-        p, v.astype(jnp.float32), (((2,), (1,)), ((0, 1), (0, 2))),
-        preferred_element_type=jnp.float32)
-    return (o / l).astype(q.dtype)
-
-
-def paged_attention_decode(q, k_pages, v_pages, page_table, seq_lens, *,
-                           scale=None, use_pallas=None, interpret=False):
-    """Single-query attention through a page table (decode step).
-
-    q (B, H, D) — one query token per sequence; k_pages/v_pages
-    (num_pages, page_size, H, D); page_table (B, pages_per_seq) int32
-    physical page ids (0 = sink/padding); seq_lens (B,) int32 tokens
-    resident per sequence (positions >= seq_len are masked). Every
-    seq_lens entry must be >= 1: a zero-length lane has every score
-    masked, which NaNs the softmax of the jnp path and leaves garbage
-    in the kernel's — callers with empty lanes must clamp them to 1 and
-    aim their page table at the sink (serve/engine.py does exactly
-    this). Returns (B, H, D).
-
-    The Pallas path is the ragged v2 kernel with one lane per sequence
-    (lane b reads table row b). use_pallas/interpret pick the
-    implementation by paged_ragged_v2.resolve_paged_impl.
-    """
-    from .paged_ragged_v2 import (JNP, paged_attention_ragged_v2,
-                                  resolve_paged_impl)
-    if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
-    if resolve_paged_impl(use_pallas, interpret) == JNP:
-        return _paged_decode_jnp(q, k_pages, v_pages, page_table,
-                                 seq_lens, scale)
-    return paged_attention_ragged_v2(
-        q, k_pages, v_pages, page_table,
-        jnp.arange(q.shape[0], dtype=jnp.int32), seq_lens, scale=scale,
-        use_pallas=use_pallas, interpret=interpret)
-
-
-def paged_attention_ragged_v1(q, k_pages, v_pages, page_tables,
-                              lane_slots, lane_lens, *, scale=None,
-                              use_pallas=None):
-    """The PR-3 ragged attention, kept as the bit-equality ORACLE for
-    kernel v2's jnp path (tests/test_kv_quant.py). Its Pallas kernel
-    (grid (T, pages_per_seq), a per-head dot batched over a non-leading
-    axis of a (ps, H, D) block) is a form Mosaic does not compile, so
-    it is gone: use_pallas=True raises by name rather than run as
-    jnp."""
-    if use_pallas:
-        raise NotImplementedError(
-            "paged_attention_ragged_v1 has no Pallas kernel (Mosaic "
-            "cannot compile its batched per-head dot); call "
-            "paged_attention_ragged, which dispatches kernel v2")
-    if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
-    lane_tables = jnp.take(page_tables, lane_slots, axis=0)  # (T, pp)
-    return _paged_decode_jnp(q, k_pages, v_pages, lane_tables, lane_lens,
-                             scale)
-
-
-def paged_attention_ragged(q, k_pages, v_pages, page_tables, lane_slots,
-                           lane_lens, *, scale=None, use_pallas=None,
-                           interpret=False, k_scales=None, v_scales=None,
-                           block_kv=None, work=None):
-    """Ragged batched attention through page tables — the chunked
-    prefill/mixed-step kernel (serve/engine.py), v2 since PR 8
-    (kernels/paged_ragged_v2.py: a work list of (run of lanes,
-    kv-block) items with ragged skipping, head packing, and tunable
-    kv-block shapes, per the "Ragged Paged Attention" paper in
-    PAPERS.md).
-
-    q (T, H, D) — one query token per LANE, where lanes mix prompt-chunk
-    tokens from any number of sequences with single decode tokens;
-    k_pages/v_pages (num_pages, page_size, H, D); page_tables
-    (max_seqs, pages_per_seq) int32 physical page ids (0 =
-    sink/padding); lane_slots (T,) int32 selects each lane's page-table
-    row (lanes of the same sequence share a row); lane_lens (T,) int32
-    the lane's visible tokens — position + 1 for a prefill token at
-    `position`, so causality inside a chunk is exact even though the
-    whole chunk's K/V is scattered before attention runs. Every
-    lane_lens entry must be >= 1 (see paged_attention_decode). Returns
-    (T, H, D).
-
-    Quantized KV pages: pass int8 k_pages/v_pages with their
-    (num_pages, page_size, H) f32 k_scales/v_scales; the kernel (and
-    the fallback) dequantizes at read (serve/kv_cache.py).
-    block_kv tunes the kv-block shape (FFConfig.serve_attn_block_kv;
-    None = autotune-by-shape table). work is the step's WorkList
-    (paged_ragged_v2.build_work_list over these lane arrays) where a
-    caller makes one for several calls; None builds it per call.
-
-    The jnp fallback runs v1's math verbatim, so a 1-lane-per-sequence
-    fp32 call is bit-for-bit `paged_attention_decode`, and the op order
-    matches the contiguous full-prefill reference exactly (tested in
-    tests/test_serve_v2.py; v2-vs-v1 equality in tests/test_kv_quant.py).
-    use_pallas/interpret pick the implementation by
-    paged_ragged_v2.resolve_paged_impl (None = Pallas on a tpu backend,
-    jnp elsewhere).
-    """
-    from .paged_ragged_v2 import paged_attention_ragged_v2
-    return paged_attention_ragged_v2(
-        q, k_pages, v_pages, page_tables, lane_slots, lane_lens,
-        k_scales=k_scales, v_scales=v_scales, scale=scale,
-        block_kv=block_kv, work=work, use_pallas=use_pallas,
-        interpret=interpret)
-
-
 def flash_attention_bshd(q, k, v, *, causal=False,
                          block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
                          interpret=False, pad_lanes=True):

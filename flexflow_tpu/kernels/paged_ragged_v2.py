@@ -62,11 +62,11 @@ This module rebuilds the kernel along the paper's lines:
     product) inside the online-softmax accumulation. bf16 pages need
     no scales (values upcast exactly like v1's bf16 handling).
 
-Numerics contract: the jnp fallback is BIT-IDENTICAL to v1's
-(`flash_attention._paged_decode_jnp`) on fp32 — same gather, same
-dot_general dims, same single-pass softmax — so every existing
-bit-equality oracle (full-prefill per lane, one-lane == decode) holds
-verbatim under v2. The Pallas kernel is the same online softmax summed
+Numerics contract: the jnp path (`_ragged_jnp`: gather, one
+dot_general, single-pass softmax, divide after the matmul) is on fp32
+bit-identical, lane by lane, to contiguous full-prefill attention over
+the same K/V (tests/test_serve.py, tests/test_kv_quant.py) — the oracle
+every serve parity test is built on. The Pallas kernel is the same online softmax summed
 in another order, statistics and accumulator in f32. With f32 q or f32
 pages every product is f32 (HIGHEST precision on the MXU); with bf16 q
 and bf16 / int8 / fp8 pages the operands of q.k are exact in bf16 and
@@ -249,10 +249,11 @@ def _ragged_jnp(q, k_pages, v_pages, page_tables, lane_slots, lane_lens,
     """Vectorized fallback over the flattened ragged layout.
 
     Gathers each lane's pages (int8 gathers move 1/4 the bytes of f32),
-    dequantizes, and runs EXACTLY v1's math — same dot_general dims,
-    same masked single-pass softmax, same divide-after-matmul — so fp32
-    outputs are bit-identical to `flash_attention._paged_decode_jnp`
-    (the oracle every serve parity test is built on)."""
+    dequantizes, and runs one dot_general, a masked single-pass
+    softmax and the divide after the matmul — so fp32 outputs are
+    bit-identical to contiguous full-prefill attention per lane (the
+    oracle every serve parity test is built on), and the one jnp twin
+    the Pallas kernel is held to."""
     b, h, d = q.shape
     ps = k_pages.shape[1]
     lane_tables = jnp.take(page_tables, lane_slots, axis=0)  # (T, pp)
@@ -843,8 +844,20 @@ def paged_attention_ragged_v2(q, k_pages, v_pages, page_tables,
                               interpret=False):
     """Ragged batched attention through page tables — kernel v2.
 
-    Same contract as flash_attention.paged_attention_ragged (q (T,H,D),
-    one query token per lane; page 0 = sink; every lane_lens >= 1) plus:
+    q (T, H, D) — one query token per LANE, where lanes mix
+    prompt-chunk tokens from any number of sequences with single decode
+    tokens (a decode step is one lane per sequence); k_pages/v_pages
+    (num_pages, page_size, H, D); page_tables (max_seqs, pages_per_seq)
+    int32 physical page ids (0 = sink/padding); lane_slots (T,) int32
+    selects each lane's page-table row (lanes of the same sequence
+    share a row); lane_lens (T,) int32 the lane's visible tokens —
+    position + 1 for a prefill token at `position`, so causality inside
+    a chunk is exact even though the whole chunk's K/V is scattered
+    before attention runs. Every lane_lens entry must be >= 1: a
+    zero-length lane has every score masked, which NaNs the softmax of
+    the jnp path and leaves garbage in the kernel's — callers with
+    empty lanes clamp them to 1 and aim their page table at the sink
+    (serve/engine.py does exactly this). Returns (T, H, D).
 
       k_scales/v_scales — (num_pages, page_size, H) f32 per-page scale
         arrays for int8 K/V pages (None = unquantized pages; the two
@@ -857,9 +870,8 @@ def paged_attention_ragged_v2(q, k_pages, v_pages, page_tables,
         the grid bound that holds for any lane arrays (and takes the
         lanes in several calls where one list would not fit SMEM).
 
-    fp32 outputs are bit-identical to v1 on the jnp path (same math);
-    the Pallas kernel agrees with it to f32 rounding (it sums in a
-    different order). use_pallas/interpret pick the implementation by
+    The Pallas kernel agrees with the jnp path to f32 rounding (it
+    sums in a different order). use_pallas/interpret pick the implementation by
     resolve_paged_impl.
     """
     if (k_scales is None) != (v_scales is None):

@@ -19,7 +19,8 @@ telemetry-driven :class:`Autoscaler`, serving the seeded timed traffic
 number (docs/serving.md "Multi-replica routing").
 """
 
-from .kv_cache import KVCacheConfig, PagedKVCache, prefix_page_keys
+from .kv_cache import (KVCacheConfig, KVPool, PagedKVCache,
+                       prefix_page_keys)
 from .scheduler import (ChunkPlan, ContinuousBatchingScheduler,
                         RejectedRequest, Request, RequestOutcome,
                         RequestState, SampleParams, StepPlan)

@@ -101,7 +101,7 @@ class TransformerLM:
         self.ff_dim = int(
             model.state.params["layer0_ff1"]["kernel"].shape[1])
 
-    def refuse(self, *, tp: int, adapters: bool, chunked: bool) -> None:
+    def refuse(self, *, tp: int, adapters: bool) -> None:
         """Raise for an engine path this model is not served on."""
 
     def embed(self, params, tokens, positions):
@@ -242,7 +242,7 @@ class OLMoE:
         self.expert_bytes = int(3 * self.hidden * self.ff_dim
                                 * w.dtype.itemsize)
 
-    def refuse(self, *, tp: int, adapters: bool, chunked: bool) -> None:
+    def refuse(self, *, tp: int, adapters: bool) -> None:
         if tp > 1:
             raise NotImplementedError(
                 "OLMoE serving is single-device: tensor-parallel serving "
@@ -252,11 +252,6 @@ class OLMoE:
             raise NotImplementedError(
                 "OLMoE serving has no adapter pool: adapter_rank > 0 "
                 "adapts the dense feed-forward, which this model lacks")
-        if not chunked:
-            raise NotImplementedError(
-                "OLMoE is served by the mixed step only: the legacy "
-                "bucket-prefill path (serve_chunked_prefill=False) "
-                "is the transformer_lm block's")
 
     def embed(self, params, tokens, positions):
         return jnp.take(params["tok_embed"]["kernel"], tokens, axis=0,

@@ -260,7 +260,7 @@ class DisaggCluster:
             # engine `index` (prefill engines first, then decode) owns
             # its own chips, like a ReplicaPool replica
             return ServeEngine(
-                model, chunked_prefill=True, prefix_cache=True,
+                model, prefix_cache=True,
                 spec_tokens=spec_tokens, drafter=drafter,
                 use_pallas=use_pallas, interpret=interpret,
                 replica=index, telemetry=self.telemetry, config=role_cfg)
@@ -416,7 +416,7 @@ class DisaggCluster:
 
     def check_invariants(self) -> None:
         for _, eng in self.engines():
-            eng.cache.check_invariants()
+            eng.cache.check_invariants(eng.pool)
             if eng.adapters is not None:
                 eng.adapters.check_invariants()
 

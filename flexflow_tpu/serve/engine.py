@@ -1,7 +1,7 @@
 """ServeEngine: one jitted MIXED step over a paged KV-cache.
 
 Wraps an LM built by models/transformer.build_transformer_lm into the
-serving hot path. The default (chunked-prefill) engine runs ONE program:
+serving hot path. The engine runs ONE program:
 
   mixed — a fixed-width batch of `serve_prefill_budget + serve_max_seqs`
     LANES, each lane one (sequence, position) query token. Prompt
@@ -9,7 +9,7 @@ serving hot path. The default (chunked-prefill) engine runs ONE program:
     every running sequence pack into the same step: K/V for all lanes
     scatters into each sequence's pages, then every lane attends
     through its page-table row masked at its own position + 1
-    (kernels/flash_attention.paged_attention_ragged), so causality is
+    (kernels/paged_ragged_v2.paged_attention_ragged_v2), so causality is
     exact and decode lanes never stall behind a long prompt. Logits
     reduce to a greedy argmax plus a static top-k head (for seeded
     temperature / top-k sampling) before leaving the device.
@@ -17,9 +17,7 @@ serving hot path. The default (chunked-prefill) engine runs ONE program:
 Static shapes are the whole game on TPU: the mixed step has ONE
 geometry, so XLA compiles ONE serving program — ever. After `warmup()` a
 serving process never recompiles (generate() can assert this via
-`compile_counts()`), which is what keeps p99 latency flat. The PR 1
-per-bucket prefill + full-width decode pair is retained behind
-`serve_chunked_prefill=False` (FFConfig) as the legacy path.
+`compile_counts()`), which is what keeps p99 latency flat.
 
 Speculative decoding (serve/speculative.py, docs/serving.md) spends
 spare prefill-budget lanes of the SAME program: a host-side drafter
@@ -30,11 +28,12 @@ told us so), and rejected tokens' pages roll back — several tokens per
 dispatch on repetitive text, token-identical output always, zero new
 program shapes.
 
-The engine owns a PERSISTENT PagedKVCache and device page arrays:
-prefix pages committed by one generate() call are matchable by the
-next, so a shared system preamble is computed once per process, not
-once per batch. Caches flow functionally: the jitted steps take the
-page arrays donated and return the updated ones, so the update is
+The engine owns a PERSISTENT PagedKVCache and its device pool
+(`self.pool`, a serve/kv_cache.KVPool — the one type that knows the
+pool's layout): prefix pages committed by one generate() call are
+matchable by the next, so a shared system preamble is computed once per
+process, not once per batch. The pool flows functionally: the jitted
+programs take it donated and return the updated one, so the update is
 in-place on device and the host never holds two copies.
 
 The engine reads weights straight out of the compiled FFModel's
@@ -59,12 +58,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import CompMode
-from ..kernels.flash_attention import (paged_attention_decode,
-                                       paged_attention_ragged)
 from ..kernels.paged_ragged_v2 import (JNP, PALLAS_INTERPRET, Q_ROWS,
                                        build_work_list, choose_block_kv,
                                        kv_page_bytes, max_work_items,
-                                       quantize_kv_rows,
+                                       paged_attention_ragged_v2,
                                        ragged_dispatch_passes,
                                        resolve_paged_impl, work_items)
 from ..parallel.mesh import TENSOR, replica_devices, serve_tensor_mesh
@@ -72,7 +69,8 @@ from ..utils.faults import FaultInjector, TransientError, injector_for
 from ..utils.telemetry import (Telemetry, pow2_bucket, serve_metrics,
                                telemetry_for)
 from .arch import _dense, describe
-from .kv_cache import KVCacheConfig, PagedKVCache, kv_storage_dtype
+from .kv_cache import (KVCacheConfig, KVPool, PagedKVCache,
+                       kv_storage_dtype)
 from .scheduler import (ChunkPlan, ContinuousBatchingScheduler, Request,
                         RequestOutcome, RequestState, SampleParams)
 
@@ -179,10 +177,9 @@ class ServeEngine:
     model must be compiled (any comp_mode); if not, it is compiled here
     in INFERENCE mode (no optimizer slots). All serving knobs come from
     the model's FFConfig (kv_page_size / kv_num_pages / serve_max_seqs /
-    serve_prefill_budget / serve_chunked_prefill / serve_prefix_cache /
-    serve_admit_watermark); `chunked_prefill` / `prefix_cache` override
-    the config (tools that A/B the optimisations build two engines over
-    one model).
+    serve_prefill_budget / serve_prefix_cache / serve_admit_watermark);
+    `prefix_cache` overrides the config (tools that A/B the
+    optimisation build two engines over one model).
     """
 
     # static top-k head width: sampling draws from the top
@@ -200,7 +197,6 @@ class ServeEngine:
 
     def __init__(self, model, *, max_seq_len: Optional[int] = None,
                  use_pallas: Optional[bool] = None, interpret: bool = False,
-                 chunked_prefill: Optional[bool] = None,
                  prefix_cache: Optional[bool] = None,
                  spec_tokens: Optional[int] = None,
                  drafter=None, faults: Optional[FaultInjector] = None,
@@ -245,16 +241,12 @@ class ServeEngine:
             max_seq_len=max_seq_len, tensor_parallel=self.tp)
         self.cache_cfg.validate()
         cfg = self.config
-        self.chunked_prefill = bool(
-            getattr(cfg, "serve_chunked_prefill", True)
-            if chunked_prefill is None else chunked_prefill)
         self.prefix_cache = bool(
             getattr(cfg, "serve_prefix_cache", True)
-            if prefix_cache is None else prefix_cache) \
-            and self.chunked_prefill
+            if prefix_cache is None else prefix_cache)
         # what this model is not served on raises HERE, by name
         self.arch.refuse(
-            tp=self.tp, chunked=self.chunked_prefill,
+            tp=self.tp,
             adapters=int(getattr(cfg, "adapter_rank", 0) or 0) > 0)
         self.prefill_budget = int(getattr(cfg, "serve_prefill_budget", 512))
         self.admit_watermark = float(
@@ -318,14 +310,14 @@ class ServeEngine:
         self._cancels: set = set()  # rids cancel() marked, swept at
         self._active: Dict[int, Request] = {}   # chunk boundaries
         # speculative decoding (serve/speculative.py): max drafted
-        # tokens per sequence per step. Needs the mixed program (draft
-        # lanes are chunk lanes); 0 disables and the engine is
-        # bit-for-bit the non-speculative one. `spec_tokens`/`drafter`
+        # tokens per sequence per step (draft lanes are chunk lanes);
+        # 0 disables and the engine is bit-for-bit the
+        # non-speculative one. `spec_tokens`/`drafter`
         # override the config for A/B benches and draft-LM plugins.
         if spec_tokens is None:
             spec_tokens = int(getattr(cfg, "serve_spec_tokens", 4)) \
                 if getattr(cfg, "serve_spec_decode", True) else 0
-        self.spec_tokens = int(spec_tokens) if self.chunked_prefill else 0
+        self.spec_tokens = int(spec_tokens)
         self.drafter = drafter
         # KV-page storage format (serve/kv_cache.py, PR 8): lossless
         # f32 keeps the bit-exactness oracle; bf16 rounds on write
@@ -346,19 +338,6 @@ class ServeEngine:
         # coarser than int8's 127-step grid at amax scale
         self.kv_tie_margin = 0.25 if self.kv_dtype == "float8_e4m3" \
             else 0.05
-        if self.kv_quantized and not self.chunked_prefill:
-            raise ValueError(
-                f"kv_dtype={self.kv_dtype!r} needs the chunked mixed "
-                f"program (quantize-on-write lives in the mixed step); "
-                f"the legacy bucket-prefill path supports "
-                f"float32/bfloat16")
-        if (self.tp > 1 or self._home is not None) \
-                and not self.chunked_prefill:
-            raise ValueError(
-                "sharded serving (serve_mesh / tensor_parallel > 1) and "
-                "replica placement apply to the ONE mixed program; the "
-                "legacy bucket-prefill path is single-device only, on "
-                "the default device")
         # ragged kernel v2 kv-block shape: explicit knob, else the
         # autotune-by-shape table (kernels/paged_ragged_v2.py) — sized
         # for the PER-DEVICE head count, which is what the sharded
@@ -419,10 +398,7 @@ class ServeEngine:
                                    "spilled_pages": 0,
                                    "recompute_chosen": 0,
                                    "reload_priced_s": 0.0}
-        self._k_pages = None
-        self._v_pages = None
-        self._k_scales = None
-        self._v_scales = None
+        self.pool: Optional[KVPool] = None   # lazy: _device_pool()
         # multi-tenant LoRA adapter pool (serve/adapters.py): fixed
         # rank-padded HBM slabs managed like the KV pool, slot 0 the
         # reserved all-zero base slab so base and adapted lanes mix in
@@ -436,11 +412,6 @@ class ServeEngine:
         self._adapter_specs = None     # PartitionSpec dict (tp > 1)
         self._adapter_shardings = None
         if int(getattr(cfg, "adapter_rank", 0) or 0) > 0:
-            if not self.chunked_prefill:
-                raise ValueError(
-                    "adapter_rank > 0 needs the chunked mixed program "
-                    "(the per-lane adapter gather lives in the mixed "
-                    "step); the legacy bucket path serves base-only")
             from .adapters import AdapterConfig, AdapterPool
             self.adapter_cfg = AdapterConfig.from_ff(
                 cfg, num_layers=self.num_layers, hidden=self.hidden,
@@ -471,8 +442,7 @@ class ServeEngine:
                 self._adapter_shardings = {
                     k: NamedSharding(self.tp_mesh, s)
                     for k, s in self._adapter_specs.items()}
-        # prompt-length buckets (legacy path + generate_reference):
-        # powers of two from one page up to the serveable length. The
+        # prompt-length buckets (generate_reference): powers of two from one page up to the serveable length. The
         # page-table ceiling rounds UP to whole pages, but a bucket
         # wider than max_seq_len would forward positions the model
         # never learned (and no admissible request can need)
@@ -484,29 +454,18 @@ class ServeEngine:
             self.buckets.append(b)
             b *= 2
         self.buckets.append(cap)
-        # the mixed-step programs: single-device, or shard_map'd over
-        # the serve mesh (same lane contract, same donation) — ONE
-        # program either way, so the zero-recompile gate is unchanged
+        # the mixed step: single-device, or shard_map'd over the serve
+        # mesh (same lane contract, same donation) — ONE program
+        # either way, keyed by the pool's pytree (quantized pools carry
+        # their scale arrays through the same step, donated alongside)
         if self.tp > 1:
             self._step_params, self._param_specs = self._shard_params()
-            self._mixed_jit = jax.jit(self._mixed_tp_impl,
-                                      donate_argnums=(1, 2))
-            self._mixed_q_jit = jax.jit(self._mixed_q_tp_impl,
-                                        donate_argnums=(1, 2, 3, 4))
         else:
             # a placed replica holds its own copy of the weights on its
             # chip; an unplaced engine reads the model's arrays in place
             self._step_params = self.params if self._home is None \
                 else jax.device_put(self.params, self._home)
-            self._mixed_jit = jax.jit(self._mixed_impl,
-                                      donate_argnums=(1, 2))
-            # quantized pools thread the scale arrays through the same
-            # step, donated alongside the pages
-            self._mixed_q_jit = jax.jit(self._mixed_q_impl,
-                                        donate_argnums=(1, 2, 3, 4))
-        self._prefill_jit = jax.jit(self._prefill_impl,
-                                    donate_argnums=(1, 2))
-        self._decode_jit = jax.jit(self._decode_impl, donate_argnums=(1, 2))
+        self._mixed_jit = jax.jit(self._mixed_impl, donate_argnums=(1,))
         self._forward_jit = jax.jit(self._forward_logits)  # naive reference
         if self.adapters is not None:
             # the on-demand tenant load: donate-in-place row write into
@@ -522,21 +481,9 @@ class ServeEngine:
         # pages_per_seq with 0 — the sink-page convention — so ONE
         # program geometry serves every shipment size and the
         # zero-recompile contract extends to handoff traffic. Import
-        # donates the pool arrays exactly like the mixed step.
-        self._n_pools = 4 if self.kv_quantized else 2
-        _imp_donate = tuple(range(1, 1 + self._n_pools))
-        if self.tp > 1:
-            self._export_jit = jax.jit(self._export_tp_impl,
-                                       static_argnums=(0,))
-            self._import_jit = jax.jit(self._import_tp_impl,
-                                       static_argnums=(0,),
-                                       donate_argnums=_imp_donate)
-        else:
-            self._export_jit = jax.jit(self._export_impl,
-                                       static_argnums=(0,))
-            self._import_jit = jax.jit(self._import_impl,
-                                       static_argnums=(0,),
-                                       donate_argnums=_imp_donate)
+        # donates the pool exactly like the mixed step.
+        self._export_jit = jax.jit(self._export_impl)
+        self._import_jit = jax.jit(self._import_impl, donate_argnums=(0,))
         # per-function compile accounting, owned by the ProgramRegistry
         # (core/programs.py): every serving dispatch resolves through
         # registry.call, which AOT-compiles on a new argument signature
@@ -550,12 +497,8 @@ class ServeEngine:
         self.programs = ProgramRegistry(
             self._program_fingerprint(),
             cache_dir=getattr(cfg, "program_cache_dir", None))
-        for fam in ("prefill", "decode", "mixed", "adapter"):
+        for fam in ("mixed", "adapter", "export", "import"):
             self.programs.register(fam)
-        # export/import carry the pool count as a static argnum: its
-        # VALUE keys the cache and is stripped at executable dispatch
-        self.programs.register("export", static_argnums=(0,))
-        self.programs.register("import", static_argnums=(0,))
         self.programs_restored = self.programs.load_warm()
         self._events_ok = True
         self._compiles = self.programs._compiles
@@ -580,10 +523,10 @@ class ServeEngine:
         attempt = 0
         while True:
             try:
-                # fault-injection site: serve.mixed / serve.prefill /
-                # serve.decode, fired at the dispatch boundary (BEFORE
-                # the jitted call, so donated buffers are untouched
-                # when an injected fault raises)
+                # fault-injection site: serve.mixed (serve.export,
+                # ...), fired at the dispatch boundary (BEFORE the
+                # jitted call, so donated buffers are untouched when
+                # an injected fault raises)
                 self.faults.fire(f"serve.{name}")
                 # the registry resolves (family, argument signature) to
                 # a compiled executable: hit -> dispatch (possibly an
@@ -599,7 +542,7 @@ class ServeEngine:
                 # that consumed them before dying cannot be redone.
                 attempt += 1
                 if attempt > self.max_retries or any(
-                        a.is_deleted() for a in args
+                        a.is_deleted() for a in jax.tree.leaves(args)
                         if hasattr(a, "is_deleted")):
                     raise
                 self._retries += 1
@@ -650,7 +593,6 @@ class ServeEngine:
             "layer_norm": self.layer_norm,
             "act_dtype": str(self.act_dtype),
             "max_seq_len": self._max_seq_len,
-            "chunked_prefill": self.chunked_prefill,
             "prefill_budget": self.prefill_budget,
             "mixed_width": self.mixed_width,
             "topk_cap": self.topk_cap,
@@ -869,17 +811,6 @@ class ServeEngine:
             out[name], specs[name] = o, s
         return out, specs
 
-    def _page_shardings(self):
-        """(page, scale) NamedShardings over the serve mesh's head
-        axis; single-device, the placed replica's chip (None unplaced)."""
-        if self.tp_mesh is None:
-            return self._home, self._home
-        from jax.sharding import NamedSharding
-        from jax.sharding import PartitionSpec as P
-        return (NamedSharding(self.tp_mesh,
-                              P(None, None, None, TENSOR, None)),
-                NamedSharding(self.tp_mesh, P(None, None, None, TENSOR)))
-
     def _sharding_stats(self) -> Optional[dict]:
         """The last_stats/serve_report sharding block: mesh shape,
         heads per device, per-device KV pool bytes, and the analytic
@@ -906,8 +837,8 @@ class ServeEngine:
         PER-DEVICE program under sharding, so serve_bench's sharded
         FLOPs-per-device gate reads a measured number, not the analytic
         formula it is checking. Lowers the engine's mixed step at its
-        fixed geometry over abstract ShapeDtypeStructs for the pool
-        operands (AOT — nothing executes, and no duplicate KV pool is
+        fixed geometry over the resident pool's ShapeDtypeStructs
+        (AOT — nothing executes, and no duplicate KV pool is
         materialized next to the resident one) and returns the
         backend's dict ({'flops': ...,} etc.), or None where the
         backend doesn't implement cost analysis. The AOT compile is
@@ -915,22 +846,15 @@ class ServeEngine:
         call it outside timed/recompile-gated regions anyway."""
         c = self.cache_cfg
         T = self.mixed_width
-        page_sh, scale_sh = self._page_shardings()
-        pool = jax.ShapeDtypeStruct(
-            (c.num_layers, c.num_pages, c.page_size, c.num_heads,
-             c.head_dim), c.storage_dtype, sharding=page_sh)
+        pool = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=a.sharding),
+            self._device_pool())
         i32 = jnp.int32
         lane = jnp.zeros((T,), i32)
-        args = (self._step_params, pool, pool)
-        jitted = self._mixed_jit
-        if self.kv_quantized:
-            scales = jax.ShapeDtypeStruct(
-                c.scale_shape, jnp.float32, sharding=scale_sh)
-            args += (scales, scales)
-            jitted = self._mixed_q_jit
-        args += (lane, lane, lane, lane,
-                 jnp.zeros((c.max_seqs, c.pages_per_seq), i32),
-                 lane, lane)
+        args = (self._step_params, pool, lane, lane, lane, lane,
+                jnp.zeros((c.max_seqs, c.pages_per_seq), i32),
+                lane, lane)
         if self.adapters is not None:
             slabs = {
                 key: jax.ShapeDtypeStruct(
@@ -942,7 +866,7 @@ class ServeEngine:
         else:
             args += (None, None)
         try:
-            ca = jitted.lower(*args).compile().cost_analysis()
+            ca = self._mixed_jit.lower(*args).compile().cost_analysis()
         except (NotImplementedError, jax.errors.JaxRuntimeError):
             return None
         return dict(ca) if ca else None
@@ -980,34 +904,20 @@ class ServeEngine:
                        self.arch.final_norm(params, x))  # (T, Vp/t)
         return jax.lax.all_gather(local, axis, axis=1, tiled=True)
 
-    # ---------------- full-sequence forward (prefill + reference) ------
-    def _forward_tokens(self, params, tokens, length, kv=None):
-        """Causal forward over (1, S) padded tokens; returns the
-        logits of position length-1 plus the (possibly updated)
-        caches. `kv = (k_pages, v_pages, pt_row)` scatters each
-        layer's K/V into the sequence's pages on the way through
-        (legacy prefill); kv=None is the pure no-cache forward (the
-        naive reference) — ONE implementation so the parity oracle and
-        the legacy serving path can never drift apart."""
-        ps = self.cache_cfg.page_size
+    # ---------------- full-sequence forward (the reference) -----------
+    def _forward_logits(self, params, tokens, length):
+        """Causal no-cache forward over (1, S) padded tokens; returns
+        the logits of position length-1 — the naive greedy-decode
+        reference generate() is tested against."""
         s = tokens.shape[1]
         positions = jnp.arange(s, dtype=jnp.int32)[None, :]
         arch = self.arch
         x = arch.embed(params, tokens, positions)         # (1, S, E)
-        if kv is not None:
-            k_pages, v_pages, pt_row = kv
-            pages = jnp.take(pt_row, positions[0] // ps)  # (S,)
-            offs = positions[0] % ps
         scale = 1.0 / np.sqrt(self.head_dim)
         causal = jnp.tril(jnp.ones((s, s), dtype=bool))
         for i in range(self.num_layers):
             q, k, v = arch.qkv(params, i, arch.norm1(params, i, x),
                                positions)                 # (1, S, H, D)
-            if kv is not None:
-                k_pages = k_pages.at[i, pages, offs].set(
-                    k[0].astype(k_pages.dtype))
-                v_pages = v_pages.at[i, pages, offs].set(
-                    v[0].astype(v_pages.dtype))
             logits = jnp.einsum("bihd,bjhd->bhij", q, k,
                                 preferred_element_type=jnp.float32) * scale
             logits = jnp.where(causal, logits, -jnp.inf)
@@ -1025,140 +935,72 @@ class ServeEngine:
             x = arch.attn_out(params, i, o, x)
             x, _ = arch.ffn(params, i, x)
         logits = arch.head(params, x)                     # (1, S, V)
-        last = jnp.take(logits[0], length - 1, axis=0)    # (V,)
-        return last, (None if kv is None else (k_pages, v_pages))
+        return jnp.take(logits[0], length - 1, axis=0)    # (V,)
 
     # ---------------- the mixed step (chunked prefill + decode) --------
-    def _mixed_impl(self, params, k_pages, v_pages, tokens, positions,
-                    write_pages, write_offs, page_tables, lane_slots,
-                    lane_lens, lane_adapters=None, adapters=None):
+    def _mixed_impl(self, params, pool, tokens, positions, write_pages,
+                    write_offs, page_tables, lane_slots, lane_lens,
+                    lane_adapters=None, adapters=None):
         """ONE serving step over `mixed_width` LANES. Per lane (all
         (T,) int32, HOST-built): the token to embed, its position, the
         physical (page, offset) its K/V lands in (inactive lanes aim at
         the sink page 0), the page-table row it reads
         (lane_slots -> page_tables (max_seqs, pages_per_seq)) and its
         visible length (position + 1; inactive lanes clamp to 1 so the
-        masked softmax stays NaN-free). All lanes' K/V is scattered
-        per layer BEFORE attention, so chunk tokens of one sequence see
-        each other causally and decode lanes see every prefix page —
-        including pages another request's chunk computes in this very
-        step (the intra-step prefix-sharing contract,
+        masked softmax stays NaN-free). All lanes' K/V is written to
+        `pool` (donated) per layer BEFORE attention, so chunk tokens of
+        one sequence see each other causally and decode lanes see every
+        prefix page — including pages another request's chunk computes
+        in this very step (the intra-step prefix-sharing contract,
         serve/scheduler.py). Inactive lanes compute garbage the host
         never reads. Returns (greedy (T,), top-k values (T, K), top-k
-        ids (T, K), k_pages, v_pages) — the static top-k head feeds
-        host-side seeded sampling without shipping (T, vocab) logits."""
-        out, (k_pages, v_pages) = self._mixed_body(
-            params, k_pages, v_pages, None, None, tokens, positions,
-            write_pages, write_offs, page_tables, lane_slots, lane_lens,
-            lane_adapters=lane_adapters, adapters=adapters)
-        return (*out, k_pages, v_pages)
+        ids (T, K)[, expert counts], pool) — the static top-k head
+        feeds host-side seeded sampling without shipping (T, vocab)
+        logits.
 
-    def _mixed_q_impl(self, params, k_pages, v_pages, k_scales, v_scales,
-                      tokens, positions, write_pages, write_offs,
-                      page_tables, lane_slots, lane_lens,
-                      lane_adapters=None, adapters=None):
-        """The mixed step over an int8 page pool: identical lane
-        contract, but every lane's K/V row quantizes on write (per-row
-        amax scale into the per-page scale arrays) and the ragged
-        kernel dequantizes at read. Scale arrays are donated and
-        returned like the page arrays."""
-        out, (k_pages, v_pages, k_scales, v_scales) = self._mixed_body(
-            params, k_pages, v_pages, k_scales, v_scales, tokens,
-            positions, write_pages, write_offs, page_tables, lane_slots,
-            lane_lens, lane_adapters=lane_adapters, adapters=adapters)
-        return (*out, k_pages, v_pages, k_scales, v_scales)
+        With a serve mesh the same body runs shard_map'd over it: each
+        device on its H/t heads of the params and the pool (tp_axis
+        threads the psums / all-gather; scales shard on the same head
+        axis and per-row quantization is per-head, so each device's
+        stored rows are BIT-identical to the unsharded engine's rows
+        for those heads). check_vma off: the replicated outputs come
+        out of collectives, which the static replication checker
+        cannot always see through."""
+        def step(*args, tp_axis=None):
+            out, pool = self._mixed_body(*args, tp_axis=tp_axis)
+            return (*out, pool)
 
-    # ---------------- the sharded mixed step ---------------------------
-    def _tp_step_specs(self, quantized: bool):
-        """(in_specs, out_specs) of the shard_map'd mixed step: params
-        per _shard_params, pages/scales on the head axis, every host-
-        built lane array replicated, the emitted token streams
-        replicated (psum/all-gather results are)."""
+        args = (params, pool, tokens, positions, write_pages, write_offs,
+                page_tables, lane_slots, lane_lens, lane_adapters,
+                adapters)
+        if self.tp_mesh is None:
+            return step(*args)
+        import functools
+
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-        page = P(None, None, None, TENSOR, None)
-        scl = P(None, None, None, TENSOR)
-        rep = P()
-        ins = (self._param_specs, page, page)
-        if quantized:
-            ins += (scl, scl)
-        ins += (rep,) * 7
-        # adapter operands: lane slot indices replicated; the slab
-        # dict per _adapter_specs (unarmed engines pass None — an
-        # empty pytree any prefix spec matches)
-        ins += (rep, self._adapter_specs
-                if self._adapter_specs is not None else rep)
-        outs = (rep, rep, rep, page, page)
-        if quantized:
-            outs += (scl, scl)
-        return ins, outs
-
-    def _mixed_tp_impl(self, params, k_pages, v_pages, tokens, positions,
-                       write_pages, write_offs, page_tables, lane_slots,
-                       lane_lens, lane_adapters=None, adapters=None):
-        """The mixed step shard_map'd over the serve mesh: identical
-        lane contract and donation; each device runs _mixed_body on its
-        H/t heads of the params and pages (tp_axis threads the psums /
-        all-gather). check_vma off: the replicated outputs come out of
-        collectives, which the static replication checker cannot always
-        see through."""
-        from jax import shard_map
-        ins, outs = self._tp_step_specs(False)
-
-        def body(params, kp, vp, tokens, positions, write_pages,
-                 write_offs, page_tables, lane_slots, lane_lens,
-                 lane_adapters, adapters):
-            out, (kp, vp) = self._mixed_body(
-                params, kp, vp, None, None, tokens, positions,
-                write_pages, write_offs, page_tables, lane_slots,
-                lane_lens, lane_adapters=lane_adapters,
-                adapters=adapters, tp_axis=TENSOR)
-            return (*out, kp, vp)
-
-        return shard_map(body, mesh=self.tp_mesh, in_specs=ins,
-                         out_specs=outs, check_vma=False)(
-            params, k_pages, v_pages, tokens, positions, write_pages,
-            write_offs, page_tables, lane_slots, lane_lens,
-            lane_adapters, adapters)
-
-    def _mixed_q_tp_impl(self, params, k_pages, v_pages, k_scales,
-                         v_scales, tokens, positions, write_pages,
-                         write_offs, page_tables, lane_slots, lane_lens,
-                         lane_adapters=None, adapters=None):
-        """The quantized mixed step over the serve mesh: scale arrays
-        shard on the same head axis as the pages, and per-row
-        quantization is per-head — so each device's quantized rows are
-        BIT-identical to the unsharded engine's rows for those heads
-        (the execution-path-invariance contract transfers verbatim)."""
-        from jax import shard_map
-        ins, outs = self._tp_step_specs(True)
-
-        def body(params, kp, vp, ks, vs, tokens, positions, write_pages,
-                 write_offs, page_tables, lane_slots, lane_lens,
-                 lane_adapters, adapters):
-            out, (kp, vp, ks, vs) = self._mixed_body(
-                params, kp, vp, ks, vs, tokens, positions, write_pages,
-                write_offs, page_tables, lane_slots, lane_lens,
-                lane_adapters=lane_adapters, adapters=adapters,
-                tp_axis=TENSOR)
-            return (*out, kp, vp, ks, vs)
-
-        return shard_map(body, mesh=self.tp_mesh, in_specs=ins,
-                         out_specs=outs, check_vma=False)(
-            params, k_pages, v_pages, k_scales, v_scales, tokens,
-            positions, write_pages, write_offs, page_tables, lane_slots,
-            lane_lens, lane_adapters, adapters)
+        # params per _shard_params, the pool on the head axis, every
+        # host-built lane array replicated (the adapter lanes too; the
+        # slabs per _adapter_specs — unarmed engines pass None, an
+        # empty pytree any prefix spec matches), the emitted token
+        # streams replicated (psum/all-gather results are)
+        rep, pool_spec = P(), KVPool.specs(TENSOR)
+        ins = (self._param_specs, pool_spec) + (rep,) * 8 + (
+            self._adapter_specs if self._adapter_specs is not None
+            else rep,)
+        return shard_map(functools.partial(step, tp_axis=TENSOR),
+                         mesh=self.tp_mesh, in_specs=ins,
+                         out_specs=(rep, rep, rep, pool_spec),
+                         check_vma=False)(*args)
 
     @jax.named_scope("serve_step")
-    def _mixed_body(self, params, k_pages, v_pages, k_scales, v_scales,
-                    tokens, positions, write_pages, write_offs,
-                    page_tables, lane_slots, lane_lens,
+    def _mixed_body(self, params, pool, tokens, positions, write_pages,
+                    write_offs, page_tables, lane_slots, lane_lens,
                     lane_adapters=None, adapters=None, tp_axis=None):
-        """Shared mixed-step body. Storage-dtype handling per layer:
-        f32 pages store activation values exactly (the bit-exactness
-        path); bf16 pages round on the scatter (the .at[].set cast);
-        quantized (int8/fp8) pages quantize each (lane, head) row
-        against its own amax scale BEFORE any lane attends, so what a
-        lane reads back this very step is already the dequantized
+        """The mixed step's body -> (outputs, pool). Every layer
+        writes its lanes' K/V to the pool (KVPool.write: the storage
+        format's cast or quantization) BEFORE any lane attends, so
+        what a lane reads back this very step is already the stored
         value — quantized content is therefore invariant to chunk
         boundaries, preemption replays, and speculative rollbacks
         (every token's row quantizes independently).
@@ -1169,7 +1011,6 @@ class ServeEngine:
         unsharded bits), the two per-layer psums complete the
         row-parallel projections, and the head all-gathers its vocab
         shards. Exactly one program geometry either way."""
-        quantized = k_scales is not None
         # named scopes (docs/observability.md "Device scopes"): metadata
         # only — under the root `serve_step` they name each device
         # operation's phase and layer in a profiler trace and change no
@@ -1211,14 +1052,12 @@ class ServeEngine:
         expert_counts = []
         for i in range(self.num_layers):
             with scope(f"layer{i}"):
-                x, k_pages, v_pages, k_scales, v_scales, counts = \
-                    self._mixed_layer(
-                        params, i, x, positions, live, k_pages, v_pages,
-                        k_scales, v_scales, write_pages, write_offs,
-                        page_tables, lane_slots, lane_lens, work, scale,
-                        None if ad is None else
-                        {key: arr[:, i] for key, arr in ad.items()},
-                        ad_s, tp_axis)
+                x, pool, counts = self._mixed_layer(
+                    params, i, x, positions, live, pool, write_pages,
+                    write_offs, page_tables, lane_slots, lane_lens, work,
+                    scale, None if ad is None else
+                    {key: arr[:, i] for key, arr in ad.items()},
+                    ad_s, tp_axis)
                 expert_counts.append(counts)
         with scope("head"):
             logits = (self._head_tp(params, x, tp_axis) if tp_axis
@@ -1229,17 +1068,14 @@ class ServeEngine:
                    topv.astype(jnp.float32), topi.astype(jnp.int32))
         if self.arch.experts:
             out += (jnp.stack(expert_counts),)           # (layers, E)
-        caches = (k_pages, v_pages, k_scales, v_scales) if quantized \
-            else (k_pages, v_pages)
-        return out, caches
+        return out, pool
 
-    def _mixed_layer(self, params, i, x, positions, live, k_pages,
-                     v_pages, k_scales, v_scales, write_pages, write_offs,
-                     page_tables, lane_slots, lane_lens, work, scale, la,
-                     ad_s, tp_axis):
+    def _mixed_layer(self, params, i, x, positions, live, pool,
+                     write_pages, write_offs, page_tables, lane_slots,
+                     lane_lens, work, scale, la, ad_s, tp_axis):
         """Layer `i` of the mixed step, one named scope per phase:
         `ln`, `qkv` (the description's projections at the lanes'
-        positions), `kv_write` (quantize and scatter into the pools),
+        positions), `kv_write` (KVPool.write: quantize and scatter),
         `attn` (the ragged paged kernel over the step's `work` list),
         `attn_out`, then the description's feed-forward under its own
         scopes (`ffn`, or `router`, `moe_dispatch`, `experts`,
@@ -1247,7 +1083,6 @@ class ServeEngine:
         (None: no adapters). Returns the layer's live slots per expert
         last (None without an expert layer)."""
         scope = jax.named_scope
-        quantized = k_scales is not None
         arch = self.arch
         with scope("ln"):
             h = arch.norm1(params, i, x)
@@ -1256,27 +1091,13 @@ class ServeEngine:
                 params, i, h, positions, lora=None if la is None else
                 (la["a_qkv"], la["b_qkv"], ad_s))         # (T, H[/t], D)
         with scope("kv_write"):
-            if quantized:
-                kq, ksc = quantize_kv_rows(k, self._kv_store_dtype)
-                vq, vsc = quantize_kv_rows(v, self._kv_store_dtype)
-                k_pages = k_pages.at[i, write_pages, write_offs].set(kq)
-                v_pages = v_pages.at[i, write_pages, write_offs].set(vq)
-                k_scales = k_scales.at[i, write_pages,
-                                       write_offs].set(ksc)
-                v_scales = v_scales.at[i, write_pages,
-                                       write_offs].set(vsc)
-            else:
-                k_pages = k_pages.at[i, write_pages, write_offs].set(
-                    k.astype(k_pages.dtype))
-                v_pages = v_pages.at[i, write_pages, write_offs].set(
-                    v.astype(v_pages.dtype))
+            pool = pool.write(i, write_pages, write_offs, k, v)
         with scope("attn"):
-            o = paged_attention_ragged(
-                q, k_pages[i], v_pages[i], page_tables, lane_slots,
-                lane_lens, scale=scale, **self._attn_kw,
-                k_scales=k_scales[i] if quantized else None,
-                v_scales=v_scales[i] if quantized else None,
-                block_kv=self.attn_block_kv, work=work)
+            k_pages, v_pages, k_scales, v_scales = pool.layer(i)
+            o = paged_attention_ragged_v2(
+                q, k_pages, v_pages, page_tables, lane_slots, lane_lens,
+                k_scales=k_scales, v_scales=v_scales, scale=scale,
+                block_kv=self.attn_block_kv, work=work, **self._attn_kw)
         with scope("attn_out"):
             x = arch.attn_out(
                 params, i, o, x, psum_axis=tp_axis,
@@ -1286,76 +1107,46 @@ class ServeEngine:
             params, i, x, live=live, psum_axis=tp_axis,
             lora=None if la is None else
             (la["a_ff1"], la["b_ff1"], la["a_ff2"], la["b_ff2"], ad_s))
-        return x, k_pages, v_pages, k_scales, v_scales, counts
+        return x, pool, counts
 
     # ---------------- disaggregated page handoff -----------------------
     # Device half of the prefill->decode transfer (serve/disagg.py;
     # host bookkeeping in PagedKVCache.export_pages/import_pages).
-    # Both directions move whole page ROWS — (layers, page, slot,
-    # head[, dim]) blocks of the pool arrays (and the f32 scale arrays
-    # on quantized pools, so quantized content crosses the link
-    # bit-exactly and dequantizes identically on the far side) —
-    # through ONE fixed-shape program each: the page-index vector pads
-    # to pages_per_seq with the sink page 0, exactly the padding
-    # convention of the mixed step's write lanes.
+    # Both directions move whole pages of every layer (KVPool.rows /
+    # with_rows: the stored values, and the f32 scales on quantized
+    # pools, so quantized content crosses the link bit-exactly and
+    # dequantizes identically on the far side) through ONE fixed-shape
+    # program each, shard_map'd over the serve mesh where there is one
+    # (pool AND rows on the head axis, the index vector replicated):
+    # the page-index vector pads to pages_per_seq with the sink page
+    # 0, exactly the padding convention of the mixed step's write
+    # lanes.
 
-    def _pool_args(self):
-        args = (self._k_pages, self._v_pages)
-        if self.kv_quantized:
-            args += (self._k_scales, self._v_scales)
-        return args
+    def _over_mesh(self, fn, *arg_specs):
+        """A handoff body `fn(pool, ...) -> pool`: itself on one
+        device, shard_map'd over the serve mesh where there is one."""
+        if self.tp_mesh is None:
+            return fn
+        from jax import shard_map
+        spec = KVPool.specs(TENSOR)
+        return shard_map(fn, mesh=self.tp_mesh,
+                         in_specs=(spec,) + arg_specs, out_specs=spec,
+                         check_vma=False)
 
-    def _restash_pools(self, pools) -> None:
-        self._k_pages, self._v_pages = pools[0], pools[1]
-        if self.kv_quantized:
-            self._k_scales, self._v_scales = pools[2], pools[3]
-
-    def _export_impl(self, n_pools, *args):
-        """Gather page rows: args = (*pools, idx); idx (pages_per_seq,)
-        int32, padding entries aim at the sink (their rows ship as
-        garbage the importer never addresses)."""
-        idx = args[n_pools]
-        return tuple(a[:, idx] for a in args[:n_pools])
-
-    def _import_impl(self, n_pools, *args):
-        """Scatter page rows: args = (*pools, *rows, idx). Padding
-        entries write their (zero) rows into the sink page — harmless
-        by the sink convention (reads are masked by seq_lens)."""
-        idx = args[2 * n_pools]
-        return tuple(p.at[:, idx].set(r)
-                     for p, r in zip(args[:n_pools],
-                                     args[n_pools:2 * n_pools]))
-
-    def _handoff_specs(self, n_pools):
-        """shard_map specs of the handoff programs: pools AND rows
-        shard on the head axis (a page row carries the head dim), the
-        index vector is replicated."""
+    def _export_impl(self, pool, idx):
+        """Gather pages: idx (pages_per_seq,) int32, padding entries
+        aim at the sink (their rows ship as garbage the importer never
+        addresses)."""
         from jax.sharding import PartitionSpec as P
-        page = P(None, None, None, TENSOR, None)
-        scl = P(None, None, None, TENSOR)
-        arrs = (page, page) + ((scl, scl) if n_pools == 4 else ())
-        return arrs, P()
+        return self._over_mesh(KVPool.rows, P())(pool, idx)
 
-    def _export_tp_impl(self, n_pools, *args):
-        # the SAME gather body per device over its head shard (pure on
-        # its args, so no duplicated indexing convention to drift)
-        import functools
-
-        from jax import shard_map
-        arrs, rep = self._handoff_specs(n_pools)
-        return shard_map(functools.partial(self._export_impl, n_pools),
-                         mesh=self.tp_mesh, in_specs=arrs + (rep,),
-                         out_specs=arrs, check_vma=False)(*args)
-
-    def _import_tp_impl(self, n_pools, *args):
-        import functools
-
-        from jax import shard_map
-        arrs, rep = self._handoff_specs(n_pools)
-        return shard_map(functools.partial(self._import_impl, n_pools),
-                         mesh=self.tp_mesh,
-                         in_specs=arrs + arrs + (rep,),
-                         out_specs=arrs, check_vma=False)(*args)
+    def _import_impl(self, pool, rows, idx):
+        """Scatter pages into the (donated) pool. Padding entries
+        write their (zero) rows into the sink page — harmless by the
+        sink convention (reads are masked by seq_lens)."""
+        from jax.sharding import PartitionSpec as P
+        return self._over_mesh(KVPool.with_rows, P(), KVPool.specs(TENSOR))(
+            pool, idx, rows)
 
     def _pad_idx(self, pages: Sequence[int]) -> np.ndarray:
         c = self.cache_cfg
@@ -1385,20 +1176,18 @@ class ServeEngine:
             slot, tokens, prev=tenant_prefix_salt(tenant_id))
         if not pages:
             return None
-        self._device_pages()
         n = len(pages)
         rows = self._call_counted(
-            "export", self._export_jit, self._n_pools,
-            *self._pool_args(), self._h2d(self._pad_idx(pages)))
+            "export", self._export_jit, self._device_pool(),
+            self._h2d(self._pad_idx(pages)))
         # copy the real-page slice: a view would pin the whole
         # pages_per_seq-padded gather buffer for the shipment's life
-        host = [np.asarray(r)[:, :n].copy() for r in rows]
+        host = jax.tree.map(lambda r: np.asarray(r)[:, :n].copy(), rows)
         c = self.cache_cfg
         return PageShipment(
             keys=list(keys), ntokens=int(ntokens),
-            k_rows=host[0], v_rows=host[1],
-            k_scale_rows=host[2] if self.kv_quantized else None,
-            v_scale_rows=host[3] if self.kv_quantized else None,
+            k_rows=host.k, v_rows=host.v,
+            k_scale_rows=host.k_scale, v_scale_rows=host.v_scale,
             page_size=c.page_size, num_layers=c.num_layers,
             num_heads=c.num_heads, head_dim=c.head_dim,
             kv_dtype=c.kv_dtype, stream_id=stream_id,
@@ -1429,23 +1218,29 @@ class ServeEngine:
         todo = self.cache.import_pages(ship.keys)
         if not todo:
             return 0
-        self._device_pages()
-        idx = self._pad_idx([page for _, page in todo])
-        srcs = [ship.k_rows, ship.v_rows]
-        if self.kv_quantized:
-            srcs += [ship.k_scale_rows, ship.v_scale_rows]
-        rows = []
-        for src in srcs:
-            buf = np.zeros((src.shape[0], c.pages_per_seq)
-                           + src.shape[2:], src.dtype)
-            for j, (chain_i, _) in enumerate(todo):
-                buf[:, j] = src[:, chain_i]
-            rows.append(self._h2d(buf))
-        pools = self._call_counted(
-            "import", self._import_jit, self._n_pools,
-            *self._pool_args(), *rows, self._h2d(idx))
-        self._restash_pools(pools)
+        chain = [chain_i for chain_i, _ in todo]
+        self._import_pages(
+            [page for _, page in todo],
+            jax.tree.map(lambda src: src[:, chain],
+                         KVPool(ship.k_rows, ship.v_rows,
+                                ship.k_scale_rows, ship.v_scale_rows)))
         return len(todo)
+
+    def _import_pages(self, pages: Sequence[int], rows: KVPool) -> None:
+        """Scatter host `rows` (a pool of len(pages) pages, numpy
+        leaves) into pool pages `pages` through the fixed-shape import
+        program: both pad to pages_per_seq, the padding aimed at the
+        sink."""
+        pad = self.cache_cfg.pages_per_seq - len(pages)
+
+        def padded(r):
+            return self._h2d(np.concatenate(
+                [r, np.zeros((r.shape[0], pad) + r.shape[2:], r.dtype)],
+                axis=1))
+
+        self.pool = self._call_counted(
+            "import", self._import_jit, self._device_pool(),
+            jax.tree.map(padded, rows), self._h2d(self._pad_idx(pages)))
 
     def warmup_handoff(self) -> Dict[str, int]:
         """Compile the export/import programs on sink-page dummies (a
@@ -1455,23 +1250,13 @@ class ServeEngine:
         (a sharded engine would otherwise warm the program against
         device-committed shardings and recompile on the first real,
         host-laid-out shipment). Returns compile_counts()."""
-        self._device_pages()
-        c = self.cache_cfg
-        idx = self._h2d(np.zeros((c.pages_per_seq,), np.int32))
-        self._call_counted(
-            "export", self._export_jit, self._n_pools,
-            *self._pool_args(), idx)
-        val = (c.num_layers, c.pages_per_seq, c.page_size,
-               c.num_heads, c.head_dim)
-        shapes = [(val, c.storage_dtype), (val, c.storage_dtype)]
-        if self.kv_quantized:
-            scl = val[:-1]
-            shapes += [(scl, np.float32), (scl, np.float32)]
-        zero_rows = [self._h2d(np.zeros(s, d)) for s, d in shapes]
-        pools = self._call_counted(
-            "import", self._import_jit, self._n_pools,
-            *self._pool_args(), *zero_rows, idx)
-        self._restash_pools(pools)
+        idx = self._h2d(np.zeros(
+            (self.cache_cfg.pages_per_seq,), np.int32))
+        rows = self._call_counted(
+            "export", self._export_jit, self._device_pool(), idx)
+        self._import_pages([], jax.tree.map(
+            lambda r: np.zeros((r.shape[0], 0) + r.shape[2:], r.dtype),
+            rows))
         return self.compile_counts()
 
     # ---------------- hierarchical host tier ---------------------------
@@ -1496,16 +1281,14 @@ class ServeEngine:
                 if not store.contains(k)]
         if not todo:
             return 0
-        self._device_pages()
         c = self.cache_cfg
         shipped = 0
         for i in range(0, len(todo), c.pages_per_seq):
             batch = todo[i:i + c.pages_per_seq]
             rows = self._call_counted(
-                "export", self._export_jit, self._n_pools,
-                *self._pool_args(),
+                "export", self._export_jit, self._device_pool(),
                 self._h2d(self._pad_idx([p for p, _ in batch])))
-            host = [np.asarray(r) for r in rows]
+            host = [np.asarray(r) for r in jax.tree.leaves(rows)]
             for j, (_, key) in enumerate(batch):
                 if store.put(key, [h[:, j] for h in host]):
                     shipped += 1
@@ -1576,9 +1359,11 @@ class ServeEngine:
             if rows is None:
                 break
             fetched.append(rows)
-        val_shape = (c.num_layers, c.page_size, c.num_heads,
-                     c.head_dim)
-        if not fetched or tuple(fetched[0][0].shape) != val_shape:
+        # one page of each of the pool's leaves, as export yields it
+        leaves, treedef = jax.tree.flatten(self._device_pool())
+        one_page = [(a.shape[:1] + a.shape[2:], a.dtype) for a in leaves]
+        if not fetched or [(r.shape, r.dtype)
+                           for r in fetched[0]] != one_page:
             decision["chose"] = "store_miss"  # raced away / foreign
             return 0                          # geometry: never scatter
         todo = cache.import_pages(keys[resident:resident + len(fetched)])
@@ -1588,20 +1373,11 @@ class ServeEngine:
         # the allocation above may have queued evictions of its own —
         # their content must ship before the scatter overwrites it
         self._drain_spills()
-        self._device_pages()
-        idx = self._pad_idx([page for _, page in todo])
-        rows_dev = []
-        for pool_i in range(self._n_pools):
-            src0 = fetched[0][pool_i]
-            buf = np.zeros((src0.shape[0], c.pages_per_seq)
-                           + src0.shape[1:], src0.dtype)
-            for j, (chain_i, _) in enumerate(todo):
-                buf[:, j] = fetched[chain_i][pool_i]
-            rows_dev.append(self._h2d(buf))
-        pools = self._call_counted(
-            "import", self._import_jit, self._n_pools,
-            *self._pool_args(), *rows_dev, self._h2d(idx))
-        self._restash_pools(pools)
+        self._import_pages(
+            [page for _, page in todo],
+            treedef.unflatten(
+                np.stack([fetched[chain_i][leaf] for chain_i, _ in todo],
+                         axis=1) for leaf in range(len(leaves))))
         n = len(todo)
         decision.update(chose="reload", reloaded_pages=n)
         self._host_reload_stats["reload_events"] += 1
@@ -1616,64 +1392,6 @@ class ServeEngine:
                       "pages": n, "dma_s": dma_s})
         return n
 
-    # ---------------- legacy prefill -----------------------------------
-    def _prefill_impl(self, params, k_pages, v_pages, tokens, length,
-                      pt_row):
-        """tokens (1, S) padded to a bucket; length scalar int32 (real
-        prompt tokens); pt_row (pages_per_seq,) the sequence's page
-        table. Returns (last-position logits (V,), k_pages, v_pages).
-
-        Padded positions scatter their K/V through page-table entries
-        normally: entries past the mapped range are 0 (the sink), and
-        padded offsets inside a mapped page are overwritten by decode
-        before the length mask ever exposes them."""
-        last, (k_pages, v_pages) = self._forward_tokens(
-            params, tokens, length, kv=(k_pages, v_pages, pt_row))
-        return last, k_pages, v_pages
-
-    # ---------------- legacy decode ------------------------------------
-    def _decode_impl(self, params, k_pages, v_pages, tokens, positions,
-                     write_pages, write_offs, page_tables, seq_lens):
-        """One token for every slot lane. tokens/positions (B,) int32;
-        write_pages/write_offs (B,) the physical slot for each lane's
-        new K/V — HOST-computed so lanes that are not decoding this
-        step (empty, or prefilled moments ago) aim at the sink page 0
-        instead of clobbering their own position 0; page_tables
-        (B, pages_per_seq); seq_lens (B,) INCLUDING the token being
-        decoded (its K/V is written here, then attended — position i
-        sees keys 0..i). Non-decoding lanes compute garbage the host
-        never reads. Returns (next_tokens (B,), top-k values, top-k
-        ids, k_pages, v_pages)."""
-        arch = self.arch
-        x = arch.embed(params, tokens, positions)         # (B, E)
-        pages, offs = write_pages, write_offs
-        scale = 1.0 / np.sqrt(self.head_dim)
-        for i in range(self.num_layers):
-            q, k, v = arch.qkv(params, i, arch.norm1(params, i, x),
-                               positions)                 # (B, H, D)
-            k_pages = k_pages.at[i, pages, offs].set(
-                k.astype(k_pages.dtype))
-            v_pages = v_pages.at[i, pages, offs].set(
-                v.astype(v_pages.dtype))
-            o = paged_attention_decode(
-                q, k_pages[i], v_pages[i], page_tables, seq_lens,
-                scale=scale, **self._attn_kw)
-            x = arch.attn_out(params, i, o, x)
-            x, _ = arch.ffn(params, i, x)
-        logits = arch.head(params, x)                     # (B, V)
-        topv, topi = jax.lax.top_k(logits, self.topk_cap)
-        return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                topv.astype(jnp.float32), topi.astype(jnp.int32),
-                k_pages, v_pages)
-
-    # ---------------- naive no-cache reference -------------------------
-    def _forward_logits(self, params, tokens, length):
-        """Full forward over (1, S) tokens, logits at position
-        length-1 — the no-KV-cache greedy-decode reference (the shared
-        _forward_tokens with the cache writes off)."""
-        last, _ = self._forward_tokens(params, tokens, length, kv=None)
-        return last
-
     # ---------------- bucketing / compile bookkeeping ------------------
     def bucket_for(self, prompt_len: int) -> int:
         for b in self.buckets:
@@ -1686,8 +1404,7 @@ class ServeEngine:
     def compile_counts(self) -> Dict[str, int]:
         """Compiled-program count per serving function. After warmup()
         these must never grow — the zero-recompile serving contract
-        (the chunked engine's whole hot path is the single `mixed`
-        program). Counted by the ProgramRegistry (core/programs.py),
+        (the whole hot path is the single `mixed` program). Counted by the ProgramRegistry (core/programs.py),
         which owns every serving dispatch: a count increments exactly
         when the registry AOT-compiles a new argument signature, so
         compiles inside warmup_handoff / adapter load can no longer
@@ -1703,17 +1420,19 @@ class ServeEngine:
         0); otherwise uncommitted on the default device — jnp.asarray."""
         return jax.device_put(x, self._home)
 
-    def _device_pages(self):
-        page_sh, scale_sh = self._page_shardings()
-        if self._k_pages is None:
-            self._k_pages, self._v_pages = \
-                self.cache.alloc_device_cache(sharding=page_sh)
-        if self.kv_quantized and self._k_scales is None:
-            self._k_scales, self._v_scales = \
-                self.cache.alloc_scale_arrays(sharding=scale_sh)
-            self.cache.register_scale_meta(self._k_scales,
-                                           self._v_scales)
-        return self._k_pages, self._v_pages
+    def _device_pool(self) -> KVPool:
+        """The resident pool, allocated on first use: head-sharded
+        over the serve mesh; single-device, on the placed replica's
+        chip (None unplaced)."""
+        if self.pool is None:
+            sharding = self._home
+            if self.tp_mesh is not None:
+                from jax.sharding import NamedSharding
+                sharding = jax.tree.map(
+                    lambda s: NamedSharding(self.tp_mesh, s),
+                    KVPool.specs(TENSOR))
+            self.pool = KVPool.alloc(self.cache_cfg, sharding)
+        return self.pool
 
     # ---------------- adapter pool: device half ------------------------
     def _adapter_slab_shapes(self):
@@ -1728,7 +1447,7 @@ class ServeEngine:
         return shapes
 
     def _device_adapters(self):
-        """The resident slab pytree (lazy, like _device_pages): A/B
+        """The resident slab pytree (lazy, like _device_pool): A/B
         factors at the activation dtype, per-slot scales f32, all
         zeros until tenants load — so slot 0 stays the zero base slab
         forever (nothing ever writes it)."""
@@ -1793,17 +1512,16 @@ class ServeEngine:
                     args={"tenant": tenant, "slot": slot})
         return len(pending)
 
-    def _dispatch_mixed(self, kp, vp, *args, lane_adapters=None):
-        """One mixed-step dispatch through the right jitted program for
-        the pool format, threading (and re-capturing) the donated scale
-        arrays on quantized pools. Returns (greedy, topv, topi, kp, vp,
-        expert counts: the step's (layers, experts) live slots per
-        expert on a model with an expert layer, else None); the page AND scale arrays are re-stashed on self each step so a
-        mid-run audit (check_kv_scales from an `on_step` callback, when
-        sequences are actually resident) reads THIS step's content, not
-        the pre-run allocation. On an adapter-armed engine the lanes'
-        slot indices + the slabs ride along (read-only — the slabs are
-        NOT donated); unarmed engines pass None (an empty pytree, zero
+    def _dispatch_mixed(self, *args, lane_adapters=None):
+        """One mixed-step dispatch: `args` are the step's seven lane
+        arrays. Returns (greedy, topv, topi, expert counts: the step's
+        (layers, experts) live slots per expert on a model with an
+        expert layer, else None) and keeps the returned pool as
+        `self.pool`, so a mid-run audit (check_kv_scales from an
+        `on_step` callback, when sequences are actually resident) reads
+        THIS step's content. On an adapter-armed engine the lanes' slot
+        indices + the slabs ride along (read-only — the slabs are NOT
+        donated); unarmed engines pass None (an empty pytree, zero
         trace cost, numerics untouched)."""
         if self.adapters is not None:
             la = lane_adapters if lane_adapters is not None \
@@ -1811,22 +1529,15 @@ class ServeEngine:
             args = args + (la, self._device_adapters())
         else:
             args = args + (None, None)
-        if self.kv_quantized:
-            *out, kp, vp, ks, vs = self._call_counted(
-                "mixed", self._mixed_q_jit, self._step_params, kp, vp,
-                self._k_scales, self._v_scales, *args)
-            self._k_scales, self._v_scales = ks, vs
-        else:
-            *out, kp, vp = self._call_counted(
-                "mixed", self._mixed_jit, self._step_params, kp, vp,
-                *args)
-        self._k_pages, self._v_pages = kp, vp
+        *out, self.pool = self._call_counted(
+            "mixed", self._mixed_jit, self._step_params,
+            self._device_pool(), *args)
         greedy, topv, topi = out[:3]
-        return (greedy, topv, topi, kp, vp,
+        return (greedy, topv, topi,
                 out[3] if self.arch.experts else None)
 
     def warmup(self) -> Dict[str, int]:
-        """Ready the active path's programs once, on throwaway inputs
+        """Ready the engine's programs once, on throwaway inputs
         (all writes aim at the sink page): compile on a cold boot, or
         dispatch executables the registry restored from
         --program-cache-dir on a warm one (zero compiles). Returns
@@ -1836,48 +1547,28 @@ class ServeEngine:
         NEXT boot over this config is warm."""
         t0 = time.perf_counter()
         c = self.cache_cfg
-        kp, vp = self._device_pages()
-        if self.chunked_prefill:
-            t = self.mixed_width
-            z = self._h2d(np.zeros((t,), np.int32))
-            pts = self._h2d(
-                np.zeros((c.max_seqs, c.pages_per_seq), np.int32))
-            _, _, _, kp, vp, _ = self._dispatch_mixed(
-                kp, vp, z, z, z, z, pts, z, self._h2d(np.ones((t,), np.int32)))
-            if self.adapters is not None:
-                # compile the adapter-load scatter on an all-zero row
-                # set aimed at the base slot (zeros into zeros — a
-                # no-op on content), host-built f32 exactly like a
-                # real load (the registered host weights are f32) so
-                # the first tenant miss reuses this program
-                rows = {k: self._h2d(np.zeros(s[1:], np.float32))
-                        for k, s in self._adapter_slab_shapes().items()}
-                self._adapter_slabs = self._call_counted(
-                    "adapter", self._adapter_load_jit,
-                    self._device_adapters(), self._h2d(np.int32(0)), rows)
-            if self.host_tier is not None:
-                # spill/reload traffic runs the handoff programs —
-                # warm them here or the first eviction under load
-                # would compile after the pool snapshots warm counts.
-                # The import donates (and restashes) the pools: the
-                # locals this method stashes at the end are dead now
-                self.warmup_handoff()
-                kp, vp = self._k_pages, self._v_pages
-        else:
-            pt_row = jnp.zeros((c.pages_per_seq,), jnp.int32)
-            for b in self.buckets:
-                toks = jnp.zeros((1, b), jnp.int32)
-                _, kp, vp = self._call_counted(
-                    "prefill", self._prefill_jit, self.params, kp, vp,
-                    toks, jnp.int32(1), pt_row)
-            toks = jnp.zeros((c.max_seqs,), jnp.int32)
-            pos = jnp.zeros((c.max_seqs,), jnp.int32)
-            pts = jnp.zeros((c.max_seqs, c.pages_per_seq), jnp.int32)
-            sls = jnp.ones((c.max_seqs,), jnp.int32)
-            _, _, _, kp, vp = self._call_counted(
-                "decode", self._decode_jit, self.params, kp, vp, toks,
-                pos, toks, pos, pts, sls)
-        self._k_pages, self._v_pages = kp, vp
+        t = self.mixed_width
+        z = self._h2d(np.zeros((t,), np.int32))
+        pts = self._h2d(
+            np.zeros((c.max_seqs, c.pages_per_seq), np.int32))
+        self._dispatch_mixed(
+            z, z, z, z, pts, z, self._h2d(np.ones((t,), np.int32)))
+        if self.adapters is not None:
+            # compile the adapter-load scatter on an all-zero row
+            # set aimed at the base slot (zeros into zeros — a
+            # no-op on content), host-built f32 exactly like a
+            # real load (the registered host weights are f32) so
+            # the first tenant miss reuses this program
+            rows = {k: self._h2d(np.zeros(s[1:], np.float32))
+                    for k, s in self._adapter_slab_shapes().items()}
+            self._adapter_slabs = self._call_counted(
+                "adapter", self._adapter_load_jit,
+                self._device_adapters(), self._h2d(np.int32(0)), rows)
+        if self.host_tier is not None:
+            # spill/reload traffic runs the handoff programs —
+            # warm them here or the first eviction under load
+            # would compile after the pool snapshots warm counts
+            self.warmup_handoff()
         rec = self.programs.boot_record()
         rec["boot_s"] = time.perf_counter() - t0
         rec["warm"] = rec["compiles"] == 0 and rec["restored"] > 0
@@ -1946,51 +1637,29 @@ class ServeEngine:
 
     # ---------------- quantized-page verification (tests) -------------
     def check_kv_scales(self) -> None:
-        """Device-side scale bookkeeping check for int8 pools (the
-        stress tests' companion to PagedKVCache.check_invariants):
-        every audited (page, offset) row must carry finite,
-        non-negative K/V scales, and a zero scale must vouch for an
-        all-zero int8 row (scale 0 is only ever written for an
-        all-zero activation row, so anything else means the scale and
-        its page drifted — e.g. a rollback/preemption interleaving
-        that reused a page slot without rewriting its scale). Audits
+        """Device-side scale bookkeeping check for quantized pools
+        (the stress tests' companion to PagedKVCache.check_invariants):
+        KVPool.check_scales over every row that must hold content — a
+        drifted one means e.g. a rollback/preemption interleaving
+        that reused a page slot without rewriting its scale. Audits
         RESIDENT (slot, position) rows — which only exist mid-run, so
         the stress tests call this from generate()'s `on_step`
-        callback (_dispatch_mixed re-stashes the live arrays each
-        step) — plus every prefix-cache-parked page: those are
+        callback (_dispatch_mixed keeps the live pool each step) —
+        plus every prefix-cache-parked page: those are
         complete pages whose content must outlive their writer for a
         later request to attach, and they are what a post-run call
         still covers. No-op on lossless pools."""
-        if not self.kv_quantized or self._k_pages is None:
+        if self.pool is None:
             return
         ps = self.cache_cfg.page_size
-        kq = np.asarray(self._k_pages)
-        vq = np.asarray(self._v_pages)
-        ks = np.asarray(self._k_scales)
-        vs = np.asarray(self._v_scales)
-
-        def audit(what: str, page: int, off: int) -> None:
-            for name, s, q in (("k", ks, kq), ("v", vs, vq)):
-                srow = s[:, page, off, :]      # (layers, H)
-                qrow = q[:, page, off, :, :]   # (layers, H, D)
-                assert np.all(np.isfinite(srow)) \
-                    and np.all(srow >= 0), (
-                    f"{name}-scale of {what} (page {page} off {off}) "
-                    f"is not finite/non-negative")
-                dead = srow == 0.0
-                assert np.all(qrow[dead] == 0), (
-                    f"{name}-page row of {what} (page {page} off "
-                    f"{off}) has zero scale but nonzero quantized "
-                    f"content")
-
-        for slot in range(self.cache_cfg.max_seqs):
-            for pos in range(int(self.cache.seq_lens[slot])):
-                audit(f"slot {slot} pos {pos}",
-                      int(self.cache.page_tables[slot, pos // ps]),
-                      pos % ps)
-        for page in self.cache.parked_pages():
-            for off in range(ps):
-                audit("cached page", page, off)
+        where = [(f"slot {slot} pos {pos}",
+                  int(self.cache.page_tables[slot, pos // ps]), pos % ps)
+                 for slot in range(self.cache_cfg.max_seqs)
+                 for pos in range(int(self.cache.seq_lens[slot]))]
+        where += [("cached page", page, off)
+                  for page in self.cache.parked_pages()
+                  for off in range(ps)]
+        self.pool.check_scales(where)
 
     @staticmethod
     def first_divergence(a, b) -> Optional[int]:
@@ -2136,13 +1805,9 @@ class ServeEngine:
         lazily when the interrupted dispatch ate them."""
         self.cache.clear_prefix()   # also drops queued host spills
         self._host_reload_s = 0.0
-        if self._k_pages is not None and \
-                getattr(self._k_pages, "is_deleted", lambda: False)():
-            self._k_pages = self._v_pages = None  # realloc on next use
-        if self._k_scales is not None and \
-                getattr(self._k_scales, "is_deleted", lambda: False)():
-            self._k_scales = self._v_scales = None
-        self.cache.check_invariants()
+        if any(a.is_deleted() for a in jax.tree.leaves(self.pool)):
+            self.pool = None              # realloc on next use
+        self.cache.check_invariants(self.pool)
 
     # ---------------- telemetry ----------------------------------------
     def _drift_predicted(self, ctx_bucket: int) -> Optional[tuple]:
@@ -2276,7 +1941,7 @@ class ServeEngine:
         evs.append(("C", self._ENGINE_TRACK, "rung", t_end,
                     float(rung), None, None))
         tel.emit(evs)
-        if plan.chunks and self.chunked_prefill:
+        if plan.chunks:
             # O(1) context length — Request.context materializes a
             # prompt+out_tokens list copy, far too hot for every step
             ctxs = [len(ch.req.prompt) + len(ch.req.out_tokens)
@@ -2373,7 +2038,7 @@ class ServeEngine:
             "detail": dict(detail or {}),
             "created_unix_s": time.time(),
             "engine": {
-                "mode": "chunked" if self.chunked_prefill else "legacy",
+                "mode": "chunked",
                 "mixed_width": self.mixed_width,
                 "tensor_parallel": self.tp,
                 "kv_dtype": self.kv_dtype,
@@ -2507,11 +2172,10 @@ class ServeEngine:
         adapter = (float(self.adapter_cfg.pool_device_bytes)
                    if self.adapter_cfg is not None else 0.0)
         total = params + kv_pool + activations + adapter
-        pools_live = self._k_pages is not None
+        pools_live = self.pool is not None
         adapters_live = self._adapter_slabs is not None
         live = params + pytree_device_bytes(
-            (self._k_pages, self._v_pages,
-             self._k_scales, self._v_scales, self._adapter_slabs))
+            (self.pool, self._adapter_slabs))
         arch = self.serve_arch()
         sim_input = float(serve_device_bytes(arch, t))
         ledger = {
@@ -2609,12 +2273,10 @@ class ServeEngine:
         exact stream a single-replica engine would — token streams
         survive crossing schedulers instead of being refused.
 
-        The chunked path runs through a :class:`ServeSession` (the
-        steppable form the multi-replica router drives directly);
-        generate() is submit-everything + drain over it, so both
-        tiers serve through one code path."""
-        c = self.cache_cfg
-        cache = self.cache
+        It runs through a :class:`ServeSession` (the steppable form
+        the multi-replica router drives directly): generate() is
+        submit-everything + drain over it, so both tiers serve through
+        one code path."""
         if isinstance(max_new_tokens, int):
             max_new_tokens = [max_new_tokens] * len(prompts)
         if len(max_new_tokens) != len(prompts):
@@ -2648,120 +2310,16 @@ class ServeEngine:
             raise ValueError(
                 "tenant_ids != 0 need an armed adapter pool "
                 "(adapter_rank > 0); this engine serves base-only")
-        if self.chunked_prefill:
-            return self._generate_session(
-                prompts, max_new_tokens, samples, eos_token,
-                deadline_s, stream_ids, stream_offset, on_step,
-                on_finish, trace_ids, tenant_ids)
-        # ---- legacy bucket path: its own scheduler + orphan recovery
-        # (the chunked path's ServeSession owns both)
-        if cache.free_slots != c.max_seqs:
-            # a previous batch died WITHOUT _fail_inflight running (a
-            # BaseException like KeyboardInterrupt mid-loop, or a user
-            # driving the scheduler directly): reclaim the orphaned
-            # slots/pages AND reset the pool state — the registry may
-            # vouch for arrays the dead batch lost, and donation may
-            # have consumed the pools — then keep serving. The
-            # PR-3-era answer ("build a fresh ServeEngine") threw away
-            # a warm compiled program for a recoverable host state.
-            cache.release_all()
-            self._reset_pool_state()
-        sched = ContinuousBatchingScheduler(
-            cache, prefill_token_budget=self.prefill_budget,
-            chunked_prefill=False,
-            admit_watermark=self.admit_watermark,
-            spec_tokens=self.spec_tokens, drafter=self.drafter,
-            faults=self.faults, degrade_ladder=self.degrade_ladder,
-            reject_stalls=self.reject_stalls)
-        reqs: List[Request] = []
-        t0 = time.perf_counter()
-        for i, (prompt, mnt, sp) in enumerate(
-                zip(prompts, max_new_tokens, samples)):
-            r = sched.submit(prompt, mnt, eos_token=eos_token, sample=sp,
-                             stream_id=(stream_ids[i]
-                                        if stream_ids is not None
-                                        else None),
-                             stream_offset=stream_offset,
-                             trace_id=(trace_ids[i]
-                                       if trace_ids is not None
-                                       else None))
-            r.t_submit = time.perf_counter()
-            if deadline_s is not None and deadline_s[i] \
-                    and float(deadline_s[i]) > 0:
-                r.t_deadline = r.t_submit + float(deadline_s[i])
-            reqs.append(r)
-            self._active[r.rid] = r
-        kp, vp = self._device_pages()
-        steps = 0
-        decode_times: List[float] = []   # seconds per step with decodes
-        decode_widths: List[int] = []    # decode lanes per such step
-        prefill_times: List[Tuple[int, float]] = []  # (lanes, seconds)
-        util: List[float] = []           # resident-page fraction per step
-
-        def emit(chunk: ChunkPlan, greedy, topv, topi) -> None:
-            req = chunk.req
-            tok = self._pick_token(req, greedy, topv, topi)
-            req.out_tokens.append(tok)
-            if len(req.out_tokens) == 1:
-                req.t_first_token = time.perf_counter()
-            if req.is_done():
-                req.t_finish = time.perf_counter()
-                if on_finish is not None:
-                    on_finish(req)
-                sched.finish(req)
-
-        retries0 = self._retries
-        tel = self.telemetry
-        try:
-            kp, vp = self._run_legacy(sched, cache, kp, vp, emit,
-                                      decode_times, decode_widths,
-                                      prefill_times, util, on_step)
-            steps = len(util)
-        except Exception:
-            self._fail_inflight(sched, reqs)
-            raise
-        finally:
-            self._active.clear()
-            self._cancels.clear()
-            # chaos runs stay inspectable post-hoc (docs/robustness.md):
-            # the injector's fired accounting and the Chrome trace
-            # flush even when a fault aborts the run (every span is
-            # already in the ring by the time the dispatch raised), and
-            # an unwritable --trace-out path must not fail a generate
-            # that already produced tokens (fit() makes both promises
-            # in its own finally)
-            if tel.enabled:
-                tel.record_faults(self.faults)
-                if self.trace_out:
-                    try:
-                        tel.export_chrome_trace(self.trace_out)
-                    except OSError:
-                        pass
-        self._k_pages, self._v_pages = kp, vp
-        cache.check_invariants()
-        assert cache.free_pages == c.usable_pages, "pages leaked"
-        self.last_stats = self._build_stats(
-            reqs, sched, wall=time.perf_counter() - t0, steps=steps,
-            retries0=retries0, decode_times=decode_times,
-            decode_widths=decode_widths, prefill_times=prefill_times,
-            util=util)
-        # fold this run into the engine-lifetime telemetry registry
-        # (counters accumulate, gauges overwrite, histograms extend) —
-        # the same canonical definitions serve_report renders from
-        # (fault accounting + the trace flush already happened in the
-        # finally above, so aborted runs get them too)
-        if tel.enabled:
-            serve_metrics(self.last_stats, registry=tel.metrics)
-        self._last_reqs = {r.rid: r for r in reqs}
-        return [list(r.out_tokens) for r in reqs]
+        return self._generate_session(
+            prompts, max_new_tokens, samples, eos_token, deadline_s,
+            stream_ids, stream_offset, on_step, on_finish, trace_ids,
+            tenant_ids)
 
     def _build_stats(self, reqs, sched, *, wall, steps, retries0,
                      decode_times, decode_widths, prefill_times,
                      util) -> dict:
-        """The last_stats dict — ONE construction shared by
-        generate()'s legacy path and ServeSession.stats_dict() (the
-        chunked path and every routed replica), so the stats surface
-        cannot fork between tiers."""
+        """The last_stats dict (ServeSession.stats_dict(): generate()
+        and every routed replica)."""
         c = self.cache_cfg
         cache = self.cache
         total_new = sum(len(r.out_tokens) for r in reqs)
@@ -2779,7 +2337,7 @@ class ServeEngine:
                  "latency_s": (r.t_finish - r.t_submit
                                if r.t_finish else None)}
                 for r in reqs],
-            "mode": "chunked" if self.chunked_prefill else "legacy",
+            "mode": "chunked",
             # the paged-attention implementation that ran and where
             "attn_impl": self.attn_impl,
             "devices": [int(d.id) for d in self.devices],
@@ -2851,7 +2409,7 @@ class ServeEngine:
                         self.mixed_width, c.pages_per_seq,
                         self.attn_block_pages, Q_ROWS,
                         slot_changes=c.max_seqs
-                    ).items()} if self.chunked_prefill else None,
+                    ).items()},
             },
             # hierarchical host tier (None unarmed): the shared
             # store's occupancy + spill/reload/hit counters plus THIS
@@ -2881,8 +2439,7 @@ class ServeEngine:
         generate() is submit-everything + drain over the same session
         machinery, so a routed replica serves through exactly the code
         path the single-engine contracts (token parity, zero
-        recompiles, invariants) are proven on. Chunked engines only;
-        at most one live session per engine (the session's scheduler
+        recompiles, invariants) are proven on. At most one live session per engine (the session's scheduler
         owns the slots)."""
         return ServeSession(self)
 
@@ -2891,10 +2448,8 @@ class ServeEngine:
                           stream_offset, on_step, on_finish,
                           trace_ids=None,
                           tenant_ids=None) -> List[List[int]]:
-        """generate()'s chunked path: one ServeSession, every prompt
-        submitted up front, stepped to drain — behavior-identical to
-        the pre-session inline loop (same sweep/plan/dispatch order,
-        same stats, same failure containment)."""
+        """generate()'s loop: one ServeSession, every prompt submitted
+        up front, stepped to drain."""
         session = self.start_session()
         reqs = session.reqs
         tel = self.telemetry
@@ -2940,7 +2495,7 @@ class ServeEngine:
                         tel.export_chrome_trace(self.trace_out)
                     except OSError:
                         pass
-        self.cache.check_invariants()
+        self.cache.check_invariants(self.pool)
         assert self.cache.free_pages == self.cache_cfg.usable_pages, \
             "pages leaked"
         self.last_stats = session.stats_dict()
@@ -2949,85 +2504,6 @@ class ServeEngine:
         if tel.enabled:
             serve_metrics(self.last_stats, registry=tel.metrics)
         return [list(r.out_tokens) for r in reqs]
-
-    def _run_legacy(self, sched, cache, kp, vp, emit, decode_times,
-                    decode_widths, prefill_times, util, on_step=None):
-        """The PR 1 two-program loop (serve_chunked_prefill=False):
-        per-request bucketed prefill, then one full-width decode —
-        kept as the A/B baseline and the bucketed-prefill fallback."""
-        c = self.cache_cfg
-        ps = c.page_size
-        while sched.has_work():
-            self._sweep_aborts(sched)
-            if not sched.has_work():
-                break
-            plan = sched.schedule()
-            if not plan.chunks:
-                continue
-            t_step0 = time.perf_counter()
-            pre = [ch for ch in plan.chunks if not ch.is_decode]
-            dec = [ch for ch in plan.chunks if ch.is_decode]
-            for ch in pre:
-                req = ch.req
-                ctx = req.context
-                b = self.bucket_for(len(ctx))
-                toks = np.zeros((1, b), np.int32)
-                toks[0, :len(ctx)] = ctx
-                tp = time.perf_counter()
-                last, kp, vp = self._call_counted(
-                    "prefill", self._prefill_jit, self.params, kp, vp,
-                    jnp.asarray(toks), jnp.int32(len(ctx)),
-                    jnp.asarray(cache.page_tables[req.slot]))
-                logits = np.asarray(last)
-                prefill_times.append((b, time.perf_counter() - tp))
-                sched.complete_chunk(ch)
-                order = np.argsort(logits)[::-1][:self.topk_cap]
-                # np.argmax, not order[0]: argsort's descending tie
-                # order differs from argmax's first-wins (the parity
-                # contract with generate_reference is argmax's)
-                emit(ch, int(np.argmax(logits)), logits[order], order)
-            if dec:
-                tokens = np.zeros((c.max_seqs,), np.int32)
-                positions = np.zeros((c.max_seqs,), np.int32)
-                write_pages = np.zeros((c.max_seqs,), np.int32)  # sink
-                write_offs = np.zeros((c.max_seqs,), np.int32)
-                # the decode step must see the new token (position i
-                # attends keys 0..i), so lengths include it up front
-                seq_lens = np.maximum(np.asarray(cache.seq_lens), 1)
-                for ch in dec:
-                    s, pos = ch.req.slot, ch.start
-                    tokens[s] = ch.req.context[pos]
-                    positions[s] = pos
-                    write_pages[s] = cache.page_tables[s, pos // ps]
-                    write_offs[s] = pos % ps
-                    seq_lens[s] = ch.end
-                tp = time.perf_counter()
-                nxt, topv, topi, kp, vp = self._call_counted(
-                    "decode", self._decode_jit, self.params, kp, vp,
-                    jnp.asarray(tokens), jnp.asarray(positions),
-                    jnp.asarray(write_pages), jnp.asarray(write_offs),
-                    jnp.asarray(cache.page_tables), jnp.asarray(seq_lens))
-                nxt = np.asarray(nxt)    # ONE device->host fetch per step
-                topv = np.asarray(topv)
-                topi = np.asarray(topi)
-                decode_times.append(time.perf_counter() - tp)
-                decode_widths.append(len(dec))
-                for ch in dec:
-                    sched.complete_chunk(ch)
-                    emit(ch, nxt[ch.req.slot], topv[ch.req.slot],
-                         topi[ch.req.slot])
-            util.append(1.0 - cache.free_pages / c.usable_pages)
-            if self.telemetry.enabled:
-                # legacy-path steps get the engine-track span + pool
-                # counter (no drift: the cost model prices the mixed
-                # program, not the bucketed prefill/decode pair)
-                self._record_step_telemetry(
-                    self.telemetry, plan, len(util) - 1,
-                    t_step0, time.perf_counter() - t_step0,
-                    sched.rung, util[-1])
-            if on_step is not None:
-                on_step(len(util) - 1)
-        return kp, vp
 
     def generate_reference(self, prompts: Sequence[Sequence[int]],
                            max_new_tokens,
@@ -3145,11 +2621,6 @@ class ServeSession:
     second / speculative verification last."""
 
     def __init__(self, engine: ServeEngine):
-        if not engine.chunked_prefill:
-            raise ValueError(
-                "serving sessions need the chunked mixed program "
-                "(serve_chunked_prefill=True); the legacy bucket path "
-                "has no single-step form")
         if engine._session is not None:
             raise RuntimeError(
                 "engine already has a live ServeSession — close() it "
@@ -3158,14 +2629,13 @@ class ServeSession:
         cache = engine.cache
         c = engine.cache_cfg
         if cache.free_slots != c.max_seqs:
-            # same orphan recovery as the pre-session generate(): a
-            # previous batch died without _fail_inflight running —
-            # reclaim slots/pages, reset the pool state, serve on
+            # orphan recovery: a previous batch died without
+            # _fail_inflight running — reclaim slots/pages, reset the
+            # pool state, serve on
             cache.release_all()
             engine._reset_pool_state()
         self.sched = ContinuousBatchingScheduler(
             cache, prefill_token_budget=engine.prefill_budget,
-            chunked_prefill=True,
             admit_watermark=engine.admit_watermark,
             spec_tokens=engine.spec_tokens, drafter=engine.drafter,
             faults=engine.faults,
@@ -3190,7 +2660,7 @@ class ServeSession:
         self._retries0 = engine._retries
         self._rejected_seen = 0   # flight-recorder rejection trigger
         self._t0 = time.perf_counter()
-        engine._device_pages()
+        engine._device_pool()
         engine._session = self
 
     # ---------------- submission ---------------------------------------
@@ -3467,9 +2937,8 @@ class ServeSession:
                 "decode": plan.num_decode_lanes,
                 "kv_bytes": ev.kv_bytes_read,
                 "items": ev.attn_items, "rows": ev.attn_rows}):
-            greedy, topv, topi, _, _, counts = eng._dispatch_mixed(
-                eng._k_pages, eng._v_pages, *dev,
-                lane_adapters=dev_adapters)
+            greedy, topv, topi, counts = eng._dispatch_mixed(
+                *dev, lane_adapters=dev_adapters)
         with timed(track, "fetch"):
             greedy = np.asarray(greedy)
             topv = np.asarray(topv)

@@ -41,24 +41,30 @@ steps scatter their K/V there through page-table entries of 0, so the
 jitted steps never need a masked scatter. Reads are masked by sequence
 length, so sink contents are never observed.
 
-Host/device split: this class owns only HOST bookkeeping (free list,
-refcounts, hash registry, page tables, lengths) as plain numpy/dicts the
-scheduler mutates freely; the device arrays are created once by
-`alloc_device_cache()` and flow functionally through the engine's jitted
-steps (donated in, returned out) — the manager never touches device
-memory.
+Host/device split: `PagedKVCache` owns only HOST bookkeeping (free
+list, refcounts, hash registry, page tables, lengths) as plain
+numpy/dicts the scheduler mutates freely — the manager never touches
+device memory. The device arrays are a `KVPool`: the one type that
+knows their layout. It is created once (`KVPool.alloc`) and flows
+functionally through the engine's jitted programs (donated in,
+returned out).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 from collections import OrderedDict
-from typing import Dict, List, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec
 
 from ..config import KV_DTYPES  # the ONE --kv-dtype allowlist
+from ..kernels.paged_ragged_v2 import quantize_kv_rows
 
 # KV_DTYPES names that store quantized values against per-row scale
 # arrays (the PR 8 scale machinery; fp8 reuses it with no new
@@ -108,7 +114,7 @@ class KVCacheConfig:
     bfloat16 (values round on write; exact when the engine's activation
     dtype is already bf16), or int8 (quantized with per-page scale
     arrays — one f32 scale per head per in-page token slot, see
-    `scale_shape`). Scales are per-slot rather than per-whole-page
+    `KVPool`). Scales are per-slot rather than per-whole-page
     because pages fill INCREMENTALLY (decode appends one token at a
     time): a page-global amax would have to re-quantize every resident
     token whenever a new token raised it, which is neither cheap nor
@@ -205,13 +211,6 @@ class KVCacheConfig:
         return int(self.storage_dtype.itemsize)
 
     @property
-    def scale_shape(self):
-        """Per-page scale-array geometry (int8 pages only): one f32
-        scale per (layer, page, in-page slot, head) for K and for V."""
-        return (self.num_layers, self.num_pages, self.page_size,
-                self.num_heads)
-
-    @property
     def page_bytes(self) -> int:
         """Device bytes ONE page costs across all layers: K + V values
         at kv_dtype itemsize, plus the f32 scale rows when quantized.
@@ -282,6 +281,136 @@ class KVCacheConfig:
                 f"({self.tensor_parallel})")
 
 
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["k", "v", "k_scale", "v_scale"], meta_fields=[])
+@dataclasses.dataclass(frozen=True)
+class KVPool:
+    """The device K/V pool, and the ONE place that knows its format.
+
+    Layout: `k`, `v` are (layer, page, slot, head, dim) at the
+    configured storage dtype; on quantized (int8 / fp8) pools
+    `k_scale`, `v_scale` are (layer, page, slot, head) f32 — one scale
+    per stored row (see `KVCacheConfig` for why per row) — and None
+    otherwise. A registered pytree whose leaves flatten in that order,
+    so a jitted program takes and returns a pool (donated) and the
+    pytree's structure, not an argument, says whether it is quantized.
+    The engine, the handoff and the tests go through the methods below;
+    nothing else indexes, scatters into or shards a leaf."""
+    k: Any
+    v: Any
+    k_scale: Any = None
+    v_scale: Any = None
+
+    @classmethod
+    def alloc(cls, cfg: KVCacheConfig, sharding=None) -> "KVPool":
+        """The zeroed pool of `cfg`'s geometry. `sharding`: None (the
+        default device), one Sharding for every leaf (a placed one-chip
+        replica), or a KVPool of them (`specs` over a serve mesh: each
+        device then holds its H/t heads of every page). Allocated IN
+        the sharding: a whole pool zero-filled on the default chip and
+        then resharded would need the unsharded bytes there first."""
+        sh = sharding if isinstance(sharding, cls) \
+            else cls(sharding, sharding, sharding, sharding)
+        rows = (cfg.num_layers, cfg.num_pages, cfg.page_size,
+                cfg.num_heads)
+        dt = cfg.storage_dtype
+        pool = cls(jnp.zeros(rows + (cfg.head_dim,), dt, device=sh.k),
+                   jnp.zeros(rows + (cfg.head_dim,), dt, device=sh.v))
+        if not cfg.quantized:
+            return pool
+        return dataclasses.replace(
+            pool,
+            k_scale=jnp.zeros(rows, jnp.float32, device=sh.k_scale),
+            v_scale=jnp.zeros(rows, jnp.float32, device=sh.v_scale))
+
+    @staticmethod
+    def specs(axis: str) -> "KVPool":
+        """The leaves' PartitionSpecs with the head axis on mesh axis
+        `axis` (a spec of a leaf the pool lacks matches its None)."""
+        page = PartitionSpec(None, None, None, axis, None)
+        scale = PartitionSpec(None, None, None, axis)
+        return KVPool(page, page, scale, scale)
+
+    @property
+    def quantized(self) -> bool:
+        return self.k_scale is not None
+
+    def write(self, layer: int, pages, offs, k, v) -> "KVPool":
+        """Store rows k, v (T, H, D) of `layer` at (pages[t], offs[t]):
+        lossless pools cast on the scatter (f32 pages keep activation
+        values exactly, bf16 pages round); quantized pools quantize
+        each (token, head) row against its own amax scale and store the
+        scale beside it."""
+        if not self.quantized:
+            return KVPool(
+                self.k.at[layer, pages, offs].set(k.astype(self.k.dtype)),
+                self.v.at[layer, pages, offs].set(v.astype(self.v.dtype)))
+        kq, ksc = quantize_kv_rows(k, self.k.dtype)
+        vq, vsc = quantize_kv_rows(v, self.v.dtype)
+        return KVPool(self.k.at[layer, pages, offs].set(kq),
+                      self.v.at[layer, pages, offs].set(vq),
+                      self.k_scale.at[layer, pages, offs].set(ksc),
+                      self.v_scale.at[layer, pages, offs].set(vsc))
+
+    def layer(self, i: int):
+        """`layer`'s operands of the paged attention kernel
+        (kernels/paged_ragged_v2.paged_attention_ragged_v2): (k_pages,
+        v_pages, k_scales, v_scales), pages (page, slot, head, dim),
+        the scales (page, slot, head) or None."""
+        if not self.quantized:
+            return self.k[i], self.v[i], None, None
+        return self.k[i], self.v[i], self.k_scale[i], self.v_scale[i]
+
+    def rows(self, idx) -> "KVPool":
+        """Whole pages `idx` of every layer, as a pool of len(idx)
+        pages — the handoff's gather (the wire carries its leaves)."""
+        return jax.tree.map(lambda a: a[:, idx], self)
+
+    def with_rows(self, idx, rows: "KVPool") -> "KVPool":
+        """The pool with `rows` (what `rows(idx)` gave, here or on
+        another engine) stored at pages `idx`."""
+        return jax.tree.map(lambda a, r: a.at[:, idx].set(r), self, rows)
+
+    def check_geometry(self, cfg: KVCacheConfig) -> None:
+        """The leaves are what `alloc(cfg)` makes: a drifted shape or
+        dtype would dequantize every resident token against the wrong
+        scale rows."""
+        want = jax.eval_shape(lambda: KVPool.alloc(cfg))
+        assert self.quantized == want.quantized, (
+            f"kv_dtype={cfg.kv_dtype} pool "
+            f"{'carries' if self.quantized else 'lacks'} scale arrays")
+        for name, a, w in zip(("k", "v", "k_scale", "v_scale"),
+                              jax.tree.leaves(self),
+                              jax.tree.leaves(want)):
+            assert (a.shape, a.dtype) == (w.shape, w.dtype), (
+                f"pool leaf {name} is {a.shape} {a.dtype}; the "
+                f"configuration's is {w.shape} {w.dtype}")
+
+    def check_scales(self, where: Sequence[Tuple[str, int, int]]) -> None:
+        """Audit the stored rows at `where` = (what, page, offset) of a
+        quantized pool: K/V scales finite and non-negative, and a zero
+        scale vouching for an all-zero stored row (scale 0 is only
+        ever written for an all-zero activation row, so anything else
+        means a scale and its page drifted apart)."""
+        if not self.quantized or not where:
+            return
+        for name, q, s in (("k", self.k, self.k_scale),
+                           ("v", self.v, self.v_scale)):
+            q, s = np.asarray(q), np.asarray(s)
+            for what, page, off in where:
+                srow = s[:, page, off]             # (layers, H)
+                qrow = q[:, page, off]             # (layers, H, D)
+                assert np.all(np.isfinite(srow)) \
+                    and np.all(srow >= 0), (
+                    f"{name}-scale of {what} (page {page} off {off}) "
+                    f"is not finite/non-negative")
+                assert np.all(qrow[srow == 0.0] == 0), (
+                    f"{name}-page row of {what} (page {page} off "
+                    f"{off}) has zero scale but nonzero quantized "
+                    f"content")
+
+
 class PagedKVCache:
     """Host-side page allocator + per-slot page tables + prefix cache.
 
@@ -312,10 +441,6 @@ class PagedKVCache:
                                     dtype=np.int32)
         self.seq_lens = np.zeros((cfg.max_seqs,), dtype=np.int32)
         self._slot_free = list(range(cfg.max_seqs - 1, -1, -1))
-        # quantized-page scale bookkeeping (register_scale_meta):
-        # geometry of the engine's scale arrays, checked by
-        # check_invariants against cfg.scale_shape
-        self._scale_meta = None
         # pages whose content arrived over the disaggregated handoff
         # (import_pages) rather than from this engine's own compute:
         # they must stay hashed for as long as they are resident — an
@@ -802,52 +927,6 @@ class PagedKVCache:
         self.seq_lens[slot] = 0
         self._slot_free.append(slot)
 
-    # ---------------- device arrays -----------------------------------
-    def alloc_device_cache(self, dtype=None, sharding=None):
-        """The (k_pages, v_pages) device arrays, each
-        (num_layers, num_pages, page_size, num_heads, head_dim) at the
-        configured kv_dtype (dtype overrides — the pre-quantization
-        callers passed explicit dtypes). `sharding` (a NamedSharding
-        over the serve mesh's head axis) places the pool head-sharded
-        for tensor-parallel serving — each device holds its H/t heads
-        of every page; a SingleDeviceSharding places a one-chip
-        replica's pool on its chip. Created once per engine; thereafter they only
-        flow through jitted steps (donated), never through this
-        manager. Quantized pools pair with :meth:`alloc_scale_arrays`."""
-        import jax.numpy as jnp
-        c = self.cfg
-        shape = (c.num_layers, c.num_pages, c.page_size, c.num_heads,
-                 c.head_dim)
-        dt = dtype or c.storage_dtype
-        # allocated IN the sharding: a whole pool zero-filled on the
-        # default chip and then resharded would need the unsharded
-        # bytes there first
-        return (jnp.zeros(shape, dt, device=sharding),
-                jnp.zeros(shape, dt, device=sharding))
-
-    def alloc_scale_arrays(self, sharding=None):
-        """The (k_scales, v_scales) f32 per-page scale arrays for
-        quantized (int8/fp8) pools (cfg.scale_shape). Like the page
-        arrays they flow functionally through the jitted steps, donated
-        — and shard on the same head axis."""
-        import jax.numpy as jnp
-        if not self.cfg.quantized:
-            raise RuntimeError(
-                f"scale arrays exist only for quantized (int8/fp8) "
-                f"pools (kv_dtype={self.cfg.kv_dtype})")
-        return (jnp.zeros(self.cfg.scale_shape, jnp.float32,
-                          device=sharding),
-                jnp.zeros(self.cfg.scale_shape, jnp.float32,
-                          device=sharding))
-
-    def register_scale_meta(self, k_scales, v_scales) -> None:
-        """Record the scale-array geometry the engine allocated so
-        check_invariants can vouch for the quantized-page bookkeeping
-        (shape/dtype drift between the host page accounting and the
-        device scale arrays would silently dequantize garbage)."""
-        self._scale_meta = (tuple(k_scales.shape), str(k_scales.dtype),
-                            tuple(v_scales.shape), str(v_scales.dtype))
-
     def parked_pages(self) -> Tuple[int, ...]:
         """The prefix-cache-parked pages: complete, unreferenced,
         prefix-matchable — content that must outlive its writer for a
@@ -878,11 +957,12 @@ class PagedKVCache:
         }
 
     # ---------------- invariant checks (tests) ------------------------
-    def check_invariants(self) -> None:
+    def check_invariants(self, pool: Optional[KVPool] = None) -> None:
         """Property-style asserts: refcounts equal the number of table
         references, the free/cached/mapped states partition the pool,
         no page leaks or double-frees, tables are contiguous prefixes,
-        and the hash registry is a consistent bijection."""
+        the hash registry is a consistent bijection, and `pool` (the
+        engine's device pool, where given) has this geometry."""
         c = self.cfg
         table_refs: Dict[int, int] = {}
         for s in range(c.max_seqs):
@@ -948,19 +1028,7 @@ class PagedKVCache:
             assert page in self._hash_of_page, (
                 f"imported page {page} lost its chain key while still "
                 f"tracked as handoff content")
-        # quantized-page scale bookkeeping: an int8 pool must have
-        # registered scale arrays whose geometry matches the page
-        # geometry exactly — a drifted shape would dequantize every
-        # resident token against the wrong scale rows — and a
-        # non-quantized pool must not carry scale state at all.
-        if c.quantized:
-            if self._scale_meta is not None:
-                ks_shape, ks_dt, vs_shape, vs_dt = self._scale_meta
-                assert ks_shape == c.scale_shape == vs_shape, (
-                    f"scale arrays {ks_shape}/{vs_shape} do not match "
-                    f"the pool geometry {c.scale_shape}")
-                assert ks_dt == vs_dt == "float32", (
-                    f"scale arrays must be float32, got {ks_dt}/{vs_dt}")
-        else:
-            assert self._scale_meta is None, (
-                f"kv_dtype={c.kv_dtype} pool carries scale bookkeeping")
+        # the device pool this bookkeeping describes, where the caller
+        # holds one: its leaves must be of this configuration's geometry
+        if pool is not None:
+            pool.check_geometry(c)

@@ -459,8 +459,8 @@ class ReplicaPool:
         pool and programs, never stacked on chip 0. On a tpu backend a
         pool that asks for more chips than there are fails here."""
         role_cfg = dataclasses.replace(self.config, metrics_port=None)
-        return ServeEngine(self.model, chunked_prefill=True,
-                           telemetry=self.telemetry, config=role_cfg,
+        return ServeEngine(self.model, telemetry=self.telemetry,
+                           config=role_cfg,
                            replica=len(self.replicas),
                            **self._engine_kwargs)
 
@@ -545,7 +545,7 @@ class ReplicaPool:
         reclaimed (prefix-parked pages are refcount-0 reclaimable and
         count as free)."""
         for r in self.replicas:
-            r.engine.cache.check_invariants()
+            r.engine.cache.check_invariants(r.engine.pool)
             c = r.engine.cache_cfg
             free = r.engine.cache.free_pages
             assert free == c.usable_pages, (

@@ -270,7 +270,6 @@ class ContinuousBatchingScheduler:
 
     def __init__(self, cache: PagedKVCache,
                  prefill_token_budget: int = 512,
-                 chunked_prefill: bool = True,
                  admit_watermark: float = 0.02,
                  spec_tokens: int = 0,
                  drafter: Optional[Drafter] = None,
@@ -297,14 +296,8 @@ class ContinuousBatchingScheduler:
         self.reject_stalls = int(reject_stalls)
         self.rung = 0
         self.prefill_token_budget = int(prefill_token_budget)
-        self.chunked_prefill = bool(chunked_prefill)
-        # prefix sharing needs chunked prefill: the legacy per-bucket
-        # program recomputes and RE-SCATTERS every prompt position, which
-        # would clobber shared pages other sequences are reading
-        self.prefix_cache = cache.prefix_enabled and self.chunked_prefill
-        # speculative decoding also needs the mixed program: the legacy
-        # decode step has exactly one lane per slot, nowhere to verify
-        self.spec_tokens = int(spec_tokens) if self.chunked_prefill else 0
+        self.prefix_cache = cache.prefix_enabled
+        self.spec_tokens = int(spec_tokens)
         self.drafter = drafter if drafter is not None \
             else (PromptLookupDrafter() if self.spec_tokens > 0 else None)
         self.watermark_pages = watermark_pages(
@@ -562,21 +555,13 @@ class ContinuousBatchingScheduler:
                     k += 1
             cached_len = len(cached_pages) * ps
             end = min(ctx_len, cached_len + budget)
-            if not self.chunked_prefill:
-                # legacy whole-prompt prefill: one bucket program per
-                # request; the first admission of a step ignores the
-                # budget so an over-budget prompt still gets served
-                if end < ctx_len and any(not c.is_decode for c in chunks):
-                    break
-                end = ctx_len
             # matched pages sitting at refcount 0 come OUT of the
             # reclaimable count the moment we attach them
             lru_cached = sum(1 for p in cached_pages if cache.ref(p) == 0)
             need = cache.pages_for(end) - len(cached_pages)
             if forced:
                 avail = (eff_free() - lru_cached) * ps
-                if self.chunked_prefill:
-                    end = min(end, cached_len + avail)
+                end = min(end, cached_len + avail)
                 if end <= cached_len or cached_len + avail < end:
                     # ladder rung 4: nothing is running, nothing else is
                     # planned, and the head STILL cannot get one chunk's

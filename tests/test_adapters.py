@@ -297,19 +297,6 @@ def test_unregistered_tenant_rejected_at_submit(base_setup):
     eng.adapters.check_invariants()
 
 
-def test_legacy_path_refuses_adapters():
-    from flexflow_tpu.models.transformer import build_transformer_lm
-    from flexflow_tpu.serve import ServeEngine
-    cfg = FFConfig(batch_size=1, kv_page_size=8, kv_num_pages=33,
-                   serve_max_seqs=4, serve_prefill_budget=16,
-                   adapter_rank=4)
-    lm = build_transformer_lm(cfg, vocab_size=61, max_seq_len=32,
-                              hidden=32, num_heads=4, num_layers=2,
-                              ff_dim=64)
-    with pytest.raises(ValueError, match="chunked"):
-        ServeEngine(lm, chunked_prefill=False)
-
-
 # ----------------------------------------------------------- tenancy
 def test_tenant_salt_disjoint_keys():
     from flexflow_tpu.serve import prefix_page_keys

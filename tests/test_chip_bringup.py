@@ -260,9 +260,8 @@ def test_replica_placement():
         assert owned == [(devs[i],) for i in range(4)]
         for r in pool.replicas:
             eng = r.engine
-            eng._device_pages()
             on = {d for leaf in jax.tree_util.tree_leaves(
-                (eng._step_params, eng._pool_args()))
+                (eng._step_params, eng._device_pool()))
                 for d in leaf.devices()}
             assert on == set(eng.devices)     # weights AND pages
         fps = {r.engine.programs.fp_hash for r in pool.replicas}

@@ -151,12 +151,11 @@ def test_engine_export_import_rows_bit_equal():
     assert ship is not None and ship.num_pages == len(prompt) // 4
     written = dst.import_kv(ship)
     assert written == ship.num_pages
-    dst.cache.check_invariants()
-    pages = [dst.cache._page_of_hash[k] for k in ship.keys]
-    got_k = np.asarray(dst._k_pages)[:, pages]
-    got_v = np.asarray(dst._v_pages)[:, pages]
-    np.testing.assert_array_equal(got_k, ship.k_rows)
-    np.testing.assert_array_equal(got_v, ship.v_rows)
+    dst.cache.check_invariants(dst.pool)
+    got = dst.pool.rows(
+        np.asarray([dst.cache._page_of_hash[k] for k in ship.keys]))
+    np.testing.assert_array_equal(np.asarray(got.k), ship.k_rows)
+    np.testing.assert_array_equal(np.asarray(got.v), ship.v_rows)
     # geometry mismatch is rejected loudly
     bad = dataclasses.replace(ship, page_size=8)
     with pytest.raises(ValueError, match="geometry"):
@@ -182,10 +181,10 @@ def test_export_import_sharded_tp2():
                      src.export_kv(r.slot, r.context)))
     (ship,) = ships
     assert dst.import_kv(ship) == ship.num_pages
-    pages = [dst.cache._page_of_hash[k] for k in ship.keys]
-    np.testing.assert_array_equal(
-        np.asarray(dst._k_pages)[:, pages], ship.k_rows)
-    dst.cache.check_invariants()
+    got = dst.pool.rows(
+        np.asarray([dst.cache._page_of_hash[k] for k in ship.keys]))
+    np.testing.assert_array_equal(np.asarray(got.k), ship.k_rows)
+    dst.cache.check_invariants(dst.pool)
     # sharded cluster == sharded unified engine, token for token
     uni = ServeEngine(ff, spec_tokens=0)
     uni.warmup()

@@ -19,6 +19,8 @@ Layers:
     the simulator with explicit values authoritative.
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -26,22 +28,21 @@ import jax
 import jax.numpy as jnp
 
 from flexflow_tpu.config import FFConfig
-from flexflow_tpu.kernels.flash_attention import (
-    paged_attention_ragged,
-    paged_attention_ragged_v1,
-)
 from flexflow_tpu.kernels.paged_ragged_v2 import (
     Q_ROWS,
     _BLOCK_KV_TABLE,
+    _ragged_jnp,
     choose_block_kv,
     dequantize_kv,
+    paged_attention_ragged_v2,
     quantize_kv_rows,
     ragged_dispatch_passes,
     register_block_kv,
 )
 from flexflow_tpu.models.transformer import build_transformer_lm
 from flexflow_tpu.serve import ServeEngine
-from flexflow_tpu.serve.kv_cache import KVCacheConfig, PagedKVCache
+from flexflow_tpu.serve.kv_cache import (KVCacheConfig, KVPool,
+                                         PagedKVCache)
 
 
 # --------------------------------------------------------------- helpers
@@ -97,17 +98,23 @@ def _prompts(rng, n, lo=4, hi=28):
 # ----------------------------------------------- kernel v2 bit-equality
 @pytest.mark.parametrize("seed", [0, 1, 2, 7])
 def test_ragged_v2_jnp_bit_identical_to_v1(seed):
-    """fp32 acceptance: the rebuilt kernel's fallback is bit-for-bit
-    the old kernel across random ragged (slot, position) mixes — the
-    whole serve parity ladder (full-prefill oracle, one-lane ==
-    decode) transfers to v2 unchanged."""
+    """fp32 acceptance: the kernel's jnp twin is bit-for-bit the
+    contiguous full-prefill attention (what v1 was held to, the oracle
+    tests/test_serve.py holds) across random ragged (slot, position)
+    mixes: the page and slot indirection is pure data movement."""
+    from test_serve import _full_prefill_attention
     q, kp, vp, table, slots, lens = _ragged_setup(3 + seed % 3, seed)
-    v1 = paged_attention_ragged_v1(q, kp, vp, table, slots, lens,
-                                   use_pallas=False)
-    v2 = paged_attention_ragged(q, kp, vp, table, slots, lens,
-                                use_pallas=False)
-    assert v1.dtype == v2.dtype
-    assert np.array_equal(np.asarray(v1), np.asarray(v2))
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    out = _ragged_jnp(q, kp, vp, table, slots, lens, scale)
+    # each lane's history laid out contiguously, gathered on the host
+    lane_pages = np.asarray(table)[np.asarray(slots)]        # (T, pp)
+    k_full, v_full = (
+        np.asarray(a)[lane_pages].reshape(len(slots), -1, *a.shape[2:])
+        for a in (kp, vp))
+    ref = _full_prefill_attention(q, jnp.asarray(k_full),
+                                  jnp.asarray(v_full), lens, scale)
+    assert out.dtype == ref.dtype
+    assert np.array_equal(np.asarray(out), np.asarray(ref))
 
 
 @pytest.mark.parametrize("block_kv", [4, 8, 12, 24])
@@ -116,9 +123,9 @@ def test_ragged_v2_pallas_interpret_matches_jnp(block_kv):
     tolerance for every kv-block shape (whole pages, ragged tails,
     whole-table blocks)."""
     q, kp, vp, table, slots, lens = _ragged_setup(3, 60)
-    ref = paged_attention_ragged(q, kp, vp, table, slots, lens,
+    ref = paged_attention_ragged_v2(q, kp, vp, table, slots, lens,
                                  use_pallas=False)
-    out = paged_attention_ragged(q, kp, vp, table, slots, lens,
+    out = paged_attention_ragged_v2(q, kp, vp, table, slots, lens,
                                  interpret=True, block_kv=block_kv)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-6, atol=2e-6)
@@ -131,9 +138,9 @@ def test_ragged_v2_int8_bounded_error_and_path_agreement():
     q, kp, vp, table, slots, lens = _ragged_setup(4, 11)
     kq, ks = quantize_kv_rows(kp)
     vq, vs = quantize_kv_rows(vp)
-    f32 = paged_attention_ragged(q, kp, vp, table, slots, lens,
+    f32 = paged_attention_ragged_v2(q, kp, vp, table, slots, lens,
                                  use_pallas=False)
-    int8 = paged_attention_ragged(q, kq, vq, table, slots, lens,
+    int8 = paged_attention_ragged_v2(q, kq, vq, table, slots, lens,
                                   use_pallas=False, k_scales=ks,
                                   v_scales=vs)
     # bound: the output is a convex combination of dequantized V rows
@@ -144,7 +151,7 @@ def test_ragged_v2_int8_bounded_error_and_path_agreement():
     err = np.abs(np.asarray(int8) - np.asarray(f32)).max()
     assert err < 0.05, f"int8 attention error {err} exceeds the bound"
     assert err > 0, "int8 path suspiciously exact (not quantizing?)"
-    pal = paged_attention_ragged(q, kq, vq, table, slots, lens,
+    pal = paged_attention_ragged_v2(q, kq, vq, table, slots, lens,
                                  interpret=True, block_kv=8,
                                  k_scales=ks, v_scales=vs)
     np.testing.assert_allclose(np.asarray(pal), np.asarray(int8),
@@ -198,9 +205,9 @@ def test_fp8_attention_bounded_error():
     q, kp, vp, table, slots, lens = _ragged_setup(4, 21)
     kq, ks = quantize_kv_rows(kp, jnp.float8_e4m3fn)
     vq, vs = quantize_kv_rows(vp, jnp.float8_e4m3fn)
-    f32 = paged_attention_ragged(q, kp, vp, table, slots, lens,
+    f32 = paged_attention_ragged_v2(q, kp, vp, table, slots, lens,
                                  use_pallas=False)
-    fp8 = paged_attention_ragged(q, kq, vq, table, slots, lens,
+    fp8 = paged_attention_ragged_v2(q, kq, vq, table, slots, lens,
                                  use_pallas=False, k_scales=ks,
                                  v_scales=vs)
     err = np.abs(np.asarray(fp8) - np.asarray(f32)).max()
@@ -334,11 +341,6 @@ def test_bf16_pages_run_and_report():
     assert pool["page_ratio_vs_f32"] == 2.0
 
 
-def test_quantized_requires_chunked_prefill():
-    with pytest.raises(ValueError, match="chunked"):
-        ServeEngine(_lm("int8"), chunked_prefill=False)
-
-
 # ------------------------------------------------- sizing / bookkeeping
 def test_kv_pool_mb_sizes_pages_from_itemsize():
     """The hardcoded-4 fix: an equal byte budget yields page counts in
@@ -371,23 +373,26 @@ def test_scale_meta_wired_into_check_invariants():
                         page_size=4, num_pages=7, max_seqs=2,
                         max_seq_len=16, kv_dtype="int8")
     cache = PagedKVCache(cfg)
-    cache.check_invariants()   # quantized, meta not yet registered: ok
-    ks, vs = cache.alloc_scale_arrays()
-    cache.register_scale_meta(ks, vs)
-    cache.check_invariants()
+    cache.check_invariants()   # host bookkeeping alone: ok
+    pool = KVPool.alloc(cfg)
+    cache.check_invariants(pool)
     # geometry drift must be caught
-    cache.register_scale_meta(ks[:, :3], vs)
-    with pytest.raises(AssertionError, match="scale arrays"):
-        cache.check_invariants()
-    # a lossless pool must not carry scale bookkeeping
-    plain = PagedKVCache(KVCacheConfig(
+    drifted = dataclasses.replace(pool, k_scale=pool.k_scale[:, :3])
+    with pytest.raises(AssertionError, match="k_scale"):
+        cache.check_invariants(drifted)
+    with pytest.raises(AssertionError, match="v_scale.*float32"):
+        cache.check_invariants(dataclasses.replace(
+            pool, v_scale=pool.v_scale.astype(jnp.bfloat16)))
+    # a lossless pool must not carry scale bookkeeping, and gets none
+    plain_cfg = KVCacheConfig(
         num_layers=1, num_heads=2, head_dim=4, page_size=4,
-        num_pages=7, max_seqs=2, max_seq_len=16))
-    plain._scale_meta = ("bogus",) * 4
-    with pytest.raises(AssertionError, match="scale bookkeeping"):
-        plain.check_invariants()
-    with pytest.raises(RuntimeError, match="int8"):
-        plain.alloc_scale_arrays()
+        num_pages=7, max_seqs=2, max_seq_len=16)
+    plain, plain_pool = PagedKVCache(plain_cfg), KVPool.alloc(plain_cfg)
+    assert plain_pool.k_scale is None and plain_pool.v_scale is None
+    plain.check_invariants(plain_pool)
+    with pytest.raises(AssertionError, match="carries scale arrays"):
+        plain.check_invariants(dataclasses.replace(
+            plain_pool, k_scale=pool.k_scale, v_scale=pool.v_scale))
 
 
 def test_kv_pool_stats_and_serve_report_line():

@@ -316,7 +316,8 @@ def test_flash_attention_bf16_fwd_bwd(impl):
 def test_paged_attention_bf16_pallas_vs_jnp():
     """The serving kernels accept bf16 queries against (f32) KV pages:
     interpret-pallas and jnp fallback agree bit-for-bit."""
-    from flexflow_tpu.kernels.flash_attention import paged_attention_decode
+    from flexflow_tpu.kernels.paged_ragged_v2 import \
+        paged_attention_ragged_v2
 
     rng = np.random.RandomState(1)
     P, ps, hh, d = 9, 8, 2, 16
@@ -326,9 +327,11 @@ def test_paged_attention_bf16_pallas_vs_jnp():
     vp = jnp.asarray(rng.randn(P, ps, hh, d), jnp.float32)
     pt = jnp.asarray(rng.randint(1, P, (B, pp)), jnp.int32)
     sl = jnp.asarray([5, 17, 30], jnp.int32)
-    a = paged_attention_decode(q, kp, vp, pt, sl, use_pallas=True,
-                               interpret=True)
-    b_ = paged_attention_decode(q, kp, vp, pt, sl, use_pallas=False)
+    slots = jnp.arange(B, dtype=jnp.int32)     # one lane per sequence
+    a = paged_attention_ragged_v2(q, kp, vp, pt, slots, sl,
+                                  use_pallas=True, interpret=True)
+    b_ = paged_attention_ragged_v2(q, kp, vp, pt, slots, sl,
+                                   use_pallas=False)
     assert a.dtype == jnp.bfloat16 and b_.dtype == jnp.bfloat16
     np.testing.assert_array_equal(np.asarray(a, np.float32),
                                   np.asarray(b_, np.float32))

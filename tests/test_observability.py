@@ -292,7 +292,7 @@ def test_serve_memory_ledger_matches_live_buffers():
     live = float(sum(
         np.prod(x.shape) * x.dtype.itemsize
         for x in [*__import__("jax").tree_util.tree_leaves(
-            eng._step_params), eng._k_pages, eng._v_pages]))
+            (eng._step_params, eng.pool))]))
     assert led["live_bytes"] == pytest.approx(live, rel=1e-9)
     assert led["params_bytes"] + led["kv_pool_bytes"] \
         == pytest.approx(live, rel=0.05)

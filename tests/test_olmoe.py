@@ -278,12 +278,11 @@ def test_step_events_count_the_live_lanes_experts(engine):
 
 def test_mixed_step_lowers_with_the_expert_scopes(engine):
     c = engine.cache_cfg
-    kp, vp = engine._device_pages()
     z = np.zeros((engine.mixed_width,), np.int32)
     pts = np.zeros((c.max_seqs, c.pages_per_seq), np.int32)
     text = jax.jit(engine._mixed_impl).lower(
-        engine._step_params, kp, vp, z, z, z, z, pts, z, z + 1
-    ).as_text(debug_info=True)
+        engine._step_params, engine._device_pool(), z, z, z, z, pts, z,
+        z + 1).as_text(debug_info=True)
     for path in ("serve_step/embed/", "serve_step/layer0/ln/",
                  "serve_step/layer0/qkv/", "serve_step/layer1/kv_write/",
                  "serve_step/layer1/attn/", "serve_step/layer0/attn_out/",
@@ -301,7 +300,6 @@ def test_mixed_step_lowers_with_the_expert_scopes(engine):
 @pytest.mark.parametrize("kwargs,cfg,message", [
     ({"tensor_parallel": 2}, {}, "tensor-parallel"),
     ({}, {"adapter_rank": 4}, "adapter"),
-    ({"chunked_prefill": False}, {}, "legacy"),
     ({}, {"serve_mesh": "auto"}, "serve_mesh='auto'"),
 ])
 def test_what_olmoe_is_not_served_on_raises_by_name(kwargs, cfg, message):
@@ -317,7 +315,8 @@ def test_a_model_of_neither_shape_is_refused():
 
 # --------------------- the paged kernel at OLMoE's head shape: 16 x 128
 def test_paged_kernel_at_16_heads_of_128_equals_the_jnp_twin():
-    from flexflow_tpu.kernels.flash_attention import paged_attention_ragged
+    from flexflow_tpu.kernels.paged_ragged_v2 import \
+        paged_attention_ragged_v2
     from flexflow_tpu.kernels.paged_ragged_v2 import (Q_ROWS,
                                                       build_work_list,
                                                       max_work_items)
@@ -335,11 +334,11 @@ def test_paged_kernel_at_16_heads_of_128_equals_the_jnp_twin():
     q = jnp.asarray(rng.randn(len(slots), h, d).astype(np.float32))
     args = (q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(pt),
             jnp.asarray(slots), jnp.asarray(lens))
-    ref = paged_attention_ragged(*args, use_pallas=False)
+    ref = paged_attention_ragged_v2(*args, use_pallas=False)
     work = build_work_list(
         args[3], args[4], args[5], page_size=ps, block_pages=2,
         max_items=max_work_items(len(slots), pp, 2, Q_ROWS, 3))
-    out = paged_attention_ragged(*args, interpret=True, work=work)
+    out = paged_attention_ragged_v2(*args, interpret=True, work=work)
     assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_allclose(out, ref, rtol=2e-6, atol=2e-6)
 

@@ -22,10 +22,10 @@ import pytest
 
 import jax.numpy as jnp
 
-from flexflow_tpu.kernels.flash_attention import paged_attention_ragged
 from flexflow_tpu.kernels.paged_ragged_v2 import (_FIRST, _LAST, _LIVE,
                                                   Q_ROWS, build_work_list,
                                                   max_work_items,
+                                                  paged_attention_ragged_v2,
                                                   quantize_kv_rows,
                                                   work_items)
 
@@ -280,7 +280,7 @@ def test_kernel_matches_the_jnp_twin_on_every_layout(layout, fmt, h, d,
     kp, vp, scales = _pools(rng, h, d, fmt)
     q = jnp.asarray(rng.randn(len(slots), h, d).astype(np.float32))
     slots, lens = jnp.asarray(slots), jnp.asarray(lens)
-    ref = paged_attention_ragged(q, kp, vp, pt, slots, lens,
+    ref = paged_attention_ragged_v2(q, kp, vp, pt, slots, lens,
                                  use_pallas=False, **scales)
     # once on the caller's proven bound, once on the kernel's own
     work = build_work_list(
@@ -289,7 +289,7 @@ def test_kernel_matches_the_jnp_twin_on_every_layout(layout, fmt, h, d,
     for kw in ({"work": work}, {"block_kv": bp * PS}):
         if "block_kv" in kw and (bp == 1 or fmt != "float32"):
             continue              # the long grid, interpreted: f32 only
-        out = np.asarray(paged_attention_ragged(
+        out = np.asarray(paged_attention_ragged_v2(
             q, kp, vp, pt, slots, lens, interpret=True, **kw, **scales))
         assert np.isfinite(out).all()   # the inactive lanes' rows too
         np.testing.assert_allclose(out, np.asarray(ref), rtol=2e-6,
@@ -309,7 +309,7 @@ def test_a_list_too_long_for_smem_is_split_by_lanes(monkeypatch):
     lens = jnp.asarray(rng.randint(1, PP * PS + 1,
                                    size=lanes).astype(np.int32))
     q = jnp.asarray(rng.randn(lanes, 4, 64).astype(np.float32))
-    ref = paged_attention_ragged(q, kp, vp, pt, slots, lens,
+    ref = paged_attention_ragged_v2(q, kp, vp, pt, slots, lens,
                                  use_pallas=False)
     calls = []
     real = k._ragged_v2_pallas
@@ -319,7 +319,7 @@ def test_a_list_too_long_for_smem_is_split_by_lanes(monkeypatch):
     # one tile of lanes a call: 32 lanes x 3 blocks x (3 + 8) words
     monkeypatch.setattr(k, "SMEM_LIST_WORDS",
                         k.max_work_items(Q_ROWS, PP, 8) * 11)
-    out = paged_attention_ragged(q, kp, vp, pt, slots, lens,
+    out = paged_attention_ragged_v2(q, kp, vp, pt, slots, lens,
                                  interpret=True, block_kv=8 * PS)
     assert calls == [Q_ROWS, Q_ROWS, Q_ROWS, 5]
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),

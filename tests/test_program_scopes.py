@@ -52,11 +52,10 @@ def _prompts(n, lo=4, hi=40, seed=0):
 # ------------------------------------------------------------- scopes
 def _mixed_lowered(eng) -> str:
     c = eng.cache_cfg
-    kp, vp = eng._device_pages()
     z = np.zeros((eng.mixed_width,), np.int32)
     pts = np.zeros((c.max_seqs, c.pages_per_seq), np.int32)
     return jax.jit(eng._mixed_impl).lower(
-        eng._step_params, kp, vp, z, z, z, z, pts, z, z + 1
+        eng._step_params, eng._device_pool(), z, z, z, z, pts, z, z + 1
     ).as_text(debug_info=True)
 
 
@@ -82,14 +81,7 @@ def test_quantized_mixed_step_keeps_the_scopes():
     m = build_transformer_lm(cfg, vocab_size=VOCAB, max_seq_len=32,
                              hidden=32, num_heads=4, num_layers=1,
                              ff_dim=64)
-    eng = ServeEngine(m)
-    c = eng.cache_cfg
-    kp, vp = eng._device_pages()
-    z = np.zeros((eng.mixed_width,), np.int32)
-    pts = np.zeros((c.max_seqs, c.pages_per_seq), np.int32)
-    text = jax.jit(eng._mixed_q_impl).lower(
-        eng._step_params, kp, vp, eng._k_scales, eng._v_scales, z, z, z,
-        z, pts, z, z + 1).as_text(debug_info=True)
+    text = _mixed_lowered(ServeEngine(m))
     # quantize-and-scatter, the scale pools' scatters included
     assert text.count("serve_step/layer0/kv_write/scatter") >= 4
     assert "serve_step/layer0/kv_write/reduce_max" in text   # the amax

@@ -30,7 +30,7 @@ from flexflow_tpu.models.transformer import build_transformer_lm
 from flexflow_tpu.serve import ServeEngine
 
 VOCAB = 89
-FAMILIES = ("prefill", "decode", "mixed", "adapter", "export", "import")
+FAMILIES = ("mixed", "adapter", "export", "import")
 
 
 def _engine(cache_dir=None, **kw):

@@ -162,7 +162,7 @@ def test_session_mid_stream_submit_matches_generate():
     assert eng.cache.free_pages == eng.cache_cfg.usable_pages
 
 
-def test_session_exclusive_and_legacy_refused():
+def test_session_exclusive():
     ff = _lm()
     eng = ServeEngine(ff)
     s = eng.start_session()
@@ -170,9 +170,6 @@ def test_session_exclusive_and_legacy_refused():
         eng.start_session()
     s.close()
     eng.start_session().close()   # reopens after close
-    leg = ServeEngine(ff, chunked_prefill=False)
-    with pytest.raises(ValueError, match="chunked"):
-        leg.start_session()
 
 
 # =======================================================================

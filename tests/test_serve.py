@@ -20,9 +20,9 @@ import jax
 import jax.numpy as jnp
 
 from flexflow_tpu.config import FFConfig
-from flexflow_tpu.kernels.flash_attention import (
-    _paged_decode_jnp,
-    paged_attention_decode,
+from flexflow_tpu.kernels.paged_ragged_v2 import (
+    _ragged_jnp,
+    paged_attention_ragged_v2,
 )
 from flexflow_tpu.serve.kv_cache import KVCacheConfig, PagedKVCache
 from flexflow_tpu.serve.scheduler import ContinuousBatchingScheduler
@@ -87,8 +87,10 @@ def _full_prefill_attention(q, k_full, v_full, seq_lens, scale):
 def test_paged_decode_bitwise_vs_full_prefill(batch):
     q, kp, vp, table, lens, k_full, v_full = _ragged_setup(batch, batch)
     scale = 1.0 / np.sqrt(q.shape[-1])
-    out = paged_attention_decode(q, kp, vp, table, lens, scale=scale,
-                                 use_pallas=False)
+    # a decode step: one lane per sequence, lane b reads table row b
+    out = paged_attention_ragged_v2(
+        q, kp, vp, table, jnp.arange(batch, dtype=jnp.int32), lens,
+        scale=scale, use_pallas=False)
     ref = _full_prefill_attention(q, k_full, v_full, lens, scale)
     assert out.dtype == ref.dtype
     # bit-for-bit: the page table is pure indirection, zero numerics
@@ -100,9 +102,10 @@ def test_paged_decode_bitwise_vs_full_prefill(batch):
 def test_paged_decode_pallas_interpret_matches_jnp(batch):
     q, kp, vp, table, lens, _, _ = _ragged_setup(batch, 100 + batch)
     scale = 1.0 / np.sqrt(q.shape[-1])
-    ref = _paged_decode_jnp(q, kp, vp, table, lens, scale)
-    out = paged_attention_decode(q, kp, vp, table, lens, scale=scale,
-                                 interpret=True)
+    slots = jnp.arange(batch, dtype=jnp.int32)
+    ref = _ragged_jnp(q, kp, vp, table, slots, lens, scale)
+    out = paged_attention_ragged_v2(q, kp, vp, table, slots, lens,
+                                    scale=scale, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-6, atol=2e-6)
 

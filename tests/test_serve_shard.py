@@ -156,8 +156,6 @@ def test_sharded_mesh_validation():
     with pytest.raises(ValueError, match="tensor"):
         from flexflow_tpu.parallel.mesh import make_mesh
         ServeEngine(ff, mesh=make_mesh((2,), ("data",)))
-    with pytest.raises(ValueError, match="single-device"):
-        ServeEngine(_lm(), tensor_parallel=2, chunked_prefill=False)
     # an explicit 1-D tensor mesh is accepted
     eng = ServeEngine(ff, mesh=serve_tensor_mesh(2))
     assert eng.tp == 2

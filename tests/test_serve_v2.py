@@ -2,10 +2,9 @@
 paged allocation, preemption, and sampling.
 
 Layered like tests/test_serve.py:
-  * kernel — paged_attention_ragged (the mixed-step kernel) equals
-    full-prefill attention BIT-FOR-BIT per lane on CPU, its Pallas
-    form (interpret mode) agrees with the jnp fallback, and a
-    one-lane-per-sequence call IS paged_attention_decode.
+  * kernel — paged_attention_ragged_v2 (the mixed-step kernel) equals
+    full-prefill attention BIT-FOR-BIT per lane on CPU, and its Pallas
+    form (interpret mode) agrees with the jnp fallback.
   * cache — refcounted sharing, commit/match/evict life cycle, and a
     property test driving random submit/decode/finish/preempt traffic
     against check_invariants.
@@ -21,10 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from flexflow_tpu.config import FFConfig
-from flexflow_tpu.kernels.flash_attention import (
-    paged_attention_decode,
-    paged_attention_ragged,
-)
+from flexflow_tpu.kernels.paged_ragged_v2 import paged_attention_ragged_v2
 from flexflow_tpu.serve import (
     ContinuousBatchingScheduler,
     KVCacheConfig,
@@ -108,7 +104,7 @@ def test_paged_ragged_bitwise_vs_full_prefill(batch):
     t = len(slots)
     q = rng.randn(t, 4, 8).astype(np.float32)
     scale = 1.0 / np.sqrt(q.shape[-1])
-    out = paged_attention_ragged(
+    out = paged_attention_ragged_v2(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
         jnp.asarray(table), jnp.asarray(slots), jnp.asarray(poss + 1),
         scale=scale, use_pallas=False)
@@ -128,35 +124,16 @@ def test_paged_ragged_pallas_interpret_matches_jnp(batch):
     t = len(slots)
     q = rng.randn(t, 4, 8).astype(np.float32)
     scale = 1.0 / np.sqrt(q.shape[-1])
-    ref = paged_attention_ragged(
+    ref = paged_attention_ragged_v2(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
         jnp.asarray(table), jnp.asarray(slots), jnp.asarray(poss + 1),
         scale=scale, use_pallas=False)
-    out = paged_attention_ragged(
+    out = paged_attention_ragged_v2(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
         jnp.asarray(table), jnp.asarray(slots), jnp.asarray(poss + 1),
         scale=scale, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-6, atol=2e-6)
-
-
-def test_paged_ragged_one_lane_is_decode():
-    """A one-lane-per-sequence ragged call at each sequence's tail is
-    exactly the decode kernel — same math, same bits."""
-    rng = np.random.RandomState(33)
-    kp, vp, table, lens, _, _ = _ragged_setup(4, 44)
-    q = rng.randn(4, 4, 8).astype(np.float32)
-    scale = 1.0 / np.sqrt(q.shape[-1])
-    slots = np.arange(4, dtype=np.int32)
-    ragged = paged_attention_ragged(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-        jnp.asarray(table), jnp.asarray(slots),
-        jnp.asarray(lens.astype(np.int32)), scale=scale, use_pallas=False)
-    decode = paged_attention_decode(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-        jnp.asarray(table), jnp.asarray(lens.astype(np.int32)),
-        scale=scale, use_pallas=False)
-    assert np.array_equal(np.asarray(ragged), np.asarray(decode))
 
 
 # --------------------------------------------------- prefix cache (host)
@@ -473,23 +450,6 @@ def test_preemption_exact_and_counted():
     assert eng.last_stats["preemptions"] > 0
     assert any(r["preemptions"] > 0
                for r in eng.last_stats["requests"])
-
-
-def test_legacy_path_exact(lm):
-    """serve_chunked_prefill=False keeps the PR 1 per-bucket prefill +
-    full-width decode pair working against the same scheduler."""
-    from flexflow_tpu.serve import ServeEngine
-    eng = ServeEngine(lm, chunked_prefill=False)
-    counts = eng.warmup()
-    assert counts["mixed"] == 0 and counts["decode"] == 1
-    rng = np.random.RandomState(9)
-    prompts = [list(rng.randint(1, 89, size=rng.randint(1, 30)))
-               for _ in range(5)]
-    max_new = [int(rng.randint(1, 8)) for _ in range(5)]
-    before = eng.compile_counts()
-    out = eng.generate(prompts, max_new)
-    assert eng.compile_counts() == before
-    assert out == eng.generate_reference(prompts, max_new)
 
 
 def test_unaligned_max_seq_len_reference_not_nan_poisoned():

@@ -102,7 +102,7 @@ def test_pool_of_four_replicas_owns_four_chips():
             (d,) for d in devs[:4]]
         for r in pool.replicas:
             on = {d for leaf in jax.tree_util.tree_leaves(
-                (r.engine._step_params, r.engine._pool_args()))
+                (r.engine._step_params, r.engine.pool))
                 for d in leaf.devices()}
             assert on == set(r.engine.devices)
         traffic = make_traffic(TrafficSpec(
