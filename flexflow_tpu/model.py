@@ -640,6 +640,7 @@ class FFModel:
         tel = self.telemetry if self.telemetry is not None \
             else telemetry_for()
         timed, track = tel.timed, ("train", "dispatch")
+        compiles = self.compile_counts().get("train_step", 0)
         with timed(track, "train_step"):
             with timed(track, "shard_batch"):
                 batch = self.executor.shard_batch(batch)
@@ -648,6 +649,11 @@ class FFModel:
             with timed(track, "dispatch"):
                 self.state, metrics = self.executor.train_step(
                     self.state, batch, rng)
+        if self.compile_counts().get("train_step", 0) != compiles:
+            # the step was traced and compiled just now: say once which
+            # core each attention op resolved to
+            tel.instant(("train", "compile"), "attn_impl",
+                        args=self.attn_impl_counts())
         return metrics
 
     def train_batches(self, batches: Sequence[Dict[str, np.ndarray]]):
@@ -1339,6 +1345,18 @@ class FFModel:
         return {k: np.asarray(jax.device_get(v))
                 for k, v in self.state.states[op_name].items()}
 
+    def attn_impl_counts(self) -> Dict[str, int]:
+        """How many attention ops' cores the last trace resolved to the
+        Pallas flash kernels and how many to the XLA path
+        (`MultiHeadAttention.attn_impl`; an op not traced yet counts
+        under neither)."""
+        counts = {"flash": 0, "xla": 0}
+        for op in self.ops:
+            impl = getattr(op, "attn_impl", None)
+            if impl is not None:
+                counts[impl] += 1
+        return counts
+
     def summary(self) -> str:
         lines = [f"{'op':30s} {'type':20s} {'output':24s} {'params':>12s}"]
         total = 0
@@ -1348,4 +1366,8 @@ class FFModel:
             lines.append(f"{op.name:30s} {op.op_type:20s} "
                          f"{str(op.outputs[0].shape):24s} {n:>12,d}")
         lines.append(f"total params: {total:,d}")
+        impl = self.attn_impl_counts()
+        if any(impl.values()):
+            lines.append("attention cores: " + ", ".join(
+                f"{k} {n}" for k, n in impl.items()))
         return "\n".join(lines)

@@ -14,10 +14,13 @@ flexflow_tpu/parallel/ring_attention.py.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.sharding import PartitionSpec
 
 from ..op import (
     CHANNEL_IN,
@@ -238,19 +241,44 @@ class MultiHeadAttention(Op):
         # kernels/flash_attention.resolve_flash, shared with the
         # all-to-all SP lowering), True = force the Pallas kernel,
         # False = never. The decision is made BEFORE the call: a kernel
-        # that is chosen and then raises, raises. pad_lanes=False for
-        # d=64 showed no consistent win in the same sweep, so it stays
-        # opt-in via flash_attention_bshd.
+        # that is chosen and then raises, raises.
+        #
+        # Under a mesh of several devices the call is made PER SHARD,
+        # inside shard_map over the mesh axes that carry this op's
+        # `sample` and `head` (GSPMD cannot partition a Mosaic call),
+        # and the gate reads the per-shard shapes. An axis that does
+        # not divide its dimension stays whole (spec_for_axes' graceful
+        # degradation). On one device the call is made directly.
         b, sq, h, d = q.shape
         sk = k.shape[1]
         from ..kernels.flash_attention import (flash_attention_bshd,
                                                resolve_flash)
+        mesh = ctx.mesh if ctx.mesh is not None and ctx.mesh.size > 1 \
+            else None
+
+        def axis_over(logical, n):
+            """(mesh axis, size) this op's `logical` axis splits n
+            over, else (None, 1)."""
+            size = ctx.mesh_axis_size(logical) if mesh is not None else 1
+            return (ctx.mesh_axis_name(logical), size) \
+                if size > 1 and n % size == 0 else (None, 1)
+
+        data_ax, nd = axis_over(SAMPLE, b)
+        head_ax, nh = axis_over(HEAD, h)
+        if head_ax == data_ax:
+            head_ax, nh = None, 1
         self.attn_impl = "flash" if (
             not has_seq_trunc and not self.add_zero_attn
-            and resolve_flash(self.use_flash, b, h, sq, sk, d,
+            and resolve_flash(self.use_flash, b // nd, h // nh, sq, sk, d,
                               jnp.dtype(q.dtype).itemsize)) else "xla"
         if self.attn_impl == "flash":
-            return flash_attention_bshd(q, k, v, causal=self.causal)
+            call = functools.partial(flash_attention_bshd,
+                                     causal=self.causal)
+            if mesh is not None:
+                spec = PartitionSpec(data_ax, None, head_ax, None)
+                call = shard_map(call, mesh=mesh, in_specs=(spec,) * 3,
+                                 out_specs=spec, check_vma=False)
+            return call(q, k, v)
         scale = 1.0 / math.sqrt(self.head_dim)
         logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                             preferred_element_type=jnp.float32) * scale

@@ -1,0 +1,100 @@
+"""The training flash kernels through the chip's OWN compiler, for a v5e
+that is described and not attached (libtpu's compile-only topology): what
+Mosaic refuses — a slice off the tiling, too much VMEM, a call GSPMD
+cannot partition — shows here and costs no chip time. Nothing runs, so
+nothing here says a result or a time (tests_tpu/test_flash_tpu.py does,
+on the chip).
+
+The topology is described inside a fixture, never at import: one process
+at a time may load the TPU's library, and the suite runs under several
+workers that all import this file.
+"""
+
+import functools
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from flexflow_tpu.kernels import flash_attention as fa
+
+KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """The gate and the kernel's platform check ask the default backend,
+    which is the CPU here: steer them, for the lowering alone. And the
+    suite's conftest asks f32 products of every matmul (a CPU's golden
+    values); the chip's program runs the default, bf16 operands as they
+    are."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with jax.default_matmul_precision("default"):
+        yield
+
+
+@pytest.mark.parametrize("b,s,h,d", [
+    (1, 2048, 32, 64),     # pretrain-1chip: two heads a slab
+    (2, 2048, 16, 128),    # one head a slab
+    (1, 1024, 64, 32),     # four heads a slab
+    (1, 1024, 3, 64),      # an odd head count, padded by one zero head
+    (1, 8192, 8, 64),      # whole-sequence operands past Mosaic's 16 MiB
+])
+def test_three_kernels_compile_for_a_v5e(topo, as_tpu, b, s, h, d):
+    one = SingleDeviceSharding(topo.devices[0])
+    x = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one)
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention_bshd(q, k, v, causal=True)
+                       .astype(jnp.float32))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    assert all(name in text for name in KERNELS)
+    assert text.count("tpu_custom_call") >= 3
+
+
+def test_sharded_attention_op_compiles_for_2x2(topo, as_tpu):
+    """OPT's widths on a data 2 x model 2 mesh: the op makes its call
+    per shard (GSPMD cannot partition a Mosaic call), and the auto gate
+    reads the shard's shapes."""
+    from flexflow_tpu import FFConfig, FFModel
+    from flexflow_tpu.op import OpContext
+
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    b, s, h, d = 2, 2048, 32, 64
+    ff = FFModel(FFConfig())
+    x = ff.create_tensor((b, s, h * d), dtype=jnp.bfloat16, name="x")
+    ff.multihead_attention(x, x, x, h * d, h, causal=True, name="mha")
+    op = ff.ops[0]
+    ctx = OpContext(training=True, mesh=mesh,
+                    op_strategy=types.SimpleNamespace(mesh_axis_for={
+                        "sample": "data", "head": "model"}.get))
+
+    def loss(params, x):
+        return jnp.sum(op.forward(params, [x] * 3, ctx)[0]
+                       .astype(jnp.float32))
+    specs = {"wq": P(None, "model", None), "wk": P(None, "model", None),
+             "wv": P(None, "model", None), "wo": P("model", None, None)}
+    shaped = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16)
+    params = {n: shaped(w.shape, sharding=NamedSharding(
+        mesh, specs.get(n, P()))) for n, w in op.weight_specs().items()}
+    xs = shaped((b, s, h * d), sharding=NamedSharding(
+        mesh, P("data", None, None)))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, xs).compile().as_text()
+    assert op.attn_impl == "flash"
+    assert all(name in text for name in KERNELS)

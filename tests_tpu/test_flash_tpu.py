@@ -35,12 +35,10 @@ def qkv(b, s, h, d, seed=0):
     return mk(), mk(), mk()
 
 
-@pytest.mark.parametrize("seq,d", [(512, 64), (1024, 64), (1024, 128)])
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_forward_and_grads_compiled(seq, d, causal):
+def assert_close_to_xla(b, seq, h, d, causal):
     from flexflow_tpu.kernels.flash_attention import flash_attention_bshd
 
-    q, k, v = qkv(4, seq, 8, d)
+    q, k, v = qkv(b, seq, h, d)
     fl = jax.jit(functools.partial(flash_attention_bshd, causal=causal))
     xl = jax.jit(functools.partial(xla_attn, causal=causal))
 
@@ -58,13 +56,36 @@ def test_flash_forward_and_grads_compiled(seq, d, causal):
     gx = loss(xl)(q, k, v)
     for a, b, name in zip(gf, gx, ("dq", "dk", "dv")):
         gerr = jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)))
-        assert float(gerr) < 0.06, (name, float(gerr))
+        # 0.06, or two bf16 steps of the largest value where a sum over
+        # 2048 rows has grown past 8 (one step there is 0.0625)
+        big = float(jnp.max(jnp.abs(b.astype(jnp.float32))))
+        tol = max(0.06, 2.0 * 2.0 ** (math.floor(math.log2(big)) - 7))
+        assert float(gerr) < tol, (name, float(gerr), tol)
+
+
+@pytest.mark.parametrize("seq,d", [(512, 64), (1024, 64), (1024, 128)])
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_forward_and_grads_compiled(seq, d, causal):
+    assert_close_to_xla(4, seq, 8, d, causal)
+
+
+@pytest.mark.parametrize("b,seq,h,d", [
+    (1, 2048, 32, 64),    # pretrain-1chip's attention: two heads a slab
+    (2, 2048, 16, 128),   # OLMoE's heads: one head a slab
+    (1, 4096, 3, 64),     # an odd head count: one zero head pads the slab
+    (1, 1024, 8, 32),     # four heads a slab
+])
+def test_packed_kernels_at_model_shapes(b, seq, h, d):
+    """The packed (b, s, h*d) kernels, Mosaic-compiled, forward and all
+    three gradients against the XLA path."""
+    assert_close_to_xla(b, seq, h, d, causal=True)
 
 
 @pytest.mark.parametrize("use_flash,b,seq,d,expect_flash", [
     (None, 2, 1024, 128, True),    # auto: eligible shape -> flash
+    (None, 1, 2048, 64, True),     # auto: 64-wide heads, long -> flash
     (None, 2, 256, 64, False),     # auto: XLA-favored shape -> no flash
-    (True, 2, 256, 64, True),      # explicit True overrides the heuristic
+    (True, 2, 256, 64, True),      # explicit True overrides the rule
     (False, 2, 1024, 128, False),  # explicit False always wins
 ])
 def test_attention_op_dispatch_tristate(monkeypatch, use_flash, b, seq, d,
