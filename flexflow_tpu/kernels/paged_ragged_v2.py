@@ -212,7 +212,8 @@ def choose_block_kv(page_size: int, pages_per_seq: int, num_heads: int,
 
 def max_work_items(num_lanes: int, pages_per_seq: int,
                    block_kv_pages: int, q_rows: int = Q_ROWS,
-                   slot_changes: Optional[int] = None) -> int:
+                   slot_changes: Optional[int] = None,
+                   window_blocks: int = 0) -> int:
     """The most work items any lane arrays of this geometry can make:
     the static length of the kernel's grid.
 
@@ -222,12 +223,23 @@ def max_work_items(num_lanes: int, pages_per_seq: int,
     a tile's first lane or where the slot changes from one lane to the
     next, so there are at most tiles + `slot_changes` of them; a caller
     that cannot bound the changes (None) gets one run a lane, the safe
-    lanes x blocks."""
+    lanes x blocks. Under a window a run has at most `window_blocks`
+    items (`window_block_bound`), whatever the table's width."""
     nb = -(-pages_per_seq // max(1, min(block_kv_pages, pages_per_seq)))
+    if window_blocks:
+        nb = min(nb, window_blocks)
     tiles = -(-num_lanes // q_rows)
     runs = tiles * q_rows if slot_changes is None \
         else min(tiles * q_rows, tiles + slot_changes)
     return runs * nb
+
+
+def window_block_bound(window: int, block_kv: int,
+                       q_rows: int = Q_ROWS) -> int:
+    """The most kv-blocks a run can have items for under a window: its
+    rows' lengths span at most q_rows - 1, so the keys any of them sees
+    are window + q_rows - 1 consecutive positions."""
+    return -(-(window + q_rows - 2) // block_kv) + 1
 
 
 def ragged_dispatch_passes(num_lanes: int, pages_per_seq: int,
@@ -245,7 +257,7 @@ def ragged_dispatch_passes(num_lanes: int, pages_per_seq: int,
 
 # ------------------------------------------------------------ jnp paths
 def _ragged_jnp(q, k_pages, v_pages, page_tables, lane_slots, lane_lens,
-                scale, k_scales=None, v_scales=None):
+                scale, k_scales=None, v_scales=None, window=0):
     """Vectorized fallback over the flattened ragged layout.
 
     Gathers each lane's pages (int8 gathers move 1/4 the bytes of f32),
@@ -253,8 +265,13 @@ def _ragged_jnp(q, k_pages, v_pages, page_tables, lane_slots, lane_lens,
     softmax and the divide after the matmul — so fp32 outputs are
     bit-identical to contiguous full-prefill attention per lane (the
     oracle every serve parity test is built on), and the one jnp twin
-    the Pallas kernel is held to."""
-    b, h, d = q.shape
+    the Pallas kernel is held to. Grouped heads (q has `group` times
+    the pages' heads: query head j reads key/value head j // group) and
+    a `window` (a lane sees its last `window` positions) take the same
+    path; one group and no window trace what they always did."""
+    b, hq, d = q.shape
+    h = k_pages.shape[2]
+    group = hq // h
     ps = k_pages.shape[1]
     lane_tables = jnp.take(page_tables, lane_slots, axis=0)  # (T, pp)
     pp = lane_tables.shape[1]
@@ -267,17 +284,33 @@ def _ragged_jnp(q, k_pages, v_pages, page_tables, lane_slots, lane_lens,
         v = dequantize_kv(v, vs)
     k = k.reshape(b, pp * ps, h, d)
     v = v.reshape(b, pp * ps, h, d)
-    s = jax.lax.dot_general(
-        q, k, (((2,), (3,)), ((0, 1), (0, 2))),
-        preferred_element_type=jnp.float32) * scale     # (T, H, pp*ps)
+    if group > 1:
+        # the group's query heads as rows of their key/value head
+        q = q.reshape(b, h, group, d)
+        s = jnp.einsum("thgd,tkhd->thgk", q, k,
+                       preferred_element_type=jnp.float32) * scale
+        s = s.reshape(b, hq, pp * ps)
+    else:
+        s = jax.lax.dot_general(
+            q, k, (((2,), (3,)), ((0, 1), (0, 2))),
+            preferred_element_type=jnp.float32) * scale  # (T, H, pp*ps)
     pos = jax.lax.broadcasted_iota(jnp.int32, (b, 1, pp * ps), 2)
-    s = jnp.where(pos < lane_lens[:, None, None], s, -jnp.inf)
+    seen = pos < lane_lens[:, None, None]
+    if window:
+        seen &= pos >= lane_lens[:, None, None] - window
+    s = jnp.where(seen, s, -jnp.inf)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
-    o = jax.lax.dot_general(
-        p, v.astype(jnp.float32), (((2,), (1,)), ((0, 1), (0, 2))),
-        preferred_element_type=jnp.float32)
+    if group > 1:
+        o = jnp.einsum("thgk,tkhd->thgd", p.reshape(b, h, group, -1),
+                       v.astype(jnp.float32),
+                       preferred_element_type=jnp.float32
+                       ).reshape(b, hq, d)
+    else:
+        o = jax.lax.dot_general(
+            p, v.astype(jnp.float32), (((2,), (1,)), ((0, 1), (0, 2))),
+            preferred_element_type=jnp.float32)
     return (o / l).astype(q.dtype)
 
 
@@ -334,13 +367,15 @@ class WorkList:
 
 
 def _work_arrays(xp, cummax, page_tables, lane_slots, lane_lens, *,
-                 page_size, block_pages, q_rows, max_items):
+                 page_size, block_pages, q_rows, max_items, window=0):
     """The work list's arrays, in numpy or jax.numpy (`xp`; `cummax`
     is its running maximum along axis 0) — ONE definition, so the
     counters on the host (`work_items`, `kv_read_bytes`) walk exactly
     the list the device builds. `max_items` None (numpy only) sizes
-    the list by the step's own items: n = their count. -> (tile, blk,
-    meta, pages (n, bp))."""
+    the list by the step's own items: n = their count. Under a
+    `window` a run's items start at the block that holds the oldest key
+    its SHORTEST lane sees, and the pages wholly behind that key are
+    not fetched. -> (tile, blk, meta, pages (n, bp))."""
     t = lane_slots.shape[0]
     pp = page_tables.shape[1]
     bp, qb = block_pages, q_rows
@@ -363,6 +398,13 @@ def _work_arrays(xp, cummax, page_tables, lane_slots, lane_lens, *,
     run_hi = xp.max(xp.where(same, row + 1, 0), axis=-1).reshape(-1)
     bs = bp * page_size
     nblk = xp.where(starts, -(-run_len // bs), 0).astype(i32)
+    if window:
+        run_min = xp.min(xp.where(same, lens.reshape(tiles, 1, qb),
+                                  np.iinfo(np.int32).max),
+                         axis=-1).reshape(-1)
+        oldest = xp.maximum(run_min - window, 0)    # first key seen
+        blk_lo = (oldest // bs).astype(i32)
+        nblk = xp.where(starts, nblk - blk_lo, 0).astype(i32)
     ends = xp.cumsum(nblk).astype(i32)      # items up to and with lane
     total = ends[-1]
     n = int(total) if max_items is None else max_items
@@ -374,12 +416,16 @@ def _work_arrays(xp, cummax, page_tables, lane_slots, lane_lens, *,
     lo, hi = run_lo[head], run_hi[head]
     first = live & (blk == 0) & (lo == 0)
     last = live & (blk == nblk[head] - 1) & (hi == qb)
+    if window:
+        blk = blk + blk_lo[head]
     meta = (lo | (hi << 8) | xp.where(first, _FIRST, 0)
             | xp.where(last, _LAST, 0) | xp.where(live, _LIVE, 0))
     # page slot i of item w: table column blk * bp + i while that page
     # holds a position the run can see, else what the slot held before
     col = blk[:, None] * bp + xp.arange(bp, dtype=i32)[None, :]
     fresh = live[:, None] & (col * page_size < run_len[head][:, None])
+    if window:
+        fresh &= (col + 1) * page_size > oldest[head][:, None]
     page = page_tables.astype(i32)[slots[head][:, None],
                                    xp.minimum(col, pp - 1)]
     src = cummax(xp.where(fresh, w[:, None], -1))
@@ -390,7 +436,8 @@ def _work_arrays(xp, cummax, page_tables, lane_slots, lane_lens, *,
 
 def build_work_list(page_tables, lane_slots, lane_lens, *, page_size: int,
                     block_pages: int, q_rows: int = Q_ROWS,
-                    max_items: Optional[int] = None) -> WorkList:
+                    max_items: Optional[int] = None,
+                    window: int = 0) -> WorkList:
     """The step's work list, on the device (jax.numpy; a few small
     fusions over the lane arrays). `max_items` is the caller's proof of
     the most items its lane arrays can make (`max_work_items` with the
@@ -407,7 +454,7 @@ def build_work_list(page_tables, lane_slots, lane_lens, *, page_size: int,
     tile, blk, meta, pages = _work_arrays(
         jnp, lambda x: jax.lax.cummax(x, axis=0), page_tables,
         lane_slots, lane_lens, page_size=page_size, block_pages=bp,
-        q_rows=q_rows, max_items=max_items + 1)
+        q_rows=q_rows, max_items=max_items + 1, window=window)
     tiles = -(-t // q_rows)
     lens = jnp.concatenate([lane_lens.astype(jnp.int32),
                             jnp.ones(tiles * q_rows - t, jnp.int32)])
@@ -429,7 +476,8 @@ def kv_page_bytes(page_size, num_heads, head_dim, kv_itemsize, quantized):
 def work_items(lane_lens, lane_slots, page_tables, *, page_size: int,
                block_kv_pages: int = 1, q_rows: int = Q_ROWS,
                max_items: Optional[int] = None,
-               live_lanes: Optional[int] = None) -> Dict[str, int]:
+               live_lanes: Optional[int] = None,
+               window: int = 0) -> Dict[str, int]:
     """What one call of the kernel has to do for these lanes (numpy;
     host side, no device work): `grid` the list's static length
     (`max_items`, else the bound for any arrays), `total` the items of
@@ -460,7 +508,7 @@ def work_items(lane_lens, lane_slots, page_tables, *, page_size: int,
         np, lambda x: np.maximum.accumulate(x, axis=0), pt,
         np.asarray(lane_slots), np.asarray(lane_lens),
         page_size=page_size, block_pages=bp, q_rows=q_rows,
-        max_items=None)
+        max_items=None, window=window)
     live = t if live_lanes is None else int(live_lanes)
     lo, hi = meta & 0xFF, (meta >> 8) & 0xFF    # the run's rows
     first = tile * q_rows + lo                  # its first lane
@@ -567,13 +615,18 @@ def _by_head(x, q_rows, heads, head_dim):
 
 def _ragged_v2_kernel(tile_ref, blk_ref, meta_ref, pages_ref, q2_ref,
                       lens_ref, *refs, page_size, block_pages, q_rows,
-                      heads, head_dim, slabs, scale, quantized, exact):
+                      heads, head_dim, slabs, scale, quantized, exact,
+                      group=1, window=0):
     """One work item: the rows [lo, hi) of a tile attend one kv-block
     of their sequence. Page refs arrive head-PACKED as (1, ps, H*D)
     blocks (plus (1, ps, H) scale blocks when quantized). The grid runs
     in order; m / l / acc carry a tile's online softmax from its first
     item to its last. The slabs are a loop inside the item (traced
-    once: the body is not unrolled in Python)."""
+    once: the body is not unrolled in Python). Grouped heads: the
+    `group` query heads of a key/value head are `group` times the rows
+    (row (g * group + j) * q_rows + r of a slab is row r's query head
+    j of the slab's head g), so one product serves them all; under a
+    `window` a row sees its last `window` positions."""
     del tile_ref, pages_ref                  # read by the index maps
     per_page = 4 if quantized else 2
     n_kv = per_page * block_pages
@@ -583,7 +636,9 @@ def _ragged_v2_kernel(tile_ref, blk_ref, meta_ref, pages_ref, q2_ref,
         o_ref, m_ref, l_ref, acc_ref, ks_ref, vs_ref = refs[n_kv + 1:]
     else:
         o_ref, m_ref, l_ref, acc_ref = refs[n_kv:]
-    qb, g, d = q_rows, heads, head_dim
+    g, d = heads, head_dim
+    qr = q_rows                 # rows of the tile (the lens' block)
+    qb = group * q_rows         # rows of one head of a slab
     w_lanes = g * d
     bs = block_pages * page_size
     op_dtype = jnp.float32 if exact else jnp.bfloat16
@@ -618,11 +673,13 @@ def _ragged_v2_kernel(tile_ref, blk_ref, meta_ref, pages_ref, q2_ref,
         base = blk_ref[w] * bs
         # each row's visible length; 0 for the tile's rows outside the
         # run (they belong to other items), stacked once per head
-        row = jax.lax.broadcasted_iota(jnp.int32, (qb, 128), 0)
+        row = jax.lax.broadcasted_iota(jnp.int32, (qr, 128), 0)
         vis = jnp.where((row >= lo) & (row < hi), lens_ref[...], 0)
-        vis = jnp.concatenate([vis] * g, axis=0)[:, :1]      # (G*QB, 1)
+        vis = jnp.concatenate([vis] * (g * group), axis=0)[:, :1]
         pos = base + jax.lax.broadcasted_iota(jnp.int32, (g * qb, bs), 1)
-        seen = pos < vis
+        seen = pos < vis                                     # (G*QB, bs)
+        if window:
+            seen &= pos >= vis - window
 
         def scales_on_lanes(j, out_ref):
             """Page slot j's (bs, H) scale rows as (Hp, bs): a product
@@ -701,12 +758,15 @@ def _vmem_limit(block_bytes: int) -> int:
 # jitted on its own: the engine's layers make the same call 24 times,
 # and tracing and lowering the kernel body is host time before the
 # compile cache can even be asked — a nested jit pays it once
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "window"))
 def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
-                      interpret, k_scales=None, v_scales=None):
-    t, h, d = q.shape
-    npages, ps = k_pages.shape[0], k_pages.shape[1]
+                      interpret, k_scales=None, v_scales=None, window=0):
+    t, hq, d = q.shape
+    npages, ps, h = k_pages.shape[:3]
+    group = hq // h             # query heads a key/value head
     qb, bp = work.q_rows, work.block_pages
+    qe = group * qb             # rows of one head of a slab
     n = work.tile.shape[0] - 1
     tiles = work.lens.shape[0] // qb
     quantized = k_scales is not None
@@ -723,13 +783,25 @@ def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
     # makes a copy for it (PERF.md section 5)
     kp = k_pages.reshape(npages, ps, hd)
     vp = v_pages.reshape(npages, ps, hd)
-    # q2[tile, slab, g * qb + r, g' * D + c] = q[tile * qb + r, slab * G
-    # + g, c] where g' == g, else 0
+    # q2[tile, slab, (g * group + j) * qb + r, g' * D + c] = q[tile * qb
+    # + r, (slab * G + g) * group + j, c] where g' == g, else 0. One
+    # group is written without the group's unit dimension: XLA lays the
+    # two forms out differently around the call, and the cells of the
+    # benchmark that run one group are held to the program they had
+    # (`docqa-closed8` exits when it is served faster: PERF.md section 7)
     qp = jnp.pad(q, ((0, tiles * qb - t), (0, 0), (0, 0)))
-    qp = qp.reshape(tiles, qb, slabs, g, d).transpose(0, 2, 3, 1, 4)
-    q2 = (qp[:, :, :, :, None, :].astype(op_dtype)
-          * jnp.eye(g, dtype=op_dtype)[None, None, :, None, :, None]
-          ).reshape(tiles, slabs, g * qb, w_lanes)
+    if group == 1:
+        qp = qp.reshape(tiles, qb, slabs, g, d).transpose(0, 2, 3, 1, 4)
+        q2 = (qp[:, :, :, :, None, :].astype(op_dtype)
+              * jnp.eye(g, dtype=op_dtype)[None, None, :, None, :, None]
+              ).reshape(tiles, slabs, g * qb, w_lanes)
+    else:
+        qp = qp.reshape(tiles, qb, slabs, g, group, d).transpose(
+            0, 2, 3, 4, 1, 5)
+        q2 = (qp[:, :, :, :, :, None, :].astype(op_dtype)
+              * jnp.eye(g, dtype=op_dtype)[None, None, :, None, None, :,
+                                           None]
+              ).reshape(tiles, slabs, g * qe, w_lanes)
 
     def page_index(i):
         def imap(w, tile, blk, meta, pages):
@@ -739,7 +811,7 @@ def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
     def tile_index(w, tile, blk, meta, pages):
         return (tile[w], 0)
 
-    in_specs = [pl.BlockSpec((1, slabs, g * qb, w_lanes),
+    in_specs = [pl.BlockSpec((1, slabs, g * qe, w_lanes),
                              lambda w, tile, *_: (tile[w], 0, 0, 0)),
                 pl.BlockSpec((qb, 128), tile_index)]
     args = [q2, work.lens]
@@ -762,17 +834,17 @@ def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
     kern = functools.partial(
         _ragged_v2_kernel, page_size=ps, block_pages=bp, q_rows=qb,
         heads=g, head_dim=d, slabs=slabs, scale=scale,
-        quantized=quantized, exact=exact)
+        quantized=quantized, exact=exact, group=group, window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,          # the work list
         grid=(n,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, slabs, qb, w_lanes),
+        out_specs=pl.BlockSpec((1, slabs, qe, w_lanes),
                                lambda w, tile, *_: (tile[w], 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((slabs, g * qb, w_lanes), jnp.float32),  # max
-            pltpu.VMEM((slabs, g * qb, w_lanes), jnp.float32),  # sum
-            pltpu.VMEM((slabs, qb, w_lanes), jnp.float32),  # accumulator
+            pltpu.VMEM((slabs, g * qe, w_lanes), jnp.float32),  # max
+            pltpu.VMEM((slabs, g * qe, w_lanes), jnp.float32),  # sum
+            pltpu.VMEM((slabs, qe, w_lanes), jnp.float32),  # accumulator
         ] + ([pltpu.VMEM((hp, bp * ps), jnp.float32)] * 2   # scales^T
              if quantized else []),
     )
@@ -782,13 +854,13 @@ def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
     block_bytes = (
         2 * bs * hd * jnp.dtype(k_pages.dtype).itemsize     # K + V pages
         + (2 * bs * lanes * 4 + 2 * hp * bs * 4 if quantized else 0)
-        + slabs * g * qb * w_lanes * (op_size + 8)  # q2, max, sum
-        + 2 * qb * hd * 4                           # acc, the output
+        + slabs * g * qe * w_lanes * (op_size + 8)  # q2, max, sum
+        + 2 * qe * hd * 4                           # acc, the output
         + 2 * bs * max(w_lanes, 128) * (4 + op_size)    # a slab of K, V
-        + 8 * g * qb * max(bs, 128) * 4)            # s, p and their kin
+        + 8 * g * qe * max(bs, 128) * 4)            # s, p and their kin
     out = pl.pallas_call(
         kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((tiles, slabs, qb, w_lanes),
+        out_shape=jax.ShapeDtypeStruct((tiles, slabs, qe, w_lanes),
                                        q.dtype),
         # the grid axis carries the online-softmax scratch from one
         # work item of a tile to the next: it must run in order
@@ -798,8 +870,13 @@ def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
         interpret=interpret,
         name="paged_ragged_v2",
     )(work.tile, work.blk, work.meta, work.pages, *args)
-    # (tile, slab, row, lane) -> (lane of the step, head, dim)
-    return out.transpose(0, 2, 1, 3).reshape(tiles * qb, h, d)[:t]
+    # (tile, slab, (group, row), (head, dim)) -> (lane of the step,
+    # query head, dim)
+    if group == 1:
+        return out.transpose(0, 2, 1, 3).reshape(tiles * qb, h, d)[:t]
+    out = out.reshape(tiles, slabs, group, qb, g, d).transpose(
+        0, 3, 1, 4, 2, 5)
+    return out.reshape(tiles * qb, hq, d)[:t]
 
 
 # ------------------------------------------------------------ entry point
@@ -841,8 +918,13 @@ def paged_attention_ragged_v2(q, k_pages, v_pages, page_tables,
                               lane_slots, lane_lens, *, k_scales=None,
                               v_scales=None, scale=None, block_kv=None,
                               work=None, use_pallas=None,
-                              interpret=False):
+                              interpret=False, window=0):
     """Ragged batched attention through page tables — kernel v2.
+
+    GROUPED HEADS: q may have `group` times the pages' heads; query
+    head j reads key/value head j // group. `window` > 0: a lane sees
+    only its last `window` positions (a `work` list given with it must
+    have been built with the same window).
 
     q (T, H, D) — one query token per LANE, where lanes mix
     prompt-chunk tokens from any number of sequences with single decode
@@ -882,12 +964,12 @@ def paged_attention_ragged_v2(q, k_pages, v_pages, page_tables,
     if impl == JNP:
         return _ragged_jnp(q, k_pages, v_pages, page_tables, lane_slots,
                            lane_lens, scale, k_scales=k_scales,
-                           v_scales=v_scales)
+                           v_scales=v_scales, window=window)
     ps = k_pages.shape[1]
     if work is None:
         if block_kv is None:
             block_kv = choose_block_kv(
-                ps, page_tables.shape[1], q.shape[1], q.shape[2],
+                ps, page_tables.shape[1], k_pages.shape[2], q.shape[2],
                 jnp.dtype(k_pages.dtype).itemsize)
         bp = max(1, min(int(block_kv) // ps, page_tables.shape[1]))
         # the list lives in SMEM: with no bound from the caller, as
@@ -902,10 +984,11 @@ def paged_attention_ragged_v2(q, k_pages, v_pages, page_tables,
                     lane_slots[a:a + step], lane_lens[a:a + step],
                     k_scales=k_scales, v_scales=v_scales, scale=scale,
                     block_kv=block_kv, use_pallas=use_pallas,
-                    interpret=interpret)
+                    interpret=interpret, window=window)
                 for a in range(0, q.shape[0], step)], axis=0)
         work = build_work_list(page_tables, lane_slots, lane_lens,
-                               page_size=ps, block_pages=bp)
+                               page_size=ps, block_pages=bp,
+                               window=window)
     return _ragged_v2_pallas(
         q, k_pages, v_pages, work, scale, impl == PALLAS_INTERPRET,
-        k_scales=k_scales, v_scales=v_scales)
+        k_scales=k_scales, v_scales=v_scales, window=int(window))

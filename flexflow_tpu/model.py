@@ -133,11 +133,15 @@ class FFModel:
 
     def embedding(self, input: Tensor, num_entries: int, out_dim: int,
                   aggr: str = "sum", name: Optional[str] = None,
-                  kernel_initializer="glorot", dtype=None) -> Tensor:
+                  kernel_initializer="glorot", dtype=None,
+                  emit_table: bool = False):
+        """`emit_table`: -> (embedded, the table itself), for a head
+        tied to it (`tied_head`)."""
         op = Embedding(self, name or self._fresh_name("embedding"), [input],
                        num_entries, out_dim, aggr, kernel_initializer,
-                       dtype=dtype)
-        return self.add_op(op).output
+                       dtype=dtype, emit_table=emit_table)
+        self.add_op(op)
+        return tuple(op.outputs) if emit_table else op.output
 
     def distributed_embedding(self, inputs: Sequence[Tensor],
                               num_entries: int, out_dim: int,
@@ -375,6 +379,60 @@ class FFModel:
                     dropless=dropless)
         return self.add_op(op).output
 
+
+    # ---- the decoder-hybrid-decoder block (models/phi4flash.py) ----
+    def selective_scan_mixer(self, input: Tensor, d_inner: int,
+                             d_state: int = 16, d_conv: int = 4,
+                             dt_rank: int = 0, emit_memory: bool = False,
+                             name: Optional[str] = None):
+        """The Mamba-1 mixer (ops/ssm.py). `emit_memory`: -> (out, the
+        scan's output before its gate), what a `gated_memory_unit`
+        reads."""
+        from .ops.ssm import SelectiveScanMixer
+        op = SelectiveScanMixer(
+            self, name or self._fresh_name("ssm"), [input], d_inner,
+            d_state, d_conv, dt_rank, emit_memory)
+        self.add_op(op)
+        return tuple(op.outputs) if emit_memory else op.output
+
+    def gated_memory_unit(self, input: Tensor, memory: Tensor,
+                          name: Optional[str] = None) -> Tensor:
+        from .ops.gated import GatedMemoryUnit
+        op = GatedMemoryUnit(self, name or self._fresh_name("gmu"),
+                             [input, memory])
+        return self.add_op(op).output
+
+    def gated_ffn(self, input: Tensor, hidden_dim: int,
+                  name: Optional[str] = None) -> Tensor:
+        from .ops.gated import GatedFFN
+        op = GatedFFN(self, name or self._fresh_name("gated_ffn"), [input],
+                      hidden_dim)
+        return self.add_op(op).output
+
+    def tied_head(self, input: Tensor, table: Tensor,
+                  name: Optional[str] = None) -> Tensor:
+        from .ops.gated import TiedHead
+        op = TiedHead(self, name or self._fresh_name("tied_head"),
+                      [input, table])
+        return self.add_op(op).output
+
+    def differential_attention(self, input: Tensor, num_heads: int,
+                               num_kv_heads: int, head_dim: int,
+                               layer_index: int, window: int = 0,
+                               kv: Optional[Sequence[Tensor]] = None,
+                               kv_from: str = "", emit_kv: bool = False,
+                               eps: float = 1e-5,
+                               name: Optional[str] = None):
+        """Differential attention with grouped key/value heads
+        (ops/diff_attention.py). `kv` = (k, v) of the layer `kv_from`
+        names: this layer then has no wk, wv. `emit_kv`: -> (y, k, v)."""
+        from .ops.diff_attention import DifferentialAttention
+        op = DifferentialAttention(
+            self, name or self._fresh_name("diff_attention"),
+            [input] + list(kv or ()), num_heads, num_kv_heads, head_dim,
+            layer_index, window, kv_from, emit_kv, eps)
+        self.add_op(op)
+        return tuple(op.outputs) if emit_kv else op.output
 
     def pipeline_blocks(self, input: Tensor, block_builder, num_layers: int,
                         num_microbatches: int = 4,

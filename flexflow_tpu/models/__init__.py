@@ -10,6 +10,7 @@ from .moe import build_moe_fused, build_moe_reference
 from .candle_uno import build_candle_uno
 from .nmt_lstm import build_nmt_lstm, build_nmt_seq2seq
 from .olmoe import build_olmoe_lm
+from .phi4flash import build_phi4flash_lm
 
 __all__ = [
     "build_alexnet",
@@ -21,6 +22,7 @@ __all__ = [
     "build_moe_reference",
     "build_moe_fused",
     "build_olmoe_lm",
+    "build_phi4flash_lm",
     "build_candle_uno",
     "build_nmt_lstm",
     "build_nmt_seq2seq",

@@ -21,6 +21,9 @@ from .embedding import DistributedEmbedding, Embedding
 from .attention import MultiHeadAttention
 from .moe import GroupBy, Aggregate
 from .moe_ffn import MoEFFN
+from .ssm import SelectiveScanMixer
+from .gated import GatedFFN, GatedMemoryUnit, TiedHead
+from .diff_attention import DifferentialAttention
 from .pipeline import PipelineBlocks
 from .rnn import LSTM
 
@@ -50,6 +53,11 @@ __all__ = [
     "GroupBy",
     "Aggregate",
     "MoEFFN",
+    "SelectiveScanMixer",
+    "GatedFFN",
+    "GatedMemoryUnit",
+    "TiedHead",
+    "DifferentialAttention",
     "PipelineBlocks",
     "LSTM",
 ]

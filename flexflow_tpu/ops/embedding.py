@@ -64,8 +64,11 @@ class Embedding(Op):
 
     def __init__(self, model, name, inputs, num_entries: int, out_dim: int,
                  aggr: str = AGGR_MODE_SUM, kernel_initializer: str = "glorot",
-                 dtype=None):
+                 dtype=None, emit_table: bool = False):
         super().__init__(model, name, inputs)
+        # a second output, the (num_entries, out_dim) table itself: what
+        # a head TIED to this embedding reads (ops/gated.TiedHead)
+        self.emit_table = bool(emit_table)
         self.num_entries = int(num_entries)
         self.out_dim = int(out_dim)
         self.aggr = aggr
@@ -79,13 +82,14 @@ class Embedding(Op):
 
     def output_shapes(self):
         in_shape = self.inputs[0].shape
+        table = [(self.num_entries, self.out_dim)] * self.emit_table
         if self.aggr == AGGR_MODE_NONE:
-            return [tuple(in_shape) + (self.out_dim,)]
+            return [tuple(in_shape) + (self.out_dim,)] + table
         # (batch, bag) -> (batch, out_dim): aggregate over the bag dim.
-        return [(in_shape[0], self.out_dim)]
+        return [(in_shape[0], self.out_dim)] + table
 
     def output_dtypes(self):
-        return [self.out_dtype]
+        return [self.out_dtype] * (1 + self.emit_table)
 
     def weight_specs(self):
         return {
@@ -117,6 +121,9 @@ class Embedding(Op):
             emb = jnp.sum(emb, axis=-2)
         elif self.aggr == AGGR_MODE_AVG:
             emb = jnp.mean(emb, axis=-2)
+        if self.emit_table:
+            return [emb.astype(self.out_dtype),
+                    params["kernel"].astype(self.out_dtype)]
         return [emb.astype(self.out_dtype)]
 
     def output_axes(self):
@@ -124,7 +131,7 @@ class Embedding(Op):
         axes = [None] * n
         axes[0] = SAMPLE
         axes[-1] = CHANNEL_OUT
-        return [tuple(axes)]
+        return [tuple(axes)] + [(None, None)] * self.emit_table
 
     def input_axes(self):
         axes = [None] * len(self.inputs[0].shape)

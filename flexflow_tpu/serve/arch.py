@@ -8,20 +8,38 @@ it cannot do, which raises at engine build (no silent fallback). A
 description reads the parameters of a compiled FFModel through the op
 names its builder wrote, and mirrors those ops' numerics.
 
-Two clients: `TransformerLM` (models/transformer.build_transformer_lm:
-learned positions, LayerNorm, ReLU feed-forward — the OPT block) and
+Three clients: `TransformerLM` (models/transformer.build_transformer_lm:
+learned positions, LayerNorm, ReLU feed-forward — the OPT block),
 `OLMoE` (models/olmoe.build_olmoe_lm: RMSNorm, rotary attention with
-QK-norm, dropless top-k SwiGLU experts).
+QK-norm, dropless top-k SwiGLU experts) and `Phi4Flash`
+(models/phi4flash.build_phi4flash_lm: state-space, window, full, gated
+memory and cross layers; no positions).
+
+What a description answers (docs/serving.md "What a description must
+answer"): the dimensions; per layer the MIXER KIND (`mixer(i)`:
+"attn", or one of models/phi4flash's five); the geometry of the K/V it
+pages (`kv_heads`, `kv_head_dim`, `paged_layers`, `attn_scale`); what a
+sequence holds besides pages (`hybrid_spec`, a serve/kv_cache.HybridSpec
+or None); and `refuse`, which raises BY NAME for every engine path the
+model is not served on.
 """
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
+from ..models.phi4flash import CROSS, FULL, GMU, SSM, WINDOW
+from ..ops import diff_attention as DA
+from ..ops import ssm as S
 from ..ops.common import rms_norm, rotary
+from ..ops.gated import gated_ffn, gated_memory
 from ..ops.moe import (dropless_combine, dropless_dispatch, grouped_ffn,
                        route_top_k)
+
+ATTN = "attn"       # the mixer kind of every layer of the plain decoders
 
 
 def _ln(p, x, eps):
@@ -70,14 +88,75 @@ def _count_layers(ops) -> int:
     return n
 
 
-class TransformerLM:
+class Description:
+    """What every description answers the same way unless it says
+    otherwise: one attention layer a layer, a key/value head a query
+    head, every layer paged, nothing held besides pages, no expert
+    layer, every engine path served."""
+
+    kind = "?"
+    builder = "?"               # the builder whose op names it reads
+    reads = ()                  # the op names `describe` knows it by
+    experts = 0                 # no expert layer: the step counts none
+    experts_per_token = 0
+    window = 0
+    # (params, (1, S) tokens) -> (S, V): a full-sequence forward to use
+    # as the engine's naive oracle in place of its own attention-only
+    # one (None: the engine's)
+    forward_logits = None
+    # engine path -> why this model is not served on it (`refuse`)
+    refused = {}
+
+    def mixer(self, i: int) -> str:
+        return ATTN
+
+    def hybrid_spec(self, chunk: int):
+        """What a sequence holds besides pages, as a
+        kv_cache.HybridSpec (`chunk`: the most tokens of one sequence a
+        step writes); None: pages alone."""
+        return None
+
+    @property
+    def kv_heads(self) -> int:
+        return self.num_heads
+
+    @property
+    def kv_head_dim(self) -> int:
+        return self.head_dim
+
+    @property
+    def paged_layers(self) -> int:
+        return self.num_layers
+
+    @property
+    def attn_scale(self) -> float:
+        return 1.0 / math.sqrt(self.head_dim)
+
+    def refuse(self, *, tp: int = 1, adapters: bool = False,
+               speculation: bool = False, prefix_cache: bool = False,
+               host_tier: bool = False, handoff: bool = False) -> None:
+        """Raise, by name, for an engine path this model is not served
+        on. One signature for every description: the engine passes
+        every path it is about to arm."""
+        asked = {"tp": tp > 1, "adapters": adapters,
+                 "speculation": speculation, "prefix_cache": prefix_cache,
+                 "host_tier": host_tier, "handoff": handoff}
+        for path, on in asked.items():
+            if on and path in self.refused:
+                raise NotImplementedError(
+                    f"{self.kind} serving refuses {path}"
+                    + (f" (tp={tp})" if path == "tp" else "")
+                    + f": {self.refused[path]}")
+
+
+class TransformerLM(Description):
     """The build_transformer_lm block: token + learned-position
     embeddings, pre-LN causal attention, ReLU feed-forward, final LN,
     untied head. Serves every engine path."""
 
     kind = "transformer_lm"
-    experts = 0                 # no expert layer: the step counts none
-    experts_per_token = 0
+    builder = "build_transformer_lm"
+    reads = ("tok_embed", "lm_head", "pos_embed")
 
     def __init__(self, model, ops):
         self.vocab_size = ops["tok_embed"].num_entries
@@ -100,9 +179,6 @@ class TransformerLM:
         self.act_dtype = jnp.dtype(ops["tok_embed"].out_dtype)
         self.ff_dim = int(
             model.state.params["layer0_ff1"]["kernel"].shape[1])
-
-    def refuse(self, *, tp: int, adapters: bool) -> None:
-        """Raise for an engine path this model is not served on."""
 
     def embed(self, params, tokens, positions):
         # mode="clip": padded lanes/positions past the learned tables
@@ -206,12 +282,21 @@ class TransformerLM:
         return _dense(params["lm_head"], self.final_norm(params, x))
 
 
-class OLMoE:
+class OLMoE(Description):
     """The build_olmoe_lm block (models/olmoe.py holds the equations).
     Served by the mixed step on one device; what it does not get yet
     raises in `refuse`."""
 
     kind = "olmoe"
+    builder = "build_olmoe_lm"
+    reads = ("tok_embed", "lm_head", "layer0_moe", "final_norm")
+    refused = {
+        "tp": "single-device: tensor-parallel serving would have to "
+              "split the experts and the QK-norm's statistics across "
+              "devices, which is not built",
+        "adapters": "no adapter pool: adapter_rank > 0 adapts the dense "
+                    "feed-forward, which this model lacks",
+    }
 
     def __init__(self, model, ops):
         self.vocab_size = ops["tok_embed"].num_entries
@@ -241,17 +326,6 @@ class OLMoE:
         # the bytes the expert phase reads for every expert it touches
         self.expert_bytes = int(3 * self.hidden * self.ff_dim
                                 * w.dtype.itemsize)
-
-    def refuse(self, *, tp: int, adapters: bool) -> None:
-        if tp > 1:
-            raise NotImplementedError(
-                "OLMoE serving is single-device: tensor-parallel serving "
-                f"(tp={tp}) would have to split the experts and the "
-                "QK-norm's statistics across devices, which is not built")
-        if adapters:
-            raise NotImplementedError(
-                "OLMoE serving has no adapter pool: adapter_rank > 0 "
-                "adapts the dense feed-forward, which this model lacks")
 
     def embed(self, params, tokens, positions):
         return jnp.take(params["tok_embed"]["kernel"], tokens, axis=0,
@@ -308,17 +382,195 @@ class OLMoE:
         return _dense(params["lm_head"], self.final_norm(params, x))
 
 
+class Phi4Flash(Description):
+    """The build_phi4flash_lm block (models/phi4flash.py holds the
+    layer pattern, ops/ssm.py, ops/diff_attention.py and ops/gated.py
+    the equations). Served by the mixed step on one device.
+
+    What it pages: ONE layer's K and V (the full layer's; every cross
+    layer reads them), as `kv_heads` = Hk / 2 heads of 2 D (the grouped
+    identity of ops/diff_attention.py). What a sequence holds besides
+    (`hybrid`): a ring of the window layers' last keys, and a scan
+    state and a convolution tail for each state-space layer."""
+
+    kind = "phi4flash"
+    builder = "build_phi4flash_lm"
+    reads = ("tok_embed", "lm_head", "layer0_ssm", "final_ln")
+    _state = ("a sequence's scan state and window keys live in its "
+              "slot, not in pages: ")
+    refused = {
+        "tp": "single-device: the scan state and the paired heads are "
+              "not split over a mesh",
+        "adapters": "no adapter pool for the gated units",
+        "speculation": _state + "rolling back rejected tokens would "
+                       "need a snapshot of the state (serve_spec_decode "
+                       "must be off)",
+        "prefix_cache": _state + "a prefix hit would need the state at "
+                        "the prefix's end (serve_prefix_cache must be "
+                        "off)",
+        "host_tier": _state + "the host tier spills pages only",
+        "handoff": _state + "the disaggregated handoff ships pages only",
+    }
+
+    def __init__(self, model, ops):
+        self.model = model
+        self.vocab_size = ops["tok_embed"].num_entries
+        self.layer_norm = True
+        n = 0
+        while f"layer{n}_ln1" in ops:
+            n += 1
+        self.num_layers = n
+        self.kinds = []
+        for i in range(n):
+            a = ops.get(f"layer{i}_attn")
+            if f"layer{i}_ssm" in ops:
+                self.kinds.append(SSM)
+            elif f"layer{i}_gmu" in ops:
+                self.kinds.append(GMU)
+            elif a is None:
+                raise ValueError(f"layer {i} has no mixer this "
+                                 f"description reads")
+            else:
+                self.kinds.append(CROSS if a.kv_from else FULL
+                                  if a.emit_kv else WINDOW)
+        if self.kinds.count(FULL) != 1:
+            raise ValueError("ServeEngine reads a build_phi4flash_lm-"
+                             "shaped model: ONE full attention layer")
+        self.full = self.kinds.index(FULL)
+        self.ssm_layers = [i for i, k in enumerate(self.kinds) if k == SSM]
+        self.window_layers = [i for i, k in enumerate(self.kinds)
+                              if k == WINDOW]
+        self.memory_layer = self.ssm_layers[-1]
+        attn = ops[f"layer{self.full}_attn"]
+        self.num_heads = attn.num_heads
+        self.head_dim = attn.head_dim
+        self._kv_heads = attn.num_kv_heads // 2
+        self.window = ops[f"layer{self.window_layers[0]}_attn"].window
+        self.hidden = attn.embed_dim
+        self.ln_eps = ops["layer0_ln1"].eps
+        self.sub_eps = attn.eps
+        self.lam0 = {i: ops[f"layer{i}_attn"].lam0 for i in range(n)
+                     if f"layer{i}_attn" in ops}
+        self.act_dtype = jnp.dtype(ops["tok_embed"].out_dtype)
+        self.ff_dim = ops["layer0_ffn"].hidden_dim
+        ssm = ops["layer0_ssm"]
+        self.d_inner, self.d_state = ssm.d_inner, ssm.d_state
+        self.d_conv, self.dt_rank = ssm.d_conv, ssm.dt_rank
+        # no positional table: the positions served are the graph's own
+        self.max_positions = int(ops["tok_embed"].inputs[0].shape[1])
+
+    def mixer(self, i: int) -> str:
+        return self.kinds[i]
+
+    def hybrid_spec(self, chunk: int):
+        from .kv_cache import HybridSpec
+        return HybridSpec(
+            window_layers=len(self.window_layers), window=self.window,
+            chunk=int(chunk), state_layers=len(self.ssm_layers),
+            state_shape=(self.d_state, self.d_inner),
+            tail_shape=(self.d_conv - 1, self.d_inner),
+            tail_dtype=str(self.act_dtype))
+
+    @property
+    def kv_heads(self) -> int:
+        return self._kv_heads
+
+    @property
+    def kv_head_dim(self) -> int:
+        return 2 * self.head_dim
+
+    @property
+    def paged_layers(self) -> int:
+        return 1
+
+    def embed(self, params, tokens, positions):
+        return jnp.take(params["tok_embed"]["kernel"], tokens, axis=0,
+                        mode="clip").astype(self.act_dtype)
+
+    def norm1(self, params, i, x):
+        return _ln(params[f"layer{i}_ln1"], x, self.ln_eps)
+
+    def qkv(self, params, i, h, positions, lora=None):
+        """h (T, E) -> the grouped identity's q (T, H, 2D), k, v (T,
+        Hk/2, 2D); a cross layer has q alone (k, v None). Positions are
+        not read: the model has no positional encoding."""
+        p = params[f"layer{i}_attn"]
+        q = DA.project(p, h, "q")
+        if self.kinds[i] == CROSS:
+            return DA.grouped_qkv(q, None, None)
+        return DA.grouped_qkv(q, DA.project(p, h, "k"),
+                              DA.project(p, h, "v"))
+
+    def diff_norm(self, params, i, o):
+        """The grouped call's output (T, H, 2D) -> RMSNorm(A1 - lam A2)
+        (1 - lam0), (T, H/2, 2D)."""
+        a1, a2 = DA.split_grouped(o)
+        return DA.diff_combine(params[f"layer{i}_attn"], a1, a2,
+                               self.lam0[i], self.sub_eps)
+
+    def attn_out(self, params, i, o, x, psum_axis=None, lora=None):
+        p = params[f"layer{i}_attn"]
+        y = jnp.einsum("...hd,hde->...e", o, p["wo"].astype(o.dtype))
+        return x + y + p["bo"].astype(y.dtype)
+
+    # the state-space layer, in the pieces the step scopes apart
+    def ssm_in(self, params, i, h):
+        """-> (u, z) (T, d_inner), the raw input projection."""
+        p = params[f"layer{i}_ssm"]
+        uz = jnp.dot(h, p["w_in"].astype(h.dtype),
+                     preferred_element_type=jnp.float32).astype(h.dtype)
+        return jnp.split(uz, 2, axis=-1)
+
+    def ssm_scan_inputs(self, params, i, u):
+        return S.scan_inputs(params[f"layer{i}_ssm"], u, self.d_state,
+                             self.dt_rank)
+
+    def ssm_out(self, params, i, g, x):
+        p = params[f"layer{i}_ssm"]
+        return x + jnp.dot(g, p["w_out"].astype(g.dtype),
+                           preferred_element_type=jnp.float32
+                           ).astype(x.dtype)
+
+    def gmu(self, params, i, h, memory, x):
+        return x + gated_memory(params[f"layer{i}_gmu"], h, memory)
+
+    def ffn(self, params, i, x, live=None, psum_axis=None, lora=None):
+        with jax.named_scope("ffn"):
+            h = _ln(params[f"layer{i}_ln2"], x, self.ln_eps)
+            return x + gated_ffn(params[f"layer{i}_ffn"], h), None
+
+    def final_norm(self, params, x):
+        return _ln(params["final_ln"], x, self.ln_eps)
+
+    def head(self, params, x):
+        """Tied: the token table is the head."""
+        h = self.final_norm(params, x)
+        table = params["tok_embed"]["kernel"].astype(h.dtype)
+        return jnp.dot(h, table.T,
+                       preferred_element_type=jnp.float32).astype(h.dtype)
+
+    def forward_logits(self, params, tokens):
+        """(1, S) tokens -> (S, V): the op graph's own full-sequence
+        forward (no cache, no kernel), the engine's naive oracle."""
+        values, _ = self.model.executor.forward_values(
+            params, {}, {"tokens": tokens}, training=False, rng=None)
+        return values[self.model.ops[-1].outputs[0].uid][0]
+
+
+SHAPES = (TransformerLM, OLMoE, Phi4Flash)
+
+
 def describe(model):
     """The description of a compiled FFModel, chosen by the op names
-    its builder wrote."""
+    its builder wrote: the first of SHAPES whose names are all there."""
     ops = {op.name: op for op in model.ops}
-    if "tok_embed" in ops and "lm_head" in ops:
-        if "pos_embed" in ops:
-            return TransformerLM(model, ops)
-        if "layer0_moe" in ops and "final_norm" in ops:
-            return OLMoE(model, ops)
-    missing = [n for n in ("tok_embed", "lm_head", "pos_embed")
-               if n not in ops]
+    for cls in SHAPES:
+        if all(n in ops for n in cls.reads):
+            return cls(model, ops)
+    missing = "; ".join(
+        f"{cls.builder}: {[n for n in cls.reads if n not in ops]}"
+        for cls in SHAPES)
     raise ValueError(
-        f"ServeEngine reads build_transformer_lm- and build_olmoe_lm-"
-        f"shaped models; this one is neither (missing ops: {missing})")
+        f"ServeEngine reads models shaped by "
+        f"{', '.join(cls.builder for cls in SHAPES)}; this one is "
+        f"neither (missing ops, by builder: {missing})")

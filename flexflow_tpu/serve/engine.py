@@ -49,6 +49,7 @@ preemption/resume.
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -63,14 +64,16 @@ from ..kernels.paged_ragged_v2 import (JNP, PALLAS_INTERPRET, Q_ROWS,
                                        kv_page_bytes, max_work_items,
                                        paged_attention_ragged_v2,
                                        ragged_dispatch_passes,
-                                       resolve_paged_impl, work_items)
+                                       resolve_paged_impl,
+                                       window_block_bound, work_items)
 from ..parallel.mesh import TENSOR, replica_devices, serve_tensor_mesh
 from ..utils.faults import FaultInjector, TransientError, injector_for
 from ..utils.telemetry import (Telemetry, pow2_bucket, serve_metrics,
                                telemetry_for)
-from .arch import _dense, describe
-from .kv_cache import (KVCacheConfig, KVPool, PagedKVCache,
-                       kv_storage_dtype)
+from ..ops import ssm
+from .arch import ATTN, CROSS, FULL, GMU, SSM, WINDOW, _dense, describe
+from .kv_cache import (HybridPool, KVCacheConfig, KVPool, PagedKVCache,
+                       kv_storage_dtype, ring_tables)
 from .scheduler import (ChunkPlan, ContinuousBatchingScheduler, Request,
                         RequestOutcome, RequestState, SampleParams)
 
@@ -225,7 +228,7 @@ class ServeEngine:
             max_seq_len = self.max_positions
         if max_seq_len > self.max_positions:
             raise ValueError(
-                f"max_seq_len {max_seq_len} exceeds the LM's learned "
+                f"max_seq_len {max_seq_len} exceeds the LM's served "
                 f"positions ({self.max_positions})")
         self._max_seq_len = int(max_seq_len)
         # tensor-parallel sharded serving (docs/serving.md "Sharded
@@ -235,20 +238,34 @@ class ServeEngine:
         # asking the placement search (search/serve_place.optimize_serve)
         # which degree minimizes the simulated decode step.
         self._resolve_serve_mesh(mesh, tensor_parallel, replica)
-        self.cache_cfg = KVCacheConfig.from_ff(
-            self.config, num_layers=self.num_layers,
-            num_heads=self.num_heads, head_dim=self.head_dim,
-            max_seq_len=max_seq_len, tensor_parallel=self.tp)
-        self.cache_cfg.validate()
         cfg = self.config
+        self.prefill_budget = int(getattr(cfg, "serve_prefill_budget", 512))
         self.prefix_cache = bool(
             getattr(cfg, "serve_prefix_cache", True)
             if prefix_cache is None else prefix_cache)
+        if spec_tokens is None:
+            spec_tokens = int(getattr(cfg, "serve_spec_tokens", 4)) \
+                if getattr(cfg, "serve_spec_decode", True) else 0
         # what this model is not served on raises HERE, by name
         self.arch.refuse(
             tp=self.tp,
-            adapters=int(getattr(cfg, "adapter_rank", 0) or 0) > 0)
-        self.prefill_budget = int(getattr(cfg, "serve_prefill_budget", 512))
+            adapters=int(getattr(cfg, "adapter_rank", 0) or 0) > 0,
+            speculation=int(spec_tokens) > 0,
+            prefix_cache=self.prefix_cache,
+            host_tier=self.prefix_cache and bool(
+                getattr(cfg, "serve_host_tier", True)) and (
+                host_tier is not None
+                or float(getattr(cfg, "host_tier_mb", 0.0) or 0.0) > 0))
+        # the pages are those of the layers the model PAGES, at its
+        # key/value heads (arch.py: kv_heads, kv_head_dim,
+        # paged_layers); what a slot holds besides them is the
+        # description's hybrid_spec
+        self.cache_cfg = KVCacheConfig.from_ff(
+            cfg, num_layers=self.arch.paged_layers,
+            num_heads=self.kv_heads, head_dim=self.kv_head_dim,
+            max_seq_len=max_seq_len, tensor_parallel=self.tp,
+            hybrid=self.arch.hybrid_spec(self.prefill_budget))
+        self.cache_cfg.validate()
         self.admit_watermark = float(
             getattr(cfg, "serve_admit_watermark", 0.02))
         # robustness (docs/robustness.md): deterministic fault injection
@@ -314,9 +331,6 @@ class ServeEngine:
         # 0 disables and the engine is bit-for-bit the
         # non-speculative one. `spec_tokens`/`drafter`
         # override the config for A/B benches and draft-LM plugins.
-        if spec_tokens is None:
-            spec_tokens = int(getattr(cfg, "serve_spec_tokens", 4)) \
-                if getattr(cfg, "serve_spec_decode", True) else 0
         self.spec_tokens = int(spec_tokens)
         self.drafter = drafter
         # KV-page storage format (serve/kv_cache.py, PR 8): lossless
@@ -346,7 +360,7 @@ class ServeEngine:
             or choose_block_kv(self.cache_cfg.page_size,
                                self.cache_cfg.pages_per_seq,
                                self.cache_cfg.heads_per_device,
-                               self.head_dim,
+                               self.kv_head_dim,
                                self.cache_cfg.kv_itemsize)
         # the one mixed-step geometry: every prefill-budget token plus
         # one decode lane per slot always fits
@@ -368,6 +382,16 @@ class ServeEngine:
             self.mixed_width, self.cache_cfg.pages_per_seq,
             self.attn_block_pages, Q_ROWS,
             slot_changes=self.cache_cfg.max_seqs)
+        # a window layer's list is built apart (its items start at the
+        # window's first block); its grid is bounded by the window
+        self.window_max_items = max_work_items(
+            self.mixed_width, self.cache_cfg.pages_per_seq,
+            self.attn_block_pages, Q_ROWS,
+            slot_changes=self.cache_cfg.max_seqs,
+            window_blocks=window_block_bound(
+                self.arch.window,
+                self.attn_block_pages * self.cache_cfg.page_size)
+        ) if self.arch.window else 0
         self.topk_cap = min(self.TOPK_CAP, self.vocab_size)
         # persistent across generate() calls: the prefix cache only
         # pays off if committed pages outlive the batch that wrote them
@@ -587,6 +611,10 @@ class ServeEngine:
             "hidden": self.hidden,
             "num_heads": self.num_heads,
             "head_dim": self.head_dim,
+            "kv_heads": (self.kv_heads, self.kv_head_dim,
+                         self.arch.paged_layers),
+            "hybrid": None if self.cache_cfg.hybrid is None
+            else dataclasses.astuple(self.cache_cfg.hybrid),
             "ff_pad": self._ff_pad,
             "vocab": self.vocab_size,
             "max_positions": self.max_positions,
@@ -625,6 +653,8 @@ class ServeEngine:
         self.num_layers = a.num_layers
         self.num_heads = a.num_heads
         self.head_dim = a.head_dim
+        self.kv_heads = a.kv_heads          # of the pages
+        self.kv_head_dim = a.kv_head_dim
         self.hidden = a.hidden
         self.ln_eps = a.ln_eps
         self.act_dtype = a.act_dtype
@@ -702,6 +732,12 @@ class ServeEngine:
                 f"experts, {self.arch.experts_per_token} a token) has no "
                 f"ServeArch yet, so serve_mesh='auto' cannot place it; "
                 f"serve it on one device")
+        if self.arch.hybrid_spec(1) is not None:     # whatever the chunk
+            raise NotImplementedError(
+                f"serve placement prices attention layers over pages: "
+                f"the {self.arch.kind} state-space, window and cross "
+                f"layers have no ServeArch yet, so serve_mesh='auto' "
+                f"cannot place them; serve it on one device")
         cfg = self.config
         kv_name = str(getattr(cfg, "kv_dtype", "float32"))
         from .kv_cache import QUANTIZED_KV_DTYPES
@@ -912,6 +948,11 @@ class ServeEngine:
         s = tokens.shape[1]
         positions = jnp.arange(s, dtype=jnp.int32)[None, :]
         arch = self.arch
+        if arch.forward_logits is not None:
+            # a model of other mixers than attention: its op graph's
+            # own full-sequence forward is the oracle
+            return jnp.take(arch.forward_logits(params, tokens),
+                            length - 1, axis=0)
         x = arch.embed(params, tokens, positions)         # (1, S, E)
         scale = 1.0 / np.sqrt(self.head_dim)
         causal = jnp.tril(jnp.ones((s, s), dtype=bool))
@@ -1022,7 +1063,7 @@ class ServeEngine:
             x = (self._embed_tp(params, tokens, positions, tp_axis)
                  if tp_axis else
                  self.arch.embed(params, tokens, positions))  # (T, E)
-        scale = 1.0 / np.sqrt(self.head_dim)
+        scale = float(self.arch.attn_scale)
         # multi-tenant adapters (serve/adapters.py): ONE gather pulls
         # each lane's whole (A, B) stack — slab (S, L, ...) rows by
         # the lane's slot index — so the per-layer loop just slices.
@@ -1049,6 +1090,11 @@ class ServeEngine:
         # lanes aim their K/V at the sink page 0, _pack), and the step
         # returns each layer's live slots per expert beside the tokens
         live = write_pages != 0 if self.arch.experts else None
+        # what the other mixer kinds share across layers (None for a
+        # model of attention layers alone)
+        hyb = self._hybrid_lanes(positions, write_pages, lane_slots,
+                                 lane_lens) \
+            if self.cache_cfg.hybrid is not None else None
         expert_counts = []
         for i in range(self.num_layers):
             with scope(f"layer{i}"):
@@ -1057,7 +1103,7 @@ class ServeEngine:
                     write_offs, page_tables, lane_slots, lane_lens, work,
                     scale, None if ad is None else
                     {key: arr[:, i] for key, arr in ad.items()},
-                    ad_s, tp_axis)
+                    ad_s, tp_axis, hyb)
                 expert_counts.append(counts)
         with scope("head"):
             logits = (self._head_tp(params, x, tp_axis) if tp_axis
@@ -1070,44 +1116,160 @@ class ServeEngine:
             out += (jnp.stack(expert_counts),)           # (layers, E)
         return out, pool
 
+    def _hybrid_lanes(self, positions, write_pages, lane_slots,
+                      lane_lens) -> dict:
+        """What the step's state-space and window layers share, from
+        the lane arrays, once for all the layers: the RUNS (consecutive
+        lanes of one sequence at consecutive positions: a chunk, a
+        decode lane), each lane's offset in its run, the slot a lane's
+        state is written back to (its own where it is its run's last
+        live lane, else the slabs' sink row), and the rings' page
+        table, write addresses and work list."""
+        c = self.cache_cfg
+        with jax.named_scope("work_list"):
+            live = write_pages != 0
+            starts = ssm.run_starts(lane_slots, positions)
+            ends = jnp.concatenate([starts[1:] | ~live[1:],
+                                    jnp.ones((1,), bool)])
+            rings = ring_tables(c, jnp)
+            page = positions // c.page_size
+            hyb = {
+                "starts": starts, "offsets": ssm.run_offsets(starts),
+                "wslots": jnp.where(live & ends, lane_slots, c.max_seqs),
+                "rings": rings, "memory": None, "work": None,
+                "ring_pages": jnp.where(live, rings[lane_slots, page], 0)}
+            if self.attn_impl != JNP:
+                hyb["work"] = build_work_list(
+                    rings, lane_slots, lane_lens, page_size=c.page_size,
+                    block_pages=self.attn_block_pages,
+                    max_items=self.window_max_items,
+                    window=self.arch.window)
+        return hyb
+
     def _mixed_layer(self, params, i, x, positions, live, pool,
                      write_pages, write_offs, page_tables, lane_slots,
-                     lane_lens, work, scale, la, ad_s, tp_axis):
-        """Layer `i` of the mixed step, one named scope per phase:
+                     lane_lens, work, scale, la, ad_s, tp_axis, hyb=None):
+        """Layer `i` of the mixed step, dispatched on the description's
+        mixer kind, one named scope per phase. An attention layer:
         `ln`, `qkv` (the description's projections at the lanes'
-        positions), `kv_write` (KVPool.write: quantize and scatter),
-        `attn` (the ragged paged kernel over the step's `work` list),
-        `attn_out`, then the description's feed-forward under its own
-        scopes (`ffn`, or `router`, `moe_dispatch`, `experts`,
-        `moe_combine`). `la` is the lanes' adapter rows of this layer
-        (None: no adapters). Returns the layer's live slots per expert
-        last (None without an expert layer)."""
+        positions), `kv_write` (KVPool.write: quantize and scatter; a
+        cross layer writes nothing), `attn` (the ragged paged kernel
+        over the step's `work` list — a window layer over its ring and
+        the window's list, a cross layer over the full layer's pages),
+        `diff_norm` where the attention is differential, `attn_out`. A
+        state-space layer: `ln`, `ssm_proj`, `ssm_conv`, `ssm_scan`. A
+        gated memory unit: `ln`, `gmu`. Then the description's
+        feed-forward under its own scopes (`ffn`, or `router`,
+        `moe_dispatch`, `experts`, `moe_combine`). `la` is the lanes'
+        adapter rows of this layer (None: no adapters); `hyb` what
+        `_hybrid_lanes` made (None: attention layers alone). Returns
+        the layer's live slots per expert last (None without an expert
+        layer)."""
         scope = jax.named_scope
         arch = self.arch
+        kind = arch.mixer(i)
         with scope("ln"):
             h = arch.norm1(params, i, x)
-        with scope("qkv"):
-            q, k, v = arch.qkv(
-                params, i, h, positions, lora=None if la is None else
-                (la["a_qkv"], la["b_qkv"], ad_s))         # (T, H[/t], D)
-        with scope("kv_write"):
-            pool = pool.write(i, write_pages, write_offs, k, v)
-        with scope("attn"):
-            k_pages, v_pages, k_scales, v_scales = pool.layer(i)
-            o = paged_attention_ragged_v2(
-                q, k_pages, v_pages, page_tables, lane_slots, lane_lens,
-                k_scales=k_scales, v_scales=v_scales, scale=scale,
-                block_kv=self.attn_block_kv, work=work, **self._attn_kw)
-        with scope("attn_out"):
-            x = arch.attn_out(
-                params, i, o, x, psum_axis=tp_axis,
-                lora=None if la is None else
-                (la["a_wo"], la["b_wo"], ad_s))
+        if kind == SSM:
+            x, pool = self._ssm_layer(params, i, x, h, positions, pool,
+                                      lane_slots, hyb)
+        elif kind == GMU:
+            with scope("gmu"):
+                x = arch.gmu(params, i, h, hyb["memory"], x)
+        else:
+            x, pool = self._attn_layer(
+                params, i, kind, x, h, positions, pool, write_pages,
+                write_offs, page_tables, lane_slots, lane_lens, work,
+                scale, la, ad_s, tp_axis, hyb)
         x, counts = arch.ffn(
             params, i, x, live=live, psum_axis=tp_axis,
             lora=None if la is None else
             (la["a_ff1"], la["b_ff1"], la["a_ff2"], la["b_ff2"], ad_s))
         return x, pool, counts
+
+    def _attn_layer(self, params, i, kind, x, h, positions, pool,
+                    write_pages, write_offs, page_tables, lane_slots,
+                    lane_lens, work, scale, la, ad_s, tp_axis, hyb):
+        """The attention mixer of layer `i` -> (x, pool). `kind` says
+        which pages it writes and reads: ATTN layer i of the one pool;
+        WINDOW its own layer of the rings; FULL the paged layer of a
+        hybrid pool; CROSS that layer too, writing nothing."""
+        scope = jax.named_scope
+        arch = self.arch
+        with scope("qkv"):
+            q, k, v = arch.qkv(
+                params, i, h, positions, lora=None if la is None else
+                (la["a_qkv"], la["b_qkv"], ad_s))         # (T, H[/t], D)
+        window = 0
+        if kind == ATTN:
+            kv, layer = pool, i
+        elif kind == WINDOW:
+            kv, layer = pool.window, arch.window_layers.index(i)
+            write_pages, page_tables = hyb["ring_pages"], hyb["rings"]
+            work, window = hyb["work"], arch.window
+        else:
+            kv, layer = pool.full, 0
+        if kind != CROSS:
+            with scope("kv_write"):
+                kv = kv.write(layer, write_pages, write_offs, k, v)
+            if kind == WINDOW:
+                pool = dataclasses.replace(pool, window=kv)
+            elif kind == FULL:
+                pool = dataclasses.replace(pool, full=kv)
+            else:
+                pool = kv
+        with scope("attn"):
+            k_pages, v_pages, k_scales, v_scales = kv.layer(layer)
+            o = paged_attention_ragged_v2(
+                q, k_pages, v_pages, page_tables, lane_slots, lane_lens,
+                k_scales=k_scales, v_scales=v_scales, scale=scale,
+                block_kv=self.attn_block_kv, work=work, window=window,
+                **self._attn_kw)
+        if kind != ATTN:
+            with scope("diff_norm"):
+                o = arch.diff_norm(params, i, o)
+        with scope("attn_out"):
+            x = arch.attn_out(
+                params, i, o, x, psum_axis=tp_axis,
+                lora=None if la is None else
+                (la["a_wo"], la["b_wo"], ad_s))
+        return x, pool
+
+    def _ssm_layer(self, params, i, x, h, positions, pool, lane_slots,
+                   hyb):
+        """The state-space mixer of layer `i` over the step's lanes ->
+        (x, pool): `ssm_proj` (the in, x, dt and out projections),
+        `ssm_conv` (the convolution over a run and its slot's tail),
+        `ssm_scan` (the recurrence from each run's slot state, the
+        gate, the state's write-back). The convolution, the scan and
+        the gate run in f32. The description's memory layer leaves its
+        scan output in `hyb` for the gated memory units."""
+        scope = jax.named_scope
+        arch = self.arch
+        j = arch.ssm_layers.index(i)
+        p = params[f"layer{i}_ssm"]
+        with scope("ssm_proj"):
+            u, z = arch.ssm_in(params, i, h)              # (T, d_inner)
+        with scope("ssm_conv"):
+            u, tail = ssm.segmented_conv(
+                p, u, pool.tail[j], lane_slots, positions,
+                hyb["offsets"], hyb["wslots"])
+            u = jax.nn.silu(u)
+        with scope("ssm_proj"):
+            dt, b, c = arch.ssm_scan_inputs(params, i, u)
+        with scope("ssm_scan"):
+            y, state = ssm.segmented_scan(
+                p, u, dt, b, c, pool.state[j], lane_slots, positions,
+                hyb["starts"], hyb["wslots"])
+            g = (y * jax.nn.silu(z.astype(jnp.float32))).astype(x.dtype)
+            pool = dataclasses.replace(
+                pool, state=pool.state.at[j].set(state),
+                tail=pool.tail.at[j].set(tail))
+            if i == arch.memory_layer:
+                hyb["memory"] = y.astype(x.dtype)
+        with scope("ssm_proj"):
+            x = arch.ssm_out(params, i, g, x)
+        return x, pool
 
     # ---------------- disaggregated page handoff -----------------------
     # Device half of the prefill->decode transfer (serve/disagg.py;
@@ -1172,6 +1334,7 @@ class ServeEngine:
         generate's on_finish hook, before the slot is freed)."""
         from .adapters import tenant_prefix_salt
         from .disagg import PageShipment
+        self.arch.refuse(handoff=True)
         pages, keys, ntokens = self.cache.export_pages(
             slot, tokens, prev=tenant_prefix_salt(tenant_id))
         if not pages:
@@ -1431,7 +1594,9 @@ class ServeEngine:
                 sharding = jax.tree.map(
                     lambda s: NamedSharding(self.tp_mesh, s),
                     KVPool.specs(TENSOR))
-            self.pool = KVPool.alloc(self.cache_cfg, sharding)
+            alloc = KVPool.alloc if self.cache_cfg.hybrid is None \
+                else HybridPool.alloc
+            self.pool = alloc(self.cache_cfg, sharding)
         return self.pool
 
     # ---------------- adapter pool: device half ------------------------
@@ -2563,7 +2728,13 @@ class StepEvents:
     counted (0: the layer is dropless), ``experts_touched`` the
     (layer, expert) pairs with at least one slot, ``expert_bytes``
     their weights' bytes (what the expert phase reads) and
-    ``expert_load_max`` the fullest expert's slots; ``dispatched``
+    ``expert_load_max`` the fullest expert's slots; on a model whose
+    slots hold state besides pages (kv_cache.HybridSpec)
+    ``state_bytes`` is the scan states and tails the step reads and
+    writes for its runs, ``ssm_runs`` the runs (segments) each scan
+    covers, ``window_kv_bytes`` / ``full_kv_bytes`` the page fetches of
+    the window layers' calls and of the calls on the full layer's
+    pages (its own and every cross layer's); ``dispatched``
     False for a planning-only iteration (rung-4
     rejections / whole-set preemption under injected pressure — the
     scheduler's forced-progress rule guarantees re-planning
@@ -2574,7 +2745,9 @@ class StepEvents:
                  "kv_bytes_read", "attn_items", "attn_rows", "topv", "topi",
                  "emit_lanes",
                  "expert_counts", "expert_slots", "expert_dropped",
-                 "experts_touched", "expert_bytes", "expert_load_max")
+                 "experts_touched", "expert_bytes", "expert_load_max",
+                 "state_bytes", "ssm_runs", "window_kv_bytes",
+                 "full_kv_bytes")
 
     def __init__(self, plan=None):
         self.dispatched = False
@@ -2602,6 +2775,10 @@ class StepEvents:
         self.experts_touched = 0
         self.expert_bytes = 0
         self.expert_load_max = 0
+        self.state_bytes = 0
+        self.ssm_runs = 0
+        self.window_kv_bytes = 0
+        self.full_kv_bytes = 0
 
 
 class ServeSession:
@@ -2657,6 +2834,8 @@ class ServeSession:
         self.expert_totals = {"steps": 0, "slots": 0, "dropped": 0,
                               "touched": 0, "bytes": 0}
         self.expert_counts_total = None     # (layers, experts) int64
+        # the rings' page table never changes (kv_cache.ring_tables)
+        self._ring_tables = ring_tables(c) if c.hybrid else None
         self._retries0 = engine._retries
         self._rejected_seen = 0   # flight-recorder rejection trigger
         self._t0 = time.perf_counter()
@@ -2831,10 +3010,34 @@ class ServeSession:
             raise RuntimeError(
                 f"the plan makes {work['total']} attention work items, "
                 f"the kernel's grid holds {work['grid']}")
-        work["kv_bytes"] = (
-            eng.num_layers * work["page_fetches"] * kv_page_bytes(
-                ps, eng.num_heads, eng.head_dim, c.kv_itemsize,
-                eng.kv_quantized))
+        page_bytes = kv_page_bytes(ps, eng.kv_heads, eng.kv_head_dim,
+                                   c.kv_itemsize, eng.kv_quantized)
+        if c.hybrid is None:
+            work["kv_bytes"] = (
+                eng.num_layers * work["page_fetches"] * page_bytes)
+            return (arrays, lane_adapters, lane, emitters, spec_emitters,
+                    work)
+        # a model of several mixer kinds: the full layer's pages are
+        # fetched by its own call and by every cross layer's; the
+        # window layers' calls walk the rings under the window's list;
+        # a scan reads and writes one state and one tail a RUN
+        arch = eng.arch
+        ring = work_items(
+            lane_lens, lane_slots, self._ring_tables, page_size=ps,
+            block_kv_pages=eng.attn_block_pages,
+            max_items=eng.window_max_items, live_lanes=lane,
+            window=arch.window)
+        if ring["total"] > ring["grid"]:
+            raise RuntimeError(
+                f"the plan makes {ring['total']} window work items, "
+                f"the kernel's grid holds {ring['grid']}")
+        readers = 1 + arch.kinds.count(CROSS)
+        work["full_kv_bytes"] = readers * work["page_fetches"] * page_bytes
+        work["window_kv_bytes"] = (len(arch.window_layers)
+                                   * ring["page_fetches"] * page_bytes)
+        work["kv_bytes"] = work["full_kv_bytes"] + work["window_kv_bytes"]
+        work["ssm_runs"] = len(plan.chunks)
+        work["state_bytes"] = 2 * len(plan.chunks) * c.hybrid.state_bytes
         return arrays, lane_adapters, lane, emitters, spec_emitters, work
 
     def _count_experts(self, ev: StepEvents, counts: np.ndarray,
@@ -2917,6 +3120,12 @@ class ServeSession:
              work) = self._pack(plan)
             ev.kv_bytes_read = work["kv_bytes"]
             ev.attn_items, ev.attn_rows = work["items"], work["rows"]
+            hybrid_args = {}
+            if c.hybrid is not None:
+                for key in ("state_bytes", "ssm_runs", "window_kv_bytes",
+                            "full_kv_bytes"):
+                    setattr(ev, key, work[key])
+                    hybrid_args[key] = work[key]
         with timed(track, "drain"):
             # land any adapters this plan admitted BEFORE their lanes
             # dispatch — the planning-visible load stall, not a
@@ -2936,7 +3145,8 @@ class ServeSession:
                 "prefill": plan.num_prefill_lanes,
                 "decode": plan.num_decode_lanes,
                 "kv_bytes": ev.kv_bytes_read,
-                "items": ev.attn_items, "rows": ev.attn_rows}):
+                "items": ev.attn_items, "rows": ev.attn_rows,
+                **hybrid_args}):
             greedy, topv, topi, counts = eng._dispatch_mixed(
                 *dev, lane_adapters=dev_adapters)
         with timed(track, "fetch"):
@@ -3004,6 +3214,9 @@ class ServeSession:
             decode_widths=self.decode_widths,
             prefill_times=self.prefill_times, util=self.util)
         stats["nonfinite_logit_steps"] = self.nonfinite_steps
+        c = self.eng.cache_cfg
+        stats["cache_bytes_per_token"] = c.cache_bytes_per_token
+        stats["cache_bytes_constant_per_seq"] = c.constant_bytes_per_seq
         if self.eng.arch.experts:
             stats["experts"] = self.expert_stats()
         return stats

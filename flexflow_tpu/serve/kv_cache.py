@@ -41,6 +41,28 @@ steps scatter their K/V there through page-table entries of 0, so the
 jitted steps never need a masked scatter. Reads are masked by sequence
 length, so sink contents are never observed.
 
+THREE KINDS OF PER-SEQUENCE STATE (a model whose description holds a
+`HybridSpec`: serve/arch.Phi4Flash). Pages, as above, for the layers
+the model pages (`KVCacheConfig.num_layers`: there, one). Besides them
+a sequence's SLOT holds a constant part that needs no allocator:
+
+  * a RING of the window layers' keys: `ring_pages` pages a slot in a
+    pool of their own, logical page p of slot s at physical page
+    1 + s * ring_pages + p % ring_pages. A window layer reads only the
+    last `window` positions, and one step writes at most a chunk of
+    `chunk` tokens before it reads, so window + chunk - 1 consecutive
+    positions (at most ring_pages pages) are ever live at once: what
+    falls behind is overwritten, never held. The ring's page table is
+    a function of the slot and nothing else (`ring_tables`).
+  * a scan STATE (f32) and a convolution TAIL for each state-space
+    layer, row s of two slabs whose last row is the write sink of the
+    step's inactive lanes. A sequence (re-)admitted at position 0
+    reads neither (the step starts it from zeros).
+
+Admission prices the constant part by the slot it takes: it exists for
+every slot from the start (`KVCacheConfig.constant_bytes_per_seq`,
+counted in `pool_bytes`). `HybridPool` is the device half of all three.
+
 Host/device split: `PagedKVCache` owns only HOST bookkeeping (free
 list, refcounts, hash registry, page tables, lengths) as plain
 numpy/dicts the scheduler mutates freely — the manager never touches
@@ -104,6 +126,34 @@ def prefix_page_keys(tokens: Sequence[int], page_size: int,
 
 
 @dataclasses.dataclass(frozen=True)
+class HybridSpec:
+    """What a sequence holds besides pages (module docstring): the
+    window layers' ring and the state-space layers' state and tail.
+    `chunk` is the most tokens of ONE sequence a step writes (the
+    engine's prefill budget)."""
+    window_layers: int
+    window: int
+    chunk: int
+    state_layers: int
+    state_shape: Tuple[int, int]        # (d_state, d_inner), f32
+    tail_shape: Tuple[int, int]         # (d_conv - 1, d_inner)
+    tail_dtype: str = "bfloat16"
+
+    def ring_pages(self, page_size: int) -> int:
+        """Pages that cover any window + chunk - 1 consecutive
+        positions: one more than their whole pages."""
+        return -(-(self.window + self.chunk - 2) // page_size) + 1
+
+    @property
+    def state_bytes(self) -> int:
+        """One sequence's scan states and tails, all layers."""
+        n = lambda shape: shape[0] * shape[1]
+        return self.state_layers * (
+            n(self.state_shape) * 4
+            + n(self.tail_shape) * jnp.dtype(self.tail_dtype).itemsize)
+
+
+@dataclasses.dataclass(frozen=True)
 class KVCacheConfig:
     """Geometry of the paged pool. Built from FFConfig + model shape via
     :meth:`from_ff` so every serving component sizes itself from the
@@ -151,11 +201,15 @@ class KVCacheConfig:
     max_seq_len: int = 512  # logical cap; rounds up to whole pages
     kv_dtype: str = "float32"
     tensor_parallel: int = 1  # head-sharding degree of the serve mesh
+    hybrid: Optional[HybridSpec] = None  # the slots' constant part
+    # pages stored head-PACKED, (page, slot, heads * head_dim): KVPool
+    packed_heads: bool = False
 
     @classmethod
     def from_ff(cls, config, *, num_layers: int, num_heads: int,
                 head_dim: int, max_seq_len: int = 512,
-                tensor_parallel: int = 1) -> "KVCacheConfig":
+                tensor_parallel: int = 1,
+                hybrid: Optional[HybridSpec] = None) -> "KVCacheConfig":
         kv_dtype = str(getattr(config, "kv_dtype", "float32"))
         num_pages = int(getattr(config, "kv_num_pages", 257))
         pool_mb = float(getattr(config, "kv_pool_mb", 0.0) or 0.0)
@@ -184,7 +238,8 @@ class KVCacheConfig:
                    num_pages=num_pages,
                    max_seqs=int(getattr(config, "serve_max_seqs", 8)),
                    max_seq_len=max_seq_len, kv_dtype=kv_dtype,
-                   tensor_parallel=tp)
+                   tensor_parallel=tp, hybrid=hybrid,
+                   packed_heads=hybrid is not None)
 
     @property
     def pages_per_seq(self) -> int:
@@ -231,7 +286,45 @@ class KVCacheConfig:
 
     @property
     def pool_bytes(self) -> int:
-        return self.num_pages * self.page_bytes
+        """Everything the cache holds on the device: the pages, and
+        every slot's constant part (the rings' sink page with them)."""
+        return self.num_pages * self.page_bytes + self.constant_bytes
+
+    # ---------------- the slots' constant part (HybridSpec) -----------
+    @property
+    def ring_pages(self) -> int:
+        return self.hybrid.ring_pages(self.page_size) if self.hybrid else 0
+
+    @property
+    def ring_page_bytes(self) -> int:
+        """One ring page across the window layers."""
+        if not self.hybrid:
+            return 0
+        return self.page_bytes // self.num_layers \
+            * self.hybrid.window_layers
+
+    @property
+    def cache_bytes_per_token(self) -> int:
+        """What one more token of context costs a sequence."""
+        return self.page_bytes // self.page_size
+
+    @property
+    def constant_bytes_per_seq(self) -> int:
+        """What a sequence holds whatever its length: its ring and its
+        states (0 for a model of pages alone)."""
+        if not self.hybrid:
+            return 0
+        return self.ring_pages * self.ring_page_bytes \
+            + self.hybrid.state_bytes
+
+    @property
+    def constant_bytes(self) -> int:
+        """All slots' constant parts, with the rings' sink page and the
+        slabs' sink row."""
+        if not self.hybrid:
+            return 0
+        return self.max_seqs * self.constant_bytes_per_seq \
+            + self.ring_page_bytes + self.hybrid.state_bytes
 
     # ---------------- per-device accounting (sharded serving) ---------
     @property
@@ -247,7 +340,8 @@ class KVCacheConfig:
 
     @property
     def pool_device_bytes(self) -> int:
-        return self.num_pages * self.page_device_bytes
+        return self.num_pages * self.page_device_bytes \
+            + self.constant_bytes
 
     @property
     def effective_page_ratio(self) -> float:
@@ -279,11 +373,13 @@ class KVCacheConfig:
                 f"head-sharded serving needs num_heads "
                 f"({self.num_heads}) divisible by the tensor degree "
                 f"({self.tensor_parallel})")
+        if self.hybrid and self.tensor_parallel > 1:
+            raise ValueError("a slot's ring and states are not sharded")
 
 
 @functools.partial(
     jax.tree_util.register_dataclass,
-    data_fields=["k", "v", "k_scale", "v_scale"], meta_fields=[])
+    data_fields=["k", "v", "k_scale", "v_scale"], meta_fields=["heads"])
 @dataclasses.dataclass(frozen=True)
 class KVPool:
     """The device K/V pool, and the ONE place that knows its format.
@@ -296,11 +392,21 @@ class KVPool:
     so a jitted program takes and returns a pool (donated) and the
     pytree's structure, not an argument, says whether it is quantized.
     The engine, the handoff and the tests go through the methods below;
-    nothing else indexes, scatters into or shards a leaf."""
+    nothing else indexes, scatters into or shards a leaf.
+
+    PACKED layout (`heads` > 0; `KVCacheConfig.packed_heads`): `k`, `v`
+    are (layer, page, slot, head * dim), the rows as the paged kernel
+    streams them. A head count that is no multiple of the TPU's 8 (16
+    in bf16) sublanes pads every (head, dim) tile of the unpacked
+    layout — 10 heads of 128 to 16 — and XLA then copies the whole
+    pool between its padded and a compact layout around every layer;
+    packed, a page is (slot, 1280): whole tiles. `layer` hands the
+    kernel the same (page, slot, head, dim) view either way."""
     k: Any
     v: Any
     k_scale: Any = None
     v_scale: Any = None
+    heads: int = 0
 
     @classmethod
     def alloc(cls, cfg: KVCacheConfig, sharding=None) -> "KVPool":
@@ -315,8 +421,11 @@ class KVPool:
         rows = (cfg.num_layers, cfg.num_pages, cfg.page_size,
                 cfg.num_heads)
         dt = cfg.storage_dtype
-        pool = cls(jnp.zeros(rows + (cfg.head_dim,), dt, device=sh.k),
-                   jnp.zeros(rows + (cfg.head_dim,), dt, device=sh.v))
+        page = rows[:3] + (cfg.num_heads * cfg.head_dim,) \
+            if cfg.packed_heads else rows + (cfg.head_dim,)
+        pool = cls(jnp.zeros(page, dt, device=sh.k),
+                   jnp.zeros(page, dt, device=sh.v),
+                   heads=cfg.num_heads if cfg.packed_heads else 0)
         if not cfg.quantized:
             return pool
         return dataclasses.replace(
@@ -342,25 +451,35 @@ class KVPool:
         values exactly, bf16 pages round); quantized pools quantize
         each (token, head) row against its own amax scale and store the
         scale beside it."""
+        row = (lambda a: a.reshape(a.shape[0], -1)) if self.heads \
+            else (lambda a: a)
         if not self.quantized:
-            return KVPool(
-                self.k.at[layer, pages, offs].set(k.astype(self.k.dtype)),
-                self.v.at[layer, pages, offs].set(v.astype(self.v.dtype)))
+            return dataclasses.replace(
+                self,
+                k=self.k.at[layer, pages, offs].set(
+                    row(k.astype(self.k.dtype))),
+                v=self.v.at[layer, pages, offs].set(
+                    row(v.astype(self.v.dtype))))
         kq, ksc = quantize_kv_rows(k, self.k.dtype)
         vq, vsc = quantize_kv_rows(v, self.v.dtype)
-        return KVPool(self.k.at[layer, pages, offs].set(kq),
-                      self.v.at[layer, pages, offs].set(vq),
-                      self.k_scale.at[layer, pages, offs].set(ksc),
-                      self.v_scale.at[layer, pages, offs].set(vsc))
+        return dataclasses.replace(
+            self, k=self.k.at[layer, pages, offs].set(row(kq)),
+            v=self.v.at[layer, pages, offs].set(row(vq)),
+            k_scale=self.k_scale.at[layer, pages, offs].set(ksc),
+            v_scale=self.v_scale.at[layer, pages, offs].set(vsc))
 
     def layer(self, i: int):
         """`layer`'s operands of the paged attention kernel
         (kernels/paged_ragged_v2.paged_attention_ragged_v2): (k_pages,
         v_pages, k_scales, v_scales), pages (page, slot, head, dim),
         the scales (page, slot, head) or None."""
+        k, v = self.k[i], self.v[i]
+        if self.heads:
+            k, v = (a.reshape(a.shape[:2] + (self.heads, -1))
+                    for a in (k, v))
         if not self.quantized:
-            return self.k[i], self.v[i], None, None
-        return self.k[i], self.v[i], self.k_scale[i], self.v_scale[i]
+            return k, v, None, None
+        return k, v, self.k_scale[i], self.v_scale[i]
 
     def rows(self, idx) -> "KVPool":
         """Whole pages `idx` of every layer, as a pool of len(idx)
@@ -409,6 +528,64 @@ class KVPool:
                     f"{name}-page row of {what} (page {page} off "
                     f"{off}) has zero scale but nonzero quantized "
                     f"content")
+
+
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["full", "window", "state", "tail"], meta_fields=[])
+@dataclasses.dataclass(frozen=True)
+class HybridPool:
+    """The device half of a HybridSpec configuration: `full` the pages
+    of the paged layers (a KVPool of `cfg.num_layers` layers), `window`
+    the slots' rings (a KVPool of the window layers, 1 + max_seqs *
+    ring_pages pages: the sink, then slot s's ring), `state`
+    (state_layers, max_seqs + 1, d_state, d_inner) f32 and `tail`
+    (state_layers, max_seqs + 1, (d_conv - 1) * d_inner) (its rows
+    flat: three rows would pad to a tile of 16), the last row of both
+    the write sink. Flows through the step like a KVPool
+    (donated in, returned out)."""
+    full: KVPool
+    window: KVPool
+    state: Any
+    tail: Any
+
+    @staticmethod
+    def ring_cfg(cfg: KVCacheConfig) -> KVCacheConfig:
+        """The rings as a page pool's geometry."""
+        return dataclasses.replace(
+            cfg, num_layers=cfg.hybrid.window_layers,
+            num_pages=1 + cfg.max_seqs * cfg.ring_pages, hybrid=None)
+
+    @classmethod
+    def alloc(cls, cfg: KVCacheConfig, sharding=None) -> "HybridPool":
+        h = cfg.hybrid
+        rows = (h.state_layers, cfg.max_seqs + 1)
+        return cls(
+            KVPool.alloc(dataclasses.replace(cfg, hybrid=None), sharding),
+            KVPool.alloc(cls.ring_cfg(cfg), sharding),
+            jnp.zeros(rows + tuple(h.state_shape), jnp.float32,
+                      device=sharding),
+            jnp.zeros(rows + (h.tail_shape[0] * h.tail_shape[1],),
+                      jnp.dtype(h.tail_dtype), device=sharding))
+
+    def check_geometry(self, cfg: KVCacheConfig) -> None:
+        want = jax.eval_shape(lambda: HybridPool.alloc(cfg))
+        self.full.check_geometry(dataclasses.replace(cfg, hybrid=None))
+        self.window.check_geometry(self.ring_cfg(cfg))
+        for name in ("state", "tail"):
+            a, w = getattr(self, name), getattr(want, name)
+            assert (a.shape, a.dtype) == (w.shape, w.dtype), (
+                f"pool leaf {name} is {a.shape} {a.dtype}; the "
+                f"configuration's is {w.shape} {w.dtype}")
+
+
+def ring_tables(cfg: KVCacheConfig, xp=np):
+    """(max_seqs, pages_per_seq) int32: the rings' page table, the same
+    for ever (numpy on the host, jax.numpy inside the step)."""
+    r = cfg.ring_pages
+    slot = xp.arange(cfg.max_seqs, dtype=xp.int32)[:, None]
+    page = xp.arange(cfg.pages_per_seq, dtype=xp.int32)[None, :]
+    return (1 + slot * r + page % r).astype(xp.int32)
 
 
 class PagedKVCache:
@@ -511,6 +688,10 @@ class PagedKVCache:
             "max_page_ref": int(self._ref.max()) if mapped else 0,
             "kv_dtype": c.kv_dtype,
             "page_size": c.page_size,
+            # the slots' constant part (0 / None for pages alone)
+            "ring_pages_per_slot": c.ring_pages,
+            "constant_bytes_per_seq": c.constant_bytes_per_seq,
+            "hybrid": dataclasses.asdict(c.hybrid) if c.hybrid else None,
             # eviction order (oldest first, bounded): what rung-2 /
             # allocation pressure would shed next — the view rung
             # post-mortems were missing
@@ -947,6 +1128,8 @@ class PagedKVCache:
             "bytes_per_page": c.page_bytes,
             "effective_pages": c.usable_pages,
             "pool_bytes": c.pool_bytes,
+            "cache_bytes_per_token": c.cache_bytes_per_token,
+            "cache_bytes_constant_per_seq": c.constant_bytes_per_seq,
             "tensor_parallel": c.tensor_parallel,
             "bytes_per_page_device": c.page_device_bytes,
             "pool_device_bytes": c.pool_device_bytes,
@@ -1028,7 +1211,20 @@ class PagedKVCache:
             assert page in self._hash_of_page, (
                 f"imported page {page} lost its chain key while still "
                 f"tracked as handoff content")
+        # the slots' constant part: a ring never holds more than the
+        # window, one step's chunk and a page of slack (a second where
+        # window + chunk is not whole pages), and holds at least what
+        # one step needs live at once
+        if c.hybrid is not None:
+            h, ring = c.hybrid, c.ring_pages * c.page_size
+            assert h.window + h.chunk - 1 <= ring \
+                < h.window + h.chunk + 2 * c.page_size, (
+                f"a ring of {ring} tokens for a window of {h.window} "
+                f"and chunks of {h.chunk}")
+            assert self.prefix_enabled is False, (
+                "a prefix hit would skip the state of the prefix")
         # the device pool this bookkeeping describes, where the caller
         # holds one: its leaves must be of this configuration's geometry
+        # (a HybridPool checks its pages, rings and slabs)
         if pool is not None:
             pool.check_geometry(c)

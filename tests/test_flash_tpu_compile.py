@@ -98,3 +98,38 @@ def test_sharded_attention_op_compiles_for_2x2(topo, as_tpu):
         params, xs).compile().as_text()
     assert op.attn_impl == "flash"
     assert all(name in text for name in KERNELS)
+
+
+@pytest.mark.parametrize("window", [0, 512])
+def test_paged_kernel_compiles_at_grouped_heads_under_a_window(
+        topo, as_tpu, window):
+    """The serving kernel at Phi-4-mini-flash's served geometry (PR 32):
+    40 query heads over 10 key/value heads of 128 (group 4), bf16 pages
+    of 16, 576 lanes, 8192 positions — with the window layers' list and
+    mask, and without."""
+    from flexflow_tpu.kernels import paged_ragged_v2 as pr
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    t, slots, pp, bp = 576, 64, 512, 13
+    items = pr.max_work_items(
+        t, pp, bp, slot_changes=slots,
+        window_blocks=pr.window_block_bound(window, bp * 16)
+        if window else 0)
+
+    def call(q, kp, vp, pt, lane_slots, lens):
+        work = pr.build_work_list(pt, lane_slots, lens, page_size=16,
+                                  block_pages=bp, max_items=items,
+                                  window=window)
+        return pr.paged_attention_ragged_v2(
+            q, kp, vp, pt, lane_slots, lens, scale=0.125, work=work,
+            use_pallas=True, window=window)
+
+    pages = sds((4161, 16, 10, 128), jnp.bfloat16)
+    text = jax.jit(call).lower(
+        sds((t, 40, 128), jnp.bfloat16), pages, pages,
+        sds((slots, pp), jnp.int32), sds((t,), jnp.int32),
+        sds((t,), jnp.int32)).compile().as_text()
+    assert "paged_ragged_v2" in text and "tpu_custom_call" in text
