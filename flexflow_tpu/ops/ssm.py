@@ -17,7 +17,9 @@ State is laid out (d_state, d_inner): the wide axis on the lanes.
 Two scans: `selective_scan` over whole sequences from a zero state (the
 graph op's forward), and `segmented_scan` over the LANES of a serving
 step — runs of consecutive lanes of one sequence, each resuming from its
-slot's stored state (serve/engine.py).
+slot's stored state (serve/engine.py): the jnp twin of the Pallas kernel
+kernels/ssm_scan.py, which the engine runs wherever it runs the paged
+kernel.
 """
 
 from __future__ import annotations
@@ -31,11 +33,15 @@ from ..op import CHANNEL_IN, CHANNEL_OUT, SAMPLE, SEQ, Op, OpContext, \
     WeightSpec, register_op
 
 F32 = jnp.float32
-# lanes a trip of the serving scan's loop (lax.scan's `unroll`): the
-# loop is a chain of small operations on one (d_state, d_inner) state.
-# On a v5e at the served shape (576 lanes, 16 x 5120; PERF.md section
-# 6, PR 32) a layer's scan takes 7.66 ms at 1, 4.68 at 2, 4.34 at 4,
-# 4.17 at 8, 4.12 at 16, bit for bit the same
+# lanes a trip of the TWIN's loop (lax.scan's `unroll`): `segmented_scan`
+# below is the jnp twin of kernels/ssm_scan.py, which runs the serving
+# step's recurrence on a tpu backend since PR 33; the twin runs where
+# jnp attention runs (the CPU tests' engines) and where the kernel does
+# not take the shape. As an XLA loop it is a chain of small operations
+# on one (d_state, d_inner) state: on a v5e at the served shape (576
+# lanes, 16 x 5120; PERF.md section 6, PR 32) a layer's scan took 7.66
+# ms at 1, 4.68 at 2, 4.34 at 4, 4.17 at 8, 4.12 at 16, bit for bit the
+# same
 SCAN_UNROLL = 8
 
 
@@ -102,6 +108,14 @@ def run_offsets(starts):
     return lane - first
 
 
+def run_write_slots(starts, live, lane_slots, sink: int):
+    """(T,) int32: the slot a lane's state (and tail) is written back
+    to — its own where the lane is its run's last live one, else the
+    slabs' `sink` row."""
+    ends = jnp.concatenate([starts[1:] | ~live[1:], jnp.ones((1,), bool)])
+    return jnp.where(live & ends, lane_slots, sink)
+
+
 def segmented_conv(p, u, tail, lane_slots, positions, offsets, wslots):
     """The convolution over the step's lanes. u (T, d_inner) raw
     projections; tail (slots + 1, (d_conv - 1) * d_inner) each slot's
@@ -136,7 +150,10 @@ def segmented_conv(p, u, tail, lane_slots, positions, offsets, wslots):
 
 def segmented_scan(p, u, dt, b, c, state, lane_slots, positions, starts,
                    wslots):
-    """The recurrence over the step's lanes, one after another. state
+    """The recurrence over the step's lanes, one after another (the
+    jnp twin of kernels/ssm_scan.py::ssm_scan, which takes the whole
+    slab and a layer, walks the live lanes only and leaves the dead
+    lanes' rows of y zero). state
     (slots + 1, N, d_inner): a run's first lane takes its slot's state
     (zeros at position 0), every lane writes the state to `wslots`
     (its slot where the lane is a run's last live one, else the sink

@@ -59,6 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import CompMode
+from ..kernels import ssm_scan
 from ..kernels.paged_ragged_v2 import (JNP, PALLAS_INTERPRET, Q_ROWS,
                                        build_work_list, choose_block_kv,
                                        kv_page_bytes, max_work_items,
@@ -365,6 +366,15 @@ class ServeEngine:
         # the one mixed-step geometry: every prefill-budget token plus
         # one decode lane per slot always fits
         self.mixed_width = self.prefill_budget + self.cache_cfg.max_seqs
+        # the state-space layers' scan runs where the paged kernel runs
+        # (kernels/ssm_scan.py, under `attn_impl`) wherever that kernel
+        # takes the step's shape, else as its jnp twin
+        # (ops/ssm.segmented_scan); None: no such layer
+        hyb = self.cache_cfg.hybrid
+        self.scan_impl = None
+        if hyb is not None and hyb.state_layers:
+            self.scan_impl = self.attn_impl if ssm_scan.supported(
+                self.mixed_width, *hyb.state_shape) else JNP
         # the paged kernel's grid: the most work items a plan can make
         # (kernels/paged_ragged_v2.max_work_items). PROOF of the slot
         # changes: _pack lays a plan's chunks one after another, each
@@ -639,6 +649,7 @@ class ServeEngine:
             # for: replicas on different chips keep separate stores
             "device_ids": tuple(int(d.id) for d in self.devices),
             "attn_impl": self.attn_impl,
+            "scan_impl": self.scan_impl,
         }
 
     # ---------------- model introspection -----------------------------
@@ -1124,19 +1135,22 @@ class ServeEngine:
         decode lane), each lane's offset in its run, the slot a lane's
         state is written back to (its own where it is its run's last
         live lane, else the slabs' sink row), and the rings' page
-        table, write addresses and work list."""
+        table, write addresses and work list; `live_lanes` the lanes
+        up to the last live one (_pack fills them from 0 up, so: the
+        live lanes), the trips of the scan kernel."""
         c = self.cache_cfg
         with jax.named_scope("work_list"):
             live = write_pages != 0
+            lane = jnp.arange(1, live.shape[0] + 1, dtype=jnp.int32)
             starts = ssm.run_starts(lane_slots, positions)
-            ends = jnp.concatenate([starts[1:] | ~live[1:],
-                                    jnp.ones((1,), bool)])
             rings = ring_tables(c, jnp)
             page = positions // c.page_size
             hyb = {
                 "starts": starts, "offsets": ssm.run_offsets(starts),
-                "wslots": jnp.where(live & ends, lane_slots, c.max_seqs),
+                "wslots": ssm.run_write_slots(starts, live, lane_slots,
+                                              c.max_seqs),
                 "rings": rings, "memory": None, "work": None,
+                "live_lanes": jnp.max(jnp.where(live, lane, 0)),
                 "ring_pages": jnp.where(live, rings[lane_slots, page], 0)}
             if self.attn_impl != JNP:
                 hyb["work"] = build_work_list(
@@ -1258,13 +1272,22 @@ class ServeEngine:
         with scope("ssm_proj"):
             dt, b, c = arch.ssm_scan_inputs(params, i, u)
         with scope("ssm_scan"):
-            y, state = ssm.segmented_scan(
-                p, u, dt, b, c, pool.state[j], lane_slots, positions,
-                hyb["starts"], hyb["wslots"])
+            # the kernel keeps an f32 slab in place; a slab of another
+            # dtype (no configuration's) keeps the twin's rounding at
+            # every lane
+            if self.scan_impl != JNP and pool.state.dtype == jnp.float32:
+                y, state = ssm_scan.ssm_scan(
+                    p, u, dt, b, c, pool.state, j, lane_slots, positions,
+                    hyb["starts"], hyb["wslots"], hyb["live_lanes"],
+                    interpret=self.scan_impl == PALLAS_INTERPRET)
+            else:
+                y, state = ssm.segmented_scan(
+                    p, u, dt, b, c, pool.state[j], lane_slots, positions,
+                    hyb["starts"], hyb["wslots"])
+                state = pool.state.at[j].set(state)
             g = (y * jax.nn.silu(z.astype(jnp.float32))).astype(x.dtype)
             pool = dataclasses.replace(
-                pool, state=pool.state.at[j].set(state),
-                tail=pool.tail.at[j].set(tail))
+                pool, state=state, tail=pool.tail.at[j].set(tail))
             if i == arch.memory_layer:
                 hyb["memory"] = y.astype(x.dtype)
         with scope("ssm_proj"):
@@ -1738,6 +1761,7 @@ class ServeEngine:
         rec["boot_s"] = time.perf_counter() - t0
         rec["warm"] = rec["compiles"] == 0 and rec["restored"] > 0
         rec["attn_impl"] = self.attn_impl
+        rec["scan_impl"] = self.scan_impl
         self.boot_stats = rec
         if self.programs.cache_dir and self.programs._dirty:
             # read-through write-back: the first (cold) engine over
@@ -2505,6 +2529,7 @@ class ServeEngine:
             "mode": "chunked",
             # the paged-attention implementation that ran and where
             "attn_impl": self.attn_impl,
+            "scan_impl": self.scan_impl,
             "devices": [int(d.id) for d in self.devices],
             "wall_s": wall,
             "total_new_tokens": total_new,

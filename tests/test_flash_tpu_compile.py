@@ -5,6 +5,9 @@ cannot partition — shows here and costs no chip time. Nothing runs, so
 nothing here says a result or a time (tests_tpu/test_flash_tpu.py does,
 on the chip).
 
+The serving kernels (the paged kernel at grouped heads, the scan of the
+state-space layers) are compiled here too, at their served shapes.
+
 The topology is described inside a fixture, never at import: one process
 at a time may load the TPU's library, and the suite runs under several
 workers that all import this file.
@@ -133,3 +136,43 @@ def test_paged_kernel_compiles_at_grouped_heads_under_a_window(
         sds((slots, pp), jnp.int32), sds((t,), jnp.int32),
         sds((t,), jnp.int32)).compile().as_text()
     assert "paged_ragged_v2" in text and "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("block", [None, 256, 1280])
+def test_serving_scan_kernel_compiles_in_place_for_a_v5e(topo, as_tpu,
+                                                         block):
+    """The state-space layers' scan (kernels/ssm_scan.py, PR 33) at
+    Phi-4-mini-flash's served shape — 576 lanes, 65 slot rows of
+    16 x 5120 f32, nine layers in one donated slab — at the block the
+    kernel chooses and at the sweep's ends: one Mosaic call, and the
+    program copies neither the slab nor a layer's row of it."""
+    import re
+
+    from flexflow_tpu.kernels import ssm_scan as ks
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    t, rows, n, d, layers = 576, 65, 16, 5120, 9
+    assert ks.supported(t, n, d)
+
+    def call(a_log, d_skip, u, dt, b, c, slab, slots, pos, starts, wslots,
+             live):
+        return ks.ssm_scan({"A_log": a_log, "D": d_skip}, u, dt, b, c, slab,
+                           4, slots, pos, starts, wslots, live, block=block)
+
+    lane = sds((t,), jnp.int32)
+    compiled = jax.jit(call, donate_argnums=(6,)).lower(
+        sds((n, d)), sds((d,)), sds((t, d)), sds((t, d)), sds((t, n)),
+        sds((t, n)), sds((layers, rows, n, d)), lane, lane,
+        sds((t,), jnp.bool_), lane, sds((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "ssm_scan" in text and text.count("tpu_custom_call") == 1
+    copies = [line for line in text.splitlines()
+              if re.search(r"\bcopy(-start)?\(", line)
+              and re.search(r"f32\[(9,)?65,16,\d+\]", line)]
+    assert not copies, copies
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 4 * layers * rows * n * d
+    assert m.temp_size_in_bytes < 2**20
