@@ -15,9 +15,14 @@ can be sharded over a mesh `expert` axis so GSPMD inserts the all-to-all
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from ..kernels import grouped_ffn as fused
+from ..kernels.paged_ragged_v2 import PALLAS, PALLAS_INTERPRET
 from ..op import EXPERT, SAMPLE, Op, OpContext, register_op
 
 
@@ -117,6 +122,9 @@ def use_sorted_dispatch(model, n_slots: int, n_experts: int,
     return n_slots * n_experts * capacity > DENSE_MASK_ELEMENT_LIMIT
 
 
+RAGGED_DOT = "ragged_dot"        # `grouped_ffn` as XLA's grouped matmuls
+
+
 # ---- dropless routing: every slot reaches its expert, whatever the load
 def route_top_k(tokens: jax.Array, gate_w: jax.Array, k: int,
                 norm_topk: bool):
@@ -151,13 +159,12 @@ def dropless_dispatch(tokens: jax.Array, assign: jax.Array,
     return jnp.take(tokens, order // k, axis=0), order, counts
 
 
-def grouped_ffn(rows: jax.Array, counts: jax.Array, wg, wu, wd,
-                activation) -> jax.Array:
-    """The gated bias-free expert over rows sorted by expert:
-    (act(rows wg_e) * (rows wu_e)) wd_e, each product one grouped
-    matmul (`jax.lax.ragged_dot`, f32 accumulation) whose group e is
-    the `counts[e]` rows of expert e. Rows past sum(counts) belong to
-    no expert and come out zero."""
+def ragged_ffn(rows: jax.Array, counts: jax.Array, wg, wu, wd,
+               activation) -> jax.Array:
+    """`grouped_ffn` as three grouped matmuls (`jax.lax.ragged_dot`,
+    f32 accumulation, each product rounded to the rows' dtype) whose
+    group e is the `counts[e]` rows of expert e: what runs wherever the
+    fused kernel does not, that kernel's jnp twin and its backward."""
     from .common import apply_activation
     dt = rows.dtype
 
@@ -170,6 +177,55 @@ def grouped_ffn(rows: jax.Array, counts: jax.Array, wg, wu, wd,
     y = gmm(h, wd)
     routed = jnp.arange(rows.shape[0]) < jnp.sum(counts)
     return jnp.where(routed[:, None], y, jnp.zeros_like(y))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _fused_ffn(rows, counts, wg, wu, wd, activation, interpret):
+    return fused.grouped_ffn(rows, counts, wg, wu, wd, activation,
+                             interpret=interpret)
+
+
+def _fused_fwd(rows, counts, wg, wu, wd, activation, interpret):
+    return (_fused_ffn(rows, counts, wg, wu, wd, activation, interpret),
+            (rows, counts, wg, wu, wd))
+
+
+def _fused_bwd(activation, interpret, saved, dy):
+    rows, counts, *ws = saved
+    _, vjp = jax.vjp(
+        lambda r, *w: ragged_ffn(r, counts, *w, activation), rows, *ws)
+    d_rows, *d_ws = vjp(dy)
+    return (d_rows, np.zeros(counts.shape, jax.dtypes.float0), *d_ws)
+
+
+_fused_ffn.defvjp(_fused_fwd, _fused_bwd)
+
+
+def expert_impl(rows, wg, *, use_pallas=None, interpret=False) -> str:
+    """Which implementation `grouped_ffn` runs for these operands
+    (anything with `shape` and `dtype`): "pallas" | "pallas_interpret"
+    (kernels/grouped_ffn.py, wherever it takes them: a tpu backend or
+    the interpreter by argument, bf16, tiled widths) or "ragged_dot"
+    (also by argument: use_pallas=False)."""
+    if use_pallas is False or not fused.supported(rows, wg,
+                                                  interpret=interpret):
+        return RAGGED_DOT
+    return PALLAS_INTERPRET if interpret else PALLAS
+
+
+def grouped_ffn(rows: jax.Array, counts: jax.Array, wg, wu, wd,
+                activation, *, use_pallas=None,
+                interpret=False) -> jax.Array:
+    """The gated bias-free expert over rows sorted by expert:
+    (act(rows wg_e) * (rows wu_e)) wd_e with f32 accumulation, where
+    expert e's rows are the next `counts[e]`. Rows past sum(counts)
+    belong to no expert and come out zero. One fused kernel where
+    `expert_impl` says so (differentiated as `ragged_ffn`), else
+    `ragged_ffn`."""
+    if expert_impl(rows, wg, use_pallas=use_pallas,
+                   interpret=interpret) == RAGGED_DOT:
+        return ragged_ffn(rows, counts, wg, wu, wd, activation)
+    return _fused_ffn(rows, counts, wg, wu, wd, activation, interpret)
 
 
 def dropless_combine(ys: jax.Array, order: jax.Array,

@@ -36,8 +36,8 @@ from ..ops import diff_attention as DA
 from ..ops import ssm as S
 from ..ops.common import rms_norm, rotary
 from ..ops.gated import gated_ffn, gated_memory
-from ..ops.moe import (dropless_combine, dropless_dispatch, grouped_ffn,
-                       route_top_k)
+from ..ops.moe import (dropless_combine, dropless_dispatch, expert_impl,
+                       grouped_ffn, route_top_k)
 
 ATTN = "attn"       # the mixer kind of every layer of the plain decoders
 
@@ -106,9 +106,17 @@ class Description:
     forward_logits = None
     # engine path -> why this model is not served on it (`refuse`)
     refused = {}
+    # the engine's resolved kernel choice (use_pallas, interpret), which
+    # it sets once: what a description's own kernels run under
+    kernels = {}
 
     def mixer(self, i: int) -> str:
         return ATTN
+
+    def expert_impl(self, lanes: int):
+        """Which implementation the expert layer of a step of `lanes`
+        lanes runs (ops/moe.py::expert_impl); None: no expert layer."""
+        return None
 
     def hybrid_spec(self, chunk: int):
         """What a sequence holds besides pages, as a
@@ -326,6 +334,12 @@ class OLMoE(Description):
         # the bytes the expert phase reads for every expert it touches
         self.expert_bytes = int(3 * self.hidden * self.ff_dim
                                 * w.dtype.itemsize)
+        self._expert_weights = jax.ShapeDtypeStruct(w.shape, w.dtype)
+
+    def expert_impl(self, lanes: int):
+        rows = jax.ShapeDtypeStruct(
+            (lanes * self.experts_per_token, self.hidden), self.act_dtype)
+        return expert_impl(rows, self._expert_weights, **self.kernels)
 
     def embed(self, params, tokens, positions):
         return jnp.take(params["tok_embed"]["kernel"], tokens, axis=0,
@@ -354,7 +368,8 @@ class OLMoE(Description):
     def ffn(self, params, i, x, live=None, psum_axis=None, lora=None):
         """The expert layer, four scopes: `router` (the norm, f32
         softmax and top-k), `moe_dispatch` (slots sorted by expert),
-        `experts` (the grouped matmuls), `moe_combine`. `live` (T,)
+        `experts` (the gated expert: one kernel or three grouped
+        matmuls, `expert_impl`), `moe_combine`. `live` (T,)
         bool: lanes that are not live route nowhere, so the expert work
         follows the live lanes. -> (x, (E,) int32 live slots per
         expert)."""
@@ -370,7 +385,7 @@ class OLMoE(Description):
                 h, assign, self.experts, live)
         with scope("experts"):
             ys = grouped_ffn(rows, counts, m["wg"], m["wu"], m["wd"],
-                             self.activation)
+                             self.activation, **self.kernels)
         with scope("moe_combine"):
             y = dropless_combine(ys, order, gate_vals)
             return x + y.astype(x.dtype).reshape(x.shape), counts

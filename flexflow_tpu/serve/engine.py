@@ -225,6 +225,7 @@ class ServeEngine:
         self._attn_kw = {"use_pallas": self.attn_impl != JNP,
                          "interpret": self.attn_impl == PALLAS_INTERPRET}
         self._read_arch(model)
+        self.arch.kernels = self._attn_kw
         if max_seq_len is None:
             max_seq_len = self.max_positions
         if max_seq_len > self.max_positions:
@@ -375,6 +376,12 @@ class ServeEngine:
         if hyb is not None and hyb.state_layers:
             self.scan_impl = self.attn_impl if ssm_scan.supported(
                 self.mixed_width, *hyb.state_shape) else JNP
+        # the expert layer's gated expert is one fused kernel
+        # (kernels/grouped_ffn.py, by the same arguments as the paged
+        # kernel) wherever that kernel takes the step's rows and
+        # weights, else three grouped matmuls ("ragged_dot"); None: no
+        # expert layer
+        self.expert_impl = self.arch.expert_impl(self.mixed_width)
         # the paged kernel's grid: the most work items a plan can make
         # (kernels/paged_ragged_v2.max_work_items). PROOF of the slot
         # changes: _pack lays a plan's chunks one after another, each
@@ -650,6 +657,7 @@ class ServeEngine:
             "device_ids": tuple(int(d.id) for d in self.devices),
             "attn_impl": self.attn_impl,
             "scan_impl": self.scan_impl,
+            "expert_impl": self.expert_impl,
         }
 
     # ---------------- model introspection -----------------------------
@@ -1762,6 +1770,7 @@ class ServeEngine:
         rec["warm"] = rec["compiles"] == 0 and rec["restored"] > 0
         rec["attn_impl"] = self.attn_impl
         rec["scan_impl"] = self.scan_impl
+        rec["expert_impl"] = self.expert_impl
         self.boot_stats = rec
         if self.programs.cache_dir and self.programs._dirty:
             # read-through write-back: the first (cold) engine over
@@ -2530,6 +2539,7 @@ class ServeEngine:
             # the paged-attention implementation that ran and where
             "attn_impl": self.attn_impl,
             "scan_impl": self.scan_impl,
+            "expert_impl": self.expert_impl,
             "devices": [int(d.id) for d in self.devices],
             "wall_s": wall,
             "total_new_tokens": total_new,

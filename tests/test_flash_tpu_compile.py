@@ -176,3 +176,76 @@ def test_serving_scan_kernel_compiles_in_place_for_a_v5e(topo, as_tpu,
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= 4 * layers * rows * n * d
     assert m.temp_size_in_bytes < 2**20
+
+
+def _sds(tree, sharding):
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=sharding), tree)
+
+
+@pytest.mark.parametrize("row_tile,f_tile", [(None, None), (32, 256)])
+def test_fused_expert_kernel_compiles_at_the_served_shape(
+        topo, as_tpu, row_tile, f_tile):
+    """The gated expert (kernels/grouped_ffn.py, PR 37) at OLMoE's
+    served shape — 4608 expert-sorted rows of 2048, 64 experts of 1024,
+    bf16 — at the tiles the kernel chooses (a whole expert a grid step:
+    24 MiB of double-buffered weights, inside the VMEM it asks for) and
+    at the sweep's other end: one Mosaic call, no grouped matmul."""
+    from flexflow_tpu.kernels import grouped_ffn as kg
+    from flexflow_tpu.ops import moe
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    s, d, e, f = 4608, 2048, 64, 1024
+    rows, wg, wd = sds((s, d)), sds((e, d, f)), sds((e, f, d))
+    assert moe.expert_impl(rows, wg) == "pallas"
+    text = jax.jit(lambda *a: kg.grouped_ffn(
+        *a, "silu", row_tile=row_tile, f_tile=f_tile)).lower(
+        rows, sds((e,), jnp.int32), wg, wg, wd).compile().as_text()
+    assert "grouped_ffn" in text and text.count("tpu_custom_call") == 1
+    assert "ragged-dot" not in text
+
+
+def test_olmoe_mixed_step_holds_one_expert_kernel_a_layer(topo, as_tpu):
+    """OLMoE's mixed step at its served widths (576 lanes of 2048, 16
+    heads of 128, experts of 1024, 8 a token, bf16; 4 layers of 8
+    experts and a small vocabulary, so the parameters are quick to
+    make): every layer's `experts` scope holds one Mosaic call and no
+    grouped matmul, and no (4608, 1024) product of one reaches HBM."""
+    import re
+
+    from flexflow_tpu import FFConfig
+    from flexflow_tpu.config import CompMode
+    from flexflow_tpu.models.olmoe import build_olmoe_lm
+    from flexflow_tpu.serve import ServeEngine
+    layers = 4
+    cfg = FFConfig(batch_size=1, kv_page_size=16, kv_num_pages=129,
+                   serve_max_seqs=64, serve_prefill_budget=512,
+                   serve_spec_decode=False, compute_dtype="bfloat16",
+                   param_dtype="bfloat16", kv_dtype="bfloat16")
+    lm = build_olmoe_lm(cfg, vocab_size=1024, max_seq_len=256, hidden=2048,
+                        num_heads=16, num_layers=layers, num_experts=8,
+                        experts_per_token=8, expert_dim=1024)
+    lm.compile(comp_mode=CompMode.INFERENCE)
+    engine = ServeEngine(lm)
+    assert engine.expert_impl == engine.attn_impl == "pallas"
+    assert engine.mixed_width == 576
+    one = SingleDeviceSharding(topo.devices[0])
+    c = engine.cache_cfg
+    lane = jax.ShapeDtypeStruct((576,), jnp.int32, sharding=one)
+    tables = jax.ShapeDtypeStruct((c.max_seqs, c.pages_per_seq), jnp.int32,
+                                  sharding=one)
+    text = jax.jit(engine._mixed_impl).lower(
+        _sds(engine._step_params, one), _sds(engine._device_pool(), one),
+        lane, lane, lane, lane, tables, lane, lane).compile().as_text()
+    engine.close()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "grouped_ffn" in line]
+    assert len(calls) == layers, len(calls)
+    for i in range(layers):
+        assert sum(f"serve_step/layer{i}/experts/" in line
+                   for line in calls) == 1
+    assert "ragged-dot" not in text
+    assert not re.search(r"(bf16|f32)\[4608,1024\]", text)

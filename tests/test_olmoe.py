@@ -293,6 +293,9 @@ def test_mixed_step_lowers_with_the_expert_scopes(engine):
                  "serve_step/sample/"):
         assert path in text, path
     assert "/ffn/" not in text
+    # an f32 engine takes the kernel's twin, three grouped matmuls
+    # (tests/test_grouped_ffn_kernel.py lowers the step on the kernel)
+    assert engine.expert_impl == "ragged_dot"
     assert "serve_step/layer0/experts/ragged_dot" in text
 
 
