@@ -868,7 +868,8 @@ def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_vmem_limit(block_bytes)),
         interpret=interpret,
-        name="paged_ragged_v2",
+        # the device trace tells the two lists' calls apart by name
+        name="paged_ragged_v2_window" if window else "paged_ragged_v2",
     )(work.tile, work.blk, work.meta, work.pages, *args)
     # (tile, slab, (group, row), (head, dim)) -> (lane of the step,
     # query head, dim)

@@ -27,6 +27,7 @@ model is not served on.
 from __future__ import annotations
 
 import math
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -139,6 +140,11 @@ class Description:
     @property
     def attn_scale(self) -> float:
         return 1.0 / math.sqrt(self.head_dim)
+
+    def attn_calls(self) -> Tuple[int, int]:
+        """(paged attention calls a step that walk the full pages' work
+        list, calls that walk the window layers' list)."""
+        return self.num_layers, 0
 
     def refuse(self, *, tp: int = 1, adapters: bool = False,
                speculation: bool = False, prefix_cache: bool = False,
@@ -497,6 +503,11 @@ class Phi4Flash(Description):
     @property
     def paged_layers(self) -> int:
         return 1
+
+    def attn_calls(self) -> Tuple[int, int]:
+        # the full layer's own call and every cross layer's read its
+        # pages; each window layer reads its ring
+        return 1 + self.kinds.count(CROSS), len(self.window_layers)
 
     def embed(self, params, tokens, positions):
         return jnp.take(params["tok_embed"]["kernel"], tokens, axis=0,

@@ -2739,6 +2739,12 @@ class ServeEngine:
         return out
 
 
+# the step's fixed shape against its live work, counted in
+# ServeSession._pack: StepEvents attributes and `dispatch` span arguments
+LIVE_COUNTS = ("grid_steps", "live_steps", "live_rows", "lanes",
+               "emitters")
+
+
 class StepEvents:
     """What one :meth:`ServeSession.step` did — the router tier's
     window into a replica's progress (serve/router.py advances each
@@ -2750,7 +2756,15 @@ class StepEvents:
     ``kv_bytes_read`` the K/V page bytes the step's attention kernel
     calls fetch, ``attn_items`` / ``attn_rows`` the work items ONE of
     those calls runs for the live lanes and the query rows they hold
-    (rows / items: how often lanes share an item), ``topv`` / ``topi``
+    (rows / items: how often lanes share an item); over ALL of the
+    step's paged calls (arch.attn_calls: each walks the full pages'
+    list or the window layers'), ``grid_steps`` is the grid steps the
+    device walks whatever is live (the lists' static lengths),
+    ``live_steps`` those that hold a live lane's work item and
+    ``live_rows`` the query rows in them (an item has room for
+    Q_ROWS); ``lanes`` is the step's fixed width and ``emitters`` the
+    lanes whose logits anyone reads (the head and the sampler run over
+    all ``lanes``); ``topv`` / ``topi``
     the step's fetched (lanes, k) top-k logits and their token ids
     and ``emit_lanes`` the first lane of each entry of ``emitted``
     (an entry's tokens come from that lane and the ones after it:
@@ -2778,7 +2792,7 @@ class StepEvents:
     __slots__ = ("dispatched", "step_index", "plan", "emitted",
                  "finished", "ctx_mean", "wall_s", "host_reload_s",
                  "kv_bytes_read", "attn_items", "attn_rows", "topv", "topi",
-                 "emit_lanes",
+                 "emit_lanes", *LIVE_COUNTS,
                  "expert_counts", "expert_slots", "expert_dropped",
                  "experts_touched", "expert_bytes", "expert_load_max",
                  "state_bytes", "ssm_runs", "window_kv_bytes",
@@ -2802,6 +2816,8 @@ class StepEvents:
         self.kv_bytes_read = 0
         self.attn_items = 0
         self.attn_rows = 0
+        for key in LIVE_COUNTS:
+            setattr(self, key, 0)
         self.topv = self.topi = None
         self.emit_lanes: List[int] = []
         self.expert_counts = None
@@ -2983,7 +2999,9 @@ class ServeSession:
         with a visible length of 1). -> (arrays in dispatch order,
         lane_adapters or None, live lanes, emitters, spec_emitters,
         the paged kernel's work for these lanes: `work_items` of one
-        call plus `kv_bytes`, what all layers' calls fetch)."""
+        call plus `kv_bytes`, what all layers' calls fetch, and
+        LIVE_COUNTS, the step's fixed shape against its live work over
+        all of its calls)."""
         eng = self.eng
         cache = eng.cache
         t_w = eng.mixed_width
@@ -3047,32 +3065,44 @@ class ServeSession:
                 f"the kernel's grid holds {work['grid']}")
         page_bytes = kv_page_bytes(ps, eng.kv_heads, eng.kv_head_dim,
                                    c.kv_itemsize, eng.kv_quantized)
+        arch = eng.arch
+        full_calls, window_calls = arch.attn_calls()
+        lists = [(full_calls, work)]        # (calls that walk it, list)
         if c.hybrid is None:
             work["kv_bytes"] = (
-                eng.num_layers * work["page_fetches"] * page_bytes)
-            return (arrays, lane_adapters, lane, emitters, spec_emitters,
-                    work)
-        # a model of several mixer kinds: the full layer's pages are
-        # fetched by its own call and by every cross layer's; the
-        # window layers' calls walk the rings under the window's list;
-        # a scan reads and writes one state and one tail a RUN
-        arch = eng.arch
-        ring = work_items(
-            lane_lens, lane_slots, self._ring_tables, page_size=ps,
-            block_kv_pages=eng.attn_block_pages,
-            max_items=eng.window_max_items, live_lanes=lane,
-            window=arch.window)
-        if ring["total"] > ring["grid"]:
-            raise RuntimeError(
-                f"the plan makes {ring['total']} window work items, "
-                f"the kernel's grid holds {ring['grid']}")
-        readers = 1 + arch.kinds.count(CROSS)
-        work["full_kv_bytes"] = readers * work["page_fetches"] * page_bytes
-        work["window_kv_bytes"] = (len(arch.window_layers)
-                                   * ring["page_fetches"] * page_bytes)
-        work["kv_bytes"] = work["full_kv_bytes"] + work["window_kv_bytes"]
-        work["ssm_runs"] = len(plan.chunks)
-        work["state_bytes"] = 2 * len(plan.chunks) * c.hybrid.state_bytes
+                full_calls * work["page_fetches"] * page_bytes)
+        else:
+            # a model of several mixer kinds: the full layer's pages
+            # are fetched by its own call and by every cross layer's;
+            # the window layers' calls walk the rings under the
+            # window's list; a scan reads and writes one state and one
+            # tail a RUN
+            ring = work_items(
+                lane_lens, lane_slots, self._ring_tables, page_size=ps,
+                block_kv_pages=eng.attn_block_pages,
+                max_items=eng.window_max_items, live_lanes=lane,
+                window=arch.window)
+            if ring["total"] > ring["grid"]:
+                raise RuntimeError(
+                    f"the plan makes {ring['total']} window work items, "
+                    f"the kernel's grid holds {ring['grid']}")
+            lists.append((window_calls, ring))
+            work["full_kv_bytes"] = (full_calls * work["page_fetches"]
+                                     * page_bytes)
+            work["window_kv_bytes"] = (window_calls * ring["page_fetches"]
+                                       * page_bytes)
+            work["kv_bytes"] = (work["full_kv_bytes"]
+                                + work["window_kv_bytes"])
+            work["ssm_runs"] = len(plan.chunks)
+            work["state_bytes"] = (2 * len(plan.chunks)
+                                   * c.hybrid.state_bytes)
+        # the step's fixed shape against its live work (LIVE_COUNTS):
+        # every call walks its list's whole grid whatever is live
+        work.update(
+            grid_steps=sum(n * w["grid"] for n, w in lists),
+            live_steps=sum(n * w["items"] for n, w in lists),
+            live_rows=sum(n * w["rows"] for n, w in lists),
+            lanes=t_w, emitters=len(emitters) + len(spec_emitters))
         return arrays, lane_adapters, lane, emitters, spec_emitters, work
 
     def _count_experts(self, ev: StepEvents, counts: np.ndarray,
@@ -3155,12 +3185,13 @@ class ServeSession:
              work) = self._pack(plan)
             ev.kv_bytes_read = work["kv_bytes"]
             ev.attn_items, ev.attn_rows = work["items"], work["rows"]
-            hybrid_args = {}
+            counted = LIVE_COUNTS
             if c.hybrid is not None:
-                for key in ("state_bytes", "ssm_runs", "window_kv_bytes",
-                            "full_kv_bytes"):
-                    setattr(ev, key, work[key])
-                    hybrid_args[key] = work[key]
+                ev.ssm_runs = work["ssm_runs"]
+                counted += ("state_bytes", "window_kv_bytes",
+                            "full_kv_bytes")
+            for key in counted:
+                setattr(ev, key, work[key])
         with timed(track, "drain"):
             # land any adapters this plan admitted BEFORE their lanes
             # dispatch — the planning-visible load stall, not a
@@ -3181,7 +3212,7 @@ class ServeSession:
                 "decode": plan.num_decode_lanes,
                 "kv_bytes": ev.kv_bytes_read,
                 "items": ev.attn_items, "rows": ev.attn_rows,
-                **hybrid_args}):
+                **{key: work[key] for key in counted}}):
             greedy, topv, topi, counts = eng._dispatch_mixed(
                 *dev, lane_adapters=dev_adapters)
         with timed(track, "fetch"):

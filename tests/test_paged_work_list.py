@@ -251,6 +251,124 @@ def test_no_plan_passes_the_engine_s_bound():
     assert any(w["rows"] == w["items"] > 0 for w, _ in seen)  # decodes
 
 
+# ------------------------- the step's fixed shape against its live work
+def _count_engine(kind, **kw):
+    """A small engine of each kind of `arch.attn_calls`: every layer on
+    the one list, or a hybrid model whose window layers walk a second
+    one (speculation off: an emitter is one lane)."""
+    from flexflow_tpu.config import CompMode, FFConfig
+    from flexflow_tpu.serve import ServeEngine
+
+    cfg = FFConfig(batch_size=1, seed=5, kv_page_size=4, kv_num_pages=65,
+                   serve_max_seqs=4, serve_prefill_budget=12,
+                   serve_spec_decode=False, serve_prefix_cache=False)
+    if kind == "hybrid":
+        from flexflow_tpu.models.phi4flash import build_phi4flash_lm
+        lm = build_phi4flash_lm(cfg, vocab_size=61, max_seq_len=64,
+                                hidden=32, num_heads=4, num_kv_heads=2,
+                                num_layers=8, ff_dim=48, window=8)
+    else:
+        from flexflow_tpu.models.transformer import build_transformer_lm
+        lm = build_transformer_lm(cfg, vocab_size=61, max_seq_len=64,
+                                  hidden=32, num_heads=4, num_layers=3,
+                                  ff_dim=64)
+    lm.compile(comp_mode=CompMode.INFERENCE)
+    return ServeEngine(lm, **kw)
+
+
+@pytest.mark.parametrize("kind", ["opt", "hybrid"])
+def test_every_step_counts_its_fixed_shape_against_its_live_work(
+        kind, monkeypatch):
+    """`_pack` walks each list once (as before the counts existed) and
+    sums what `work_items` answered over the calls that walk it; the
+    `dispatch` span carries the same numbers."""
+    from flexflow_tpu.serve import engine as E
+    from flexflow_tpu.utils.telemetry import Telemetry
+
+    tel = Telemetry()
+    eng = _count_engine(kind, use_pallas=False, telemetry=tel)
+    full_calls, window_calls = eng.arch.attn_calls()
+    assert (full_calls, window_calls) == \
+        ((3, 0) if kind == "opt" else (2, 2))       # 1 full + 1 cross
+    walked = []
+    real = E.work_items
+    monkeypatch.setattr(
+        E, "work_items",
+        lambda *a, **kw: walked.append(real(*a, **kw)) or walked[-1])
+    rng = np.random.RandomState(3)
+    steps = []
+    with E.ServeSession(eng) as s:
+        for n in (30, 3, 17, 40, 9, 25, 2, 33):
+            s.submit(list(rng.randint(1, 61, size=n)), 10)
+        while s.has_work():
+            del walked[:]
+            ev = s.step()
+            if ev is None or not ev.dispatched:
+                continue
+            lists = list(walked)    # `_pack` adds keys, changes none
+            assert len(lists) == 1 + bool(window_calls)
+            calls = [full_calls, window_calls][:len(lists)]
+            grids = [eng.attn_max_items, eng.window_max_items]
+            assert ev.grid_steps == sum(
+                n * g for n, g in zip(calls, grids))
+            for key, item in (("grid_steps", "grid"),
+                              ("live_steps", "items"),
+                              ("live_rows", "rows")):
+                assert getattr(ev, key) == sum(
+                    n * w[item] for n, w in zip(calls, lists)), key
+            assert (ev.attn_items, ev.attn_rows) == (
+                lists[0]["items"], lists[0]["rows"])    # ONE call's
+            assert 0 < ev.live_steps <= ev.grid_steps
+            assert 0 < ev.live_rows <= ev.live_steps * Q_ROWS
+            assert ev.lanes == eng.mixed_width
+            assert ev.emitters == len(ev.emit_lanes) <= ev.lanes
+            steps.append({k: getattr(ev, k) for k in E.LIVE_COUNTS})
+    assert len(steps) > 10
+    assert any(st["live_rows"] > st["live_steps"] for st in steps)  # chunk
+    assert any(st["emitters"] == 0 for st in steps)     # mid-prompt chunk
+    spans = [e[6] for e in tel.events
+             if e[0] == "X" and e[2] == "dispatch"]
+    assert [{k: a[k] for k in E.LIVE_COUNTS} for a in spans] == steps
+    assert not any("ssm_runs" in a for a in spans)
+    assert ("state_bytes" in spans[0]) == (kind == "hybrid")
+
+
+def test_the_row_fill_metrics_scale_by_the_kernel_s_rows_an_item():
+    """`attn_row_fill.*` reads live_rows / live_steps: its files' scale
+    is 100 over the rows an item has room for."""
+    import glob
+    import json
+    import os
+    here = os.path.dirname(os.path.abspath(__file__))
+    files = glob.glob(os.path.join(
+        here, "..", "benchmark", "metrics", "attn_row_fill.*.json"))
+    assert len(files) == 4
+    for path in files:
+        with open(path) as f:
+            args = json.load(f)["args"]
+        assert (args["num"], args["den"]) == ("live_rows", "live_steps")
+        assert args["scale"] * Q_ROWS == 100.0
+
+
+@pytest.mark.parametrize("kind", ["opt", "hybrid"])
+def test_the_window_list_s_calls_have_a_name_of_their_own(kind):
+    """The device trace tells the two lists' calls apart by the
+    kernel's name; a model with one list lowers with the one name."""
+    import re
+
+    import jax
+    eng = _count_engine(kind, interpret=True)
+    c = eng.cache_cfg
+    z = np.zeros((eng.mixed_width,), np.int32)
+    pts = np.zeros((c.max_seqs, c.pages_per_seq), np.int32)
+    text = jax.jit(eng._mixed_impl).lower(
+        eng._step_params, eng._device_pool(), z, z, z, z, pts, z, z + 1
+    ).as_text(debug_info=True)
+    names = set(re.findall(r"paged_ragged_v2\w*", text))
+    assert names == ({"paged_ragged_v2", "paged_ragged_v2_window"}
+                     if kind == "hybrid" else {"paged_ragged_v2"})
+
+
 # -------------------------------------------- the kernel on those layouts
 def _pools(rng, h, d, fmt):
     num_pages = 1 + SEQS * PP

@@ -37,6 +37,17 @@ def test_trace_reduce(check):
     getattr(_load("check_trace_reduce"), check)()
 
 
+@pytest.mark.parametrize("check", [
+    "check_span_ratio", "check_kernel_time_per_count",
+    "check_host_gap_phase", "check_metric_files"])
+def test_live_counters(check):
+    """`benchmark/check_live_counters.py`: the readers of the step's
+    live counts and of the host's gaps by phase, on hand-made
+    operations and spans, and their metric files on runs that hold
+    nothing for them."""
+    getattr(_load("check_live_counters"), check)()
+
+
 def test_every_metric_of_the_benchmark_has_its_files():
     """Each per-layer entry names a metric file, and that a reader."""
     import json
