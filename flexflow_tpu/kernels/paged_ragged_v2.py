@@ -37,6 +37,10 @@ This module rebuilds the kernel along the paper's lines:
     same K/V block in one item. Every row is masked by its own
     `lane_lens` entry; grouping decides what is fetched together, never
     what a row may see.
+  * A BODY FOR ONE LANE: where a tile's product is 128 rows or more
+    (four query heads a key/value head), the item of a one-lane run —
+    a decode lane — computes on the 16 rows that hold the lane, chosen
+    per item at run time inside the one call (`has_short_body`).
   * RAGGED SKIPPING: a run has items only for the kv-blocks that start
     below its longest lane, and a page slot past its last live page
     keeps the page it held (no DMA, positions masked).
@@ -473,11 +477,20 @@ def kv_page_bytes(page_size, num_heads, head_dim, kv_itemsize, quantized):
     return per_page
 
 
+def has_short_body(group: int, q_rows: int = Q_ROWS) -> bool:
+    """Whether a call whose query has `group` heads a key/value head
+    holds the one-lane item's body beside the whole-tile one (a rule on
+    the call's shapes alone; the kernel section says why)."""
+    rows = group * q_rows       # of one head in the whole-tile product
+    return (rows >= SHORT_MIN_ROWS and rows % SHORT_ROWS == 0
+            and SHORT_ROWS % group == 0)
+
+
 def work_items(lane_lens, lane_slots, page_tables, *, page_size: int,
                block_kv_pages: int = 1, q_rows: int = Q_ROWS,
                max_items: Optional[int] = None,
                live_lanes: Optional[int] = None,
-               window: int = 0) -> Dict[str, int]:
+               window: int = 0, group: int = 1) -> Dict[str, int]:
     """What one call of the kernel has to do for these lanes (numpy;
     host side, no device work): `grid` the list's static length
     (`max_items`, else the bound for any arrays), `total` the items of
@@ -487,7 +500,9 @@ def work_items(lane_lens, lane_slots, page_tables, *, page_size: int,
     lanes behind them are the step's inactive padding) — `items` and
     the query `rows` they hold. rows / items is how often sharing
     engages: 1.0 when every run is one decode lane, near q_rows inside
-    a long chunk.
+    a long chunk. `short_items` are those of them that take the
+    one-lane body: the items of one-lane runs where a call at `group`
+    query heads a key/value head has that body, else 0.
 
     `page_fetches` walks the list the kernel is given: page slot i of
     item w is one pipelined operand whose block index is `pages[w, i]`,
@@ -513,8 +528,9 @@ def work_items(lane_lens, lane_slots, page_tables, *, page_size: int,
     lo, hi = meta & 0xFF, (meta >> 8) & 0xFF    # the run's rows
     first = tile * q_rows + lo                  # its first lane
     mine = first < live
+    short = mine & (hi - lo == 1) & has_short_body(group, q_rows)
     return {"grid": grid, "total": len(tile),
-            "items": int(np.sum(mine)),
+            "items": int(np.sum(mine)), "short_items": int(np.sum(short)),
             "rows": int(np.sum(np.minimum(hi - lo, live - first)[mine])),
             "page_fetches": bp + int(np.sum(pages[1:] != pages[:-1]))}
 
@@ -573,6 +589,21 @@ def kv_read_bytes(lane_lens, lane_slots, page_tables, *, page_size: int,
 # whole loop unrolled (0.73 against 0.70 ms for a decode-only call at
 # the serving cell; 1 slab a trip: 0.97) at a quarter of its equations
 SLAB_UNROLL = 4
+# Rows of each head that the body of a one-lane item works on: one
+# packed bf16 tile (two f32 tiles), the aligned stretch of the tile's
+# rows that holds the lane's `group` query heads. A call gets that body
+# where the whole-tile product has SHORT_MIN_ROWS rows or more (4 query
+# heads a key/value head at Q_ROWS lanes: 128 rows for one lane's 4);
+# below that the calls keep the one body and the program they had.
+# The MXU takes its products in the order they are written, so a slab's
+# two wait for each other through its softmax: the short body, whose
+# tiles are small enough to hold, writes each stage for up to
+# SHORT_ABREAST slabs side by side. us a one-lane item at Phi's
+# geometry (10 slabs; whole tile 5.6): 1 abreast 4.2, 2 2.1, 5 1.1,
+# 10 0.6 (tests_tpu/test_paged_short_tpu.py; PERF.md section 6, PR 40)
+SHORT_ROWS = 16
+SHORT_ABREAST = 16
+SHORT_MIN_ROWS = 128
 _MASK = -0.5 * float(jnp.finfo(jnp.float32).max)  # finite: m stays finite
 _HIGHEST = jax.lax.Precision.HIGHEST
 _NT = (((1,), (1,)), ((), ()))                    # a @ b.T
@@ -616,7 +647,7 @@ def _by_head(x, q_rows, heads, head_dim):
 def _ragged_v2_kernel(tile_ref, blk_ref, meta_ref, pages_ref, q2_ref,
                       lens_ref, *refs, page_size, block_pages, q_rows,
                       heads, head_dim, slabs, scale, quantized, exact,
-                      group=1, window=0):
+                      group=1, window=0, short=False):
     """One work item: the rows [lo, hi) of a tile attend one kv-block
     of their sequence. Page refs arrive head-PACKED as (1, ps, H*D)
     blocks (plus (1, ps, H) scale blocks when quantized). The grid runs
@@ -624,9 +655,14 @@ def _ragged_v2_kernel(tile_ref, blk_ref, meta_ref, pages_ref, q2_ref,
     item to its last. The slabs are a loop inside the item (traced
     once: the body is not unrolled in Python). Grouped heads: the
     `group` query heads of a key/value head are `group` times the rows
-    (row (g * group + j) * q_rows + r of a slab is row r's query head
-    j of the slab's head g), so one product serves them all; under a
-    `window` a row sees its last `window` positions."""
+    (row (g * q_rows + r) * group + j of a slab is row r's query head
+    j of the slab's head g: a lane's rows lie together, and `lens_ref`
+    holds a lane's length once a query head), so one product serves
+    them all; under a `window` a row sees its last `window` positions.
+    With `short` a live item whose run is ONE lane takes a second body:
+    the same mathematics on the aligned SHORT_ROWS rows of each head
+    that hold the lane's `group`, the tile's other rows untouched (as
+    the whole-tile body leaves them: they see nothing of the item)."""
     del tile_ref, pages_ref                  # read by the index maps
     per_page = 4 if quantized else 2
     n_kv = per_page * block_pages
@@ -637,7 +673,6 @@ def _ragged_v2_kernel(tile_ref, blk_ref, meta_ref, pages_ref, q2_ref,
     else:
         o_ref, m_ref, l_ref, acc_ref = refs[n_kv:]
     g, d = heads, head_dim
-    qr = q_rows                 # rows of the tile (the lens' block)
     qb = group * q_rows         # rows of one head of a slab
     w_lanes = g * d
     bs = block_pages * page_size
@@ -647,17 +682,28 @@ def _ragged_v2_kernel(tile_ref, blk_ref, meta_ref, pages_ref, q2_ref,
     w = pl.program_id(0)
     meta = meta_ref[w]
 
-    def each_slab(body):
-        """body(slab, its lanes) for every slab: a loop whose body
-        holds SLAB_UNROLL slabs, independent of each other, so that
-        their loads, matmuls and exponentials overlap."""
-        u = math.gcd(slabs, SLAB_UNROLL)
+    def each_slab(*stages, abreast=0):
+        """stage(slab, its lanes, what the stage before returned) for
+        every slab, the stages in turn: a loop whose body holds
+        SLAB_UNROLL slabs, independent of each other, so that their
+        loads, matmuls and exponentials overlap. `abreast` > 0: that
+        many slabs a trip, and a stage runs for all of them before the
+        next one starts."""
+        u = math.gcd(slabs, SLAB_UNROLL) if not abreast else max(
+            n for n in range(1, abreast + 1) if slabs % n == 0)
+
+        def lanes(slab):
+            return pl.ds(pl.multiple_of(slab * w_lanes, w_lanes), w_lanes)
 
         def step(i, carry):
-            for j in range(u):
-                slab = i * u + j
-                body(slab, pl.ds(pl.multiple_of(slab * w_lanes, w_lanes),
-                                 w_lanes))
+            side = u if abreast else 1      # slabs a stage runs for
+            for j in range(0, u, side):
+                at = [i * u + k for k in range(j, j + side)]
+                at = [(slab, lanes(slab)) for slab in at]
+                vals = [None] * side
+                for stage in stages:
+                    vals = [stage(slab, cols, v)
+                            for (slab, cols), v in zip(at, vals)]
             return carry
         jax.lax.fori_loop(0, slabs // u, step, 0)
 
@@ -667,17 +713,40 @@ def _ragged_v2_kernel(tile_ref, blk_ref, meta_ref, pages_ref, q2_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when((meta & _LIVE) != 0)
-    def _accumulate():
-        lo, hi = meta & 0xFF, (meta >> 8) & 0xFF
+    def accumulate(lo, hi, start=None):
+        """The item of the run at rows [lo, hi) of a head's qb, over
+        the SHORT_ROWS rows from `start` of every head of a slab (None:
+        all qb of them, each ref read whole)."""
+        nr = qb if start is None else SHORT_ROWS
+
+        def rows(i=0):
+            return pl.ds(pl.multiple_of(i * qb + start, nr), nr)
+
+        def load(ref, *lead, heads=g):
+            """The item's rows of the `heads` row blocks of ref[lead]."""
+            if start is None:
+                return ref[lead]
+            return jnp.concatenate([ref[(*lead, rows(i))]
+                                    for i in range(heads)], axis=0)
+
+        def store(ref, slab, x, heads=g):
+            if start is None:
+                ref[slab] = x
+                return
+            for i in range(heads):
+                ref[slab, rows(i)] = x[i * nr:(i + 1) * nr]
+
         base = blk_ref[w] * bs
         # each row's visible length; 0 for the tile's rows outside the
         # run (they belong to other items), stacked once per head
-        row = jax.lax.broadcasted_iota(jnp.int32, (qr, 128), 0)
-        vis = jnp.where((row >= lo) & (row < hi), lens_ref[...], 0)
-        vis = jnp.concatenate([vis] * (g * group), axis=0)[:, :1]
-        pos = base + jax.lax.broadcasted_iota(jnp.int32, (g * qb, bs), 1)
-        seen = pos < vis                                     # (G*QB, bs)
+        row = jax.lax.broadcasted_iota(jnp.int32, (nr, 128), 0)
+        if start is not None:
+            row = row + start
+        vis = jnp.where((row >= lo) & (row < hi),
+                        load(lens_ref, heads=1), 0)
+        vis = jnp.concatenate([vis] * g, axis=0)[:, :1]
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, (g * nr, bs), 1)
+        seen = pos < vis                                     # (G*nr, bs)
         if window:
             seen &= pos >= vis - window
 
@@ -691,39 +760,45 @@ def _ragged_v2_kernel(tile_ref, blk_ref, meta_ref, pages_ref, q2_ref,
                 preferred_element_type=jnp.float32)
 
         def head_rows(sc_ref, slab):
-            """Rows slab * G + g of (Hp, bs), each over its q_rows."""
+            """Rows slab * G + g of (Hp, bs), each over its rows."""
             return jnp.concatenate(
                 [jnp.broadcast_to(sc_ref[pl.ds(slab * g + i, 1), :],
-                                  (qb, bs)) for i in range(g)], axis=0)
+                                  (nr, bs)) for i in range(g)], axis=0)
 
         if quantized:
             scales_on_lanes(1, ks_ref)
             scales_on_lanes(3, vs_ref)
 
-        def one_slab(slab, cols):
-            def block(j):
-                return _stack([kv_refs[per_page * i + j][0, :, cols]
-                               for i in range(block_pages)], op_dtype)
+        def block(j, cols):
+            return _stack([kv_refs[per_page * i + j][0, :, cols]
+                           for i in range(block_pages)], op_dtype)
 
+        def scores(slab, cols, _):
             s = jax.lax.dot_general(
-                q2_ref[0, slab], block(0), _NT, precision=prec,
-                preferred_element_type=jnp.float32)      # (G*QB, bs)
+                load(q2_ref, 0, slab), block(0, cols), _NT, precision=prec,
+                preferred_element_type=jnp.float32)      # (G*nr, bs)
             if quantized:
                 s = s * head_rows(ks_ref, slab)
-            s = jnp.where(seen, s * scale, _MASK)
-            m_prev = m_ref[slab]                         # (G*QB, W)
+            return jnp.where(seen, s * scale, _MASK)
+
+        def softmax(slab, cols, s):
+            m_prev = load(m_ref, slab)                   # (G*nr, W)
             m_new = jnp.maximum(m_prev,
                                 jnp.max(s, axis=1, keepdims=True))
             # a row that sees nothing here has s == m_new == _MASK:
             # exp(0) = 1, so the mask zeroes p itself
             p = jnp.where(seen, jnp.exp(s - m_new[:, :1]), 0.0)
             alpha = jnp.exp(m_prev - m_new)
-            m_ref[slab] = m_new
-            l_ref[slab] = l_ref[slab] * alpha + jnp.sum(
-                p, axis=1, keepdims=True)
+            store(m_ref, slab, m_new)
+            store(l_ref, slab, load(l_ref, slab) * alpha + jnp.sum(
+                p, axis=1, keepdims=True))
             if quantized:
                 p = p * head_rows(vs_ref, slab)
-            v = block(2 if quantized else 1)             # (bs, W)
+            return p, alpha
+
+        def weigh(slab, cols, p_alpha):
+            p, alpha = p_alpha
+            v = block(2 if quantized else 1, cols)       # (bs, W)
             if exact:
                 pv = jnp.dot(p, v, precision=_HIGHEST,
                              preferred_element_type=jnp.float32)
@@ -732,15 +807,35 @@ def _ragged_v2_kernel(tile_ref, blk_ref, meta_ref, pages_ref, q2_ref,
                 p_lo = (p - p_hi.astype(jnp.float32)).astype(jnp.bfloat16)
                 pv = jnp.dot(jnp.concatenate([p_hi, p_lo], axis=0), v,
                              preferred_element_type=jnp.float32)
-                pv = pv[:g * qb] + pv[g * qb:]
-            acc_ref[slab] = (acc_ref[slab] * _by_head(alpha, qb, g, d)
-                             + _by_head(pv, qb, g, d))
+                pv = pv[:g * nr] + pv[g * nr:]
+            store(acc_ref, slab,
+                  load(acc_ref, slab, heads=1) * _by_head(alpha, nr, g, d)
+                  + _by_head(pv, nr, g, d), heads=1)
 
-        each_slab(one_slab)
+        each_slab(scores, softmax, weigh,
+                  abreast=0 if start is None else SHORT_ABREAST)
+
+    @pl.when((meta & _LIVE) != 0)
+    def _accumulate():
+        lo, hi = meta & 0xFF, (meta >> 8) & 0xFF
+        if group > 1:           # the run's rows among a head's qb
+            lo, hi = lo * group, hi * group
+        if not short:
+            accumulate(lo, hi)
+            return
+        one_lane = hi - lo == group
+
+        @pl.when(one_lane)
+        def _its_rows():
+            accumulate(lo, hi, lo // SHORT_ROWS * SHORT_ROWS)
+
+        @pl.when(jnp.logical_not(one_lane))
+        def _whole_tile():
+            accumulate(lo, hi)
 
     @pl.when((meta & _LAST) != 0)
     def _emit():
-        def one_slab(slab, cols):
+        def one_slab(slab, cols, _):
             o_ref[0, slab] = (acc_ref[slab] / _by_head(
                 l_ref[slab], qb, g, d)).astype(o_ref.dtype)
 
@@ -758,10 +853,11 @@ def _vmem_limit(block_bytes: int) -> int:
 # jitted on its own: the engine's layers make the same call 24 times,
 # and tracing and lowering the kernel body is host time before the
 # compile cache can even be asked — a nested jit pays it once
-@functools.partial(jax.jit,
-                   static_argnames=("scale", "interpret", "window"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret",
+                                             "window", "short"))
 def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
-                      interpret, k_scales=None, v_scales=None, window=0):
+                      interpret, k_scales=None, v_scales=None, window=0,
+                      short=False):
     t, hq, d = q.shape
     npages, ps, h = k_pages.shape[:3]
     group = hq // h             # query heads a key/value head
@@ -783,8 +879,10 @@ def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
     # makes a copy for it (PERF.md section 5)
     kp = k_pages.reshape(npages, ps, hd)
     vp = v_pages.reshape(npages, ps, hd)
-    # q2[tile, slab, (g * group + j) * qb + r, g' * D + c] = q[tile * qb
-    # + r, (slab * G + g) * group + j, c] where g' == g, else 0. One
+    # q2[tile, slab, (g * qb + r) * group + j, g' * D + c] = q[tile * qb
+    # + r, (slab * G + g) * group + j, c] where g' == g, else 0: a
+    # lane's `group` rows lie together, so a one-lane item finds them
+    # in one aligned stretch. One
     # group is written without the group's unit dimension: XLA lays the
     # two forms out differently around the call, and the cells of the
     # benchmark that run one group are held to the program they had
@@ -797,7 +895,7 @@ def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
               ).reshape(tiles, slabs, g * qb, w_lanes)
     else:
         qp = qp.reshape(tiles, qb, slabs, g, group, d).transpose(
-            0, 2, 3, 4, 1, 5)
+            0, 2, 3, 1, 4, 5)
         q2 = (qp[:, :, :, :, :, None, :].astype(op_dtype)
               * jnp.eye(g, dtype=op_dtype)[None, None, :, None, None, :,
                                            None]
@@ -811,10 +909,12 @@ def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
     def tile_index(w, tile, blk, meta, pages):
         return (tile[w], 0)
 
+    # a lane's length once a row of its group
+    lens = work.lens if group == 1 else jnp.repeat(work.lens, group, axis=0)
     in_specs = [pl.BlockSpec((1, slabs, g * qe, w_lanes),
                              lambda w, tile, *_: (tile[w], 0, 0, 0)),
-                pl.BlockSpec((qb, 128), tile_index)]
-    args = [q2, work.lens]
+                pl.BlockSpec((qe, 128), tile_index)]
+    args = [q2, lens]
     for i in range(bp):
         imap = page_index(i)
         in_specs.append(pl.BlockSpec((1, ps, hd), imap))
@@ -834,7 +934,8 @@ def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
     kern = functools.partial(
         _ragged_v2_kernel, page_size=ps, block_pages=bp, q_rows=qb,
         heads=g, head_dim=d, slabs=slabs, scale=scale,
-        quantized=quantized, exact=exact, group=group, window=window)
+        quantized=quantized, exact=exact, group=group, window=window,
+        short=short)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,          # the work list
         grid=(n,),
@@ -875,8 +976,8 @@ def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
     # query head, dim)
     if group == 1:
         return out.transpose(0, 2, 1, 3).reshape(tiles * qb, h, d)[:t]
-    out = out.reshape(tiles, slabs, group, qb, g, d).transpose(
-        0, 3, 1, 4, 2, 5)
+    out = out.reshape(tiles, slabs, qb, group, g, d).transpose(
+        0, 2, 1, 4, 3, 5)
     return out.reshape(tiles * qb, hq, d)[:t]
 
 
@@ -992,4 +1093,5 @@ def paged_attention_ragged_v2(q, k_pages, v_pages, page_tables,
                                window=window)
     return _ragged_v2_pallas(
         q, k_pages, v_pages, work, scale, impl == PALLAS_INTERPRET,
-        k_scales=k_scales, v_scales=v_scales, window=int(window))
+        k_scales=k_scales, v_scales=v_scales, window=int(window),
+        short=has_short_body(q.shape[1] // k_pages.shape[2], work.q_rows))

@@ -2741,8 +2741,8 @@ class ServeEngine:
 
 # the step's fixed shape against its live work, counted in
 # ServeSession._pack: StepEvents attributes and `dispatch` span arguments
-LIVE_COUNTS = ("grid_steps", "live_steps", "live_rows", "lanes",
-               "emitters")
+LIVE_COUNTS = ("grid_steps", "live_steps", "short_steps", "live_rows",
+               "lanes", "emitters")
 
 
 class StepEvents:
@@ -2760,7 +2760,9 @@ class StepEvents:
     step's paged calls (arch.attn_calls: each walks the full pages'
     list or the window layers'), ``grid_steps`` is the grid steps the
     device walks whatever is live (the lists' static lengths),
-    ``live_steps`` those that hold a live lane's work item and
+    ``live_steps`` those that hold a live lane's work item,
+    ``short_steps`` those of them that take the kernel's one-lane body
+    (0 on a model whose calls do not hold it: has_short_body) and
     ``live_rows`` the query rows in them (an item has room for
     Q_ROWS); ``lanes`` is the step's fixed width and ``emitters`` the
     lanes whose logits anyone reads (the head and the sampler run over
@@ -2881,6 +2883,9 @@ class ServeSession:
         # steps whose packed lanes returned a non-finite top-k logit
         # (a NaN anywhere upstream of the head reaches them)
         self.nonfinite_steps = 0
+        # the paged calls' live grid steps so far, and those of them
+        # that took the kernel's one-lane body (stats_dict)
+        self.attn_steps = {"live": 0, "short": 0}
         # running totals of the expert layer's counters (expert_stats)
         self.expert_totals = {"steps": 0, "slots": 0, "dropped": 0,
                               "touched": 0, "bytes": 0}
@@ -3055,10 +3060,11 @@ class ServeSession:
         # made where the lanes are made), and the proof's check: a plan
         # whose items passed the grid's bound would lose work
         c = eng.cache_cfg
+        group = eng.num_heads // eng.kv_heads   # query heads a K/V head
         work = work_items(
             lane_lens, lane_slots, cache.page_tables, page_size=ps,
             block_kv_pages=eng.attn_block_pages,
-            max_items=eng.attn_max_items, live_lanes=lane)
+            max_items=eng.attn_max_items, live_lanes=lane, group=group)
         if work["total"] > work["grid"]:
             raise RuntimeError(
                 f"the plan makes {work['total']} attention work items, "
@@ -3081,7 +3087,7 @@ class ServeSession:
                 lane_lens, lane_slots, self._ring_tables, page_size=ps,
                 block_kv_pages=eng.attn_block_pages,
                 max_items=eng.window_max_items, live_lanes=lane,
-                window=arch.window)
+                window=arch.window, group=group)
             if ring["total"] > ring["grid"]:
                 raise RuntimeError(
                     f"the plan makes {ring['total']} window work items, "
@@ -3101,6 +3107,7 @@ class ServeSession:
         work.update(
             grid_steps=sum(n * w["grid"] for n, w in lists),
             live_steps=sum(n * w["items"] for n, w in lists),
+            short_steps=sum(n * w["short_items"] for n, w in lists),
             live_rows=sum(n * w["rows"] for n, w in lists),
             lanes=t_w, emitters=len(emitters) + len(spec_emitters))
         return arrays, lane_adapters, lane, emitters, spec_emitters, work
@@ -3192,6 +3199,8 @@ class ServeSession:
                             "full_kv_bytes")
             for key in counted:
                 setattr(ev, key, work[key])
+            self.attn_steps["live"] += ev.live_steps
+            self.attn_steps["short"] += ev.short_steps
         with timed(track, "drain"):
             # land any adapters this plan admitted BEFORE their lanes
             # dispatch — the planning-visible load stall, not a
@@ -3283,6 +3292,7 @@ class ServeSession:
         c = self.eng.cache_cfg
         stats["cache_bytes_per_token"] = c.cache_bytes_per_token
         stats["cache_bytes_constant_per_seq"] = c.constant_bytes_per_seq
+        stats["attn_steps"] = dict(self.attn_steps)
         if self.eng.arch.experts:
             stats["experts"] = self.expert_stats()
         return stats

@@ -75,9 +75,22 @@ def _not_rising():
     return slots, lens, Q_ROWS + 3          # not a whole tile
 
 
+def _one_lane_rows():
+    """One-lane runs at the first, middle and last rows of a tile, a
+    chunk between them, then an empty tile, then a lone decode lane."""
+    slots = [1, 2, 3] + [4] * 20 + [5, 1, 2, 3, 5, 1, 2, 3, 5]
+    lens = [70, 3, 41] + list(range(11, 31)) + [9, 80, 17, 33, 64, 2, 55,
+                                                26, 79]
+    assert len(slots) == Q_ROWS
+    slots += [0] * (Q_ROWS + 7) + [2]
+    lens += [1] * (Q_ROWS + 7) + [61]
+    return slots, lens, 3 * Q_ROWS
+
+
 LAYOUTS = {"decode_tail": _decode_tail, "chunk_across_tiles":
            _chunk_across_tiles, "chunk_and_drafts": _chunk_and_drafts,
-           "neighbours": _neighbours, "not_rising": _not_rising}
+           "neighbours": _neighbours, "not_rising": _not_rising,
+           "one_lane_rows": _one_lane_rows}
 
 
 def _lanes(name):
@@ -163,6 +176,15 @@ def test_work_list_equals_a_brute_force_walk(layout, bp):
     assert got["items"] == len(mine)
     assert got["rows"] == sum(min(hi, live - t * Q_ROWS) - lo
                               for t, lo, hi in mine)
+    # the items the one-lane body takes, on a call that holds it (four
+    # query heads a key/value head) and on one that does not
+    assert got["short_items"] == 0
+    grouped = work_items(lens, slots, pt, page_size=PS, block_kv_pages=bp,
+                         max_items=bound, live_lanes=live, group=4)
+    assert grouped["short_items"] == sum(hi - lo == 1
+                                         for _, lo, hi in mine)
+    assert {k: v for k, v in grouped.items() if k != "short_items"} == \
+        {k: v for k, v in got.items() if k != "short_items"}
 
 
 def test_rows_per_item_says_how_often_lanes_share():
@@ -313,17 +335,25 @@ def test_every_step_counts_its_fixed_shape_against_its_live_work(
                 n * g for n, g in zip(calls, grids))
             for key, item in (("grid_steps", "grid"),
                               ("live_steps", "items"),
+                              ("short_steps", "short_items"),
                               ("live_rows", "rows")):
                 assert getattr(ev, key) == sum(
                     n * w[item] for n, w in zip(calls, lists)), key
             assert (ev.attn_items, ev.attn_rows) == (
                 lists[0]["items"], lists[0]["rows"])    # ONE call's
             assert 0 < ev.live_steps <= ev.grid_steps
+            assert 0 <= ev.short_steps <= ev.live_steps
             assert 0 < ev.live_rows <= ev.live_steps * Q_ROWS
             assert ev.lanes == eng.mixed_width
             assert ev.emitters == len(ev.emit_lanes) <= ev.lanes
             steps.append({k: getattr(ev, k) for k in E.LIVE_COUNTS})
+        assert s.stats_dict()["attn_steps"] == {
+            "live": sum(st["live_steps"] for st in steps),
+            "short": sum(st["short_steps"] for st in steps)}
     assert len(steps) > 10
+    # decode lanes are one-lane runs: the body they take is in a call of
+    # four query heads a key/value head and in no call of one
+    assert any(st["short_steps"] for st in steps) == (kind == "hybrid")
     assert any(st["live_rows"] > st["live_steps"] for st in steps)  # chunk
     assert any(st["emitters"] == 0 for st in steps)     # mid-prompt chunk
     spans = [e[6] for e in tel.events
@@ -369,6 +399,39 @@ def test_the_window_list_s_calls_have_a_name_of_their_own(kind):
                      if kind == "hybrid" else {"paged_ragged_v2"})
 
 
+@pytest.mark.parametrize("hq,h,d,conds", [
+    (4, 4, 64, 3),      # one group, two heads a slab (OPT's)
+    (2, 2, 128, 3),     # one group, one head a slab (OLMoE's)
+    (8, 2, 128, 5),     # four query heads a key/value head (Phi's)
+])
+def test_the_one_lane_body_is_in_a_call_by_its_shapes_alone(hq, h, d, conds):
+    """A call whose whole-tile product has under SHORT_MIN_ROWS rows
+    traces the kernel it always traced: init, accumulate, emit, and no
+    second body (the cells of one group are held to the program they
+    had); four query heads a key/value head add the one-lane body."""
+    import jax
+
+    from flexflow_tpu.kernels import paged_ragged_v2 as K
+    assert K.has_short_body(hq // h) == (conds == 5)
+    q = jnp.zeros((Q_ROWS, hq, d), jnp.bfloat16)
+    kp = jnp.zeros((1 + SEQS * PP, PS, h, d), jnp.bfloat16)
+    slots, lens = jnp.zeros(Q_ROWS, jnp.int32), jnp.ones(Q_ROWS, jnp.int32)
+    pt = jnp.asarray(_table(np.random.RandomState(0)))
+    work = build_work_list(pt, slots, lens, page_size=PS, block_pages=2)
+
+    def text(**kw):
+        return str(jax.make_jaxpr(lambda q, kp, work: K._ragged_v2_pallas(
+            q, kp, kp, work, 0.125, True, **kw))(q, kp, work))
+
+    by_rule = str(jax.make_jaxpr(
+        lambda q, kp, work: paged_attention_ragged_v2(
+            q, kp, kp, pt, slots, lens, scale=0.125, work=work,
+            interpret=True))(q, kp, work))
+    assert by_rule == text(short=conds == 5) != text(short=conds != 5)
+    assert by_rule.count("cond[") == conds
+    assert text() == text(short=False)      # a caller sets nothing
+
+
 # -------------------------------------------- the kernel on those layouts
 def _pools(rng, h, d, fmt):
     num_pages = 1 + SEQS * PP
@@ -412,6 +475,49 @@ def test_kernel_matches_the_jnp_twin_on_every_layout(layout, fmt, h, d,
         assert np.isfinite(out).all()   # the inactive lanes' rows too
         np.testing.assert_allclose(out, np.asarray(ref), rtol=2e-6,
                                    atol=2e-6)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5),
+                                       ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("window", [0, 24])
+def test_one_lane_items_on_their_own_rows_equal_the_twin(window, dtype,
+                                                         tol):
+    """Phi-4-mini-flash's served heads — 40 query heads over 10
+    key/value heads of 128 — on the layout whose first tile holds
+    one-lane runs at its first rows (the first starts the tile's
+    softmax), a chunk's run, more one-lane runs and one at its last row
+    (whose last block emits the tile), whose second tile is empty and
+    whose third holds a lone decode lane: the one-lane body and the
+    whole-tile body give the twin's answer, and each other's."""
+    from flexflow_tpu.kernels import paged_ragged_v2 as K
+    hq, h, d, bp = 40, 10, 128, 2
+    slots, lens, live, _ = _lanes("one_lane_rows")
+    rng = np.random.RandomState(window)
+    pt = _table(rng)
+    counts = work_items(lens, slots, pt, page_size=PS, block_kv_pages=bp,
+                        live_lanes=live, window=window, group=hq // h)
+    assert 0 < counts["short_items"] < counts["items"]
+    kp, vp, _ = _pools(rng, h, d, "float32")
+    kp, vp = kp.astype(dtype), vp.astype(dtype)
+    q = jnp.asarray(rng.randn(len(slots), hq, d), dtype)
+    pt, slots, lens = jnp.asarray(pt), jnp.asarray(slots), jnp.asarray(lens)
+    twin = np.asarray(paged_attention_ragged_v2(
+        q, kp, vp, pt, slots, lens, use_pallas=False, window=window,
+        scale=0.3), np.float32)
+    work = build_work_list(pt, slots, lens, page_size=PS, block_pages=bp,
+                           window=window)
+    whole, short = (np.asarray(K._ragged_v2_pallas(
+        q, kp, vp, work, 0.3, True, window=window, short=s), np.float32)
+        for s in (False, True))
+    assert np.isfinite(short).all()         # the empty tile's rows too
+    np.testing.assert_allclose(short[:live], twin[:live], atol=tol, rtol=0)
+    np.testing.assert_allclose(whole[:live], twin[:live], atol=tol, rtol=0)
+    # the same products summed in the same order
+    np.testing.assert_allclose(short, whole, atol=1e-6, rtol=0)
+    by_rule = np.asarray(paged_attention_ragged_v2(
+        q, kp, vp, pt, slots, lens, work=work, interpret=True,
+        window=window, scale=0.3), np.float32)
+    np.testing.assert_array_equal(by_rule, short)
 
 
 def test_a_list_too_long_for_smem_is_split_by_lanes(monkeypatch):

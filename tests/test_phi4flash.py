@@ -305,6 +305,8 @@ def test_one_refuse_signature_for_every_description():
 
 # -------------------- (f) the paged kernel: grouped heads under a window
 @pytest.mark.parametrize("hq,h,d,window", [
+    (40, 10, 128, 16),    # the served heads: ten slabs, four query heads
+    (40, 10, 128, 0),     # a key/value head (the one-lane body is in)
     (8, 2, 128, 16),      # group 4 under a window: the served shape
     (8, 2, 128, 0),       # group 4, the full layer
     (8, 4, 64, 24),       # group 2, two heads a slab
