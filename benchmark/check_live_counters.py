@@ -164,7 +164,7 @@ def check_metric_files():
         for run in runs:
             assert reader.read(run, **spec["args"]) is None, path
         seen[spec["reader"]] = seen.get(spec["reader"], 0) + 1
-    assert seen == {"span_ratio": 12, "kernel_time_per_count": 4,
+    assert seen == {"span_ratio": 13, "kernel_time_per_count": 4,
                     "host_gap_phase": 12}, seen
 
 

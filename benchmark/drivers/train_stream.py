@@ -8,6 +8,7 @@ import time
 import numpy as np
 
 from lib import checks, flops, system, traffic_gen
+from lib.chip_state import step_ms_thirds
 from lib.window import Window
 
 
@@ -35,6 +36,7 @@ def run(ctx):
                  float(ctx.traffic.get("trace_s", 3.0)))
     losses, step_s, fetched_at = [], [], []
     nxt = batch(100)            # made ahead: the host's part of a step
+    ctx.chip.take("before_ramp")
     w0 = time.perf_counter()
     w1 = w0 + ctx.seconds
     i = 100
@@ -53,6 +55,7 @@ def run(ctx):
             losses.append(float(out["loss"]))
         fetched_at.append(time.perf_counter())
         step_s.append(fetched_at[-1] - t0)
+    ctx.chip.take("after_drain")    # before the profiler stops
     trace = win.finish(ctx.chips)
     # a step counts if its loss fetch completed inside the window
     done = sum(1 for t in fetched_at if t <= w1)
@@ -70,6 +73,8 @@ def run(ctx):
     num = {"seconds": ctx.seconds, "setup_s": w0 - ctx.t_process_start,
            "steps_done": done, "tokens_per_step": tokens_per_step,
            "train_tokens": done * tokens_per_step, "step_s": step_s,
+           "step_ms_thirds": step_ms_thirds(
+               [t - s for t, s in zip(fetched_at, step_s)], step_s, w0, w1),
            "compiles_in_window": compiles, "search_s": info["search_s"],
            "flops_per_token": flops.lm_train_flops_per_token(conf, seq),
            "chips": ctx.chips}

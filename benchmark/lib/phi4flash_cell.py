@@ -157,8 +157,10 @@ def run(ctx) -> dict:
     win = Window(ctx.spans, eng.compile_counts, ctx.trace_dir,
                  float(t.get("trace_s", 5.0)))
     ramp, drain = float(t["ramp_s"]), float(t["drain_s"])
+    ctx.chip.take("before_ramp")
     w = serving.run_open_loop(loop, reqs, ramp, ctx.seconds, drain,
                               win.tick)
+    ctx.chip.take("after_drain")    # before the profiler stops
     trace = win.finish(ctx.chips)
     stats = loop.close()
     num = serving.window_numbers(loop, w, True)

@@ -24,6 +24,7 @@ from __future__ import annotations
 import time
 from typing import List, Optional
 
+from .chip_state import step_ms_thirds
 from .spans import Spans
 from .traffic_gen import Req
 
@@ -215,6 +216,8 @@ def window_numbers(loop: ServeLoop, w: dict, open_loop: bool) -> dict:
         "prompt_tokens_served": served,
         "hit_tokens": sum(r.hit_tokens for r in first_in),
         "step_s": [b - a for a, b, *_ in steps],
+        "step_ms_thirds": step_ms_thirds(
+            [s[0] for s in steps], [s[1] - s[0] for s in steps], w0, w1),
         "lane_occupancy": [s[2] / width for s in steps],
         "prefill_lanes": sum(s[3] for s in steps),
         "decode_lanes": sum(s[4] for s in steps),

@@ -41,9 +41,9 @@ class Req:
     ask: int = 0                # which ask of its document
 
 
-def _rng(seed: int, tag: int):
+def _rng(seed: int, *tags: int):
     # SeedSequence takes any non-negative integer, 2**32 and over too
-    return np.random.default_rng([int(seed), int(tag)])
+    return np.random.default_rng([int(seed), *map(int, tags)])
 
 
 def clipped_pareto(rng, n: int, spec: dict) -> np.ndarray:
@@ -108,6 +108,26 @@ def make_requests(t: dict, seed: int, vocab: int, n: int) -> List[Req]:
     return reqs
 
 
+def _document_lengths(t: dict, n_docs: int, asks: int):
+    """The fixed lengths of `n_docs` documents, their questions and
+    answers, drawn in blocks of the file's `length_block` documents
+    (all at once without the key): block 0 by the generator the whole
+    list had before it was drawn in blocks, block b by one of its own.
+    So a list made longer keeps every earlier request as it was, and a
+    cell's numbers cannot move until a tree serves past the old end."""
+    block = int(t.get("length_block", n_docs))
+    parts = []
+    for b, d0 in enumerate(range(0, n_docs, block)):
+        n = min(block, n_docs - d0)
+        fixed = _rng(t["sizes_seed"], 3, *((b,) if b else ()))
+        parts.append((_lengths(fixed, n, t["document"]),
+                      _lengths(fixed, n * asks, t["question"]
+                               ).reshape(n, asks),
+                      _lengths(fixed, n * asks, t["output"]
+                               ).reshape(n, asks)))
+    return tuple(np.concatenate(p) for p in zip(*parts))
+
+
 def make_document_asks(t: dict, seed: int, vocab: int, n_docs: int
                        ) -> List[Req]:
     """Closed-loop list: documents in groups of `reuse_distance + 1`;
@@ -116,10 +136,7 @@ def make_document_asks(t: dict, seed: int, vocab: int, n_docs: int
     other requests lie between two asks of one document."""
     group = int(t["reuse_distance"]) + 1
     asks = int(t["asks_per_document"])
-    fixed = _rng(t["sizes_seed"], 3)
-    doc_len = _lengths(fixed, n_docs, t["document"])
-    q_len = _lengths(fixed, n_docs * asks, t["question"]).reshape(n_docs, asks)
-    out = _lengths(fixed, n_docs * asks, t["output"]).reshape(n_docs, asks)
+    doc_len, q_len, out = _document_lengths(t, n_docs, asks)
     var = _rng(seed, 4)
     reqs = []
     for g0 in range(0, n_docs, group):

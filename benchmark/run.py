@@ -21,6 +21,15 @@ Output: progress lines `# <what>: <json>`, then as the last line one
 JSON object {correct, attempted, failed, metrics, device[, breakdown]}.
 `--trace 0` gives the cell's end-to-end metrics, `--trace 1` its
 per-layer metrics, the device's busy seconds and the breakdown.
+
+The command is a parent that stays off JAX (a chip belongs to one
+process at a time) and runs the cell in a child, `--start 1`, whose
+output and exit code are its own. An untraced child that finds the
+chip slow at the end of its set-up (lib/chip_state.py) exits with
+EXIT_SLOW before its ramp, and the parent starts another while the
+budget lasts; the one that runs the window prints `# chip_state: {...}`
+and the result.
+`setup_s` counts from the start of the process that ran the window.
 """
 
 import time
@@ -31,6 +40,8 @@ import dataclasses       # noqa: E402
 import importlib         # noqa: E402
 import json              # noqa: E402
 import os                # noqa: E402
+import signal            # noqa: E402
+import subprocess        # noqa: E402
 import sys               # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -51,6 +62,7 @@ class Ctx:
     rehearse: bool
     spans: object
     t_process_start: float
+    chip: object            # lib/chip_state.py: the probe's readings
 
     @staticmethod
     def say(what: str, obj) -> None:
@@ -81,6 +93,46 @@ def read_metric(entry: dict, run: dict):
     return reader.read(run, **spec.get("args", {}))
 
 
+def _die_with_parent() -> None:
+    """In the child, before it runs: a parent that is killed takes its
+    child with it (Linux; elsewhere the signals below have to do)."""
+    try:
+        import ctypes
+        ctypes.CDLL(None).prctl(1, signal.SIGKILL)  # PR_SET_PDEATHSIG
+    except (OSError, AttributeError):
+        pass
+
+
+def run_child(cmd: list) -> int:
+    """One child to its end, on this process's own stdout and stderr;
+    a signal that ends this process reaches the child first."""
+    child = subprocess.Popen(cmd, preexec_fn=_die_with_parent)
+
+    def forward(signum, _frame):
+        if child.poll() is None:
+            child.send_signal(signum)
+
+    for sig in (signal.SIGTERM, signal.SIGINT, signal.SIGHUP):
+        signal.signal(sig, forward)
+    return child.wait()
+
+
+def parent(argv: list, run=run_child, clock=time.perf_counter) -> int:
+    """Start the cell in a child until one runs its window; that
+    child's exit code is this process's. Each child is told which start
+    it is and what the starts before it have cost (the gate's budget is
+    lib/chip_state.py's)."""
+    from lib.chip_state import EXIT_SLOW
+    t0, start = clock(), 0
+    while True:
+        start += 1
+        rc = run([sys.executable, os.path.abspath(__file__), *argv,
+                  "--start", str(start),
+                  "--gate-spent-s", f"{clock() - t0:.3f}"])
+        if rc != EXIT_SLOW:
+            return rc
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
@@ -88,9 +140,17 @@ def main() -> int:
     ap.add_argument("--seconds", type=float, default=None)
     ap.add_argument("--trace", type=int, default=0)
     ap.add_argument("--rehearse-cpu", action="store_true")
+    ap.add_argument("--start", type=int, default=0,
+                    help="set by the parent: which start of this run "
+                         "this process is (0: this is the parent)")
+    ap.add_argument("--gate-spent-s", type=float, default=0.0,
+                    help="set by the parent: seconds the starts given "
+                         "up before this one have cost")
     args = ap.parse_args()
     if args.seed < 0:
         raise SystemExit("benchmark: --seed is a non-negative integer")
+    if not args.start:
+        return parent(sys.argv[1:])
 
     bench = load_json(ROOT, "BENCHMARK.json")
     cell = next((w for w in bench["workloads"]
@@ -139,15 +199,26 @@ def main() -> int:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     Ctx.say("compile_cache", {"dir": cache_dir, "was_empty": was_empty})
+    from lib.chip_state import EXIT_SLOW, ChipState, SlowChip
+    chip = ChipState(args.rehearse_cpu, T_PROCESS_START, args.start,
+                     args.gate_spent_s, gate=not args.trace)
+    chip.take("start")
 
     ctx = Ctx(workload=cell["name"], seed=args.seed, seconds=seconds,
               chips=cell["chips"], conf=conf, traffic=traffic,
               trace_dir=(scratch_dir("trace", cell["name"])
                          if args.trace else None),
               rehearse=args.rehearse_cpu,
-              spans=Spans(), t_process_start=T_PROCESS_START)
+              spans=Spans(), t_process_start=T_PROCESS_START, chip=chip)
     driver = importlib.import_module("drivers." + traffic["driver"])
-    result = driver.run(ctx)
+    try:
+        result = driver.run(ctx)
+    except SlowChip:
+        Ctx.say("chip_state", dict(chip.summary(), gave_up=True))
+        return EXIT_SLOW
+    Ctx.say("chip_state", chip.summary(
+        result["numbers"].get("step_ms_thirds") or ()))
+    result["numbers"]["chip_probe_tflops"] = chip.tflops_of_window()
 
     used = devs[:cell["chips"]]
     peak_mem = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
