@@ -177,10 +177,11 @@ class FFModel:
 
     def layer_norm(self, input: Tensor, eps: float = 1e-5,
                    elementwise_affine: bool = True,
-                   name: Optional[str] = None) -> Tensor:
+                   name: Optional[str] = None,
+                   use_bias: bool = True) -> Tensor:
         from .ops import LayerNorm
         op = LayerNorm(self, name or self._fresh_name("layer_norm"),
-                       [input], eps, elementwise_affine)
+                       [input], eps, elementwise_affine, use_bias)
         return self.add_op(op).output
 
     def rms_norm(self, input: Tensor, eps: float = 1e-5,
@@ -232,17 +233,26 @@ class FFModel:
                             use_flash=None, positions: Tensor = None,
                             rotary_theta: float = 0.0,
                             qk_norm: bool = False,
-                            qk_norm_eps: float = 1e-5) -> Tensor:
+                            qk_norm_eps: float = 1e-5,
+                            num_kv_heads: int = 0, window: int = 0,
+                            rotary_interleaved: bool = False,
+                            head_dim: int = 0) -> Tensor:
         """`positions` ((batch, seq) int32) with `rotary_theta` > 0
-        rotates q and k per head at those absolute positions;
-        `qk_norm` RMS-normalises the whole q and k projections first."""
+        rotates q and k per head at those absolute positions
+        (`rotary_interleaved`: neighbouring pairs, GPT-J's);
+        `qk_norm` RMS-normalises the whole q and k projections first;
+        `num_kv_heads` < num_heads: query head j reads key/value head
+        j // (num_heads / num_kv_heads); `window` > 0: token t sees
+        keys t - window + 1 .. t; `head_dim` > 0: heads of that size
+        (num_heads * head_dim wide inside, embed_dim out)."""
         inputs = [query, key, value] \
             + ([positions] if positions is not None else [])
         op = MultiHeadAttention(
             self, name or self._fresh_name("attention"), inputs,
             embed_dim, num_heads, kdim, vdim, dropout, bias, add_bias_kv,
             add_zero_attn, causal, kernel_initializer, use_flash,
-            rotary_theta, qk_norm, qk_norm_eps)
+            rotary_theta, qk_norm, qk_norm_eps, num_kv_heads, window,
+            rotary_interleaved, head_dim)
         return self.add_op(op).output
 
     # elementwise unary (model.h exp/relu/sigmoid/tanh/elu/scalar ops)
@@ -366,17 +376,22 @@ class FFModel:
                 capacity_factor: float = 1.25, activation="relu",
                 aux_loss_weight: float = 1e-2,
                 name: Optional[str] = None, norm_topk: bool = True,
-                dropless: bool = False) -> Tensor:
+                dropless: bool = False, score: str = "softmax",
+                shared_experts: int = 0, experts_held=None) -> Tensor:
         """Fused expert-parallel MoE FFN (TPU-first EP; the composable
         reference path softmax+topk+group_by+aggregate also exists).
         `dropless`: bias-free gated experts, (act(x wg) * (x wu)) wd,
         and every token reaches all its k experts whatever the load (no
         capacity); `norm_topk=False` keeps the k router probabilities
-        as they are."""
+        as they are. A dropless layer's `score` ("softmax" |
+        "sigmoid"), `shared_experts` and `experts_held` (first, count):
+        ops/moe_ffn.py."""
         op = MoEFFN(self, name or self._fresh_name("moe_ffn"), [input],
                     num_experts, k, hidden_dim, out_dim, capacity_factor,
                     activation, aux_loss_weight, norm_topk=norm_topk,
-                    dropless=dropless)
+                    dropless=dropless, score=score,
+                    shared_experts=shared_experts,
+                    experts_held=experts_held)
         return self.add_op(op).output
 
 
@@ -410,10 +425,11 @@ class FFModel:
         return self.add_op(op).output
 
     def tied_head(self, input: Tensor, table: Tensor,
-                  name: Optional[str] = None) -> Tensor:
+                  name: Optional[str] = None,
+                  scale: float = 1.0) -> Tensor:
         from .ops.gated import TiedHead
         op = TiedHead(self, name or self._fresh_name("tied_head"),
-                      [input, table])
+                      [input, table], scale)
         return self.add_op(op).output
 
     def differential_attention(self, input: Tensor, num_heads: int,

@@ -9,6 +9,7 @@ from .dlrm import build_dlrm
 from .moe import build_moe_fused, build_moe_reference
 from .candle_uno import build_candle_uno
 from .nmt_lstm import build_nmt_lstm, build_nmt_seq2seq
+from .cmdaplus import build_cmdaplus_lm
 from .olmoe import build_olmoe_lm
 from .phi4flash import build_phi4flash_lm
 
@@ -21,6 +22,7 @@ __all__ = [
     "build_dlrm",
     "build_moe_reference",
     "build_moe_fused",
+    "build_cmdaplus_lm",
     "build_olmoe_lm",
     "build_phi4flash_lm",
     "build_candle_uno",

@@ -46,7 +46,9 @@ class MultiHeadAttention(Op):
                  add_zero_attn: bool = False, causal: bool = False,
                  kernel_initializer: str = "glorot",
                  use_flash=None, rotary_theta: float = 0.0,
-                 qk_norm: bool = False, qk_norm_eps: float = 1e-5):
+                 qk_norm: bool = False, qk_norm_eps: float = 1e-5,
+                 num_kv_heads: int = 0, window: int = 0,
+                 rotary_interleaved: bool = False, head_dim: int = 0):
         super().__init__(model, name, inputs)
         # a fourth input, (batch, seq) int32 absolute positions, turns
         # the rotary embedding on (rotary_theta > 0 needs it)
@@ -54,6 +56,22 @@ class MultiHeadAttention(Op):
         self.rotary_theta = float(rotary_theta)
         self.qk_norm = bool(qk_norm)
         self.qk_norm_eps = float(qk_norm_eps)
+        self.rotary_interleaved = bool(rotary_interleaved)
+        # GROUPED heads: query head j reads key/value head j // group;
+        # `window` > 0: token t sees keys t - window + 1 .. t. Both run
+        # as the dense masked softmax below (the flash kernel and the
+        # sequence-parallel lowerings take neither yet: ROADMAP M3/M4)
+        self.num_kv_heads = int(num_kv_heads) or int(num_heads)
+        self.window = int(window)
+        if int(num_heads) % self.num_kv_heads:
+            raise ValueError(
+                f"{name}: {num_heads} query heads do not divide over "
+                f"{self.num_kv_heads} key/value heads")
+        if (self.num_kv_heads != int(num_heads) or self.window) and (
+                qk_norm or add_bias_kv or add_zero_attn or not causal):
+            raise ValueError(
+                f"{name}: grouped heads and a window are built for plain "
+                f"causal attention (no qk_norm, bias_kv or zero_attn)")
         if (self.rotary_theta > 0) != (len(inputs) == 4):
             raise ValueError(
                 f"{name}: rotary attention takes q, k, v AND positions "
@@ -63,7 +81,9 @@ class MultiHeadAttention(Op):
         self.kdim = int(kdim) if kdim > 0 else self.embed_dim
         self.vdim = int(vdim) if vdim > 0 else self.embed_dim
         assert self.embed_dim % self.num_heads == 0
-        self.head_dim = self.embed_dim // self.num_heads
+        # `head_dim` > 0: heads of a size of their own (num_heads *
+        # head_dim wide inside, embed_dim out), else embed_dim's split
+        self.head_dim = int(head_dim) or self.embed_dim // self.num_heads
         self.dropout = dropout
         self.use_bias = use_bias
         self.add_bias_kv = add_bias_kv
@@ -82,7 +102,8 @@ class MultiHeadAttention(Op):
         # tracers, which would silently disable the fused path under
         # remat
         self._fused_qkv = (q is k and k is v
-                           and self.q_in == self.k_in == self.v_in)
+                           and self.q_in == self.k_in == self.v_in
+                           and self.num_kv_heads == self.num_heads)
         # cross-attention (seq2seq decoders): K and V read the SAME
         # encoder output — fuse their projections into one 2x-wide GEMM
         self._fused_kv = (not self._fused_qkv and k is v
@@ -94,6 +115,11 @@ class MultiHeadAttention(Op):
         if self.rotary_theta > 0 or self.qk_norm:
             self.attrs.update(rotary_theta=self.rotary_theta,
                               qk_norm=self.qk_norm)
+        if self.num_kv_heads != self.num_heads or self.window \
+                or self.rotary_interleaved:
+            self.attrs.update(num_kv_heads=self.num_kv_heads,
+                              window=self.window, head_dim=self.head_dim,
+                              rotary_interleaved=self.rotary_interleaved)
 
     def output_shapes(self):
         q = self.inputs[0]
@@ -102,20 +128,21 @@ class MultiHeadAttention(Op):
     def weight_specs(self):
         h, d = self.num_heads, self.head_dim
         e = self.embed_dim
+        hk = self.num_kv_heads
         specs = {
             "wq": WeightSpec((self.q_in, h, d), initializer=self.kernel_initializer,
                              axes=(CHANNEL_IN, HEAD, None),
-                             fan_in=self.q_in, fan_out=e),
-            "wk": WeightSpec((self.k_in, h, d), initializer=self.kernel_initializer,
+                             fan_in=self.q_in, fan_out=h * d),
+            "wk": WeightSpec((self.k_in, hk, d), initializer=self.kernel_initializer,
                              axes=(CHANNEL_IN, HEAD, None),
-                             fan_in=self.k_in, fan_out=e),
-            "wv": WeightSpec((self.v_in, h, d), initializer=self.kernel_initializer,
+                             fan_in=self.k_in, fan_out=hk * d),
+            "wv": WeightSpec((self.v_in, hk, d), initializer=self.kernel_initializer,
                              axes=(CHANNEL_IN, HEAD, None),
-                             fan_in=self.v_in, fan_out=e),
+                             fan_in=self.v_in, fan_out=hk * d),
             "wo": WeightSpec((h, d, e),
                              initializer=self.kernel_initializer,
                              axes=(HEAD, None, CHANNEL_OUT),
-                             fan_in=e, fan_out=e),
+                             fan_in=h * d, fan_out=e),
         }
         if self.use_bias:
             specs["bo"] = WeightSpec((self.embed_dim,), initializer="zeros",
@@ -168,8 +195,8 @@ class MultiHeadAttention(Op):
             q = rms_norm(q, params["q_norm"], self.qk_norm_eps)
             k = rms_norm(k, params["k_norm"], self.qk_norm_eps)
         if self.rotary_theta > 0:
-            q = rotary(q, xs[3], self.rotary_theta)
-            k = rotary(k, xs[3], self.rotary_theta)
+            q = rotary(q, xs[3], self.rotary_theta, self.rotary_interleaved)
+            k = rotary(k, xs[3], self.rotary_theta, self.rotary_interleaved)
         if self.add_bias_kv:
             b = k.shape[0]
             bk = jnp.broadcast_to(params["bias_k"].astype(k.dtype),
@@ -192,6 +219,8 @@ class MultiHeadAttention(Op):
 
     def _attend(self, q, k, v, ctx: OpContext):
         """softmax(QK^T/sqrt(d))V, (b, s, h, d) layout."""
+        if self.num_kv_heads != self.num_heads or self.window:
+            return self._attend_grouped(q, k, v)
         has_seq_trunc = ctx.seq_length is not None and ctx.seq_length >= 0
         # Sequence parallelism: when the strategy maps `seq` to a mesh
         # axis, run ring attention over that axis (K/V rotate over ICI).
@@ -295,6 +324,26 @@ class MultiHeadAttention(Op):
         probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
         return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
+    def _attend_grouped(self, q, k, v):
+        """Causal attention with grouped key/value heads and, where
+        `window` > 0, the last `window` keys alone: a dense masked
+        softmax, probabilities f32 through the product with v (the
+        paged kernels' convention)."""
+        b, s, h, d = q.shape
+        hk = self.num_kv_heads
+        q = q.reshape(b, s, hk, h // hk, d)
+        logits = jnp.einsum("bqhgd,bkhd->bhgqk", q, k,
+                            preferred_element_type=jnp.float32) \
+            / math.sqrt(d)
+        pos = jnp.arange(s)
+        mask = pos[:, None] >= pos[None, :]
+        if self.window:
+            mask &= pos[:, None] - pos[None, :] < self.window
+        probs = jax.nn.softmax(jnp.where(mask, logits, -jnp.inf), axis=-1)
+        o = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v.astype(jnp.float32),
+                       preferred_element_type=jnp.float32)
+        return o.reshape(b, s, h, d).astype(v.dtype)
+
     def output_axes(self):
         return [(SAMPLE, SEQ, CHANNEL_OUT)]
 
@@ -306,7 +355,9 @@ class MultiHeadAttention(Op):
         b, lq = self.inputs[0].shape[:2]
         lk = self.inputs[1].shape[1]
         e, h, d = self.embed_dim, self.num_heads, self.head_dim
-        proj = 2.0 * b * (lq * self.q_in + lk * self.k_in + lk * self.v_in) * e
+        kv = self.num_kv_heads * d          # == e without grouped heads
+        proj = 2.0 * b * (lq * self.q_in * h * d
+                          + lk * (self.k_in + self.v_in) * kv)
         attn = 2.0 * b * h * lq * lk * d * 2
-        out = 2.0 * b * lq * e * e
+        out = 2.0 * b * lq * h * d * e
         return proj + attn + out

@@ -42,16 +42,22 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
             * w.astype(jnp.float32)).astype(x.dtype)
 
 
-def rotary(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def rotary(x: jax.Array, positions: jax.Array, theta: float,
+           interleaved: bool = False) -> jax.Array:
     """Rotary position embedding over the whole head: x (..., H, D),
     positions (...) absolute. Half-rotation pairing (x[:D/2] with
-    x[D/2:]), angles position * theta^(-2i/D), computed in f32."""
+    x[D/2:]) or, `interleaved` (GPT-J's), neighbours (x[2i] with
+    x[2i+1]); angles position * theta^(-2i/D), computed in f32."""
     d = x.shape[-1]
     half = d // 2
     inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / d)
     ang = positions.astype(jnp.float32)[..., None, None] * inv
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     xf = x.astype(jnp.float32)
+    if interleaved:
+        x1, x2 = xf[..., 0::2], xf[..., 1::2]
+        return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                         axis=-1).reshape(x.shape).astype(x.dtype)
     x1, x2 = xf[..., :half], xf[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1).astype(x.dtype)

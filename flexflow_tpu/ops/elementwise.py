@@ -258,13 +258,16 @@ class LayerNorm(PassthroughAxesMixin, Op):
     op_type = "layer_norm"
 
     def __init__(self, model, name, inputs, eps: float = 1e-5,
-                 elementwise_affine: bool = True):
+                 elementwise_affine: bool = True, use_bias: bool = True):
         super().__init__(model, name, inputs)
         self.eps = float(eps)
         self.elementwise_affine = elementwise_affine
+        self.use_bias = bool(use_bias)      # False: a learned scale alone
         self.num_channels = inputs[0].shape[-1]
         self.attrs = {"eps": eps,
                       "elementwise_affine": elementwise_affine}
+        if not self.use_bias:
+            self.attrs["use_bias"] = False
 
     def output_shapes(self):
         return [tuple(self.inputs[0].shape)]
@@ -273,12 +276,12 @@ class LayerNorm(PassthroughAxesMixin, Op):
         if not self.elementwise_affine:
             return {}
         c = self.num_channels
-        return {
-            "scale": WeightSpec((c,), initializer="ones",
-                                axes=(CHANNEL,)),
-            "bias": WeightSpec((c,), initializer="zeros",
-                               axes=(CHANNEL,)),
-        }
+        specs = {"scale": WeightSpec((c,), initializer="ones",
+                                     axes=(CHANNEL,))}
+        if self.use_bias:
+            specs["bias"] = WeightSpec((c,), initializer="zeros",
+                                       axes=(CHANNEL,))
+        return specs
 
     def forward(self, params, xs, ctx: OpContext):
         (x,) = xs
@@ -287,8 +290,9 @@ class LayerNorm(PassthroughAxesMixin, Op):
         var = jnp.var(xf, axis=-1, keepdims=True)
         y = (xf - mean) * jax.lax.rsqrt(var + self.eps)
         if self.elementwise_affine:
-            y = y * params["scale"].astype(jnp.float32) \
-                + params["bias"].astype(jnp.float32)
+            y = y * params["scale"].astype(jnp.float32)
+            if self.use_bias:
+                y = y + params["bias"].astype(jnp.float32)
         return [y.astype(x.dtype)]
 
     def flops(self) -> float:

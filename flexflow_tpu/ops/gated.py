@@ -126,19 +126,25 @@ class GatedMemoryUnit(_SeqOp):
 @register_op
 class TiedHead(_SeqOp):
     """Two inputs: x (B, S, E) and the token table (V, E). No weight of
-    its own."""
+    its own. `scale` (a config's `logit_scale`) multiplies the logits
+    in f32; 1 leaves them as they are."""
 
     op_type = "tied_head"
 
-    def __init__(self, model, name, inputs):
+    def __init__(self, model, name, inputs, scale: float = 1.0):
         super().__init__(model, name, inputs)
         self.out_dim = int(inputs[1].shape[0])
+        self.scale = float(scale)
         self.attrs = {"vocab": self.out_dim}
+        if self.scale != 1.0:
+            self.attrs["scale"] = self.scale
 
     def forward(self, params, xs, ctx: OpContext):
         x, table = xs
-        return [jnp.dot(x, table.astype(x.dtype).T,
-                        preferred_element_type=F32).astype(x.dtype)]
+        y = jnp.dot(x, table.astype(x.dtype).T, preferred_element_type=F32)
+        if self.scale != 1.0:
+            y = y * self.scale
+        return [y.astype(x.dtype)]
 
     def input_axes(self):
         return [_axes(self.inputs[0].shape), (None, None)]

@@ -127,14 +127,20 @@ RAGGED_DOT = "ragged_dot"        # `grouped_ffn` as XLA's grouped matmuls
 
 # ---- dropless routing: every slot reaches its expert, whatever the load
 def route_top_k(tokens: jax.Array, gate_w: jax.Array, k: int,
-                norm_topk: bool):
-    """The router: tokens (N, D) -> (probabilities (N, E) f32, the k
-    largest (N, k) f32, their expert ids (N, k) int32). Logits
-    accumulate in f32 and are never rounded; softmax and top-k run in
-    f32. `norm_topk` renormalises the k weights to sum to 1."""
+                norm_topk: bool, score: str = "softmax"):
+    """The router: tokens (N, D) -> (scores (N, E) f32, the k largest
+    (N, k) f32, their expert ids (N, k) int32). Logits accumulate in
+    f32 and are never rounded; the scoring function (`score`: "softmax"
+    over all the experts, or "sigmoid" of each logit alone) and top-k
+    run in f32. `norm_topk` renormalises the k weights to sum to 1."""
     logits = jnp.dot(tokens, gate_w.astype(tokens.dtype),
                      preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
+    if score == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+    elif score == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"route_top_k: no scoring function {score!r}")
     gate_vals, assign = jax.lax.top_k(probs, k)
     if norm_topk:
         gate_vals = gate_vals / jnp.clip(
@@ -143,20 +149,47 @@ def route_top_k(tokens: jax.Array, gate_w: jax.Array, k: int,
 
 
 def dropless_dispatch(tokens: jax.Array, assign: jax.Array,
-                      n_experts: int, live=None):
+                      n_experts: int, live=None, held=None):
     """Sort the N*k slots by expert (stable; slot s belongs to token
     s // k). `live` (N,) bool: slots of tokens that are not live route
     NOWHERE — they sort behind every expert's rows and no expert counts
-    them. -> (rows (S, D) in expert order, order (S,), counts (E,)
-    int32 live slots per expert: the grouped matmul's group sizes)."""
+    them. `held` (first, count): only experts first .. first + count - 1
+    of the `n_experts` live HERE (one share of an expert-parallel
+    layer): a slot of an absent expert routes nowhere too, and the
+    counts are over the held experts. -> (rows (S, D) in expert order,
+    order (S,), counts (E or count,) int32 live slots per expert: the
+    grouped matmul's group sizes)."""
     k = assign.shape[1]
     flat = assign.reshape(-1)
+    if held is not None:
+        first, n_experts = held
+        flat = flat - first
+        flat = jnp.where((flat >= 0) & (flat < n_experts), flat, n_experts)
     if live is not None:
         flat = jnp.where(jnp.repeat(live, k), flat, n_experts)
     order = jnp.argsort(flat)
     counts = jnp.zeros((n_experts,), jnp.int32).at[flat].add(
         1, mode="drop")
     return jnp.take(tokens, order // k, axis=0), order, counts
+
+
+def shared_ffn(tokens: jax.Array, wg, wu, wd, activation,
+               n_shared: int) -> jax.Array:
+    """The experts EVERY token passes, averaged: (1 / n) sum_m
+    (act(x wg_m) * (x wu_m)) wd_m, with the n experts' matrices side by
+    side — wg, wu (D, n * F), wd (n * F, D) — so the sum over m is the
+    down projection's own contraction: three plain matmuls, f32
+    accumulation, `g`, `u` and `h` rounded to the tokens' dtype.
+    -> (N, D) f32."""
+    from .common import apply_activation
+    dt = tokens.dtype
+
+    def mm(a, w):
+        return jnp.dot(a, w.astype(dt), preferred_element_type=jnp.float32)
+
+    h = apply_activation(mm(tokens, wg).astype(dt), activation) \
+        * mm(tokens, wu).astype(dt)
+    return mm(h, wd) * (1.0 / n_shared)
 
 
 def ragged_ffn(rows: jax.Array, counts: jax.Array, wg, wu, wd,

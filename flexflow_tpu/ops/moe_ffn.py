@@ -16,6 +16,16 @@ there is no capacity: slots are sorted by expert and run through
 grouped matmuls (ops/moe.py), so an overloaded expert changes no
 token's mathematics. `norm_topk=False` keeps the k router probabilities
 unnormalised. OLMoE's layer is both (models/olmoe.py).
+
+A dropless layer also takes (models/cmdaplus.py uses all three):
+`score="sigmoid"`, the router's scoring function; `shared_experts=n`,
+n gated experts of the same width that every token passes, their mean
+added to the routed sum (`ops/moe.py::shared_ffn`); and
+`experts_held=(first, count)`, ONE SHARE of an expert-parallel layer —
+the router keeps its `num_experts` outputs and its k a token, only
+`count` experts' weights exist here, a slot of an absent expert adds
+nothing, and the output is this share's part of the routed sum (plus
+the shared term, which every share holds whole).
 """
 
 from __future__ import annotations
@@ -32,6 +42,7 @@ from .moe import (
     dropless_dispatch,
     grouped_ffn,
     route_top_k,
+    shared_ffn,
     sorted_combine,
     sorted_dispatch,
     use_sorted_dispatch,
@@ -51,10 +62,22 @@ class MoEFFN(Op):
                  capacity_factor: float = 1.25,
                  activation=AC_MODE_RELU, aux_loss_weight: float = 1e-2,
                  kernel_initializer: str = "glorot",
-                 norm_topk: bool = True, dropless: bool = False):
+                 norm_topk: bool = True, dropless: bool = False,
+                 score: str = "softmax", shared_experts: int = 0,
+                 experts_held=None):
         super().__init__(model, name, inputs)
         self.norm_topk = bool(norm_topk)
         self.dropless = bool(dropless)
+        self.score = str(score)
+        self.shared_experts = int(shared_experts)
+        self.experts_held = None if experts_held is None \
+            else (int(experts_held[0]), int(experts_held[1]))
+        if not self.dropless and (self.score != "softmax"
+                                  or self.shared_experts
+                                  or self.experts_held):
+            raise ValueError(
+                f"{name}: score, shared_experts and experts_held are the "
+                f"dropless layer's (dropless=True)")
         self.num_experts = int(num_experts)
         self.k = int(k)
         self.hidden_dim = int(hidden_dim)
@@ -79,6 +102,11 @@ class MoEFFN(Op):
                       "capacity": self.capacity}
         if self.dropless:
             self.attrs.update(dropless=True, norm_topk=self.norm_topk)
+        if self.score != "softmax" or self.shared_experts \
+                or self.experts_held:
+            self.attrs.update(score=self.score,
+                              shared_experts=self.shared_experts,
+                              experts_held=self.experts_held)
 
     def output_shapes(self):
         return [tuple(self.inputs[0].shape[:-1]) + (self.out_dim,)]
@@ -92,8 +120,20 @@ class MoEFFN(Op):
                 return WeightSpec(shape, axes=(EXPERT, None, None),
                                   initializer=self.kernel_initializer,
                                   fan_in=fi, fan_out=fo)
-            return {"gate": gate, "wg": w((e, d, h), d, h),
-                    "wu": w((e, d, h), d, h), "wd": w((e, h, o), h, o)}
+            if self.experts_held:
+                e = self.experts_held[1]
+            specs = {"gate": gate, "wg": w((e, d, h), d, h),
+                     "wu": w((e, d, h), d, h), "wd": w((e, h, o), h, o)}
+            if self.shared_experts:
+                # the shared experts' matrices side by side (shared_ffn)
+                def sw(shape, fi, fo):
+                    return WeightSpec(shape, axes=(None, None),
+                                      initializer=self.kernel_initializer,
+                                      fan_in=fi, fan_out=fo)
+                n = self.shared_experts * h
+                specs.update(sg=sw((d, n), d, h), su=sw((d, n), d, h),
+                             sd=sw((n, o), h, o))
+            return specs
         return {
             "gate": gate,
             "w1": WeightSpec((e, d, h), initializer=self.kernel_initializer,
@@ -115,12 +155,18 @@ class MoEFFN(Op):
         e, cap, k = self.num_experts, self.capacity, self.k
 
         probs, gate_vals, assign = route_top_k(
-            tokens, params["gate"], k, self.norm_topk)  # (N, E), (N, k) x 2
+            tokens, params["gate"], k, self.norm_topk,
+            self.score)                         # (N, E), (N, k) x 2
         if self.dropless:
-            rows, order, counts = dropless_dispatch(tokens, assign, e)
+            rows, order, counts = dropless_dispatch(
+                tokens, assign, e, held=self.experts_held)
             ys = grouped_ffn(rows, counts, params["wg"], params["wu"],
                              params["wd"], self.activation)
             out = dropless_combine(ys, order, gate_vals)
+            if self.shared_experts:
+                out = out + shared_ffn(
+                    tokens, params["sg"], params["su"], params["sd"],
+                    self.activation, self.shared_experts)
             self._aux_loss(ctx, assign, probs)
             return [out.astype(x.dtype).reshape(
                 orig_shape[:-1] + (self.out_dim,))]
@@ -192,7 +238,12 @@ class MoEFFN(Op):
         up = (2 if self.dropless else 1) * self.in_dim * self.hidden_dim
         per_row = 2.0 * (up + self.hidden_dim * self.out_dim)
         if self.dropless:
-            return gate + self.n_tokens * self.k * per_row
+            # a share holds count / num_experts of the k slots a token,
+            # in the mean; every token passes every shared expert
+            held = self.experts_held[1] / self.num_experts \
+                if self.experts_held else 1.0
+            return gate + self.n_tokens * per_row * (
+                self.k * held + self.shared_experts)
         ffn = self.num_experts * self.capacity * per_row
         dispatch = 2.0 * self.n_tokens * self.k * self.num_experts * self.capacity
         return gate + ffn + dispatch

@@ -8,12 +8,15 @@ it cannot do, which raises at engine build (no silent fallback). A
 description reads the parameters of a compiled FFModel through the op
 names its builder wrote, and mirrors those ops' numerics.
 
-Three clients: `TransformerLM` (models/transformer.build_transformer_lm:
+Four clients: `TransformerLM` (models/transformer.build_transformer_lm:
 learned positions, LayerNorm, ReLU feed-forward — the OPT block),
 `OLMoE` (models/olmoe.build_olmoe_lm: RMSNorm, rotary attention with
-QK-norm, dropless top-k SwiGLU experts) and `Phi4Flash`
+QK-norm, dropless top-k SwiGLU experts), `Phi4Flash`
 (models/phi4flash.build_phi4flash_lm: state-space, window, full, gated
-memory and cross layers; no positions).
+memory and cross layers; no positions) and `CommandAPlus`
+(models/cmdaplus.build_cmdaplus_lm: a parallel block of grouped window
+or full attention beside sigmoid-routed experts, of which this chip
+holds a share, and averaged shared experts).
 
 What a description answers (docs/serving.md "What a description must
 answer"): the dimensions; per layer the MIXER KIND (`mixer(i)`:
@@ -38,7 +41,7 @@ from ..ops import ssm as S
 from ..ops.common import rms_norm, rotary
 from ..ops.gated import gated_ffn, gated_memory
 from ..ops.moe import (dropless_combine, dropless_dispatch, expert_impl,
-                       grouped_ffn, route_top_k)
+                       grouped_ffn, route_top_k, shared_ffn)
 
 ATTN = "attn"       # the mixer kind of every layer of the plain decoders
 
@@ -50,7 +53,9 @@ def _ln(p, x, eps):
     mean = jnp.mean(xf, axis=-1, keepdims=True)
     var = jnp.var(xf, axis=-1, keepdims=True)
     y = (xf - mean) * jax.lax.rsqrt(var + eps)
-    y = y * p["scale"].astype(jnp.float32) + p["bias"].astype(jnp.float32)
+    y = y * p["scale"].astype(jnp.float32)
+    if "bias" in p:
+        y = y + p["bias"].astype(jnp.float32)
     return y.astype(x.dtype)
 
 
@@ -100,7 +105,19 @@ class Description:
     reads = ()                  # the op names `describe` knows it by
     experts = 0                 # no expert layer: the step counts none
     experts_per_token = 0
+    # (first, count): the experts whose weights live HERE, one share of
+    # an expert-parallel layer (None: all of them). The step's counts
+    # are then over the held experts, and one more: the live slots
+    # whose expert is absent
+    experts_held = None
     window = 0
+    # differential attention: the paged call's output goes through
+    # `diff_norm` before `attn_out`
+    differential = False
+    # a PARALLEL block: one norm a layer, `attn_out` and `ffn` (which is
+    # handed `h`, that norm's output) return their BRANCH alone and the
+    # step adds x + (a + f) once. False: each returns x + its branch
+    parallel_block = False
     # (params, (1, S) tokens) -> (S, V): a full-sequence forward to use
     # as the engine's naive oracle in place of its own attention-only
     # one (None: the engine's)
@@ -417,6 +434,7 @@ class Phi4Flash(Description):
     kind = "phi4flash"
     builder = "build_phi4flash_lm"
     reads = ("tok_embed", "lm_head", "layer0_ssm", "final_ln")
+    differential = True
     _state = ("a sequence's scan state and window keys live in its "
               "slot, not in pages: ")
     refused = {
@@ -458,6 +476,7 @@ class Phi4Flash(Description):
             raise ValueError("ServeEngine reads a build_phi4flash_lm-"
                              "shaped model: ONE full attention layer")
         self.full = self.kinds.index(FULL)
+        self.full_layers = [self.full]
         self.ssm_layers = [i for i, k in enumerate(self.kinds) if k == SSM]
         self.window_layers = [i for i, k in enumerate(self.kinds)
                               if k == WINDOW]
@@ -583,7 +602,186 @@ class Phi4Flash(Description):
         return values[self.model.ops[-1].outputs[0].uid][0]
 
 
-SHAPES = (TransformerLM, OLMoE, Phi4Flash)
+class CommandAPlus(Description):
+    """The build_cmdaplus_lm block (models/cmdaplus.py holds the
+    equations): a parallel block, window layers on rings beside full
+    layers on pages (no state), grouped key/value heads, and an expert
+    layer of which this chip may hold a share (`experts_held`). Served
+    by the mixed step on one device."""
+
+    kind = "command_a_plus"
+    builder = "build_cmdaplus_lm"
+    reads = ("tok_embed", "lm_head", "layer0_ln", "layer0_moe", "final_ln")
+    parallel_block = True
+    _ring = ("a sequence's window keys live in its slot's ring, not in "
+             "pages: ")
+    refused = {
+        "tp": "single-device: the held experts, the grouped heads and "
+              "the rings are not split over a mesh (the layer's exchange "
+              "between shares is not built: ROADMAP M1)",
+        "adapters": "no adapter pool for the expert layer",
+        "speculation": _ring + "rolling back rejected tokens would need "
+                       "the ring as it was (serve_spec_decode must be off)",
+        "prefix_cache": _ring + "a prefix hit would need the window "
+                        "layers' keys at the prefix's end "
+                        "(serve_prefix_cache must be off)",
+        "host_tier": _ring + "the host tier spills pages only",
+        "handoff": _ring + "the disaggregated handoff ships pages only",
+    }
+
+    def __init__(self, model, ops):
+        self.model = model
+        self.vocab_size = ops["tok_embed"].num_entries
+        self.layer_norm = True
+        n = 0
+        while f"layer{n}_ln" in ops:
+            n += 1
+        self.num_layers = n
+        attns = [ops[f"layer{i}_attn"] for i in range(n)]
+        moe0 = ops["layer0_moe"]
+        self.kinds = [WINDOW if a.window else FULL for a in attns]
+        self.window_layers = [i for i, k in enumerate(self.kinds)
+                              if k == WINDOW]
+        self.full_layers = [i for i, k in enumerate(self.kinds)
+                            if k == FULL]
+        if not (self.window_layers and self.full_layers and moe0.dropless
+                and all(a.causal and not a.qk_norm for a in attns)):
+            raise ValueError(
+                "ServeEngine reads a build_cmdaplus_lm-shaped model: "
+                "window AND full causal attention layers, a dropless "
+                "MoEFFN")
+        a0 = attns[self.window_layers[0]]
+        self.window = a0.window
+        self.num_heads, self._kv_heads = a0.num_heads, a0.num_kv_heads
+        self.head_dim = a0.head_dim
+        self.hidden = a0.embed_dim
+        # rotary by layer: (theta, interleaved), theta 0 = no rotation
+        self.rope = [(a.rotary_theta, a.rotary_interleaved) for a in attns]
+        self.ln_eps = ops["layer0_ln"].eps
+        self.logit_scale = ops["lm_head"].scale
+        self.act_dtype = jnp.dtype(ops["tok_embed"].out_dtype)
+        # rotary has no table: the positions served are the graph's own
+        self.max_positions = int(ops["tok_embed"].inputs[0].shape[1])
+        self.experts = moe0.num_experts
+        self.experts_per_token = moe0.k
+        self.experts_held = moe0.experts_held or (0, moe0.num_experts)
+        self.shared_experts = moe0.shared_experts
+        self.norm_topk, self.score = moe0.norm_topk, moe0.score
+        self.activation = moe0.activation
+        self.ff_dim = moe0.hidden_dim
+        w = model.state.params["layer0_moe"]["wg"]
+        # one expert's three matrices as they are resident, and what a
+        # step reads of the shared experts: all of them, every layer
+        self.expert_bytes = int(3 * self.hidden * self.ff_dim
+                                * w.dtype.itemsize)
+        self.shared_bytes = n * self.shared_experts * self.expert_bytes
+        self._expert_weights = jax.ShapeDtypeStruct(w.shape, w.dtype)
+
+    def mixer(self, i: int) -> str:
+        return self.kinds[i]
+
+    def expert_impl(self, lanes: int):
+        rows = jax.ShapeDtypeStruct(
+            (lanes * self.experts_per_token, self.hidden), self.act_dtype)
+        return expert_impl(rows, self._expert_weights, **self.kernels)
+
+    def hybrid_spec(self, chunk: int):
+        from .kv_cache import HybridSpec
+        return HybridSpec(window_layers=len(self.window_layers),
+                          window=self.window, chunk=int(chunk))
+
+    @property
+    def kv_heads(self) -> int:
+        return self._kv_heads
+
+    @property
+    def paged_layers(self) -> int:
+        return len(self.full_layers)
+
+    def attn_calls(self) -> Tuple[int, int]:
+        return len(self.full_layers), len(self.window_layers)
+
+    def embed(self, params, tokens, positions):
+        return jnp.take(params["tok_embed"]["kernel"], tokens, axis=0,
+                        mode="clip").astype(self.act_dtype)
+
+    def norm1(self, params, i, x):
+        return _ln(params[f"layer{i}_ln"], x, self.ln_eps)
+
+    def qkv(self, params, i, h, positions, lora=None):
+        """h (T, E), positions (T,) -> q (T, H, D), k, v (T, Hk, D): a
+        window layer's q and k rotated at the lanes' absolute
+        positions, a full layer's as they are (no position signal)."""
+        q, k, v = _project(params[f"layer{i}_attn"], h)
+        theta, interleaved = self.rope[i]
+        if theta > 0:
+            q = rotary(q, positions, theta, interleaved)
+            k = rotary(k, positions, theta, interleaved)
+        return q, k, v
+
+    def attn_out(self, params, i, o, x, psum_axis=None, lora=None):
+        """The attention BRANCH alone (parallel_block)."""
+        p = params[f"layer{i}_attn"]
+        return jnp.einsum("...hd,hde->...e", o, p["wo"].astype(o.dtype))
+
+    def ffn(self, params, i, x, h=None, live=None, psum_axis=None,
+            lora=None):
+        """The expert BRANCH alone, of `h` (the layer's one norm), five
+        scopes: `router` (f32 sigmoid, top-k, renormalised), `moe_dispatch`
+        (slots sorted by held expert; a slot of an absent expert routes
+        nowhere, as a dead lane's), `experts` (the gated expert over the
+        held experts), `shared_experts` (plain matmuls), `moe_combine`.
+        -> (f, (held + 1,) int32: live slots per held expert, then the
+        live slots whose expert is absent)."""
+        m = params[f"layer{i}_moe"]
+        scope = jax.named_scope
+        k = self.experts_per_token
+        h = h.reshape(-1, self.hidden)
+        with scope("router"):
+            _, gate_vals, assign = route_top_k(
+                h, m["gate"], k, self.norm_topk, self.score)
+        with scope("moe_dispatch"):
+            rows, order, counts = dropless_dispatch(
+                h, assign, self.experts, live, self.experts_held)
+            slots = k * (h.shape[0] if live is None
+                         else jnp.sum(live, dtype=jnp.int32))
+            counts = jnp.concatenate(
+                [counts, (slots - jnp.sum(counts))[None]])
+        with scope("experts"):
+            ys = grouped_ffn(rows, counts[:-1], m["wg"], m["wu"], m["wd"],
+                             self.activation, **self.kernels)
+        with scope("shared_experts"):
+            f = shared_ffn(h, m["sg"], m["su"], m["sd"], self.activation,
+                           self.shared_experts) \
+                if self.shared_experts else 0.0
+        with scope("moe_combine"):
+            f = f + dropless_combine(ys, order, gate_vals)
+            return f.astype(x.dtype).reshape(x.shape), counts
+
+    def final_norm(self, params, x):
+        return _ln(params["final_ln"], x, self.ln_eps)
+
+    def head(self, params, x):
+        """Tied: the token table (this chip's slice of it) is the head,
+        times `logit_scale`."""
+        h = self.final_norm(params, x)
+        table = params["tok_embed"]["kernel"].astype(h.dtype)
+        y = jnp.dot(h, table.T, preferred_element_type=jnp.float32)
+        if self.logit_scale != 1.0:
+            y = y * self.logit_scale
+        return y.astype(h.dtype)
+
+    def forward_logits(self, params, tokens):
+        """(1, S) tokens -> (S, V): the op graph's own full-sequence
+        forward (no cache, no kernel), the engine's naive oracle."""
+        positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
+        values, _ = self.model.executor.forward_values(
+            params, {}, {"tokens": tokens, "positions": positions},
+            training=False, rng=None)
+        return values[self.model.ops[-1].outputs[0].uid][0]
+
+
+SHAPES = (TransformerLM, OLMoE, Phi4Flash, CommandAPlus)
 
 
 def describe(model):

@@ -1150,16 +1150,21 @@ class ServeEngine:
         with jax.named_scope("work_list"):
             live = write_pages != 0
             lane = jnp.arange(1, live.shape[0] + 1, dtype=jnp.int32)
-            starts = ssm.run_starts(lane_slots, positions)
+            # runs are the scans' alone: nothing of them without a
+            # state-space layer
+            state = c.hybrid.state_layers > 0
+            starts = ssm.run_starts(lane_slots, positions) if state \
+                else None
             rings = ring_tables(c, jnp)
             page = positions // c.page_size
-            hyb = {
-                "starts": starts, "offsets": ssm.run_offsets(starts),
-                "wslots": ssm.run_write_slots(starts, live, lane_slots,
-                                              c.max_seqs),
-                "rings": rings, "memory": None, "work": None,
-                "live_lanes": jnp.max(jnp.where(live, lane, 0)),
-                "ring_pages": jnp.where(live, rings[lane_slots, page], 0)}
+            hyb = {"rings": rings, "memory": None, "work": None}
+            if state:
+                hyb.update(
+                    starts=starts, offsets=ssm.run_offsets(starts),
+                    wslots=ssm.run_write_slots(starts, live, lane_slots,
+                                               c.max_seqs),
+                    live_lanes=jnp.max(jnp.where(live, lane, 0)))
+            hyb["ring_pages"] = jnp.where(live, rings[lane_slots, page], 0)
             if self.attn_impl != JNP:
                 hyb["work"] = build_work_list(
                     rings, lane_slots, lane_lens, page_size=c.page_size,
@@ -1182,7 +1187,10 @@ class ServeEngine:
         state-space layer: `ln`, `ssm_proj`, `ssm_conv`, `ssm_scan`. A
         gated memory unit: `ln`, `gmu`. Then the description's
         feed-forward under its own scopes (`ffn`, or `router`,
-        `moe_dispatch`, `experts`, `moe_combine`). `la` is the lanes'
+        `moe_dispatch`, `experts`, `moe_combine`, with `shared_experts`
+        where there are some). A PARALLEL block (arch.parallel_block)
+        has the one norm: the feed-forward reads `h` too, both branches
+        come back alone and `residual` adds them to x once. `la` is the lanes'
         adapter rows of this layer (None: no adapters); `hyb` what
         `_hybrid_lanes` made (None: attention layers alone). Returns
         the layer's live slots per expert last (None without an expert
@@ -1199,10 +1207,18 @@ class ServeEngine:
             with scope("gmu"):
                 x = arch.gmu(params, i, h, hyb["memory"], x)
         else:
-            x, pool = self._attn_layer(
+            # x + the attention branch or, in a parallel block, the
+            # branch alone
+            a, pool = self._attn_layer(
                 params, i, kind, x, h, positions, pool, write_pages,
                 write_offs, page_tables, lane_slots, lane_lens, work,
                 scale, la, ad_s, tp_axis, hyb)
+            if arch.parallel_block:
+                f, counts = arch.ffn(params, i, x, h=h, live=live,
+                                     psum_axis=tp_axis)
+                with scope("residual"):
+                    return x + (a + f), pool, counts
+            x = a
         x, counts = arch.ffn(
             params, i, x, live=live, psum_axis=tp_axis,
             lora=None if la is None else
@@ -1214,8 +1230,9 @@ class ServeEngine:
                     lane_lens, work, scale, la, ad_s, tp_axis, hyb):
         """The attention mixer of layer `i` -> (x, pool). `kind` says
         which pages it writes and reads: ATTN layer i of the one pool;
-        WINDOW its own layer of the rings; FULL the paged layer of a
-        hybrid pool; CROSS that layer too, writing nothing."""
+        WINDOW its own layer of the rings; FULL its own of a hybrid
+        pool's paged layers; CROSS the first of those, writing
+        nothing."""
         scope = jax.named_scope
         arch = self.arch
         with scope("qkv"):
@@ -1229,6 +1246,8 @@ class ServeEngine:
             kv, layer = pool.window, arch.window_layers.index(i)
             write_pages, page_tables = hyb["ring_pages"], hyb["rings"]
             work, window = hyb["work"], arch.window
+        elif kind == FULL:
+            kv, layer = pool.full, arch.full_layers.index(i)
         else:
             kv, layer = pool.full, 0
         if kind != CROSS:
@@ -1247,7 +1266,7 @@ class ServeEngine:
                 k_scales=k_scales, v_scales=v_scales, scale=scale,
                 block_kv=self.attn_block_kv, work=work, window=window,
                 **self._attn_kw)
-        if kind != ATTN:
+        if arch.differential:
             with scope("diff_norm"):
                 o = arch.diff_norm(params, i, o)
         with scope("attn_out"):
@@ -2779,7 +2798,13 @@ class StepEvents:
     counted (0: the layer is dropless), ``experts_touched`` the
     (layer, expert) pairs with at least one slot, ``expert_bytes``
     their weights' bytes (what the expert phase reads) and
-    ``expert_load_max`` the fullest expert's slots; on a model whose
+    ``expert_load_max`` the fullest expert's slots; where this chip
+    holds a share of the experts (arch.experts_held) the counts are
+    over the held experts, ``slots_held`` their sum beside
+    ``expert_slots`` (what the live lanes routed, held here or not),
+    and ``shared_bytes`` what the step reads of the shared experts; on
+    a model with window layers ``lanes_past_window`` is the live lanes
+    whose length exceeds the window; on a model whose
     slots hold state besides pages (kv_cache.HybridSpec)
     ``state_bytes`` is the scan states and tails the step reads and
     writes for its runs, ``ssm_runs`` the runs (segments) each scan
@@ -2797,6 +2822,7 @@ class StepEvents:
                  "emit_lanes", *LIVE_COUNTS,
                  "expert_counts", "expert_slots", "expert_dropped",
                  "experts_touched", "expert_bytes", "expert_load_max",
+                 "slots_held", "shared_bytes", "lanes_past_window",
                  "state_bytes", "ssm_runs", "window_kv_bytes",
                  "full_kv_bytes")
 
@@ -2828,6 +2854,9 @@ class StepEvents:
         self.experts_touched = 0
         self.expert_bytes = 0
         self.expert_load_max = 0
+        self.slots_held = 0
+        self.shared_bytes = 0
+        self.lanes_past_window = 0
         self.state_bytes = 0
         self.ssm_runs = 0
         self.window_kv_bytes = 0
@@ -3099,7 +3128,10 @@ class ServeSession:
                                        * page_bytes)
             work["kv_bytes"] = (work["full_kv_bytes"]
                                 + work["window_kv_bytes"])
-            work["ssm_runs"] = len(plan.chunks)
+            work["lanes_past_window"] = int(
+                (lane_lens[:lane] > arch.window).sum())
+            work["ssm_runs"] = len(plan.chunks) \
+                if c.hybrid.state_layers else 0
             work["state_bytes"] = (2 * len(plan.chunks)
                                    * c.hybrid.state_bytes)
         # the step's fixed shape against its live work (LIVE_COUNTS):
@@ -3117,9 +3149,16 @@ class ServeSession:
         """The step's expert counters from its (layers, experts) live
         slots per expert, and the session's running totals."""
         arch = self.eng.arch
+        absent = 0
+        if arch.experts_held is not None:
+            # a share's counts: the held experts', then the live slots
+            # whose expert is absent
+            absent, counts = int(counts[:, -1].sum()), counts[:, :-1]
+            ev.slots_held = int(counts.sum())
+            ev.shared_bytes = arch.shared_bytes
         ev.expert_counts = counts
         ev.expert_slots = live * arch.experts_per_token * counts.shape[0]
-        ev.expert_dropped = ev.expert_slots - int(counts.sum())
+        ev.expert_dropped = ev.expert_slots - int(counts.sum()) - absent
         assert ev.expert_dropped == 0, (
             f"{ev.expert_dropped} of {ev.expert_slots} expert slots of "
             f"the step's {live} live lanes reached no expert")
@@ -3195,6 +3234,7 @@ class ServeSession:
             counted = LIVE_COUNTS
             if c.hybrid is not None:
                 ev.ssm_runs = work["ssm_runs"]
+                ev.lanes_past_window = work["lanes_past_window"]
                 counted += ("state_bytes", "window_kv_bytes",
                             "full_kv_bytes")
             for key in counted:
@@ -3235,7 +3275,9 @@ class ServeSession:
         with timed(track, "emit", None if not eng.arch.experts else {
                 "step": step_idx, "expert_slots": ev.expert_slots,
                 "experts_touched": ev.experts_touched,
-                "expert_bytes": ev.expert_bytes}):
+                "expert_bytes": ev.expert_bytes,
+                **({} if eng.arch.experts_held is None else {
+                    "shared_bytes": ev.shared_bytes})}):
             if not np.isfinite(topv[:lane]).all():
                 self.nonfinite_steps += 1
             self.util.append(1.0 - cache.free_pages / c.usable_pages)

@@ -128,15 +128,16 @@ def prefix_page_keys(tokens: Sequence[int], page_size: int,
 @dataclasses.dataclass(frozen=True)
 class HybridSpec:
     """What a sequence holds besides pages (module docstring): the
-    window layers' ring and the state-space layers' state and tail.
-    `chunk` is the most tokens of ONE sequence a step writes (the
-    engine's prefill budget)."""
+    window layers' ring and the state-space layers' state and tail
+    (`state_layers` 0: rings alone, and nothing is allocated or
+    computed for state). `chunk` is the most tokens of ONE sequence a
+    step writes (the engine's prefill budget)."""
     window_layers: int
     window: int
     chunk: int
-    state_layers: int
-    state_shape: Tuple[int, int]        # (d_state, d_inner), f32
-    tail_shape: Tuple[int, int]         # (d_conv - 1, d_inner)
+    state_layers: int = 0
+    state_shape: Tuple[int, int] = (0, 0)   # (d_state, d_inner), f32
+    tail_shape: Tuple[int, int] = (0, 0)    # (d_conv - 1, d_inner)
     tail_dtype: str = "bfloat16"
 
     def ring_pages(self, page_size: int) -> int:
@@ -542,8 +543,9 @@ class HybridPool:
     (state_layers, max_seqs + 1, d_state, d_inner) f32 and `tail`
     (state_layers, max_seqs + 1, (d_conv - 1) * d_inner) (its rows
     flat: three rows would pad to a tile of 16), the last row of both
-    the write sink. Flows through the step like a KVPool
-    (donated in, returned out)."""
+    the write sink; both None without a state-space layer (no
+    zero-sized leaf in the donated pool). Flows through the step like a
+    KVPool (donated in, returned out)."""
     full: KVPool
     window: KVPool
     state: Any
@@ -560,9 +562,12 @@ class HybridPool:
     def alloc(cls, cfg: KVCacheConfig, sharding=None) -> "HybridPool":
         h = cfg.hybrid
         rows = (h.state_layers, cfg.max_seqs + 1)
+        full = KVPool.alloc(dataclasses.replace(cfg, hybrid=None), sharding)
+        window = KVPool.alloc(cls.ring_cfg(cfg), sharding)
+        if not h.state_layers:
+            return cls(full, window, None, None)
         return cls(
-            KVPool.alloc(dataclasses.replace(cfg, hybrid=None), sharding),
-            KVPool.alloc(cls.ring_cfg(cfg), sharding),
+            full, window,
             jnp.zeros(rows + tuple(h.state_shape), jnp.float32,
                       device=sharding),
             jnp.zeros(rows + (h.tail_shape[0] * h.tail_shape[1],),
@@ -574,6 +579,9 @@ class HybridPool:
         self.window.check_geometry(self.ring_cfg(cfg))
         for name in ("state", "tail"):
             a, w = getattr(self, name), getattr(want, name)
+            if w is None:
+                assert a is None, f"pool leaf {name} without a state layer"
+                continue
             assert (a.shape, a.dtype) == (w.shape, w.dtype), (
                 f"pool leaf {name} is {a.shape} {a.dtype}; the "
                 f"configuration's is {w.shape} {w.dtype}")

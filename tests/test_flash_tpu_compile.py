@@ -103,20 +103,24 @@ def test_sharded_attention_op_compiles_for_2x2(topo, as_tpu):
     assert all(name in text for name in KERNELS)
 
 
-@pytest.mark.parametrize("window", [0, 512])
+@pytest.mark.parametrize("window,t,slots,pp,bp,hq,h", [
+    (0, 576, 64, 512, 13, 40, 10), (512, 576, 64, 512, 13, 40, 10),
+    (0, 544, 32, 1280, 16, 128, 8), (4096, 544, 32, 1280, 16, 128, 8)])
 def test_paged_kernel_compiles_at_grouped_heads_under_a_window(
-        topo, as_tpu, window):
+        topo, as_tpu, window, t, slots, pp, bp, hq, h):
     """The serving kernel at Phi-4-mini-flash's served geometry (PR 32):
     40 query heads over 10 key/value heads of 128 (group 4), bf16 pages
     of 16, 576 lanes, 8192 positions — with the window layers' list and
-    mask, and without."""
+    mask, and without; and at Command A+'s (PR 43): 128 query heads
+    over 8 key/value heads of 128 (group 16: 512 rows a head in the
+    whole-tile product), 544 lanes, 20480 positions, a window of
+    4096."""
     from flexflow_tpu.kernels import paged_ragged_v2 as pr
     one = SingleDeviceSharding(topo.devices[0])
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    t, slots, pp, bp = 576, 64, 512, 13
     items = pr.max_work_items(
         t, pp, bp, slot_changes=slots,
         window_blocks=pr.window_block_bound(window, bp * 16)
@@ -130,9 +134,9 @@ def test_paged_kernel_compiles_at_grouped_heads_under_a_window(
             q, kp, vp, pt, lane_slots, lens, scale=0.125, work=work,
             use_pallas=True, window=window)
 
-    pages = sds((4161, 16, 10, 128), jnp.bfloat16)
+    pages = sds((4161, 16, h, 128), jnp.bfloat16)
     text = jax.jit(call).lower(
-        sds((t, 40, 128), jnp.bfloat16), pages, pages,
+        sds((t, hq, 128), jnp.bfloat16), pages, pages,
         sds((slots, pp), jnp.int32), sds((t,), jnp.int32),
         sds((t,), jnp.int32)).compile().as_text()
     assert "paged_ragged_v2" in text and "tpu_custom_call" in text
@@ -183,14 +187,18 @@ def _sds(tree, sharding):
         a.shape, a.dtype, sharding=sharding), tree)
 
 
-@pytest.mark.parametrize("row_tile,f_tile", [(None, None), (32, 256)])
+@pytest.mark.parametrize("row_tile,f_tile,s,d,e,f", [
+    (None, None, 4608, 2048, 64, 1024), (32, 256, 4608, 2048, 64, 1024),
+    (None, None, 4352, 4096, 16, 4096)])
 def test_fused_expert_kernel_compiles_at_the_served_shape(
-        topo, as_tpu, row_tile, f_tile):
+        topo, as_tpu, row_tile, f_tile, s, d, e, f):
     """The gated expert (kernels/grouped_ffn.py, PR 37) at OLMoE's
     served shape — 4608 expert-sorted rows of 2048, 64 experts of 1024,
     bf16 — at the tiles the kernel chooses (a whole expert a grid step:
     24 MiB of double-buffered weights, inside the VMEM it asks for) and
-    at the sweep's other end: one Mosaic call, no grouped matmul."""
+    at the sweep's other end; and at Command A+'s share (PR 43): 4352
+    rows of 4096, the 16 held experts of 4096 in four tiles of F (48
+    MiB double-buffered): one Mosaic call, no grouped matmul."""
     from flexflow_tpu.kernels import grouped_ffn as kg
     from flexflow_tpu.ops import moe
     one = SingleDeviceSharding(topo.devices[0])
@@ -198,7 +206,6 @@ def test_fused_expert_kernel_compiles_at_the_served_shape(
     def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    s, d, e, f = 4608, 2048, 64, 1024
     rows, wg, wd = sds((s, d)), sds((e, d, f)), sds((e, f, d))
     assert moe.expert_impl(rows, wg) == "pallas"
     text = jax.jit(lambda *a: kg.grouped_ffn(
