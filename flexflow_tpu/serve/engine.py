@@ -607,6 +607,15 @@ class ServeEngine:
                                   "attempt": attempt})
         return out
 
+    @property
+    def head_rows(self) -> int:
+        """The rows the step's head, argmax and top-k run over: one a
+        sequence that can emit (a sequence has at most one chunk a
+        step), plus its drafts under speculation; never more than the
+        step's lanes, where the gather is the identity."""
+        return min(self.mixed_width,
+                   self.cache_cfg.max_seqs * (1 + self.spec_tokens))
+
     def _program_fingerprint(self) -> Dict:
         """The cache identity of this engine's program set: everything
         that shapes or numbers a serving executable. Two engines with
@@ -640,6 +649,7 @@ class ServeEngine:
             "max_seq_len": self._max_seq_len,
             "prefill_budget": self.prefill_budget,
             "mixed_width": self.mixed_width,
+            "head_rows": self.head_rows,
             "topk_cap": self.topk_cap,
             "buckets": tuple(self.buckets),
             "kv_dtype": self.kv_dtype,
@@ -871,14 +881,14 @@ class ServeEngine:
         heads per device, per-device KV pool bytes, and the analytic
         per-step collective payload (2 all-reduces of the lane
         activations per layer + the embedding psum + the final logits
-        all-gather)."""
+        all-gather, of the head's rows)."""
         if self.tp <= 1:
             return None
         c = self.cache_cfg
         T = self.mixed_width
         act = int(self.act_dtype.itemsize)
         coll = ((2 * self.num_layers + 1) * T * self.hidden * act
-                + T * self._vocab_pad * act)
+                + self.head_rows * self._vocab_pad * act)
         return {
             "mesh": {TENSOR: self.tp},
             "tensor_parallel": self.tp,
@@ -909,7 +919,7 @@ class ServeEngine:
         lane = jnp.zeros((T,), i32)
         args = (self._step_params, pool, lane, lane, lane, lane,
                 jnp.zeros((c.max_seqs, c.pages_per_seq), i32),
-                lane, lane)
+                lane, lane, jnp.zeros((self.head_rows,), i32))
         if self.adapters is not None:
             slabs = {
                 key: jax.ShapeDtypeStruct(
@@ -951,12 +961,13 @@ class ServeEngine:
     def _head_tp(self, params, x, axis):
         """Vocab-column-sharded head: each device computes its V/t
         logit columns (full contraction over E — no partial sums) and
-        ONE all-gather assembles the (T, vocab_pad) logits, replicated,
-        for the argmax/top-k tail. This is the program's only
-        all-gather — the 'sharded vocab, gather only at the final
-        logits' contract."""
+        ONE all-gather assembles the (R, vocab_pad) logits, replicated,
+        for the argmax/top-k tail (`x` is the rows the step's head
+        takes, the emitting lanes', gathered BEFORE the product). This
+        is the program's only all-gather — the 'sharded vocab, gather
+        only at the final logits' contract."""
         local = _dense(params["lm_head"],
-                       self.arch.final_norm(params, x))  # (T, Vp/t)
+                       self.arch.final_norm(params, x))  # (R, Vp/t)
         return jax.lax.all_gather(local, axis, axis=1, tiled=True)
 
     # ---------------- full-sequence forward (the reference) -----------
@@ -1000,7 +1011,7 @@ class ServeEngine:
     # ---------------- the mixed step (chunked prefill + decode) --------
     def _mixed_impl(self, params, pool, tokens, positions, write_pages,
                     write_offs, page_tables, lane_slots, lane_lens,
-                    lane_adapters=None, adapters=None):
+                    head_lanes, lane_adapters=None, adapters=None):
         """ONE serving step over `mixed_width` LANES. Per lane (all
         (T,) int32, HOST-built): the token to embed, its position, the
         physical (page, offset) its K/V lands in (inactive lanes aim at
@@ -1013,10 +1024,13 @@ class ServeEngine:
         prefix page — including pages another request's chunk computes
         in this very step (the intra-step prefix-sharing contract,
         serve/scheduler.py). Inactive lanes compute garbage the host
-        never reads. Returns (greedy (T,), top-k values (T, K), top-k
-        ids (T, K)[, expert counts], pool) — the static top-k head
-        feeds host-side seeded sampling without shipping (T, vocab)
-        logits.
+        never reads. The head runs over R rows, not over the T lanes:
+        the last layer's output at `head_lanes` ((R,) int32, HOST-built:
+        the lanes whose logits the host reads, padded with lane 0; R is
+        `head_rows` in the engine's own step). Returns (greedy (R,),
+        top-k values (R, K), top-k ids (R, K)[, expert counts], pool) —
+        the static top-k head feeds host-side seeded sampling without
+        shipping (R, vocab) logits.
 
         With a serve mesh the same body runs shard_map'd over it: each
         device on its H/t heads of the params and the pool (tp_axis
@@ -1031,8 +1045,8 @@ class ServeEngine:
             return (*out, pool)
 
         args = (params, pool, tokens, positions, write_pages, write_offs,
-                page_tables, lane_slots, lane_lens, lane_adapters,
-                adapters)
+                page_tables, lane_slots, lane_lens, head_lanes,
+                lane_adapters, adapters)
         if self.tp_mesh is None:
             return step(*args)
         import functools
@@ -1045,7 +1059,7 @@ class ServeEngine:
         # empty pytree any prefix spec matches), the emitted token
         # streams replicated (psum/all-gather results are)
         rep, pool_spec = P(), KVPool.specs(TENSOR)
-        ins = (self._param_specs, pool_spec) + (rep,) * 8 + (
+        ins = (self._param_specs, pool_spec) + (rep,) * 9 + (
             self._adapter_specs if self._adapter_specs is not None
             else rep,)
         return shard_map(functools.partial(step, tp_axis=TENSOR),
@@ -1056,7 +1070,8 @@ class ServeEngine:
     @jax.named_scope("serve_step")
     def _mixed_body(self, params, pool, tokens, positions, write_pages,
                     write_offs, page_tables, lane_slots, lane_lens,
-                    lane_adapters=None, adapters=None, tp_axis=None):
+                    head_lanes, lane_adapters=None, adapters=None,
+                    tp_axis=None):
         """The mixed step's body -> (outputs, pool). Every layer
         writes its lanes' K/V to the pool (KVPool.write: the storage
         format's cast or quantization) BEFORE any lane attends, so
@@ -1125,8 +1140,12 @@ class ServeEngine:
                     ad_s, tp_axis, hyb)
                 expert_counts.append(counts)
         with scope("head"):
+            # only the lanes that emit have logits anyone reads: the
+            # head, the argmax and the sort take their rows, not the
+            # step's width
+            x = jnp.take(x, head_lanes, axis=0)              # (R, E)
             logits = (self._head_tp(params, x, tp_axis) if tp_axis
-                      else self.arch.head(params, x))        # (T, V[pad])
+                      else self.arch.head(params, x))        # (R, V[pad])
         with scope("sample"):
             topv, topi = jax.lax.top_k(logits, self.topk_cap)
             out = (jnp.argmax(logits, axis=-1).astype(jnp.int32),
@@ -1729,12 +1748,13 @@ class ServeEngine:
 
     def _dispatch_mixed(self, *args, lane_adapters=None):
         """One mixed-step dispatch: `args` are the step's seven lane
-        arrays. Returns (greedy, topv, topi, expert counts: the step's
-        (layers, experts) live slots per expert on a model with an
-        expert layer, else None) and keeps the returned pool as
-        `self.pool`, so a mid-run audit (check_kv_scales from an
-        `on_step` callback, when sequences are actually resident) reads
-        THIS step's content. On an adapter-armed engine the lanes' slot
+        arrays and the (head_rows,) lanes its head runs over. Returns
+        (greedy, topv, topi: a row for each of those lanes, in their
+        order; expert counts: the step's (layers, experts) live slots
+        per expert on a model with an expert layer, else None) and
+        keeps the returned pool as `self.pool`, so a mid-run audit
+        (check_kv_scales from an `on_step` callback, when sequences are
+        actually resident) reads THIS step's content. On an adapter-armed engine the lanes' slot
         indices + the slabs ride along (read-only — the slabs are NOT
         donated); unarmed engines pass None (an empty pytree, zero
         trace cost, numerics untouched)."""
@@ -1767,7 +1787,8 @@ class ServeEngine:
         pts = self._h2d(
             np.zeros((c.max_seqs, c.pages_per_seq), np.int32))
         self._dispatch_mixed(
-            z, z, z, z, pts, z, self._h2d(np.ones((t,), np.int32)))
+            z, z, z, z, pts, z, self._h2d(np.ones((t,), np.int32)),
+            self._h2d(np.zeros((self.head_rows,), np.int32)))
         if self.adapters is not None:
             # compile the adapter-load scatter on an all-zero row
             # set aimed at the base slot (zeros into zeros — a
@@ -2783,14 +2804,16 @@ class StepEvents:
     ``short_steps`` those of them that take the kernel's one-lane body
     (0 on a model whose calls do not hold it: has_short_body) and
     ``live_rows`` the query rows in them (an item has room for
-    Q_ROWS); ``lanes`` is the step's fixed width and ``emitters`` the
-    lanes whose logits anyone reads (the head and the sampler run over
-    all ``lanes``); ``topv`` / ``topi``
-    the step's fetched (lanes, k) top-k logits and their token ids
-    and ``emit_lanes`` the first lane of each entry of ``emitted``
-    (an entry's tokens come from that lane and the ones after it:
-    what a check against a reference reads, the engine's logits
-    through the cache); on a model with
+    Q_ROWS); ``lanes`` is the rows the head and the sampler run over
+    (the engine's fixed ``head_rows``: the emitting lanes' rows,
+    gathered before the head, padded with lane 0's) and ``emitters``
+    the chunks that emit, a lane each whose logits the host reads;
+    ``topv`` / ``topi`` the step's fetched (lanes, k) top-k logits and
+    their token ids and ``emit_lanes`` the ROW of those arrays at which
+    each entry of ``emitted`` starts (an entry's tokens come from that
+    row and the ones after it: what a check against a reference reads,
+    the engine's logits through the cache; the name is older than the
+    gather, when a row was a lane); on a model with
     an expert layer ``expert_counts`` is the step's (layers, experts)
     live slots per expert as the device counted them,
     ``expert_slots`` the slots the live lanes asked for (live lanes x
@@ -2985,11 +3008,12 @@ class ServeSession:
         if req.is_done():
             self._finish(ev, req)
 
-    def _emit_spec(self, ev: StepEvents, chunk: ChunkPlan, lane0: int,
+    def _emit_spec(self, ev: StepEvents, chunk: ChunkPlan, row0: int,
                    greedy, topv, topi) -> int:
         """Verify a speculative decode chunk and emit its step's
-        tokens: walk lanes lane0..lane0+k (the context token and the k
-        drafts), picking each lane's token exactly as sequential
+        tokens: walk rows row0..row0+k of the step's outputs (the
+        context token's lane and the k drafts', which _pack put side by
+        side), picking each lane's token exactly as sequential
         decode would — lane j's logits are valid BECAUSE every earlier
         pick matched the draft that fed lane j+1 — and stop at the
         first mismatch (that pick IS the corrected token), at EOS /
@@ -3002,8 +3026,8 @@ class ServeSession:
         k = len(chunk.draft_tokens)
         matched = emitted = 0
         for j in range(k + 1):
-            ln = lane0 + j
-            tok = eng._pick_token(req, greedy[ln], topv[ln], topi[ln])
+            row = row0 + j
+            tok = eng._pick_token(req, greedy[row], topv[row], topi[row])
             # (no t_first_token stamp: only decode chunks speculate,
             # and a decoding request already emitted)
             req.out_tokens.append(tok)
@@ -3021,7 +3045,7 @@ class ServeSession:
                       "drafted": k, "accepted": matched,
                       "emitted": emitted})
         ev.emitted.append((req, emitted))
-        ev.emit_lanes.append(lane0)
+        ev.emit_lanes.append(row0)
         if req.is_done():
             self._finish(ev, req)
         return emitted
@@ -3030,12 +3054,16 @@ class ServeSession:
     def _pack(self, plan):
         """The plan's chunks as the mixed program's host-built lane
         arrays (mixed_width wide; inactive lanes aim at the sink page
-        with a visible length of 1). -> (arrays in dispatch order,
-        lane_adapters or None, live lanes, emitters, spec_emitters,
-        the paged kernel's work for these lanes: `work_items` of one
-        call plus `kv_bytes`, what all layers' calls fetch, and
-        LIVE_COUNTS, the step's fixed shape against its live work over
-        all of its calls)."""
+        with a visible length of 1) and, last of them, the (head_rows,)
+        lanes the step's head runs over: the emitters' lanes first, then
+        each speculative chunk's 1 + k lanes side by side, padded with
+        lane 0. -> (arrays in dispatch order, lane_adapters or None,
+        live lanes, emitters, spec_emitters: (chunk, the ROW of the
+        step's outputs that holds its last lane's logits), the paged
+        kernel's work for these lanes: `work_items` of one call plus
+        `kv_bytes`, what all layers' calls fetch, and LIVE_COUNTS, the
+        step's fixed shape against its live work over all of its
+        calls)."""
         eng = self.eng
         cache = eng.cache
         t_w = eng.mixed_width
@@ -3050,8 +3078,8 @@ class ServeSession:
         lane_adapters = np.zeros((t_w,), np.int32) \
             if eng.adapters is not None else None
         lane = 0
-        emitters: List[Tuple[ChunkPlan, int]] = []
-        spec_emitters: List[Tuple[ChunkPlan, int]] = []
+        emit_at: List[Tuple[ChunkPlan, int]] = []      # (chunk, lane)
+        spec_at: List[Tuple[ChunkPlan, int]] = []
         for ch in plan.chunks:
             ctx = ch.req.context
             row = cache.page_tables[ch.req.slot]
@@ -3067,7 +3095,7 @@ class ServeSession:
                     lane_adapters[lane] = aslot
                 lane += 1
             if ch.draft_tokens:
-                spec_emitters.append((ch, lane - 1))
+                spec_at.append((ch, lane - 1))
                 for j, d in enumerate(ch.draft_tokens):
                     pos = ch.end + j
                     tokens[lane] = d
@@ -3080,11 +3108,25 @@ class ServeSession:
                         lane_adapters[lane] = aslot
                     lane += 1
             elif ch.emits:
-                emitters.append((ch, lane - 1))
+                emit_at.append((ch, lane - 1))
         assert lane <= t_w, (
             f"scheduler packed {lane} lanes into a {t_w}-lane step")
+        # the head's rows: a row an emitter, 1 + k a speculative chunk,
+        # lane 0's for the rest
+        read = [ln for _, ln in emit_at]
+        emitters = [(ch, row) for row, (ch, _) in enumerate(emit_at)]
+        spec_emitters: List[Tuple[ChunkPlan, int]] = []
+        for ch, ln in spec_at:
+            spec_emitters.append((ch, len(read)))
+            read += range(ln, ln + 1 + len(ch.draft_tokens))
+        rows = eng.head_rows
+        assert len(read) <= rows, (
+            f"the plan's emitters need {len(read)} rows of the step's "
+            f"head, which has {rows}")
+        head_lanes = np.zeros((rows,), np.int32)
+        head_lanes[:len(read)] = read
         arrays = (tokens, positions, write_pages, write_offs,
-                  cache.page_tables, lane_slots, lane_lens)
+                  cache.page_tables, lane_slots, lane_lens, head_lanes)
         # what the paged kernel will do for these lanes (the count is
         # made where the lanes are made), and the proof's check: a plan
         # whose items passed the grid's bound would lose work
@@ -3141,7 +3183,7 @@ class ServeSession:
             live_steps=sum(n * w["items"] for n, w in lists),
             short_steps=sum(n * w["short_items"] for n, w in lists),
             live_rows=sum(n * w["rows"] for n, w in lists),
-            lanes=t_w, emitters=len(emitters) + len(spec_emitters))
+            lanes=rows, emitters=len(emitters) + len(spec_emitters))
         return arrays, lane_adapters, lane, emitters, spec_emitters, work
 
     def _count_experts(self, ev: StepEvents, counts: np.ndarray,
@@ -3278,7 +3320,9 @@ class ServeSession:
                 "expert_bytes": ev.expert_bytes,
                 **({} if eng.arch.experts_held is None else {
                     "shared_bytes": ev.shared_bytes})}):
-            if not np.isfinite(topv[:lane]).all():
+            # every fetched row is a live lane's (the padding is lane
+            # 0's)
+            if not np.isfinite(topv).all():
                 self.nonfinite_steps += 1
             self.util.append(1.0 - cache.free_pages / c.usable_pages)
             if eng.telemetry.enabled:
@@ -3293,13 +3337,13 @@ class ServeSession:
                 if not ch.draft_tokens:
                     sched.complete_chunk(ch)
             dec_tokens = 0
-            for ch, ln in emitters:
-                self._emit(ev, ch, greedy[ln], topv[ln], topi[ln])
-                ev.emit_lanes.append(ln)
+            for ch, row in emitters:
+                self._emit(ev, ch, greedy[row], topv[row], topi[row])
+                ev.emit_lanes.append(row)
                 if ch.is_decode:
                     dec_tokens += 1
-            for ch, ln in spec_emitters:
-                dec_tokens += self._emit_spec(ev, ch, ln, greedy, topv,
+            for ch, row in spec_emitters:
+                dec_tokens += self._emit_spec(ev, ch, row, greedy, topv,
                                               topi)
         if plan.num_decode_lanes:
             self.decode_times.append(dt)

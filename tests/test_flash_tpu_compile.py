@@ -238,15 +238,17 @@ def test_olmoe_mixed_step_holds_one_expert_kernel_a_layer(topo, as_tpu):
     lm.compile(comp_mode=CompMode.INFERENCE)
     engine = ServeEngine(lm)
     assert engine.expert_impl == engine.attn_impl == "pallas"
-    assert engine.mixed_width == 576
+    assert (engine.mixed_width, engine.head_rows) == (576, 64)
     one = SingleDeviceSharding(topo.devices[0])
     c = engine.cache_cfg
     lane = jax.ShapeDtypeStruct((576,), jnp.int32, sharding=one)
+    rows = jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one)
     tables = jax.ShapeDtypeStruct((c.max_seqs, c.pages_per_seq), jnp.int32,
                                   sharding=one)
     text = jax.jit(engine._mixed_impl).lower(
         _sds(engine._step_params, one), _sds(engine._device_pool(), one),
-        lane, lane, lane, lane, tables, lane, lane).compile().as_text()
+        lane, lane, lane, lane, tables, lane, lane, rows
+    ).compile().as_text()
     engine.close()
     calls = [line for line in text.splitlines()
              if "tpu_custom_call" in line and "grouped_ffn" in line]
@@ -256,3 +258,6 @@ def test_olmoe_mixed_step_holds_one_expert_kernel_a_layer(topo, as_tpu):
                    for line in calls) == 1
     assert "ragged-dot" not in text
     assert not re.search(r"(bf16|f32)\[4608,1024\]", text)
+    # the head runs over the emitting lanes' 64 rows: no array of all
+    # the lanes by the vocabulary
+    assert not re.search(r"(bf16|f32)\[576,1024\]", text)

@@ -267,13 +267,16 @@ def test_step_events_count_the_live_lanes_experts(engine):
     # a decode-only step: two live lanes of 72, and the 70 others
     # route nowhere
     assert seen[1].expert_slots == 2 * TOPK * LAYERS
-    # the logits a reference check reads: the fetched arrays, and the
-    # lane each emitted token came from
+    # the logits a reference check reads: the fetched arrays (a row
+    # for each lane the head ran over: the emitters', lanes 20 and 25
+    # of the first step, then padding), and the row each emitted token
+    # came from
     for ev in (first, seen[1]):
         assert len(ev.emit_lanes) == len(ev.emitted) == 2
         assert ev.topv.shape == ev.topi.shape \
-            == (engine.mixed_width, engine.topk_cap)
-    assert first.emit_lanes == [20, 25] and seen[1].emit_lanes == [0, 1]
+            == (engine.head_rows, engine.topk_cap)
+        assert ev.lanes == engine.head_rows == engine.cache_cfg.max_seqs
+    assert first.emit_lanes == [0, 1] and seen[1].emit_lanes == [0, 1]
 
 
 def test_mixed_step_lowers_with_the_expert_scopes(engine):
@@ -282,7 +285,7 @@ def test_mixed_step_lowers_with_the_expert_scopes(engine):
     pts = np.zeros((c.max_seqs, c.pages_per_seq), np.int32)
     text = jax.jit(engine._mixed_impl).lower(
         engine._step_params, engine._device_pool(), z, z, z, z, pts, z,
-        z + 1).as_text(debug_info=True)
+        z + 1, z[:engine.head_rows]).as_text(debug_info=True)
     for path in ("serve_step/embed/", "serve_step/layer0/ln/",
                  "serve_step/layer0/qkv/", "serve_step/layer1/kv_write/",
                  "serve_step/layer1/attn/", "serve_step/layer0/attn_out/",

@@ -344,8 +344,10 @@ def test_every_step_counts_its_fixed_shape_against_its_live_work(
             assert 0 < ev.live_steps <= ev.grid_steps
             assert 0 <= ev.short_steps <= ev.live_steps
             assert 0 < ev.live_rows <= ev.live_steps * Q_ROWS
-            assert ev.lanes == eng.mixed_width
+            assert ev.lanes == eng.head_rows < eng.mixed_width
             assert ev.emitters == len(ev.emit_lanes) <= ev.lanes
+            assert ev.emit_lanes == list(range(ev.emitters))
+            assert ev.topv.shape == (ev.lanes, eng.topk_cap)
             steps.append({k: getattr(ev, k) for k in E.LIVE_COUNTS})
         assert s.stats_dict()["attn_steps"] == {
             "live": sum(st["live_steps"] for st in steps),
@@ -392,8 +394,8 @@ def test_the_window_list_s_calls_have_a_name_of_their_own(kind):
     z = np.zeros((eng.mixed_width,), np.int32)
     pts = np.zeros((c.max_seqs, c.pages_per_seq), np.int32)
     text = jax.jit(eng._mixed_impl).lower(
-        eng._step_params, eng._device_pool(), z, z, z, z, pts, z, z + 1
-    ).as_text(debug_info=True)
+        eng._step_params, eng._device_pool(), z, z, z, z, pts, z, z + 1,
+        z[:eng.head_rows]).as_text(debug_info=True)
     names = set(re.findall(r"paged_ragged_v2\w*", text))
     assert names == ({"paged_ragged_v2", "paged_ragged_v2_window"}
                      if kind == "hybrid" else {"paged_ragged_v2"})
