@@ -410,6 +410,34 @@ class FFModel:
         self.add_op(op)
         return tuple(op.outputs) if emit_memory else op.output
 
+    # ---- MiniCPM-SALA's two mixers (models/minicpm_sala.py) ----
+    def lightning_attention(self, input: Tensor, positions: Tensor,
+                            num_heads: int, head_dim: int,
+                            layer_index: int = 0, published_layers: int = 1,
+                            rotary_theta: float = 10000.0,
+                            eps: float = 1e-6,
+                            name: Optional[str] = None) -> Tensor:
+        """Lightning linear attention (ops/linear_attention.py)."""
+        from .ops.linear_attention import LightningAttention
+        op = LightningAttention(
+            self, name or self._fresh_name("linear"), [input, positions],
+            num_heads, head_dim, layer_index, published_layers,
+            rotary_theta, eps)
+        return self.add_op(op).output
+
+    def sparse_attention(self, input: Tensor, num_heads: int,
+                         num_kv_heads: int, head_dim: int, sparse=None,
+                         eps: float = 1e-6, qk_norm_init: float = 1.0,
+                         name: Optional[str] = None) -> Tensor:
+        """Block-sparse attention over a learned selection
+        (ops/sparse_attention.py); `sparse` a SparseConfig."""
+        from .ops.sparse_attention import SparseAttention, SparseConfig
+        op = SparseAttention(
+            self, name or self._fresh_name("sparse"), [input], num_heads,
+            num_kv_heads, head_dim, sparse or SparseConfig(), eps,
+            qk_norm_init=qk_norm_init)
+        return self.add_op(op).output
+
     def gated_memory_unit(self, input: Tensor, memory: Tensor,
                           name: Optional[str] = None) -> Tensor:
         from .ops.gated import GatedMemoryUnit

@@ -10,6 +10,7 @@ from .moe import build_moe_fused, build_moe_reference
 from .candle_uno import build_candle_uno
 from .nmt_lstm import build_nmt_lstm, build_nmt_seq2seq
 from .cmdaplus import build_cmdaplus_lm
+from .minicpm_sala import build_minicpm_sala_lm
 from .olmoe import build_olmoe_lm
 from .phi4flash import build_phi4flash_lm
 
@@ -23,6 +24,7 @@ __all__ = [
     "build_moe_reference",
     "build_moe_fused",
     "build_cmdaplus_lm",
+    "build_minicpm_sala_lm",
     "build_olmoe_lm",
     "build_phi4flash_lm",
     "build_candle_uno",

@@ -22,6 +22,8 @@ from .attention import MultiHeadAttention
 from .moe import GroupBy, Aggregate
 from .moe_ffn import MoEFFN
 from .ssm import SelectiveScanMixer
+from .linear_attention import LightningAttention
+from .sparse_attention import SparseAttention
 from .gated import GatedFFN, GatedMemoryUnit, TiedHead
 from .diff_attention import DifferentialAttention
 from .pipeline import PipelineBlocks
@@ -54,6 +56,8 @@ __all__ = [
     "Aggregate",
     "MoEFFN",
     "SelectiveScanMixer",
+    "LightningAttention",
+    "SparseAttention",
     "GatedFFN",
     "GatedMemoryUnit",
     "TiedHead",

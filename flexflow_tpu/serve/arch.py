@@ -8,7 +8,7 @@ it cannot do, which raises at engine build (no silent fallback). A
 description reads the parameters of a compiled FFModel through the op
 names its builder wrote, and mirrors those ops' numerics.
 
-Four clients: `TransformerLM` (models/transformer.build_transformer_lm:
+Five clients: `TransformerLM` (models/transformer.build_transformer_lm:
 learned positions, LayerNorm, ReLU feed-forward — the OPT block),
 `OLMoE` (models/olmoe.build_olmoe_lm: RMSNorm, rotary attention with
 QK-norm, dropless top-k SwiGLU experts), `Phi4Flash`
@@ -16,11 +16,15 @@ QK-norm, dropless top-k SwiGLU experts), `Phi4Flash`
 memory and cross layers; no positions) and `CommandAPlus`
 (models/cmdaplus.build_cmdaplus_lm: a parallel block of grouped window
 or full attention beside sigmoid-routed experts, of which this chip
-holds a share, and averaged shared experts).
+holds a share, and averaged shared experts) and `MiniCPMSala`
+(models/minicpm_sala.build_minicpm_sala_lm: block-sparse attention over
+a learned selection of the context in some layers, lightning linear
+attention with a matrix state a sequence in the others).
 
 What a description answers (docs/serving.md "What a description must
 answer"): the dimensions; per layer the MIXER KIND (`mixer(i)`:
-"attn", or one of models/phi4flash's five); the geometry of the K/V it
+"attn", one of models/phi4flash's five, or models/minicpm_sala's two);
+the geometry of the K/V it
 pages (`kv_heads`, `kv_head_dim`, `paged_layers`, `attn_scale`); what a
 sequence holds besides pages (`hybrid_spec`, a serve/kv_cache.HybridSpec
 or None); and `refuse`, which raises BY NAME for every engine path the
@@ -35,8 +39,11 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from ..models.minicpm_sala import LINEAR, SPARSE
 from ..models.phi4flash import CROSS, FULL, GMU, SSM, WINDOW
 from ..ops import diff_attention as DA
+from ..ops import linear_attention as LA
+from ..ops import sparse_attention as SA
 from ..ops import ssm as S
 from ..ops.common import rms_norm, rotary
 from ..ops.gated import gated_ffn, gated_memory
@@ -94,6 +101,19 @@ def _count_layers(ops) -> int:
     return n
 
 
+def _graph_logits(model, params, tokens, positions: bool):
+    """(1, S) tokens -> (S, V): the op graph's own full-sequence
+    forward (no cache, no kernel), the engine's naive oracle;
+    `positions`: the graph takes the tokens' positions as an input."""
+    inputs = {"tokens": tokens}
+    if positions:
+        inputs["positions"] = jnp.arange(
+            tokens.shape[1], dtype=jnp.int32)[None, :]
+    values, _ = model.executor.forward_values(
+        params, {}, inputs, training=False, rng=None)
+    return values[model.ops[-1].outputs[0].uid][0]
+
+
 class Description:
     """What every description answers the same way unless it says
     otherwise: one attention layer a layer, a key/value head a query
@@ -111,6 +131,13 @@ class Description:
     # whose expert is absent
     experts_held = None
     window = 0
+    # width of the selector's row a page (serve/kv_cache.KVPool.kc); 0:
+    # the model selects nothing and the pool has no such leaf
+    selector_dim = 0
+    # a model that SELECTS its context: the positions under which a
+    # lane attends every key before it, through the paged kernel (0: no
+    # selection, every lane does)
+    dense_len = 0
     # differential attention: the paged call's output goes through
     # `diff_norm` before `attn_out`
     differential = False
@@ -595,11 +622,7 @@ class Phi4Flash(Description):
                        preferred_element_type=jnp.float32).astype(h.dtype)
 
     def forward_logits(self, params, tokens):
-        """(1, S) tokens -> (S, V): the op graph's own full-sequence
-        forward (no cache, no kernel), the engine's naive oracle."""
-        values, _ = self.model.executor.forward_values(
-            params, {}, {"tokens": tokens}, training=False, rng=None)
-        return values[self.model.ops[-1].outputs[0].uid][0]
+        return _graph_logits(self.model, params, tokens, positions=False)
 
 
 class CommandAPlus(Description):
@@ -772,16 +795,153 @@ class CommandAPlus(Description):
         return y.astype(h.dtype)
 
     def forward_logits(self, params, tokens):
-        """(1, S) tokens -> (S, V): the op graph's own full-sequence
-        forward (no cache, no kernel), the engine's naive oracle."""
-        positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
-        values, _ = self.model.executor.forward_values(
-            params, {}, {"tokens": tokens, "positions": positions},
-            training=False, rng=None)
-        return values[self.model.ops[-1].outputs[0].uid][0]
+        return _graph_logits(self.model, params, tokens, positions=True)
 
 
-SHAPES = (TransformerLM, OLMoE, Phi4Flash, CommandAPlus)
+class MiniCPMSala(Description):
+    """The build_minicpm_sala_lm block (models/minicpm_sala.py holds the
+    equations, ops/sparse_attention.py and ops/linear_attention.py the
+    mixers'). Served by the mixed step on one device.
+
+    What it pages: the SPARSE layers' K and V (`kv_heads` grouped
+    key/value heads) and, a row a page a head, the selector's compressed
+    keys (`selector_dim`). How the pool lays a head's pages out is the
+    pool's (serve/kv_cache.KVCacheConfig.split_heads).
+    What a sequence holds besides (`hybrid_spec`): a (D, H * D) f32
+    matrix state for each LINEAR layer; no ring, no tail."""
+
+    kind = "minicpm_sala"
+    builder = "build_minicpm_sala_lm"
+    reads = ("tok_embed", "lm_head", "embed_scale", "head_scale",
+             "final_norm")
+    _state = ("a sequence's matrix states live in its slot, not in "
+              "pages: ")
+    refused = {
+        "tp": "single-device: the matrix states, the grouped heads and "
+              "the selection are not split over a mesh",
+        "adapters": "no adapter pool for the gated mixers",
+        "speculation": _state + "rolling back rejected tokens would "
+                       "need a snapshot of the state (serve_spec_decode "
+                       "must be off)",
+        "prefix_cache": _state + "a prefix hit would need the state at "
+                        "the prefix's end (serve_prefix_cache must be "
+                        "off)",
+        "host_tier": _state + "the host tier spills pages only",
+        "handoff": _state + "the disaggregated handoff ships pages only",
+    }
+
+    def __init__(self, model, ops):
+        self.model = model
+        self.vocab_size = ops["tok_embed"].num_entries
+        self.layer_norm = True
+        n = 0
+        while f"layer{n}_norm1" in ops:
+            n += 1
+        self.num_layers = n
+        self.kinds = [SPARSE if f"layer{i}_sparse" in ops else LINEAR
+                      for i in range(n)]
+        self.sparse_layers = [i for i, k in enumerate(self.kinds)
+                              if k == SPARSE]
+        self.linear_layers = [i for i, k in enumerate(self.kinds)
+                              if k == LINEAR]
+        if not (self.sparse_layers and self.linear_layers and all(
+                f"layer{i}_linear" in ops for i in self.linear_layers)):
+            raise ValueError(
+                "ServeEngine reads a build_minicpm_sala_lm-shaped model: "
+                "sparse AND linear attention layers")
+        sp = ops[f"layer{self.sparse_layers[0]}_sparse"]
+        lin = ops[f"layer{self.linear_layers[0]}_linear"]
+        self.num_heads, self._kv_heads = sp.num_heads, sp.num_kv_heads
+        self.head_dim = sp.head_dim
+        self.sparse = sp.sparse
+        self.dense_len = sp.sparse.dense_len
+        self.selector_dim = sp.head_dim
+        self.linear_heads, self.linear_head_dim = lin.num_heads, lin.head_dim
+        self.rope_theta = lin.rotary_theta
+        # a kept layer's decays are those of its PUBLISHED index
+        self.decays = {i: ops[f"layer{i}_linear"].decay()
+                       for i in self.linear_layers}
+        self.hidden = sp.embed_dim
+        self.ln_eps = ops["layer0_norm1"].eps
+        self.scale_emb = ops["embed_scale"].scalar
+        self.residual = ops["layer0_scale1"].scalar
+        self.head_scale = ops["head_scale"].scalar
+        self.act_dtype = jnp.dtype(ops["tok_embed"].out_dtype)
+        self.ff_dim = ops["layer0_ffn"].hidden_dim
+        # rotary has no table: the positions served are the graph's own
+        self.max_positions = int(ops["tok_embed"].inputs[0].shape[1])
+
+    def mixer(self, i: int) -> str:
+        return self.kinds[i]
+
+    def hybrid_spec(self, chunk: int):
+        from .kv_cache import HybridSpec
+        d = self.linear_head_dim
+        return HybridSpec(
+            window_layers=0, window=0, chunk=int(chunk),
+            state_layers=len(self.linear_layers),
+            state_shape=(d, self.linear_heads * d))
+
+    @property
+    def kv_heads(self) -> int:
+        return self._kv_heads
+
+    @property
+    def paged_layers(self) -> int:
+        return len(self.sparse_layers)
+
+    def attn_calls(self) -> Tuple[int, int]:
+        # a paged call a key/value head of a sparse layer: the lanes
+        # under dense_len, over the table's first dense_len positions
+        return self.paged_layers * self.kv_heads, 0
+
+    def embed(self, params, tokens, positions):
+        x = jnp.take(params["tok_embed"]["kernel"], tokens, axis=0,
+                     mode="clip").astype(self.act_dtype)
+        return x * self.scale_emb
+
+    def norm1(self, params, i, x):
+        return rms_norm(x, params[f"layer{i}_norm1"]["scale"], self.ln_eps)
+
+    def sparse_qkv(self, params, i, h):
+        """h (T, E) -> q (T, H, D), k, v (T, G, D), q and k normed; no
+        position signal."""
+        return SA.project_qkv(params[f"layer{i}_sparse"], h, self.ln_eps)
+
+    def sparse_out(self, params, i, o, h, x):
+        y = SA.gate_and_project(params[f"layer{i}_sparse"], o, h)
+        return x + y * self.residual
+
+    def linear_qkv(self, params, i, h, positions):
+        """h (T, E), positions (T,) -> q, k (normed, rotated), v, each
+        (T, H, D)."""
+        return LA.project_qkv(params[f"layer{i}_linear"], h, positions,
+                              self.rope_theta, self.ln_eps)
+
+    def linear_out(self, params, i, o, h, x):
+        """o (T, H, D) f32, the recurrence's output over sqrt(D)."""
+        y = LA.gate_and_project(params[f"layer{i}_linear"], o, h,
+                                self.ln_eps)
+        return x + y * self.residual
+
+    def ffn(self, params, i, x, live=None, psum_axis=None, lora=None):
+        with jax.named_scope("ffn"):
+            h = rms_norm(x, params[f"layer{i}_norm2"]["scale"], self.ln_eps)
+            f = gated_ffn(params[f"layer{i}_ffn"], h)
+            return x + f * self.residual, None
+
+    def final_norm(self, params, x):
+        return rms_norm(x, params["final_norm"]["scale"], self.ln_eps)
+
+    def head(self, params, x):
+        h = self.final_norm(params, x) * self.head_scale
+        return _dense(params["lm_head"], h)
+
+    def forward_logits(self, params, tokens):
+        return _graph_logits(self.model, params, tokens, positions=True)
+
+
+SHAPES = (TransformerLM, OLMoE, Phi4Flash, CommandAPlus, MiniCPMSala)
 
 
 def describe(model):

@@ -71,12 +71,14 @@ from ..parallel.mesh import TENSOR, replica_devices, serve_tensor_mesh
 from ..utils.faults import FaultInjector, TransientError, injector_for
 from ..utils.telemetry import (Telemetry, pow2_bucket, serve_metrics,
                                telemetry_for)
-from ..ops import ssm
-from .arch import ATTN, CROSS, FULL, GMU, SSM, WINDOW, _dense, describe
+from ..ops import linear_attention, ssm
+from .arch import (ATTN, CROSS, FULL, GMU, LINEAR, SPARSE, SSM, WINDOW,
+                   _dense, describe)
 from .kv_cache import (HybridPool, KVCacheConfig, KVPool, PagedKVCache,
                        kv_storage_dtype, ring_tables)
 from .scheduler import (ChunkPlan, ContinuousBatchingScheduler, Request,
                         RequestOutcome, RequestState, SampleParams)
+from .sparse_paged import paged_sparse_attention, stride_keys
 
 # pad bias for vocab columns the head padding invents (vocab % t != 0):
 # a padded logit must never win argmax or enter the top-k window
@@ -266,7 +268,8 @@ class ServeEngine:
             cfg, num_layers=self.arch.paged_layers,
             num_heads=self.kv_heads, head_dim=self.kv_head_dim,
             max_seq_len=max_seq_len, tensor_parallel=self.tp,
-            hybrid=self.arch.hybrid_spec(self.prefill_budget))
+            hybrid=self.arch.hybrid_spec(self.prefill_budget),
+            selector_dim=self.arch.selector_dim)
         self.cache_cfg.validate()
         self.admit_watermark = float(
             getattr(cfg, "serve_admit_watermark", 0.02))
@@ -374,7 +377,11 @@ class ServeEngine:
         hyb = self.cache_cfg.hybrid
         self.scan_impl = None
         if hyb is not None and hyb.state_layers:
-            self.scan_impl = self.attn_impl if ssm_scan.supported(
+            # a linear-attention layer's matrix state has the twin alone
+            # (ops/linear_attention.segmented_lightning)
+            self.scan_impl = self.attn_impl if SSM in map(
+                self.arch.mixer, range(self.num_layers)
+            ) and ssm_scan.supported(
                 self.mixed_width, *hyb.state_shape) else JNP
         # the expert layer's gated expert is one fused kernel
         # (kernels/grouped_ffn.py, by the same arguments as the paged
@@ -395,8 +402,17 @@ class ServeEngine:
         # (tests/test_paged_work_list.py drives a busy session at it).
         self.attn_block_pages = max(
             1, self.attn_block_kv // self.cache_cfg.page_size)
+        # a model that SELECTS its context (arch.dense_len) walks pages
+        # in the paged kernel only for its lanes under dense_len: the
+        # list is built over the table's first `dense_pages` columns,
+        # and the grid is bounded by them, not by the positions served
+        # (0: every column)
+        self.dense_pages = min(
+            self.cache_cfg.pages_per_seq,
+            -(-self.arch.dense_len // self.cache_cfg.page_size))
         self.attn_max_items = max_work_items(
-            self.mixed_width, self.cache_cfg.pages_per_seq,
+            self.mixed_width,
+            self.dense_pages or self.cache_cfg.pages_per_seq,
             self.attn_block_pages, Q_ROWS,
             slot_changes=self.cache_cfg.max_seqs)
         # a window layer's list is built apart (its items start at the
@@ -1113,10 +1129,14 @@ class ServeEngine:
         # the paged kernel's work list: from the lane arrays, once for
         # all the layers (the jnp attention reads the lane arrays)
         work = None
+        # what the paged calls walk: every lane's pages — or, where the
+        # model selects its context, the lanes under its dense_len
+        walked = (page_tables, lane_lens) if not self.dense_pages \
+            else self._dense_lanes(page_tables, positions, lane_lens)
         if self.attn_impl != JNP:
             with scope("work_list"):
                 work = build_work_list(
-                    page_tables, lane_slots, lane_lens,
+                    walked[0], lane_slots, walked[1],
                     page_size=self.cache_cfg.page_size,
                     block_pages=self.attn_block_pages,
                     max_items=self.attn_max_items)
@@ -1129,6 +1149,9 @@ class ServeEngine:
         hyb = self._hybrid_lanes(positions, write_pages, lane_slots,
                                  lane_lens) \
             if self.cache_cfg.hybrid is not None else None
+        if self.dense_pages:
+            hyb = {**(hyb or {}), "dense_tables": walked[0],
+                   "dense_lens": walked[1]}
         expert_counts = []
         for i in range(self.num_layers):
             with scope(f"layer{i}"):
@@ -1154,6 +1177,13 @@ class ServeEngine:
             out += (jnp.stack(expert_counts),)           # (layers, E)
         return out, pool
 
+    def _dense_lanes(self, page_tables, positions, lane_lens):
+        """-> (the page tables' first `dense_pages` columns, the lanes'
+        lengths with 1 for a lane past the selector's dense_len): what
+        the paged call of a sparse layer walks."""
+        return (page_tables[:, :self.dense_pages],
+                jnp.where(positions < self.arch.dense_len, lane_lens, 1))
+
     def _hybrid_lanes(self, positions, write_pages, lane_slots,
                       lane_lens) -> dict:
         """What the step's state-space and window layers share, from
@@ -1174,15 +1204,22 @@ class ServeEngine:
             state = c.hybrid.state_layers > 0
             starts = ssm.run_starts(lane_slots, positions) if state \
                 else None
-            rings = ring_tables(c, jnp)
-            page = positions // c.page_size
-            hyb = {"rings": rings, "memory": None, "work": None}
+            # rings are the window layers' alone: no table, write
+            # addresses or work list of them without one
+            ringed = c.hybrid.window_layers > 0
+            if ringed:
+                rings = ring_tables(c, jnp)
+                page = positions // c.page_size
+            hyb = {"memory": None, "work": None, "live": live}
             if state:
                 hyb.update(
                     starts=starts, offsets=ssm.run_offsets(starts),
                     wslots=ssm.run_write_slots(starts, live, lane_slots,
                                                c.max_seqs),
                     live_lanes=jnp.max(jnp.where(live, lane, 0)))
+            if not ringed:
+                return hyb
+            hyb["rings"] = rings
             hyb["ring_pages"] = jnp.where(live, rings[lane_slots, page], 0)
             if self.attn_impl != JNP:
                 hyb["work"] = build_work_list(
@@ -1222,6 +1259,13 @@ class ServeEngine:
         if kind == SSM:
             x, pool = self._ssm_layer(params, i, x, h, positions, pool,
                                       lane_slots, hyb)
+        elif kind == LINEAR:
+            x, pool = self._linear_layer(params, i, x, h, positions, pool,
+                                         lane_slots, hyb)
+        elif kind == SPARSE:
+            x, pool = self._sparse_layer(
+                params, i, x, h, positions, pool, write_pages, write_offs,
+                page_tables, lane_slots, work, scale, hyb)
         elif kind == GMU:
             with scope("gmu"):
                 x = arch.gmu(params, i, h, hyb["memory"], x)
@@ -1338,6 +1382,88 @@ class ServeEngine:
                 hyb["memory"] = y.astype(x.dtype)
         with scope("ssm_proj"):
             x = arch.ssm_out(params, i, g, x)
+        return x, pool
+
+    def _linear_layer(self, params, i, x, h, positions, pool, lane_slots,
+                      hyb):
+        """The lightning linear-attention mixer of layer `i` over the
+        step's lanes -> (x, pool): `linear_proj` (the projections, the
+        QK-norm and rotation; the output norm, gate and projection),
+        `linear_scan` (the recurrence from each run's slot state and
+        the state's write-back, ops/linear_attention.
+        segmented_lightning, in f32; a slab of another dtype — no
+        configuration's — is read and rounded back at the step's
+        edge)."""
+        scope = jax.named_scope
+        arch = self.arch
+        j = arch.linear_layers.index(i)
+        with scope("linear_proj"):
+            q, k, v = arch.linear_qkv(params, i, h, positions)
+        with scope("linear_scan"):
+            o, state = linear_attention.segmented_lightning(
+                q, k, v, arch.decays[i], pool.state[j].astype(jnp.float32),
+                lane_slots, positions, hyb["live"], hyb["starts"],
+                hyb["offsets"])
+            pool = dataclasses.replace(pool, state=pool.state.at[j].set(
+                state.astype(pool.state.dtype)))
+        with scope("linear_proj"):
+            x = arch.linear_out(params, i, o, h, x)
+        return x, pool
+
+    def _sparse_layer(self, params, i, x, h, positions, pool, write_pages,
+                      write_offs, page_tables, lane_slots, work, scale,
+                      hyb):
+        """The block-sparse attention mixer of layer `i` -> (x, pool):
+        `qkv`, `kv_write`, `sparse_compress` (the compressed key of
+        every stride a lane's token completes, from the pages just
+        written, to the selector's row of that lane's page), `attn`
+        (the lanes under the selector's dense_len: the paged kernel
+        over the table's first dense_len positions, `work` its list, a
+        call a key/value head: each head's pages are a pool layer of
+        their own, KVCacheConfig.head_layers),
+        then for the lanes past it `sparse_score` (each lane against
+        its sequence's compressed keys), `sparse_select` (block scores,
+        forced blocks, top-k) and `sparse_attn` (the selected blocks'
+        pages, gathered a lane at a time), `attn_out` (the gate and
+        the output projection)."""
+        scope = jax.named_scope
+        arch = self.arch
+        sc = arch.sparse
+        # a pool layer a key/value head
+        layers = self.cache_cfg.head_layers(arch.sparse_layers.index(i))
+        with scope("qkv"):
+            q, k, v = arch.sparse_qkv(params, i, h)
+        kv = pool.full
+        with scope("kv_write"):
+            for g, layer in enumerate(layers):
+                kv = kv.write(layer, write_pages, write_offs,
+                              k[:, g:g + 1], v[:, g:g + 1])
+        with scope("sparse_compress"):
+            tables = jnp.take(page_tables, lane_slots, axis=0)
+            for layer in layers:
+                rows, done = stride_keys(kv, layer, tables, positions, sc)
+                kv = kv.write_selector(
+                    layer, jnp.where(done, write_pages, 0), rows)
+        pool = dataclasses.replace(pool, full=kv)
+        with scope("attn"):
+            each = self.num_heads // self.kv_heads
+            o_dense = []
+            for g, layer in enumerate(layers):
+                k_pages, v_pages, k_scales, v_scales = kv.layer(layer)
+                o_dense.append(paged_attention_ragged_v2(
+                    q[:, g * each:(g + 1) * each], k_pages, v_pages,
+                    hyb["dense_tables"], lane_slots, hyb["dense_lens"],
+                    k_scales=k_scales, v_scales=v_scales, scale=scale,
+                    block_kv=self.attn_block_kv, work=work,
+                    **self._attn_kw))
+            o_dense = jnp.concatenate(o_dense, axis=1)
+        o = paged_sparse_attention(q, kv, layers, page_tables, lane_slots,
+                                   positions, sc)
+        with scope("sparse_attn"):
+            o = jnp.where((positions < sc.dense_len)[:, None, None],
+                          o_dense, o)
+        with scope("attn_out"):
+            x = arch.sparse_out(params, i, o, h, x)
         return x, pool
 
     # ---------------- disaggregated page handoff -----------------------
@@ -2783,6 +2909,10 @@ class ServeEngine:
 # ServeSession._pack: StepEvents attributes and `dispatch` span arguments
 LIVE_COUNTS = ("grid_steps", "live_steps", "short_steps", "live_rows",
                "lanes", "emitters")
+# what a step's selection did, counted there too, on a model that
+# selects its context (arch.selector_dim)
+SELECT_COUNTS = ("sparse_lanes", "blocks_selected", "blocks_visible",
+                 "selected_kv_bytes", "selector_bytes")
 
 
 class StepEvents:
@@ -2833,7 +2963,16 @@ class StepEvents:
     writes for its runs, ``ssm_runs`` the runs (segments) each scan
     covers, ``window_kv_bytes`` / ``full_kv_bytes`` the page fetches of
     the window layers' calls and of the calls on the full layer's
-    pages (its own and every cross layer's); ``dispatched``
+    pages (its own and every cross layer's); on a model that selects
+    its context (arch.selector_dim; SELECT_COUNTS) ``sparse_lanes`` is
+    the live lanes past the selector's dense_len, ``blocks_visible`` /
+    ``blocks_selected`` the blocks those lanes see and select over all
+    sparse layers and key/value heads; ``selected_kv_bytes`` and
+    ``selector_bytes`` are what the DEVICE gathers a step, of K and V
+    blocks and of compressed keys: every lane of the step's width
+    gathers, live and past dense_len or not, so both are constants of
+    the shapes (``kv_bytes_read`` stays the paged calls' page fetches);
+    ``dispatched``
     False for a planning-only iteration (rung-4
     rejections / whole-set preemption under injected pressure — the
     scheduler's forced-progress rule guarantees re-planning
@@ -2847,7 +2986,7 @@ class StepEvents:
                  "experts_touched", "expert_bytes", "expert_load_max",
                  "slots_held", "shared_bytes", "lanes_past_window",
                  "state_bytes", "ssm_runs", "window_kv_bytes",
-                 "full_kv_bytes")
+                 "full_kv_bytes", *SELECT_COUNTS)
 
     def __init__(self, plan=None):
         self.dispatched = False
@@ -2884,6 +3023,8 @@ class StepEvents:
         self.ssm_runs = 0
         self.window_kv_bytes = 0
         self.full_kv_bytes = 0
+        for key in SELECT_COUNTS:
+            setattr(self, key, 0)
 
 
 class ServeSession:
@@ -2943,7 +3084,7 @@ class ServeSession:
                               "touched": 0, "bytes": 0}
         self.expert_counts_total = None     # (layers, experts) int64
         # the rings' page table never changes (kv_cache.ring_tables)
-        self._ring_tables = ring_tables(c) if c.hybrid else None
+        self._ring_tables = ring_tables(c) if c.ring_pages else None
         self._retries0 = engine._retries
         self._rejected_seen = 0   # flight-recorder rejection trigger
         self._t0 = time.perf_counter()
@@ -3132,17 +3273,24 @@ class ServeSession:
         # whose items passed the grid's bound would lose work
         c = eng.cache_cfg
         group = eng.num_heads // eng.kv_heads   # query heads a K/V head
+        arch = eng.arch
+        walked = (cache.page_tables, lane_lens)
+        if eng.dense_pages:
+            # a model that selects its context: the paged calls walk the
+            # lanes under dense_len (ServeEngine._dense_lanes)
+            walked = (cache.page_tables[:, :eng.dense_pages], np.where(
+                positions < arch.dense_len, lane_lens, 1))
         work = work_items(
-            lane_lens, lane_slots, cache.page_tables, page_size=ps,
+            walked[1], lane_slots, walked[0], page_size=ps,
             block_kv_pages=eng.attn_block_pages,
             max_items=eng.attn_max_items, live_lanes=lane, group=group)
         if work["total"] > work["grid"]:
             raise RuntimeError(
                 f"the plan makes {work['total']} attention work items, "
                 f"the kernel's grid holds {work['grid']}")
-        page_bytes = kv_page_bytes(ps, eng.kv_heads, eng.kv_head_dim,
+        # what ONE call fetches of a page: a pool layer's heads
+        page_bytes = kv_page_bytes(ps, c.layer_heads, eng.kv_head_dim,
                                    c.kv_itemsize, eng.kv_quantized)
-        arch = eng.arch
         full_calls, window_calls = arch.attn_calls()
         lists = [(full_calls, work)]        # (calls that walk it, list)
         if c.hybrid is None:
@@ -3154,16 +3302,18 @@ class ServeSession:
             # the window layers' calls walk the rings under the
             # window's list; a scan reads and writes one state and one
             # tail a RUN
-            ring = work_items(
-                lane_lens, lane_slots, self._ring_tables, page_size=ps,
-                block_kv_pages=eng.attn_block_pages,
-                max_items=eng.window_max_items, live_lanes=lane,
-                window=arch.window, group=group)
-            if ring["total"] > ring["grid"]:
-                raise RuntimeError(
-                    f"the plan makes {ring['total']} window work items, "
-                    f"the kernel's grid holds {ring['grid']}")
-            lists.append((window_calls, ring))
+            ring = {"page_fetches": 0}
+            if self._ring_tables is not None:
+                ring = work_items(
+                    lane_lens, lane_slots, self._ring_tables, page_size=ps,
+                    block_kv_pages=eng.attn_block_pages,
+                    max_items=eng.window_max_items, live_lanes=lane,
+                    window=arch.window, group=group)
+                if ring["total"] > ring["grid"]:
+                    raise RuntimeError(
+                        f"the plan makes {ring['total']} window work "
+                        f"items, the kernel's grid holds {ring['grid']}")
+                lists.append((window_calls, ring))
             work["full_kv_bytes"] = (full_calls * work["page_fetches"]
                                      * page_bytes)
             work["window_kv_bytes"] = (window_calls * ring["page_fetches"]
@@ -3171,11 +3321,35 @@ class ServeSession:
             work["kv_bytes"] = (work["full_kv_bytes"]
                                 + work["window_kv_bytes"])
             work["lanes_past_window"] = int(
-                (lane_lens[:lane] > arch.window).sum())
+                (lane_lens[:lane] > arch.window).sum()) \
+                if arch.window else 0
             work["ssm_runs"] = len(plan.chunks) \
                 if c.hybrid.state_layers else 0
             work["state_bytes"] = (2 * len(plan.chunks)
                                    * c.hybrid.state_bytes)
+        if eng.dense_pages:
+            # what the selection does for the live lanes past dense_len,
+            # counted where the lanes are made: a lane at position t sees
+            # t // block + 1 blocks and selects min(topk, that) of them a
+            # key/value head, whatever the scores say. What the device
+            # MOVES for it does not depend on the lanes: every lane of
+            # the step's width scores its table's every stride and
+            # gathers min(topk, a table's blocks) blocks, read or not
+            sc = arch.sparse
+            past = positions[:lane][positions[:lane] >= sc.dense_len]
+            visible = past // sc.block_size + 1
+            heads = eng.kv_heads * len(arch.sparse_layers)
+            gathered = eng.mixed_width * heads * min(
+                sc.topk, c.pages_per_seq * ps // sc.block_size)
+            work.update(
+                sparse_lanes=len(past),
+                blocks_visible=int(visible.sum()) * heads,
+                blocks_selected=int(np.minimum(visible, sc.topk).sum())
+                * heads,
+                selected_kv_bytes=gathered * 2 * sc.block_size
+                * eng.kv_head_dim * c.kv_itemsize,
+                selector_bytes=eng.mixed_width * heads * c.pages_per_seq
+                * c.selector_dim * int(c.selector_dtype.itemsize))
         # the step's fixed shape against its live work (LIVE_COUNTS):
         # every call walks its list's whole grid whatever is live
         work.update(
@@ -3279,6 +3453,8 @@ class ServeSession:
                 ev.lanes_past_window = work["lanes_past_window"]
                 counted += ("state_bytes", "window_kv_bytes",
                             "full_kv_bytes")
+            if eng.dense_pages:
+                counted += SELECT_COUNTS
             for key in counted:
                 setattr(ev, key, work[key])
             self.attn_steps["live"] += ev.live_steps

@@ -130,8 +130,10 @@ class HybridSpec:
     """What a sequence holds besides pages (module docstring): the
     window layers' ring and the state-space layers' state and tail
     (`state_layers` 0: rings alone, and nothing is allocated or
-    computed for state). `chunk` is the most tokens of ONE sequence a
-    step writes (the engine's prefill budget)."""
+    computed for state; `window_layers` 0: states alone, and no ring; a
+    `tail_shape` of no rows: a state with no tail, as a linear-attention
+    layer's matrix state is). `chunk` is the most tokens of ONE
+    sequence a step writes (the engine's prefill budget)."""
     window_layers: int
     window: int
     chunk: int
@@ -142,7 +144,10 @@ class HybridSpec:
 
     def ring_pages(self, page_size: int) -> int:
         """Pages that cover any window + chunk - 1 consecutive
-        positions: one more than their whole pages."""
+        positions: one more than their whole pages (none without a
+        window layer)."""
+        if not self.window_layers:
+            return 0
         return -(-(self.window + self.chunk - 2) // page_size) + 1
 
     @property
@@ -205,12 +210,21 @@ class KVCacheConfig:
     hybrid: Optional[HybridSpec] = None  # the slots' constant part
     # pages stored head-PACKED, (page, slot, heads * head_dim): KVPool
     packed_heads: bool = False
+    # width of the SELECTOR's row a page a pool layer (KVPool.kc: the
+    # compressed key a page's last token completes); 0: none
+    selector_dim: int = 0
+    # each key/value head's pages a POOL LAYER of their own (`layer *
+    # num_heads + head`, one head a page row; `head_layers`): what a
+    # pool with selector rows chooses, because every head then selects
+    # its own blocks and a block's pages are whole rows (sparse_paged.py)
+    split_heads: bool = False
 
     @classmethod
     def from_ff(cls, config, *, num_layers: int, num_heads: int,
                 head_dim: int, max_seq_len: int = 512,
                 tensor_parallel: int = 1,
-                hybrid: Optional[HybridSpec] = None) -> "KVCacheConfig":
+                hybrid: Optional[HybridSpec] = None,
+                selector_dim: int = 0) -> "KVCacheConfig":
         kv_dtype = str(getattr(config, "kv_dtype", "float32"))
         num_pages = int(getattr(config, "kv_num_pages", 257))
         pool_mb = float(getattr(config, "kv_pool_mb", 0.0) or 0.0)
@@ -240,7 +254,9 @@ class KVCacheConfig:
                    max_seqs=int(getattr(config, "serve_max_seqs", 8)),
                    max_seq_len=max_seq_len, kv_dtype=kv_dtype,
                    tensor_parallel=tp, hybrid=hybrid,
-                   packed_heads=hybrid is not None)
+                   packed_heads=hybrid is not None,
+                   selector_dim=int(selector_dim),
+                   split_heads=selector_dim > 0)
 
     @property
     def pages_per_seq(self) -> int:
@@ -276,7 +292,36 @@ class KVCacheConfig:
                   * self.head_dim * self.kv_itemsize)
         scales = (2 * self.num_layers * self.page_size * self.num_heads
                   * 4) if self.quantized else 0
-        return values + scales
+        return values + scales + self.selector_page_bytes
+
+    @property
+    def pool_layers(self) -> int:
+        """Layers of the pool's arrays."""
+        return self.num_layers * (self.num_heads if self.split_heads else 1)
+
+    @property
+    def layer_heads(self) -> int:
+        """Key/value heads in one pool layer: what ONE paged call reads."""
+        return 1 if self.split_heads else self.num_heads
+
+    def head_layers(self, layer: int) -> list:
+        """The pool layers that hold paged layer `layer`'s heads."""
+        each = self.pool_layers // self.num_layers
+        return list(range(layer * each, (layer + 1) * each))
+
+    @property
+    def selector_dtype(self):
+        """The compressed keys' dtype: the pages' own where that is a
+        float format the selector can score in, bfloat16 on a quantized
+        pool (a mean of dequantized keys has no scale row to live by)."""
+        return jnp.dtype(jnp.bfloat16) if self.quantized \
+            else self.storage_dtype
+
+    @property
+    def selector_page_bytes(self) -> int:
+        """One page's selector rows across the pool's layers."""
+        return (self.pool_layers * self.selector_dim
+                * int(self.selector_dtype.itemsize))
 
     @property
     def f32_page_bytes(self) -> int:
@@ -301,8 +346,8 @@ class KVCacheConfig:
         """One ring page across the window layers."""
         if not self.hybrid:
             return 0
-        return self.page_bytes // self.num_layers \
-            * self.hybrid.window_layers
+        return (self.page_bytes - self.selector_page_bytes) \
+            // self.num_layers * self.hybrid.window_layers
 
     @property
     def cache_bytes_per_token(self) -> int:
@@ -380,7 +425,8 @@ class KVCacheConfig:
 
 @functools.partial(
     jax.tree_util.register_dataclass,
-    data_fields=["k", "v", "k_scale", "v_scale"], meta_fields=["heads"])
+    data_fields=["k", "v", "k_scale", "v_scale", "kc"],
+    meta_fields=["heads"])
 @dataclasses.dataclass(frozen=True)
 class KVPool:
     """The device K/V pool, and the ONE place that knows its format.
@@ -402,11 +448,19 @@ class KVPool:
     layout — 10 heads of 128 to 16 — and XLA then copies the whole
     pool between its padded and a compact layout around every layer;
     packed, a page is (slot, 1280): whole tiles. `layer` hands the
-    kernel the same (page, slot, head, dim) view either way."""
+    kernel the same (page, slot, head, dim) view either way.
+
+    SELECTOR rows (`kc`; `KVCacheConfig.selector_dim` > 0, else None and
+    no leaf): (layer, page, selector_dim), one row a page — the
+    compressed key of a block-sparse attention layer's selector
+    (ops/sparse_attention.py) that the page's LAST token completed,
+    written by `write_selector` after `write` has stored that token's
+    key. A pool without them flattens to the leaves it always had."""
     k: Any
     v: Any
     k_scale: Any = None
     v_scale: Any = None
+    kc: Any = None
     heads: int = 0
 
     @classmethod
@@ -419,14 +473,18 @@ class KVPool:
         then resharded would need the unsharded bytes there first."""
         sh = sharding if isinstance(sharding, cls) \
             else cls(sharding, sharding, sharding, sharding)
-        rows = (cfg.num_layers, cfg.num_pages, cfg.page_size,
-                cfg.num_heads)
+        rows = (cfg.pool_layers, cfg.num_pages, cfg.page_size,
+                cfg.layer_heads)
         dt = cfg.storage_dtype
-        page = rows[:3] + (cfg.num_heads * cfg.head_dim,) \
+        page = rows[:3] + (cfg.layer_heads * cfg.head_dim,) \
             if cfg.packed_heads else rows + (cfg.head_dim,)
         pool = cls(jnp.zeros(page, dt, device=sh.k),
                    jnp.zeros(page, dt, device=sh.v),
-                   heads=cfg.num_heads if cfg.packed_heads else 0)
+                   heads=cfg.layer_heads if cfg.packed_heads else 0)
+        if cfg.selector_dim:
+            pool = dataclasses.replace(pool, kc=jnp.zeros(
+                rows[:2] + (cfg.selector_dim,), cfg.selector_dtype,
+                device=sh.k))
         if not cfg.quantized:
             return pool
         return dataclasses.replace(
@@ -469,6 +527,47 @@ class KVPool:
             k_scale=self.k_scale.at[layer, pages, offs].set(ksc),
             v_scale=self.v_scale.at[layer, pages, offs].set(vsc))
 
+    def write_selector(self, layer: int, pages, rows) -> "KVPool":
+        """Store the selector's rows (T, selector_dim) of `layer` at
+        pages[t] (a lane that completes none aims at the sink page)."""
+        return dataclasses.replace(
+            self, kc=self.kc.at[layer, pages].set(
+                rows.astype(self.kc.dtype)))
+
+    def gather(self, layer: int, pages):
+        """K and V of whole pages `pages` (any shape, int32) of `layer`
+        -> (k, v), each pages.shape + (slot, heads * dim): the rows as
+        stored on a lossless pool, dequantized to f32 on a quantized
+        one. A row gather over the WHOLE pool, pages as rows (layer *
+        num_pages + page): a slice of one layer handed to a gather is
+        copied out first, 0.25 GiB at a served size. Packed pools."""
+        if self.heads == 0:
+            raise ValueError("gather reads a head-packed pool")
+        n_pages = self.k.shape[1]
+        rows = layer * n_pages + jnp.asarray(pages, jnp.int32)
+
+        def take(a):
+            return jnp.take(a.reshape((-1,) + a.shape[2:]), rows, axis=0,
+                            mode="clip")
+
+        k, v = take(self.k), take(self.v)
+        if not self.quantized:
+            return k, v
+        each = self.k.shape[-1] // self.heads
+
+        def scaled(q, scale):
+            return q.astype(jnp.float32) * jnp.repeat(
+                take(scale), each, axis=-1)
+
+        return scaled(k, self.k_scale), scaled(v, self.v_scale)
+
+    def selector_rows(self, layer: int, pages):
+        """The selector's rows of `pages` (any shape) of `layer`,
+        pages.shape + (selector_dim,), gathered like `gather`."""
+        rows = layer * self.kc.shape[1] + jnp.asarray(pages, jnp.int32)
+        return jnp.take(self.kc.reshape(-1, self.kc.shape[-1]), rows,
+                        axis=0, mode="clip")
+
     def layer(self, i: int):
         """`layer`'s operands of the paged attention kernel
         (kernels/paged_ragged_v2.paged_attention_ragged_v2): (k_pages,
@@ -500,8 +599,12 @@ class KVPool:
         assert self.quantized == want.quantized, (
             f"kv_dtype={cfg.kv_dtype} pool "
             f"{'carries' if self.quantized else 'lacks'} scale arrays")
-        for name, a, w in zip(("k", "v", "k_scale", "v_scale"),
-                              jax.tree.leaves(self),
+        assert (self.kc is None) == (want.kc is None), (
+            f"pool {'carries' if self.kc is not None else 'lacks'} "
+            f"selector rows; selector_dim is {cfg.selector_dim}")
+        names = [n for n in ("k", "v", "k_scale", "v_scale", "kc")
+                 if getattr(self, n) is not None]
+        for name, a, w in zip(names, jax.tree.leaves(self),
                               jax.tree.leaves(want)):
             assert (a.shape, a.dtype) == (w.shape, w.dtype), (
                 f"pool leaf {name} is {a.shape} {a.dtype}; the "
@@ -539,13 +642,14 @@ class HybridPool:
     """The device half of a HybridSpec configuration: `full` the pages
     of the paged layers (a KVPool of `cfg.num_layers` layers), `window`
     the slots' rings (a KVPool of the window layers, 1 + max_seqs *
-    ring_pages pages: the sink, then slot s's ring), `state`
+    ring_pages pages: the sink, then slot s's ring; None without a
+    window layer), `state`
     (state_layers, max_seqs + 1, d_state, d_inner) f32 and `tail`
     (state_layers, max_seqs + 1, (d_conv - 1) * d_inner) (its rows
     flat: three rows would pad to a tile of 16), the last row of both
-    the write sink; both None without a state-space layer (no
-    zero-sized leaf in the donated pool). Flows through the step like a
-    KVPool (donated in, returned out)."""
+    the write sink; both None without a state-space layer, `tail` also
+    where the state has none (no zero-sized leaf in the donated pool).
+    Flows through the step like a KVPool (donated in, returned out)."""
     full: KVPool
     window: KVPool
     state: Any
@@ -556,27 +660,33 @@ class HybridPool:
         """The rings as a page pool's geometry."""
         return dataclasses.replace(
             cfg, num_layers=cfg.hybrid.window_layers,
-            num_pages=1 + cfg.max_seqs * cfg.ring_pages, hybrid=None)
+            num_pages=1 + cfg.max_seqs * cfg.ring_pages, hybrid=None,
+            selector_dim=0, split_heads=False)
 
     @classmethod
     def alloc(cls, cfg: KVCacheConfig, sharding=None) -> "HybridPool":
         h = cfg.hybrid
         rows = (h.state_layers, cfg.max_seqs + 1)
         full = KVPool.alloc(dataclasses.replace(cfg, hybrid=None), sharding)
-        window = KVPool.alloc(cls.ring_cfg(cfg), sharding)
+        window = KVPool.alloc(cls.ring_cfg(cfg), sharding) \
+            if h.window_layers else None
         if not h.state_layers:
             return cls(full, window, None, None)
+        tail = h.tail_shape[0] * h.tail_shape[1]
         return cls(
             full, window,
             jnp.zeros(rows + tuple(h.state_shape), jnp.float32,
                       device=sharding),
-            jnp.zeros(rows + (h.tail_shape[0] * h.tail_shape[1],),
-                      jnp.dtype(h.tail_dtype), device=sharding))
+            jnp.zeros(rows + (tail,), jnp.dtype(h.tail_dtype),
+                      device=sharding) if tail else None)
 
     def check_geometry(self, cfg: KVCacheConfig) -> None:
         want = jax.eval_shape(lambda: HybridPool.alloc(cfg))
         self.full.check_geometry(dataclasses.replace(cfg, hybrid=None))
-        self.window.check_geometry(self.ring_cfg(cfg))
+        if want.window is None:
+            assert self.window is None, "rings without a window layer"
+        else:
+            self.window.check_geometry(self.ring_cfg(cfg))
         for name in ("state", "tail"):
             a, w = getattr(self, name), getattr(want, name)
             if w is None:
@@ -1225,7 +1335,7 @@ class PagedKVCache:
         # one step needs live at once
         if c.hybrid is not None:
             h, ring = c.hybrid, c.ring_pages * c.page_size
-            assert h.window + h.chunk - 1 <= ring \
+            assert not h.window_layers or h.window + h.chunk - 1 <= ring \
                 < h.window + h.chunk + 2 * c.page_size, (
                 f"a ring of {ring} tokens for a window of {h.window} "
                 f"and chunks of {h.chunk}")

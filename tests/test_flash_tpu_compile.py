@@ -261,3 +261,62 @@ def test_olmoe_mixed_step_holds_one_expert_kernel_a_layer(topo, as_tpu):
     # the head runs over the emitting lanes' 64 rows: no array of all
     # the lanes by the vocabulary
     assert not re.search(r"(bf16|f32)\[576,1024\]", text)
+
+
+def test_minicpm_sala_mixed_step_compiles_within_its_memory_plan(topo,
+                                                                 as_tpu):
+    """MiniCPM-SALA's mixed step at its served widths (544 lanes of
+    4096; a sparse layer at 32 query / 2 key-value heads of 128 beside a
+    lightning layer of 32 heads; 65,536 positions, 32,769 pages, 32
+    slots; ONE sparse and ONE lightning layer and a small vocabulary,
+    so the parameters are quick to make; PR 45): it compiles for a v5e
+    with a paged call a key/value head for the lanes under dense_len
+    and no other Mosaic call (the selection and the matrix states are
+    XLA's), the pool is updated in place, no copy of a pool leaf is
+    laid out anew, and the step's temporaries stay under 1 GiB — the
+    16-layer configuration holds 12.3 GiB of weights and cache."""
+    from flexflow_tpu import FFConfig
+    from flexflow_tpu.config import CompMode
+    from flexflow_tpu.models.minicpm_sala import (LIGHTNING, MINICPM4,
+                                                  build_minicpm_sala_lm)
+    from flexflow_tpu.serve import ServeEngine
+    from flexflow_tpu.serve.kv_cache import HybridPool
+    cfg = FFConfig(batch_size=1, kv_page_size=16, kv_num_pages=32769,
+                   serve_max_seqs=32, serve_prefill_budget=512,
+                   serve_spec_decode=False, serve_prefix_cache=False,
+                   compute_dtype="bfloat16", param_dtype="bfloat16",
+                   kv_dtype="bfloat16")
+    lm = build_minicpm_sala_lm(
+        cfg, vocab_size=2048, max_seq_len=65536,
+        mixer_types=(MINICPM4, LIGHTNING, LIGHTNING, LIGHTNING),
+        layers_kept=[0, 1])
+    lm.compile(comp_mode=CompMode.INFERENCE)
+    engine = ServeEngine(lm)
+    assert (engine.attn_impl, engine.scan_impl) == ("pallas", "jnp")
+    assert (engine.mixed_width, engine.head_rows) == (544, 32)
+    # the paged calls' grid is bounded by dense_len, not by the positions
+    assert engine.dense_pages == 512 and engine.attn_max_items == 1568
+    one = SingleDeviceSharding(topo.devices[0])
+    c = engine.cache_cfg
+    assert c.pages_per_seq == 4096
+    pool = jax.eval_shape(lambda: HybridPool.alloc(c))   # 0.7 GiB unmade
+    lane = jax.ShapeDtypeStruct((544,), jnp.int32, sharding=one)
+    rows = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one)
+    tables = jax.ShapeDtypeStruct((c.max_seqs, c.pages_per_seq), jnp.int32,
+                                  sharding=one)
+    compiled = jax.jit(engine._mixed_impl, donate_argnums=(1,)).lower(
+        _sds(engine._step_params, one), _sds(pool, one), lane, lane, lane,
+        lane, tables, lane, lane, rows).compile()
+    engine.close()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 2 and all("paged_ragged_v2" in c for c in calls)
+    assert "ragged-dot" not in text
+    m = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(pool))
+    assert m.alias_size_in_bytes >= pool_bytes
+    assert m.temp_size_in_bytes < 2**30, m.temp_size_in_bytes
+    # no leaf of the pool is copied into another layout
+    import re
+    assert not re.search(r"= bf16\[2,32769,16,128\]\S* copy\(", text)
