@@ -24,12 +24,16 @@ This module rebuilds the kernel along the paper's lines:
     draft tokens, one decode lane); a work item is (run, kv-block). The
     list is built from the lane arrays on the device, once a step
     (`build_work_list`; the engine's 24 layers share it), and reaches
-    the kernel by scalar prefetch. The grid's length is a STATIC bound
-    on the list (`max_work_items`: (tiles + slot changes) x kv-blocks
-    for a caller that can bound the changes, lanes x kv-blocks for any
-    other), not lanes x table columns: the steps a call takes follow
-    the plan's shape, and the items past the list's end re-select the
-    blocks before them and do nothing.
+    the kernel by scalar prefetch. The grid's length is the LIST's own
+    (`WorkList.count`, a device scalar made with the list: the live
+    lanes' items and one a tile of inactive lanes), not lanes x table
+    columns and not the plan's worst case: a call walks the step's
+    work. The ARRAYS are as long as a static bound on the list
+    (`max_work_items`: (tiles + slot changes) x kv-blocks for a caller
+    that can bound the changes, lanes x kv-blocks for any other) — the
+    proof that no item is lost, and what SMEM has to hold; their
+    entries past the list's end repeat the last item and do nothing
+    (the Pallas interpreter, which takes no traced bound, walks them).
   * SEVERAL PAGES AN ITEM: a kv-block covers `block_kv_pages` pages —
     one BlockSpec per page slot, so Mosaic pipelines their DMAs — sized
     so that fetching it takes longer than a grid step costs.
@@ -219,7 +223,8 @@ def max_work_items(num_lanes: int, pages_per_seq: int,
                    slot_changes: Optional[int] = None,
                    window_blocks: int = 0) -> int:
     """The most work items any lane arrays of this geometry can make:
-    the static length of the kernel's grid.
+    the static length of the list's arrays, and the most a call's grid
+    walks.
 
     A RUN is a maximal stretch of consecutive lanes of one tile that
     name one slot; it has one item per kv-block up to its longest
@@ -251,9 +256,10 @@ def ragged_dispatch_passes(num_lanes: int, pages_per_seq: int,
                            slot_changes: Optional[int] = None
                            ) -> Dict[str, int]:
     """Grid-step accounting for the serve bench: the v1 kernel runs one
-    grid step per (lane, page); v2's grid is `max_work_items` long —
-    (tiles + slot changes) x kv-blocks for a caller that bounds the
-    changes, lanes x kv-blocks otherwise."""
+    grid step per (lane, page); v2's grid is at most `max_work_items`
+    long — (tiles + slot changes) x kv-blocks for a caller that bounds
+    the changes, lanes x kv-blocks otherwise — and as long as the
+    step's list."""
     return {"v1": num_lanes * pages_per_seq,
             "v2": max_work_items(num_lanes, pages_per_seq,
                                  block_kv_pages, q_rows, slot_changes)}
@@ -338,10 +344,11 @@ def _ragged_jnp(q, k_pages, v_pages, page_tables, lane_slots, lane_lens,
 # by its OWN lane_lens entry, and the rows of the tile outside the run
 # are masked whole, so any lane arrays give the jnp twin's answer.
 #
-# The list is as long as the caller's bound says (`max_work_items`),
-# not as long as the step's work: the entries past the last live item
+# The arrays are as long as the caller's bound says (`max_work_items`),
+# not as long as the step's work: the entries past the last item
 # repeat it with every flag clear — they re-select the blocks already
-# resident and do nothing. A page slot of a live item that lies past
+# resident and do nothing — and the compiled kernel's grid ends before
+# them, at the list's `count`. A page slot of a live item that lies past
 # the run's last live page likewise repeats the page the slot held in
 # the item before it: nothing is fetched for it, and its positions are
 # masked.
@@ -354,18 +361,20 @@ SMEM_LIST_WORDS = 128 * 1024
 
 @functools.partial(
     jax.tree_util.register_dataclass,
-    data_fields=["tile", "blk", "meta", "pages", "lens"],
+    data_fields=["tile", "blk", "meta", "pages", "lens", "count"],
     meta_fields=["q_rows", "block_pages"])
 @dataclasses.dataclass(frozen=True)
 class WorkList:
-    """The kernel's scalar-prefetch operands (n = the grid's length;
-    one entry more than that, because Mosaic's pipeline evaluates the
-    index maps one step past the grid's end)."""
+    """The kernel's scalar-prefetch operands (n = the caller's bound on
+    the list; one entry more than that, because Mosaic's pipeline
+    evaluates the index maps one step past the grid's end) and the
+    list's own length, which is the grid's."""
     tile: Any    # (n + 1,) the item's tile
     blk: Any     # (n + 1,) its kv-block
     meta: Any    # (n + 1,) row_lo | row_hi << 8 | _FIRST | _LAST | _LIVE
     pages: Any   # ((n + 1) * block_pages,) physical page per page slot
     lens: Any    # (tiles * q_rows, 128) lane_lens, along the lanes
+    count: Any   # () the step's items, in [1, n]: the steps a call walks
     q_rows: int
     block_pages: int
 
@@ -379,7 +388,8 @@ def _work_arrays(xp, cummax, page_tables, lane_slots, lane_lens, *,
     the list by the step's own items: n = their count. Under a
     `window` a run's items start at the block that holds the oldest key
     its SHORTEST lane sees, and the pages wholly behind that key are
-    not fetched. -> (tile, blk, meta, pages (n, bp))."""
+    not fetched. -> (tile, blk, meta, pages (n, bp), the items'
+    count)."""
     t = lane_slots.shape[0]
     pp = page_tables.shape[1]
     bp, qb = block_pages, q_rows
@@ -435,7 +445,7 @@ def _work_arrays(xp, cummax, page_tables, lane_slots, lane_lens, *,
     src = cummax(xp.where(fresh, w[:, None], -1))
     pages = xp.where(
         src >= 0, xp.take_along_axis(page, xp.maximum(src, 0), axis=0), 0)
-    return (head // qb).astype(i32), blk, meta.astype(i32), pages
+    return (head // qb).astype(i32), blk, meta.astype(i32), pages, total
 
 
 def build_work_list(page_tables, lane_slots, lane_lens, *, page_size: int,
@@ -455,7 +465,7 @@ def build_work_list(page_tables, lane_slots, lane_lens, *, page_size: int,
         max_items = max_work_items(t, pp, bp, q_rows)
     # one entry more than the grid: the step past its end repeats the
     # last item like every entry past the list's end
-    tile, blk, meta, pages = _work_arrays(
+    tile, blk, meta, pages, total = _work_arrays(
         jnp, lambda x: jax.lax.cummax(x, axis=0), page_tables,
         lane_slots, lane_lens, page_size=page_size, block_pages=bp,
         q_rows=q_rows, max_items=max_items + 1, window=window)
@@ -465,6 +475,7 @@ def build_work_list(page_tables, lane_slots, lane_lens, *, page_size: int,
     return WorkList(
         tile=tile, blk=blk, meta=meta, pages=pages.reshape(-1),
         lens=jnp.broadcast_to(lens[:, None], (tiles * q_rows, 128)),
+        count=jnp.clip(total, 1, max_items).astype(jnp.int32),
         q_rows=q_rows, block_pages=bp)
 
 
@@ -492,10 +503,11 @@ def work_items(lane_lens, lane_slots, page_tables, *, page_size: int,
                live_lanes: Optional[int] = None,
                window: int = 0, group: int = 1) -> Dict[str, int]:
     """What one call of the kernel has to do for these lanes (numpy;
-    host side, no device work): `grid` the list's static length
+    host side, no device work): `grid` the list's static bound
     (`max_items`, else the bound for any arrays), `total` the items of
-    all lanes (more than `grid` would lose work: the caller's bound was
-    wrong), `page_fetches` the (K, V) page pairs the call fetches from
+    all lanes — the grid steps the call walks, `WorkList.count` (more
+    than `grid` would lose work: the caller's bound was wrong),
+    `page_fetches` the (K, V) page pairs the call fetches from
     HBM, and — among the first `live_lanes` lanes (all when None; the
     lanes behind them are the step's inactive padding) — `items` and
     the query `rows` they hold. rows / items is how often sharing
@@ -519,7 +531,7 @@ def work_items(lane_lens, lane_slots, page_tables, *, page_size: int,
         else int(max_items)
     # numpy sizes the arrays by the step's own items: the entries past
     # them repeat the last one, fetch nothing and count nothing
-    tile, _, meta, pages = _work_arrays(
+    tile, _, meta, pages, _ = _work_arrays(
         np, lambda x: np.maximum.accumulate(x, axis=0), pt,
         np.asarray(lane_slots), np.asarray(lane_lens),
         page_size=page_size, block_pages=bp, q_rows=q_rows,
@@ -938,7 +950,11 @@ def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
         short=short)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,          # the work list
-        grid=(n,),
+        # the list's own length, a device scalar: a call walks its
+        # items and not the bound's empty tail. The interpreter takes
+        # no traced bound and walks the arrays whole; the entries past
+        # `count` are not _LIVE, so both give the same result
+        grid=(n if interpret else work.count,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, slabs, qe, w_lanes),
                                lambda w, tile, *_: (tile[w], 0, 0, 0)),

@@ -2929,7 +2929,9 @@ class StepEvents:
     (rows / items: how often lanes share an item); over ALL of the
     step's paged calls (arch.attn_calls: each walks the full pages'
     list or the window layers'), ``grid_steps`` is the grid steps the
-    device walks whatever is live (the lists' static lengths),
+    device walks: the lists' own lengths (a call's grid ends at its
+    list's `count`; the inactive tiles keep an item each on the sink
+    page, so it is more than the live lanes' items),
     ``live_steps`` those that hold a live lane's work item,
     ``short_steps`` those of them that take the kernel's one-lane body
     (0 on a model whose calls do not hold it: has_short_body) and
@@ -3350,10 +3352,11 @@ class ServeSession:
                 * eng.kv_head_dim * c.kv_itemsize,
                 selector_bytes=eng.mixed_width * heads * c.pages_per_seq
                 * c.selector_dim * int(c.selector_dtype.itemsize))
-        # the step's fixed shape against its live work (LIVE_COUNTS):
-        # every call walks its list's whole grid whatever is live
+        # what the calls walk against the step's live work
+        # (LIVE_COUNTS): a call's grid is its list's own length, the
+        # live lanes' items and one a tile of the inactive ones
         work.update(
-            grid_steps=sum(n * w["grid"] for n, w in lists),
+            grid_steps=sum(n * w["total"] for n, w in lists),
             live_steps=sum(n * w["items"] for n, w in lists),
             short_steps=sum(n * w["short_items"] for n, w in lists),
             live_rows=sum(n * w["rows"] for n, w in lists),

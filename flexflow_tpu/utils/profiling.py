@@ -211,8 +211,8 @@ def serve_report(stats: dict) -> str:
             lines.append(
                 f"ragged kernel v2: block_kv="
                 f"{pool.get('attn_block_kv', 0)} tokens, "
-                f"{dp['v2']} grid steps vs {dp['v1']} at v1 per-page "
-                f"dispatch ({red:.1f}x fewer)")
+                f"at most {dp['v2']} grid steps vs {dp['v1']} at v1 "
+                f"per-page dispatch ({red:.1f}x fewer)")
     # adapter pool: multi-tenant LoRA slab residency + churn counters
     # (serve/adapters.pool_report); None / absent when unarmed
     ad = stats.get("adapter_pool")

@@ -410,7 +410,7 @@ def test_kv_pool_stats_and_serve_report_line():
     assert pool["kv_dtype"] == "int8" and not pool["kv_exact"]
     dp = pool["attn_dispatch_passes"]
     assert dp["v1"] > dp["v2"] > 0
-    # v2 is the grid the kernel runs: the engine's bound, every step
+    # v2 is the most the kernel's grid walks: the engine's bound a step
     assert dp["v2"] % eng.attn_max_items == 0
     report = serve_report(eng.last_stats)
     assert "kv pool: int8 pages" in report

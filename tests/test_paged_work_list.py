@@ -17,6 +17,8 @@
     jnp path).
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -208,6 +210,71 @@ def test_an_idle_step_is_one_item_a_tile_on_the_sink_page():
     assert got["items"] == got["rows"] == 0
 
 
+def _random_lanes(kind, rng, width=3 * Q_ROWS):
+    """Lane arrays as `_pack` lays them: chunks one after another, a
+    slot each, then the inactive lanes on slot 0."""
+    top = PP * PS
+    chunks = {"decode_only": [1] * 5, "one_long_chunk": [Q_ROWS + 13],
+              "mixed": [17, 1, 1, 6, 1], "all_inactive": []}[kind]
+    slots, lens = [], []
+    for slot, n in zip(rng.permutation(SEQS - 1) + 1, chunks):
+        end = rng.randint(n, top + 1)
+        slots += [slot] * n
+        lens += range(end - n + 1, end + 1)
+    live = len(slots)
+    slots = np.array(slots + [0] * (width - live), np.int32)
+    lens = np.array(lens + [1] * (width - live), np.int32)
+    return slots, lens, live
+
+
+@pytest.mark.parametrize("window", [0, 24])
+@pytest.mark.parametrize("kind", ["decode_only", "one_long_chunk", "mixed",
+                                  "all_inactive"])
+def test_the_list_s_count_is_the_items_the_host_counts(kind, window):
+    """`WorkList.count`, the grid's length on the device, is
+    `work_items(...)["total"]`, what `_pack` checks against the bound
+    and reports as walked: the live lanes' items and one a tile of the
+    inactive ones."""
+    tiles = 3
+    for seed in range(4):
+        rng = np.random.RandomState(seed)
+        pt = _table(rng)
+        slots, lens, live = _random_lanes(kind, rng)
+        for bp in (1, 2, 8):
+            got = work_items(lens, slots, pt, page_size=PS,
+                             block_kv_pages=bp, live_lanes=live,
+                             window=window)
+            work = build_work_list(
+                jnp.asarray(pt), jnp.asarray(slots), jnp.asarray(lens),
+                page_size=PS, block_pages=bp, window=window)
+            count = int(work.count)
+            assert work.count.dtype == jnp.int32 and work.count.shape == ()
+            assert count == got["total"] <= got["grid"]
+            assert tiles <= count and got["items"] <= count
+            assert count - got["items"] <= tiles
+            meta = np.asarray(work.meta)
+            assert (meta[:count] & _LIVE).all()
+            assert not (meta[count:] & _LIVE).any()
+            assert kind != "all_inactive" or count == tiles
+
+
+def test_the_count_never_passes_the_caller_s_bound():
+    """A bound too short for the arrays loses the tail (`_pack` raises
+    before that); the grid still ends inside the arrays."""
+    rng = np.random.RandomState(0)
+    pt = _table(rng)
+    slots, lens, live = _random_lanes("mixed", rng)
+    total = work_items(lens, slots, pt, page_size=PS, block_kv_pages=1,
+                       live_lanes=live)["total"]
+    for bound, want in ((total - 3, total - 3), (total, total),
+                        (total + 5, total)):
+        work = build_work_list(jnp.asarray(pt), jnp.asarray(slots),
+                               jnp.asarray(lens), page_size=PS,
+                               block_pages=1, max_items=bound)
+        assert int(work.count) == want
+        assert work.tile.shape[0] == bound + 1
+
+
 @pytest.mark.parametrize("bp", [1, 3, 8])
 def test_the_bound_is_reached_and_not_passed(bp):
     """`max_work_items` for lanes whose slot changes `c` times: the
@@ -331,9 +398,11 @@ def test_every_step_counts_its_fixed_shape_against_its_live_work(
             assert len(lists) == 1 + bool(window_calls)
             calls = [full_calls, window_calls][:len(lists)]
             grids = [eng.attn_max_items, eng.window_max_items]
-            assert ev.grid_steps == sum(
-                n * g for n, g in zip(calls, grids))
-            for key, item in (("grid_steps", "grid"),
+            # a call walks its list's own length, under the bound
+            assert [w["grid"] for w in lists] == grids[:len(lists)]
+            bound = sum(n * g for n, g in zip(calls, grids))
+            assert ev.grid_steps <= bound
+            for key, item in (("grid_steps", "total"),
                               ("live_steps", "items"),
                               ("short_steps", "short_items"),
                               ("live_rows", "rows")):
@@ -342,6 +411,10 @@ def test_every_step_counts_its_fixed_shape_against_its_live_work(
             assert (ev.attn_items, ev.attn_rows) == (
                 lists[0]["items"], lists[0]["rows"])    # ONE call's
             assert 0 < ev.live_steps <= ev.grid_steps
+            # what is walked and not live: an item a tile of inactive
+            # lanes, a call
+            tiles = -(-eng.mixed_width // Q_ROWS)
+            assert ev.grid_steps - ev.live_steps <= sum(calls) * tiles
             assert 0 <= ev.short_steps <= ev.live_steps
             assert 0 < ev.live_rows <= ev.live_steps * Q_ROWS
             assert ev.lanes == eng.head_rows < eng.mixed_width
@@ -353,6 +426,7 @@ def test_every_step_counts_its_fixed_shape_against_its_live_work(
             "live": sum(st["live_steps"] for st in steps),
             "short": sum(st["short_steps"] for st in steps)}
     assert len(steps) > 10
+    assert min(st["grid_steps"] for st in steps) < bound / 2
     # decode lanes are one-lane runs: the body they take is in a call of
     # four query heads a key/value head and in no call of one
     assert any(st["short_steps"] for st in steps) == (kind == "hybrid")
@@ -520,6 +594,82 @@ def test_one_lane_items_on_their_own_rows_equal_the_twin(window, dtype,
         q, kp, vp, pt, slots, lens, work=work, interpret=True,
         window=window, scale=0.3), np.float32)
     np.testing.assert_array_equal(by_rule, short)
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                yield from _pallas_calls(inner)
+
+
+@pytest.mark.parametrize("interpret", [False, True])
+def test_the_compiled_call_s_grid_bound_is_the_traced_count(interpret):
+    """Mosaic takes the grid's length as an operand (`work.count`); the
+    interpreter takes no traced bound and keeps the arrays' length."""
+    import jax
+    from flexflow_tpu.kernels import paged_ragged_v2 as K
+    q = jnp.zeros((Q_ROWS, 4, 64), jnp.bfloat16)
+    kp = jnp.zeros((1 + SEQS * PP, PS, 4, 64), jnp.bfloat16)
+    slots, lens = jnp.zeros(Q_ROWS, jnp.int32), jnp.ones(Q_ROWS, jnp.int32)
+    pt = jnp.asarray(_table(np.random.RandomState(0)))
+    work = build_work_list(pt, slots, lens, page_size=PS, block_pages=2)
+    call, = _pallas_calls(jax.make_jaxpr(
+        lambda q, kp, work: K._ragged_v2_pallas(
+            q, kp, kp, work, 0.125, interpret))(q, kp, work).jaxpr)
+    grid = call.params["grid_mapping"]
+    assert grid.num_dynamic_grid_bounds == (0 if interpret else 1)
+    if interpret:
+        assert grid.grid == (work.tile.shape[0] - 1,)
+
+
+def _cut(work):
+    """The list cut to its own length (and the one entry past it that
+    the pipeline evaluates): through the interpreter, whose grid is the
+    arrays' length, the walk the compiled kernel takes from `count`."""
+    n = int(work.count) + 1
+    return dataclasses.replace(
+        work, tile=work.tile[:n], blk=work.blk[:n], meta=work.meta[:n],
+        pages=work.pages[:n * work.block_pages])
+
+
+@pytest.mark.parametrize("hq,h,d,fmt,window", [
+    (4, 4, 64, "float32", 0),       # one group: OPT's and OLMoE's form
+    (40, 10, 128, "float32", 0),    # group 4: the one-lane body too
+    (40, 10, 128, "float32", 24),   # and the window's list
+    (8, 4, 64, "float32", 24),      # group 2 under a window, one body
+    (4, 4, 64, "int8", 0)])         # quantized pages with their scales
+@pytest.mark.parametrize("layout", ["one_lane_rows", "chunk_and_drafts"])
+def test_the_walk_to_count_equals_the_walk_of_the_whole_bound(
+        layout, hq, h, d, fmt, window):
+    """The entries past `count` do nothing: the kernel over the list cut
+    to `count` entries gives, bit for bit, what it gives over the
+    bound's whole static grid."""
+    from flexflow_tpu.kernels import paged_ragged_v2 as K
+    bp = 2
+    slots, lens, live, changes = _lanes(layout)
+    rng = np.random.RandomState(hq + window)
+    pt = jnp.asarray(_table(rng))
+    kp, vp, scales = _pools(rng, h, d, fmt)
+    q = jnp.asarray(rng.randn(len(slots), hq, d).astype(np.float32))
+    slots, lens = jnp.asarray(slots), jnp.asarray(lens)
+    bound = max_work_items(len(slots), PP, bp, Q_ROWS, changes)
+    work = build_work_list(pt, slots, lens, page_size=PS, block_pages=bp,
+                           max_items=bound, window=window)
+    assert int(work.count) < bound == work.tile.shape[0] - 1
+    whole, cut = (np.asarray(K._ragged_v2_pallas(
+        q, kp, vp, w, 0.3, True, window=window,
+        short=K.has_short_body(hq // h), **scales))
+        for w in (work, _cut(work)))
+    assert np.isfinite(cut).all()
+    np.testing.assert_array_equal(cut, whole)
+    twin = np.asarray(paged_attention_ragged_v2(
+        q, kp, vp, pt, slots, lens, use_pallas=False, window=window,
+        scale=0.3, **scales))
+    np.testing.assert_allclose(cut[:live], twin[:live], atol=1e-5, rtol=0)
 
 
 def test_a_list_too_long_for_smem_is_split_by_lanes(monkeypatch):
