@@ -456,6 +456,9 @@ class ServeEngine:
                                    "recompute_chosen": 0,
                                    "reload_priced_s": 0.0}
         self.pool: Optional[KVPool] = None   # lazy: _device_pool()
+        # the newest mixed dispatch's `greedy`, on the device: the next
+        # dispatch's token source (_dispatch_mixed)
+        self._greedy = None
         # multi-tenant LoRA adapter pool (serve/adapters.py): fixed
         # rank-padded HBM slabs managed like the KV pool, slot 0 the
         # reserved all-zero base slab so base and adapted lanes mix in
@@ -935,7 +938,8 @@ class ServeEngine:
         lane = jnp.zeros((T,), i32)
         args = (self._step_params, pool, lane, lane, lane, lane,
                 jnp.zeros((c.max_seqs, c.pages_per_seq), i32),
-                lane, lane, jnp.zeros((self.head_rows,), i32))
+                lane, lane, jnp.zeros((self.head_rows,), i32), lane,
+                jnp.zeros((self.head_rows,), i32))
         if self.adapters is not None:
             slabs = {
                 key: jax.ShapeDtypeStruct(
@@ -1027,7 +1031,8 @@ class ServeEngine:
     # ---------------- the mixed step (chunked prefill + decode) --------
     def _mixed_impl(self, params, pool, tokens, positions, write_pages,
                     write_offs, page_tables, lane_slots, lane_lens,
-                    head_lanes, lane_adapters=None, adapters=None):
+                    head_lanes, token_src, prev_greedy,
+                    lane_adapters=None, adapters=None):
         """ONE serving step over `mixed_width` LANES. Per lane (all
         (T,) int32, HOST-built): the token to embed, its position, the
         physical (page, offset) its K/V lands in (inactive lanes aim at
@@ -1046,7 +1051,12 @@ class ServeEngine:
         `head_rows` in the engine's own step). Returns (greedy (R,),
         top-k values (R, K), top-k ids (R, K)[, expert counts], pool) —
         the static top-k head feeds host-side seeded sampling without
-        shipping (R, vocab) logits.
+        shipping (R, vocab) logits. A lane whose token the host does
+        not have yet takes it on the device: `token_src` ((T,) int32,
+        HOST-built) is the row of `prev_greedy` (the previous step's
+        `greedy`, a device array never fetched first) that holds it,
+        -1 for the host's token (ServeSession runs one step ahead of
+        the host).
 
         With a serve mesh the same body runs shard_map'd over it: each
         device on its H/t heads of the params and the pool (tp_axis
@@ -1061,8 +1071,8 @@ class ServeEngine:
             return (*out, pool)
 
         args = (params, pool, tokens, positions, write_pages, write_offs,
-                page_tables, lane_slots, lane_lens, head_lanes,
-                lane_adapters, adapters)
+                page_tables, lane_slots, lane_lens, head_lanes, token_src,
+                prev_greedy, lane_adapters, adapters)
         if self.tp_mesh is None:
             return step(*args)
         import functools
@@ -1073,9 +1083,10 @@ class ServeEngine:
         # host-built lane array replicated (the adapter lanes too; the
         # slabs per _adapter_specs — unarmed engines pass None, an
         # empty pytree any prefix spec matches), the emitted token
-        # streams replicated (psum/all-gather results are)
+        # streams replicated (psum/all-gather results are), and so the
+        # previous step's that comes back in
         rep, pool_spec = P(), KVPool.specs(TENSOR)
-        ins = (self._param_specs, pool_spec) + (rep,) * 9 + (
+        ins = (self._param_specs, pool_spec) + (rep,) * 11 + (
             self._adapter_specs if self._adapter_specs is not None
             else rep,)
         return shard_map(functools.partial(step, tp_axis=TENSOR),
@@ -1086,8 +1097,8 @@ class ServeEngine:
     @jax.named_scope("serve_step")
     def _mixed_body(self, params, pool, tokens, positions, write_pages,
                     write_offs, page_tables, lane_slots, lane_lens,
-                    head_lanes, lane_adapters=None, adapters=None,
-                    tp_axis=None):
+                    head_lanes, token_src, prev_greedy,
+                    lane_adapters=None, adapters=None, tp_axis=None):
         """The mixed step's body -> (outputs, pool). Every layer
         writes its lanes' K/V to the pool (KVPool.write: the storage
         format's cast or quantization) BEFORE any lane attends, so
@@ -1110,6 +1121,11 @@ class ServeEngine:
         # of the phase that does it.
         scope = jax.named_scope
         with scope("embed"):
+            # the token a step still in flight will emit: read where it
+            # is, so this step need not wait for the host's copy
+            tokens = jnp.where(
+                token_src >= 0,
+                jnp.take(prev_greedy, jnp.maximum(token_src, 0)), tokens)
             x = (self._embed_tp(params, tokens, positions, tp_axis)
                  if tp_axis else
                  self.arch.embed(params, tokens, positions))  # (T, E)
@@ -1874,26 +1890,44 @@ class ServeEngine:
 
     def _dispatch_mixed(self, *args, lane_adapters=None):
         """One mixed-step dispatch: `args` are the step's seven lane
-        arrays and the (head_rows,) lanes its head runs over. Returns
+        arrays, the (head_rows,) lanes its head runs over and the
+        lanes' token source. Returns
         (greedy, topv, topi: a row for each of those lanes, in their
         order; expert counts: the step's (layers, experts) live slots
-        per expert on a model with an expert layer, else None) and
+        per expert on a model with an expert layer, else None), all
+        still on the device, and
         keeps the returned pool as `self.pool`, so a mid-run audit
         (check_kv_scales from an `on_step` callback, when sequences are
-        actually resident) reads THIS step's content. On an adapter-armed engine the lanes' slot
+        actually resident) reads THIS step's content. The token source
+        ((mixed_width,) int32, -1 = the host's token) names, a lane,
+        the row of the PREVIOUS dispatch's `greedy` that holds its
+        token (_mixed_impl): that array goes back in as it came out,
+        not donated, and this call's is kept for the next. On an adapter-armed engine the lanes' slot
         indices + the slabs ride along (read-only — the slabs are NOT
         donated); unarmed engines pass None (an empty pytree, zero
         trace cost, numerics untouched)."""
+        slabs = None
         if self.adapters is not None:
-            la = lane_adapters if lane_adapters is not None \
-                else self._h2d(np.zeros((self.mixed_width,), np.int32))
-            args = args + (la, self._device_adapters())
-        else:
-            args = args + (None, None)
+            slabs = self._device_adapters()
+            if lane_adapters is None:
+                lane_adapters = self._h2d(
+                    np.zeros((self.mixed_width,), np.int32))
+        if self._greedy is None:
+            # nothing dispatched yet: zeros placed as a step's own
+            # `greedy` comes out, so that the program is traced once
+            rows = np.zeros((self.head_rows,), np.int32)
+            if self.tp_mesh is None:
+                self._greedy = self._h2d(rows)
+            else:
+                from jax.sharding import NamedSharding, PartitionSpec
+                self._greedy = jax.device_put(
+                    rows, NamedSharding(self.tp_mesh, PartitionSpec()))
         *out, self.pool = self._call_counted(
             "mixed", self._mixed_jit, self._step_params,
-            self._device_pool(), *args)
+            self._device_pool(), *args, self._greedy, lane_adapters,
+            slabs)
         greedy, topv, topi = out[:3]
+        self._greedy = greedy
         return (greedy, topv, topi,
                 out[3] if self.arch.experts else None)
 
@@ -1914,7 +1948,8 @@ class ServeEngine:
             np.zeros((c.max_seqs, c.pages_per_seq), np.int32))
         self._dispatch_mixed(
             z, z, z, z, pts, z, self._h2d(np.ones((t,), np.int32)),
-            self._h2d(np.zeros((self.head_rows,), np.int32)))
+            self._h2d(np.zeros((self.head_rows,), np.int32)),
+            self._h2d(np.full((t,), -1, np.int32)))
         if self.adapters is not None:
             # compile the adapter-load scatter on an all-zero row
             # set aimed at the base slot (zeros into zeros — a
@@ -2101,9 +2136,11 @@ class ServeEngine:
     def _sweep_aborts(self, sched) -> None:
         """Chunk-boundary sweep: apply pending cancels and expire
         deadlines. Runs at the top of every serving step, BEFORE the
-        scheduler plans — so no aborted request can have a chunk in
-        flight, and its slot/pages are free for this very step's
-        admissions."""
+        scheduler plans — so its slot/pages are free for this very
+        step's admissions. A chunk the request holds in a step still in
+        flight is dropped when that step lands (ServeSession._land):
+        the device runs its programs in order, so a page freed here is
+        rewritten only after that step has read and written it."""
         now = time.perf_counter()
         tel = self.telemetry
         live = list(sched.running.values()) + list(sched.waiting)
@@ -2235,40 +2272,45 @@ class ServeEngine:
             tracks.append((self._proc, f"slot {len(tracks)}"))
         return tracks[slot]
 
-    def _record_step_telemetry(self, tel, plan, step_idx: int,
-                               t_start: float, dt: float,
-                               rung: int, occupancy: float) -> None:
+    def _record_step_telemetry(self, tel, fl: "_Flight",
+                               t_start: float, dt: float) -> None:
         """One engine step's telemetry: the step span on the engine
-        track, a chunk span per request on its slot track, queue-wait
-        async spans for this step's admissions, preemption instants,
-        pool-occupancy/rung counter samples, and the drift sample
-        (measured dt vs the cost model's prediction for this step's
-        regime). Called AFTER the dispatch returned, so a fault that
+        track, a chunk span per request on the slot track it held at
+        the dispatch, queue-wait async spans for this step's
+        admissions, preemption instants, pool-occupancy/rung counter
+        samples, and the drift sample (measured dt vs the cost model's
+        prediction for this step's regime; `t_start` and `dt` as
+        ServeSession._land gives them). A `requeue_wait` runs from the
+        scheduler's stamp of the eviction (Scheduler._preempt) to
+        `t_start` of the step that re-admits, where that step's chunk
+        span begins: its dispatch, or the landing before it where it
+        was dispatched ahead and the device still ran the step the
+        request sat out. Called once the step has LANDED, so a fault that
         kills the step never half-records it. The whole step is built
         as raw event tuples and handed to the bus in ONE
         :meth:`Telemetry.emit` — this runs on every engine step, and
         the per-call overhead of the one-at-a-time recorders is what
         the <= 3% gate budget goes to."""
+        plan, step_idx = fl.ev.plan, fl.ev.step_index
+        rung, occupancy = fl.rung, fl.util
         t_end = t_start + dt
         dur = max(0.0, dt)
-        now = time.perf_counter()
         evs = []
+        for req, t_preempt, ordinal in fl.requeued:
+            # re-admission after preemption: the span an operator
+            # debugging page pressure needs is preempt -> readmit
+            # (NOT a duplicate of the original queue wait; ident
+            # carries the preemption ordinal so Perfetto pairs
+            # each b/e uniquely per eviction)
+            ident = f"{req.rid}.{ordinal}"
+            evs.append(("b", self._QUEUE_TRACK, "requeue_wait",
+                        t_preempt, 0.0, ident,
+                        {"rid": req.rid, "trace": req.trace_id,
+                         "preemptions": ordinal}))
+            evs.append(("e", self._QUEUE_TRACK, "requeue_wait",
+                        max(t_preempt, t_start), 0.0, ident, None))
         for req in plan.admitted:
-            if req._t_requeue is not None:
-                # re-admission after preemption: the span an operator
-                # debugging page pressure needs is preempt -> readmit
-                # (NOT a duplicate of the original queue wait; ident
-                # carries the preemption ordinal so Perfetto pairs
-                # each b/e uniquely per eviction)
-                ident = f"{req.rid}.{req.preemptions}"
-                evs.append(("b", self._QUEUE_TRACK, "requeue_wait",
-                            req._t_requeue, 0.0, ident,
-                            {"rid": req.rid, "trace": req.trace_id,
-                             "preemptions": req.preemptions}))
-                evs.append(("e", self._QUEUE_TRACK, "requeue_wait",
-                            now, 0.0, ident, None))
-                req._t_requeue = None
-            elif not req.preemptions:
+            if not fl.stint[req.rid][0]:
                 # first admission: the wait ended where the scheduler
                 # stamped it, before this step packed a lane
                 evs.append(("b", self._QUEUE_TRACK, "queue_wait",
@@ -2277,18 +2319,18 @@ class ServeEngine:
                              "prompt_tokens": len(req.prompt)}))
                 evs.append(("e", self._QUEUE_TRACK, "queue_wait",
                             req.t_admit, 0.0, req.rid, None))
-        for victim in plan.preempted:
-            victim._t_requeue = now
-            evs.append(("i", self._ENGINE_TRACK, "preempt", now, 0.0,
-                        None, {"rid": victim.rid,
-                               "trace": victim.trace_id,
-                               "preemptions": victim.preemptions}))
+        for victim, t_preempt, ordinal in fl.preempted:
+            evs.append(("i", self._ENGINE_TRACK, "preempt", t_preempt,
+                        0.0, None, {"rid": victim.rid,
+                                    "trace": victim.trace_id,
+                                    "preemptions": ordinal}))
         drafted = 0
         for ch in plan.chunks:
             name = ("spec_decode" if ch.draft_tokens
                     else "decode" if ch.is_decode else "prefill")
             drafted += len(ch.draft_tokens)
-            evs.append(("X", self._slot_track(ch.req.slot), name,
+            evs.append(("X",
+                        self._slot_track(fl.stint[ch.req.rid][1]), name,
                         t_start, dur,
                         None, {"rid": ch.req.rid,
                                "trace": ch.req.trace_id,
@@ -2916,10 +2958,16 @@ SELECT_COUNTS = ("sparse_lanes", "blocks_selected", "blocks_visible",
 
 
 class StepEvents:
-    """What one :meth:`ServeSession.step` did — the router tier's
+    """What ONE engine step did, handed out by the
+    :meth:`ServeSession.step` call in which it LANDED (its results
+    reached the host), which is the call that dispatched it or the one
+    after — the router tier's
     window into a replica's progress (serve/router.py advances each
     replica's virtual clock by a cost-model-priced step and stamps
-    TTFT/TPOT off these). ``emitted`` is [(request, tokens emitted
+    TTFT/TPOT off these). Every field is of that one step: its plan,
+    its counters, its tokens. ``ahead`` is True for a step that was
+    dispatched before the step before it was fetched. ``emitted`` is
+    [(request, tokens emitted
     this step)] (speculation can emit several per step), ``finished``
     the requests that completed THIS step, ``ctx_mean`` the mean
     decode-context length (the drift calibrator's pricing regime),
@@ -2975,12 +3023,15 @@ class StepEvents:
     gathers, live and past dense_len or not, so both are constants of
     the shapes (``kv_bytes_read`` stays the paged calls' page fetches);
     ``dispatched``
-    False for a planning-only iteration (rung-4
+    False for a call in which no step landed: a planning-only
+    iteration (rung-4
     rejections / whole-set preemption under injected pressure — the
     scheduler's forced-progress rule guarantees re-planning
-    converges)."""
+    converges; ``plan`` is that plan), or a call that dispatched a
+    step and left it in flight (``plan`` None: the next call brings
+    its events)."""
 
-    __slots__ = ("dispatched", "step_index", "plan", "emitted",
+    __slots__ = ("dispatched", "ahead", "step_index", "plan", "emitted",
                  "finished", "ctx_mean", "wall_s", "host_reload_s",
                  "kv_bytes_read", "attn_items", "attn_rows", "topv", "topi",
                  "emit_lanes", *LIVE_COUNTS,
@@ -2992,6 +3043,7 @@ class StepEvents:
 
     def __init__(self, plan=None):
         self.dispatched = False
+        self.ahead = False
         self.step_index = -1
         self.plan = plan
         self.emitted: List[Tuple[Request, int]] = []
@@ -3029,6 +3081,53 @@ class StepEvents:
             setattr(self, key, 0)
 
 
+class _Flight:
+    """A step that was dispatched and has not landed: its events so
+    far, its outputs still on the device, and what the landing needs of
+    the plan as it was packed (ServeSession._step / _land)."""
+
+    __slots__ = ("ev", "outputs", "lane", "emitters", "spec_emitters",
+                 "t_dispatch", "rung", "util", "lands_first", "rows",
+                 "stint", "preempted", "requeued")
+
+    def __init__(self, ev, outputs, lane, emitters, spec_emitters,
+                 t_dispatch, rung, util, lands_first):
+        self.ev = ev
+        self.outputs = outputs      # (greedy, topv, topi, counts)
+        self.lane = lane            # live lanes
+        self.emitters = emitters
+        self.spec_emitters = spec_emitters
+        self.t_dispatch = t_dispatch
+        self.rung = rung
+        self.util = util            # the pool's occupancy as planned
+        self.lands_first = lands_first
+        # request -> the row of `greedy` that holds its token: the next
+        # plan's token source
+        self.rows = {ch.req.rid: row for ch, row in emitters}
+        # request -> its preemptions and its slot at the dispatch: a
+        # request that is running at the landing, in the same stint,
+        # still holds its chunk
+        plan = ev.plan
+        self.stint = {ch.req.rid: (ch.req.preemptions, ch.req.slot)
+                      for ch in plan.chunks}
+        # the evictions this plan made and the waits its re-admissions
+        # end, (request, the scheduler's stamp, the preemption's
+        # ordinal), read at the dispatch: a later plan may evict the
+        # request again before this step lands
+        self.preempted = [(v, v._t_requeue, v.preemptions)
+                          for v in plan.preempted]
+        self.requeued = [(r, r._t_requeue, r.preemptions)
+                         for r in plan.admitted
+                         if r._t_requeue is not None]
+        for req, _, _ in self.requeued:
+            req._t_requeue = None
+
+    def holds(self, ch: ChunkPlan) -> bool:
+        req = ch.req
+        return req.state == RequestState.RUNNING \
+            and req.preemptions == self.stint[req.rid][0]
+
+
 class ServeSession:
     """Incremental (steppable) serving over one ServeEngine.
 
@@ -3040,10 +3139,19 @@ class ServeSession:
     session owns the scheduler (and with it the engine's slots); at
     most one is live per engine until ``close()``.
 
-    The step body is the former ``_run_chunked`` loop body verbatim:
-    sweep cancels/deadlines at the chunk boundary, plan, pack lanes,
-    dispatch the ONE mixed program, bookkeeping first / emission
-    second / speculative verification last."""
+    One step: sweep cancels/deadlines at the chunk boundary, plan,
+    pack lanes, dispatch the ONE mixed program; then, once its results
+    are fetched (it LANDS), bookkeeping first / emission second /
+    speculative verification last. At most one step is in flight AHEAD
+    of the host: step N+1 is planned, packed, uploaded and dispatched
+    while step N runs, and N is fetched and emitted after that
+    dispatch, so the device does not wait for the host. The one token
+    of N that N+1 needs is read on the device (_pack's token source).
+    What the next plan needs is booked at dispatch (residency, that
+    the request has a token more), what needs the token's value at
+    landing (out_tokens, finishing, the prefix cache's keys). A step
+    whose results the next plan cannot do without lands before that
+    plan is made (_lands_first), in today's order."""
 
     def __init__(self, engine: ServeEngine):
         if engine._session is not None:
@@ -3089,6 +3197,15 @@ class ServeSession:
         self._ring_tables = ring_tables(c) if c.ring_pages else None
         self._retries0 = engine._retries
         self._rejected_seen = 0   # flight-recorder rejection trigger
+        # the step dispatched and not landed yet, if any (_Flight), how
+        # many were dispatched, how many of them ahead of the landing
+        # of the one before, and the emitting rows of a step in flight
+        # that nobody read (their request had left by the landing)
+        self._flight: Optional[_Flight] = None
+        self.steps_dispatched = 0
+        self.steps_ahead = 0
+        self.lanes_dropped = 0
+        self._t_landed = 0.0
         self._t0 = time.perf_counter()
         engine._device_pool()
         engine._session = self
@@ -3128,7 +3245,9 @@ class ServeSession:
         return r
 
     def has_work(self) -> bool:
-        return self.sched.has_work()
+        """Whether a step() call has anything to do: a request waits or
+        runs, or a step is in flight."""
+        return self._flight is not None or self.sched.has_work()
 
     # ---------------- emission -----------------------------------------
     def _finish(self, ev: StepEvents, req: Request) -> None:
@@ -3200,7 +3319,11 @@ class ServeSession:
         with a visible length of 1) and, last of them, the (head_rows,)
         lanes the step's head runs over: the emitters' lanes first, then
         each speculative chunk's 1 + k lanes side by side, padded with
-        lane 0. -> (arrays in dispatch order, lane_adapters or None,
+        lane 0; after it the (mixed_width,) token source: -1 for a lane
+        whose token the host has, else the row of the in-flight step's
+        `greedy` that will hold it — the one token of a context not
+        landed yet, where the request emitted in that step.
+        -> (arrays in dispatch order, lane_adapters or None,
         live lanes, emitters, spec_emitters: (chunk, the ROW of the
         step's outputs that holds its last lane's logits), the paged
         kernel's work for these lanes: `work_items` of one call plus
@@ -3217,6 +3340,8 @@ class ServeSession:
         write_offs = np.zeros((t_w,), np.int32)
         lane_slots = np.zeros((t_w,), np.int32)
         lane_lens = np.ones((t_w,), np.int32)      # NaN-free padding
+        token_src = np.full((t_w,), -1, np.int32)
+        flight_rows = self._flight.rows if self._flight is not None else {}
         # inactive lanes gather adapter slot 0 (the zero base slab)
         lane_adapters = np.zeros((t_w,), np.int32) \
             if eng.adapters is not None else None
@@ -3228,7 +3353,10 @@ class ServeSession:
             row = cache.page_tables[ch.req.slot]
             aslot = int(getattr(ch.req, "adapter_slot", 0) or 0)
             for pos in range(ch.start, ch.end):
-                tokens[lane] = ctx[pos]
+                if pos < len(ctx):
+                    tokens[lane] = ctx[pos]
+                else:
+                    token_src[lane] = flight_rows[ch.req.rid]
                 positions[lane] = pos
                 write_pages[lane] = row[pos // ps]
                 write_offs[lane] = pos % ps
@@ -3269,7 +3397,8 @@ class ServeSession:
         head_lanes = np.zeros((rows,), np.int32)
         head_lanes[:len(read)] = read
         arrays = (tokens, positions, write_pages, write_offs,
-                  cache.page_tables, lane_slots, lane_lens, head_lanes)
+                  cache.page_tables, lane_slots, lane_lens, head_lanes,
+                  token_src)
         # what the paged kernel will do for these lanes (the count is
         # made where the lanes are made), and the proof's check: a plan
         # whose items passed the grid's bound would lose work
@@ -3406,14 +3535,54 @@ class ServeSession:
                 "counts": None if counts is None else counts.copy()}
 
     def step(self) -> Optional[StepEvents]:
-        """Advance one engine step. Returns None when the session is
-        drained (no waiting or running requests survive the abort
-        sweep), else a StepEvents. The whole step is one phase span,
+        """Advance the session by one call: plan and dispatch the next
+        engine step, and land (fetch, book, emit) the one before it —
+        or, where that one's results are needed for the plan
+        (_lands_first), land it first and return. Returns the
+        StepEvents of the step that LANDED in this call, whole and of
+        one step; an empty StepEvents (no plan, `dispatched` False)
+        from a call that dispatched a step and landed none, which is
+        what the first call of a stream returns; a plan's own where
+        nothing could be dispatched; None when nothing waits, runs or
+        is in flight (the session is drained: no request survived the
+        abort sweep). The whole call is one phase span,
         `serve_step`, and each part of it a child span
-        (Telemetry.timed: docs/observability.md "Phase spans")."""
+        (Telemetry.timed: docs/observability.md "Phase spans"): `fetch`
+        and `emit` are the landing step's, the others the dispatched
+        step's."""
         eng = self.eng
-        with eng.telemetry.timed(eng._ENGINE_TRACK, "serve_step"):
-            return self._step()
+        try:
+            with eng.telemetry.timed(eng._ENGINE_TRACK, "serve_step"):
+                return self._step()
+        except Exception:
+            # whatever is in flight died with this call, or is the
+            # caller's to fail (_fail_inflight): nothing is left to land
+            self._flight = None
+            raise
+
+    def _lands_first(self, emitters, spec_emitters) -> bool:
+        """Whether the step just dispatched must land before the next
+        plan is made, read from the plan alone. (a) The plan needs its
+        tokens' VALUES: a speculative chunk or a request that could
+        draft (the drafter reads the context), a request that samples
+        (_pick_token draws on the host); the host tier and the adapter
+        pool move device state between steps from the host, and keep
+        their step-by-step order too. (b) It changes who is running and
+        the host can foresee it: an emitter reaches max_new_tokens in
+        it, and the caller — a closed loop's client, a router — acts on
+        the finish before the next plan. Every other step runs while
+        the next is planned."""
+        eng = self.eng
+        if spec_emitters or eng.host_tier is not None \
+                or eng.adapters is not None:
+            return True
+        for ch, _ in emitters:
+            req = ch.req
+            if req.sample is not None or req.spec is not None \
+                    or len(req.out_tokens) + req.inflight \
+                    >= req.max_new_tokens:
+                return True
+        return False
 
     def _step(self) -> Optional[StepEvents]:
         eng = self.eng
@@ -3421,12 +3590,19 @@ class ServeSession:
         cache = eng.cache
         c = eng.cache_cfg
         timed, track = eng.telemetry.timed, eng._ENGINE_TRACK
+        prev = self._flight
+        if prev is not None and prev.lands_first:
+            # the tail of the step before, as it always was: the caller
+            # sees its events before anything new is swept or planned
+            self._flight = None
+            return self._land(prev)
         # chunk boundary: cancels and expired deadlines leave the
         # system HERE, before any of this step's chunks exist
         with timed(track, "sweep"):
             eng._sweep_aborts(sched)
         if not sched.has_work():
-            return None
+            self._flight = None
+            return None if prev is None else self._land(prev)
         with timed(track, "schedule"):
             plan = sched.schedule()
         ev = StepEvents(plan)
@@ -3443,8 +3619,13 @@ class ServeSession:
             # every waiting request was rejected (rung 4) or the
             # running set was preempted whole under injected pressure;
             # the next step() re-plans (forced progress guarantees
-            # this cannot spin)
-            return ev
+            # this cannot spin). A step in flight lands meanwhile
+            if prev is None:
+                return ev
+            self._flight = None
+            landed = self._land(prev)
+            landed.host_reload_s += ev.host_reload_s
+            return landed
         with timed(track, "pack"):
             (arrays, lane_adapters, lane, emitters, spec_emitters,
              work) = self._pack(plan)
@@ -3470,31 +3651,75 @@ class ServeSession:
             # ship queued evictions to the host tier BEFORE the
             # dispatch overwrites their pages (the spill-safety window)
             eng._drain_spills()
-        step_idx = len(self.util)
+        ev.step_index = self.steps_dispatched
+        ev.ahead = prev is not None
         tp = time.perf_counter()
         with timed(track, "upload"):
             dev = [eng._h2d(a) for a in arrays]
             dev_adapters = None if lane_adapters is None \
                 else eng._h2d(lane_adapters)
         with timed(track, "dispatch", {
-                "step": step_idx, "live": lane,
+                "step": ev.step_index, "live": lane,
                 "prefill": plan.num_prefill_lanes,
                 "decode": plan.num_decode_lanes,
                 "kv_bytes": ev.kv_bytes_read,
                 "items": ev.attn_items, "rows": ev.attn_rows,
-                **{key: work[key] for key in counted}}):
-            greedy, topv, topi, counts = eng._dispatch_mixed(
-                *dev, lane_adapters=dev_adapters)
+                **{key: work[key] for key in counted},
+                "ahead": int(ev.ahead), "dispatched": 1}):
+            outputs = eng._dispatch_mixed(*dev,
+                                          lane_adapters=dev_adapters)
+        self.steps_dispatched += 1
+        self.steps_ahead += ev.ahead
+        # what the next plan needs, booked now: the chunks' tokens are
+        # resident for every later program, and each emitter has a
+        # token more (its value lands with the step)
+        for ch in plan.chunks:
+            if not ch.draft_tokens:
+                sched.chunk_dispatched(ch)
+        for ch, _ in emitters:
+            ch.req.inflight += 1
+        cur = self._flight = _Flight(
+            ev, outputs, lane, emitters, spec_emitters, tp, sched.rung,
+            1.0 - cache.free_pages / c.usable_pages,
+            self._lands_first(emitters, spec_emitters))
+        if prev is not None:
+            return self._land(prev)
+        if cur.lands_first:
+            self._flight = None
+            return self._land(cur)
+        return StepEvents()
+
+    def _land(self, fl: "_Flight") -> StepEvents:
+        """Fetch a dispatched step's results and do what needs their
+        values: commit the pages its chunks completed, emit its tokens,
+        finish what they finish. A chunk whose request has left the
+        running set since the dispatch (an EOS the host could not
+        foresee, a cancel, a deadline, a preemption) is neither
+        committed nor emitted: its row is dropped (`lanes_dropped`).
+        Its pages could be freed at once, since every later program is
+        ordered behind this one on the device. -> the step's events.
+        `wall_s`, `decode_times`, `prefill_times` and the telemetry's
+        step span are the time from the later of this step's dispatch
+        and the landing before it to this landing: the step's own time
+        where it ran ahead, dispatch to fetch as before where not."""
+        eng = self.eng
+        sched = self.sched
+        timed, track = eng.telemetry.timed, eng._ENGINE_TRACK
+        ev, plan = fl.ev, fl.ev.plan
+        greedy, topv, topi, counts = fl.outputs
         with timed(track, "fetch"):
             greedy = np.asarray(greedy)
             topv = np.asarray(topv)
             topi = np.asarray(topi)
             ev.topv, ev.topi = topv, topi
             if counts is not None:
-                self._count_experts(ev, np.asarray(counts), lane)
-        dt = time.perf_counter() - tp
+                self._count_experts(ev, np.asarray(counts), fl.lane)
+        now = time.perf_counter()
+        t_start = max(fl.t_dispatch, self._t_landed)
+        dt = now - t_start
+        self._t_landed = now
         with timed(track, "emit", None if not eng.arch.experts else {
-                "step": step_idx, "expert_slots": ev.expert_slots,
+                "step": ev.step_index, "expert_slots": ev.expert_slots,
                 "experts_touched": ev.experts_touched,
                 "expert_bytes": ev.expert_bytes,
                 **({} if eng.arch.experts_held is None else {
@@ -3503,25 +3728,28 @@ class ServeSession:
             # 0's)
             if not np.isfinite(topv).all():
                 self.nonfinite_steps += 1
-            self.util.append(1.0 - cache.free_pages / c.usable_pages)
+            self.util.append(fl.util)
             if eng.telemetry.enabled:
-                eng._record_step_telemetry(
-                    eng.telemetry, plan, step_idx, tp, dt, sched.rung,
-                    self.util[-1])
+                eng._record_step_telemetry(eng.telemetry, fl, t_start,
+                                           dt)
             # bookkeeping FIRST (page commits hash the context as it
             # was when the chunk ran), emission second; speculative
             # chunks verify LAST — their residency bookkeeping is a
             # function of the tokens they emit
             for ch in plan.chunks:
-                if not ch.draft_tokens:
-                    sched.complete_chunk(ch)
+                if not ch.draft_tokens and fl.holds(ch):
+                    sched.chunk_landed(ch)
             dec_tokens = 0
-            for ch, row in emitters:
+            for ch, row in fl.emitters:
+                if not fl.holds(ch):
+                    self.lanes_dropped += 1
+                    continue
+                ch.req.inflight -= 1
                 self._emit(ev, ch, greedy[row], topv[row], topi[row])
                 ev.emit_lanes.append(row)
                 if ch.is_decode:
                     dec_tokens += 1
-            for ch, row in spec_emitters:
+            for ch, row in fl.spec_emitters:
                 dec_tokens += self._emit_spec(ev, ch, row, greedy, topv,
                                               topi)
         if plan.num_decode_lanes:
@@ -3533,7 +3761,6 @@ class ServeSession:
         if plan.num_prefill_lanes:
             self.prefill_times.append((plan.num_prefill_lanes, dt))
         ev.dispatched = True
-        ev.step_index = step_idx
         ev.wall_s = dt
         ctxs = [len(ch.req.prompt) + len(ch.req.out_tokens)
                 for ch in plan.chunks if ch.is_decode] \
@@ -3558,14 +3785,23 @@ class ServeSession:
         stats["cache_bytes_per_token"] = c.cache_bytes_per_token
         stats["cache_bytes_constant_per_seq"] = c.constant_bytes_per_seq
         stats["attn_steps"] = dict(self.attn_steps)
+        # how often a step ran ahead of the landing before it
+        # (`steps` counts the landed ones)
+        stats["steps_dispatched"] = self.steps_dispatched
+        stats["steps_ahead"] = self.steps_ahead
+        stats["lanes_dropped"] = self.lanes_dropped
         if self.eng.arch.experts:
             stats["experts"] = self.expert_stats()
         return stats
 
     def close(self) -> None:
         """Release the session (idempotent): the engine can open a new
-        one. Does NOT force-abort live requests — drain first, or use
-        engine.cancel / _fail_inflight for abnormal teardown."""
+        one. A step still in flight lands first (its events go
+        unread). Does NOT force-abort live requests — drain first, or
+        use engine.cancel / _fail_inflight for abnormal teardown."""
+        fl, self._flight = self._flight, None
+        if fl is not None:
+            self._land(fl)
         if self.eng._session is self:
             self.eng._session = None
         if self.reqs:

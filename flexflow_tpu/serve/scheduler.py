@@ -143,6 +143,13 @@ class Request:
     t_deadline: float = 0.0
     outcome: str = RequestOutcome.PENDING
     stalled: int = 0
+    # tokens a DISPATCHED step will emit for this request that have not
+    # landed in out_tokens yet (0 or 1: ServeSession keeps at most one
+    # step in flight ahead of the host). Counted in context_len, so the
+    # next plan gives the request its decode lane; the token's value is
+    # fed on the device (engine._mixed_body). Zero again once the step
+    # lands or the request leaves the running set.
+    inflight: int = 0
     # adaptive draft-length state (speculative decoding); None when the
     # request is ineligible (non-deterministic sampling) or spec is off
     spec: Optional[DraftControl] = None
@@ -160,8 +167,9 @@ class Request:
     # until the armed tier matches this request's prefix
     host_reload: Optional[dict] = dataclasses.field(default=None,
                                                     repr=False)
-    # preemption stamp for the telemetry requeue_wait span (set at
-    # eviction, cleared at re-admission; telemetry-only bookkeeping)
+    # preemption stamp for the telemetry requeue_wait span (set by
+    # _preempt, taken by the step that re-admits when it is dispatched;
+    # telemetry-only bookkeeping)
     _t_requeue: Optional[float] = dataclasses.field(default=None,
                                                     repr=False)
 
@@ -176,6 +184,12 @@ class Request:
         re-prefilling THIS (its generated work is not redone, only its
         K/V), which is why it lives here and not on the engine."""
         return self.prompt + self.out_tokens
+
+    @property
+    def context_len(self) -> int:
+        """The context's length as the next plan must see it: the
+        landed tokens plus the one an in-flight step will emit."""
+        return len(self.prompt) + len(self.out_tokens) + self.inflight
 
     def is_done(self) -> bool:
         if len(self.out_tokens) >= self.max_new_tokens:
@@ -205,7 +219,7 @@ class ChunkPlan:
 
     @property
     def emits(self) -> bool:
-        return self.end == len(self.req.context)
+        return self.end == self.req.context_len
 
 
 @dataclasses.dataclass
@@ -447,6 +461,9 @@ class ContinuousBatchingScheduler:
         def note_pending(req: Request, start: int, end: int) -> None:
             if not self.prefix_cache:
                 return
+            # a page that ends on a token still in flight has no key
+            # yet (the key hashes the token's value)
+            end = min(end, req.context_len - req.inflight)
             keys = self._keys_for(req, end // ps)
             for idx in range(start // ps, end // ps):
                 pending.setdefault(keys[idx],
@@ -458,10 +475,10 @@ class ContinuousBatchingScheduler:
         i = 0
         while i < len(order):
             req = order[i]
-            ctx_len = len(req.context)
+            ctx_len = req.context_len
             remaining = ctx_len - req.num_computed
             assert remaining >= 1, f"request {req.rid} over-computed"
-            is_decode = remaining == 1 and bool(req.out_tokens)
+            is_decode = remaining == 1 and ctx_len > len(req.prompt)
             want = 1 if is_decode else min(budget, remaining)
             if want == 0:           # prefill budget spent this step
                 i += 1
@@ -680,6 +697,7 @@ class ContinuousBatchingScheduler:
         else:
             return False
         self._release_adapter(req)
+        req.inflight = 0
         req.state = RequestState.FINISHED
         req.outcome = outcome
         if outcome in self.stats:
@@ -708,23 +726,35 @@ class ContinuousBatchingScheduler:
         victim.slot = -1
         victim.state = RequestState.WAITING
         victim.num_computed = 0
+        # a token still in flight is not waited for: the row is dropped
+        # at landing (ServeSession._land) and the re-prefill emits it
+        victim.inflight = 0
         victim.preemptions += 1
+        victim._t_requeue = time.perf_counter()
         self.stats["preemptions"] += 1
         self.waiting.appendleft(victim)
 
-    def complete_chunk(self, chunk: ChunkPlan) -> None:
-        """Bookkeeping after the engine computed a chunk: the tokens
-        are now resident, and every page the chunk COMPLETED is
-        registered in the prefix cache (full pages only — the tail is
-        still being written). The engine emits the chunk's token (if
-        `chunk.emits`) after this call."""
+    def chunk_dispatched(self, chunk: ChunkPlan) -> None:
+        """Bookkeeping once the engine DISPATCHED a chunk: its tokens
+        are resident for every later program (the device runs programs
+        in order), so the next plan may build on them before the step's
+        results reach the host."""
         assert not chunk.draft_tokens, (
             "speculative chunks complete via complete_spec_chunk "
             "(their residency depends on verification)")
         req = chunk.req
         self.cache.advance(req.slot, chunk.end)
         req.num_computed = chunk.end
+
+    def chunk_landed(self, chunk: ChunkPlan) -> None:
+        """Bookkeeping once a dispatched chunk's step LANDED: every
+        page the chunk COMPLETED is registered in the prefix cache
+        (full pages only — the tail is still being written). The keys
+        hash the context's tokens, so every token under them must have
+        landed. The engine emits the chunk's token (if `chunk.emits`)
+        after this call."""
         if self.prefix_cache:
+            req = chunk.req
             ps = self.cache.cfg.page_size
             keys = self._keys_for(req, chunk.end // ps)
             for idx in range(chunk.start // ps, chunk.end // ps):
@@ -798,6 +828,7 @@ class ContinuousBatchingScheduler:
         the prefix cache's LRU — so the next schedule() backfills from
         the waiting queue."""
         assert req.state == RequestState.RUNNING, req.state
+        req.inflight = 0
         req.state = RequestState.FINISHED
         req.outcome = RequestOutcome.COMPLETED
         del self.running[req.slot]

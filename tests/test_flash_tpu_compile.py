@@ -247,7 +247,7 @@ def test_olmoe_mixed_step_holds_one_expert_kernel_a_layer(topo, as_tpu):
                                   sharding=one)
     text = jax.jit(engine._mixed_impl).lower(
         _sds(engine._step_params, one), _sds(engine._device_pool(), one),
-        lane, lane, lane, lane, tables, lane, lane, rows
+        lane, lane, lane, lane, tables, lane, lane, rows, lane, rows
     ).compile().as_text()
     engine.close()
     calls = [line for line in text.splitlines()
@@ -306,7 +306,7 @@ def test_minicpm_sala_mixed_step_compiles_within_its_memory_plan(topo,
                                   sharding=one)
     compiled = jax.jit(engine._mixed_impl, donate_argnums=(1,)).lower(
         _sds(engine._step_params, one), _sds(pool, one), lane, lane, lane,
-        lane, tables, lane, lane, rows).compile()
+        lane, tables, lane, lane, rows, lane, rows).compile()
     engine.close()
     text = compiled.as_text()
     calls = [line for line in text.splitlines() if "tpu_custom_call" in line]

@@ -263,6 +263,7 @@ def test_mixed_step_on_the_kernel_lowers_no_grouped_matmul(engine):
     pts = np.zeros((c.max_seqs, c.pages_per_seq), np.int32)
     text = jax.jit(engine._mixed_impl).lower(
         engine._step_params, engine._device_pool(), z, z, z, z, pts, z,
-        z + 1, z[:engine.head_rows]).as_text(debug_info=True)
+        z + 1, z[:engine.head_rows], z - 1, z[:engine.head_rows]
+    ).as_text(debug_info=True)
     assert "serve_step/layer1/experts/" in text
     assert "ragged_dot" not in text

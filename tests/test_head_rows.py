@@ -144,8 +144,11 @@ def test_the_step_s_rows_are_the_emitting_lanes_of_the_all_lane_head(
         del seen[:]
         with monkeypatch.context() as m:
             m.setattr(owner, name, recording)
+            # (a token the step before emits comes from its `greedy`,
+            # still on the device: the step's own token source)
             full = all_lanes(eng._step_params, eng._device_pool(),
-                             *args[:7], jnp.arange(width, dtype=jnp.int32))
+                             *args[:7], jnp.arange(width, dtype=jnp.int32),
+                             args[8], eng._greedy)
             jax.effects_barrier()
         out = real_dispatch(*args, **kw)
         steps.append((seen[0], [np.asarray(a) for a in full],
@@ -162,9 +165,11 @@ def test_the_step_s_rows_are_the_emitting_lanes_of_the_all_lane_head(
             ev = s.step()
             if ev is None or not ev.dispatched:
                 continue
-            want, head_lanes = packed[-1]
+            # a call hands out the step that LANDED in it, not the one
+            # it packed
+            want, head_lanes = packed[ev.step_index]
             lanes = [ln for ln, _ in want]
-            x, full, got = steps[-1]
+            x, full, got = steps[ev.step_index]
             assert ev.lanes == rows and ev.emitters <= ev.lanes
             assert head_lanes.shape == (rows,)
             assert list(head_lanes[:len(lanes)]) == lanes

@@ -420,6 +420,7 @@ def test_the_older_descriptions_steps_have_nothing_of_the_selection(which):
     text = str(jax.make_jaxpr(eng._mixed_impl)(
         eng._step_params, pool, lane, lane, lane, lane,
         jnp.zeros((c.max_seqs, c.pages_per_seq), jnp.int32), lane,
-        lane + 1, jnp.zeros((eng.head_rows,), jnp.int32)))
+        lane + 1, jnp.zeros((eng.head_rows,), jnp.int32), lane - 1,
+        jnp.zeros((eng.head_rows,), jnp.int32)))
     assert "sparse_" not in text and "linear_" not in text
     eng.close()

@@ -285,7 +285,8 @@ def test_mixed_step_lowers_with_the_expert_scopes(engine):
     pts = np.zeros((c.max_seqs, c.pages_per_seq), np.int32)
     text = jax.jit(engine._mixed_impl).lower(
         engine._step_params, engine._device_pool(), z, z, z, z, pts, z,
-        z + 1, z[:engine.head_rows]).as_text(debug_info=True)
+        z + 1, z[:engine.head_rows], z - 1, z[:engine.head_rows]
+    ).as_text(debug_info=True)
     for path in ("serve_step/embed/", "serve_step/layer0/ln/",
                  "serve_step/layer0/qkv/", "serve_step/layer1/kv_write/",
                  "serve_step/layer1/attn/", "serve_step/layer0/attn_out/",

@@ -385,16 +385,18 @@ def test_every_step_counts_its_fixed_shape_against_its_live_work(
         E, "work_items",
         lambda *a, **kw: walked.append(real(*a, **kw)) or walked[-1])
     rng = np.random.RandomState(3)
-    steps = []
+    steps, packed = [], []
     with E.ServeSession(eng) as s:
         for n in (30, 3, 17, 40, 9, 25, 2, 33):
             s.submit(list(rng.randint(1, 61, size=n)), 10)
         while s.has_work():
             del walked[:]
             ev = s.step()
+            if walked:      # the call packed a step: the next to land
+                packed.append(list(walked))
             if ev is None or not ev.dispatched:
                 continue
-            lists = list(walked)    # `_pack` adds keys, changes none
+            lists = packed[ev.step_index]   # `_pack` adds keys, changes none
             assert len(lists) == 1 + bool(window_calls)
             calls = [full_calls, window_calls][:len(lists)]
             grids = [eng.attn_max_items, eng.window_max_items]
@@ -469,7 +471,8 @@ def test_the_window_list_s_calls_have_a_name_of_their_own(kind):
     pts = np.zeros((c.max_seqs, c.pages_per_seq), np.int32)
     text = jax.jit(eng._mixed_impl).lower(
         eng._step_params, eng._device_pool(), z, z, z, z, pts, z, z + 1,
-        z[:eng.head_rows]).as_text(debug_info=True)
+        z[:eng.head_rows], z - 1, z[:eng.head_rows]
+    ).as_text(debug_info=True)
     names = set(re.findall(r"paged_ragged_v2\w*", text))
     assert names == ({"paged_ragged_v2", "paged_ragged_v2_window"}
                      if kind == "hybrid" else {"paged_ragged_v2"})

@@ -366,8 +366,8 @@ def test_the_step_names_every_mixer_s_phases(engine):
     text = jax.jit(engine._mixed_impl).lower(
         engine.params, engine._device_pool(), z, z, z, z,
         jnp.zeros((c.max_seqs, c.pages_per_seq), jnp.int32), z,
-        jnp.ones((t,), jnp.int32), z[:engine.head_rows]
-    ).as_text(debug_info=True)
+        jnp.ones((t,), jnp.int32), z[:engine.head_rows], z - 1,
+        z[:engine.head_rows]).as_text(debug_info=True)
     for path in ("serve_step/layer0/ssm_proj/", "serve_step/layer0/ssm_conv/",
                  "serve_step/layer4/ssm_scan/", "serve_step/layer1/kv_write/",
                  "serve_step/layer1/attn/", "serve_step/layer1/diff_norm/",

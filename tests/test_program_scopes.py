@@ -56,7 +56,8 @@ def _mixed_lowered(eng) -> str:
     pts = np.zeros((c.max_seqs, c.pages_per_seq), np.int32)
     return jax.jit(eng._mixed_impl).lower(
         eng._step_params, eng._device_pool(), z, z, z, z, pts, z, z + 1,
-        z[:eng.head_rows]).as_text(debug_info=True)
+        z[:eng.head_rows], z - 1, z[:eng.head_rows]
+    ).as_text(debug_info=True)
 
 
 def test_mixed_step_lowers_with_its_scopes(lm):

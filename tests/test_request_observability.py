@@ -24,6 +24,7 @@ Layered like the subsystem:
     down cleanly on close().
 """
 
+import functools
 import glob
 import json
 import os
@@ -254,11 +255,12 @@ def test_explain_request_sums_and_errors():
         eng_off.explain_request(0)
 
 
-def test_preempted_request_attributes_stall():
-    """Preemption leaves a preempt_stall component (the requeue_wait
-    async span), and the sum contract survives the adversarial path.
-    Injected page pressure (the PR-6 chaos site) makes the eviction
-    deterministic."""
+@functools.lru_cache(maxsize=None)
+def _preempting_run():
+    """One run under injected page pressure (the PR-6 chaos site makes
+    the evictions deterministic). -> the engine, and the rids that
+    sat out a step: no chunk span in some step between their first
+    and their last."""
     from flexflow_tpu.utils.faults import FaultInjector
     tel = Telemetry()
     inj = FaultInjector("serve.page_pressure:exhaust:0.9@4-8", seed=0)
@@ -270,15 +272,70 @@ def test_preempted_request_attributes_stall():
     rng = np.random.RandomState(5)
     prompts = _prompts(rng, 8, lo=10, hi=26)
     eng.generate(prompts, 8)
-    st = eng.last_stats
-    preempted = [r for r in st["requests"] if r["preemptions"] > 0]
+    steps, chunks = [], {}
+    for ph, track, name, ts, _dur, _ident, args in tel.events:
+        if ph != "X":
+            continue
+        if name == "step":
+            steps.append(ts)
+        elif name in ("prefill", "decode"):
+            chunks.setdefault(args["rid"], set()).add(ts)
+    sat_out = {rid for rid, at in chunks.items()
+               if any(min(at) < ts < max(at) and ts not in at
+                      for ts in steps)}
+    return eng, sat_out
+
+
+def test_preempted_request_attributes_stall():
+    """Preemption leaves a preempt_stall component (the requeue_wait
+    async span) in EVERY request that sat out a step for it, and the
+    sum contract survives the adversarial path."""
+    eng, sat_out = _preempting_run()
+    preempted = [r for r in eng.last_stats["requests"]
+                 if r["preemptions"] > 0]
     assert preempted, "tiny pool should force preemption"
+    assert sat_out & {r["rid"] for r in preempted}
     for row in preempted:
         b = eng.explain_request(row["rid"])
-        assert b["components"]["preempt_stall"] > 0.0
+        if row["rid"] in sat_out:
+            assert b["components"]["preempt_stall"] > 0.0
         lat = b["latency_s"]
         assert abs(sum(b["components"].values()) - lat) \
             <= 1e-9 + 0.01 * lat
+
+
+def test_same_plan_evict_readmit_stalls_no_step():
+    """A request evicted and re-admitted by ONE plan (the pages its
+    eviction freed cover the needy request and its own return) holds a
+    chunk in every step: each eviction still leaves its `preempt`
+    instant and a closed `requeue_wait`, which ends where the
+    re-admitting step's chunk span starts — so the stall is the host's
+    planning time where the step landed first, and nothing where the
+    step before was still running (its chunk span covers the wait).
+    What the eviction cost is the re-prefill, under `prefill`."""
+    eng, sat_out = _preempting_run()
+    never = [r for r in eng.last_stats["requests"]
+             if r["preemptions"] > 0 and r["rid"] not in sat_out]
+    assert never, "the injected pressure evicts the oldest in place"
+    evs = list(eng.telemetry.events)
+    for row in never:
+        rid = row["rid"]
+        begun = {ident: ts for ph, _t, name, ts, _d, ident, args in evs
+                 if ph == "b" and name == "requeue_wait"
+                 and args["rid"] == rid}
+        ended = {ident: ts for ph, _t, name, ts, _d, ident, _a in evs
+                 if ph == "e" and name == "requeue_wait"
+                 and ident in begun}
+        assert len(begun) == len(ended) == row["preemptions"]
+        assert all(ended[i] >= begun[i] for i in begun)
+        instants = [args for ph, _t, name, _ts, _d, _i, args in evs
+                    if ph == "i" and name == "preempt"
+                    and args["rid"] == rid]
+        assert len(instants) == row["preemptions"]
+        b = eng.explain_request(rid)
+        waits = sum(ended[i] - begun[i] for i in begun)
+        assert 0.0 <= b["components"]["preempt_stall"] <= waits + 1e-9
+        assert b["components"]["prefill"] > 0.0
 
 
 def test_fold_attribution_registry_series():

@@ -165,9 +165,11 @@ def test_kv_cache_exhaustion_recovers():
 # --------------------------------------------------------- scheduler
 def _drive_step(sched, cache, plan):
     """What the engine does with a plan, minus the device work:
-    bookkeeping first (complete_chunk), then emissions."""
+    bookkeeping first (chunk_dispatched, chunk_landed), then
+    emissions."""
     for ch in plan.chunks:
-        sched.complete_chunk(ch)
+        sched.chunk_dispatched(ch)
+        sched.chunk_landed(ch)
     for ch in plan.chunks:
         if ch.emits:
             ch.req.out_tokens.append(0)

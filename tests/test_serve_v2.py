@@ -204,7 +204,8 @@ def test_kv_pool_stress_property():
         plan = sched.schedule()
         assert plan.chunks
         for ch in plan.chunks:
-            sched.complete_chunk(ch)
+            sched.chunk_dispatched(ch)
+            sched.chunk_landed(ch)
         for ch in plan.chunks:
             if ch.emits:
                 ch.req.out_tokens.append(int(rng.randint(0, 9)))
@@ -262,7 +263,8 @@ def test_kv_pool_stress_with_rollback():
         assert plan.chunks
         for ch in plan.chunks:
             if not ch.draft_tokens:
-                sched.complete_chunk(ch)
+                sched.chunk_dispatched(ch)
+                sched.chunk_landed(ch)
         for ch in plan.chunks:
             if ch.draft_tokens:
                 # simulated verification: the engine's emit_spec rules
@@ -327,7 +329,8 @@ def test_scheduler_many_slots_fast_partition():
     assert len(plan.admitted) == n
     assert plan.num_prefill_lanes == 2 * n and plan.num_decode_lanes == 0
     for ch in plan.chunks:
-        sched.complete_chunk(ch)
+        sched.chunk_dispatched(ch)
+        sched.chunk_landed(ch)
         ch.req.out_tokens.append(0)
     plan2 = sched.schedule()
     # every slot decodes; the partition is exact and disjoint

@@ -421,7 +421,8 @@ def test_compile_counter_sees_forced_new_signature(lm):
     z = jnp.zeros((t,), jnp.int32)
     pts = jnp.zeros((c.max_seqs, c.pages_per_seq), jnp.int32)
     eng._call_counted("mixed", eng._mixed_jit, eng.params, pool,
-                      z, z, z, z, pts, z, jnp.ones((t,), jnp.int32), z)
+                      z, z, z, z, pts, z, jnp.ones((t,), jnp.int32), z,
+                      z - 1, z)
     assert eng.compile_counts()["mixed"] == c0 + 1
     if eng._events_ok:   # jax.monitoring present: the EVENT path saw it
         assert eng._compiles["mixed"] == 2
