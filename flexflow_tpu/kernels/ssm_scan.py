@@ -3,9 +3,9 @@
 Replaces `ops/ssm.py::segmented_scan` — a `lax.scan` over the step's
 lanes whose every trip round-trips the carried state and the slots'
 slab through HBM (2.8 ms a layer at Phi-4-mini-flash's served shape;
-PERF.md section 6, PR 32) — in `ServeEngine._ssm_layer`. That scan stays
-as this kernel's jnp twin (as `_ragged_jnp` is the paged kernel's): the
-tests hold the two together.
+PERF.md section 6, PR 32) — in the state-space body of
+serve/mixers.py. That scan stays as this kernel's jnp twin (as
+`_ragged_jnp` is the paged kernel's): the tests hold the two together.
 
 Why a kernel: the recurrence is elementwise in `d_inner`, so the grid
 runs over BLOCKS of `d_inner`, each independent and exact. A grid step

@@ -1,12 +1,15 @@
 """What the serve engine asks of the model it serves.
 
-The engine (serve/engine.py) owns lanes, pages, the paged kernel, the
-head's top-k and every program; the MODEL is a description it asks:
-how a token is embedded, a layer's norm and projections at the lanes'
+The engine (serve/engine.py) owns lanes, pages, the head's top-k and
+every program, and serve/mixers.py owns what each layer's MIXER KIND
+does in the step (its body around the paged kernel or the scans, its
+grid bound, its counts); the MODEL is a description they ask: how a
+token is embedded, a layer's norm and projections at the lanes'
 positions, its output projection, its feed-forward, the head — and what
 it cannot do, which raises at engine build (no silent fallback). A
 description reads the parameters of a compiled FFModel through the op
-names its builder wrote, and mirrors those ops' numerics.
+names its builder wrote, and mirrors those ops' numerics. This module
+imports neither the engine nor the scheduler.
 
 Five clients: `TransformerLM` (models/transformer.build_transformer_lm:
 learned positions, LayerNorm, ReLU feed-forward — the OPT block),
@@ -22,9 +25,10 @@ a learned selection of the context in some layers, lightning linear
 attention with a matrix state a sequence in the others).
 
 What a description answers (docs/serving.md "What a description must
-answer"): the dimensions; per layer the MIXER KIND (`mixer(i)`:
-"attn", one of models/phi4flash's five, or models/minicpm_sala's two);
-the geometry of the K/V it
+answer"): the dimensions; per layer the MIXER KIND (`mixer(i)`: one of
+the eight names serve/mixers.py has a body for — "attn",
+models/phi4flash's five, models/minicpm_sala's two) and the
+projections that kind's body calls; the geometry of the K/V it
 pages (`kv_heads`, `kv_head_dim`, `paged_layers`, `attn_scale`); what a
 sequence holds besides pages (`hybrid_spec`, a serve/kv_cache.HybridSpec
 or None); and `refuse`, which raises BY NAME for every engine path the
@@ -34,8 +38,6 @@ model is not served on.
 from __future__ import annotations
 
 import math
-from typing import Tuple
-
 import jax
 import jax.numpy as jnp
 
@@ -128,22 +130,26 @@ class Description:
     # (first, count): the experts whose weights live HERE, one share of
     # an expert-parallel layer (None: all of them). The step's counts
     # are then over the held experts, and one more: the live slots
-    # whose expert is absent
+    # whose expert is absent (read by ServeSession._count_experts)
     experts_held = None
+    # the WINDOW layers' window (0: none): mixers.geometry bounds their
+    # grid by it, their body and step_counts walk the rings under it
     window = 0
     # width of the selector's row a page (serve/kv_cache.KVPool.kc); 0:
-    # the model selects nothing and the pool has no such leaf
+    # the model selects nothing and the pool has no such leaf (read at
+    # engine build, for KVCacheConfig)
     selector_dim = 0
     # a model that SELECTS its context: the positions under which a
     # lane attends every key before it, through the paged kernel (0: no
-    # selection, every lane does)
+    # selection, every lane does). mixers.walked is the one reader
     dense_len = 0
     # differential attention: the paged call's output goes through
-    # `diff_norm` before `attn_out`
+    # `diff_norm` before `attn_out` (the attention body of mixers.py)
     differential = False
     # a PARALLEL block: one norm a layer, `attn_out` and `ffn` (which is
     # handed `h`, that norm's output) return their BRANCH alone and the
-    # step adds x + (a + f) once. False: each returns x + its branch
+    # step adds x + (a + f) once (ServeEngine._mixed_layer). False: each
+    # returns x + its branch
     parallel_block = False
     # (params, (1, S) tokens) -> (S, V): a full-sequence forward to use
     # as the engine's naive oracle in place of its own attention-only
@@ -184,11 +190,6 @@ class Description:
     @property
     def attn_scale(self) -> float:
         return 1.0 / math.sqrt(self.head_dim)
-
-    def attn_calls(self) -> Tuple[int, int]:
-        """(paged attention calls a step that walk the full pages' work
-        list, calls that walk the window layers' list)."""
-        return self.num_layers, 0
 
     def refuse(self, *, tp: int = 1, adapters: bool = False,
                speculation: bool = False, prefix_cache: bool = False,
@@ -550,11 +551,6 @@ class Phi4Flash(Description):
     def paged_layers(self) -> int:
         return 1
 
-    def attn_calls(self) -> Tuple[int, int]:
-        # the full layer's own call and every cross layer's read its
-        # pages; each window layer reads its ring
-        return 1 + self.kinds.count(CROSS), len(self.window_layers)
-
     def embed(self, params, tokens, positions):
         return jnp.take(params["tok_embed"]["kernel"], tokens, axis=0,
                         mode="clip").astype(self.act_dtype)
@@ -721,9 +717,6 @@ class CommandAPlus(Description):
     def paged_layers(self) -> int:
         return len(self.full_layers)
 
-    def attn_calls(self) -> Tuple[int, int]:
-        return len(self.full_layers), len(self.window_layers)
-
     def embed(self, params, tokens, positions):
         return jnp.take(params["tok_embed"]["kernel"], tokens, axis=0,
                         mode="clip").astype(self.act_dtype)
@@ -889,11 +882,6 @@ class MiniCPMSala(Description):
     @property
     def paged_layers(self) -> int:
         return len(self.sparse_layers)
-
-    def attn_calls(self) -> Tuple[int, int]:
-        # a paged call a key/value head of a sparse layer: the lanes
-        # under dense_len, over the table's first dense_len positions
-        return self.paged_layers * self.kv_heads, 0
 
     def embed(self, params, tokens, positions):
         x = jnp.take(params["tok_embed"]["kernel"], tokens, axis=0,

@@ -59,26 +59,20 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import CompMode
-from ..kernels import ssm_scan
-from ..kernels.paged_ragged_v2 import (JNP, PALLAS_INTERPRET, Q_ROWS,
-                                       build_work_list, choose_block_kv,
-                                       kv_page_bytes, max_work_items,
-                                       paged_attention_ragged_v2,
+from ..kernels.paged_ragged_v2 import (Q_ROWS, choose_block_kv,
                                        ragged_dispatch_passes,
-                                       resolve_paged_impl,
-                                       window_block_bound, work_items)
+                                       resolve_paged_impl)
 from ..parallel.mesh import TENSOR, replica_devices, serve_tensor_mesh
 from ..utils.faults import FaultInjector, TransientError, injector_for
 from ..utils.telemetry import (Telemetry, pow2_bucket, serve_metrics,
                                telemetry_for)
-from ..ops import linear_attention, ssm
-from .arch import (ATTN, CROSS, FULL, GMU, LINEAR, SPARSE, SSM, WINDOW,
-                   _dense, describe)
+from . import mixers
+from .arch import _dense, describe
 from .kv_cache import (HybridPool, KVCacheConfig, KVPool, PagedKVCache,
-                       kv_storage_dtype, ring_tables)
+                       kv_storage_dtype)
+from .mixers import LIVE_COUNTS, SELECT_COUNTS  # noqa: F401 (importable here)
 from .scheduler import (ChunkPlan, ContinuousBatchingScheduler, Request,
                         RequestOutcome, RequestState, SampleParams)
-from .sparse_paged import paged_sparse_attention, stride_keys
 
 # pad bias for vocab columns the head padding invents (vocab % t != 0):
 # a padded logit must never win argmax or enter the top-k window
@@ -224,10 +218,7 @@ class ServeEngine:
         # resolved choice down; last_stats / boot_stats / the program
         # fingerprint report it.
         self.attn_impl = resolve_paged_impl(use_pallas, interpret)
-        self._attn_kw = {"use_pallas": self.attn_impl != JNP,
-                         "interpret": self.attn_impl == PALLAS_INTERPRET}
         self._read_arch(model)
-        self.arch.kernels = self._attn_kw
         if max_seq_len is None:
             max_seq_len = self.max_positions
         if max_seq_len > self.max_positions:
@@ -370,61 +361,26 @@ class ServeEngine:
         # the one mixed-step geometry: every prefill-budget token plus
         # one decode lane per slot always fits
         self.mixed_width = self.prefill_budget + self.cache_cfg.max_seqs
-        # the state-space layers' scan runs where the paged kernel runs
-        # (kernels/ssm_scan.py, under `attn_impl`) wherever that kernel
-        # takes the step's shape, else as its jnp twin
-        # (ops/ssm.segmented_scan); None: no such layer
-        hyb = self.cache_cfg.hybrid
-        self.scan_impl = None
-        if hyb is not None and hyb.state_layers:
-            # a linear-attention layer's matrix state has the twin alone
-            # (ops/linear_attention.segmented_lightning)
-            self.scan_impl = self.attn_impl if SSM in map(
-                self.arch.mixer, range(self.num_layers)
-            ) and ssm_scan.supported(
-                self.mixed_width, *hyb.state_shape) else JNP
+        # what each layer's mixer kind makes of this step, decided once
+        # (serve/mixers.py: which scan runs, the columns a selecting
+        # model's paged calls walk, the two grid bounds with their
+        # proof, the paged calls a step). The names below are read by
+        # stats, tests and the benchmark: plain copies
+        self.geometry = g = mixers.geometry(
+            self.arch, self.cache_cfg, width=self.mixed_width,
+            attn_impl=self.attn_impl, block_kv=self.attn_block_kv)
+        self.scan_impl = g.scan_impl
+        self.dense_pages = g.dense_pages
+        self.attn_block_pages = g.block_pages
+        self.attn_max_items = g.attn_max_items
+        self.window_max_items = g.window_max_items
+        self.arch.kernels = g.attn_kw
         # the expert layer's gated expert is one fused kernel
         # (kernels/grouped_ffn.py, by the same arguments as the paged
         # kernel) wherever that kernel takes the step's rows and
         # weights, else three grouped matmuls ("ragged_dot"); None: no
         # expert layer
         self.expert_impl = self.arch.expert_impl(self.mixed_width)
-        # the paged kernel's grid: the most work items a plan can make
-        # (kernels/paged_ragged_v2.max_work_items). PROOF of the slot
-        # changes: _pack lays a plan's chunks one after another, each
-        # chunk (with its draft tokens) in consecutive lanes of ONE
-        # slot, then the inactive lanes on slot 0; a plan holds at most
-        # one chunk per running request (Scheduler.schedule: one per
-        # entry of `running`, one per admission, each with a slot of
-        # its own), so at most max_seqs chunks; the slot changes from
-        # a lane to the next only where a chunk ends: at most max_seqs
-        # times. _pack checks every plan against the bound and raises
-        # (tests/test_paged_work_list.py drives a busy session at it).
-        self.attn_block_pages = max(
-            1, self.attn_block_kv // self.cache_cfg.page_size)
-        # a model that SELECTS its context (arch.dense_len) walks pages
-        # in the paged kernel only for its lanes under dense_len: the
-        # list is built over the table's first `dense_pages` columns,
-        # and the grid is bounded by them, not by the positions served
-        # (0: every column)
-        self.dense_pages = min(
-            self.cache_cfg.pages_per_seq,
-            -(-self.arch.dense_len // self.cache_cfg.page_size))
-        self.attn_max_items = max_work_items(
-            self.mixed_width,
-            self.dense_pages or self.cache_cfg.pages_per_seq,
-            self.attn_block_pages, Q_ROWS,
-            slot_changes=self.cache_cfg.max_seqs)
-        # a window layer's list is built apart (its items start at the
-        # window's first block); its grid is bounded by the window
-        self.window_max_items = max_work_items(
-            self.mixed_width, self.cache_cfg.pages_per_seq,
-            self.attn_block_pages, Q_ROWS,
-            slot_changes=self.cache_cfg.max_seqs,
-            window_blocks=window_block_bound(
-                self.arch.window,
-                self.attn_block_pages * self.cache_cfg.page_size)
-        ) if self.arch.window else 0
         self.topk_cap = min(self.TOPK_CAP, self.vocab_size)
         # persistent across generate() calls: the prefix cache only
         # pays off if committed pages outlive the batch that wrote them
@@ -1129,7 +1085,6 @@ class ServeEngine:
             x = (self._embed_tp(params, tokens, positions, tp_axis)
                  if tp_axis else
                  self.arch.embed(params, tokens, positions))  # (T, E)
-        scale = float(self.arch.attn_scale)
         # multi-tenant adapters (serve/adapters.py): ONE gather pulls
         # each lane's whole (A, B) stack — slab (S, L, ...) rows by
         # the lane's slot index — so the per-layer loop just slices.
@@ -1142,41 +1097,21 @@ class ServeEngine:
                 ad = {key: jnp.take(arr, lane_adapters, axis=0)
                       for key, arr in adapters.items() if key != "scale"}
                 ad_s = jnp.take(adapters["scale"], lane_adapters, axis=0)
-        # the paged kernel's work list: from the lane arrays, once for
-        # all the layers (the jnp attention reads the lane arrays)
-        work = None
-        # what the paged calls walk: every lane's pages — or, where the
-        # model selects its context, the lanes under its dense_len
-        walked = (page_tables, lane_lens) if not self.dense_pages \
-            else self._dense_lanes(page_tables, positions, lane_lens)
-        if self.attn_impl != JNP:
-            with scope("work_list"):
-                work = build_work_list(
-                    walked[0], lane_slots, walked[1],
-                    page_size=self.cache_cfg.page_size,
-                    block_pages=self.attn_block_pages,
-                    max_items=self.attn_max_items)
-        # an expert layer routes only the step's live lanes (inactive
-        # lanes aim their K/V at the sink page 0, _pack), and the step
-        # returns each layer's live slots per expert beside the tokens
-        live = write_pages != 0 if self.arch.experts else None
-        # what the other mixer kinds share across layers (None for a
-        # model of attention layers alone)
-        hyb = self._hybrid_lanes(positions, write_pages, lane_slots,
-                                 lane_lens) \
-            if self.cache_cfg.hybrid is not None else None
-        if self.dense_pages:
-            hyb = {**(hyb or {}), "dense_tables": walked[0],
-                   "dense_lens": walked[1]}
+        # what the layers share, made of the lane arrays once: the work
+        # lists, the runs, the rings (serve/mixers.py)
+        lanes = mixers.step_lanes(self.geometry, positions, write_pages,
+                                  write_offs, page_tables, lane_slots,
+                                  lane_lens)
+        # what the description's memory layer hands the layers after it
+        memory = None
         expert_counts = []
         for i in range(self.num_layers):
             with scope(f"layer{i}"):
-                x, pool, counts = self._mixed_layer(
-                    params, i, x, positions, live, pool, write_pages,
-                    write_offs, page_tables, lane_slots, lane_lens, work,
-                    scale, None if ad is None else
+                x, pool, memory, counts = self._mixed_layer(
+                    params, i, x, lanes, pool, memory,
+                    None if ad is None else
                     {key: arr[:, i] for key, arr in ad.items()},
-                    ad_s, tp_axis, hyb)
+                    ad_s, tp_axis)
                 expert_counts.append(counts)
         with scope("head"):
             # only the lanes that emit have logits anyone reads: the
@@ -1193,294 +1128,38 @@ class ServeEngine:
             out += (jnp.stack(expert_counts),)           # (layers, E)
         return out, pool
 
-    def _dense_lanes(self, page_tables, positions, lane_lens):
-        """-> (the page tables' first `dense_pages` columns, the lanes'
-        lengths with 1 for a lane past the selector's dense_len): what
-        the paged call of a sparse layer walks."""
-        return (page_tables[:, :self.dense_pages],
-                jnp.where(positions < self.arch.dense_len, lane_lens, 1))
-
-    def _hybrid_lanes(self, positions, write_pages, lane_slots,
-                      lane_lens) -> dict:
-        """What the step's state-space and window layers share, from
-        the lane arrays, once for all the layers: the RUNS (consecutive
-        lanes of one sequence at consecutive positions: a chunk, a
-        decode lane), each lane's offset in its run, the slot a lane's
-        state is written back to (its own where it is its run's last
-        live lane, else the slabs' sink row), and the rings' page
-        table, write addresses and work list; `live_lanes` the lanes
-        up to the last live one (_pack fills them from 0 up, so: the
-        live lanes), the trips of the scan kernel."""
-        c = self.cache_cfg
-        with jax.named_scope("work_list"):
-            live = write_pages != 0
-            lane = jnp.arange(1, live.shape[0] + 1, dtype=jnp.int32)
-            # runs are the scans' alone: nothing of them without a
-            # state-space layer
-            state = c.hybrid.state_layers > 0
-            starts = ssm.run_starts(lane_slots, positions) if state \
-                else None
-            # rings are the window layers' alone: no table, write
-            # addresses or work list of them without one
-            ringed = c.hybrid.window_layers > 0
-            if ringed:
-                rings = ring_tables(c, jnp)
-                page = positions // c.page_size
-            hyb = {"memory": None, "work": None, "live": live}
-            if state:
-                hyb.update(
-                    starts=starts, offsets=ssm.run_offsets(starts),
-                    wslots=ssm.run_write_slots(starts, live, lane_slots,
-                                               c.max_seqs),
-                    live_lanes=jnp.max(jnp.where(live, lane, 0)))
-            if not ringed:
-                return hyb
-            hyb["rings"] = rings
-            hyb["ring_pages"] = jnp.where(live, rings[lane_slots, page], 0)
-            if self.attn_impl != JNP:
-                hyb["work"] = build_work_list(
-                    rings, lane_slots, lane_lens, page_size=c.page_size,
-                    block_pages=self.attn_block_pages,
-                    max_items=self.window_max_items,
-                    window=self.arch.window)
-        return hyb
-
-    def _mixed_layer(self, params, i, x, positions, live, pool,
-                     write_pages, write_offs, page_tables, lane_slots,
-                     lane_lens, work, scale, la, ad_s, tp_axis, hyb=None):
-        """Layer `i` of the mixed step, dispatched on the description's
-        mixer kind, one named scope per phase. An attention layer:
-        `ln`, `qkv` (the description's projections at the lanes'
-        positions), `kv_write` (KVPool.write: quantize and scatter; a
-        cross layer writes nothing), `attn` (the ragged paged kernel
-        over the step's `work` list — a window layer over its ring and
-        the window's list, a cross layer over the full layer's pages),
-        `diff_norm` where the attention is differential, `attn_out`. A
-        state-space layer: `ln`, `ssm_proj`, `ssm_conv`, `ssm_scan`. A
-        gated memory unit: `ln`, `gmu`. Then the description's
-        feed-forward under its own scopes (`ffn`, or `router`,
-        `moe_dispatch`, `experts`, `moe_combine`, with `shared_experts`
-        where there are some). A PARALLEL block (arch.parallel_block)
-        has the one norm: the feed-forward reads `h` too, both branches
-        come back alone and `residual` adds them to x once. `la` is the lanes'
-        adapter rows of this layer (None: no adapters); `hyb` what
-        `_hybrid_lanes` made (None: attention layers alone). Returns
-        the layer's live slots per expert last (None without an expert
-        layer)."""
+    def _mixed_layer(self, params, i, x, lanes, pool, memory, la, ad_s,
+                     tp_axis):
+        """Layer `i` of the mixed step -> (x, pool, memory, the layer's
+        live slots per expert or None): `ln`, then the body of the
+        layer's mixer kind (serve/mixers.py BODIES, under its own
+        scopes), then the description's feed-forward under its scopes
+        (`ffn`, or `router`, `moe_dispatch`, `experts`, `moe_combine`,
+        with `shared_experts` where there are some). A PARALLEL block
+        (arch.parallel_block) has the one norm: the feed-forward reads
+        `h` too, both branches come back alone and `residual` adds them
+        to x once. `la` is the lanes' adapter rows of this layer (None:
+        no adapters), `ad_s` their scales; `memory` what the memory
+        layer's body returned for the layers after it."""
         scope = jax.named_scope
         arch = self.arch
-        kind = arch.mixer(i)
         with scope("ln"):
             h = arch.norm1(params, i, x)
-        if kind == SSM:
-            x, pool = self._ssm_layer(params, i, x, h, positions, pool,
-                                      lane_slots, hyb)
-        elif kind == LINEAR:
-            x, pool = self._linear_layer(params, i, x, h, positions, pool,
-                                         lane_slots, hyb)
-        elif kind == SPARSE:
-            x, pool = self._sparse_layer(
-                params, i, x, h, positions, pool, write_pages, write_offs,
-                page_tables, lane_slots, work, scale, hyb)
-        elif kind == GMU:
-            with scope("gmu"):
-                x = arch.gmu(params, i, h, hyb["memory"], x)
-        else:
-            # x + the attention branch or, in a parallel block, the
-            # branch alone
-            a, pool = self._attn_layer(
-                params, i, kind, x, h, positions, pool, write_pages,
-                write_offs, page_tables, lane_slots, lane_lens, work,
-                scale, la, ad_s, tp_axis, hyb)
-            if arch.parallel_block:
-                f, counts = arch.ffn(params, i, x, h=h, live=live,
-                                     psum_axis=tp_axis)
-                with scope("residual"):
-                    return x + (a + f), pool, counts
-            x = a
+        # x + the mixer's branch or, in a parallel block, the branch
+        # alone
+        a, pool, memory = mixers.BODIES[arch.mixer(i)](
+            self.geometry, params, i, x, h, lanes, pool, memory,
+            None if la is None else (la, ad_s), tp_axis)
+        if arch.parallel_block:
+            f, counts = arch.ffn(params, i, x, h=h, live=lanes.ffn_live,
+                                 psum_axis=tp_axis)
+            with scope("residual"):
+                return x + (a + f), pool, memory, counts
         x, counts = arch.ffn(
-            params, i, x, live=live, psum_axis=tp_axis,
+            params, i, a, live=lanes.ffn_live, psum_axis=tp_axis,
             lora=None if la is None else
             (la["a_ff1"], la["b_ff1"], la["a_ff2"], la["b_ff2"], ad_s))
-        return x, pool, counts
-
-    def _attn_layer(self, params, i, kind, x, h, positions, pool,
-                    write_pages, write_offs, page_tables, lane_slots,
-                    lane_lens, work, scale, la, ad_s, tp_axis, hyb):
-        """The attention mixer of layer `i` -> (x, pool). `kind` says
-        which pages it writes and reads: ATTN layer i of the one pool;
-        WINDOW its own layer of the rings; FULL its own of a hybrid
-        pool's paged layers; CROSS the first of those, writing
-        nothing."""
-        scope = jax.named_scope
-        arch = self.arch
-        with scope("qkv"):
-            q, k, v = arch.qkv(
-                params, i, h, positions, lora=None if la is None else
-                (la["a_qkv"], la["b_qkv"], ad_s))         # (T, H[/t], D)
-        window = 0
-        if kind == ATTN:
-            kv, layer = pool, i
-        elif kind == WINDOW:
-            kv, layer = pool.window, arch.window_layers.index(i)
-            write_pages, page_tables = hyb["ring_pages"], hyb["rings"]
-            work, window = hyb["work"], arch.window
-        elif kind == FULL:
-            kv, layer = pool.full, arch.full_layers.index(i)
-        else:
-            kv, layer = pool.full, 0
-        if kind != CROSS:
-            with scope("kv_write"):
-                kv = kv.write(layer, write_pages, write_offs, k, v)
-            if kind == WINDOW:
-                pool = dataclasses.replace(pool, window=kv)
-            elif kind == FULL:
-                pool = dataclasses.replace(pool, full=kv)
-            else:
-                pool = kv
-        with scope("attn"):
-            k_pages, v_pages, k_scales, v_scales = kv.layer(layer)
-            o = paged_attention_ragged_v2(
-                q, k_pages, v_pages, page_tables, lane_slots, lane_lens,
-                k_scales=k_scales, v_scales=v_scales, scale=scale,
-                block_kv=self.attn_block_kv, work=work, window=window,
-                **self._attn_kw)
-        if arch.differential:
-            with scope("diff_norm"):
-                o = arch.diff_norm(params, i, o)
-        with scope("attn_out"):
-            x = arch.attn_out(
-                params, i, o, x, psum_axis=tp_axis,
-                lora=None if la is None else
-                (la["a_wo"], la["b_wo"], ad_s))
-        return x, pool
-
-    def _ssm_layer(self, params, i, x, h, positions, pool, lane_slots,
-                   hyb):
-        """The state-space mixer of layer `i` over the step's lanes ->
-        (x, pool): `ssm_proj` (the in, x, dt and out projections),
-        `ssm_conv` (the convolution over a run and its slot's tail),
-        `ssm_scan` (the recurrence from each run's slot state, the
-        gate, the state's write-back). The convolution, the scan and
-        the gate run in f32. The description's memory layer leaves its
-        scan output in `hyb` for the gated memory units."""
-        scope = jax.named_scope
-        arch = self.arch
-        j = arch.ssm_layers.index(i)
-        p = params[f"layer{i}_ssm"]
-        with scope("ssm_proj"):
-            u, z = arch.ssm_in(params, i, h)              # (T, d_inner)
-        with scope("ssm_conv"):
-            u, tail = ssm.segmented_conv(
-                p, u, pool.tail[j], lane_slots, positions,
-                hyb["offsets"], hyb["wslots"])
-            u = jax.nn.silu(u)
-        with scope("ssm_proj"):
-            dt, b, c = arch.ssm_scan_inputs(params, i, u)
-        with scope("ssm_scan"):
-            # the kernel keeps an f32 slab in place; a slab of another
-            # dtype (no configuration's) keeps the twin's rounding at
-            # every lane
-            if self.scan_impl != JNP and pool.state.dtype == jnp.float32:
-                y, state = ssm_scan.ssm_scan(
-                    p, u, dt, b, c, pool.state, j, lane_slots, positions,
-                    hyb["starts"], hyb["wslots"], hyb["live_lanes"],
-                    interpret=self.scan_impl == PALLAS_INTERPRET)
-            else:
-                y, state = ssm.segmented_scan(
-                    p, u, dt, b, c, pool.state[j], lane_slots, positions,
-                    hyb["starts"], hyb["wslots"])
-                state = pool.state.at[j].set(state)
-            g = (y * jax.nn.silu(z.astype(jnp.float32))).astype(x.dtype)
-            pool = dataclasses.replace(
-                pool, state=state, tail=pool.tail.at[j].set(tail))
-            if i == arch.memory_layer:
-                hyb["memory"] = y.astype(x.dtype)
-        with scope("ssm_proj"):
-            x = arch.ssm_out(params, i, g, x)
-        return x, pool
-
-    def _linear_layer(self, params, i, x, h, positions, pool, lane_slots,
-                      hyb):
-        """The lightning linear-attention mixer of layer `i` over the
-        step's lanes -> (x, pool): `linear_proj` (the projections, the
-        QK-norm and rotation; the output norm, gate and projection),
-        `linear_scan` (the recurrence from each run's slot state and
-        the state's write-back, ops/linear_attention.
-        segmented_lightning, in f32; a slab of another dtype — no
-        configuration's — is read and rounded back at the step's
-        edge)."""
-        scope = jax.named_scope
-        arch = self.arch
-        j = arch.linear_layers.index(i)
-        with scope("linear_proj"):
-            q, k, v = arch.linear_qkv(params, i, h, positions)
-        with scope("linear_scan"):
-            o, state = linear_attention.segmented_lightning(
-                q, k, v, arch.decays[i], pool.state[j].astype(jnp.float32),
-                lane_slots, positions, hyb["live"], hyb["starts"],
-                hyb["offsets"])
-            pool = dataclasses.replace(pool, state=pool.state.at[j].set(
-                state.astype(pool.state.dtype)))
-        with scope("linear_proj"):
-            x = arch.linear_out(params, i, o, h, x)
-        return x, pool
-
-    def _sparse_layer(self, params, i, x, h, positions, pool, write_pages,
-                      write_offs, page_tables, lane_slots, work, scale,
-                      hyb):
-        """The block-sparse attention mixer of layer `i` -> (x, pool):
-        `qkv`, `kv_write`, `sparse_compress` (the compressed key of
-        every stride a lane's token completes, from the pages just
-        written, to the selector's row of that lane's page), `attn`
-        (the lanes under the selector's dense_len: the paged kernel
-        over the table's first dense_len positions, `work` its list, a
-        call a key/value head: each head's pages are a pool layer of
-        their own, KVCacheConfig.head_layers),
-        then for the lanes past it `sparse_score` (each lane against
-        its sequence's compressed keys), `sparse_select` (block scores,
-        forced blocks, top-k) and `sparse_attn` (the selected blocks'
-        pages, gathered a lane at a time), `attn_out` (the gate and
-        the output projection)."""
-        scope = jax.named_scope
-        arch = self.arch
-        sc = arch.sparse
-        # a pool layer a key/value head
-        layers = self.cache_cfg.head_layers(arch.sparse_layers.index(i))
-        with scope("qkv"):
-            q, k, v = arch.sparse_qkv(params, i, h)
-        kv = pool.full
-        with scope("kv_write"):
-            for g, layer in enumerate(layers):
-                kv = kv.write(layer, write_pages, write_offs,
-                              k[:, g:g + 1], v[:, g:g + 1])
-        with scope("sparse_compress"):
-            tables = jnp.take(page_tables, lane_slots, axis=0)
-            for layer in layers:
-                rows, done = stride_keys(kv, layer, tables, positions, sc)
-                kv = kv.write_selector(
-                    layer, jnp.where(done, write_pages, 0), rows)
-        pool = dataclasses.replace(pool, full=kv)
-        with scope("attn"):
-            each = self.num_heads // self.kv_heads
-            o_dense = []
-            for g, layer in enumerate(layers):
-                k_pages, v_pages, k_scales, v_scales = kv.layer(layer)
-                o_dense.append(paged_attention_ragged_v2(
-                    q[:, g * each:(g + 1) * each], k_pages, v_pages,
-                    hyb["dense_tables"], lane_slots, hyb["dense_lens"],
-                    k_scales=k_scales, v_scales=v_scales, scale=scale,
-                    block_kv=self.attn_block_kv, work=work,
-                    **self._attn_kw))
-            o_dense = jnp.concatenate(o_dense, axis=1)
-        o = paged_sparse_attention(q, kv, layers, page_tables, lane_slots,
-                                   positions, sc)
-        with scope("sparse_attn"):
-            o = jnp.where((positions < sc.dense_len)[:, None, None],
-                          o_dense, o)
-        with scope("attn_out"):
-            x = arch.sparse_out(params, i, o, h, x)
-        return x, pool
+        return x, pool, memory, counts
 
     # ---------------- disaggregated page handoff -----------------------
     # Device half of the prefill->decode transfer (serve/disagg.py;
@@ -2947,16 +2626,6 @@ class ServeEngine:
         return out
 
 
-# the step's fixed shape against its live work, counted in
-# ServeSession._pack: StepEvents attributes and `dispatch` span arguments
-LIVE_COUNTS = ("grid_steps", "live_steps", "short_steps", "live_rows",
-               "lanes", "emitters")
-# what a step's selection did, counted there too, on a model that
-# selects its context (arch.selector_dim)
-SELECT_COUNTS = ("sparse_lanes", "blocks_selected", "blocks_visible",
-                 "selected_kv_bytes", "selector_bytes")
-
-
 class StepEvents:
     """What ONE engine step did, handed out by the
     :meth:`ServeSession.step` call in which it LANDED (its results
@@ -2975,7 +2644,7 @@ class StepEvents:
     calls fetch, ``attn_items`` / ``attn_rows`` the work items ONE of
     those calls runs for the live lanes and the query rows they hold
     (rows / items: how often lanes share an item); over ALL of the
-    step's paged calls (arch.attn_calls: each walks the full pages'
+    step's paged calls (mixers.attn_calls: each walks the full pages'
     list or the window layers'), ``grid_steps`` is the grid steps the
     device walks: the lists' own lengths (a call's grid ends at its
     list's `count`; the inactive tiles keep an item each on the sink
@@ -3033,13 +2702,10 @@ class StepEvents:
 
     __slots__ = ("dispatched", "ahead", "step_index", "plan", "emitted",
                  "finished", "ctx_mean", "wall_s", "host_reload_s",
-                 "kv_bytes_read", "attn_items", "attn_rows", "topv", "topi",
-                 "emit_lanes", *LIVE_COUNTS,
+                 "topv", "topi", "emit_lanes", *mixers.STEP_COUNTS,
                  "expert_counts", "expert_slots", "expert_dropped",
                  "experts_touched", "expert_bytes", "expert_load_max",
-                 "slots_held", "shared_bytes", "lanes_past_window",
-                 "state_bytes", "ssm_runs", "window_kv_bytes",
-                 "full_kv_bytes", *SELECT_COUNTS)
+                 "slots_held", "shared_bytes")
 
     def __init__(self, plan=None):
         self.dispatched = False
@@ -3054,13 +2720,8 @@ class StepEvents:
         # (the router adds it to the virtual clock; wall mode measures
         # it inside the step wall time naturally)
         self.host_reload_s = 0.0
-        # K/V page (and scale) bytes the paged kernel fetches in this
-        # step, all layers, and one call's live work items and their
-        # query rows (kernels/paged_ragged_v2.work_items)
-        self.kv_bytes_read = 0
-        self.attn_items = 0
-        self.attn_rows = 0
-        for key in LIVE_COUNTS:
+        # what the step's mixers do for its lanes (mixers.step_counts)
+        for key in mixers.STEP_COUNTS:
             setattr(self, key, 0)
         self.topv = self.topi = None
         self.emit_lanes: List[int] = []
@@ -3072,13 +2733,6 @@ class StepEvents:
         self.expert_load_max = 0
         self.slots_held = 0
         self.shared_bytes = 0
-        self.lanes_past_window = 0
-        self.state_bytes = 0
-        self.ssm_runs = 0
-        self.window_kv_bytes = 0
-        self.full_kv_bytes = 0
-        for key in SELECT_COUNTS:
-            setattr(self, key, 0)
 
 
 class _Flight:
@@ -3193,8 +2847,6 @@ class ServeSession:
         self.expert_totals = {"steps": 0, "slots": 0, "dropped": 0,
                               "touched": 0, "bytes": 0}
         self.expert_counts_total = None     # (layers, experts) int64
-        # the rings' page table never changes (kv_cache.ring_tables)
-        self._ring_tables = ring_tables(c) if c.ring_pages else None
         self._retries0 = engine._retries
         self._rejected_seen = 0   # flight-recorder rejection trigger
         # the step dispatched and not landed yet, if any (_Flight), how
@@ -3327,9 +2979,9 @@ class ServeSession:
         live lanes, emitters, spec_emitters: (chunk, the ROW of the
         step's outputs that holds its last lane's logits), the paged
         kernel's work for these lanes: `work_items` of one call plus
-        `kv_bytes`, what all layers' calls fetch, and LIVE_COUNTS, the
-        step's fixed shape against its live work over all of its
-        calls)."""
+        `kv_bytes_read`, what all layers' calls fetch, and LIVE_COUNTS,
+        the step's fixed shape against its live work over all of its
+        calls: mixers.step_counts)."""
         eng = self.eng
         cache = eng.cache
         t_w = eng.mixed_width
@@ -3399,97 +3051,13 @@ class ServeSession:
         arrays = (tokens, positions, write_pages, write_offs,
                   cache.page_tables, lane_slots, lane_lens, head_lanes,
                   token_src)
-        # what the paged kernel will do for these lanes (the count is
-        # made where the lanes are made), and the proof's check: a plan
-        # whose items passed the grid's bound would lose work
-        c = eng.cache_cfg
-        group = eng.num_heads // eng.kv_heads   # query heads a K/V head
-        arch = eng.arch
-        walked = (cache.page_tables, lane_lens)
-        if eng.dense_pages:
-            # a model that selects its context: the paged calls walk the
-            # lanes under dense_len (ServeEngine._dense_lanes)
-            walked = (cache.page_tables[:, :eng.dense_pages], np.where(
-                positions < arch.dense_len, lane_lens, 1))
-        work = work_items(
-            walked[1], lane_slots, walked[0], page_size=ps,
-            block_kv_pages=eng.attn_block_pages,
-            max_items=eng.attn_max_items, live_lanes=lane, group=group)
-        if work["total"] > work["grid"]:
-            raise RuntimeError(
-                f"the plan makes {work['total']} attention work items, "
-                f"the kernel's grid holds {work['grid']}")
-        # what ONE call fetches of a page: a pool layer's heads
-        page_bytes = kv_page_bytes(ps, c.layer_heads, eng.kv_head_dim,
-                                   c.kv_itemsize, eng.kv_quantized)
-        full_calls, window_calls = arch.attn_calls()
-        lists = [(full_calls, work)]        # (calls that walk it, list)
-        if c.hybrid is None:
-            work["kv_bytes"] = (
-                full_calls * work["page_fetches"] * page_bytes)
-        else:
-            # a model of several mixer kinds: the full layer's pages
-            # are fetched by its own call and by every cross layer's;
-            # the window layers' calls walk the rings under the
-            # window's list; a scan reads and writes one state and one
-            # tail a RUN
-            ring = {"page_fetches": 0}
-            if self._ring_tables is not None:
-                ring = work_items(
-                    lane_lens, lane_slots, self._ring_tables, page_size=ps,
-                    block_kv_pages=eng.attn_block_pages,
-                    max_items=eng.window_max_items, live_lanes=lane,
-                    window=arch.window, group=group)
-                if ring["total"] > ring["grid"]:
-                    raise RuntimeError(
-                        f"the plan makes {ring['total']} window work "
-                        f"items, the kernel's grid holds {ring['grid']}")
-                lists.append((window_calls, ring))
-            work["full_kv_bytes"] = (full_calls * work["page_fetches"]
-                                     * page_bytes)
-            work["window_kv_bytes"] = (window_calls * ring["page_fetches"]
-                                       * page_bytes)
-            work["kv_bytes"] = (work["full_kv_bytes"]
-                                + work["window_kv_bytes"])
-            work["lanes_past_window"] = int(
-                (lane_lens[:lane] > arch.window).sum()) \
-                if arch.window else 0
-            work["ssm_runs"] = len(plan.chunks) \
-                if c.hybrid.state_layers else 0
-            work["state_bytes"] = (2 * len(plan.chunks)
-                                   * c.hybrid.state_bytes)
-        if eng.dense_pages:
-            # what the selection does for the live lanes past dense_len,
-            # counted where the lanes are made: a lane at position t sees
-            # t // block + 1 blocks and selects min(topk, that) of them a
-            # key/value head, whatever the scores say. What the device
-            # MOVES for it does not depend on the lanes: every lane of
-            # the step's width scores its table's every stride and
-            # gathers min(topk, a table's blocks) blocks, read or not
-            sc = arch.sparse
-            past = positions[:lane][positions[:lane] >= sc.dense_len]
-            visible = past // sc.block_size + 1
-            heads = eng.kv_heads * len(arch.sparse_layers)
-            gathered = eng.mixed_width * heads * min(
-                sc.topk, c.pages_per_seq * ps // sc.block_size)
-            work.update(
-                sparse_lanes=len(past),
-                blocks_visible=int(visible.sum()) * heads,
-                blocks_selected=int(np.minimum(visible, sc.topk).sum())
-                * heads,
-                selected_kv_bytes=gathered * 2 * sc.block_size
-                * eng.kv_head_dim * c.kv_itemsize,
-                selector_bytes=eng.mixed_width * heads * c.pages_per_seq
-                * c.selector_dim * int(c.selector_dtype.itemsize))
-        # what the calls walk against the step's live work
-        # (LIVE_COUNTS): a call's grid is its list's own length, the
-        # live lanes' items and one a tile of the inactive ones
-        work.update(
-            grid_steps=sum(n * w["total"] for n, w in lists),
-            live_steps=sum(n * w["items"] for n, w in lists),
-            short_steps=sum(n * w["short_items"] for n, w in lists),
-            live_rows=sum(n * w["rows"] for n, w in lists),
-            lanes=rows, emitters=len(emitters) + len(spec_emitters))
+        # what the mixers will do for these lanes: the count is made
+        # where the lanes are made, and a plan that passes a grid's
+        # bound raises there (serve/mixers.py)
+        work = mixers.step_counts(
+            eng.geometry, cache.page_tables, positions, lane_slots,
+            lane_lens, live_lanes=lane, runs=len(plan.chunks),
+            head_rows=rows, emitters=len(emitters) + len(spec_emitters))
         return arrays, lane_adapters, lane, emitters, spec_emitters, work
 
     def _count_experts(self, ev: StepEvents, counts: np.ndarray,
@@ -3629,17 +3197,8 @@ class ServeSession:
         with timed(track, "pack"):
             (arrays, lane_adapters, lane, emitters, spec_emitters,
              work) = self._pack(plan)
-            ev.kv_bytes_read = work["kv_bytes"]
-            ev.attn_items, ev.attn_rows = work["items"], work["rows"]
-            counted = LIVE_COUNTS
-            if c.hybrid is not None:
-                ev.ssm_runs = work["ssm_runs"]
-                ev.lanes_past_window = work["lanes_past_window"]
-                counted += ("state_bytes", "window_kv_bytes",
-                            "full_kv_bytes")
-            if eng.dense_pages:
-                counted += SELECT_COUNTS
-            for key in counted:
+            counted = eng.geometry.counted
+            for key in mixers.EVENT_COUNTS + counted:
                 setattr(ev, key, work[key])
             self.attn_steps["live"] += ev.live_steps
             self.attn_steps["short"] += ev.short_steps

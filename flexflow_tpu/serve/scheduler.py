@@ -147,8 +147,8 @@ class Request:
     # landed in out_tokens yet (0 or 1: ServeSession keeps at most one
     # step in flight ahead of the host). Counted in context_len, so the
     # next plan gives the request its decode lane; the token's value is
-    # fed on the device (engine._mixed_body). Zero again once the step
-    # lands or the request leaves the running set.
+    # fed on the device (ServeEngine._mixed_body, scope `embed`). Zero
+    # again once the step lands or the request leaves the running set.
     inflight: int = 0
     # adaptive draft-length state (speculative decoding); None when the
     # request is ineligible (non-deterministic sampling) or spec is off

@@ -1,7 +1,7 @@
 """Block-sparse attention over a learned selection for the lanes of a
 serving step, through the page pool (ops/sparse_attention.py holds the
 equations and, on plain arrays, the selection's one definition;
-serve/engine.py::_sparse_layer calls these two).
+the SPARSE body of serve/mixers.py calls these two).
 
 A selecting model's pool keeps each key/value head's pages as a POOL
 LAYER of their own (serve/kv_cache.KVCacheConfig.split_heads; pool

@@ -256,7 +256,7 @@ def test_a_depth_of_two_periods_indexes_two_full_layers():
         shared_experts=SHARED, experts_held=HELD)
     lm.compile(comp_mode=CompMode.INFERENCE)
     eng = ServeEngine(lm, interpret=True)
-    assert eng.arch.full_layers == [1, 3] and eng.arch.attn_calls() == (2, 2)
+    assert eng.arch.full_layers == [1, 3] and eng.geometry.attn_calls == (2, 2)
     assert eng.cache_cfg.num_layers == 2
     conf = dict(CONF, layer_types=[SLIDING, GLOBAL] * 2,
                 max_position_embeddings=64)

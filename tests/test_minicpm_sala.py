@@ -285,7 +285,7 @@ def test_describe_reads_the_fifth_shape(engine):
     assert arch.kinds == [SPARSE, LINEAR, LINEAR, SPARSE]
     # the model's geometry; a paged call a key/value head
     assert (arch.kv_heads, arch.paged_layers) == (KV_HEADS, 2)
-    assert arch.attn_calls() == (2 * KV_HEADS, 0)
+    assert engine.geometry.attn_calls == (2 * KV_HEADS, 0)
     assert arch.selector_dim == HEAD_DIM
     assert arch.hybrid_spec(24) == HybridSpec(
         window_layers=0, window=0, chunk=24, state_layers=2,

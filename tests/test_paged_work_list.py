@@ -342,7 +342,7 @@ def test_no_plan_passes_the_engine_s_bound():
 
 # ------------------------- the step's fixed shape against its live work
 def _count_engine(kind, **kw):
-    """A small engine of each kind of `arch.attn_calls`: every layer on
+    """A small engine of each kind of `geometry.attn_calls`: every layer on
     the one list, or a hybrid model whose window layers walk a second
     one (speculation off: an emitter is one lane)."""
     from flexflow_tpu.config import CompMode, FFConfig
@@ -368,21 +368,22 @@ def _count_engine(kind, **kw):
 @pytest.mark.parametrize("kind", ["opt", "hybrid"])
 def test_every_step_counts_its_fixed_shape_against_its_live_work(
         kind, monkeypatch):
-    """`_pack` walks each list once (as before the counts existed) and
-    sums what `work_items` answered over the calls that walk it; the
-    `dispatch` span carries the same numbers."""
+    """`_pack` has each list walked once (mixers.step_counts, as before
+    the counts existed) and what `work_items` answered summed over the
+    calls that walk it; the `dispatch` span carries the same numbers."""
     from flexflow_tpu.serve import engine as E
+    from flexflow_tpu.serve import mixers as M
     from flexflow_tpu.utils.telemetry import Telemetry
 
     tel = Telemetry()
     eng = _count_engine(kind, use_pallas=False, telemetry=tel)
-    full_calls, window_calls = eng.arch.attn_calls()
+    full_calls, window_calls = eng.geometry.attn_calls
     assert (full_calls, window_calls) == \
         ((3, 0) if kind == "opt" else (2, 2))       # 1 full + 1 cross
     walked = []
-    real = E.work_items
+    real = M.work_items
     monkeypatch.setattr(
-        E, "work_items",
+        M, "work_items",
         lambda *a, **kw: walked.append(real(*a, **kw)) or walked[-1])
     rng = np.random.RandomState(3)
     steps, packed = [], []

@@ -223,7 +223,7 @@ def test_no_slot_holds_window_keys_behind_the_ring_and_invariants_hold(
             ring = work_items(
                 np.asarray([ch.end for ch in ev.plan.chunks]),
                 np.asarray([ch.req.slot for ch in ev.plan.chunks]),
-                session._ring_tables, page_size=PAGE,
+                engine.geometry.rings, page_size=PAGE,
                 block_kv_pages=engine.attn_block_pages, window=WINDOW)
             assert ring["page_fetches"] <= len(ev.plan.chunks) * (
                 c.ring_pages + engine.attn_block_pages)

@@ -46,7 +46,8 @@ def _inputs(seed, t=T, d=D):
 def _lanes(runs, t=T):
     """runs: (slot, first position, lanes) one after another from lane
     0; the lanes behind them are dead (slot 0, position 0, as _pack
-    leaves them). -> the lane arrays as `_hybrid_lanes` makes them."""
+    leaves them). -> the lane arrays as serve/mixers.py
+    `step_lanes` makes them."""
     slots, pos = np.zeros(t, np.int32), np.zeros(t, np.int32)
     n = 0
     for slot, p0, k in runs:
