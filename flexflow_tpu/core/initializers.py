@@ -62,8 +62,32 @@ def make_uniform(minv: float, maxv: float, seed: int = 0):
     return init
 
 
-def make_normal(mean: float = 0.0, stddev: float = 1.0, seed: int = 0):
+def make_signed_uniform(lo: float, hi: float):
+    """Magnitudes uniform in [lo, hi], each with a random sign: values
+    AWAY from zero on both sides of it."""
     def init(key, shape, dtype=jnp.float32):
+        k1, k2 = jax.random.split(key)
+        sign = jnp.where(jax.random.bernoulli(k1, 0.5, shape), 1.0, -1.0)
+        return (sign * jax.random.uniform(k2, shape, jnp.float32, lo, hi)
+                ).astype(dtype)
+    return init
+
+
+def range_init(spec):
+    """(lo, hi) -> uniform in it; (lo, hi, "signed") -> magnitudes in
+    it with a random sign: how a configuration states where a scale
+    starts."""
+    lo, hi, *kind = spec
+    if kind and kind[0] != "signed":
+        raise ValueError(f"a range is (lo, hi) or (lo, hi, 'signed'), "
+                         f"not {spec!r}")
+    return (make_signed_uniform if kind else make_uniform)(
+        float(lo), float(hi))
+
+
+def make_normal(mean: float = 0.0, stddev: float = 1.0, seed: int = 0):
+    def init(key, shape, dtype=jnp.float32, **_fans):
+        # a deviation of its own: a spec's fan_in / fan_out do not move it
         return mean + stddev * jax.random.normal(key, shape, dtype)
     return init
 

@@ -185,10 +185,14 @@ class FFModel:
         return self.add_op(op).output
 
     def rms_norm(self, input: Tensor, eps: float = 1e-5,
-                 name: Optional[str] = None) -> Tensor:
+                 name: Optional[str] = None, zero_centered: bool = False,
+                 scale_init=None) -> Tensor:
+        """`zero_centered`: scale 1 + w; `scale_init` (lo, hi): w starts
+        uniform in it (ops/elementwise.RMSNorm)."""
         from .ops import RMSNorm
         op = RMSNorm(self, name or self._fresh_name("rms_norm"), [input],
-                     eps)
+                     eps, zero_centered=zero_centered,
+                     scale_init=scale_init)
         return self.add_op(op).output
 
     def reduce_mean(self, input: Tensor, axis: int, keepdims: bool = False,
@@ -377,21 +381,24 @@ class FFModel:
                 aux_loss_weight: float = 1e-2,
                 name: Optional[str] = None, norm_topk: bool = True,
                 dropless: bool = False, score: str = "softmax",
-                shared_experts: int = 0, experts_held=None) -> Tensor:
+                shared_experts: int = 0, experts_held=None,
+                shared_gate: bool = False,
+                kernel_initializer="glorot") -> Tensor:
         """Fused expert-parallel MoE FFN (TPU-first EP; the composable
         reference path softmax+topk+group_by+aggregate also exists).
         `dropless`: bias-free gated experts, (act(x wg) * (x wu)) wd,
         and every token reaches all its k experts whatever the load (no
         capacity); `norm_topk=False` keeps the k router probabilities
         as they are. A dropless layer's `score` ("softmax" |
-        "sigmoid"), `shared_experts` and `experts_held` (first, count):
-        ops/moe_ffn.py."""
+        "sigmoid"), `shared_experts`, `shared_gate` and `experts_held`
+        (first, count): ops/moe_ffn.py."""
         op = MoEFFN(self, name or self._fresh_name("moe_ffn"), [input],
                     num_experts, k, hidden_dim, out_dim, capacity_factor,
-                    activation, aux_loss_weight, norm_topk=norm_topk,
-                    dropless=dropless, score=score,
+                    activation, aux_loss_weight,
+                    kernel_initializer=kernel_initializer,
+                    norm_topk=norm_topk, dropless=dropless, score=score,
                     shared_experts=shared_experts,
-                    experts_held=experts_held)
+                    experts_held=experts_held, shared_gate=shared_gate)
         return self.add_op(op).output
 
 
@@ -423,6 +430,35 @@ class FFModel:
             self, name or self._fresh_name("linear"), [input, positions],
             num_heads, head_dim, layer_index, published_layers,
             rotary_theta, eps)
+        return self.add_op(op).output
+
+    def gated_delta_net(self, input: Tensor, key_heads: int,
+                        value_heads: int, key_dim: int, value_dim: int,
+                        d_conv: int = 4, eps: float = 1e-6,
+                        dt_range=(1e-3, 1e-1), norm_init=(1.0, 1.0),
+                        kernel_initializer="glorot",
+                        name: Optional[str] = None) -> Tensor:
+        """The gated delta rule's mixer (ops/gated_delta.py)."""
+        from .ops.gated_delta import GatedDeltaNet
+        op = GatedDeltaNet(
+            self, name or self._fresh_name("delta"), [input], key_heads,
+            value_heads, key_dim, value_dim, d_conv, eps, dt_range,
+            norm_init, kernel_initializer)
+        return self.add_op(op).output
+
+    def gated_attention(self, input: Tensor, positions: Tensor,
+                        num_heads: int, num_kv_heads: int, head_dim: int,
+                        rotary_theta: float = 1e7, rotary_dim: int = 0,
+                        eps: float = 1e-6, qk_norm_init=(0.0, 0.0),
+                        kernel_initializer="glorot",
+                        name: Optional[str] = None) -> Tensor:
+        """Gated softmax attention (ops/gated_attention.py)."""
+        from .ops.gated_attention import GatedAttention
+        op = GatedAttention(
+            self, name or self._fresh_name("gated_attn"),
+            [input, positions], num_heads, num_kv_heads, head_dim,
+            rotary_theta, rotary_dim, eps, qk_norm_init,
+            kernel_initializer)
         return self.add_op(op).output
 
     def sparse_attention(self, input: Tensor, num_heads: int,

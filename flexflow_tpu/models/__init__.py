@@ -13,6 +13,7 @@ from .cmdaplus import build_cmdaplus_lm
 from .minicpm_sala import build_minicpm_sala_lm
 from .olmoe import build_olmoe_lm
 from .phi4flash import build_phi4flash_lm
+from .qwen3_next import build_qwen3_next_lm
 
 __all__ = [
     "build_alexnet",
@@ -27,6 +28,7 @@ __all__ = [
     "build_minicpm_sala_lm",
     "build_olmoe_lm",
     "build_phi4flash_lm",
+    "build_qwen3_next_lm",
     "build_candle_uno",
     "build_nmt_lstm",
     "build_nmt_seq2seq",

@@ -23,6 +23,8 @@ from .moe import GroupBy, Aggregate
 from .moe_ffn import MoEFFN
 from .ssm import SelectiveScanMixer
 from .linear_attention import LightningAttention
+from .gated_delta import GatedDeltaNet
+from .gated_attention import GatedAttention
 from .sparse_attention import SparseAttention
 from .gated import GatedFFN, GatedMemoryUnit, TiedHead
 from .diff_attention import DifferentialAttention
@@ -57,6 +59,8 @@ __all__ = [
     "MoEFFN",
     "SelectiveScanMixer",
     "LightningAttention",
+    "GatedDeltaNet",
+    "GatedAttention",
     "SparseAttention",
     "GatedFFN",
     "GatedMemoryUnit",

@@ -303,26 +303,43 @@ class LayerNorm(PassthroughAxesMixin, Op):
 class RMSNorm(PassthroughAxesMixin, Op):
     """x * rsqrt(mean(x^2) + eps) * scale over the LAST dim: no mean
     subtracted, no bias (the modern decoder block's norm). Statistics
-    in f32 regardless of activation dtype, like LayerNorm here."""
+    in f32 regardless of activation dtype, like LayerNorm here.
+    `zero_centered`: the scale is 1 + w (Qwen3-Next's norm, whose w
+    starts at 0); `scale_init` (lo, hi): w starts uniform in it — or,
+    (lo, hi, "signed"), with magnitudes in it and a random sign."""
 
     op_type = "rms_norm"
 
-    def __init__(self, model, name, inputs, eps: float = 1e-5):
+    def __init__(self, model, name, inputs, eps: float = 1e-5,
+                 zero_centered: bool = False, scale_init=None):
         super().__init__(model, name, inputs)
         self.eps = float(eps)
+        self.zero_centered = bool(zero_centered)
+        self.scale_init = None if scale_init is None else tuple(scale_init)
         self.num_channels = inputs[0].shape[-1]
         self.attrs = {"eps": eps}
+        if self.zero_centered:
+            self.attrs["zero_centered"] = True
 
     def output_shapes(self):
         return [tuple(self.inputs[0].shape)]
 
     def weight_specs(self):
-        return {"scale": WeightSpec((self.num_channels,),
-                                    initializer="ones", axes=(CHANNEL,))}
+        if self.scale_init is not None:
+            from ..core.initializers import range_init
+            return {"scale": WeightSpec(
+                (self.num_channels,), axes=(CHANNEL,),
+                custom_init=range_init(self.scale_init))}
+        return {"scale": WeightSpec(
+            (self.num_channels,), axes=(CHANNEL,),
+            initializer="zeros" if self.zero_centered else "ones")}
 
     def forward(self, params, xs, ctx: OpContext):
         (x,) = xs
-        return [rms_norm(x, params["scale"], self.eps)]
+        w = params["scale"]
+        if self.zero_centered:
+            w = 1.0 + w.astype(jnp.float32)
+        return [rms_norm(x, w, self.eps)]
 
     def flops(self) -> float:
         return 4.0 * self.inputs[0].num_elements

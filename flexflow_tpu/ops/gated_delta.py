@@ -1,0 +1,421 @@
+"""The gated delta rule (Gated DeltaNet, arXiv:2412.06464; the
+`linear_attention` layers of Qwen3-Next, `model_type` qwen3_next): a
+matrix state a value head that every token DECAYS, then CORRECTS along
+its key before it adds to it.
+
+Per token t of a sequence, h (E), Hk key heads of Dk, Hv = r Hk value
+heads of Dv:
+  [q | k | v | z] = h W_qkvz, laid out a KEY head at a time as
+      [q Dk | k Dk | v r Dv | z r Dv];  [b | a] = h W_ba, [b r | a r]
+      a key head;
+  [q | k | v] (all heads' q, then k, then v: 2 Hk Dk + Hv Dv channels)
+      through a causal depthwise convolution of `d_conv` taps, no bias,
+      then silu;
+  q, k L2-normalised over their Dk dims (x / sqrt(sum x^2 + 1e-6)),
+      each key head serving its r value heads, q <- q / sqrt(Dk);
+  beta = sigmoid(b),  g = -exp(A_log) * softplus(a + dt_bias)  (a value
+      head, f32; g <= 0);
+  S <- exp(g_t) S;  S <- S + k_t (beta_t (v_t - S^T k_t))^T;
+  o_t = S^T q_t                            (S is Dk x Dv a head, f32);
+  out = (RMSNorm_Dv(o; w) * silu(z)) W_o   (the norm's scale is w, not
+      1 + w).
+The state, the recurrence, the convolution and the norm run in f32
+whatever the activation dtype.
+
+Three forms of the recurrence, the same numbers up to f32 rounding:
+`recurrent` (a token a trip: the definition), `chunked` (whole sequences
+from a zero state, CHUNK tokens a trip, the WY form: with G the running
+sum of g inside the chunk and D_ij = exp(G_i - G_j) for i >= j,
+  T = (I + tril(beta K K^T * D, -1))^-1,  W = T (beta K * exp(G)),
+  U = T (beta V),  V' = U - W S,
+  O = (Q * exp(G)) S + tril(Q K^T * D) V',
+  S <- exp(G_C) S + (K * exp(G_C - G))^T V';
+the graph op's forward) and `segmented` (the LANES of a serving step:
+runs of consecutive lanes of one sequence, each resuming from its
+slot's state, serve/mixers.py). Every decay is the exp of a non-positive
+sum of g, never a quotient of two of them.
+
+The state is laid out (Hv * Dk, Dv): a value head's Dk x Dv matrix after
+another's, the value dimension on the lanes — the layout a batched
+product over heads and a token's rank-one update both take as it lies
+(with the key dimension leading, XLA re-laid the whole slab of every
+layer out for each block of lanes: PERF.md section 6, PR 49).
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+from ..op import CHANNEL_IN, CHANNEL_OUT, SAMPLE, SEQ, Op, OpContext, \
+    WeightSpec, register_op
+from .ssm import causal_conv
+
+F32 = jnp.float32
+_HI = jax.lax.Precision.HIGHEST
+CHUNK = 64          # tokens a trip of the chunk form; a power of two
+# the fewest live lanes of ONE run for which a block of the serving step
+# takes the chunk form: under it the lanes go one after another, which
+# costs a lane what the chunk form costs a sixteenth of a block
+CHUNK_MIN_LANES = 16
+L2_EPS = 1e-6
+
+
+# ------------------------------------------------------------ the layer
+def project(p, h, key_heads: int, ratio: int, dk: int, dv: int):
+    """h (..., E) -> (u (..., 2 Hk Dk + Hv Dv) the convolution's raw
+    input in its channel order [q | k | v], z (..., Hv, Dv), b, a
+    (..., Hv)), all in h's dtype."""
+    lead = h.shape[:-1]
+    mm = lambda w: jnp.dot(h, p[w].astype(h.dtype),
+                           preferred_element_type=F32).astype(h.dtype)
+    qkvz = mm("w_qkvz").reshape(lead + (key_heads, -1))
+    q, k, v, z = jnp.split(
+        qkvz, [dk, 2 * dk, 2 * dk + ratio * dv], axis=-1)
+    ba = mm("w_ba").reshape(lead + (key_heads, 2 * ratio))
+    flat = lambda a: a.reshape(lead + (-1,))
+    u = jnp.concatenate([flat(q), flat(k), flat(v)], axis=-1)
+    hv = key_heads * ratio
+    return (u, z.reshape(lead + (hv, dv)),
+            ba[..., :ratio].reshape(lead + (hv,)),
+            ba[..., ratio:].reshape(lead + (hv,)))
+
+
+def gates(p, b, a):
+    """-> (beta, g) (..., Hv) f32."""
+    beta = jax.nn.sigmoid(b.astype(F32))
+    g = -jnp.exp(p["A_log"].astype(F32)) * jax.nn.softplus(
+        a.astype(F32) + p["dt_bias"].astype(F32))
+    return beta, g
+
+
+def split_heads(u, key_heads: int, ratio: int, dk: int, dv: int):
+    """u (..., channels) f32 after the convolution and silu -> q, k
+    (..., Hv, Dk) L2-normalised, q over sqrt(Dk), a key head repeated
+    for its `ratio` value heads, and v (..., Hv, Dv), f32."""
+    lead = u.shape[:-1]
+    q, k, v = jnp.split(u, [key_heads * dk, 2 * key_heads * dk], axis=-1)
+
+    def unit(x):
+        x = x.reshape(lead + (key_heads, dk))
+        x = x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True)
+                              + L2_EPS)
+        return jnp.repeat(x, ratio, axis=-2)
+
+    return (unit(q) * (1.0 / math.sqrt(dk)), unit(k),
+            v.reshape(lead + (key_heads * ratio, dv)))
+
+
+def gate_and_project(p, o, z, eps: float):
+    """o (..., Hv, Dv) f32, z (..., Hv, Dv) -> (RMSNorm_Dv(o; w) *
+    silu(z)) W_o in z's dtype."""
+    var = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+    y = o * jax.lax.rsqrt(var + eps) * p["o_norm"].astype(F32)
+    y = (y * jax.nn.silu(z.astype(F32))).astype(z.dtype)
+    y = y.reshape(y.shape[:-2] + (-1,))
+    return jnp.dot(y, p["wo"].astype(z.dtype),
+                   preferred_element_type=F32).astype(z.dtype)
+
+
+# ------------------------------------------------------ the recurrence
+def _token(s, q, k, v, g, beta):
+    """One token on a state s (H, Dk, Dv): q, k (H, Dk), v (H, Dv), g,
+    beta (H,), all f32 -> (s', o (H, Dv))."""
+    kc = k[:, :, None]                                     # (H, Dk, 1)
+    s = s * jnp.exp(g)[:, None, None]
+    u = beta[:, None] * (v - jnp.sum(kc * s, axis=1))
+    s = s + kc * u[:, None, :]
+    return s, jnp.sum(q[:, :, None] * s, axis=1)
+
+
+def recurrent(q, k, v, g, beta, state=None):
+    """The definition, a token a trip: q, k (S, H, Dk), v (S, H, Dv),
+    g, beta (S, H) -> (o (S, H, Dv) f32, the state after (H, Dk, Dv)),
+    from `state` (None: zeros)."""
+    s0 = jnp.zeros((q.shape[1], q.shape[2], v.shape[2]), F32) \
+        if state is None else state
+
+    def step(s, x):
+        return _token(s, *(a.astype(F32) for a in x))
+
+    s, o = jax.lax.scan(step, s0, (q, k, v, g, beta))
+    return o, s
+
+
+def _unit_lower_inverse(m):
+    """(..., C, C) unit lower triangular, C a power of two -> its
+    inverse, by halves: [[A, 0], [B, D]]^-1 = [[A^-1, 0], [-D^-1 B A^-1,
+    D^-1]] from blocks of one up (block forward substitution: as stable
+    as a row at a time, in 2 log2(C) small products)."""
+    c = m.shape[-1]
+    lead = m.shape[:-2]
+    inv = jnp.broadcast_to(jnp.eye(c, dtype=m.dtype), m.shape)
+    s = 1
+    while s < c:
+        n = c // (2 * s)
+
+        def diagonal(x):                   # (..., n, 2s, 2s)
+            x = x.reshape(lead + (n, 2 * s, n, 2 * s))
+            return jnp.moveaxis(
+                jnp.diagonal(x, axis1=-4, axis2=-2), -1, -3)
+
+        mb, ib = diagonal(m), diagonal(inv)
+        low = -jnp.einsum("...ab,...bc,...cd->...ad", ib[..., s:, s:],
+                          mb[..., s:, :s], ib[..., :s, :s], precision=_HI)
+        ib = ib.at[..., s:, :s].set(low)
+        inv = jnp.einsum("...nab,nm->...namb", ib,
+                         jnp.eye(n, dtype=m.dtype)).reshape(m.shape)
+        s *= 2
+    return inv
+
+
+def _chunk(s, q, k, v, g, beta):
+    """CHUNK (or fewer) tokens of ONE sequence on a state s (H, Dk, Dv),
+    the WY form: q, k (C, H, Dk), v (C, H, Dv), g, beta (C, H), f32 ->
+    (s', o (C, H, Dv)). A token with beta 0 and g 0 changes nothing."""
+    c = q.shape[0]
+    cum = jnp.cumsum(g, axis=0)                              # (C, H) <= 0
+    i = jnp.arange(c)
+    low = (i[:, None] >= i[None, :])[None]                   # (1, C, C)
+    diff = cum.T[:, :, None] - cum.T[:, None, :]             # (H, C, C)
+    decay = jnp.where(low, jnp.exp(jnp.where(low, diff, 0.0)), 0.0)
+    kk = jnp.einsum("ihd,jhd->hij", k, k, precision=_HI)
+    a = jnp.where(i[:, None] > i[None, :], 1.0, 0.0)[None] * decay * kk \
+        * beta.T[:, :, None]
+    t = _unit_lower_inverse(a + jnp.eye(c, dtype=F32))
+    d_in = jnp.exp(cum)                                      # (C, H)
+    w = jnp.einsum("hij,jhd->ihd", t, k * (beta * d_in)[:, :, None],
+                   precision=_HI)
+    u = jnp.einsum("hij,jhd->ihd", t, v * beta[:, :, None], precision=_HI)
+    vn = u - jnp.einsum("ihk,hkv->ihv", w, s, precision=_HI)
+    qk = jnp.einsum("ihd,jhd->hij", q, k, precision=_HI) * decay
+    o = jnp.einsum("ihk,hkv->ihv", q * d_in[:, :, None], s, precision=_HI) \
+        + jnp.einsum("hij,jhv->ihv", qk, vn, precision=_HI)
+    d_out = jnp.exp(cum[-1][None] - cum)                     # (C, H)
+    s = jnp.exp(cum[-1])[:, None, None] * s + jnp.einsum(
+        "jhk,jhv->hkv", k * d_out[:, :, None], vn, precision=_HI)
+    return s, o
+
+
+def chunked(q, k, v, g, beta, chunk: int = CHUNK):
+    """Whole sequences from a zero state, `chunk` tokens a trip: q, k
+    (B, S, H, Dk), v (B, S, H, Dv), g, beta (B, S, H) -> o (B, S, H, Dv)
+    f32, equal to the recurrence."""
+    b, s, h, dk = q.shape
+    pad = -s % chunk
+    n = (s + pad) // chunk
+
+    def blocks(a):
+        # padding tokens: beta 0 and g 0, they change nothing
+        a = jnp.pad(a.astype(F32), ((0, 0), (0, pad)) + ((0, 0),) * (
+            a.ndim - 2))
+        return a.reshape((b, n, chunk) + a.shape[2:]).swapaxes(0, 1)
+
+    def trip(state, x):
+        return jax.vmap(_chunk)(state, *x)
+
+    s0 = jnp.zeros((b, h, dk, v.shape[-1]), F32)
+    _, o = jax.lax.scan(trip, s0, tuple(map(blocks, (q, k, v, g, beta))))
+    return o.swapaxes(0, 1).reshape(b, s + pad, h, -1)[:, :s]
+
+
+def segmented(q, k, v, g, beta, state, lane_slots, positions, live,
+              starts, wslots, live_lanes, layer=None, block: int = CHUNK):
+    """The recurrence over the step's lanes. q, k (T, H, Dk), v (T, H,
+    Dv), g, beta (T, H), f32; state (slots + 1, H * Dk, Dv) f32, every
+    slot's matrix state (the last row the write sink) — or, with
+    `layer`, the slab of all the layers' (layers, slots + 1, H * Dk,
+    Dv), of which this call reads and writes row `layer` in place;
+    `live` (T,) the
+    lanes that hold a token, `live_lanes` how many from lane 0 up hold
+    one; `starts` / `wslots` the runs (ops/ssm.run_starts /
+    run_write_slots). A run resumes from its slot's state — from zero
+    where the sequence starts inside it — and leaves the state after its
+    last live lane in its slot.
+
+    The lanes go by in BLOCKS of `block`, one after another, the state
+    of the run that crosses a block's edge carried. A block whose live
+    lanes are CHUNK_MIN_LANES or more of ONE run takes the chunk form on
+    that run's one state; any other block's live lanes go a lane at a
+    time, each run on its own slot's state; a block with no live lane
+    does nothing. So a step touches a state once a run (or once a block
+    of a long run): the work grows with the lanes and the runs, never
+    with lanes x slots. -> (o (T, H, Dv) f32, state)."""
+    t, h, dk = q.shape
+    dv = v.shape[-1]
+    pad = -t % block
+    n = (t + pad) // block
+    whole = state if layer is not None else state[None]
+    at = layer or 0
+    sink = whole.shape[1] - 1
+    slab = whole.reshape(whole.shape[:2] + (h, dk, dv))
+
+    def blocks(a, fill=0):
+        a = jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1),
+                    constant_values=fill)
+        return a.reshape((n, block) + a.shape[1:])
+
+    lane = jnp.arange(block)
+
+    def resume(slab, s, start, slot, pos):
+        """The state a lane works on: its slot's where the lane starts a
+        run (zeros where the sequence starts there), else the carried."""
+        own = jnp.where(pos > 0, slab[at, slot], 0.0)
+        return jnp.where(start, own, s)
+
+    def trip(carry, x):
+        s, slab = carry
+        first, qb, kb, vb, gb, bb, slots, pos, alive, begins, wb = x
+        count = jnp.clip(live_lanes - first, 0, block)      # live lanes
+        among = (lane < count) & alive
+        one_run = ~jnp.any(begins & among & (lane > 0))
+        as_chunk = one_run & (count >= CHUNK_MIN_LANES)
+
+        def a_lane(j, c):
+            s, slab, o = c
+            s = resume(slab, s, begins[j], slots[j], pos[j])
+            s, oj = _token(s, qb[j], kb[j], vb[j], gb[j], bb[j])
+            return (s, slab.at[at, wb[j]].set(s),
+                    jax.lax.dynamic_update_index_in_dim(o, oj, j, 0))
+
+        s, slab, o_lanes = jax.lax.fori_loop(
+            0, jnp.where(as_chunk, 0, count), a_lane,
+            (s, slab, jnp.zeros((block, h, dv), F32)))
+
+        def the_chunk(s_in):
+            m = among[:, None]
+            return _chunk(s_in, qb, kb, vb, jnp.where(m, gb, 0.0),
+                          jnp.where(m, bb, 0.0))
+
+        s_in = resume(slab, s, begins[0], slots[0], pos[0])
+        s_out, o_chunk = jax.lax.cond(
+            as_chunk, the_chunk,
+            lambda s_in: (s_in, jnp.zeros((block, h, dv), F32)), s_in)
+        s = jnp.where(as_chunk, s_out, s)
+        last = jnp.maximum(count - 1, 0)
+        slab = slab.at[at, jnp.where(as_chunk, wb[last], sink)].set(s)
+        return (s, slab), jnp.where(as_chunk, o_chunk, o_lanes)
+
+    xs = (jnp.arange(n, dtype=jnp.int32) * block, blocks(q), blocks(k),
+          blocks(v), blocks(g), blocks(beta), blocks(lane_slots),
+          blocks(positions), blocks(live, False), blocks(starts, True),
+          blocks(wslots, sink))
+    (_, slab), o = jax.lax.scan(
+        trip, (jnp.zeros((h, dk, dv), F32), slab), xs)
+    whole = slab.reshape(whole.shape)
+    return (o.reshape(n * block, h, dv)[:t],
+            whole if layer is not None else whole[0])
+
+
+# ----------------------------------------------------------------- the op
+def a_log_init(key, shape, dtype=F32):
+    """A = exp(A_log) uniform in (0, 16): the layer's published start."""
+    return jnp.log(jax.random.uniform(key, shape, F32, 1e-3, 16.0)
+                   ).astype(dtype)
+
+
+def make_dt_bias_init(dt_min: float, dt_max: float):
+    """softplus^-1 of a step drawn log-uniformly in [dt_min, dt_max]."""
+    def init(key, shape, dtype=F32):
+        dt = jnp.exp(jax.random.uniform(key, shape, F32)
+                     * (math.log(dt_max) - math.log(dt_min))
+                     + math.log(dt_min))
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+    return init
+
+
+@register_op
+class GatedDeltaNet(Op):
+    """x (B, S, E) -> out (B, S, E): the whole mixer (projections,
+    convolution, gates, the delta rule, output norm, gate and
+    projection). `dt_range`: the steps dt_bias starts at (softplus^-1,
+    log-uniform); `norm_init` (lo, hi): the output norm's scale starts
+    uniform in it."""
+
+    op_type = "gated_delta_net"
+
+    def __init__(self, model, name, inputs, key_heads: int,
+                 value_heads: int, key_dim: int, value_dim: int,
+                 d_conv: int = 4, eps: float = 1e-6,
+                 dt_range=(1e-3, 1e-1), norm_init=(1.0, 1.0),
+                 kernel_initializer="glorot"):
+        super().__init__(model, name, inputs)
+        self.embed_dim = int(inputs[0].shape[-1])
+        self.key_heads, self.value_heads = int(key_heads), int(value_heads)
+        if self.value_heads % self.key_heads:
+            raise ValueError(f"{name}: {value_heads} value heads do not "
+                             f"divide over {key_heads} key heads")
+        self.ratio = self.value_heads // self.key_heads
+        self.key_dim, self.value_dim = int(key_dim), int(value_dim)
+        self.d_conv, self.eps = int(d_conv), float(eps)
+        self.dt_range = tuple(map(float, dt_range))
+        self.norm_init = tuple(norm_init)
+        self.kernel_initializer = kernel_initializer
+        self.attrs = {"key_heads": self.key_heads,
+                      "value_heads": self.value_heads,
+                      "key_dim": self.key_dim, "value_dim": self.value_dim,
+                      "d_conv": self.d_conv}
+
+    @property
+    def channels(self) -> int:
+        """What the convolution runs over: all heads' q, k and v."""
+        return 2 * self.key_heads * self.key_dim \
+            + self.value_heads * self.value_dim
+
+    @property
+    def shape_args(self) -> tuple:
+        return (self.key_heads, self.ratio, self.key_dim, self.value_dim)
+
+    def output_shapes(self):
+        return [tuple(self.inputs[0].shape)]
+
+    def output_dtypes(self):
+        return [self.inputs[0].dtype]
+
+    def weight_specs(self):
+        from ..core.initializers import range_init
+        e, hv = self.embed_dim, self.value_heads
+        inner = hv * self.value_dim
+        init = self.kernel_initializer
+        mat = lambda i, o: WeightSpec((i, o), initializer=init,
+                                      axes=(CHANNEL_IN, CHANNEL_OUT))
+        return {
+            "w_qkvz": mat(e, self.channels + inner),
+            "w_ba": mat(e, 2 * hv),
+            # glorot over the taps whatever the matrices start at
+            "conv_w": WeightSpec((self.d_conv, self.channels),
+                                 fan_in=self.d_conv, fan_out=self.d_conv),
+            "A_log": WeightSpec((hv,), custom_init=a_log_init),
+            "dt_bias": WeightSpec((hv,), custom_init=make_dt_bias_init(
+                *self.dt_range)),
+            "o_norm": WeightSpec((self.value_dim,),
+                                 custom_init=range_init(self.norm_init)),
+            "wo": mat(inner, e),
+        }
+
+    def forward(self, params, xs, ctx: OpContext):
+        (x,) = xs
+        u, z, b, a = project(params, x, *self.shape_args)
+        u = jax.nn.silu(causal_conv(params, u))
+        q, k, v = split_heads(u, *self.shape_args)
+        beta, g = gates(params, b, a)
+        o = chunked(q, k, v, g, beta)
+        return [gate_and_project(params, o, z, self.eps)]
+
+    def output_axes(self):
+        return [(SAMPLE, SEQ, None)]
+
+    def input_axes(self):
+        return [(SAMPLE, SEQ, None)]
+
+    def flops(self) -> float:
+        n_tok = 1
+        for s in self.inputs[0].shape[:-1]:
+            n_tok *= s
+        e, hv = self.embed_dim, self.value_heads
+        inner = hv * self.value_dim
+        proj = 2.0 * e * (self.channels + 2 * inner + 2 * hv)
+        rule = 6.0 * hv * self.key_dim * self.value_dim
+        return n_tok * (proj + 2.0 * self.d_conv * self.channels + rule)

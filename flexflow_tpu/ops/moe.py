@@ -192,6 +192,13 @@ def shared_ffn(tokens: jax.Array, wg, wu, wd, activation,
     return mm(h, wd) * (1.0 / n_shared)
 
 
+def shared_scale(tokens: jax.Array, w) -> jax.Array:
+    """sigmoid(x w), w (D, 1): the scalar a token that scales what the
+    shared experts give it. -> (N, 1) f32."""
+    return jax.nn.sigmoid(jnp.dot(tokens, w.astype(tokens.dtype),
+                                  preferred_element_type=jnp.float32))
+
+
 def ragged_ffn(rows: jax.Array, counts: jax.Array, wg, wu, wd,
                activation) -> jax.Array:
     """`grouped_ffn` as three grouped matmuls (`jax.lax.ragged_dot`,

@@ -20,7 +20,9 @@ unnormalised. OLMoE's layer is both (models/olmoe.py).
 A dropless layer also takes (models/cmdaplus.py uses all three):
 `score="sigmoid"`, the router's scoring function; `shared_experts=n`,
 n gated experts of the same width that every token passes, their mean
-added to the routed sum (`ops/moe.py::shared_ffn`); and
+added to the routed sum (`ops/moe.py::shared_ffn`), times
+`sigmoid(x w_sg)`, a scalar a token, under `shared_gate` (Qwen3-Next's
+shared expert); and
 `experts_held=(first, count)`, ONE SHARE of an expert-parallel layer —
 the router keeps its `num_experts` outputs and its k a token, only
 `count` experts' weights exist here, a slot of an absent expert adds
@@ -43,6 +45,7 @@ from .moe import (
     grouped_ffn,
     route_top_k,
     shared_ffn,
+    shared_scale,
     sorted_combine,
     sorted_dispatch,
     use_sorted_dispatch,
@@ -64,12 +67,16 @@ class MoEFFN(Op):
                  kernel_initializer: str = "glorot",
                  norm_topk: bool = True, dropless: bool = False,
                  score: str = "softmax", shared_experts: int = 0,
-                 experts_held=None):
+                 experts_held=None, shared_gate: bool = False):
         super().__init__(model, name, inputs)
         self.norm_topk = bool(norm_topk)
         self.dropless = bool(dropless)
         self.score = str(score)
         self.shared_experts = int(shared_experts)
+        self.shared_gate = bool(shared_gate)
+        if self.shared_gate and not self.shared_experts:
+            raise ValueError(f"{name}: shared_gate gates the shared "
+                             f"experts (shared_experts > 0)")
         self.experts_held = None if experts_held is None \
             else (int(experts_held[0]), int(experts_held[1]))
         if not self.dropless and (self.score != "softmax"
@@ -107,6 +114,8 @@ class MoEFFN(Op):
             self.attrs.update(score=self.score,
                               shared_experts=self.shared_experts,
                               experts_held=self.experts_held)
+        if self.shared_gate:
+            self.attrs["shared_gate"] = True
 
     def output_shapes(self):
         return [tuple(self.inputs[0].shape[:-1]) + (self.out_dim,)]
@@ -133,6 +142,8 @@ class MoEFFN(Op):
                 n = self.shared_experts * h
                 specs.update(sg=sw((d, n), d, h), su=sw((d, n), d, h),
                              sd=sw((n, o), h, o))
+                if self.shared_gate:
+                    specs["sgate"] = sw((d, 1), d, 1)
             return specs
         return {
             "gate": gate,
@@ -164,9 +175,12 @@ class MoEFFN(Op):
                              params["wd"], self.activation)
             out = dropless_combine(ys, order, gate_vals)
             if self.shared_experts:
-                out = out + shared_ffn(
+                shared = shared_ffn(
                     tokens, params["sg"], params["su"], params["sd"],
                     self.activation, self.shared_experts)
+                if self.shared_gate:
+                    shared = shared * shared_scale(tokens, params["sgate"])
+                out = out + shared
             self._aux_loss(ctx, assign, probs)
             return [out.astype(x.dtype).reshape(
                 orig_shape[:-1] + (self.out_dim,))]

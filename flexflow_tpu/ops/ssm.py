@@ -82,14 +82,15 @@ def selective_scan(p, u, dt, b, c):
 
 def causal_conv(p, u):
     """u (B, S, d_inner) -> the causal depthwise convolution over the
-    last d_conv positions (zeros before the sequence), f32."""
+    last d_conv positions (zeros before the sequence), f32; with the
+    bias `conv_b` where the layer has one."""
     w = p["conv_w"].astype(F32)                     # (d_conv, d_inner)
     k = w.shape[0]
     uf = u.astype(F32)
     pad = jnp.pad(uf, ((0, 0), (k - 1, 0), (0, 0)))
     s = u.shape[1]
     y = sum(pad[:, j:j + s] * w[j] for j in range(k))
-    return y + p["conv_b"].astype(F32)
+    return y + p["conv_b"].astype(F32) if "conv_b" in p else y
 
 
 # ------------------------------------------------- the serving step's scans
@@ -141,7 +142,9 @@ def segmented_conv(p, u, tail, lane_slots, positions, offsets, wslots):
         from_tail = jnp.take_along_axis(
             old, idx[:, None, None], axis=1)[:, 0]
         hist.append(jnp.where((offsets >= j)[:, None], shifted, from_tail))
-    y = u.astype(F32) * w[k - 1] + p["conv_b"].astype(F32)
+    y = u.astype(F32) * w[k - 1]
+    if "conv_b" in p:           # a convolution without bias has no leaf
+        y = y + p["conv_b"].astype(F32)
     for j, h in zip(range(k - 1, 0, -1), hist):
         y = y + h.astype(F32) * w[k - 1 - j]
     new = jnp.concatenate(hist[1:] + [u], axis=1).astype(tail.dtype)

@@ -11,7 +11,7 @@ description reads the parameters of a compiled FFModel through the op
 names its builder wrote, and mirrors those ops' numerics. This module
 imports neither the engine nor the scheduler.
 
-Five clients: `TransformerLM` (models/transformer.build_transformer_lm:
+Six clients: `TransformerLM` (models/transformer.build_transformer_lm:
 learned positions, LayerNorm, ReLU feed-forward — the OPT block),
 `OLMoE` (models/olmoe.build_olmoe_lm: RMSNorm, rotary attention with
 QK-norm, dropless top-k SwiGLU experts), `Phi4Flash`
@@ -22,12 +22,17 @@ or full attention beside sigmoid-routed experts, of which this chip
 holds a share, and averaged shared experts) and `MiniCPMSala`
 (models/minicpm_sala.build_minicpm_sala_lm: block-sparse attention over
 a learned selection of the context in some layers, lightning linear
-attention with a matrix state a sequence in the others).
+attention with a matrix state a sequence in the others) and `Qwen3Next`
+(models/qwen3_next.build_qwen3_next_lm: the gated delta rule with a
+matrix state and a convolution tail a sequence in three layers of four,
+gated softmax attention in the fourth, top-k experts of which this chip
+holds a share beside a gated shared expert).
 
 What a description answers (docs/serving.md "What a description must
 answer"): the dimensions; per layer the MIXER KIND (`mixer(i)`: one of
-the eight names serve/mixers.py has a body for — "attn",
-models/phi4flash's five, models/minicpm_sala's two) and the
+the nine names serve/mixers.py has a body for — "attn",
+models/phi4flash's five, models/minicpm_sala's two, models/qwen3_next's
+one) and the
 projections that kind's body calls; the geometry of the K/V it
 pages (`kv_heads`, `kv_head_dim`, `paged_layers`, `attn_scale`); what a
 sequence holds besides pages (`hybrid_spec`, a serve/kv_cache.HybridSpec
@@ -43,14 +48,17 @@ import jax.numpy as jnp
 
 from ..models.minicpm_sala import LINEAR, SPARSE
 from ..models.phi4flash import CROSS, FULL, GMU, SSM, WINDOW
+from ..models.qwen3_next import DELTA
 from ..ops import diff_attention as DA
+from ..ops import gated_attention as GA
+from ..ops import gated_delta as GD
 from ..ops import linear_attention as LA
 from ..ops import sparse_attention as SA
 from ..ops import ssm as S
 from ..ops.common import rms_norm, rotary
 from ..ops.gated import gated_ffn, gated_memory
 from ..ops.moe import (dropless_combine, dropless_dispatch, expert_impl,
-                       grouped_ffn, route_top_k, shared_ffn)
+                       grouped_ffn, route_top_k, shared_ffn, shared_scale)
 
 ATTN = "attn"       # the mixer kind of every layer of the plain decoders
 
@@ -116,6 +124,41 @@ def _graph_logits(model, params, tokens, positions: bool):
     return values[model.ops[-1].outputs[0].uid][0]
 
 
+def _held_expert_layer(arch, m, h, live):
+    """The expert layer of which this chip holds a share, up to its
+    combine, over `h` (N, E), four scopes: `router` (f32 scores over all
+    the experts, top-k, renormalised), `moe_dispatch` (slots sorted by
+    held expert; a slot of an absent expert routes nowhere, as a dead
+    lane's), `experts` (the gated expert over the held experts),
+    `shared_experts` (plain matmuls; times sigmoid(h w_sg) where the
+    layer has the gate `sgate`). -> (ys in expert order, order, the k
+    weights a token, the shared term or 0.0, (held + 1,) int32: live
+    slots per held expert, then the live slots whose expert is
+    absent)."""
+    scope = jax.named_scope
+    k = arch.experts_per_token
+    with scope("router"):
+        _, gate_vals, assign = route_top_k(
+            h, m["gate"], k, arch.norm_topk, arch.score)
+    with scope("moe_dispatch"):
+        rows, order, counts = dropless_dispatch(
+            h, assign, arch.experts, live, arch.experts_held)
+        slots = k * (h.shape[0] if live is None
+                     else jnp.sum(live, dtype=jnp.int32))
+        counts = jnp.concatenate(
+            [counts, (slots - jnp.sum(counts))[None]])
+    with scope("experts"):
+        ys = grouped_ffn(rows, counts[:-1], m["wg"], m["wu"], m["wd"],
+                         arch.activation, **arch.kernels)
+    with scope("shared_experts"):
+        f = shared_ffn(h, m["sg"], m["su"], m["sd"], arch.activation,
+                       arch.shared_experts) \
+            if arch.shared_experts else 0.0
+        if "sgate" in m:
+            f = f * shared_scale(h, m["sgate"])
+    return ys, order, gate_vals, f, counts
+
+
 class Description:
     """What every description answers the same way unless it says
     otherwise: one attention layer a layer, a key/value head a query
@@ -146,6 +189,10 @@ class Description:
     # differential attention: the paged call's output goes through
     # `diff_norm` before `attn_out` (the attention body of mixers.py)
     differential = False
+    # an output GATE born in the query projection: `qkv` returns it
+    # fourth, and the paged call's output goes through `attn_gate`
+    # before `attn_out` (the attention body of mixers.py)
+    output_gate = False
     # a PARALLEL block: one norm a layer, `attn_out` and `ffn` (which is
     # handed `h`, that norm's output) return their BRANCH alone and the
     # step adds x + (a + f) once (ServeEngine._mixed_layer). False: each
@@ -749,28 +796,9 @@ class CommandAPlus(Description):
         held experts), `shared_experts` (plain matmuls), `moe_combine`.
         -> (f, (held + 1,) int32: live slots per held expert, then the
         live slots whose expert is absent)."""
-        m = params[f"layer{i}_moe"]
-        scope = jax.named_scope
-        k = self.experts_per_token
-        h = h.reshape(-1, self.hidden)
-        with scope("router"):
-            _, gate_vals, assign = route_top_k(
-                h, m["gate"], k, self.norm_topk, self.score)
-        with scope("moe_dispatch"):
-            rows, order, counts = dropless_dispatch(
-                h, assign, self.experts, live, self.experts_held)
-            slots = k * (h.shape[0] if live is None
-                         else jnp.sum(live, dtype=jnp.int32))
-            counts = jnp.concatenate(
-                [counts, (slots - jnp.sum(counts))[None]])
-        with scope("experts"):
-            ys = grouped_ffn(rows, counts[:-1], m["wg"], m["wu"], m["wd"],
-                             self.activation, **self.kernels)
-        with scope("shared_experts"):
-            f = shared_ffn(h, m["sg"], m["su"], m["sd"], self.activation,
-                           self.shared_experts) \
-                if self.shared_experts else 0.0
-        with scope("moe_combine"):
+        ys, order, gate_vals, f, counts = _held_expert_layer(
+            self, params[f"layer{i}_moe"], h.reshape(-1, self.hidden), live)
+        with jax.named_scope("moe_combine"):
             f = f + dropless_combine(ys, order, gate_vals)
             return f.astype(x.dtype).reshape(x.shape), counts
 
@@ -929,12 +957,189 @@ class MiniCPMSala(Description):
         return _graph_logits(self.model, params, tokens, positions=True)
 
 
-SHAPES = (TransformerLM, OLMoE, Phi4Flash, CommandAPlus, MiniCPMSala)
+class Qwen3Next(Description):
+    """The build_qwen3_next_lm block (models/qwen3_next.py holds the
+    equations, ops/gated_delta.py and ops/gated_attention.py the
+    mixers'). Served by the mixed step on one device.
+
+    What it pages: the FULL layers' K and V (`kv_heads` grouped
+    key/value heads of `head_dim`, 256 as published). What a sequence
+    holds besides (`hybrid_spec`): for each DELTA layer a (Hv * Dk, Dv)
+    f32 matrix state and a convolution tail of d_conv - 1 rows over the
+    q, k and v channels; no ring. The expert layer is a share of an
+    expert-parallel one where the builder says so (`experts_held`)."""
+
+    kind = "qwen3_next"
+    builder = "build_qwen3_next_lm"
+    reads = ("tok_embed", "lm_head", "layer0_delta", "layer0_moe",
+             "final_norm")
+    output_gate = True
+    score = "softmax"           # the router's, over all the experts
+    _state = ("a sequence's matrix states and convolution tails live in "
+              "its slot, not in pages: ")
+    refused = {
+        "tp": "single-device: the matrix states, the grouped heads and "
+              "the held experts are not split over a mesh (the layer's "
+              "exchange between shares is not built: ROADMAP M1)",
+        "adapters": "no adapter pool for the gated mixers and the expert "
+                    "layer",
+        "speculation": _state + "rolling back rejected tokens would "
+                       "need a snapshot of the state (serve_spec_decode "
+                       "must be off)",
+        "prefix_cache": _state + "a prefix hit would need the state at "
+                        "the prefix's end (serve_prefix_cache must be "
+                        "off)",
+        "host_tier": _state + "the host tier spills pages only",
+        "handoff": _state + "the disaggregated handoff ships pages only",
+    }
+
+    def __init__(self, model, ops):
+        self.model = model
+        self.vocab_size = ops["tok_embed"].num_entries
+        self.layer_norm = True
+        n = 0
+        while f"layer{n}_norm1" in ops:
+            n += 1
+        self.num_layers = n
+        self.kinds = [DELTA if f"layer{i}_delta" in ops else FULL
+                      for i in range(n)]
+        self.delta_layers = [i for i, k in enumerate(self.kinds)
+                             if k == DELTA]
+        self.full_layers = [i for i, k in enumerate(self.kinds)
+                            if k == FULL]
+        moe0 = ops["layer0_moe"]
+        if not (self.full_layers and moe0.dropless and moe0.shared_gate
+                and all(f"layer{i}_attn" in ops for i in self.full_layers)
+                and all(ops[f"layer{i}_norm1"].zero_centered
+                        for i in range(n))):
+            raise ValueError(
+                "ServeEngine reads a build_qwen3_next_lm-shaped model: "
+                "delta AND gated attention layers under zero-centred "
+                "norms, a dropless MoEFFN with a gated shared expert")
+        attn = ops[f"layer{self.full_layers[0]}_attn"]
+        self.delta = ops["layer0_delta"]
+        self.num_heads, self._kv_heads = attn.num_heads, attn.num_kv_heads
+        self.head_dim = attn.head_dim
+        self.rope_theta, self.rotary_dim = attn.rotary_theta, attn.rotary_dim
+        self.hidden = attn.embed_dim
+        self.ln_eps = ops["layer0_norm1"].eps
+        self.act_dtype = jnp.dtype(ops["tok_embed"].out_dtype)
+        # rotary has no table: the positions served are the graph's own
+        self.max_positions = int(ops["tok_embed"].inputs[0].shape[1])
+        self.experts = moe0.num_experts
+        self.experts_per_token = moe0.k
+        self.experts_held = moe0.experts_held or (0, moe0.num_experts)
+        self.shared_experts = moe0.shared_experts
+        self.norm_topk, self.activation = moe0.norm_topk, moe0.activation
+        self.ff_dim = moe0.hidden_dim
+        w = model.state.params["layer0_moe"]["wg"]
+        # one expert's three matrices as they are resident, and what a
+        # step reads of the shared experts: all of them, every layer
+        self.expert_bytes = int(3 * self.hidden * self.ff_dim
+                                * w.dtype.itemsize)
+        self.shared_bytes = n * self.shared_experts * self.expert_bytes
+        self._expert_weights = jax.ShapeDtypeStruct(w.shape, w.dtype)
+
+    def mixer(self, i: int) -> str:
+        return self.kinds[i]
+
+    def expert_impl(self, lanes: int):
+        rows = jax.ShapeDtypeStruct(
+            (lanes * self.experts_per_token, self.hidden), self.act_dtype)
+        return expert_impl(rows, self._expert_weights, **self.kernels)
+
+    def hybrid_spec(self, chunk: int):
+        from .kv_cache import HybridSpec
+        d = self.delta
+        return HybridSpec(
+            window_layers=0, window=0, chunk=int(chunk),
+            state_layers=len(self.delta_layers),
+            state_shape=(d.value_heads * d.key_dim, d.value_dim),
+            tail_shape=(d.d_conv - 1, d.channels),
+            tail_dtype=str(self.act_dtype))
+
+    @property
+    def kv_heads(self) -> int:
+        return self._kv_heads
+
+    @property
+    def paged_layers(self) -> int:
+        return len(self.full_layers)
+
+    def embed(self, params, tokens, positions):
+        return jnp.take(params["tok_embed"]["kernel"], tokens, axis=0,
+                        mode="clip").astype(self.act_dtype)
+
+    def norm1(self, params, i, x):
+        return GA.rms_norm0(x, params[f"layer{i}_norm1"]["scale"],
+                            self.ln_eps)
+
+    def qkv(self, params, i, h, positions, lora=None):
+        """h (T, E), positions (T,) -> q (T, H, D), k, v (T, Hk, D), q
+        and k normed and rotated over their first `rotary_dim` dims, and
+        the output gate (T, H, D)."""
+        return GA.project_qkv(params[f"layer{i}_attn"], h, positions,
+                              self.rope_theta, self.rotary_dim, self.ln_eps)
+
+    attn_gate = staticmethod(GA.gate_output)
+
+    def attn_out(self, params, i, o, x, psum_axis=None, lora=None):
+        p = params[f"layer{i}_attn"]
+        return x + jnp.einsum("...hd,hde->...e", o,
+                              p["wo"].astype(o.dtype))
+
+    # the delta layer, in the pieces the step scopes apart
+    def delta_in(self, params, i, h):
+        """-> (u (T, channels) the convolution's raw input, z (T, Hv,
+        Dv), beta, g (T, Hv) f32)."""
+        p = params[f"layer{i}_delta"]
+        u, z, b, a = GD.project(p, h, *self.delta.shape_args)
+        return (u, z) + GD.gates(p, b, a)
+
+    def delta_heads(self, u):
+        """The convolution's output after silu -> q, k, v by value
+        head, f32."""
+        return GD.split_heads(u, *self.delta.shape_args)
+
+    def delta_out(self, params, i, o, z, x):
+        return x + GD.gate_and_project(params[f"layer{i}_delta"], o, z,
+                                       self.ln_eps)
+
+    def ffn(self, params, i, x, live=None, psum_axis=None, lora=None):
+        """The expert layer, five scopes as CommandAPlus.ffn's: `router`
+        (the norm, f32 softmax over all the experts, top-k,
+        renormalised), `moe_dispatch` (slots sorted by held expert; a
+        slot of an absent expert routes nowhere), `experts`,
+        `shared_experts` (plain matmuls, times sigmoid(h w_sg)),
+        `moe_combine`. -> (x, (held + 1,) int32: live slots per held
+        expert, then the live slots whose expert is absent)."""
+        with jax.named_scope("router"):
+            h = GA.rms_norm0(x, params[f"layer{i}_norm2"]["scale"],
+                             self.ln_eps).reshape(-1, self.hidden)
+        ys, order, gate_vals, f, counts = _held_expert_layer(
+            self, params[f"layer{i}_moe"], h, live)
+        with jax.named_scope("moe_combine"):
+            f = f + dropless_combine(ys, order, gate_vals)
+            return x + f.astype(x.dtype).reshape(x.shape), counts
+
+    def final_norm(self, params, x):
+        return GA.rms_norm0(x, params["final_norm"]["scale"], self.ln_eps)
+
+    def head(self, params, x):
+        return _dense(params["lm_head"], self.final_norm(params, x))
+
+    def forward_logits(self, params, tokens):
+        return _graph_logits(self.model, params, tokens, positions=True)
+
+
+SHAPES = (TransformerLM, Qwen3Next, OLMoE, Phi4Flash, CommandAPlus,
+          MiniCPMSala)
 
 
 def describe(model):
     """The description of a compiled FFModel, chosen by the op names
-    its builder wrote: the first of SHAPES whose names are all there."""
+    its builder wrote: the first of SHAPES whose names are all there
+    (Qwen3Next's before OLMoE's, whose names it has too)."""
     ops = {op.name: op for op in model.ops}
     for cls in SHAPES:
         if all(n in ops for n in cls.reads):

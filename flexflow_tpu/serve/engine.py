@@ -370,6 +370,11 @@ class ServeEngine:
             self.arch, self.cache_cfg, width=self.mixed_width,
             attn_impl=self.attn_impl, block_kv=self.attn_block_kv)
         self.scan_impl = g.scan_impl
+        # the delta rule's lanes (ops/gated_delta.segmented), named in
+        # the stats and the fingerprint of a model that has such a
+        # layer, and of no other ({}: their keys stay what they were)
+        self._delta_impl = {} if g.delta_impl is None \
+            else {"delta_impl": g.delta_impl}
         self.dense_pages = g.dense_pages
         self.attn_block_pages = g.block_pages
         self.attn_max_items = g.attn_max_items
@@ -643,6 +648,7 @@ class ServeEngine:
             "attn_impl": self.attn_impl,
             "scan_impl": self.scan_impl,
             "expert_impl": self.expert_impl,
+            **self._delta_impl,
         }
 
     # ---------------- model introspection -----------------------------
@@ -1651,6 +1657,7 @@ class ServeEngine:
         rec["attn_impl"] = self.attn_impl
         rec["scan_impl"] = self.scan_impl
         rec["expert_impl"] = self.expert_impl
+        rec.update(self._delta_impl)
         self.boot_stats = rec
         if self.programs.cache_dir and self.programs._dirty:
             # read-through write-back: the first (cold) engine over
@@ -2427,6 +2434,7 @@ class ServeEngine:
             "attn_impl": self.attn_impl,
             "scan_impl": self.scan_impl,
             "expert_impl": self.expert_impl,
+            **self._delta_impl,
             "devices": [int(d.id) for d in self.devices],
             "wall_s": wall,
             "total_new_tokens": total_new,

@@ -34,8 +34,9 @@ from ..kernels.paged_ragged_v2 import (JNP, PALLAS_INTERPRET, Q_ROWS,
                                        kv_page_bytes, max_work_items,
                                        paged_attention_ragged_v2,
                                        window_block_bound, work_items)
-from ..ops import linear_attention, ssm
-from .arch import ATTN, CROSS, FULL, GMU, LINEAR, SPARSE, SSM, WINDOW
+from ..ops import gated_delta, linear_attention, ssm
+from .arch import (ATTN, CROSS, DELTA, FULL, GMU, LINEAR, SPARSE, SSM,
+                   WINDOW)
 from .kv_cache import KVCacheConfig, ring_tables
 from .sparse_paged import paged_sparse_attention, stride_keys
 
@@ -68,6 +69,7 @@ class Geometry:
     block_kv: int               # the paged kernel's kv-block, positions
     block_pages: int            # the same in pages
     scan_impl: Optional[str]    # the state-space scan; None: no state
+    delta_impl: Optional[str]   # the delta rule's lanes; None: no such layer
     dense_pages: int            # columns a selecting model walks (0: all)
     attn_max_items: int         # grid bound of a call on the full list
     window_max_items: int       # ... on the window layers' list (0: none)
@@ -105,7 +107,9 @@ def geometry(arch, cfg: KVCacheConfig, *, width: int, attn_impl: str,
     # (kernels/ssm_scan.py, under `attn_impl`) wherever that kernel
     # takes the step's shape, else as its jnp twin
     # (ops/ssm.segmented_scan); a linear-attention layer's matrix state
-    # has the twin alone (ops/linear_attention.segmented_lightning)
+    # has the twin alone (ops/linear_attention.segmented_lightning),
+    # and so has the delta rule's (ops/gated_delta.segmented), under a
+    # name of its own: `delta_impl`
     scan_impl = None
     if hyb is not None and hyb.state_layers:
         scan_impl = attn_impl if SSM in kinds and ssm_scan.supported(
@@ -144,6 +148,7 @@ def geometry(arch, cfg: KVCacheConfig, *, width: int, attn_impl: str,
     return Geometry(
         arch=arch, cfg=cfg, width=width, attn_impl=attn_impl,
         block_kv=block_kv, block_pages=block_pages, scan_impl=scan_impl,
+        delta_impl=JNP if DELTA in kinds else None,
         dense_pages=dense_pages, attn_max_items=attn_max_items,
         window_max_items=window_max_items, attn_calls=attn_calls(arch),
         rings=ring_tables(cfg) if cfg.ring_pages else None,
@@ -283,9 +288,10 @@ def _attention(g, params, i, x, h, lanes, pool, memory, lora=None,
     """An attention layer: `qkv` (the description's projections at the
     lanes' positions), `kv_write` (KVPool.write: quantize and scatter),
     `attn` (the ragged paged kernel over a work list), `diff_norm`
-    where the attention is differential (arch.differential),
-    `attn_out`. The kind says which pages it writes and reads: ATTN
-    layer i of the one pool; WINDOW its own layer of the rings, under
+    where the attention is differential (arch.differential), `attn_gate`
+    where `qkv` bore an output gate (arch.output_gate), `attn_out`. The
+    kind says which pages it writes and reads: ATTN layer i of the one
+    pool; WINDOW its own layer of the rings, under
     the window's list; FULL its own of a hybrid pool's paged layers;
     CROSS the first of those, writing nothing."""
     scope = jax.named_scope
@@ -293,7 +299,7 @@ def _attention(g, params, i, x, h, lanes, pool, memory, lora=None,
     kind = arch.mixer(i)
     la, ad_s = lora if lora is not None else (None, None)
     with scope("qkv"):
-        q, k, v = arch.qkv(
+        q, k, v, *gate = arch.qkv(
             params, i, h, lanes.positions, lora=None if la is None else
             (la["a_qkv"], la["b_qkv"], ad_s))             # (T, H[/t], D)
     write_pages, page_tables = lanes.write_pages, lanes.page_tables
@@ -323,6 +329,9 @@ def _attention(g, params, i, x, h, lanes, pool, memory, lora=None,
     if arch.differential:
         with scope("diff_norm"):
             o = arch.diff_norm(params, i, o)
+    if arch.output_gate:
+        with scope("attn_gate"):
+            o = arch.attn_gate(o, *gate)
     with scope("attn_out"):
         x = arch.attn_out(
             params, i, o, x, psum_axis=tp_axis,
@@ -409,6 +418,36 @@ def _linear(g, params, i, x, h, lanes, pool, memory, lora=None,
     return x, pool, memory
 
 
+def _delta(g, params, i, x, h, lanes, pool, memory, lora=None,
+           tp_axis=None):
+    """A gated-delta-rule layer: `delta_proj` (the q, k, v, z, b and a
+    projections and the gates; the output norm, gate and projection),
+    `delta_conv` (the convolution over a run and its slot's tail, silu,
+    the L2 norms), `delta_scan` (the rule from each run's slot state and
+    the state's write-back, ops/gated_delta.segmented, in f32 and in
+    place in the pool's slab)."""
+    scope = jax.named_scope
+    arch = g.arch
+    j = arch.delta_layers.index(i)
+    with scope("delta_proj"):
+        u, z, beta, gl = arch.delta_in(params, i, h)
+    with scope("delta_conv"):
+        u, tail = ssm.segmented_conv(
+            params[f"layer{i}_delta"], u, pool.tail[j], lanes.lane_slots,
+            lanes.positions, lanes.offsets, lanes.wslots)
+        q, k, v = arch.delta_heads(jax.nn.silu(u))
+    with scope("delta_scan"):
+        o, state = gated_delta.segmented(
+            q, k, v, gl, beta, pool.state, lanes.lane_slots,
+            lanes.positions, lanes.live, lanes.starts, lanes.wslots,
+            lanes.live_lanes, layer=j)
+        pool = dataclasses.replace(
+            pool, state=state, tail=pool.tail.at[j].set(tail))
+    with scope("delta_proj"):
+        x = arch.delta_out(params, i, o, z, x)
+    return x, pool, memory
+
+
 def _sparse(g, params, i, x, h, lanes, pool, memory, lora=None,
             tp_axis=None):
     """A block-sparse attention layer: `qkv`, `kv_write`,
@@ -462,7 +501,7 @@ def _sparse(g, params, i, x, h, lanes, pool, memory, lora=None,
 
 BODIES = {ATTN: _attention, WINDOW: _attention, FULL: _attention,
           CROSS: _attention, SSM: _state_space, GMU: _gated_memory,
-          LINEAR: _linear, SPARSE: _sparse}
+          LINEAR: _linear, SPARSE: _sparse, DELTA: _delta}
 
 
 # --------------------------------------------------------------- counts

@@ -320,3 +320,62 @@ def test_minicpm_sala_mixed_step_compiles_within_its_memory_plan(topo,
     # no leaf of the pool is copied into another layout
     import re
     assert not re.search(r"= bf16\[2,32769,16,128\]\S* copy\(", text)
+
+
+def test_qwen3_next_mixed_step_compiles_within_its_memory_plan(topo, as_tpu):
+    """Qwen3-Next's mixed step at its served widths (576 lanes of 2048;
+    a delta layer of 16 key / 32 value heads of 128 beside a gated
+    attention layer at 16 query / 2 key-value heads of 256; 128 of 512
+    top-10 experts of width 512 held; 32,768 positions, 49,153 pages,
+    64 slots; ONE delta and ONE full layer and a small vocabulary, so
+    the parameters are quick to make; PR 49): it compiles for a v5e with
+    the paged kernel at a slab of 256 lanes (never compiled before this
+    model) and the fused expert kernel at one tile of F, the delta rule
+    is XLA's, the pool — pages, states, tails — is updated in place with
+    no copy of a state slab, and the step's temporaries stay under
+    0.5 GiB: the 8-layer configuration holds 10.6 GiB of weights and
+    cache."""
+    import re
+    from flexflow_tpu import FFConfig
+    from flexflow_tpu.config import CompMode
+    from flexflow_tpu.models.qwen3_next import build_qwen3_next_lm
+    from flexflow_tpu.serve import ServeEngine
+    from flexflow_tpu.serve.kv_cache import HybridPool
+    cfg = FFConfig(batch_size=1, kv_page_size=16, kv_num_pages=49153,
+                   serve_max_seqs=64, serve_prefill_budget=512,
+                   serve_spec_decode=False, serve_prefix_cache=False,
+                   compute_dtype="bfloat16", param_dtype="bfloat16",
+                   kv_dtype="bfloat16")
+    lm = build_qwen3_next_lm(
+        cfg, vocab_size=2048, max_seq_len=32768, num_layers=2,
+        full_attention_interval=2, experts_held=(0, 128))
+    lm.compile(comp_mode=CompMode.INFERENCE)
+    engine = ServeEngine(lm)
+    assert (engine.attn_impl, engine.geometry.delta_impl,
+            engine.expert_impl) == ("pallas", "jnp", "pallas")
+    assert (engine.mixed_width, engine.head_rows) == (576, 64)
+    one = SingleDeviceSharding(topo.devices[0])
+    c = engine.cache_cfg
+    assert (c.pages_per_seq, c.cache_bytes_per_token) == (2048, 2048)
+    pool = jax.eval_shape(lambda: HybridPool.alloc(c))
+    lane = jax.ShapeDtypeStruct((576,), jnp.int32, sharding=one)
+    rows = jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one)
+    tables = jax.ShapeDtypeStruct((c.max_seqs, c.pages_per_seq), jnp.int32,
+                                  sharding=one)
+    compiled = jax.jit(engine._mixed_impl, donate_argnums=(1,)).lower(
+        _sds(engine._step_params, one), _sds(pool, one), lane, lane, lane,
+        lane, tables, lane, lane, rows, lane, rows).compile()
+    engine.close()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 3
+    assert sum("paged_ragged_v2" in c for c in calls) == 1
+    assert sum("grouped_ffn" in c for c in calls) == 2
+    assert "ragged-dot" not in text
+    m = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(pool))
+    assert m.alias_size_in_bytes >= pool_bytes
+    assert m.temp_size_in_bytes < 2**29, m.temp_size_in_bytes
+    # the one delta layer's state slab is moved on where it lies
+    assert not re.search(r"= f32\[1,65,\S* copy\(", text)

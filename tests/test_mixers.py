@@ -19,11 +19,11 @@ from flexflow_tpu.kernels.paged_ragged_v2 import PALLAS_INTERPRET
 from flexflow_tpu.serve import ServeEngine
 from flexflow_tpu.serve import engine as E
 from flexflow_tpu.serve import mixers as M
-from flexflow_tpu.serve.arch import (ATTN, CROSS, FULL, GMU, LINEAR, SPARSE,
-                                     SSM, WINDOW)
+from flexflow_tpu.serve.arch import (ATTN, CROSS, DELTA, FULL, GMU, LINEAR,
+                                     SPARSE, SSM, WINDOW)
 from flexflow_tpu.serve.kv_cache import ring_tables
 
-KINDS = (ATTN, WINDOW, FULL, CROSS, SSM, GMU, LINEAR, SPARSE)
+KINDS = (ATTN, WINDOW, FULL, CROSS, SSM, GMU, LINEAR, SPARSE, DELTA)
 # description -> (the test module whose `_lm` builds it, the paged calls
 # a step of that build as the hand-written `attn_calls()` answered
 # before ISSUE 48)
@@ -31,7 +31,9 @@ BUILDS = {"transformer_lm": ("test_paged_work_list", (3, 0)),
           "olmoe": ("test_olmoe", (2, 0)),
           "phi4flash": ("test_phi4flash", (2, 2)),
           "command_a_plus": ("test_cmdaplus", (1, 3)),
-          "minicpm_sala": ("test_minicpm_sala", (4, 0))}
+          "minicpm_sala": ("test_minicpm_sala", (4, 0)),
+          # since PR 49: a description that never had such a method
+          "qwen3_next": ("test_qwen3_next", (1, 0))}
 _lms, _engines = {}, {}
 
 
@@ -94,8 +96,8 @@ def test_the_calls_a_step_at_the_served_depths(name, arch, calls):
     assert M.attn_calls(arch) == calls
 
 
-def test_the_table_is_the_eight_kinds():
-    assert set(M.BODIES) == set(KINDS) and len(set(KINDS)) == 8
+def test_the_table_is_the_nine_kinds():
+    assert set(M.BODIES) == set(KINDS) and len(set(KINDS)) == 9
 
 
 # ------------------------------------------------ (b) the arrows, one way
@@ -118,10 +120,11 @@ def test_mixers_and_arch_import_neither_engine_nor_scheduler(module):
 def test_the_engine_names_no_mixer_kind():
     held = set(vars(E))
     assert not held & {"SSM", "GMU", "LINEAR", "SPARSE", "WINDOW", "FULL",
-                       "CROSS"}
+                       "CROSS", "DELTA"}
     # no import of a mixer's ops or kernels, no forwarding method
     assert not held & {"ssm", "ssm_scan", "linear_attention",
-                       "paged_sparse_attention", "stride_keys"}
+                       "gated_delta", "paged_sparse_attention",
+                       "stride_keys"}
     for gone in ("_attn_layer", "_ssm_layer", "_linear_layer",
                  "_sparse_layer", "_hybrid_lanes", "_dense_lanes"):
         assert not hasattr(E.ServeEngine, gone), gone
@@ -263,6 +266,18 @@ EXPECTED["minicpm_sala"] = [
     (8, 4, 4, 4, 36864, 1, 1, 16384, 0, 1, 0, 1, 16),
     (8, 4, 4, 4, 36864, 1, 1, 16384, 0, 1, 0, 1, 16),
     (8, 4, 4, 4, 36864, 1, 1, 16384, 0, 1, 0, 1, 16),
+]
+# no parent's: as PR 49 counted them (a state and a tail in and out a
+# run and a delta layer: 2 x 3 x (4 x 16 x 16 x 4 + 3 x 128 x 4) a run)
+EXPECTED["qwen3_next"] = [
+    (1, 1, 0, 24, 131072, 1, 24, 33792, 0, 1, 0, 0, 0),
+    (4, 3, 0, 24, 147456, 3, 24, 101376, 0, 3, 0, 0, 0),
+    (4, 3, 2, 26, 159744, 3, 26, 101376, 0, 3, 0, 0, 0),
+    (4, 3, 2, 26, 172032, 3, 26, 101376, 0, 3, 0, 0, 0),
+    (4, 3, 2, 9, 176128, 3, 9, 101376, 0, 3, 0, 0, 0),
+    (2, 1, 1, 1, 135168, 1, 1, 33792, 0, 1, 0, 0, 0),
+    (2, 1, 1, 1, 135168, 1, 1, 33792, 0, 1, 0, 0, 0),
+    (2, 1, 1, 1, 135168, 1, 1, 33792, 0, 1, 0, 0, 0),
 ]
 
 
