@@ -51,12 +51,18 @@ This module rebuilds the kernel along the paper's lines:
   * HEAD PACKING: page blocks stream as (page_size, H*D) rows, which
     fills 128-lane VMEM tiles where (page_size, H, D) tiling padded
     D < 128 up to 128, and STAY packed — Mosaic cannot split a lane
-    dimension. (On a standalone array the reshape is free; on the
-    engine's tiled pool slice XLA makes a copy for it: PERF.md
-    section 5.) The per-head products are MXU matmuls over a run's
-    rows, one 128-lane slab of the packed axis at a time (see the
-    kernel section). Every page format and head size takes this path;
-    only the operands' precision differs (below).
+    dimension. A head-PACKED pool (serve/kv_cache.KVPool: its leaves
+    are stored as those rows) is read IN PLACE: the call takes the
+    whole leaf as rows of all its layers and the layer's first row as
+    a scalar-prefetch operand (`page_base`), added to every page the
+    index maps fetch — no slice, no copy, one trace for all the
+    layers. Of an UNPACKED pool (OPT's, OLMoE's) the engine hands a
+    layer's slice, and XLA copies the slab out and lays it out anew
+    for every call: PERF.md section 5, ROADMAP S2. The per-head
+    products are MXU matmuls over a run's rows, one 128-lane slab of
+    the packed axis at a time (see the kernel section). Every page
+    format and head size takes this path; only the operands'
+    precision differs (below).
   * TUNABLE KV-BLOCK SHAPES: `block_kv` (tokens per work item; FFConfig
     serve_attn_block_kv / --serve-attn-block-kv) with an
     autotune-by-shape table supplying defaults: measured entries for
@@ -265,9 +271,16 @@ def ragged_dispatch_passes(num_lanes: int, pages_per_seq: int,
                                  block_kv_pages, q_rows, slot_changes)}
 
 
+def _kv_heads(k_pages, head_dim: int) -> int:
+    """Key/value heads of pages (page, slot, head, dim) or, head-packed
+    as a packed pool stores them, (row, slot, head * dim)."""
+    return k_pages.shape[2] // (head_dim if k_pages.ndim == 3 else 1)
+
+
 # ------------------------------------------------------------ jnp paths
 def _ragged_jnp(q, k_pages, v_pages, page_tables, lane_slots, lane_lens,
-                scale, k_scales=None, v_scales=None, window=0):
+                scale, k_scales=None, v_scales=None, window=0,
+                page_base=None):
     """Vectorized fallback over the flattened ragged layout.
 
     Gathers each lane's pages (int8 gathers move 1/4 the bytes of f32),
@@ -278,12 +291,20 @@ def _ragged_jnp(q, k_pages, v_pages, page_tables, lane_slots, lane_lens,
     the Pallas kernel is held to. Grouped heads (q has `group` times
     the pages' heads: query head j reads key/value head j // group) and
     a `window` (a lane sees its last `window` positions) take the same
-    path; one group and no window trace what they always did."""
+    path; one group and no window trace what they always did. Rows of
+    many layers (`page_base`, packed pages (rows, ps, H*D)): the base
+    is added to the lanes' page tables, and the rows gathered are the
+    layer's own."""
     b, hq, d = q.shape
-    h = k_pages.shape[2]
+    h = _kv_heads(k_pages, d)
+    # head-packed rows: a free view here
+    k_pages, v_pages = (a.reshape(a.shape[:2] + (h, d))
+                        for a in (k_pages, v_pages))
     group = hq // h
     ps = k_pages.shape[1]
     lane_tables = jnp.take(page_tables, lane_slots, axis=0)  # (T, pp)
+    if page_base is not None:
+        lane_tables = lane_tables + jnp.asarray(page_base, jnp.int32)
     pp = lane_tables.shape[1]
     k = jnp.take(k_pages, lane_tables, axis=0)  # (T, pp, ps, H, D)
     v = jnp.take(v_pages, lane_tables, axis=0)
@@ -656,10 +677,10 @@ def _by_head(x, q_rows, heads, head_dim):
     return out
 
 
-def _ragged_v2_kernel(tile_ref, blk_ref, meta_ref, pages_ref, q2_ref,
-                      lens_ref, *refs, page_size, block_pages, q_rows,
-                      heads, head_dim, slabs, scale, quantized, exact,
-                      group=1, window=0, short=False):
+def _ragged_v2_kernel(tile_ref, blk_ref, meta_ref, pages_ref, *refs,
+                      page_size, block_pages, q_rows, heads, head_dim,
+                      slabs, scale, quantized, exact, group=1, window=0,
+                      short=False, based=False):
     """One work item: the rows [lo, hi) of a tile attend one kv-block
     of their sequence. Page refs arrive head-PACKED as (1, ps, H*D)
     blocks (plus (1, ps, H) scale blocks when quantized). The grid runs
@@ -674,8 +695,11 @@ def _ragged_v2_kernel(tile_ref, blk_ref, meta_ref, pages_ref, q2_ref,
     With `short` a live item whose run is ONE lane takes a second body:
     the same mathematics on the aligned SHORT_ROWS rows of each head
     that hold the lane's `group`, the tile's other rows untouched (as
-    the whole-tile body leaves them: they see nothing of the item)."""
+    the whole-tile body leaves them: they see nothing of the item).
+    `based`: a fifth scalar operand, the first row of the call's layer
+    in the pages' arrays, comes before q2."""
     del tile_ref, pages_ref                  # read by the index maps
+    q2_ref, lens_ref, *refs = refs[1:] if based else refs
     per_page = 4 if quantized else 2
     n_kv = per_page * block_pages
     kv_refs = refs[:n_kv]
@@ -869,9 +893,10 @@ def _vmem_limit(block_bytes: int) -> int:
                                              "window", "short"))
 def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
                       interpret, k_scales=None, v_scales=None, window=0,
-                      short=False):
+                      short=False, page_base=None):
     t, hq, d = q.shape
-    npages, ps, h = k_pages.shape[:3]
+    npages, ps = k_pages.shape[:2]
+    h = _kv_heads(k_pages, d)
     group = hq // h             # query heads a key/value head
     qb, bp = work.q_rows, work.block_pages
     qe = group * qb             # rows of one head of a slab
@@ -887,10 +912,13 @@ def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
     op_dtype = jnp.float32 if exact else jnp.bfloat16
 
     # head packing: pages stream as (ps, H*D) rows. On a standalone
-    # array this reshape is free; on the engine's tiled pool slice XLA
-    # makes a copy for it (PERF.md section 5)
+    # array and on a packed pool's whole leaf (rows of all its layers,
+    # `page_base` the call's first) this reshape is free; of an UNPACKED
+    # pool the engine hands a layer's slice, and XLA copies the slab out
+    # and lays it out anew for the call (PERF.md section 5)
     kp = k_pages.reshape(npages, ps, hd)
     vp = v_pages.reshape(npages, ps, hd)
+    based = page_base is not None
     # q2[tile, slab, (g * qb + r) * group + j, g' * D + c] = q[tile * qb
     # + r, (slab * G + g) * group + j, c] where g' == g, else 0: a
     # lane's `group` rows lie together, so a one-lane item finds them
@@ -914,11 +942,13 @@ def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
               ).reshape(tiles, slabs, g * qe, w_lanes)
 
     def page_index(i):
-        def imap(w, tile, blk, meta, pages):
-            return (pages[w * bp + i], 0, 0)
+        def imap(w, tile, blk, meta, pages, *base):
+            page = pages[w * bp + i]
+            # with a base: the page's row among the rows of all layers
+            return (base[0][0] + page if base else page, 0, 0)
         return imap
 
-    def tile_index(w, tile, blk, meta, pages):
+    def tile_index(w, tile, *_):
         return (tile[w], 0)
 
     # a lane's length once a row of its group
@@ -947,9 +977,14 @@ def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
         _ragged_v2_kernel, page_size=ps, block_pages=bp, q_rows=qb,
         heads=g, head_dim=d, slabs=slabs, scale=scale,
         quantized=quantized, exact=exact, group=group, window=window,
-        short=short)
+        short=short, based=based)
+    # the work list, and the layer's first row where the pages are rows
+    # of many layers: a device scalar, so every layer of a leaf shares
+    # this trace and one Mosaic kernel
+    prefetch = (work.tile, work.blk, work.meta, work.pages) + (
+        (jnp.asarray(page_base, jnp.int32).reshape(1),) if based else ())
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,          # the work list
+        num_scalar_prefetch=len(prefetch),
         # the list's own length, a device scalar: a call walks its
         # items and not the bound's empty tail. The interpreter takes
         # no traced bound and walks the arrays whole; the entries past
@@ -987,7 +1022,7 @@ def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
         interpret=interpret,
         # the device trace tells the two lists' calls apart by name
         name="paged_ragged_v2_window" if window else "paged_ragged_v2",
-    )(work.tile, work.blk, work.meta, work.pages, *args)
+    )(*prefetch, *args)
     # (tile, slab, (group, row), (head, dim)) -> (lane of the step,
     # query head, dim)
     if group == 1:
@@ -1036,7 +1071,7 @@ def paged_attention_ragged_v2(q, k_pages, v_pages, page_tables,
                               lane_slots, lane_lens, *, k_scales=None,
                               v_scales=None, scale=None, block_kv=None,
                               work=None, use_pallas=None,
-                              interpret=False, window=0):
+                              interpret=False, window=0, page_base=None):
     """Ragged batched attention through page tables — kernel v2.
 
     GROUPED HEADS: q may have `group` times the pages' heads; query
@@ -1064,6 +1099,17 @@ def paged_attention_ragged_v2(q, k_pages, v_pages, page_tables,
         must be both present or both absent).
       block_kv — KV tokens per work item (None = the autotune-by-shape
         table via choose_block_kv; rounded to whole pages).
+      page_base — ROWS OF MANY LAYERS: k_pages/v_pages (rows, page_size,
+        H * D) head-packed (the scales (rows, page_size, H)), the pages
+        of several layers one after another as a packed `KVPool` stores
+        them, and `page_base` () int32 the row of THIS layer's page 0:
+        page p of the tables is row page_base + p. The kernel fetches
+        its blocks at those rows of the whole array, so a caller hands
+        over the pool's leaf where it lies, not a slice of it that XLA
+        would first copy out; the base is a device scalar (traced, not
+        static), so every layer of a leaf shares one trace of the
+        kernel. None: the arrays hold one layer's pages, in either
+        form.
       work — the step's WorkList (`build_work_list` over these very
         lane arrays, made once for all the calls that share them: its
         kv-block shape is the one used); None builds one here, with
@@ -1082,12 +1128,14 @@ def paged_attention_ragged_v2(q, k_pages, v_pages, page_tables,
     if impl == JNP:
         return _ragged_jnp(q, k_pages, v_pages, page_tables, lane_slots,
                            lane_lens, scale, k_scales=k_scales,
-                           v_scales=v_scales, window=window)
+                           v_scales=v_scales, window=window,
+                           page_base=page_base)
     ps = k_pages.shape[1]
+    heads = _kv_heads(k_pages, q.shape[2])
     if work is None:
         if block_kv is None:
             block_kv = choose_block_kv(
-                ps, page_tables.shape[1], k_pages.shape[2], q.shape[2],
+                ps, page_tables.shape[1], heads, q.shape[2],
                 jnp.dtype(k_pages.dtype).itemsize)
         bp = max(1, min(int(block_kv) // ps, page_tables.shape[1]))
         # the list lives in SMEM: with no bound from the caller, as
@@ -1102,7 +1150,8 @@ def paged_attention_ragged_v2(q, k_pages, v_pages, page_tables,
                     lane_slots[a:a + step], lane_lens[a:a + step],
                     k_scales=k_scales, v_scales=v_scales, scale=scale,
                     block_kv=block_kv, use_pallas=use_pallas,
-                    interpret=interpret, window=window)
+                    interpret=interpret, window=window,
+                    page_base=page_base)
                 for a in range(0, q.shape[0], step)], axis=0)
         work = build_work_list(page_tables, lane_slots, lane_lens,
                                page_size=ps, block_pages=bp,
@@ -1110,4 +1159,5 @@ def paged_attention_ragged_v2(q, k_pages, v_pages, page_tables,
     return _ragged_v2_pallas(
         q, k_pages, v_pages, work, scale, impl == PALLAS_INTERPRET,
         k_scales=k_scales, v_scales=v_scales, window=int(window),
-        short=has_short_body(q.shape[1] // k_pages.shape[2], work.q_rows))
+        short=has_short_body(q.shape[1] // heads, work.q_rows),
+        page_base=page_base)

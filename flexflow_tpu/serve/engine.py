@@ -2435,6 +2435,9 @@ class ServeEngine:
             "scan_impl": self.scan_impl,
             "expert_impl": self.expert_impl,
             **self._delta_impl,
+            # the paged calls a step makes, and those that read their
+            # pool's leaf where it lies
+            **mixers.paged_calls(self.geometry),
             "devices": [int(d.id) for d in self.devices],
             "wall_s": wall,
             "total_new_tokens": total_new,
@@ -2665,6 +2668,10 @@ class StepEvents:
     (the engine's fixed ``head_rows``: the emitting lanes' rows,
     gathered before the head, padded with lane 0's) and ``emitters``
     the chunks that emit, a lane each whose logits the host reads;
+    ``paged_calls`` is the paged calls the step makes and
+    ``paged_calls_in_place`` those that read their pool's leaf where
+    it lies (mixers.paged_calls: all on a head-packed pool, none on an
+    unpacked one, whose layer slab XLA copies out for every call);
     ``topv`` / ``topi`` the step's fetched (lanes, k) top-k logits and
     their token ids and ``emit_lanes`` the ROW of those arrays at which
     each entry of ``emitted`` starts (an entry's tokens come from that

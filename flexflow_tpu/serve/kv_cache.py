@@ -447,8 +447,10 @@ class KVPool:
     in bf16) sublanes pads every (head, dim) tile of the unpacked
     layout — 10 heads of 128 to 16 — and XLA then copies the whole
     pool between its padded and a compact layout around every layer;
-    packed, a page is (slot, 1280): whole tiles. `layer` hands the
-    kernel the same (page, slot, head, dim) view either way.
+    packed, a page is (slot, 1280): whole tiles. The paged kernel
+    reads a packed pool where it lies (`layer` hands it the whole
+    leaves and the layer's first row); of an unpacked pool it is handed
+    a layer's slice, which XLA copies out for every call.
 
     SELECTOR rows (`kc`; `KVCacheConfig.selector_dim` > 0, else None and
     no leaf): (layer, page, selector_dim), one row a page — the
@@ -569,17 +571,30 @@ class KVPool:
                         axis=0, mode="clip")
 
     def layer(self, i: int):
-        """`layer`'s operands of the paged attention kernel
+        """Layer `i`'s operands of the paged attention kernel
         (kernels/paged_ragged_v2.paged_attention_ragged_v2): (k_pages,
-        v_pages, k_scales, v_scales), pages (page, slot, head, dim),
-        the scales (page, slot, head) or None."""
-        k, v = self.k[i], self.v[i]
-        if self.heads:
-            k, v = (a.reshape(a.shape[:2] + (self.heads, -1))
-                    for a in (k, v))
-        if not self.quantized:
-            return k, v, None, None
-        return k, v, self.k_scale[i], self.v_scale[i]
+        v_pages, k_scales, v_scales, page_base), the scales None on a
+        lossless pool.
+
+        A PACKED pool is read IN PLACE: its leaves are stored as the
+        rows the kernel streams, so the operands are the whole leaves
+        as rows of all the layers — (layer * page, slot, head * dim),
+        the scales (layer * page, slot, head); merging the leading
+        dimensions is a view — and `page_base` = i * num_pages, the row
+        of the layer's page 0, which the kernel adds to every page it
+        fetches. An UNPACKED pool hands the layer's slice, pages (page,
+        slot, head, dim), the scales (page, slot, head), and no base:
+        the kernel packs the heads of that slice, and a Mosaic call
+        needs a whole buffer, so XLA copies the slab out of the pool
+        and lays it out anew for every call (ROADMAP S2)."""
+        if not self.heads:
+            scales = (self.k_scale[i], self.v_scale[i]) if self.quantized \
+                else (None, None)
+            return (self.k[i], self.v[i]) + scales + (None,)
+        k, v, ks, vs = (
+            None if a is None else a.reshape((-1,) + a.shape[2:])
+            for a in (self.k, self.v, self.k_scale, self.v_scale))
+        return k, v, ks, vs, jnp.int32(i * self.k.shape[1])
 
     def rows(self, idx) -> "KVPool":
         """Whole pages `idx` of every layer, as a pool of len(idx)

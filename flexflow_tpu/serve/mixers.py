@@ -43,7 +43,7 @@ from .sparse_paged import paged_sparse_attention, stride_keys
 # the step's fixed shape against its live work: StepEvents attributes
 # and `dispatch` span arguments of every model
 LIVE_COUNTS = ("grid_steps", "live_steps", "short_steps", "live_rows",
-               "lanes", "emitters")
+               "lanes", "emitters", "paged_calls", "paged_calls_in_place")
 # what a step's selection did, on a model that selects its context
 # (a SPARSE layer)
 SELECT_COUNTS = ("sparse_lanes", "blocks_selected", "blocks_visible",
@@ -95,6 +95,18 @@ def attn_calls(arch) -> Tuple[int, int]:
     full = sum(kinds.count(k) for k in (ATTN, FULL, CROSS)) \
         + arch.kv_heads * kinds.count(SPARSE)
     return full, kinds.count(WINDOW)
+
+
+def paged_calls(g: "Geometry") -> dict:
+    """`paged_calls`, the paged calls a step makes, and
+    `paged_calls_in_place`, those of them that read their pool's leaf
+    where it lies: all of them on a head-packed pool, whose rows are
+    what the kernel streams; none on an unpacked one, where XLA copies
+    the layer's K and V slab out of the pool for every call
+    (KVPool.layer; ROADMAP S2 counts what is left by the difference)."""
+    calls = sum(g.attn_calls)
+    return {"paged_calls": calls,
+            "paged_calls_in_place": calls if g.cfg.packed_heads else 0}
 
 
 def geometry(arch, cfg: KVCacheConfig, *, width: int, attn_impl: str,
@@ -274,13 +286,13 @@ def _paged(g, q, kv, layer, tables, lanes, lens, work, window=0,
            heads=None):
     """One call of the ragged paged kernel on a pool layer's pages, for
     all of q's heads or for `heads`, a slice of them."""
-    k_pages, v_pages, k_scales, v_scales = kv.layer(layer)
+    k_pages, v_pages, k_scales, v_scales, page_base = kv.layer(layer)
     return paged_attention_ragged_v2(
         q if heads is None else q[:, heads], k_pages, v_pages, tables,
         lanes.lane_slots, lens,
         k_scales=k_scales, v_scales=v_scales,
         scale=float(g.arch.attn_scale), block_kv=g.block_kv, work=work,
-        window=window, **g.attn_kw)
+        window=window, page_base=page_base, **g.attn_kw)
 
 
 def _attention(g, params, i, x, h, lanes, pool, memory, lora=None,
@@ -596,5 +608,5 @@ def step_counts(g: Geometry, page_tables, positions, lane_slots,
         live_steps=sum(n * w["items"] for n, w in lists),
         short_steps=sum(n * w["short_items"] for n, w in lists),
         live_rows=sum(n * w["rows"] for n, w in lists),
-        lanes=head_rows, emitters=emitters)
+        lanes=head_rows, emitters=emitters, **paged_calls(g))
     return work

@@ -379,3 +379,99 @@ def test_qwen3_next_mixed_step_compiles_within_its_memory_plan(topo, as_tpu):
     assert m.temp_size_in_bytes < 2**29, m.temp_size_in_bytes
     # the one delta layer's state slab is moved on where it lies
     assert not re.search(r"= f32\[1,65,\S* copy\(", text)
+
+
+def _qwen3_next_two_full():
+    from flexflow_tpu.models.qwen3_next import build_qwen3_next_lm
+    return dict(kv_num_pages=49153, serve_max_seqs=64), lambda cfg: \
+        build_qwen3_next_lm(cfg, vocab_size=2048, max_seq_len=32768,
+                            num_layers=4, full_attention_interval=2,
+                            experts_held=(0, 128))
+
+
+def _command_a_plus_rings():
+    from flexflow_tpu.models.cmdaplus import build_cmdaplus_lm
+    return dict(kv_num_pages=16385, serve_max_seqs=32), lambda cfg: \
+        build_cmdaplus_lm(cfg, vocab_size=2048, max_seq_len=20480,
+                          num_experts=8, expert_dim=1024, shared_experts=1,
+                          experts_held=(0, 2))
+
+
+def _phi4flash_rings():
+    from flexflow_tpu.models.phi4flash import build_phi4flash_lm
+    return dict(kv_num_pages=16385, serve_max_seqs=64), lambda cfg: \
+        build_phi4flash_lm(cfg, vocab_size=2048, max_seq_len=8192,
+                           num_layers=8, ff_dim=2560)
+
+
+@pytest.mark.parametrize("build,calls,leaves", [
+    (_qwen3_next_two_full, 2, [(2, 49153, 16, 512)]),
+    (_command_a_plus_rings, 4, [(1, 16385, 16, 1024), (3, 9249, 16, 1024)]),
+    (_phi4flash_rings, 4, [(1, 16385, 16, 1280), (2, 4161, 16, 1280)])],
+    ids=["qwen3_next", "command_a_plus", "phi4flash"])
+def test_a_packed_pool_s_paged_calls_read_their_leaf_where_it_lies(
+        topo, as_tpu, build, calls, leaves):
+    """The mixed step of the configurations whose pool leaves hold
+    SEVERAL layers, at the served widths of the attention and of the
+    pool (PR 50): Qwen3-Next with two full layers (2 x 49,153 pages of
+    16 x 512), Command A+ with its three ring layers beside the full
+    one (3 x 9,249 of 16 x 1024), Phi with two ring layers, the full
+    layer and a cross layer (2 x 4,161 of 16 x 1280) — fewer layers, a
+    small vocabulary and narrow experts / FFN, so the parameters are
+    quick to make. Every paged call takes its leaf whole with the
+    layer's first row as a scalar (KVPool.layer), ordered between the
+    in-place scatters of `kv_write` by control dependences alone: the
+    compiled text holds no copy, slice or fusion whose result is a
+    layer's slab or a whole leaf (the scatters apart, which are in
+    place: `alias_size_in_bytes` covers the pool), and the temporaries
+    are smaller than ONE slab — at the parent they held the K and the V
+    slab of a layer (Qwen3-Next: 1.70 GB, now 0.12)."""
+    import re
+    from flexflow_tpu import FFConfig
+    from flexflow_tpu.config import CompMode
+    from flexflow_tpu.serve import ServeEngine, mixers
+    from flexflow_tpu.serve.kv_cache import HybridPool
+    system, make = build()
+    cfg = FFConfig(batch_size=1, kv_page_size=16, serve_prefill_budget=512,
+                   serve_spec_decode=False, serve_prefix_cache=False,
+                   compute_dtype="bfloat16", param_dtype="bfloat16",
+                   kv_dtype="bfloat16", **system)
+    lm = make(cfg)
+    lm.compile(comp_mode=CompMode.INFERENCE)
+    engine = ServeEngine(lm)
+    assert engine.attn_impl == "pallas"
+    assert mixers.paged_calls(engine.geometry) == {
+        "paged_calls": calls, "paged_calls_in_place": calls}
+    one = SingleDeviceSharding(topo.devices[0])
+    c = engine.cache_cfg
+    pool = jax.eval_shape(lambda: HybridPool.alloc(c))
+    kv = [p for p in (pool.full, pool.window) if p is not None]
+    assert [p.k.shape for p in kv] == leaves and all(p.heads for p in kv)
+    lane = jax.ShapeDtypeStruct((engine.mixed_width,), jnp.int32,
+                                sharding=one)
+    rows = jax.ShapeDtypeStruct((engine.head_rows,), jnp.int32,
+                                sharding=one)
+    tables = jax.ShapeDtypeStruct((c.max_seqs, c.pages_per_seq), jnp.int32,
+                                  sharding=one)
+    compiled = jax.jit(engine._mixed_impl, donate_argnums=(1,)).lower(
+        _sds(engine._step_params, one), _sds(pool, one), lane, lane, lane,
+        lane, tables, lane, lane, rows, lane, rows).compile()
+    engine.close()
+    text = compiled.as_text()
+    assert sum("tpu_custom_call" in line and "paged_ragged_v2" in line
+               for line in text.splitlines()) == calls
+    # a layer's slab, the leaf, and the leaf as rows of all its layers
+    shapes = {s for n, p, slot, hd in leaves for s in (
+        f"{p},{slot},{hd}", f"1,{p},{slot},{hd}", f"{n},{p},{slot},{hd}",
+        f"{n * p},{slot},{hd}")}
+    made = re.compile(r"= bf16\[(?:%s)\]\S* ([\w-]+)\(" % "|".join(shapes))
+    moved = [line.strip()[:200] for line in text.splitlines()
+             for op in made.findall(line)
+             if op in ("copy", "copy-start", "slice", "dynamic-slice")
+             or op == "fusion" and "kv_write" not in line]
+    assert not moved, moved
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= sum(
+        a.size * a.dtype.itemsize for a in jax.tree.leaves(pool))
+    slab = min(2 * p * slot * hd for n, p, slot, hd in leaves if n > 1)
+    assert m.temp_size_in_bytes < slab, (m.temp_size_in_bytes, slab)

@@ -37,7 +37,7 @@ def _cfg(kv_dtype):
 
 def _stored(pool, layer):
     """`layer`'s K and V as the kernel reads them, in f32."""
-    k, v, ks, vs = pool.layer(layer)
+    k, v, ks, vs, _ = pool.layer(layer)
     if pool.quantized:
         return np.asarray(dequantize_kv(k, ks)), \
             np.asarray(dequantize_kv(v, vs))
@@ -148,3 +148,66 @@ def test_every_engine_kind_compiles_one_mixed_program(kv_dtype, tp):
         eng.assert_token_parity(prompts, out, ref)
     eng.check_kv_scales()
     eng.cache.check_invariants(eng.pool)
+
+
+@pytest.mark.parametrize("impl", ["jnp", "pallas_interpret"])
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("packed", [False, True], ids=["unpacked", "packed"])
+def test_a_packed_pool_s_leaves_reach_the_kernel_whole(packed, kv_dtype,
+                                                       impl):
+    """What `mixers._paged` does to the pool's leaves before the kernel
+    has them. A packed pool: each leaf is reshaped (its layers' pages
+    as rows) and nothing else — no slice of a layer that XLA would copy
+    out for the call — and the layer reaches the kernel as a scalar, a
+    fifth scalar-prefetch operand. An unpacked pool: a layer's slice of
+    every leaf and the call of before, four scalar operands."""
+    import dataclasses
+    import types
+
+    import jax.extend.core
+    from flexflow_tpu.kernels.paged_ragged_v2 import JNP, build_work_list
+    from flexflow_tpu.serve import mixers
+    cfg = dataclasses.replace(_cfg(kv_dtype), packed_heads=packed)
+    pool = KVPool.alloc(cfg)
+    assert bool(pool.heads) == packed
+    leaves = len(jax.tree.leaves(pool))
+    t = 8
+    tables = jnp.arange(cfg.max_seqs * cfg.pages_per_seq,
+                        dtype=jnp.int32).reshape(cfg.max_seqs, -1) % 9
+    slots = jnp.zeros(t, jnp.int32)
+    lens = jnp.arange(1, t + 1, dtype=jnp.int32)
+    q = jnp.ones((t, cfg.num_heads, cfg.head_dim), jnp.float32)
+    g = types.SimpleNamespace(
+        arch=types.SimpleNamespace(attn_scale=0.5), block_kv=4,
+        attn_kw={"use_pallas": impl != JNP, "interpret": impl != JNP})
+    lanes = types.SimpleNamespace(lane_slots=slots)
+    work = None if impl == JNP else build_work_list(
+        tables, slots, lens, page_size=cfg.page_size, block_pages=1)
+    layer = 2
+    jaxpr = jax.make_jaxpr(lambda pool: mixers._paged(
+        g, q, pool, layer, tables, lanes, lens, work))(pool).jaxpr
+    first = [e for e in jaxpr.eqns
+             if any(v in jaxpr.invars for v in e.invars)]
+    names = sorted(e.primitive.name for e in first)
+    if packed:
+        assert names == ["reshape"] * leaves
+        p = cfg.num_pages
+        assert {e.outvars[0].aval.shape for e in first} == {
+            (3 * p, 4, 16)} | ({(3 * p, 4, 2)} if cfg.quantized else set())
+    else:
+        assert names == ["dynamic_slice"] * leaves \
+            or names == ["slice"] * leaves
+        assert all(e.outvars[0].aval.shape[:2] == (1, cfg.num_pages)
+                   for e in first)
+    if impl == JNP:
+        return
+    (call,) = [e for e in jaxpr.eqns
+               if e.params.get("name") == "_ragged_v2_pallas"]
+    (kernel,) = [e for e in call.params["jaxpr"].eqns
+                 if e.primitive.name == "pallas_call"]
+    assert kernel.params["grid_mapping"].num_index_operands == 4 + packed
+    # the layer's first row: a scalar operand of the nested call, so
+    # every layer of a leaf shares its trace
+    scalars = [int(v.val) for v in call.invars
+               if isinstance(v, jax.extend.core.Literal) and v.aval.shape == ()]
+    assert scalars == ([layer * cfg.num_pages] if packed else [])

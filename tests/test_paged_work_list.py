@@ -22,6 +22,7 @@ import dataclasses
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from flexflow_tpu.kernels.paged_ragged_v2 import (_FIRST, _LAST, _LIVE,
@@ -598,6 +599,109 @@ def test_one_lane_items_on_their_own_rows_equal_the_twin(window, dtype,
         q, kp, vp, pt, slots, lens, work=work, interpret=True,
         window=window, scale=0.3), np.float32)
     np.testing.assert_array_equal(by_rule, short)
+
+
+@pytest.mark.parametrize("fmt,hq,h,d,window,tol,layout", [
+    ("float32", 4, 4, 64, 0, 2e-6, "one_lane_rows"),
+    ("float32", 8, 2, 128, 24, 2e-6, "chunk_and_drafts"),
+    ("bfloat16", 4, 4, 64, 0, 2e-2, "one_lane_rows"),
+    ("bfloat16", 8, 2, 128, 0, 2e-2, "chunk_and_drafts"),
+    ("bfloat16", 8, 2, 128, 24, 2e-2, "one_lane_rows"),
+    ("bfloat16", 16, 4, 128, 0, 2e-2, "one_lane_rows"),
+    ("int8", 4, 4, 64, 0, 2e-6, "neighbours"),
+    ("int8", 8, 2, 64, 24, 2e-6, "neighbours"),
+    ("float8_e4m3", 4, 4, 64, 0, 2e-6, "neighbours"),
+    ("float8_e4m3", 8, 2, 64, 24, 2e-6, "neighbours")])
+def test_rows_of_many_layers_read_by_a_base_equal_the_layer_s_own_call(
+        fmt, hq, h, d, window, tol, layout):
+    """A head-packed pool's leaf holds the pages of all its layers as
+    rows (KVPool.layer): the call on the whole leaf with `page_base` =
+    layer * pages gives, for EVERY layer of three, exactly what the call
+    on that layer's own (page, slot, head, dim) slice gives — the jnp
+    twin against itself and the kernel against itself (the same
+    products in the same order; only where a block comes from differs)
+    — and the kernel with a base agrees with the twin like any other
+    call. One query head a key/value head and several (with the
+    one-lane body at 16 / 4), a window's list, and bf16, int8 and fp8
+    pages."""
+    layers, bp = 3, 2
+    slots, lens, live, _ = _lanes(layout)
+    rng = np.random.RandomState(hq + d + window)
+    pt = jnp.asarray(_table(rng))
+    quant = fmt in ("int8", "float8_e4m3")
+    act = jnp.float32 if quant else jnp.dtype(fmt)
+    each = [_pools(rng, h, d, fmt if quant else "float32")
+            for _ in range(layers)]
+    kl, vl = (jnp.stack([e[i] for e in each]) for i in (0, 1))
+    if not quant:
+        kl, vl = kl.astype(act), vl.astype(act)
+    sl = {n: jnp.stack([e[2][n] for e in each]) for n in each[0][2]}
+    pages = kl.shape[1]
+    rows = lambda a: a.reshape((-1,) + a.shape[2:])
+    kr, vr = (rows(a).reshape(layers * pages, PS, h * d) for a in (kl, vl))
+    sr = {n: rows(a) for n, a in sl.items()}
+    q = jnp.asarray(rng.randn(len(slots), hq, d), act)
+    slots, lens = jnp.asarray(slots), jnp.asarray(lens)
+    work = build_work_list(pt, slots, lens, page_size=PS, block_pages=bp,
+                           window=window)
+    outs = []
+    for i in range(layers):
+        base = jnp.int32(i * pages)
+        for kw in ({"use_pallas": False}, {"interpret": True, "work": work}):
+            own = paged_attention_ragged_v2(
+                q, kl[i], vl[i], pt, slots, lens, window=window, **kw,
+                **{n: a[i] for n, a in sl.items()})
+            based = paged_attention_ragged_v2(
+                q, kr, vr, pt, slots, lens, window=window,
+                page_base=base, **kw, **sr)
+            np.testing.assert_array_equal(np.asarray(based, np.float32),
+                                          np.asarray(own, np.float32))
+            outs.append(np.asarray(based, np.float32))
+        np.testing.assert_allclose(outs[-1][:live], outs[-2][:live],
+                                   atol=tol, rtol=0)
+    # the layers hold different pages: a base that named another
+    # layer's rows would have given another layer's answer
+    assert np.abs(outs[1] - outs[3]).max() > 0.1
+    # a packed layer's own rows need no base
+    alone = paged_attention_ragged_v2(
+        q, kr[:pages], vr[:pages], pt, slots, lens, window=window,
+        interpret=True, work=work, **{n: a[:pages] for n, a in sr.items()})
+    np.testing.assert_array_equal(np.asarray(alone, np.float32), outs[1])
+
+
+def test_a_call_with_no_base_lowers_to_the_program_it_was():
+    """`page_base=None` is the call of before: four scalar-prefetch
+    operands and the pages' index maps with no add; a base is a fifth,
+    traced — two layers of one leaf share the nested jit's one trace."""
+    h, d, bp = 4, 64, 2
+    slots, lens, _, _ = _lanes("decode_tail")
+    rng = np.random.RandomState(0)
+    pt = jnp.asarray(_table(rng))
+    kp, vp, _ = _pools(rng, h, d, "float32")
+    q = jnp.asarray(rng.randn(len(slots), h, d), jnp.float32)
+    slots, lens = jnp.asarray(slots), jnp.asarray(lens)
+    work = build_work_list(pt, slots, lens, page_size=PS, block_pages=bp)
+
+    def call(kp, vp, **kw):
+        return jax.make_jaxpr(lambda *a: paged_attention_ragged_v2(
+            q, *a, pt, slots, lens, work=work, interpret=True, **kw))(kp, vp)
+
+    def prefetched(jaxpr):
+        (eqn,) = _pallas_calls(jaxpr.jaxpr)
+        return eqn.params["grid_mapping"].num_index_operands
+
+    assert prefetched(call(kp, vp)) == 4
+    assert str(call(kp, vp)) == str(call(kp, vp, page_base=None))
+    kr, vr = (jnp.concatenate([a, a]).reshape(-1, PS, h * d)
+              for a in (kp, vp))
+    both = jax.make_jaxpr(lambda *a: [paged_attention_ragged_v2(
+        q, *a, pt, slots, lens, work=work, interpret=True,
+        page_base=jnp.int32(layer * kp.shape[0])) for layer in (0, 1)])(
+        kr, vr)
+    traces = [e.params["jaxpr"] for e in both.jaxpr.eqns
+              if e.params.get("name") == "_ragged_v2_pallas"]
+    assert len(traces) == 2 and traces[0] is traces[1]
+    assert prefetched(traces[0]) == 5
 
 
 def _pallas_calls(jaxpr):
