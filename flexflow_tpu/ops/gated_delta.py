@@ -32,8 +32,10 @@ sum of g inside the chunk and D_ij = exp(G_i - G_j) for i >= j,
   S <- exp(G_C) S + (K * exp(G_C - G))^T V';
 the graph op's forward) and `segmented` (the LANES of a serving step:
 runs of consecutive lanes of one sequence, each resuming from its
-slot's state, serve/mixers.py). Every decay is the exp of a non-positive
-sum of g, never a quotient of two of them.
+slot's state, serve/mixers.py; on the chip its lane form is the kernel
+kernels/gated_delta_scan.py, whose twin it is, and `lane_plan` /
+`chunk_blocks` below are that kernel's order of work). Every decay is
+the exp of a non-positive sum of g, never a quotient of two of them.
 
 The state is laid out (Hv * Dk, Dv): a value head's Dk x Dv matrix after
 another's, the value dimension on the lanes — the layout a batched
@@ -45,6 +47,7 @@ layer out for each block of lanes: PERF.md section 6, PR 49).
 from __future__ import annotations
 
 import math
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -57,8 +60,14 @@ F32 = jnp.float32
 _HI = jax.lax.Precision.HIGHEST
 CHUNK = 64          # tokens a trip of the chunk form; a power of two
 # the fewest live lanes of ONE run for which a block of the serving step
-# takes the chunk form: under it the lanes go one after another, which
-# costs a lane what the chunk form costs a sixteenth of a block
+# takes the chunk form: under it the lanes go one after another. Set
+# (PR 49) where XLA's loop cost a lane what the chunk form cost a
+# sixteenth of a block. On the chip that is the twin's price alone now:
+# in the kernel (kernels/gated_delta_scan.py, PR 51) a run's lanes cost
+# 2.4 us each behind 20 us a call, a chunk-form block 171 us, so the
+# two cross near a WHOLE block (evidence/gated_delta_tpu.json: one run
+# of 64 lanes 166 us lane by lane). PR 51 leaves the threshold where it
+# was; moving it is ROADMAP S14 (d-chunk)
 CHUNK_MIN_LANES = 16
 L2_EPS = 1e-6
 
@@ -221,6 +230,132 @@ def chunked(q, k, v, g, beta, chunk: int = CHUNK):
     return o.swapaxes(0, 1).reshape(b, s + pad, h, -1)[:, :s]
 
 
+def block_forms(starts, live, live_lanes, xp=jnp, block: int = CHUNK):
+    """Which form each BLOCK of `block` lanes takes: `starts`, `live`
+    (T,) bool, `live_lanes` how many lanes from lane 0 up hold a token
+    -> (as_chunk (n,) bool, count (n,) the block's live lanes, among
+    (n, block) bool which lanes those are). A block whose live lanes
+    are CHUNK_MIN_LANES or more of ONE run takes the chunk form; any
+    other block's live lanes go lane by lane. numpy where the host
+    counts the forms (serve/mixers.step_counts), jax.numpy where the
+    step takes them."""
+    t = starts.shape[0]
+    pad = -t % block
+    n = (t + pad) // block
+    begins = xp.pad(starts, (0, pad), constant_values=True).reshape(n, block)
+    alive = xp.pad(live, (0, pad), constant_values=False).reshape(n, block)
+    lane = xp.arange(block)[None]
+    count = xp.clip(live_lanes - xp.arange(n) * block, 0, block)
+    among = (lane < count[:, None]) & alive
+    one_run = ~xp.any(begins & among & (lane > 0), axis=1)
+    return one_run & (count >= CHUNK_MIN_LANES), count, among
+
+
+class Segments(NamedTuple):
+    """Consecutive lanes of one run that go lane by lane, the first
+    `count` entries of (T,) int32 arrays: a segment's first lane, its
+    lanes, the slot its state comes from (-1: from zero, the sequence
+    starts there) and the slot its state goes back to."""
+    first: Any
+    length: Any
+    src: Any
+    dst: Any
+    count: Any
+
+
+class LanePlan(NamedTuple):
+    """A step's lanes as kernels/gated_delta_scan.py walks them: the
+    first `chunks` entries of `chunk_ids` (n,) are the blocks that take
+    the chunk form, in order, `among` (n, block) every block's live
+    lanes; the lanes that go lane by lane are the segments `before` any
+    chunk-form block of their run and `after` one."""
+    chunk_ids: Any
+    chunks: Any
+    among: Any
+    before: Segments
+    after: Segments
+
+
+def _front(flag):
+    """(N,) bool -> (N,) int32: the indices where `flag`, in order,
+    from entry 0 up (no scatter: a compare of every entry with every
+    index), zeros behind them."""
+    at = jnp.arange(flag.shape[0], dtype=jnp.int32)
+    nth = jnp.cumsum(flag.astype(jnp.int32)) - 1
+    hit = flag[None, :] & (nth[None, :] == at[:, None])
+    return jnp.sum(jnp.where(hit, at[None, :], 0), axis=1)
+
+
+def _segments(mask, starts, lane_slots, positions) -> Segments:
+    """The lanes of `mask` (T,) as `Segments`: one where a run starts
+    or the lane before is not of the mask."""
+    no = jnp.zeros((1,), bool)
+    begin = mask & (starts | ~jnp.concatenate([no, mask[:-1]]))
+    end = mask & jnp.concatenate([begin[1:] | ~mask[1:], ~no])
+    first, last = _front(begin), _front(end)
+    slot = lane_slots[first].astype(jnp.int32)
+    return Segments(first, last - first + 1,
+                    jnp.where(positions[first] > 0, slot, -1), slot,
+                    jnp.sum(begin.astype(jnp.int32)))
+
+
+def lane_plan(lane_slots, positions, live, starts, live_lanes,
+              block: int = CHUNK) -> LanePlan:
+    """`segmented`'s order of work, made once a step for all the
+    layers. A run is at most lanes (in a block it shares, or too few
+    for the chunk form), then whole chunk-form blocks, then lanes (a
+    tail under CHUNK_MIN_LANES, or one that shares its block): the
+    lanes AFTER a chunk-form block of their own run are those of a
+    block whose predecessor took the chunk form, up to the block's
+    first run start."""
+    t = starts.shape[0]
+    as_chunk, _, among = block_forms(starts, live, live_lanes, block=block)
+    lane = jnp.arange(t, dtype=jnp.int32)
+    blk = lane // block
+    lane_form = (lane < live_lanes) & ~as_chunk[blk]
+    begun = jnp.cumsum(starts.astype(jnp.int32))
+    base = jnp.concatenate([jnp.zeros((1,), jnp.int32), begun])[blk * block]
+    no = jnp.zeros((1,), bool)
+    after = lane_form & jnp.concatenate([no, as_chunk[:-1]])[blk] \
+        & (begun == base)
+    return LanePlan(
+        _front(as_chunk), jnp.sum(as_chunk.astype(jnp.int32)), among,
+        _segments(lane_form & ~after, starts, lane_slots, positions),
+        _segments(after, starts, lane_slots, positions))
+
+
+def chunk_blocks(q, k, v, g, beta, o, state, layer, lane_slots, positions,
+                 plan: LanePlan, block: int = CHUNK):
+    """The chunk-form blocks of `plan`, one after another — a loop of
+    as many trips as the step has such blocks, none where it has none —
+    on layer `layer` of the slab `state` (layers, slots + 1, H * Dk,
+    Dv): a block's run resumes from its slot's state (from zero where
+    the sequence starts at the block's first lane) and leaves its state
+    there, where the next block, or the lanes after, take it up; the
+    block's rows of `o` (n * block, H, Dv) are written. q, k, v, g,
+    beta: n * block rows. -> (o, the slab)."""
+    _, h, dk = q.shape
+    dv = v.shape[-1]
+    slab = state.reshape(state.shape[:2] + (h, dk, dv))
+
+    def a_block(i, carry):
+        slab, o = carry
+        b = plan.chunk_ids[i]
+        first = b * block
+        rows = lambda a: jax.lax.dynamic_slice_in_dim(a, first, block)
+        m = plan.among[b][:, None]
+        slot = lane_slots[first]
+        s = jnp.where(positions[first] > 0, slab[layer, slot], 0.0)
+        s, ob = _chunk(s, rows(q), rows(k), rows(v),
+                       jnp.where(m, rows(g), 0.0),
+                       jnp.where(m, rows(beta), 0.0))
+        return (slab.at[layer, slot].set(s),
+                jax.lax.dynamic_update_slice_in_dim(o, ob, first, 0))
+
+    slab, o = jax.lax.fori_loop(0, plan.chunks, a_block, (slab, o))
+    return o, slab.reshape(state.shape)
+
+
 def segmented(q, k, v, g, beta, state, lane_slots, positions, live,
               starts, wslots, live_lanes, layer=None, block: int = CHUNK):
     """The recurrence over the step's lanes. q, k (T, H, Dk), v (T, H,
@@ -257,8 +392,6 @@ def segmented(q, k, v, g, beta, state, lane_slots, positions, live,
                     constant_values=fill)
         return a.reshape((n, block) + a.shape[1:])
 
-    lane = jnp.arange(block)
-
     def resume(slab, s, start, slot, pos):
         """The state a lane works on: its slot's where the lane starts a
         run (zeros where the sequence starts there), else the carried."""
@@ -267,11 +400,8 @@ def segmented(q, k, v, g, beta, state, lane_slots, positions, live,
 
     def trip(carry, x):
         s, slab = carry
-        first, qb, kb, vb, gb, bb, slots, pos, alive, begins, wb = x
-        count = jnp.clip(live_lanes - first, 0, block)      # live lanes
-        among = (lane < count) & alive
-        one_run = ~jnp.any(begins & among & (lane > 0))
-        as_chunk = one_run & (count >= CHUNK_MIN_LANES)
+        as_chunk, count, among, qb, kb, vb, gb, bb, slots, pos, begins, \
+            wb = x
 
         def a_lane(j, c):
             s, slab, o = c
@@ -298,10 +428,10 @@ def segmented(q, k, v, g, beta, state, lane_slots, positions, live,
         slab = slab.at[at, jnp.where(as_chunk, wb[last], sink)].set(s)
         return (s, slab), jnp.where(as_chunk, o_chunk, o_lanes)
 
-    xs = (jnp.arange(n, dtype=jnp.int32) * block, blocks(q), blocks(k),
-          blocks(v), blocks(g), blocks(beta), blocks(lane_slots),
-          blocks(positions), blocks(live, False), blocks(starts, True),
-          blocks(wslots, sink))
+    xs = block_forms(starts, live, live_lanes, block=block) + (
+        blocks(q), blocks(k), blocks(v), blocks(g), blocks(beta),
+        blocks(lane_slots), blocks(positions), blocks(starts, True),
+        blocks(wslots, sink))
     (_, slab), o = jax.lax.scan(
         trip, (jnp.zeros((h, dk, dv), F32), slab), xs)
     whole = slab.reshape(whole.shape)

@@ -94,11 +94,12 @@ def causal_conv(p, u):
 
 
 # ------------------------------------------------- the serving step's scans
-def run_starts(lane_slots, positions):
+def run_starts(lane_slots, positions, xp=jnp):
     """(T,) bool: the lane starts a RUN — the step's first lane, or its
-    slot or position does not continue the lane before it."""
-    prev_s = jnp.concatenate([lane_slots[:1] - 1, lane_slots[:-1]])
-    prev_p = jnp.concatenate([positions[:1], positions[:-1]])
+    slot or position does not continue the lane before it (numpy where
+    the host counts the runs' forms)."""
+    prev_s = xp.concatenate([lane_slots[:1] - 1, lane_slots[:-1]])
+    prev_p = xp.concatenate([positions[:1], positions[:-1]])
     return (lane_slots != prev_s) | (positions != prev_p + 1)
 
 

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels import ssm_scan
+from ..kernels import gated_delta_scan, ssm_scan
 from ..kernels.paged_ragged_v2 import (JNP, PALLAS_INTERPRET, Q_ROWS,
                                        WorkList, build_work_list,
                                        kv_page_bytes, max_work_items,
@@ -50,11 +50,16 @@ SELECT_COUNTS = ("sparse_lanes", "blocks_selected", "blocks_visible",
                  "selected_kv_bytes", "selector_bytes")
 # ... where a sequence holds state or a ring besides pages
 HYBRID_COUNTS = ("state_bytes", "window_kv_bytes", "full_kv_bytes")
+# ... where a layer runs the gated delta rule: the live lanes that go
+# lane by lane and the blocks of lanes that take the chunk form
+# (ops/gated_delta.block_forms), a layer
+DELTA_COUNTS = ("delta_lanes", "delta_chunk_blocks")
 # what StepEvents takes of every step and the span does not (it has
 # the first three under older names)
 EVENT_COUNTS = ("kv_bytes_read", "attn_items", "attn_rows", "ssm_runs",
                 "lanes_past_window")
-STEP_COUNTS = EVENT_COUNTS + LIVE_COUNTS + HYBRID_COUNTS + SELECT_COUNTS
+STEP_COUNTS = EVENT_COUNTS + LIVE_COUNTS + HYBRID_COUNTS + SELECT_COUNTS \
+    + DELTA_COUNTS
 
 
 # ------------------------------------------------------------- geometry
@@ -119,13 +124,18 @@ def geometry(arch, cfg: KVCacheConfig, *, width: int, attn_impl: str,
     # (kernels/ssm_scan.py, under `attn_impl`) wherever that kernel
     # takes the step's shape, else as its jnp twin
     # (ops/ssm.segmented_scan); a linear-attention layer's matrix state
-    # has the twin alone (ops/linear_attention.segmented_lightning),
-    # and so has the delta rule's (ops/gated_delta.segmented), under a
-    # name of its own: `delta_impl`
-    scan_impl = None
+    # has the twin alone (ops/linear_attention.segmented_lightning).
+    # The delta rule's lanes resolve the same way under a name of their
+    # own, `delta_impl`: kernels/gated_delta_scan.py where it takes the
+    # step's shape, else its twin (ops/gated_delta.segmented)
+    scan_impl = delta_impl = None
     if hyb is not None and hyb.state_layers:
         scan_impl = attn_impl if SSM in kinds and ssm_scan.supported(
             width, *hyb.state_shape) else JNP
+    if DELTA in kinds:
+        d = arch.delta
+        delta_impl = attn_impl if gated_delta_scan.supported(
+            width, d.value_heads, d.key_dim, d.value_dim) else JNP
     block_pages = max(1, block_kv // cfg.page_size)
     # a model that SELECTS its context (arch.dense_len) walks pages in
     # the paged kernel only for its lanes under dense_len: the list is
@@ -156,11 +166,12 @@ def geometry(arch, cfg: KVCacheConfig, *, width: int, attn_impl: str,
             arch.window, block_pages * cfg.page_size)
     ) if arch.window else 0
     counted = LIVE_COUNTS + (HYBRID_COUNTS if hyb is not None else ()) \
-        + (SELECT_COUNTS if dense_pages else ())
+        + (SELECT_COUNTS if dense_pages else ()) \
+        + (DELTA_COUNTS if DELTA in kinds else ())
     return Geometry(
         arch=arch, cfg=cfg, width=width, attn_impl=attn_impl,
         block_kv=block_kv, block_pages=block_pages, scan_impl=scan_impl,
-        delta_impl=JNP if DELTA in kinds else None,
+        delta_impl=delta_impl,
         dense_pages=dense_pages, attn_max_items=attn_max_items,
         window_max_items=window_max_items, attn_calls=attn_calls(arch),
         rings=ring_tables(cfg) if cfg.ring_pages else None,
@@ -215,6 +226,9 @@ class Lanes(NamedTuple):
     rings: Any = None
     ring_pages: Any = None
     window_work: Optional[WorkList] = None
+    # the delta rule's lanes as its kernel walks them (ops/gated_delta.
+    # lane_plan); None: no such layer, or the twin sorts its own
+    delta_plan: Optional[gated_delta.LanePlan] = None
 
 
 def step_lanes(g: Geometry, positions, write_pages, write_offs,
@@ -256,6 +270,9 @@ def step_lanes(g: Geometry, positions, write_pages, write_offs,
                 wslots=ssm.run_write_slots(starts, live, lane_slots,
                                            c.max_seqs),
                 live_lanes=jnp.max(jnp.where(live, lane, 0)))
+            if g.delta_impl not in (None, JNP):
+                made["delta_plan"] = gated_delta.lane_plan(
+                    lane_slots, positions, live, starts, made["live_lanes"])
         if ringed:
             made.update(
                 rings=rings,
@@ -436,8 +453,9 @@ def _delta(g, params, i, x, h, lanes, pool, memory, lora=None,
     projections and the gates; the output norm, gate and projection),
     `delta_conv` (the convolution over a run and its slot's tail, silu,
     the L2 norms), `delta_scan` (the rule from each run's slot state and
-    the state's write-back, ops/gated_delta.segmented, in f32 and in
-    place in the pool's slab)."""
+    the state's write-back, in f32 and in place in the pool's slab:
+    kernels/gated_delta_scan.py for the lanes that go lane by lane, or
+    its twin ops/gated_delta.segmented)."""
     scope = jax.named_scope
     arch = g.arch
     j = arch.delta_layers.index(i)
@@ -449,10 +467,18 @@ def _delta(g, params, i, x, h, lanes, pool, memory, lora=None,
             lanes.positions, lanes.offsets, lanes.wslots)
         q, k, v = arch.delta_heads(jax.nn.silu(u))
     with scope("delta_scan"):
-        o, state = gated_delta.segmented(
-            q, k, v, gl, beta, pool.state, lanes.lane_slots,
-            lanes.positions, lanes.live, lanes.starts, lanes.wslots,
-            lanes.live_lanes, layer=j)
+        # the kernel keeps an f32 slab in place; a slab of another
+        # dtype (no configuration's) keeps the twin
+        if lanes.delta_plan is not None and pool.state.dtype == jnp.float32:
+            o, state = gated_delta_scan.gated_delta_scan(
+                q, k, v, gl, beta, pool.state, j, lanes.lane_slots,
+                lanes.positions, lanes.delta_plan,
+                interpret=g.delta_impl == PALLAS_INTERPRET)
+        else:
+            o, state = gated_delta.segmented(
+                q, k, v, gl, beta, pool.state, lanes.lane_slots,
+                lanes.positions, lanes.live, lanes.starts, lanes.wslots,
+                lanes.live_lanes, layer=j)
         pool = dataclasses.replace(
             pool, state=state, tail=pool.tail.at[j].set(tail))
     with scope("delta_proj"):
@@ -575,6 +601,14 @@ def step_counts(g: Geometry, page_tables, positions, lane_slots,
             if arch.window else 0,
             ssm_runs=runs if c.hybrid.state_layers else 0,
             state_bytes=2 * runs * c.hybrid.state_bytes)
+    if g.delta_impl is not None:
+        # which form each block of lanes takes, by the rule the step
+        # itself follows (the same function over numpy)
+        live = np.arange(g.width) < live_lanes
+        as_chunk, count, _ = gated_delta.block_forms(
+            ssm.run_starts(lane_slots, positions, np), live, live_lanes, np)
+        work.update(delta_lanes=int(count[~as_chunk].sum()),
+                    delta_chunk_blocks=int(as_chunk.sum()))
     work["kv_bytes_read"] = full + ringed
     if g.dense_pages:
         # what the selection does for the live lanes past dense_len,

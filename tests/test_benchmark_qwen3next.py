@@ -197,4 +197,6 @@ def test_the_cell_rehearses_on_the_cpu():
     assert numbers["expert_load_max_over_mean"] >= 1.0
     engine = json.loads(next(
         ln for ln in lines if ln.startswith("# engine: "))[10:])
+    # the rehearsal's key dimension of 16 keeps the delta rule's twin;
+    # at the published 128 the cell's engine line says "pallas"
     assert engine["kinds"] == "dddf" and engine["delta_impl"] == "jnp"

@@ -182,6 +182,51 @@ def test_serving_scan_kernel_compiles_in_place_for_a_v5e(topo, as_tpu,
     assert m.temp_size_in_bytes < 2**20
 
 
+def test_delta_lane_kernel_compiles_in_place_for_a_v5e(topo, as_tpu):
+    """The delta rule's lanes (kernels/gated_delta_scan.py, PR 51) at
+    Qwen3-Next's served shape — 576 lanes, 65 slot rows of 4096 x 128
+    f32 (32 value heads of 128 x 128), six layers in one donated slab,
+    the plan made from the lane arrays: Mosaic takes the kernel (the
+    gates as f32 scalars in SMEM, a 128 x 128 transpose, the states'
+    copies in and out by hand), it is called on either side of the
+    chunk-form blocks' loop, and the program copies neither the slab
+    nor a layer's row of it."""
+    import re
+
+    from flexflow_tpu.kernels import gated_delta_scan as kd
+    from flexflow_tpu.ops import gated_delta as gd
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    t, rows, h, dk, dv, layers = 576, 65, 32, 128, 128, 6
+    assert kd.supported(t, h, dk, dv)
+
+    def call(q, k, v, g, beta, slab, slots, pos, live, starts, n):
+        plan = gd.lane_plan(slots, pos, live, starts, n)
+        return kd.gated_delta_scan(q, k, v, g, beta, slab, 4, slots, pos,
+                                   plan)
+
+    lane, flag = sds((t,), jnp.int32), sds((t,), jnp.bool_)
+    compiled = jax.jit(call, donate_argnums=(5,)).lower(
+        sds((t, h, dk)), sds((t, h, dk)), sds((t, h, dv)), sds((t, h)),
+        sds((t, h)), sds((layers, rows, h * dk, dv)), lane, lane, flag,
+        flag, sds((), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 2 and all("gated_delta_scan" in c for c in calls)
+    assert len(re.findall(r"\bwhile\(", text)) == 1
+    copies = [line for line in text.splitlines()
+              if re.search(r"\bcopy(-start)?\(", line)
+              and re.search(r"f32\[(6,)?(1,)?65,", line)]
+    assert not copies, copies
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 4 * layers * rows * h * dk * dv
+    # q, k, v of one block of the chunk form and `o`: no state slab
+    assert m.temp_size_in_bytes < 2**26, m.temp_size_in_bytes
+
+
 def _sds(tree, sharding):
     return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
         a.shape, a.dtype, sharding=sharding), tree)
@@ -330,11 +375,13 @@ def test_qwen3_next_mixed_step_compiles_within_its_memory_plan(topo, as_tpu):
     64 slots; ONE delta and ONE full layer and a small vocabulary, so
     the parameters are quick to make; PR 49): it compiles for a v5e with
     the paged kernel at a slab of 256 lanes (never compiled before this
-    model) and the fused expert kernel at one tile of F, the delta rule
-    is XLA's, the pool — pages, states, tails — is updated in place with
-    no copy of a state slab, and the step's temporaries stay under
-    0.5 GiB: the 8-layer configuration holds 10.6 GiB of weights and
-    cache."""
+    model) and the fused expert kernel at one tile of F, the delta
+    rule's lanes in their own kernel (kernels/gated_delta_scan.py, PR
+    51: the one kernel called on either side of the chunk-form blocks,
+    whose loop is the only one under `delta_scan`), the pool — pages, states, tails
+    — is updated in place with no copy of a state slab, and the step's
+    temporaries stay under 0.5 GiB: the 8-layer configuration holds
+    10.6 GiB of weights and cache."""
     import re
     from flexflow_tpu import FFConfig
     from flexflow_tpu.config import CompMode
@@ -352,7 +399,7 @@ def test_qwen3_next_mixed_step_compiles_within_its_memory_plan(topo, as_tpu):
     lm.compile(comp_mode=CompMode.INFERENCE)
     engine = ServeEngine(lm)
     assert (engine.attn_impl, engine.geometry.delta_impl,
-            engine.expert_impl) == ("pallas", "jnp", "pallas")
+            engine.expert_impl) == ("pallas", "pallas", "pallas")
     assert (engine.mixed_width, engine.head_rows) == (576, 64)
     one = SingleDeviceSharding(topo.devices[0])
     c = engine.cache_cfg
@@ -368,9 +415,14 @@ def test_qwen3_next_mixed_step_compiles_within_its_memory_plan(topo, as_tpu):
     engine.close()
     text = compiled.as_text()
     calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
-    assert len(calls) == 3
+    assert len(calls) == 5
     assert sum("paged_ragged_v2" in c for c in calls) == 1
     assert sum("grouped_ffn" in c for c in calls) == 2
+    assert sum("gated_delta_scan" in c for c in calls) == 2
+    # the chunk-form blocks' loop, of no trip where a step has none
+    loops = [line for line in text.splitlines()
+             if re.search(r"\bwhile\(", line) and "delta_scan" in line]
+    assert len(loops) == 1 and "conditional(" not in text
     assert "ragged-dot" not in text
     m = compiled.memory_analysis()
     pool_bytes = sum(a.size * a.dtype.itemsize

@@ -290,7 +290,8 @@ def test_the_host_s_counts_are_the_parent_s(kind):
     g = eng.geometry
     assert g.counted == M.LIVE_COUNTS + (
         M.HYBRID_COUNTS if eng.cache_cfg.hybrid is not None else ()) + (
-        M.SELECT_COUNTS if g.dense_pages else ())
+        M.SELECT_COUNTS if g.dense_pages else ()) + (
+        M.DELTA_COUNTS if g.delta_impl is not None else ())
     assert not set(M.EVENT_COUNTS) & set(g.counted)
     assert set(M.STEP_COUNTS) < set(E.StepEvents.__slots__)
 

@@ -315,6 +315,9 @@ def test_describe_reads_the_sixth_shape(engine):
         window_layers=0, window=0, chunk=24, state_layers=3,
         state_shape=(VALUE_HEADS * KEY_DIM, VALUE_DIM),
         tail_shape=(3, channels), tail_dtype="float32")
+    # a key dimension of 16 is not the kernel's tile: the twin, even on
+    # the interpreted paged kernel (tests/test_gated_delta_kernel.py
+    # builds the engine that resolves kernels/gated_delta_scan.py)
     assert (engine.geometry.delta_impl, engine.scan_impl) == ("jnp", "jnp")
     fp = engine._program_fingerprint()
     assert fp["delta_impl"] == "jnp" and fp["arch"] == "qwen3_next"
