@@ -240,7 +240,8 @@ class FFModel:
                             qk_norm_eps: float = 1e-5,
                             num_kv_heads: int = 0, window: int = 0,
                             rotary_interleaved: bool = False,
-                            head_dim: int = 0) -> Tensor:
+                            head_dim: int = 0,
+                            qk_norm_init=None) -> Tensor:
         """`positions` ((batch, seq) int32) with `rotary_theta` > 0
         rotates q and k per head at those absolute positions
         (`rotary_interleaved`: neighbouring pairs, GPT-J's);
@@ -248,7 +249,9 @@ class FFModel:
         `num_kv_heads` < num_heads: query head j reads key/value head
         j // (num_heads / num_kv_heads); `window` > 0: token t sees
         keys t - window + 1 .. t; `head_dim` > 0: heads of that size
-        (num_heads * head_dim wide inside, embed_dim out)."""
+        (num_heads * head_dim wide inside, embed_dim out);
+        `qk_norm_init` (lo, hi): where the QK-norm's scales start
+        (core/initializers.range_init; None: at 1)."""
         inputs = [query, key, value] \
             + ([positions] if positions is not None else [])
         op = MultiHeadAttention(
@@ -256,7 +259,7 @@ class FFModel:
             embed_dim, num_heads, kdim, vdim, dropout, bias, add_bias_kv,
             add_zero_attn, causal, kernel_initializer, use_flash,
             rotary_theta, qk_norm, qk_norm_eps, num_kv_heads, window,
-            rotary_interleaved, head_dim)
+            rotary_interleaved, head_dim, qk_norm_init)
         return self.add_op(op).output
 
     # elementwise unary (model.h exp/relu/sigmoid/tanh/elu/scalar ops)
@@ -437,13 +440,14 @@ class FFModel:
                         d_conv: int = 4, eps: float = 1e-6,
                         dt_range=(1e-3, 1e-1), norm_init=(1.0, 1.0),
                         kernel_initializer="glorot",
+                        allow_neg_eigval: bool = False,
                         name: Optional[str] = None) -> Tensor:
         """The gated delta rule's mixer (ops/gated_delta.py)."""
         from .ops.gated_delta import GatedDeltaNet
         op = GatedDeltaNet(
             self, name or self._fresh_name("delta"), [input], key_heads,
             value_heads, key_dim, value_dim, d_conv, eps, dt_range,
-            norm_init, kernel_initializer)
+            norm_init, kernel_initializer, allow_neg_eigval)
         return self.add_op(op).output
 
     def gated_attention(self, input: Tensor, positions: Tensor,
@@ -482,10 +486,11 @@ class FFModel:
         return self.add_op(op).output
 
     def gated_ffn(self, input: Tensor, hidden_dim: int,
-                  name: Optional[str] = None) -> Tensor:
+                  name: Optional[str] = None,
+                  kernel_initializer="glorot") -> Tensor:
         from .ops.gated import GatedFFN
         op = GatedFFN(self, name or self._fresh_name("gated_ffn"), [input],
-                      hidden_dim)
+                      hidden_dim, kernel_initializer)
         return self.add_op(op).output
 
     def tied_head(self, input: Tensor, table: Tensor,

@@ -11,6 +11,7 @@ from .candle_uno import build_candle_uno
 from .nmt_lstm import build_nmt_lstm, build_nmt_seq2seq
 from .cmdaplus import build_cmdaplus_lm
 from .minicpm_sala import build_minicpm_sala_lm
+from .olmo_hybrid import build_olmo_hybrid_lm
 from .olmoe import build_olmoe_lm
 from .phi4flash import build_phi4flash_lm
 from .qwen3_next import build_qwen3_next_lm
@@ -26,6 +27,7 @@ __all__ = [
     "build_moe_fused",
     "build_cmdaplus_lm",
     "build_minicpm_sala_lm",
+    "build_olmo_hybrid_lm",
     "build_olmoe_lm",
     "build_phi4flash_lm",
     "build_qwen3_next_lm",

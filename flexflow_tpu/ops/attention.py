@@ -48,7 +48,8 @@ class MultiHeadAttention(Op):
                  use_flash=None, rotary_theta: float = 0.0,
                  qk_norm: bool = False, qk_norm_eps: float = 1e-5,
                  num_kv_heads: int = 0, window: int = 0,
-                 rotary_interleaved: bool = False, head_dim: int = 0):
+                 rotary_interleaved: bool = False, head_dim: int = 0,
+                 qk_norm_init=None):
         super().__init__(model, name, inputs)
         # a fourth input, (batch, seq) int32 absolute positions, turns
         # the rotary embedding on (rotary_theta > 0 needs it)
@@ -56,6 +57,9 @@ class MultiHeadAttention(Op):
         self.rotary_theta = float(rotary_theta)
         self.qk_norm = bool(qk_norm)
         self.qk_norm_eps = float(qk_norm_eps)
+        # where the QK-norm's scales start, (lo, hi); None: at 1
+        self.qk_norm_init = None if qk_norm_init is None \
+            else tuple(qk_norm_init)
         self.rotary_interleaved = bool(rotary_interleaved)
         # GROUPED heads: query head j reads key/value head j // group;
         # `window` > 0: token t sees keys t - window + 1 .. t. Both run
@@ -150,10 +154,14 @@ class MultiHeadAttention(Op):
         if self.qk_norm:
             # one RMSNorm over the WHOLE projection (all heads), before
             # the split into heads: OLMoE's q_norm / k_norm
+            start = {}
+            if self.qk_norm_init is not None:
+                from ..core.initializers import range_init
+                start["custom_init"] = range_init(self.qk_norm_init)
             specs["q_norm"] = WeightSpec((h, d), initializer="ones",
-                                         axes=(HEAD, None))
+                                         axes=(HEAD, None), **start)
             specs["k_norm"] = WeightSpec((h, d), initializer="ones",
-                                         axes=(HEAD, None))
+                                         axes=(HEAD, None), **start)
         if self.add_bias_kv:
             # one learned extra kv position (torch MultiheadAttention
             # bias_k/bias_v semantics)

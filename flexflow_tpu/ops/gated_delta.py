@@ -1,7 +1,7 @@
 """The gated delta rule (Gated DeltaNet, arXiv:2412.06464; the
-`linear_attention` layers of Qwen3-Next, `model_type` qwen3_next): a
-matrix state a value head that every token DECAYS, then CORRECTS along
-its key before it adds to it.
+`linear_attention` layers of Qwen3-Next, `model_type` qwen3_next, and of
+Olmo-Hybrid, `model_type` olmo_hybrid): a matrix state a value head that
+every token DECAYS, then CORRECTS along its key before it adds to it.
 
 Per token t of a sequence, h (E), Hk key heads of Dk, Hv = r Hk value
 heads of Dv:
@@ -13,8 +13,12 @@ heads of Dv:
       then silu;
   q, k L2-normalised over their Dk dims (x / sqrt(sum x^2 + 1e-6)),
       each key head serving its r value heads, q <- q / sqrt(Dk);
-  beta = sigmoid(b),  g = -exp(A_log) * softplus(a + dt_bias)  (a value
-      head, f32; g <= 0);
+  beta = beta_scale sigmoid(b),  g = -exp(A_log) * softplus(a + dt_bias)
+      (a value head, f32; g <= 0; beta_scale 1, Qwen3-Next's — or 2
+      where the layer ALLOWS NEGATIVE EIGENVALUES, Olmo-Hybrid's
+      `linear_allow_neg_eigval`: I - beta k k^T then has the eigenvalue
+      1 - beta in (-1, 1) along k and a state can flip sign along a key;
+      every form below takes beta as data);
   S <- exp(g_t) S;  S <- S + k_t (beta_t (v_t - S^T k_t))^T;
   o_t = S^T q_t                            (S is Dk x Dv a head, f32);
   out = (RMSNorm_Dv(o; w) * silu(z)) W_o   (the norm's scale is w, not
@@ -41,7 +45,14 @@ The state is laid out (Hv * Dk, Dv): a value head's Dk x Dv matrix after
 another's, the value dimension on the lanes — the layout a batched
 product over heads and a token's rank-one update both take as it lies
 (with the key dimension leading, XLA re-laid the whole slab of every
-layer out for each block of lanes: PERF.md section 6, PR 49).
+layer out for each block of lanes: PERF.md section 6, PR 49). Where Dv
+is no multiple of the 128 lanes but two heads side by side are (192:
+an f32 row of 192 tiles to 256 in HBM, a third more to hold and to
+move), the heads lie in PAIRS on the lanes, (Hv / 2 * Dk, 2 Dv): head
+2p's matrix in a row's first Dv lanes, head 2p + 1's in its last.
+`state_pack` is the one rule, `state_shape` the slab's rows by it;
+serve/kv_cache.HybridSpec.state_shape, `segmented`, `chunk_blocks` and
+the kernel all ask them.
 """
 
 from __future__ import annotations
@@ -92,9 +103,12 @@ def project(p, h, key_heads: int, ratio: int, dk: int, dv: int):
             ba[..., ratio:].reshape(lead + (hv,)))
 
 
-def gates(p, b, a):
-    """-> (beta, g) (..., Hv) f32."""
+def gates(p, b, a, beta_scale: float = 1.0):
+    """-> (beta, g) (..., Hv) f32; `beta_scale` static, 1.0 or 2.0 (the
+    layer allows negative eigenvalues)."""
     beta = jax.nn.sigmoid(b.astype(F32))
+    if beta_scale != 1.0:
+        beta = beta * beta_scale
     g = -jnp.exp(p["A_log"].astype(F32)) * jax.nn.softplus(
         a.astype(F32) + p["dt_bias"].astype(F32))
     return beta, g
@@ -126,6 +140,72 @@ def gate_and_project(p, o, z, eps: float):
     y = y.reshape(y.shape[:-2] + (-1,))
     return jnp.dot(y, p["wo"].astype(z.dtype),
                    preferred_element_type=F32).astype(z.dtype)
+
+
+# ------------------------------------------------- the state's layout
+LANE_TILE = 128     # an HBM row's f32 lanes: a narrower last dim is padded
+
+
+def state_pack(heads: int, dv: int) -> int:
+    """How many value heads lie side by side on a state row's lanes: 1
+    (a head after another, the rows (Hv * Dk, Dv)) wherever Dv fills
+    whole lane tiles or pairs would not either; 2 where two heads do and
+    one does not."""
+    return 2 if dv % LANE_TILE and (2 * dv) % LANE_TILE == 0 \
+        and heads % 2 == 0 else 1
+
+
+def state_shape(heads: int, dk: int, dv: int) -> tuple:
+    """A sequence's state of one layer as the slab holds it."""
+    pack = state_pack(heads, dv)
+    return (heads // pack * dk, pack * dv)
+
+
+STATE_LAYOUTS = {1: "heads", 2: "head_pairs"}
+
+
+def state_layout(heads: int, dk: int, dv: int) -> dict:
+    """What an engine's record says of the slab: the layout's name, a
+    state's rows and what ONE state of one layer holds of HBM, its rows
+    up to 8 and its lanes up to 128 (the logical bytes where the layout
+    pads nothing)."""
+    rows, lanes = state_shape(heads, dk, dv)
+    return {"delta_state_layout": STATE_LAYOUTS[state_pack(heads, dv)],
+            "delta_state_shape": (rows, lanes),
+            "delta_state_slot_bytes":
+                4 * (-(-rows // 8) * 8) * (-(-lanes // LANE_TILE)
+                                          * LANE_TILE)}
+
+
+def _slab_view(whole, h: int, dk: int, dv: int):
+    """The slab (layers, slots + 1) + state_shape with a state's rows
+    split by head — a view where a head's rows are whole (pack 1). A
+    pair's two heads share their rows' LANES: splitting those is no view
+    of the slab but a copy of all of it (an f32 row of 192 tiles to
+    256), so at pack 2 the slab stays as it lies and ONE state is
+    re-laid where it is read or written (`_heads_of`, `_rows_of`)."""
+    if state_pack(h, dv) == 1:
+        return whole.reshape(whole.shape[:2] + (h, dk, dv))
+    return whole
+
+
+def _heads_of(rows, h: int, dk: int, dv: int):
+    """One state of `_slab_view`'s slab -> (H, Dk, Dv)."""
+    if rows.ndim == 3:
+        return rows
+    pack = state_pack(h, dv)
+    return jnp.moveaxis(rows.reshape(h // pack, dk, pack, dv), 2, 1
+                        ).reshape(h, dk, dv)
+
+
+def _rows_of(s, like):
+    """(H, Dk, Dv) -> one state as `_slab_view`'s slab `like` holds it."""
+    if like.ndim == 5:
+        return s
+    h, dk, dv = s.shape
+    pack = state_pack(h, dv)
+    return jnp.moveaxis(s.reshape(h // pack, pack, dk, dv), 1, 2
+                        ).reshape(like.shape[2:])
 
 
 # ------------------------------------------------------ the recurrence
@@ -325,18 +405,20 @@ def lane_plan(lane_slots, positions, live, starts, live_lanes,
 
 
 def chunk_blocks(q, k, v, g, beta, o, state, layer, lane_slots, positions,
-                 plan: LanePlan, block: int = CHUNK):
+                 plan: LanePlan, block: int = CHUNK, o_rows=None):
     """The chunk-form blocks of `plan`, one after another — a loop of
     as many trips as the step has such blocks, none where it has none —
-    on layer `layer` of the slab `state` (layers, slots + 1, H * Dk,
-    Dv): a block's run resumes from its slot's state (from zero where
+    on layer `layer` of the slab `state` (layers, slots + 1) +
+    `state_shape`: a block's run resumes from its slot's state (from zero where
     the sequence starts at the block's first lane) and leaves its state
     there, where the next block, or the lanes after, take it up; the
-    block's rows of `o` (n * block, H, Dv) are written. q, k, v, g,
+    block's rows of `o` (n * block, H, Dv) are written — through `o_rows`
+    where `o` is held otherwise (the kernel's view of it). q, k, v, g,
     beta: n * block rows. -> (o, the slab)."""
     _, h, dk = q.shape
     dv = v.shape[-1]
-    slab = state.reshape(state.shape[:2] + (h, dk, dv))
+    slab = _slab_view(state, h, dk, dv)
+    heads = lambda rows: _heads_of(rows, h, dk, dv)
 
     def a_block(i, carry):
         slab, o = carry
@@ -345,12 +427,13 @@ def chunk_blocks(q, k, v, g, beta, o, state, layer, lane_slots, positions,
         rows = lambda a: jax.lax.dynamic_slice_in_dim(a, first, block)
         m = plan.among[b][:, None]
         slot = lane_slots[first]
-        s = jnp.where(positions[first] > 0, slab[layer, slot], 0.0)
+        s = jnp.where(positions[first] > 0, heads(slab[layer, slot]), 0.0)
         s, ob = _chunk(s, rows(q), rows(k), rows(v),
                        jnp.where(m, rows(g), 0.0),
                        jnp.where(m, rows(beta), 0.0))
-        return (slab.at[layer, slot].set(s),
-                jax.lax.dynamic_update_slice_in_dim(o, ob, first, 0))
+        return (slab.at[layer, slot].set(_rows_of(s, slab)),
+                jax.lax.dynamic_update_slice_in_dim(
+                    o, ob if o_rows is None else o_rows(ob), first, 0))
 
     slab, o = jax.lax.fori_loop(0, plan.chunks, a_block, (slab, o))
     return o, slab.reshape(state.shape)
@@ -359,10 +442,11 @@ def chunk_blocks(q, k, v, g, beta, o, state, layer, lane_slots, positions,
 def segmented(q, k, v, g, beta, state, lane_slots, positions, live,
               starts, wslots, live_lanes, layer=None, block: int = CHUNK):
     """The recurrence over the step's lanes. q, k (T, H, Dk), v (T, H,
-    Dv), g, beta (T, H), f32; state (slots + 1, H * Dk, Dv) f32, every
-    slot's matrix state (the last row the write sink) — or, with
-    `layer`, the slab of all the layers' (layers, slots + 1, H * Dk,
-    Dv), of which this call reads and writes row `layer` in place;
+    Dv), g, beta (T, H), f32; state (slots + 1,) + `state_shape` f32,
+    every slot's matrix state (the last row the write sink) — or, with
+    `layer`, the slab of all the layers' (layers, slots + 1) +
+    `state_shape`, of which this call reads and writes row `layer` in
+    place;
     `live` (T,) the
     lanes that hold a token, `live_lanes` how many from lane 0 up hold
     one; `starts` / `wslots` the runs (ops/ssm.run_starts /
@@ -385,7 +469,8 @@ def segmented(q, k, v, g, beta, state, lane_slots, positions, live,
     whole = state if layer is not None else state[None]
     at = layer or 0
     sink = whole.shape[1] - 1
-    slab = whole.reshape(whole.shape[:2] + (h, dk, dv))
+    slab = _slab_view(whole, h, dk, dv)
+    heads = lambda rows: _heads_of(rows, h, dk, dv)
 
     def blocks(a, fill=0):
         a = jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1),
@@ -395,7 +480,7 @@ def segmented(q, k, v, g, beta, state, lane_slots, positions, live,
     def resume(slab, s, start, slot, pos):
         """The state a lane works on: its slot's where the lane starts a
         run (zeros where the sequence starts there), else the carried."""
-        own = jnp.where(pos > 0, slab[at, slot], 0.0)
+        own = jnp.where(pos > 0, heads(slab[at, slot]), 0.0)
         return jnp.where(start, own, s)
 
     def trip(carry, x):
@@ -407,7 +492,7 @@ def segmented(q, k, v, g, beta, state, lane_slots, positions, live,
             s, slab, o = c
             s = resume(slab, s, begins[j], slots[j], pos[j])
             s, oj = _token(s, qb[j], kb[j], vb[j], gb[j], bb[j])
-            return (s, slab.at[at, wb[j]].set(s),
+            return (s, slab.at[at, wb[j]].set(_rows_of(s, slab)),
                     jax.lax.dynamic_update_index_in_dim(o, oj, j, 0))
 
         s, slab, o_lanes = jax.lax.fori_loop(
@@ -425,7 +510,8 @@ def segmented(q, k, v, g, beta, state, lane_slots, positions, live,
             lambda s_in: (s_in, jnp.zeros((block, h, dv), F32)), s_in)
         s = jnp.where(as_chunk, s_out, s)
         last = jnp.maximum(count - 1, 0)
-        slab = slab.at[at, jnp.where(as_chunk, wb[last], sink)].set(s)
+        slab = slab.at[at, jnp.where(as_chunk, wb[last], sink)].set(
+            _rows_of(s, slab))
         return (s, slab), jnp.where(as_chunk, o_chunk, o_lanes)
 
     xs = block_forms(starts, live, live_lanes, block=block) + (
@@ -462,7 +548,8 @@ class GatedDeltaNet(Op):
     convolution, gates, the delta rule, output norm, gate and
     projection). `dt_range`: the steps dt_bias starts at (softplus^-1,
     log-uniform); `norm_init` (lo, hi): the output norm's scale starts
-    uniform in it."""
+    uniform in it; `allow_neg_eigval`: beta = 2 sigmoid(b) (the module's
+    docstring)."""
 
     op_type = "gated_delta_net"
 
@@ -470,7 +557,8 @@ class GatedDeltaNet(Op):
                  value_heads: int, key_dim: int, value_dim: int,
                  d_conv: int = 4, eps: float = 1e-6,
                  dt_range=(1e-3, 1e-1), norm_init=(1.0, 1.0),
-                 kernel_initializer="glorot"):
+                 kernel_initializer="glorot",
+                 allow_neg_eigval: bool = False):
         super().__init__(model, name, inputs)
         self.embed_dim = int(inputs[0].shape[-1])
         self.key_heads, self.value_heads = int(key_heads), int(value_heads)
@@ -483,10 +571,14 @@ class GatedDeltaNet(Op):
         self.dt_range = tuple(map(float, dt_range))
         self.norm_init = tuple(norm_init)
         self.kernel_initializer = kernel_initializer
+        self.allow_neg_eigval = bool(allow_neg_eigval)
+        self.beta_scale = 2.0 if self.allow_neg_eigval else 1.0
         self.attrs = {"key_heads": self.key_heads,
                       "value_heads": self.value_heads,
                       "key_dim": self.key_dim, "value_dim": self.value_dim,
                       "d_conv": self.d_conv}
+        if self.allow_neg_eigval:
+            self.attrs["allow_neg_eigval"] = True
 
     @property
     def channels(self) -> int:
@@ -497,6 +589,11 @@ class GatedDeltaNet(Op):
     @property
     def shape_args(self) -> tuple:
         return (self.key_heads, self.ratio, self.key_dim, self.value_dim)
+
+    @property
+    def state_shape(self) -> tuple:
+        """A sequence's state as a serving slab holds it."""
+        return state_shape(self.value_heads, self.key_dim, self.value_dim)
 
     def output_shapes(self):
         return [tuple(self.inputs[0].shape)]
@@ -530,7 +627,7 @@ class GatedDeltaNet(Op):
         u, z, b, a = project(params, x, *self.shape_args)
         u = jax.nn.silu(causal_conv(params, u))
         q, k, v = split_heads(u, *self.shape_args)
-        beta, g = gates(params, b, a)
+        beta, g = gates(params, b, a, self.beta_scale)
         o = chunked(q, k, v, g, beta)
         return [gate_and_project(params, o, z, self.eps)]
 

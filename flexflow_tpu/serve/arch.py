@@ -11,7 +11,7 @@ description reads the parameters of a compiled FFModel through the op
 names its builder wrote, and mirrors those ops' numerics. This module
 imports neither the engine nor the scheduler.
 
-Six clients: `TransformerLM` (models/transformer.build_transformer_lm:
+Seven clients: `TransformerLM` (models/transformer.build_transformer_lm:
 learned positions, LayerNorm, ReLU feed-forward — the OPT block),
 `OLMoE` (models/olmoe.build_olmoe_lm: RMSNorm, rotary attention with
 QK-norm, dropless top-k SwiGLU experts), `Phi4Flash`
@@ -26,7 +26,11 @@ attention with a matrix state a sequence in the others) and `Qwen3Next`
 (models/qwen3_next.build_qwen3_next_lm: the gated delta rule with a
 matrix state and a convolution tail a sequence in three layers of four,
 gated softmax attention in the fourth, top-k experts of which this chip
-holds a share beside a gated shared expert).
+holds a share beside a gated shared expert) and `OlmoHybrid`
+(models/olmo_hybrid.build_olmo_hybrid_lm: the delta rule with negative
+eigenvalues in three layers of four, plain multi-head attention with
+QK-norm and no rotation in the fourth, a dense gated feed-forward, every
+norm AFTER its sub-layer).
 
 What a description answers (docs/serving.md "What a description must
 answer"): the dimensions; per layer the MIXER KIND (`mixer(i)`: one of
@@ -198,6 +202,12 @@ class Description:
     # step adds x + (a + f) once (ServeEngine._mixed_layer). False: each
     # returns x + its branch
     parallel_block = False
+    # a POST-NORM block: no norm before a sub-layer (`norm1` hands x
+    # on), `attn_out` / the mixer's own output projection and `ffn`
+    # (which reads x itself) return their BRANCH alone, and the step
+    # adds x + branch_norm(branch) after each, under `post_norm`
+    # (ServeEngine._mixed_layer). False: each returns x + its branch
+    post_norm = False
     # (params, (1, S) tokens) -> (S, V): a full-sequence forward to use
     # as the engine's naive oracle in place of its own attention-only
     # one (None: the engine's)
@@ -957,7 +967,50 @@ class MiniCPMSala(Description):
         return _graph_logits(self.model, params, tokens, positions=True)
 
 
-class Qwen3Next(Description):
+class _DeltaAndFull:
+    """What the descriptions whose layers are DELTA or FULL answer
+    alike (Qwen3Next, OlmoHybrid): the kinds, what a sequence holds
+    besides pages, the paged layers, and the delta layer's pieces up to
+    its output. They set `kinds`, `delta` (a delta layer's op),
+    `delta_layers`, `full_layers`, `act_dtype`."""
+
+    def mixer(self, i: int) -> str:
+        return self.kinds[i]
+
+    def hybrid_spec(self, chunk: int):
+        from .kv_cache import HybridSpec
+        d = self.delta
+        return HybridSpec(
+            window_layers=0, window=0, chunk=int(chunk),
+            state_layers=len(self.delta_layers),
+            state_shape=d.state_shape,
+            tail_shape=(d.d_conv - 1, d.channels),
+            tail_dtype=str(self.act_dtype))
+
+    @property
+    def paged_layers(self) -> int:
+        return len(self.full_layers)
+
+    def embed(self, params, tokens, positions):
+        return jnp.take(params["tok_embed"]["kernel"], tokens, axis=0,
+                        mode="clip").astype(self.act_dtype)
+
+    # the delta layer, in the pieces the step scopes apart
+    def delta_in(self, params, i, h):
+        """-> (u (T, channels) the convolution's raw input, z (T, Hv,
+        Dv), beta, g (T, Hv) f32), beta in (0, 2) where the layer
+        allows negative eigenvalues."""
+        p = params[f"layer{i}_delta"]
+        u, z, b, a = GD.project(p, h, *self.delta.shape_args)
+        return (u, z) + GD.gates(p, b, a, self.delta.beta_scale)
+
+    def delta_heads(self, u):
+        """The convolution's output after silu -> q, k, v by value
+        head, f32."""
+        return GD.split_heads(u, *self.delta.shape_args)
+
+
+class Qwen3Next(_DeltaAndFull, Description):
     """The build_qwen3_next_lm block (models/qwen3_next.py holds the
     equations, ops/gated_delta.py and ops/gated_attention.py the
     mixers'). Served by the mixed step on one device.
@@ -1040,35 +1093,14 @@ class Qwen3Next(Description):
         self.shared_bytes = n * self.shared_experts * self.expert_bytes
         self._expert_weights = jax.ShapeDtypeStruct(w.shape, w.dtype)
 
-    def mixer(self, i: int) -> str:
-        return self.kinds[i]
-
     def expert_impl(self, lanes: int):
         rows = jax.ShapeDtypeStruct(
             (lanes * self.experts_per_token, self.hidden), self.act_dtype)
         return expert_impl(rows, self._expert_weights, **self.kernels)
 
-    def hybrid_spec(self, chunk: int):
-        from .kv_cache import HybridSpec
-        d = self.delta
-        return HybridSpec(
-            window_layers=0, window=0, chunk=int(chunk),
-            state_layers=len(self.delta_layers),
-            state_shape=(d.value_heads * d.key_dim, d.value_dim),
-            tail_shape=(d.d_conv - 1, d.channels),
-            tail_dtype=str(self.act_dtype))
-
     @property
     def kv_heads(self) -> int:
         return self._kv_heads
-
-    @property
-    def paged_layers(self) -> int:
-        return len(self.full_layers)
-
-    def embed(self, params, tokens, positions):
-        return jnp.take(params["tok_embed"]["kernel"], tokens, axis=0,
-                        mode="clip").astype(self.act_dtype)
 
     def norm1(self, params, i, x):
         return GA.rms_norm0(x, params[f"layer{i}_norm1"]["scale"],
@@ -1087,19 +1119,6 @@ class Qwen3Next(Description):
         p = params[f"layer{i}_attn"]
         return x + jnp.einsum("...hd,hde->...e", o,
                               p["wo"].astype(o.dtype))
-
-    # the delta layer, in the pieces the step scopes apart
-    def delta_in(self, params, i, h):
-        """-> (u (T, channels) the convolution's raw input, z (T, Hv,
-        Dv), beta, g (T, Hv) f32)."""
-        p = params[f"layer{i}_delta"]
-        u, z, b, a = GD.project(p, h, *self.delta.shape_args)
-        return (u, z) + GD.gates(p, b, a)
-
-    def delta_heads(self, u):
-        """The convolution's output after silu -> q, k, v by value
-        head, f32."""
-        return GD.split_heads(u, *self.delta.shape_args)
 
     def delta_out(self, params, i, o, z, x):
         return x + GD.gate_and_project(params[f"layer{i}_delta"], o, z,
@@ -1132,14 +1151,128 @@ class Qwen3Next(Description):
         return _graph_logits(self.model, params, tokens, positions=True)
 
 
-SHAPES = (TransformerLM, Qwen3Next, OLMoE, Phi4Flash, CommandAPlus,
-          MiniCPMSala)
+class OlmoHybrid(_DeltaAndFull, Description):
+    """The build_olmo_hybrid_lm block (models/olmo_hybrid.py holds the
+    equations, ops/gated_delta.py the delta rule's). Served by the mixed
+    step on one device.
+
+    What it pages: the FULL layers' K and V, a key/value head a query
+    head (`head_dim` 128 as published, no rotation unless the builder
+    was given a theta). What a sequence holds besides (`hybrid_spec`):
+    for each DELTA layer an f32 matrix state, laid out as
+    ops/gated_delta.state_shape says (heads in pairs on the lanes at the
+    published 96 x 192), and a convolution tail of d_conv - 1 rows over
+    the q, k and v channels; no ring. A POST-NORM block: `norm1` is the
+    identity and every branch comes back alone (`post_norm`)."""
+
+    kind = "olmo_hybrid"
+    builder = "build_olmo_hybrid_lm"
+    reads = ("tok_embed", "lm_head", "layer0_delta", "layer0_post_norm1",
+             "layer0_mlp", "final_norm")
+    post_norm = True
+    _state = Qwen3Next._state
+    refused = {
+        "tp": "single-device: the matrix states and the heads are not "
+              "split over a mesh",
+        "adapters": "no adapter pool for the gated mixers and the gated "
+                    "feed-forward",
+        **{path: Qwen3Next.refused[path] for path in (
+            "speculation", "prefix_cache", "host_tier", "handoff")},
+    }
+
+    def __init__(self, model, ops):
+        self.model = model
+        self.vocab_size = ops["tok_embed"].num_entries
+        self.layer_norm = True
+        n = 0
+        while f"layer{n}_post_norm1" in ops:
+            n += 1
+        self.num_layers = n
+        self.kinds = [DELTA if f"layer{i}_delta" in ops else FULL
+                      for i in range(n)]
+        self.delta_layers = [i for i, k in enumerate(self.kinds)
+                             if k == DELTA]
+        self.full_layers = [i for i, k in enumerate(self.kinds)
+                            if k == FULL]
+        attns = [ops.get(f"layer{i}_attn") for i in self.full_layers]
+        if not (attns and all(
+                a is not None and a.causal and a.qk_norm and not a.window
+                and a.num_kv_heads == a.num_heads for a in attns)):
+            raise ValueError(
+                "ServeEngine reads a build_olmo_hybrid_lm-shaped model: "
+                "delta AND causal multi-head attention layers with "
+                "QK-norm, each sub-layer under a post-norm")
+        attn = attns[0]
+        self.delta = ops["layer0_delta"]
+        self.num_heads, self.head_dim = attn.num_heads, attn.head_dim
+        self.rope_theta = attn.rotary_theta     # 0: no rotation
+        self.hidden = attn.embed_dim
+        self.ln_eps = ops["layer0_post_norm1"].eps
+        self.act_dtype = jnp.dtype(ops["tok_embed"].out_dtype)
+        self.ff_dim = ops["layer0_mlp"].hidden_dim
+        # no table is sized by them: the positions served are the graph's
+        self.max_positions = int(ops["tok_embed"].inputs[0].shape[1])
+
+    def norm1(self, params, i, x):
+        """None stands before a sub-layer: it reads the stream itself."""
+        return x
+
+    def branch_norm(self, params, i, which: int, y):
+        """The norm on a sub-layer's output: `which` 1 the mixer's, 2
+        the feed-forward's."""
+        return rms_norm(y, params[f"layer{i}_post_norm{which}"]["scale"],
+                        self.ln_eps)
+
+    def qkv(self, params, i, h, positions, lora=None):
+        """h (T, E) -> q, k, v (T, H, D): the projections, the RMS norm
+        of q and k over the whole projection; rotated at the lanes'
+        positions only where the builder was given a theta (else the
+        positions are not read: the delta layers alone carry order)."""
+        p = params[f"layer{i}_attn"]
+        q, k, v = _project(p, h)
+        q = rms_norm(q, p["q_norm"], self.ln_eps)
+        k = rms_norm(k, p["k_norm"], self.ln_eps)
+        if self.rope_theta > 0:
+            q = rotary(q, positions, self.rope_theta)
+            k = rotary(k, positions, self.rope_theta)
+        return q, k, v
+
+    def attn_out(self, params, i, o, x, psum_axis=None, lora=None):
+        """The attention BRANCH alone (post_norm)."""
+        p = params[f"layer{i}_attn"]
+        return jnp.einsum("...hd,hde->...e", o, p["wo"].astype(o.dtype))
+
+    def delta_out(self, params, i, o, z, x):
+        """The delta BRANCH alone (post_norm)."""
+        return GD.gate_and_project(params[f"layer{i}_delta"], o, z,
+                                   self.ln_eps)
+
+    def ffn(self, params, i, x, live=None, psum_axis=None, lora=None):
+        """The feed-forward BRANCH alone, of the stream itself; one
+        scope, `ffn`. -> (f, None: no expert counts)."""
+        with jax.named_scope("ffn"):
+            return gated_ffn(params[f"layer{i}_mlp"], x), None
+
+    def final_norm(self, params, x):
+        return rms_norm(x, params["final_norm"]["scale"], self.ln_eps)
+
+    def head(self, params, x):
+        return _dense(params["lm_head"], self.final_norm(params, x))
+
+    def forward_logits(self, params, tokens):
+        return _graph_logits(self.model, params, tokens,
+                             positions=self.rope_theta > 0)
+
+
+SHAPES = (TransformerLM, OlmoHybrid, Qwen3Next, OLMoE, Phi4Flash,
+          CommandAPlus, MiniCPMSala)
 
 
 def describe(model):
     """The description of a compiled FFModel, chosen by the op names
     its builder wrote: the first of SHAPES whose names are all there
-    (Qwen3Next's before OLMoE's, whose names it has too)."""
+    (Qwen3Next's before OLMoE's, whose names it has too; OlmoHybrid's
+    are nobody else's)."""
     ops = {op.name: op for op in model.ops}
     for cls in SHAPES:
         if all(n in ops for n in cls.reads):

@@ -1144,9 +1144,12 @@ class ServeEngine:
         with `shared_experts` where there are some). A PARALLEL block
         (arch.parallel_block) has the one norm: the feed-forward reads
         `h` too, both branches come back alone and `residual` adds them
-        to x once. `la` is the lanes' adapter rows of this layer (None:
-        no adapters), `ad_s` their scales; `memory` what the memory
-        layer's body returned for the layers after it."""
+        to x once; a POST-NORM block (arch.post_norm) has no `ln`: both
+        branches come back alone too, and each is normed under
+        `post_norm` and added where it stood. `la` is the lanes' adapter
+        rows of this layer (None: no adapters), `ad_s` their scales;
+        `memory` what the memory layer's body returned for the layers
+        after it."""
         scope = jax.named_scope
         arch = self.arch
         with scope("ln"):
@@ -1161,6 +1164,16 @@ class ServeEngine:
                                  psum_axis=tp_axis)
             with scope("residual"):
                 return x + (a + f), pool, memory, counts
+        if arch.post_norm:
+            # the norm stands AFTER a sub-layer: each branch comes back
+            # alone and is normed before it is added
+            with scope("post_norm"):
+                x = x + arch.branch_norm(params, i, 1, a)
+            f, counts = arch.ffn(params, i, x, live=lanes.ffn_live,
+                                 psum_axis=tp_axis)
+            with scope("post_norm"):
+                return (x + arch.branch_norm(params, i, 2, f), pool,
+                        memory, counts)
         x, counts = arch.ffn(
             params, i, a, live=lanes.ffn_live, psum_axis=tp_axis,
             lora=None if la is None else
@@ -1658,6 +1671,9 @@ class ServeEngine:
         rec["scan_impl"] = self.scan_impl
         rec["expert_impl"] = self.expert_impl
         rec.update(self._delta_impl)
+        # ... and how its slab holds a state (not in the fingerprint:
+        # the pool's shapes are)
+        rec.update(self.geometry.delta_state)
         self.boot_stats = rec
         if self.programs.cache_dir and self.programs._dirty:
             # read-through write-back: the first (cold) engine over

@@ -83,6 +83,10 @@ class Geometry:
     # the keys of `step_counts` that the `dispatch` span takes beside
     # StepEvents: LIVE_COUNTS, and the other two where they apply
     counted: Tuple[str, ...]
+    # how the delta layers' slab holds a state (ops/gated_delta.
+    # state_layout: the layout's name, a state's rows, its resident
+    # bytes); {}: no such layer
+    delta_state: dict = dataclasses.field(default_factory=dict)
 
     @property
     def attn_kw(self) -> dict:
@@ -129,6 +133,7 @@ def geometry(arch, cfg: KVCacheConfig, *, width: int, attn_impl: str,
     # own, `delta_impl`: kernels/gated_delta_scan.py where it takes the
     # step's shape, else its twin (ops/gated_delta.segmented)
     scan_impl = delta_impl = None
+    delta_state = {}
     if hyb is not None and hyb.state_layers:
         scan_impl = attn_impl if SSM in kinds and ssm_scan.supported(
             width, *hyb.state_shape) else JNP
@@ -136,6 +141,8 @@ def geometry(arch, cfg: KVCacheConfig, *, width: int, attn_impl: str,
         d = arch.delta
         delta_impl = attn_impl if gated_delta_scan.supported(
             width, d.value_heads, d.key_dim, d.value_dim) else JNP
+        delta_state = gated_delta.state_layout(
+            d.value_heads, d.key_dim, d.value_dim)
     block_pages = max(1, block_kv // cfg.page_size)
     # a model that SELECTS its context (arch.dense_len) walks pages in
     # the paged kernel only for its lanes under dense_len: the list is
@@ -175,7 +182,7 @@ def geometry(arch, cfg: KVCacheConfig, *, width: int, attn_impl: str,
         dense_pages=dense_pages, attn_max_items=attn_max_items,
         window_max_items=window_max_items, attn_calls=attn_calls(arch),
         rings=ring_tables(cfg) if cfg.ring_pages else None,
-        counted=counted)
+        counted=counted, delta_state=delta_state)
 
 
 def walked(g: Geometry, page_tables, positions, lane_lens, xp=np):

@@ -527,3 +527,120 @@ def test_a_packed_pool_s_paged_calls_read_their_leaf_where_it_lies(
         a.size * a.dtype.itemsize for a in jax.tree.leaves(pool))
     slab = min(2 * p * slot * hd for n, p, slot, hd in leaves if n > 1)
     assert m.temp_size_in_bytes < slab, (m.temp_size_in_bytes, slab)
+
+
+def test_delta_lane_kernel_compiles_at_olmo_hybrid_s_heads(topo, as_tpu):
+    """The delta rule's lanes at Olmo-Hybrid's served shape (PR 52) —
+    544 lanes, 30 heads of 96 x 192, 33 slot rows, twelve layers in one
+    donated slab laid out in head PAIRS, (1440, 384) a state: Mosaic
+    takes the kernel with q's heads at sublane 32 of the shared tile,
+    the transposed tile's first 96 rows and a pair's two heads selected
+    by lane; it is called on either side of the chunk-form blocks' loop,
+    the slab is moved on where it lies (no copy of it, of a layer's row
+    or of a re-laid view of either: splitting a row's 384 lanes into 2
+    x 192 would copy all of it), and the temporaries are the lanes' own
+    rows."""
+    import re
+
+    from flexflow_tpu.kernels import gated_delta_scan as kd
+    from flexflow_tpu.ops import gated_delta as gd
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    t, rows, h, dk, dv, layers = 544, 33, 30, 96, 192, 12
+    assert kd.supported(t, h, dk, dv)
+    state = gd.state_shape(h, dk, dv)
+    assert state == (1440, 384)
+
+    def call(q, k, v, g, beta, slab, slots, pos, live, starts, n):
+        plan = gd.lane_plan(slots, pos, live, starts, n)
+        return kd.gated_delta_scan(q, k, v, g, beta, slab, 4, slots, pos,
+                                   plan)
+
+    lane, flag = sds((t,), jnp.int32), sds((t,), jnp.bool_)
+    compiled = jax.jit(call, donate_argnums=(5,)).lower(
+        sds((t, h, dk)), sds((t, h, dk)), sds((t, h, dv)), sds((t, h)),
+        sds((t, h)), sds((layers, rows) + state), lane, lane, flag, flag,
+        sds((), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 2 and all("gated_delta_scan" in c for c in calls)
+    assert len(re.findall(r"\bwhile\(", text)) == 1
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"\b(copy|copy-start|reshape)\(", line)
+             and re.search(r"= f32\[(12,)?(1,)?33,", line)]
+    assert not moved, moved
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 4 * layers * rows * h * dk * dv
+    assert m.temp_size_in_bytes < 2**26, m.temp_size_in_bytes
+
+
+def test_olmo_hybrid_mixed_step_compiles_within_its_memory_plan(topo,
+                                                                as_tpu):
+    """Olmo-Hybrid's mixed step at its served widths (544 lanes of 3840;
+    a delta layer of 30 heads of 96 x 192 beside a full layer of 30 / 30
+    heads of 128 on a head-packed pool of 4,916 pages, 15,360 B a token
+    a layer; the dense feed-forward 11,008 wide; 32,768 positions, 32
+    slots; ONE delta and ONE full layer and a small vocabulary, so the
+    parameters are quick to make; PR 52): it compiles for a v5e with
+    the paged kernel at a head count that is no power of two, reading
+    its leaf where it lies, and the delta rule's lanes in their kernel;
+    the pool — pages, states, tails — is updated in place with no copy
+    of the state slab, and the step's temporaries stay under ONE state
+    slab of the twelve-layer configuration's (876 MB), which holds 13
+    GiB of weights and cache."""
+    import re
+    from flexflow_tpu import FFConfig
+    from flexflow_tpu.config import CompMode
+    from flexflow_tpu.models.olmo_hybrid import build_olmo_hybrid_lm
+    from flexflow_tpu.serve import ServeEngine, mixers
+    from flexflow_tpu.serve.kv_cache import HybridPool
+    cfg = FFConfig(batch_size=1, kv_page_size=16, kv_num_pages=4916,
+                   serve_max_seqs=32, serve_prefill_budget=512,
+                   serve_spec_decode=False, serve_prefix_cache=False,
+                   compute_dtype="bfloat16", param_dtype="bfloat16",
+                   kv_dtype="bfloat16")
+    lm = build_olmo_hybrid_lm(
+        cfg, vocab_size=2048, max_seq_len=32768, num_layers=2,
+        types=["linear_attention", "full_attention"])
+    lm.compile(comp_mode=CompMode.INFERENCE)
+    engine = ServeEngine(lm)
+    assert (engine.attn_impl, engine.geometry.delta_impl) == ("pallas",
+                                                              "pallas")
+    assert (engine.mixed_width, engine.head_rows) == (544, 32)
+    assert engine.geometry.delta_state["delta_state_slot_bytes"] == 2211840
+    assert mixers.paged_calls(engine.geometry) == {
+        "paged_calls": 1, "paged_calls_in_place": 1}
+    one = SingleDeviceSharding(topo.devices[0])
+    c = engine.cache_cfg
+    assert (c.pages_per_seq, c.cache_bytes_per_token, c.packed_heads) == (
+        2048, 15360, True)
+    pool = jax.eval_shape(lambda: HybridPool.alloc(c))
+    assert pool.state.shape == (1, 33, 1440, 384)
+    assert pool.full.k.shape == (1, 4916, 16, 3840)
+    lane = jax.ShapeDtypeStruct((544,), jnp.int32, sharding=one)
+    rows = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one)
+    tables = jax.ShapeDtypeStruct((c.max_seqs, c.pages_per_seq), jnp.int32,
+                                  sharding=one)
+    compiled = jax.jit(engine._mixed_impl, donate_argnums=(1,)).lower(
+        _sds(engine._step_params, one), _sds(pool, one), lane, lane, lane,
+        lane, tables, lane, lane, rows, lane, rows).compile()
+    engine.close()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 3
+    assert sum("paged_ragged_v2" in c for c in calls) == 1
+    assert sum("gated_delta_scan" in c for c in calls) == 2
+    loops = [line for line in text.splitlines()
+             if re.search(r"\bwhile\(", line) and "delta_scan" in line]
+    assert len(loops) == 1 and "conditional(" not in text
+    m = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(pool))
+    assert m.alias_size_in_bytes >= pool_bytes
+    assert m.temp_size_in_bytes < 12 * 33 * 2211840, m.temp_size_in_bytes
+    # neither the state slab nor the pages' leaf is copied or re-laid
+    assert not re.search(r"= f32\[1,33,\S* (copy|reshape)\(", text)
+    assert not re.search(r"= bf16\[(1,)?4916,16,3840\]\S* copy\(", text)

@@ -248,9 +248,14 @@ def test_value_heads_are_independent(heads):
     (576, 72, 128, 128, False),     # ... and would pass it
     (32, 8, 128, 128, True),        # this file's engine
     (28, 4, 16, 16, False),         # tests/test_qwen3_next.py's
-    (576, 32, 64, 128, False),      # a key dimension under the tile
-    (576, 32, 128, 64, False),      # a value dimension under the lanes
-    (576, 12, 128, 128, False),     # heads off the sublane tile
+    (576, 32, 64, 128, True),       # a key dimension under the tile (PR 52)
+    (576, 32, 128, 64, True),       # two heads side by side fill the lanes
+    (576, 12, 128, 128, True),      # q's heads at the next sublane tile
+    (544, 30, 96, 192, True),       # Olmo-Hybrid: all three at once
+    (544, 30, 100, 192, False),     # a key dimension off the sublanes
+    (544, 30, 96, 96, False),       # pairs of 192 lanes fill no tile
+    (544, 15, 96, 192, False),      # an odd head count has no pairs
+    (576, 32, 256, 128, False),     # a key dimension past the tile
     (4096, 32, 128, 128, False),    # exp(g) and beta past SMEM
 ])
 def test_what_the_kernel_takes(lanes, heads, dk, dv, ok):

@@ -424,7 +424,8 @@ def test_the_older_descriptions_keep_their_programs(which):
         eng._step_params, eng._device_pool(), lane, lane, lane, lane,
         jnp.zeros((c.max_seqs, c.pages_per_seq), jnp.int32), lane,
         lane + 1, rows, lane - 1, rows).as_text(debug_info=True)
-    assert "delta_" not in text and "attn_gate" not in text
+    # scope paths, not the file names a cached trace may carry
+    assert "/delta_" not in text and "attn_gate" not in text
     fp = eng._program_fingerprint()
     assert "delta_impl" not in fp and eng.geometry.delta_impl is None
     assert list(fp)[-3:] == ["attn_impl", "scan_impl", "expert_impl"]

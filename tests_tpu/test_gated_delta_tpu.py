@@ -1,12 +1,15 @@
 """The delta rule's lane kernel COMPILED on the chip (kernels/
 gated_delta_scan.py, PR 51) at Qwen3-Next's served shape — 576 lanes,
 65 slot rows of 4096 x 128 f32 (32 value heads of 128 x 128), six
-layers in one slab: parity with its jnp twin (ops/gated_delta.py::
+layers in one slab — or, with DELTA_SHAPE=olmohybrid in the
+environment (PR 52), at Olmo-Hybrid's: 544 lanes, 30 heads of 96 x 192,
+their states in head pairs, 1440 x 384: parity with its jnp twin (ops/gated_delta.py::
 segmented) over decode lanes, a short tail and a run that goes lanes,
 chunk-form blocks, lanes; the time of a layer's call with 8 / 24 / 48
 one-lane runs, with one 15-lane tail and with nothing live, beside the
 twin's; and where the lane form and the chunk form cross. Run with `-s`
-to see the table; it is also written to chiprun_out/gated_delta_tpu.json.
+to see the table; it is also written to chiprun_out/gated_delta_tpu.json
+(gated_delta_tpu.olmohybrid.json at the other shape).
 """
 
 import json
@@ -22,7 +25,10 @@ from flexflow_tpu.kernels import gated_delta_scan as K
 from flexflow_tpu.ops import gated_delta as GD
 from flexflow_tpu.ops import ssm
 
-T, SLOTS, H, DK, DV, LAYERS = 576, 64, 32, 128, 128, 6
+SHAPE = os.environ.get("DELTA_SHAPE", "qwen3next")
+T, SLOTS, H, DK, DV, LAYERS = {
+    "qwen3next": (576, 64, 32, 128, 128, 6),
+    "olmohybrid": (544, 64, 30, 96, 192, 6)}[SHAPE]
 STATE_BYTES = H * DK * DV * 4
 HBM_GBS = 819.0
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -60,7 +66,8 @@ def _decode(n):
 
 def _slab(seed):
     return jax.random.normal(jax.random.key(seed),
-                             (LAYERS, SLOTS + 1, H * DK, DV), jnp.float32)
+                             (LAYERS, SLOTS + 1) + GD.state_shape(H, DK, DV),
+                             jnp.float32)
 
 
 def _kernel(slab, layer, q, k, v, g, beta, slots, pos, live, starts,
@@ -150,7 +157,8 @@ def _pass_alone(slab, layer, q, k, v, g, beta, slots, pos, live, starts,
 def test_a_layer_s_time_by_live_lanes_and_where_the_forms_cross():
     args = _inputs(0)
     table = {"device": jax.devices()[0].device_kind,
-             "shape": [T, SLOTS + 1, H * DK, DV], "layers": LAYERS}
+             "shape": [T, SLOTS + 1, *GD.state_shape(H, DK, DV)],
+             "heads": [H, DK, DV], "layers": LAYERS}
     rows = {"nothing_live": [], "decode_8": _decode(8),
             "decode_24": _decode(24), "decode_48": _decode(48),
             "a_tail_of_15": [(7, 640, 15)]}
@@ -190,7 +198,9 @@ def test_a_layer_s_time_by_live_lanes_and_where_the_forms_cross():
     table["one_run_of"] = cross
     out = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out, exist_ok=True)
-    with open(os.path.join(out, "gated_delta_tpu.json"), "w") as f:
+    name = "gated_delta_tpu.json" if SHAPE == "qwen3next" \
+        else f"gated_delta_tpu.{SHAPE}.json"
+    with open(os.path.join(out, name), "w") as f:
         json.dump(table, f, indent=1)
     # the loop it replaces took 80 us a lane
     assert table["decode_24"]["kernel_us_a_lane"] < 20.0, table
