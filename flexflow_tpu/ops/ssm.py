@@ -118,15 +118,29 @@ def run_write_slots(starts, live, lane_slots, sink: int):
     return jnp.where(live & ends, lane_slots, sink)
 
 
-def segmented_conv(p, u, tail, lane_slots, positions, offsets, wslots):
+def run_tail_lanes(wslots, slots: int):
+    """(slots,) int32: for each slot the lane whose last d_conv - 1
+    inputs replace the slot's tail — the last lane `wslots` aims at it
+    (a run's last live one) — or -1 where no run of the slot ends live
+    in this step (no scatter: a compare of every lane with every
+    slot)."""
+    lane = jnp.arange(wslots.shape[0], dtype=jnp.int32)
+    slot = jnp.arange(slots, dtype=jnp.int32)
+    return jnp.max(jnp.where(wslots[None, :] == slot[:, None],
+                             lane[None, :], -1), axis=1)
+
+
+def segmented_conv(p, u, tail, lane_slots, positions, offsets, tail_lanes):
     """The convolution over the step's lanes. u (T, d_inner) raw
     projections; tail (slots + 1, (d_conv - 1) * d_inner) each slot's
-    last raw inputs, flat (row `slots` is the write sink); a lane whose run offset
+    last raw inputs, flat (row `slots`, the other slabs' write sink, is
+    left as it lies); a lane whose run offset
     is under d_conv - 1 reads what it lacks from its slot's tail, or
-    zeros where the sequence starts inside the run. `wslots` (T,): the
-    slot whose tail the lane's last d_conv - 1 inputs replace (a run's
-    last live lane), else the sink. -> (conv output f32 (T, d_inner),
-    tail)."""
+    zeros where the sequence starts inside the run. `tail_lanes`
+    (slots,): the lane whose last d_conv - 1 inputs replace the slot's
+    tail (`run_tail_lanes`), -1 where the tail stays: the write-back
+    follows the runs, a gather of at most `slots` rows and a select, not
+    the lanes. -> (conv output f32 (T, d_inner), tail)."""
     w = p["conv_w"].astype(F32)
     k = w.shape[0]
     t = u.shape[0]
@@ -148,8 +162,12 @@ def segmented_conv(p, u, tail, lane_slots, positions, offsets, wslots):
         y = y + p["conv_b"].astype(F32)
     for j, h in zip(range(k - 1, 0, -1), hist):
         y = y + h.astype(F32) * w[k - 1 - j]
-    new = jnp.concatenate(hist[1:] + [u], axis=1).astype(tail.dtype)
-    return y, tail.at[wslots].set(new)
+    slots = tail_lanes.shape[0]
+    src = jnp.maximum(tail_lanes, 0)
+    new = jnp.concatenate([h[src] for h in hist[1:] + [u]],
+                          axis=1).astype(tail.dtype)
+    kept = jnp.where((tail_lanes >= 0)[:, None], new, tail[:slots])
+    return y, tail.at[:slots].set(kept)
 
 
 def segmented_scan(p, u, dt, b, c, state, lane_slots, positions, starts,

@@ -228,6 +228,10 @@ class Lanes(NamedTuple):
     offsets: Any = None
     wslots: Any = None
     live_lanes: Any = None
+    # for each slot the lane whose inputs replace its convolution tail,
+    # -1 where none does (ops/ssm.run_tail_lanes); None: no layer holds
+    # a tail
+    tail_lanes: Any = None
     # the rings' page table, the ring page each lane writes and the
     # window layers' work list; None: no window layer
     rings: Any = None
@@ -277,6 +281,9 @@ def step_lanes(g: Geometry, positions, write_pages, write_offs,
                 wslots=ssm.run_write_slots(starts, live, lane_slots,
                                            c.max_seqs),
                 live_lanes=jnp.max(jnp.where(live, lane, 0)))
+            if c.hybrid.tail_shape[0] > 0:
+                made["tail_lanes"] = ssm.run_tail_lanes(
+                    made["wslots"], c.max_seqs)
             if g.delta_impl not in (None, JNP):
                 made["delta_plan"] = gated_delta.lane_plan(
                     lane_slots, positions, live, starts, made["live_lanes"])
@@ -393,7 +400,7 @@ def _state_space(g, params, i, x, h, lanes, pool, memory, lora=None,
     with scope("ssm_conv"):
         u, tail = ssm.segmented_conv(
             p, u, pool.tail[j], slots, positions, lanes.offsets,
-            lanes.wslots)
+            lanes.tail_lanes)
         u = jax.nn.silu(u)
     with scope("ssm_proj"):
         dt, b, c = arch.ssm_scan_inputs(params, i, u)
@@ -471,7 +478,7 @@ def _delta(g, params, i, x, h, lanes, pool, memory, lora=None,
     with scope("delta_conv"):
         u, tail = ssm.segmented_conv(
             params[f"layer{i}_delta"], u, pool.tail[j], lanes.lane_slots,
-            lanes.positions, lanes.offsets, lanes.wslots)
+            lanes.positions, lanes.offsets, lanes.tail_lanes)
         q, k, v = arch.delta_heads(jax.nn.silu(u))
     with scope("delta_scan"):
         # the kernel keeps an f32 slab in place; a slab of another

@@ -14,6 +14,7 @@ workers that all import this file.
 """
 
 import functools
+import re
 import types
 
 import jax
@@ -367,6 +368,27 @@ def test_minicpm_sala_mixed_step_compiles_within_its_memory_plan(topo,
     assert not re.search(r"= bf16\[2,32769,16,128\]\S* copy\(", text)
 
 
+def _loops(text):
+    """The compiled step's `while(` lines but the binary searches'
+    (`jnp.searchsorted` lowers to a loop of log2 trips: the work list's
+    and the expert dispatch's, a few microseconds each)."""
+    return [line for line in text.splitlines()
+            if re.search(r"\bwhile\(", line) and "searchsorted" not in line]
+
+
+def _scope_of(line):
+    """The named scope a compiled instruction stands under: the last
+    but one part of its `op_name`."""
+    return re.search(r'op_name="([^"]*)"', line).group(1).split("/")[-2]
+
+
+def _loop_bodies(text):
+    """The text of every computation a `while(` names as its body or
+    condition."""
+    names = re.findall(r"(?:body|condition)=%([\w.\-]+)", text)
+    return [text[text.index(f"\n%{n} ("):].split("\n}\n")[0] for n in names]
+
+
 def test_qwen3_next_mixed_step_compiles_within_its_memory_plan(topo, as_tpu):
     """Qwen3-Next's mixed step at its served widths (576 lanes of 2048;
     a delta layer of 16 key / 32 value heads of 128 beside a gated
@@ -420,9 +442,10 @@ def test_qwen3_next_mixed_step_compiles_within_its_memory_plan(topo, as_tpu):
     assert sum("grouped_ffn" in c for c in calls) == 2
     assert sum("gated_delta_scan" in c for c in calls) == 2
     # the chunk-form blocks' loop, of no trip where a step has none
-    loops = [line for line in text.splitlines()
-             if re.search(r"\bwhile\(", line) and "delta_scan" in line]
-    assert len(loops) == 1 and "conditional(" not in text
+    assert [_scope_of(line) for line in _loops(text)] == ["delta_scan"]
+    assert "conditional(" not in text
+    # the convolution's tail is written back with no scatter (PR 53)
+    assert not re.search(r"scatter\(\S*bf16\[(1,)?65,24576\]", text)
     assert "ragged-dot" not in text
     m = compiled.memory_analysis()
     pool_bytes = sum(a.size * a.dtype.itemsize
@@ -633,9 +656,14 @@ def test_olmo_hybrid_mixed_step_compiles_within_its_memory_plan(topo,
     assert len(calls) == 3
     assert sum("paged_ragged_v2" in c for c in calls) == 1
     assert sum("gated_delta_scan" in c for c in calls) == 2
-    loops = [line for line in text.splitlines()
-             if re.search(r"\bwhile\(", line) and "delta_scan" in line]
-    assert len(loops) == 1 and "conditional(" not in text
+    # the step's ONLY loop is the chunk-form blocks' (the convolution's
+    # tail write-back was one of 544 one-row updates until PR 53), and
+    # nothing scatters into the tail or updates it inside a loop
+    assert [_scope_of(line) for line in _loops(text)] == ["delta_scan"]
+    assert "conditional(" not in text
+    assert not re.search(r"scatter\(\S*bf16\[(1,)?33,34560\]", text)
+    for body in _loop_bodies(text):
+        assert "bf16[33,34560]" not in body and "bf16[1,33,34560]" not in body
     m = compiled.memory_analysis()
     pool_bytes = sum(a.size * a.dtype.itemsize
                      for a in jax.tree.leaves(pool))
@@ -644,3 +672,34 @@ def test_olmo_hybrid_mixed_step_compiles_within_its_memory_plan(topo,
     # neither the state slab nor the pages' leaf is copied or re-laid
     assert not re.search(r"= f32\[1,33,\S* (copy|reshape)\(", text)
     assert not re.search(r"= bf16\[(1,)?4916,16,3840\]\S* copy\(", text)
+
+
+@pytest.mark.parametrize("lanes,channels,slots", [
+    (544, 11520, 32),      # Olmo-Hybrid: XLA had EXPANDED the scatter here
+    (576, 8192, 64),       # Qwen3-Next: a native scatter( until PR 53
+    (576, 5120, 64),       # Phi-4-mini-flash: likewise
+])
+def test_the_convolution_s_tail_write_back_is_no_loop_and_no_scatter(
+        topo, lanes, channels, slots):
+    """`ops/ssm.py::segmented_conv` alone at the three served shapes,
+    its tail donated: the write-back follows the runs — a gather of at
+    most `slots` rows and a select, one static update of the tail in
+    place — so the compiled text holds neither a `while(` (the 544
+    one-row `dynamic-update-slice`s a layer of Olmo-Hybrid's step, 43 ms
+    of 123.5; PR 53) nor a `scatter(`."""
+    from flexflow_tpu.ops import ssm
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one)
+    lane = sds((lanes,), jnp.int32)
+    compiled = jax.jit(ssm.segmented_conv, donate_argnums=(2,)).lower(
+        {"conv_w": sds((4, channels), jnp.bfloat16)},
+        sds((lanes, channels), jnp.bfloat16),
+        sds((slots + 1, 3 * channels), jnp.bfloat16), lane, lane, lane,
+        sds((slots,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert not re.search(r"\bwhile\(", text)
+    assert not re.search(r"\bscatter\(", text)
+    assert len(re.findall(r"dynamic-update-slice\(", text)) == 1
+    # the tail is updated where it lies
+    assert compiled.memory_analysis().alias_size_in_bytes > 0
