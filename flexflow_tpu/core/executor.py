@@ -997,7 +997,8 @@ class Executor:
             from .programs import ProgramRegistry
             self._programs = ProgramRegistry(
                 self._train_fingerprint(),
-                cache_dir=getattr(self.config, "program_cache_dir", None))
+                cache_dir=getattr(self.config, "program_cache_dir", None),
+                phase=self.model.setup_phase)
             self._programs.load_warm()
         return self._programs
 
@@ -1006,6 +1007,13 @@ class Executor:
         (registry query — empty dict before the first dispatch)."""
         reg = self._programs
         return {} if reg is None else reg.compile_counts()
+
+    def boot_record(self) -> dict:
+        """What the train programs' registry cost so far
+        (ProgramRegistry.boot_record; empty before the first
+        dispatch)."""
+        reg = self._programs
+        return {} if reg is None else reg.boot_record()
 
     def save_programs(self) -> int:
         """Snapshot freshly compiled train executables to

@@ -40,19 +40,30 @@ different chips keep separate stores.
 The registry places the serialized executables (``*.ffprog``) only.
 JAX's persistent compilation cache is placed by the entry points
 through ``utils/cache_dirs.arm_compile_cache`` — never from here.
+
+Also here, because every start resolves its programs here: what a
+START costs (docs/observability.md "Set-up phases"). ``CompileEvents``
+is the process's one listener on JAX's compile events;
+``boot_phases()`` makes the list a model or an engine keeps its set-up
+phases in; ``PROCESS_PHASES`` holds the process's own (the package's
+``import``); a registry given a ``phase`` writes one
+``compile:<family>`` a program it compiles or restores.
 """
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import json
 import os
 import pickle
 import time
 import warnings
-from typing import Any, Dict, Optional
+from typing import Any, Callable, Dict, Optional
 
 import jax
+
+from ..utils.telemetry import PhaseList
 
 # 2: entries record the devices they execute on
 # 3: programs carry named scopes in their operations' metadata — a store
@@ -60,6 +71,89 @@ import jax
 #    cannot attribute (the fingerprint folds no metadata)
 _STORE_VERSION = 3
 _STORE_SUFFIX = ".ffprog"
+
+
+class CompileEvents:
+    """The process's ONE listener on jax.monitoring's public event
+    stream: running totals of what compiling cost, whoever asked.
+
+    '/jax/core/compile/backend_compile_duration' fires once for every
+    program that reaches the backend's compiler OR is read back from
+    JAX's persistent compilation cache, and never on a jit-cache hit;
+    '/jax/compilation_cache/cache_hits' fires for the second kind
+    alone, so ``backend_compiles - cache_hits`` programs were compiled.
+    The seconds are the event's own: the compile, or the cache read.
+    Tracing and lowering to MLIR come before either and are never
+    cached between processes; their seconds are kept beside (a jit
+    traced inside another's trace fires inside it, so ``trace_s`` counts
+    nested traces twice and can pass the phase it is read over).
+
+    Two readers. The zero-recompile gates (fit's drift sampling,
+    tools/train_bench.py) diff ``count`` around a region: monkeypatch-
+    free, and it catches even a same-signature recompile (a dropped jit
+    cache) that a distinct-shape count would miss. Set-up phases
+    (``boot_phases``) diff ``totals()``, which is what names the eager
+    compiles of ``init_state`` and the pool's allocation that no
+    registry sees. Single listener per process; starts are not
+    concurrent, so an around-phase diff is race-free."""
+
+    count = 0               # backend_compiles
+    compile_s = 0.0
+    cache_hits = 0
+    trace_s = 0.0
+    lower_s = 0.0
+    _installed: Optional[bool] = None
+    _SECONDS = {
+        "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    }
+
+    @classmethod
+    def install(cls) -> bool:
+        if cls._installed is None:
+            from jax import monitoring
+            monitoring.register_event_duration_secs_listener(
+                cls._on_duration)
+            monitoring.register_event_listener(cls._on_event)
+            cls._installed = True
+        return cls._installed
+
+    @classmethod
+    def _on_duration(cls, event: str, duration: float, **kwargs) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            cls.count += 1
+            cls.compile_s += duration
+        elif event in cls._SECONDS:
+            key = cls._SECONDS[event]
+            setattr(cls, key, getattr(cls, key) + duration)
+
+    @classmethod
+    def _on_event(cls, event: str, **kwargs) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            cls.cache_hits += 1
+
+    @classmethod
+    def totals(cls) -> Dict[str, float]:
+        return {"backend_compiles": cls.count,
+                "backend_compile_s": cls.compile_s,
+                "cache_hits": cls.cache_hits,
+                "trace_s": cls.trace_s, "lower_s": cls.lower_s}
+
+
+def boot_phases() -> PhaseList:
+    """The list one start's set-up phases are kept in
+    (``Telemetry.timed(..., keep=)``): every record carries what the
+    process compiled, read from the cache, traced and lowered while the
+    phase ran."""
+    CompileEvents.install()
+    return PhaseList(totals=CompileEvents.totals)
+
+
+# the process's own phases: `import` and `jax_import`
+# (flexflow_tpu/__init__.py), and what an entry point adds
+# (tools/setup_phases.py: `backend_init`, `import_driver`). A model's
+# and an engine's are their own lists.
+PROCESS_PHASES = boot_phases()
 
 
 def fingerprint_hash(fp: Dict[str, Any]) -> str:
@@ -100,10 +194,17 @@ class ProgramRegistry:
     executor; families are e.g. serve's six serving functions)."""
 
     def __init__(self, fingerprint: Dict[str, Any],
-                 cache_dir: Optional[str] = None):
+                 cache_dir: Optional[str] = None,
+                 phase: Optional[Callable] = None):
         self.fingerprint = dict(fingerprint)
         self.fp_hash = fingerprint_hash(self.fingerprint)
         self.cache_dir = cache_dir
+        # the owner's set-up phase writer, `phase(name, args)` -> a
+        # context manager (FFModel.setup_phase, ServeEngine.
+        # setup_phase); without one nothing is written
+        self._phase = phase or (lambda name, args=None:
+                                contextlib.nullcontext())
+        self._restore_s = 0.0
         self._statics: Dict[str, tuple] = {}          # family -> argnums
         self._compiled: Dict[tuple, Any] = {}         # (family, sig) ->
         self._restored_keys: set = set()              # Compiled
@@ -133,7 +234,9 @@ class ProgramRegistry:
 
     def _compile(self, name: str, fn, args) -> Any:
         t0 = time.perf_counter()
-        compiled = fn.lower(*args).compile()
+        with self._phase("compile:" + name, {
+                "fingerprint": self.fp_hash, "source": "compiled"}):
+            compiled = fn.lower(*args).compile()
         self._compile_s[name] = self._compile_s.get(name, 0.0) \
             + (time.perf_counter() - t0)
         self._compiles[name] = self._compiles.get(name, 0) + 1
@@ -203,6 +306,7 @@ class ProgramRegistry:
             "restored": int(sum(self._restored.values())),
             "compiles": int(sum(self._compiles.values())),
             "compile_s": self.compile_seconds(),
+            "restore_s": self._restore_s,   # load_warm's reads
             "families": {n: {"compiles": self._compiles.get(n, 0),
                              "restored": self._restored.get(n, 0),
                              "compile_s": round(
@@ -329,7 +433,17 @@ class ProgramRegistry:
         d = cache_dir if cache_dir is not None else self.cache_dir
         if not d:
             return 0
-        doc = self._read_store(self._store_path(d))
+        path = self._store_path(d)
+        t0 = time.perf_counter()
+        args = {"restored": 0, "store_bytes": os.path.getsize(path)
+                if os.path.exists(path) else 0}
+        with self._phase("load_programs", args):
+            args["restored"] = self._load_store(path)
+        self._restore_s += time.perf_counter() - t0
+        return args["restored"]
+
+    def _load_store(self, path: str) -> int:
+        doc = self._read_store(path)
         if doc is None:
             return 0
         from jax.experimental.serialize_executable import \
@@ -343,10 +457,13 @@ class ProgramRegistry:
                 # onto the devices it was compiled for: the default
                 # binds to ALL local devices, and the first call then
                 # fails with "expected N shards"
-                compiled = deserialize_and_load(
-                    e["payload"], e["in_tree"], e["out_tree"],
-                    execution_devices=[by_id[i]
-                                       for i in e["device_ids"]])
+                with self._phase("compile:" + family, {
+                        "fingerprint": self.fp_hash,
+                        "source": "restored"}):
+                    compiled = deserialize_and_load(
+                        e["payload"], e["in_tree"], e["out_tree"],
+                        execution_devices=[by_id[i]
+                                           for i in e["device_ids"]])
             except Exception as exc:
                 warnings.warn(
                     f"program cache: could not deserialize a "

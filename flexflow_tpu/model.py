@@ -85,6 +85,9 @@ class FFModel:
         self.last_train_stats = None  # set by fit()
         self.telemetry = None         # set by fit() (utils/telemetry)
         # (profiling.train_report renders it)
+        # what this model's starts cost, phase by phase (setup_phase)
+        from .core.programs import boot_phases
+        self._boot_phases = boot_phases()
         self.label_tensor: Optional[Tensor] = None
         # pretrained weights staged by frontends before compile()
         # (applied after init_state; reference Parameter::set_weights role)
@@ -551,7 +554,40 @@ class FFModel:
                 strategy: Optional[Strategy] = None) -> None:
         """Reference: FFModel::compile (model.cc:1551-1796). Runs strategy
         search when config.search_budget > 0, builds the executor, and
-        initializes parameters (sharded per strategy)."""
+        initializes parameters (sharded per strategy). One set-up
+        phase, `model_compile`, over `search` (where one runs),
+        `lower_strategy`, `build_step` and `init_state`
+        (docs/observability.md "Set-up phases")."""
+        with self.setup_phase("model_compile"):
+            self._compile(optimizer, loss_type, metrics, comp_mode, mesh,
+                          strategy)
+
+    def setup_phase(self, name: str, args: Optional[dict] = None):
+        """One set-up phase of this model, through the bus's `timed`:
+        kept in `boot_stats["phases"]` whether or not a bus is on, and
+        on track ("model", "setup") of `self.telemetry` where one is."""
+        from .utils.telemetry import SETUP_THREAD, telemetry_for
+        tel = self.telemetry if self.telemetry is not None \
+            else telemetry_for()
+        return tel.timed(("model", SETUP_THREAD), name, args,
+                         keep=self._boot_phases)
+
+    @property
+    def boot_stats(self) -> dict:
+        """What starting this model cost: its set-up `phases` in order
+        (`(name, parent, t_start_s, dur_s, args)`), `setup_s` (their
+        roots' sum) and, once a step has run, the executor's registry's
+        `compiles` / `compile_s` / `restore_s` / `families` — the shape
+        of ServeEngine.boot_stats."""
+        rec = self.executor.boot_record() if self.executor is not None \
+            else {}
+        from .utils.telemetry import roots_s
+        rec["phases"] = list(self._boot_phases)
+        rec["setup_s"] = roots_s(rec["phases"])
+        return rec
+
+    def _compile(self, optimizer, loss_type, metrics, comp_mode, mesh,
+                 strategy) -> None:
         self.config.validate()  # catch post-construction field edits
         if mesh is not None:
             self.mesh = mesh
@@ -581,6 +617,41 @@ class FFModel:
             if self.config.export_strategy_file:
                 self.strategy.save(self.config.export_strategy_file)
 
+        with self.setup_phase("lower_strategy"):
+            stage_of, pipe_axis = self._lower_strategy()
+        # Executor validates comp_mode; assign OURS only after it
+        # succeeds so a rejected compile leaves the previous mode live
+        with self.setup_phase("build_step"):
+            if stage_of is not None and pipe_axis is not None:
+                from .core.staged import StagedExecutor
+                self.executor = StagedExecutor(
+                    self, optimizer, loss_type, metrics, mesh=self.mesh,
+                    strategy=self.strategy, comp_mode=comp_mode,
+                    stage_of=stage_of, pipe_axis=pipe_axis,
+                    num_microbatches=self.config.pipeline_microbatches,
+                    schedule=self.config.pipeline_schedule)
+            else:
+                self.executor = Executor(
+                    self, optimizer, loss_type, metrics,
+                    mesh=self.mesh, strategy=self.strategy,
+                    comp_mode=comp_mode)
+        self.comp_mode = comp_mode
+        args = {}
+        with self.setup_phase("init_state", args):
+            self.state = self.executor.init_state(self._next_rng())
+            leaves = jax.tree_util.tree_leaves(self.state)
+            args.update(leaves=len(leaves),
+                        bytes=int(sum(x.nbytes for x in leaves)))
+        self._host_step = 0  # mirrors state.step for the train rng
+        for op_name, ws in self.imported_weights.items():
+            self.set_weights(op_name, ws)
+        for op_name, ss in self.imported_states.items():
+            self.set_states(op_name, ss)
+
+    def _lower_strategy(self):
+        """The strategy's pipeline block and device pins lowered to
+        (stage_of, pipe_axis): None, None where the model runs as one
+        SPMD program."""
         # a search-discovered interleaved pipeline rides the strategy's
         # `pipeline` block (pins cannot express v stages per device) —
         # apply it to the config knobs the auto-cut lowering below
@@ -699,28 +770,7 @@ class FFModel:
                 "come from pins or no pipeline at all — interleaving "
                 "was NOT applied")
 
-        # Executor validates comp_mode; assign OURS only after it
-        # succeeds so a rejected compile leaves the previous mode live
-        if stage_of is not None and pipe_axis is not None:
-            from .core.staged import StagedExecutor
-            self.executor = StagedExecutor(
-                self, optimizer, loss_type, metrics, mesh=self.mesh,
-                strategy=self.strategy, comp_mode=comp_mode,
-                stage_of=stage_of, pipe_axis=pipe_axis,
-                num_microbatches=self.config.pipeline_microbatches,
-                schedule=self.config.pipeline_schedule)
-        else:
-            self.executor = Executor(
-                self, optimizer, loss_type, metrics,
-                mesh=self.mesh, strategy=self.strategy,
-                comp_mode=comp_mode)
-        self.comp_mode = comp_mode
-        self.state = self.executor.init_state(self._next_rng())
-        self._host_step = 0  # mirrors state.step for the train rng
-        for op_name, ws in self.imported_weights.items():
-            self.set_weights(op_name, ws)
-        for op_name, ss in self.imported_states.items():
-            self.set_states(op_name, ss)
+        return stage_of, pipe_axis
 
     def _load_strategy_file(self, path: str) -> Strategy:
         """--import-strategy dispatch: our JSON format, the reference's
@@ -983,9 +1033,9 @@ class FFModel:
             # must not feed the drift calibrator — compile seconds are
             # not step time, and one contaminated sample poisons the
             # regime average
-            from .serve.engine import _CompileEvents
-            if _CompileEvents.install():
-                _compiles = _CompileEvents
+            from .core.programs import CompileEvents
+            if CompileEvents.install():
+                _compiles = CompileEvents
         win = DispatchWindow(
             getattr(self.config, "train_dispatch_depth", 2),
             telemetry=tel)

@@ -16,6 +16,7 @@ SP/EP/PP axes.
 
 from __future__ import annotations
 
+import functools
 import math
 import os
 import random
@@ -25,6 +26,20 @@ from typing import Dict, List, Optional
 from ..parallel.pconfig import DEVICE_KEY, OpStrategy, Strategy
 from .measure import calibrated_machine_model
 from .simulator import Simulator, op_edges
+
+
+def _search_phase(fn):
+    """The `search` set-up phase around a search's entry point, in the
+    model's boot record (FFModel.setup_phase): the budget asked for and
+    the engine that ran."""
+    @functools.wraps(fn)
+    def run(model, budget: int = 1000, *a, **kw):
+        args = {"budget": int(budget)}
+        with model.setup_phase("search", args):
+            out = fn(model, budget, *a, **kw)
+            args["engine"] = (model.search_stats or {}).get("engine")
+        return out
+    return run
 
 
 def _resolve_chains(cfg, chains: Optional[int]) -> int:
@@ -225,6 +240,7 @@ def enumerate_mesh_shapes(n_devices: int, model, cfg
     return shapes
 
 
+@_search_phase
 def optimize_with_mesh(model, budget: int = 1000, alpha: float = 0.05,
                        devices=None, seed: Optional[int] = None,
                        verbose: bool = False,
@@ -668,6 +684,7 @@ def _optimize_impl(model, budget: int, alpha: float, mesh, seed: int,
                                           engine)
 
 
+@_search_phase
 def optimize(model, budget: int = 1000, alpha: float = 0.05,
              mesh=None, seed: Optional[int] = None, verbose: bool = False,
              simulator: Optional[Simulator] = None,

@@ -59,13 +59,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import CompMode
+from ..core.programs import ProgramRegistry, boot_phases
 from ..kernels.paged_ragged_v2 import (Q_ROWS, choose_block_kv,
                                        ragged_dispatch_passes,
                                        resolve_paged_impl)
 from ..parallel.mesh import TENSOR, replica_devices, serve_tensor_mesh
 from ..utils.faults import FaultInjector, TransientError, injector_for
-from ..utils.telemetry import (Telemetry, pow2_bucket, serve_metrics,
-                               telemetry_for)
+from ..utils.telemetry import (SETUP_THREAD, Telemetry, pow2_bucket,
+                               roots_s, serve_metrics, telemetry_for)
 from . import mixers
 from .arch import _dense, describe
 from .kv_cache import (HybridPool, KVCacheConfig, KVPool, PagedKVCache,
@@ -79,39 +80,10 @@ from .scheduler import (ChunkPlan, ContinuousBatchingScheduler, Request,
 _PAD_LOGIT_BIAS = -1e30
 
 
-class _CompileEvents:
-    """Process-wide counter of ACTUAL XLA backend compiles, fed by
-    jax.monitoring's public event stream (the
-    '/jax/core/compile/backend_compile_duration' event fires once per
-    backend compile and never on a jit-cache hit).
-
-    This exists because the zero-recompile serving gate must not go
-    vacuous: jit's `_cache_size` is a private API that has moved across
-    jax versions, and a gate comparing "?" == "?" passes while the
-    engine silently recompiles every step. The engine snapshots this
-    counter around each jitted call and attributes any increment to
-    that serving function — monkeypatch-free, and it catches even a
-    same-signature recompile (e.g. a dropped jit cache) that a
-    distinct-shape count would miss. Single listener per process;
-    serving calls are not concurrent, so the around-call diff is
-    race-free."""
-
-    count = 0
-    _installed: Optional[bool] = None
-
-    @classmethod
-    def install(cls) -> bool:
-        if cls._installed is None:
-            from jax import monitoring
-            monitoring.register_event_duration_secs_listener(
-                cls._on_event)
-            cls._installed = True
-        return cls._installed
-
-    @staticmethod
-    def _on_event(event: str, duration: float, **kwargs) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            _CompileEvents.count += 1
+def _tree_bytes(tree) -> int:
+    """Bytes of a pytree's arrays (shapes alone: nothing is fetched)."""
+    return int(sum(x.nbytes for x in jax.tree.leaves(tree)
+                   if hasattr(x, "nbytes")))
 
 
 def probe_serve_arch(model, config=None, context=None):
@@ -211,6 +183,38 @@ class ServeEngine:
         # DisaggCluster gives each role its own serving knobs (prefill
         # budget, scrape endpoint) over ONE shared model
         self.config = config if config is not None else model.config
+        # observability (utils/telemetry.py, docs/observability.md):
+        # per-request/per-step spans, the metrics registry, and the
+        # simulator-drift calibrator. An explicit `telemetry` bus wins
+        # (benches A/B on vs off over one config); else
+        # FFConfig.telemetry / trace_out resolve one (off = the shared
+        # disabled instance, one attribute read per site). All of it
+        # is host-side: telemetry on vs off is token-identical with
+        # zero recompiles (ci.sh step 1k gates <= 3% overhead).
+        self.telemetry = telemetry if telemetry is not None \
+            else telemetry_for(self.config)
+        # telemetry track process name: a ReplicaPool re-homes each
+        # replica's tracks (set_track_process) so N replicas' spans
+        # don't merge onto one "serve" track in the exported trace
+        self._proc = "serve"
+        # what this engine's start cost, phase by phase (setup_phase;
+        # boot_stats["phases"] puts the model's before them)
+        self._boot_phases = boot_phases()
+        with self.setup_phase("engine_init"):
+            self._init(model, max_seq_len, use_pallas, interpret,
+                       prefix_cache, spec_tokens, drafter, faults, mesh,
+                       tensor_parallel, replica, host_tier)
+
+    def setup_phase(self, name: str, args: Optional[dict] = None):
+        """One set-up phase of this engine, through the bus's `timed`:
+        kept for `boot_stats["phases"]` whether or not the bus is on,
+        and on track (proc, "setup") where it is."""
+        return self.telemetry.timed((self._proc, SETUP_THREAD), name,
+                                    args, keep=self._boot_phases)
+
+    def _init(self, model, max_seq_len, use_pallas, interpret,
+              prefix_cache, spec_tokens, drafter, faults, mesh,
+              tensor_parallel, replica, host_tier) -> None:
         # the paged-attention implementation, resolved ONCE from the
         # arguments and the backend (kernels/paged_ragged_v2.
         # resolve_paged_impl): "pallas" (Mosaic-compiled),
@@ -218,7 +222,8 @@ class ServeEngine:
         # resolved choice down; last_stats / boot_stats / the program
         # fingerprint report it.
         self.attn_impl = resolve_paged_impl(use_pallas, interpret)
-        self._read_arch(model)
+        with self.setup_phase("read_arch"):
+            self._read_arch(model)
         if max_seq_len is None:
             max_seq_len = self.max_positions
         if max_seq_len > self.max_positions:
@@ -270,21 +275,7 @@ class ServeEngine:
         # deadlines, host-side cancellation, and the scheduler's
         # degradation ladder
         self.faults = faults if faults is not None else injector_for(cfg)
-        # observability (utils/telemetry.py, docs/observability.md):
-        # per-request/per-step spans, the metrics registry, and the
-        # simulator-drift calibrator. An explicit `telemetry` bus wins
-        # (benches A/B on vs off over one config); else
-        # FFConfig.telemetry / trace_out resolve one (off = the shared
-        # disabled instance, one attribute read per site). All of it
-        # is host-side: telemetry on vs off is token-identical with
-        # zero recompiles (ci.sh step 1k gates <= 3% overhead).
-        self.telemetry = telemetry if telemetry is not None \
-            else telemetry_for(cfg)
         self.trace_out = getattr(cfg, "trace_out", None)
-        # telemetry track process name: a ReplicaPool re-homes each
-        # replica's tracks (set_track_process) so N replicas' spans
-        # don't merge onto one "serve" track in the exported trace
-        self._proc = "serve"
         self._ENGINE_TRACK = (self._proc, "engine")
         self._QUEUE_TRACK = (self._proc, "queue")
         # at most ONE live ServeSession owns the scheduler/slots at a
@@ -479,13 +470,18 @@ class ServeEngine:
         # mesh (same lane contract, same donation) — ONE program
         # either way, keyed by the pool's pytree (quantized pools carry
         # their scale arrays through the same step, donated alongside)
-        if self.tp > 1:
-            self._step_params, self._param_specs = self._shard_params()
-        else:
-            # a placed replica holds its own copy of the weights on its
-            # chip; an unplaced engine reads the model's arrays in place
-            self._step_params = self.params if self._home is None \
-                else jax.device_put(self.params, self._home)
+        args = {}
+        with self.setup_phase("shard_params", args):
+            if self.tp > 1:
+                self._step_params, self._param_specs = \
+                    self._shard_params()
+            else:
+                # a placed replica holds its own copy of the weights on
+                # its chip; an unplaced engine reads the model's arrays
+                # in place
+                self._step_params = self.params if self._home is None \
+                    else jax.device_put(self.params, self._home)
+            args["bytes"] = _tree_bytes(self._step_params)
         self._mixed_jit = jax.jit(self._mixed_impl, donate_argnums=(1,))
         self._forward_jit = jax.jit(self._forward_logits)  # naive reference
         if self.adapters is not None:
@@ -514,10 +510,10 @@ class ServeEngine:
         # cold replica boots warm (zero compiles). `_compiles` stays
         # the registry's live per-family dict (test/bench API compat);
         # `_events_ok` is always True now that counting is exact.
-        from ..core.programs import ProgramRegistry
         self.programs = ProgramRegistry(
             self._program_fingerprint(),
-            cache_dir=getattr(cfg, "program_cache_dir", None))
+            cache_dir=getattr(cfg, "program_cache_dir", None),
+            phase=self.setup_phase)
         for fam in ("mixed", "adapter", "export", "import"):
             self.programs.register(fam)
         self.programs_restored = self.programs.load_warm()
@@ -1505,7 +1501,10 @@ class ServeEngine:
                     KVPool.specs(TENSOR))
             alloc = KVPool.alloc if self.cache_cfg.hybrid is None \
                 else HybridPool.alloc
-            self.pool = alloc(self.cache_cfg, sharding)
+            args = {"pages": self.cache_cfg.num_pages}
+            with self.setup_phase("alloc_pool", args):
+                self.pool = alloc(self.cache_cfg, sharding)
+                args["pool_bytes"] = _tree_bytes(self.pool)
         return self.pool
 
     # ---------------- adapter pool: device half ------------------------
@@ -1639,15 +1638,47 @@ class ServeEngine:
         engine with a cache dir armed writes its snapshot back so the
         NEXT boot over this config is warm."""
         t0 = time.perf_counter()
+        with self.setup_phase("warmup"):
+            self._warm_programs()
+        rec = self.programs.boot_record()
+        rec["boot_s"] = time.perf_counter() - t0
+        rec["warm"] = rec["compiles"] == 0 and rec["restored"] > 0
+        rec["attn_impl"] = self.attn_impl
+        rec["scan_impl"] = self.scan_impl
+        rec["expert_impl"] = self.expert_impl
+        rec.update(self._delta_impl)
+        # ... and how its slab holds a state (not in the fingerprint:
+        # the pool's shapes are)
+        rec.update(self.geometry.delta_state)
+        # where the start went: the model's phases, then this engine's
+        rec["phases"] = list(self.model.boot_stats["phases"]) \
+            + list(self._boot_phases)
+        rec["setup_s"] = roots_s(rec["phases"])
+        self.boot_stats = rec
+        if self.programs.cache_dir and self.programs._dirty:
+            # read-through write-back: the first (cold) engine over
+            # this fingerprint populates the snapshot, every later
+            # replica — in-process scale-up or a fresh process —
+            # deserializes instead of compiling
+            self.programs.save()
+        return self.compile_counts()
+
+    def _warm_programs(self) -> None:
+        """warmup()'s work: the pool (`alloc_pool`), the throwaway
+        mixed step to its end on the device (`first_dispatch`, the
+        step's `compile:mixed` inside it), then the adapter's and the
+        hand-off's programs."""
+        self._device_pool()
         c = self.cache_cfg
         t = self.mixed_width
         z = self._h2d(np.zeros((t,), np.int32))
         pts = self._h2d(
             np.zeros((c.max_seqs, c.pages_per_seq), np.int32))
-        self._dispatch_mixed(
-            z, z, z, z, pts, z, self._h2d(np.ones((t,), np.int32)),
-            self._h2d(np.zeros((self.head_rows,), np.int32)),
-            self._h2d(np.full((t,), -1, np.int32)))
+        with self.setup_phase("first_dispatch"):
+            jax.block_until_ready(self._dispatch_mixed(
+                z, z, z, z, pts, z, self._h2d(np.ones((t,), np.int32)),
+                self._h2d(np.zeros((self.head_rows,), np.int32)),
+                self._h2d(np.full((t,), -1, np.int32))))
         if self.adapters is not None:
             # compile the adapter-load scatter on an all-zero row
             # set aimed at the base slot (zeros into zeros — a
@@ -1664,24 +1695,6 @@ class ServeEngine:
             # warm them here or the first eviction under load
             # would compile after the pool snapshots warm counts
             self.warmup_handoff()
-        rec = self.programs.boot_record()
-        rec["boot_s"] = time.perf_counter() - t0
-        rec["warm"] = rec["compiles"] == 0 and rec["restored"] > 0
-        rec["attn_impl"] = self.attn_impl
-        rec["scan_impl"] = self.scan_impl
-        rec["expert_impl"] = self.expert_impl
-        rec.update(self._delta_impl)
-        # ... and how its slab holds a state (not in the fingerprint:
-        # the pool's shapes are)
-        rec.update(self.geometry.delta_state)
-        self.boot_stats = rec
-        if self.programs.cache_dir and self.programs._dirty:
-            # read-through write-back: the first (cold) engine over
-            # this fingerprint populates the snapshot, every later
-            # replica — in-process scale-up or a fresh process —
-            # deserializes instead of compiling
-            self.programs.save()
-        return self.compile_counts()
 
     # ---------------- sampling -----------------------------------------
     @staticmethod

@@ -63,11 +63,39 @@ __all__ = [
     "pct", "pow2_bucket", "serve_metrics", "train_metrics",
     "next_trace_id", "attribute_request", "fold_attribution",
     "write_json_atomic", "REQUEST_COMPONENTS", "PHASE_PREFIX",
+    "PhaseList", "SETUP_THREAD", "roots_s",
 ]
 
 # prefix of the phase spans Telemetry.timed writes into a profiler
 # trace (the benchmark's own spans are `bench:`)
 PHASE_PREFIX = "ff:"
+# thread of the track a process's set-up phases lie on: (proc, "setup")
+SETUP_THREAD = "setup"
+
+
+class PhaseList(list):
+    """The finished phases of one start, kept by whoever started (a
+    model's or an engine's boot record, the process's own): what
+    ``Telemetry.timed(..., keep=this)`` appends, whether or not the bus
+    is on. A record is ``(name, parent, t_start_s, dur_s, args)`` on
+    the bus's clock (``time.perf_counter``); ``parent`` is the name of
+    the phase of THIS list that was open when the record's began, None
+    for a root. A process writes a few dozen, never one a step.
+
+    ``totals`` is a function of nothing that returns running totals
+    ({name: number}, e.g. core/programs.CompileEvents.totals); every
+    record's args then carry each total's difference over the phase."""
+
+    def __init__(self, totals=None):
+        super().__init__()
+        self.totals = totals
+        self.open: List[str] = []   # phases still running, outermost first
+
+
+def roots_s(phases: Iterable[tuple]) -> float:
+    """Seconds of the root phases among PhaseList records: what the
+    starts they record cost (a child's seconds lie inside its parent's)."""
+    return float(sum(r[3] for r in phases if r[1] is None))
 
 
 # ---------------------------------------------------------------------------
@@ -528,24 +556,45 @@ class Telemetry:
 
     @contextlib.contextmanager
     def timed(self, track: Tuple[str, str], name: str,
-              args: Optional[dict] = None):
+              args: Optional[dict] = None,
+              keep: Optional[PhaseList] = None,
+              t_start: Optional[float] = None):
         """THE phase-span entry point of the hot loops
-        (ServeSession.step, FFModel.train_batch): a
+        (ServeSession.step, FFModel.train_batch) and of set-up: a
         ``jax.profiler.TraceAnnotation`` named ``ff:<name>`` — on the
         profiler's clock, so it can be laid over the device trace;
         inactive and near-free unless a profiler session runs — and,
         when this bus is enabled, the same span on the bus. The
         disabled shared instance takes the same path minus the ring
-        append (no lock, no record)."""
+        append (no lock, no record).
+
+        ``keep`` (set-up alone, never a step): the finished span is
+        also appended to that PhaseList, bus on or off, with the
+        differences of its ``totals`` in the args; the caller may add
+        to ``args`` until the phase ends. ``t_start`` (a
+        ``perf_counter`` stamp) backdates a phase that began before
+        this module could be imported (the package's own import)."""
         with TraceAnnotation(PHASE_PREFIX + name, **(args or {})):
-            if not self.enabled:
+            if keep is None and not self.enabled:
                 yield
                 return
-            t0 = time.perf_counter()
+            t0 = time.perf_counter() if t_start is None else t_start
+            if keep is not None:
+                parent = keep.open[-1] if keep.open else None
+                keep.open.append(name)
+                before = keep.totals() if keep.totals else {}
             try:
                 yield
             finally:
-                self.span(track, name, t0, time.perf_counter(), args)
+                t1 = time.perf_counter()
+                if keep is not None:
+                    keep.open.pop()
+                    if before:
+                        after = keep.totals()
+                        args = dict(args or {}, **{
+                            k: after[k] - v for k, v in before.items()})
+                    keep.append((name, parent, t0, t1 - t0, args))
+                self.span(track, name, t0, t1, args)
 
     # ---------------- drift calibration --------------------------------
     def record_drift(self, domain: str, regime: str, predicted_s: float,

@@ -19,6 +19,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmark")
 sys.path.insert(0, BENCH)
 
+from test_benchmark_sala import (EIGHTEEN,  # noqa: E402
+                                 assert_cell_and_its_entries)
+
 from lib import delta_counts, olmohybrid_cell, traffic_gen  # noqa: E402
 
 CELL, CONFIG = "olmohybrid-ragchat", "olmo-hybrid-7b-1chip-l16"
@@ -48,37 +51,20 @@ def test_every_metric_file_of_the_cell_reads_nothing_from_an_empty_run(name):
     assert _read(name, UNTRACED) is None
 
 
-def test_the_cell_is_appended_and_nothing_else_changed():
-    e2e = [m["name"] for m in BENCHMARK["end_to_end"]
-           if CELL in m.get("workloads", ())]
-    assert e2e == ["tpot_p50_ms.olmoe"]
-    # the builder's contract holds `per_layer` to 128 entries, and the
-    # accepted benchmark has them: the cell adds none (PERF.md, q. 33)
-    assert len(BENCHMARK["per_layer"]) == 128
-    layer = [m for m in BENCHMARK["per_layer"]
-             if CELL in m.get("workloads", ())]
-    assert len(layer) == 20
-    assert all(m["moves"] == e2e[0] and m["workloads"][-1] == CELL
-               for m in layer)
+def test_the_cell_keeps_its_place_and_its_entries_still_list_it():
     # the 18 entries `sala-longdoc` is on, and two of `olmoe-chat`'s
     # whose readers take the paged kernel's spans and name as they are
-    assert sum("sala-longdoc" in m["workloads"] for m in layer) == 18
-    assert [m["name"] for m in layer
-            if "sala-longdoc" not in m["workloads"]] == TWO_MORE
-    assert BENCHMARK["workloads"][-1]["name"] == CELL
-    assert BENCHMARK["configs"][-1]["name"] == CONFIG
-    cell = BENCHMARK["workloads"][-1]
-    assert (cell["config"], cell["chips"], cell["traffic"]) == (
-        CONFIG, 1, CELL)
-    assert all(len(x["why"]) <= 200 for x in BENCHMARK["workloads"]
-               + BENCHMARK["configs"])
-    # no metric file was added for it (check_live_counters.py counts)
-    assert len(os.listdir(os.path.join(BENCH, "metrics"))) == 134
+    layer = assert_cell_and_its_entries(CELL, CONFIG, EIGHTEEN + TWO_MORE)
+    by_name = {m["name"]: m for m in layer}
+    assert all("sala-longdoc" in by_name[n]["workloads"] for n in EIGHTEEN)
+    assert all("sala-longdoc" not in by_name[n]["workloads"]
+               and "olmoe-chat" in by_name[n]["workloads"]
+               for n in TWO_MORE)
 
 
 def test_the_configuration_holds_the_catalog_s_numbers_but_the_reduced():
     conf = _json(BENCH, "configs", CONFIG + ".json")
-    entry = BENCHMARK["configs"][-1]
+    entry = next(c for c in BENCHMARK["configs"] if c["name"] == CONFIG)
     assert conf["reduced"] == entry["reduced"] == [
         "num_hidden_layers", "max_position_embeddings"]
     assert conf["source"] == entry["source"]

@@ -18,6 +18,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmark")
 sys.path.insert(0, BENCH)
 
+from test_benchmark_sala import (EIGHTEEN,  # noqa: E402
+                                 assert_cell_and_its_entries)
+
 from lib import qwen3next_cell, traffic_gen  # noqa: E402
 
 CELL, CONFIG = "qwen3next-longchat", "qwen3-next-80b-a3b-1chip-ep4-l8"
@@ -48,32 +51,15 @@ def test_every_metric_file_of_the_cell_reads_nothing_from_an_empty_run(name):
     assert _read(name, UNTRACED) is None
 
 
-def test_the_cell_is_appended_and_nothing_else_changed():
-    e2e = [m["name"] for m in BENCHMARK["end_to_end"]
-           if CELL in m.get("workloads", ())]
-    assert e2e == ["tpot_p50_ms.olmoe"]
-    # the builder's contract holds `per_layer` to 128 entries, and the
-    # accepted benchmark has them: the cell adds none (PERF.md, q. 33)
-    assert len(BENCHMARK["per_layer"]) == 128
-    layer = [m for m in BENCHMARK["per_layer"]
-             if CELL in m.get("workloads", ())]
-    assert len(layer) == 25
-    # (the cells a later PR appended stand behind it: PR 52's)
-    assert all(m["moves"] == e2e[0] and CELL in m["workloads"][-2:]
-               for m in layer)
+def test_the_cell_keeps_its_place_and_its_entries_still_list_it():
     # the 18 entries `sala-longdoc` is on, and the seven of
     # `cmdaplus-mixedlen` whose readers take this expert layer's scopes
     # and counters as they are
-    assert sum("sala-longdoc" in m["workloads"] for m in layer) == 18
-    assert [m["name"] for m in layer if m["name"].endswith(".cmda")] == SEVEN
-    cell = next(w for w in BENCHMARK["workloads"] if w["name"] == CELL)
-    assert CONFIG in [c["name"] for c in BENCHMARK["configs"]]
-    assert (cell["config"], cell["chips"], cell["traffic"]) == (
-        CONFIG, 1, CELL)
-    assert all(len(x["why"]) <= 200 for x in BENCHMARK["workloads"]
-               + BENCHMARK["configs"])
-    # no metric file was added for it (check_live_counters.py counts)
-    assert len(os.listdir(os.path.join(BENCH, "metrics"))) == 134
+    layer = assert_cell_and_its_entries(CELL, CONFIG, EIGHTEEN + SEVEN)
+    by_name = {m["name"]: m for m in layer}
+    assert all("sala-longdoc" in by_name[n]["workloads"] for n in EIGHTEEN)
+    assert all(by_name[n]["workloads"][0] == "cmdaplus-mixedlen"
+               for n in SEVEN)
 
 
 def test_the_configuration_holds_the_catalog_s_numbers_but_the_reduced():

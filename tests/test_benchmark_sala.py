@@ -42,23 +42,54 @@ def test_every_metric_file_of_the_cell_reads_nothing_from_an_empty_run(name):
     assert _read(name, UNTRACED) is None
 
 
-def test_the_cell_is_appended_and_nothing_else_changed():
-    e2e = [m["name"] for m in BENCHMARK["end_to_end"]
-           if CELL in m.get("workloads", ())]
+def assert_cell_and_its_entries(cell_name, config, named):
+    """What a serving cell's place in BENCHMARK.json has to keep, however
+    many metrics, files and later cells the benchmark has: the cell
+    with its configuration on one chip, `tpot_p50_ms.olmoe` as its one
+    end-to-end metric beside `setup_s`, every entry that lists it moving
+    a metric it reports and read by a metric file, and the entries
+    `named` still listing it. (That each reader reads nothing from an
+    empty run is the parametrised test above, a case an entry.)"""
+    bench = _json(ROOT, "BENCHMARK.json")
+    cell = next(w for w in bench["workloads"] if w["name"] == cell_name)
+    assert (cell["config"], cell["chips"], cell["traffic"]) == (
+        config, 1, cell_name)
+    assert config in [c["name"] for c in bench["configs"]]
+    assert len(cell["why"]) <= 200
+    e2e = [m["name"] for m in bench["end_to_end"]
+           if cell_name in m.get("workloads", ())]
     assert e2e == ["tpot_p50_ms.olmoe"]
-    # the builder's contract holds `per_layer` to 128 entries, and the
-    # accepted benchmark has them: the cell adds none (PERF.md, q. 33)
-    assert len(BENCHMARK["per_layer"]) == 128
-    layer = [m for m in BENCHMARK["per_layer"]
-             if CELL in m.get("workloads", ())]
-    assert len(layer) == 18
-    # appended after `olmoe-chat` (a later PR's cell comes after it)
-    assert all(m["moves"] == e2e[0] and m["workloads"][:2] ==
-               ["olmoe-chat", CELL] for m in layer)
-    cell = next(w for w in BENCHMARK["workloads"] if w["name"] == CELL)
-    assert (cell["config"], cell["chips"]) == (CONFIG, 1)
-    assert all(len(x["why"]) <= 200 for x in BENCHMARK["workloads"]
-               + BENCHMARK["configs"])
+    reports = set(e2e) | {m["name"] for m in bench["end_to_end"]
+                          if "workloads" not in m}
+    assert "setup_s" in reports
+    layer = [m for m in bench["per_layer"]
+             if cell_name in m.get("workloads", ())]
+    for m in layer:
+        assert m["moves"] in reports, m["name"]
+        assert os.path.exists(os.path.join(
+            BENCH, "metrics", m["name"] + ".json")), m["name"]
+    assert set(named) <= {m["name"] for m in layer}
+    return layer
+
+
+# the entries the cell was put on (PR 45): the step, its host side, the
+# paged kernel and the request's waits, as `olmoe-chat` reads them
+EIGHTEEN = [
+    "compiles_in_window.olmoe", "step_ms.olmoe", "tpot_p95_ms.olmoe",
+    "ttft_p50_ms.olmoe", "lane_occupancy.olmoe", "attn_kernel_share.olmoe",
+    "kv_write_share.olmoe", "unscoped_share.olmoe", "step_host_ms.olmoe",
+    "queue_wait_p95_ms.olmoe", "ttft_p95_ms.olmoe",
+    "attn_grid_live_share.olmoe", "attn_row_fill.olmoe",
+    "sample_live_share.olmoe", "fetch_host_ms.olmoe",
+    "upload_host_ms.olmoe", "dispatch_fetch_host_ms.olmoe",
+    "chip_probe_tflops.olmoe"]
+
+
+def test_the_cell_keeps_its_place_and_the_eighteen_still_list_it():
+    layer = assert_cell_and_its_entries(CELL, CONFIG, EIGHTEEN)
+    # each was `olmoe-chat`'s before it was this cell's
+    assert all("olmoe-chat" in m["workloads"] for m in layer
+               if m["name"] in EIGHTEEN)
 
 
 def test_the_configuration_holds_the_catalog_s_numbers_but_the_reduced():

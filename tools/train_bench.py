@@ -109,7 +109,7 @@ def run_arm(model, args, overlap):
     """-> (sec/step best-of-repeats, losses float32 array, stats)."""
     import jax
     from flexflow_tpu.core.overlap import DispatchWindow
-    from flexflow_tpu.serve.engine import _CompileEvents
+    from flexflow_tpu.core.programs import CompileEvents
 
     ff, x, y = _build(model, args, overlap)
     names = list(x)
@@ -150,8 +150,8 @@ def run_arm(model, args, overlap):
     for s in range(0, warm, K):
         dispatch(s)
     drain()
-    installed = _CompileEvents.install()
-    compiles0 = _CompileEvents.count
+    installed = CompileEvents.install()
+    compiles0 = CompileEvents.count
     best = float("inf")
     step = warm
     for _ in range(args.repeat):
@@ -161,7 +161,7 @@ def run_arm(model, args, overlap):
             step += K
         drain()
         best = min(best, (time.perf_counter() - t0) / args.steps)
-    compiles = (_CompileEvents.count - compiles0) if installed else None
+    compiles = (CompileEvents.count - compiles0) if installed else None
     sg = sorted(gaps)
     stats = {
         "depth": depth,
