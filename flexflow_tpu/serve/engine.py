@@ -2733,8 +2733,13 @@ class StepEvents:
     sparse layers and key/value heads; ``selected_kv_bytes`` and
     ``selector_bytes`` are what the DEVICE gathers a step, of K and V
     blocks and of compressed keys: every lane of the step's width
-    gathers, live and past dense_len or not, so both are constants of
-    the shapes (``kv_bytes_read`` stays the paged calls' page fetches);
+    gathers its blocks, live and past dense_len or not, so the first
+    is a constant of the shapes; of the compressed keys every stretch
+    of lanes fetches one copy, its main sequence's, and the stray
+    lanes, those of another sequence, a copy each, a stretch of them a
+    trip (``score_tiles`` the stretches a layer, ``score_shared_tiles``
+    those with no stray lane: sparse_paged.main_slots;
+    ``kv_bytes_read`` stays the paged calls' page fetches);
     ``dispatched``
     False for a call in which no step landed: a planning-only
     iteration (rung-4

@@ -563,12 +563,21 @@ class KVPool:
 
         return scaled(k, self.k_scale), scaled(v, self.v_scale)
 
-    def selector_rows(self, layer: int, pages):
-        """The selector's rows of `pages` (any shape) of `layer`,
-        pages.shape + (selector_dim,), gathered like `gather`."""
+    def selector_table(self):
+        """The selector's rows of all the layers as ONE table (layer *
+        page, selector_dim), what `selector_rows` gathers from. Merging
+        the leading dimensions lays the leaf out anew where a layer's
+        pages are no whole tiles, so a program that gathers in several
+        places (or in a loop's body) makes the table once, before
+        them."""
+        return self.kc.reshape(-1, self.kc.shape[-1])
+
+    def selector_rows(self, table, layer: int, pages):
+        """The selector's rows of `pages` (any shape) of `layer` from
+        `selector_table()`, pages.shape + (selector_dim,), gathered
+        like `gather`."""
         rows = layer * self.kc.shape[1] + jnp.asarray(pages, jnp.int32)
-        return jnp.take(self.kc.reshape(-1, self.kc.shape[-1]), rows,
-                        axis=0, mode="clip")
+        return jnp.take(table, rows, axis=0, mode="clip")
 
     def layer(self, i: int):
         """Layer `i`'s operands of the paged attention kernel

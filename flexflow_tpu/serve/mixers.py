@@ -38,16 +38,21 @@ from ..ops import gated_delta, linear_attention, ssm
 from .arch import (ATTN, CROSS, DELTA, FULL, GMU, LINEAR, SPARSE, SSM,
                    WINDOW)
 from .kv_cache import KVCacheConfig, ring_tables
-from .sparse_paged import paged_sparse_attention, stride_keys
+from .sparse_paged import (LANE_TILE, STRAY_TILE, main_slots,
+                           paged_sparse_attention, stray_batches,
+                           stride_keys)
 
 # the step's fixed shape against its live work: StepEvents attributes
 # and `dispatch` span arguments of every model
 LIVE_COUNTS = ("grid_steps", "live_steps", "short_steps", "live_rows",
                "lanes", "emitters", "paged_calls", "paged_calls_in_place")
 # what a step's selection did, on a model that selects its context
-# (a SPARSE layer)
+# (a SPARSE layer); the last two: the stretches of lanes the scores
+# take a layer, and those of them whose live lanes all share the
+# stretch's one fetch of compressed keys (sparse_paged.main_slots)
 SELECT_COUNTS = ("sparse_lanes", "blocks_selected", "blocks_visible",
-                 "selected_kv_bytes", "selector_bytes")
+                 "selected_kv_bytes", "selector_bytes", "score_tiles",
+                 "score_shared_tiles")
 # ... where a sequence holds state or a ring besides pages
 HYBRID_COUNTS = ("state_bytes", "window_kv_bytes", "full_kv_bytes")
 # ... where a layer runs the gated delta rule: the live lanes that go
@@ -510,7 +515,9 @@ def _sparse(g, params, i, x, h, lanes, pool, memory, lora=None,
     positions, `lanes.work` its list, a call a key/value head: each
     head's pages are a pool layer of their own,
     KVCacheConfig.head_layers), then for the lanes past it
-    `sparse_score` (each lane against its sequence's compressed keys),
+    `sparse_score` (each lane against its sequence's compressed keys,
+    fetched once a stretch of lanes for its main sequence, a copy a
+    lane for the stray lanes of another),
     `sparse_select` (block scores, forced blocks, top-k) and
     `sparse_attn` (the selected blocks' pages, gathered a lane at a
     time), `attn_out` (the gate and the output projection)."""
@@ -542,7 +549,7 @@ def _sparse(g, params, i, x, h, lanes, pool, memory, lora=None,
                    heads=slice(head * each, (head + 1) * each))
             for head, layer in enumerate(layers)], axis=1)
     o = paged_sparse_attention(q, kv, layers, lanes.page_tables, slots,
-                               positions, sc)
+                               positions, lanes.live, sc)
     with scope("sparse_attn"):
         o = jnp.where((positions < sc.dense_len)[:, None, None],
                       o_dense, o)
@@ -629,9 +636,12 @@ def step_counts(g: Geometry, page_tables, positions, lane_slots,
         # counted where the lanes are made: a lane at position t sees
         # t // block + 1 blocks and selects min(topk, that) of them a
         # key/value head, whatever the scores say. What the device
-        # MOVES for it does not depend on the lanes: every lane of the
-        # step's width scores its table's every stride and gathers
-        # min(topk, a table's blocks) blocks, read or not
+        # MOVES for it: every lane of the step's width gathers
+        # min(topk, a table's blocks) blocks, read or not; of a table's
+        # compressed keys every stretch fetches one copy (its main
+        # sequence's) and the stray lanes a copy each, a stretch of
+        # them a trip, by the rule the step itself follows (the same
+        # functions over numpy)
         sc = arch.sparse
         past = positions[:live_lanes]
         past = past[past >= sc.dense_len]
@@ -639,6 +649,11 @@ def step_counts(g: Geometry, page_tables, positions, lane_slots,
         heads = arch.kv_heads * len(arch.sparse_layers)
         gathered = g.width * heads * min(
             sc.topk, c.pages_per_seq * ps // sc.block_size)
+        _, stray = main_slots(
+            lane_slots, np.arange(g.width) < live_lanes, np)
+        tiles = -(-g.width // LANE_TILE)
+        mixed = len(np.unique(np.flatnonzero(stray) // LANE_TILE))
+        copies = tiles + STRAY_TILE * int(stray_batches(stray, np))
         work.update(
             sparse_lanes=len(past),
             blocks_visible=int(visible.sum()) * heads,
@@ -646,8 +661,9 @@ def step_counts(g: Geometry, page_tables, positions, lane_slots,
             * heads,
             selected_kv_bytes=gathered * 2 * sc.block_size
             * arch.kv_head_dim * c.kv_itemsize,
-            selector_bytes=g.width * heads * c.pages_per_seq
-            * c.selector_dim * int(c.selector_dtype.itemsize))
+            selector_bytes=copies * heads * c.pages_per_seq
+            * c.selector_dim * int(c.selector_dtype.itemsize),
+            score_tiles=tiles, score_shared_tiles=tiles - mixed)
     # what the calls walk against the step's live work (LIVE_COUNTS): a
     # call's grid is its list's own length, the live lanes' items and
     # one a tile of the inactive ones

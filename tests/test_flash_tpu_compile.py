@@ -327,6 +327,7 @@ def test_minicpm_sala_mixed_step_compiles_within_its_memory_plan(topo,
                                                   build_minicpm_sala_lm)
     from flexflow_tpu.serve import ServeEngine
     from flexflow_tpu.serve.kv_cache import HybridPool
+    from flexflow_tpu.serve.sparse_paged import STRAY_TILE
     cfg = FFConfig(batch_size=1, kv_page_size=16, kv_num_pages=32769,
                    serve_max_seqs=32, serve_prefill_budget=512,
                    serve_spec_decode=False, serve_prefix_cache=False,
@@ -366,6 +367,28 @@ def test_minicpm_sala_mixed_step_compiles_within_its_memory_plan(topo,
     # no leaf of the pool is copied into another layout
     import re
     assert not re.search(r"= bf16\[2,32769,16,128\]\S* copy\(", text)
+    # the scores' two passes (PR 55): the keys the lanes of a stretch
+    # share are fetched in ONE gather of a (4096, 128) copy a stretch
+    # and head (17 x 4096 rows) and meet the stretch's 32 x 16 rows in
+    # one product a head; the stray lanes gather a copy a lane
+    # (STRAY_TILE x 4096 rows) once in the program and once in the one
+    # loop under `sparse_score`, whose trips are the further stretches
+    # of strays; no conditional (its branch ran the same gather 3.6
+    # times slower, and the step's logits left the reference's)
+    score = [line for line in text.splitlines() if "/sparse_score/" in line]
+    assert not any(" conditional(" in line for line in score)
+    assert sum(bool(re.search(r"\bwhile\(", line)) for line in score) == 1
+    shared = [line for line in score
+              if "nd,jd->nj" in line and " convolution(" in line]
+    assert len(shared) == 17 * 2
+    assert all("= f32[32,16,4096]" in line for line in shared)
+    fetch = {rows: [line for line in score if re.search(
+        rf"= bf16\[{rows},128\]\S* fusion\(.*gather", line)]
+        for rows in (17 * 4096, STRAY_TILE * 4096)}
+    assert len(fetch[17 * 4096]) == 2
+    assert not any("/while/" in line for line in fetch[17 * 4096])
+    assert len(fetch[STRAY_TILE * 4096]) == 2 * 2
+    assert sum("/while/" in line for line in fetch[STRAY_TILE * 4096]) == 2
 
 
 def _loops(text):

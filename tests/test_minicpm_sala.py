@@ -36,6 +36,8 @@ from flexflow_tpu.serve import ServeEngine  # noqa: E402
 from flexflow_tpu.serve.arch import MiniCPMSala, describe  # noqa: E402
 from flexflow_tpu.serve.kv_cache import (HybridPool, HybridSpec,  # noqa: E402
                                          KVCacheConfig, KVPool)
+from flexflow_tpu.serve.sparse_paged import (LANE_TILE,  # noqa: E402
+                                             STRAY_TILE, main_slots)
 
 VOCAB, HIDDEN, HEADS, KV_HEADS, HEAD_DIM = 128, 64, 8, 2, 16
 LIN_HEADS, LIN_DIM, FF = 4, 16, 96
@@ -343,15 +345,52 @@ def test_the_step_counts_what_was_selected(engine):
     assert sum(ev.sparse_lanes for ev in evs) == len(past)
     assert sum(ev.blocks_visible for ev in evs) == visible
     assert sum(ev.blocks_selected for ev in evs) == selected
-    # what the device gathers is the shapes': EVERY lane of the step's
-    # width takes topk blocks and scores its table's every stride, in
-    # both sparse layers and heads, live and past dense_len or not
+    # what the device gathers of K and V is the shapes': EVERY lane of
+    # the step's width takes topk blocks, in both sparse layers and
+    # heads, live and past dense_len or not
     c = engine.cache_cfg
-    lanes = engine.mixed_width * 2 * KV_HEADS
+    heads = 2 * KV_HEADS
     assert {ev.selected_kv_bytes for ev in evs} == {
-        lanes * SIZES["topk"] * 2 * block * HEAD_DIM * 4}
+        engine.mixed_width * heads * SIZES["topk"] * 2 * block * HEAD_DIM
+        * 4}
+    # of the compressed keys every stretch fetches ONE copy of its main
+    # sequence's strides and the stray lanes — those of another
+    # sequence — a copy each, a stretch of them a trip, the first trip
+    # standing in the program whatever is live (PR 55): one sequence
+    # here, so no lane strays, its chunks' and its decode lane's alike
+    tiles = -(-engine.mixed_width // LANE_TILE)
+    assert {(ev.score_tiles, ev.score_shared_tiles) for ev in evs} == {
+        (tiles, tiles)}
+    table = heads * c.pages_per_seq * HEAD_DIM * 4
     assert {ev.selector_bytes for ev in evs} == {
-        lanes * c.pages_per_seq * HEAD_DIM * 4}
+        (tiles + STRAY_TILE) * table}
+    # two sequences together: the lanes of the one with fewer lanes in
+    # a stretch stray, and the host's count is the rule the device
+    # follows (the one helper, over numpy there and jax.numpy here)
+    session = engine.start_session()
+    for n in (70, 90):
+        session.submit(_tokens(n, n), 3)
+    strayed = 0
+    while session.has_work():
+        ev = session.step()
+        if not ev.dispatched:
+            continue
+        rids = [ch.req.rid for ch in ev.plan.chunks
+                for _ in range(ch.start, ch.end)]
+        width = engine.mixed_width
+        slots = np.zeros(width, np.int32)
+        slots[:len(rids)] = rids
+        _, stray = main_slots(jnp.asarray(slots),
+                              jnp.arange(width) < len(rids))
+        fewer = min(rids.count(r) for r in set(rids)) \
+            if len(set(rids)) > 1 else 0
+        assert tiles == 1 and int(stray.sum()) == fewer
+        assert (ev.score_tiles, ev.score_shared_tiles) == (1, fewer == 0)
+        assert ev.selector_bytes == (
+            1 + STRAY_TILE * max(1, -(-fewer // STRAY_TILE))) * table
+        strayed += fewer
+    session.close()
+    assert strayed
     # the paged calls' fetches alone are `kv_bytes_read`
     assert all(ev.kv_bytes_read == ev.full_kv_bytes for ev in evs)
     # a state in and a state out for every run and layer
