@@ -92,6 +92,39 @@ def make_normal(mean: float = 0.0, stddev: float = 1.0, seed: int = 0):
     return init
 
 
+def named(initializer, name: str):
+    """An op's `kernel_initializer`: one for all its matrices, or a dict
+    with one a matrix by name."""
+    return initializer[name] if isinstance(initializer, dict) \
+        else initializer
+
+
+def make_normal_as(stddev: float, dtype, blocks: int = 16):
+    """normal(0, stddev) for a matrix too large to draw at once beside
+    the rest of a model (Falcon-H1's 261,120 x 5,120 head: 5 GB in f32,
+    twice that while `make_normal` scales it): drawn in f32 a block of
+    rows at a time into a buffer of `dtype` — what an f32-declared
+    weight is STORED as (FFConfig.param_dtype), so the executor's cast
+    after it is no copy; a weight declared in another dtype keeps
+    that."""
+    def init(key, shape, asked=jnp.float32, **_fans):
+        out = jnp.dtype(dtype) if jnp.dtype(asked) == jnp.float32 \
+            else jnp.dtype(asked)
+        n = max(b for b in range(1, blocks + 1) if shape[0] % b == 0)
+        rows = shape[0] // n
+
+        def a_block(i, buf):
+            blk = stddev * jax.random.normal(
+                jax.random.fold_in(key, i), (rows,) + tuple(shape[1:]),
+                jnp.float32)
+            return jax.lax.dynamic_update_slice_in_dim(
+                buf, blk.astype(out), i * rows, 0)
+
+        return jax.jit(lambda: jax.lax.fori_loop(
+            0, n, a_block, jnp.zeros(shape, out)))()
+    return init
+
+
 def he_normal(key, shape, dtype=jnp.float32, fan_in=None, fan_out=None):
     if fan_in is None:
         fan_in, _ = _fans(shape)

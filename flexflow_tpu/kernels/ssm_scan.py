@@ -49,9 +49,20 @@ TRIP = 8          # lanes a trip of the loop: one f32 sublane tile
 MAX_BLOCK = 640
 
 
-def supported(lanes: int, d_state: int, d_inner: int) -> bool:
-    """Whether the kernel takes this shape (else the jnp twin runs)."""
-    return lanes % TRIP == 0 and d_inner % 128 == 0 and d_state % 8 == 0
+VMEM_CAP = 64 * 2**20       # what `_vmem_limit` grants at most
+
+
+def supported(lanes: int, d_state: int, d_inner: int, rows: int = 0) -> bool:
+    """Whether the kernel takes this shape (else the jnp twin runs):
+    whole tiles, and — where the caller says how many slot `rows` the
+    slab has — a grid step's blocks (its column of EVERY row's state,
+    in and out) within the VMEM a call may ask for, so that it is
+    `Geometry.scan_impl` that falls to the twin and not Mosaic that
+    refuses the compile."""
+    if lanes % TRIP or d_inner % 128 or d_state % 8:
+        return False
+    return not rows or 2 * (block_bytes(
+        lanes, rows, d_state, choose_block(d_inner)) + 2 * 2**20) <= VMEM_CAP
 
 
 def choose_block(d_inner: int) -> int:
@@ -59,6 +70,14 @@ def choose_block(d_inner: int) -> int:
     MAX_BLOCK."""
     return max(w for w in range(128, min(d_inner, MAX_BLOCK) + 1, 128)
                if d_inner % w == 0)
+
+
+def block_bytes(lanes: int, rows: int, d_state: int, block: int) -> int:
+    """What one grid step holds in VMEM: the block's column of every
+    slot row's state in and out, of u, dt and y, of A_log and D, and
+    the lanes' b | c tiles."""
+    return 4 * block * (2 * rows * d_state + 3 * lanes + d_state + 1) \
+        + 4 * (lanes // TRIP) * d_state * 128
 
 
 def _scan_kernel(slots_ref, pos_ref, starts_ref, wslots_ref, meta_ref,
@@ -133,8 +152,6 @@ def _scan_pallas(a_log, d_skip, u, dt, b, c, state, lane_slots, positions,
             pl.BlockSpec((None, rows, n, block), row),
         ],
     )
-    block_bytes = 4 * block * (2 * rows * n + 3 * t + n + 1) \
-        + 4 * (t // TRIP) * n * 128
     y, state = pl.pallas_call(
         _scan_kernel, grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((t, d_inner), F32),
@@ -143,7 +160,8 @@ def _scan_pallas(a_log, d_skip, u, dt, b, c, state, lane_slots, positions,
         input_output_aliases={10: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
-            vmem_limit_bytes=_vmem_limit(block_bytes + 2 * 2**20)),
+            vmem_limit_bytes=_vmem_limit(
+                block_bytes(t, rows, n, block) + 2 * 2**20)),
         interpret=interpret,
         name="ssm_scan",
     )(lane_slots, positions, starts, wslots, meta,

@@ -244,7 +244,8 @@ class FFModel:
                             num_kv_heads: int = 0, window: int = 0,
                             rotary_interleaved: bool = False,
                             head_dim: int = 0,
-                            qk_norm_init=None) -> Tensor:
+                            qk_norm_init=None,
+                            key_multiplier: float = 1.0) -> Tensor:
         """`positions` ((batch, seq) int32) with `rotary_theta` > 0
         rotates q and k per head at those absolute positions
         (`rotary_interleaved`: neighbouring pairs, GPT-J's);
@@ -254,7 +255,8 @@ class FFModel:
         keys t - window + 1 .. t; `head_dim` > 0: heads of that size
         (num_heads * head_dim wide inside, embed_dim out);
         `qk_norm_init` (lo, hi): where the QK-norm's scales start
-        (core/initializers.range_init; None: at 1)."""
+        (core/initializers.range_init; None: at 1); `key_multiplier`:
+        a scalar on the key projection's output."""
         inputs = [query, key, value] \
             + ([positions] if positions is not None else [])
         op = MultiHeadAttention(
@@ -262,7 +264,7 @@ class FFModel:
             embed_dim, num_heads, kdim, vdim, dropout, bias, add_bias_kv,
             add_zero_attn, causal, kernel_initializer, use_flash,
             rotary_theta, qk_norm, qk_norm_eps, num_kv_heads, window,
-            rotary_interleaved, head_dim, qk_norm_init)
+            rotary_interleaved, head_dim, qk_norm_init, key_multiplier)
         return self.add_op(op).output
 
     # elementwise unary (model.h exp/relu/sigmoid/tanh/elu/scalar ops)
@@ -453,6 +455,23 @@ class FFModel:
             norm_init, kernel_initializer, allow_neg_eigval)
         return self.add_op(op).output
 
+    def mamba2_mixer(self, input: Tensor, heads: int, head_dim: int,
+                     groups: int, d_state: int, d_conv: int = 4,
+                     eps: float = 1e-5, in_multiplier: float = 1.0,
+                     multipliers=(1.0,) * 5, out_multiplier: float = 1.0,
+                     dt_range=(1e-3, 1e-1), a_range=(1.0, 16.0),
+                     norm_init=(1.0, 1.0), kernel_initializer="glorot",
+                     out_initializer=None,
+                     name: Optional[str] = None) -> Tensor:
+        """The Mamba-2 mixer (ops/ssd.py)."""
+        from .ops.ssd import Mamba2Mixer
+        op = Mamba2Mixer(
+            self, name or self._fresh_name("mamba2"), [input], heads,
+            head_dim, groups, d_state, d_conv, eps, in_multiplier,
+            multipliers, out_multiplier, dt_range, a_range, norm_init,
+            kernel_initializer, out_initializer)
+        return self.add_op(op).output
+
     def gated_attention(self, input: Tensor, positions: Tensor,
                         num_heads: int, num_kv_heads: int, head_dim: int,
                         rotary_theta: float = 1e7, rotary_dim: int = 0,
@@ -490,10 +509,13 @@ class FFModel:
 
     def gated_ffn(self, input: Tensor, hidden_dim: int,
                   name: Optional[str] = None,
-                  kernel_initializer="glorot") -> Tensor:
+                  kernel_initializer="glorot",
+                  multipliers=(1.0, 1.0)) -> Tensor:
+        """`multipliers`: scalars on the gate projection's output and
+        on the down projection's (ops/gated.gated_ffn)."""
         from .ops.gated import GatedFFN
         op = GatedFFN(self, name or self._fresh_name("gated_ffn"), [input],
-                      hidden_dim, kernel_initializer)
+                      hidden_dim, kernel_initializer, multipliers)
         return self.add_op(op).output
 
     def tied_head(self, input: Tensor, table: Tensor,

@@ -49,7 +49,7 @@ class MultiHeadAttention(Op):
                  qk_norm: bool = False, qk_norm_eps: float = 1e-5,
                  num_kv_heads: int = 0, window: int = 0,
                  rotary_interleaved: bool = False, head_dim: int = 0,
-                 qk_norm_init=None):
+                 qk_norm_init=None, key_multiplier: float = 1.0):
         super().__init__(model, name, inputs)
         # a fourth input, (batch, seq) int32 absolute positions, turns
         # the rotary embedding on (rotary_theta > 0 needs it)
@@ -61,6 +61,9 @@ class MultiHeadAttention(Op):
         self.qk_norm_init = None if qk_norm_init is None \
             else tuple(qk_norm_init)
         self.rotary_interleaved = bool(rotary_interleaved)
+        # a scalar on the key projection's output, before the rotation
+        # (Falcon-H1's muP `key_multiplier`; 1: none)
+        self.key_multiplier = float(key_multiplier)
         # GROUPED heads: query head j reads key/value head j // group;
         # `window` > 0: token t sees keys t - window + 1 .. t. Both run
         # as the dense masked softmax below (the flash kernel and the
@@ -133,18 +136,19 @@ class MultiHeadAttention(Op):
         h, d = self.num_heads, self.head_dim
         e = self.embed_dim
         hk = self.num_kv_heads
+        from ..core.initializers import named
+        init = lambda w: named(self.kernel_initializer, w)
         specs = {
-            "wq": WeightSpec((self.q_in, h, d), initializer=self.kernel_initializer,
+            "wq": WeightSpec((self.q_in, h, d), initializer=init("wq"),
                              axes=(CHANNEL_IN, HEAD, None),
                              fan_in=self.q_in, fan_out=h * d),
-            "wk": WeightSpec((self.k_in, hk, d), initializer=self.kernel_initializer,
+            "wk": WeightSpec((self.k_in, hk, d), initializer=init("wk"),
                              axes=(CHANNEL_IN, HEAD, None),
                              fan_in=self.k_in, fan_out=hk * d),
-            "wv": WeightSpec((self.v_in, hk, d), initializer=self.kernel_initializer,
+            "wv": WeightSpec((self.v_in, hk, d), initializer=init("wv"),
                              axes=(CHANNEL_IN, HEAD, None),
                              fan_in=self.v_in, fan_out=hk * d),
-            "wo": WeightSpec((h, d, e),
-                             initializer=self.kernel_initializer,
+            "wo": WeightSpec((h, d, e), initializer=init("wo"),
                              axes=(HEAD, None, CHANNEL_OUT),
                              fan_in=h * d, fan_out=e),
         }
@@ -202,6 +206,8 @@ class MultiHeadAttention(Op):
         if self.qk_norm:
             q = rms_norm(q, params["q_norm"], self.qk_norm_eps)
             k = rms_norm(k, params["k_norm"], self.qk_norm_eps)
+        if self.key_multiplier != 1.0:
+            k = k * self.key_multiplier
         if self.rotary_theta > 0:
             q = rotary(q, xs[3], self.rotary_theta, self.rotary_interleaved)
             k = rotary(k, xs[3], self.rotary_theta, self.rotary_interleaved)

@@ -28,9 +28,15 @@ def mm(x, w):
                    preferred_element_type=F32).astype(x.dtype)
 
 
-def gated_ffn(p, h):
+def gated_ffn(p, h, multipliers=(1.0, 1.0)):
+    """`multipliers`: scalars on the gate projection's output and on
+    the down projection's (Falcon-H1's muP `mlp_multipliers`)."""
+    gate_m, down_m = multipliers
     g, u = jnp.split(mm(h, p["w_gu"]), 2, axis=-1)
-    return mm(jax.nn.silu(g) * u, p["w_down"])
+    if gate_m != 1.0:
+        g = g * gate_m
+    y = mm(jax.nn.silu(g) * u, p["w_down"])
+    return y * down_m if down_m != 1.0 else y
 
 
 def gated_memory(p, h, m):
@@ -67,25 +73,29 @@ class GatedFFN(_SeqOp):
     op_type = "gated_ffn"
 
     def __init__(self, model, name, inputs, hidden_dim: int,
-                 kernel_initializer: str = "glorot"):
+                 kernel_initializer: str = "glorot",
+                 multipliers=(1.0, 1.0)):
         super().__init__(model, name, inputs)
         self.in_dim = self.out_dim = int(inputs[0].shape[-1])
         self.hidden_dim = int(hidden_dim)
         self.kernel_initializer = kernel_initializer
+        self.multipliers = tuple(map(float, multipliers))
         self.attrs = {"hidden_dim": self.hidden_dim}
 
     def weight_specs(self):
         e, f = self.in_dim, self.hidden_dim
+        from ..core.initializers import named
+        init = lambda w: named(self.kernel_initializer, w)
         return {
             "w_gu": WeightSpec((e, 2 * f), axes=(CHANNEL_IN, CHANNEL_OUT),
-                               initializer=self.kernel_initializer,
+                               initializer=init("w_gu"),
                                fan_in=e, fan_out=f),
             "w_down": WeightSpec((f, e), axes=(CHANNEL_IN, CHANNEL_OUT),
-                                 initializer=self.kernel_initializer),
+                                 initializer=init("w_down")),
         }
 
     def forward(self, params, xs, ctx: OpContext):
-        return [gated_ffn(params, xs[0])]
+        return [gated_ffn(params, xs[0], self.multipliers)]
 
     def flops(self) -> float:
         return 6.0 * _tokens(self.inputs[0].shape) * self.in_dim \

@@ -11,7 +11,7 @@ description reads the parameters of a compiled FFModel through the op
 names its builder wrote, and mirrors those ops' numerics. This module
 imports neither the engine nor the scheduler.
 
-Seven clients: `TransformerLM` (models/transformer.build_transformer_lm:
+Eight clients: `TransformerLM` (models/transformer.build_transformer_lm:
 learned positions, LayerNorm, ReLU feed-forward — the OPT block),
 `OLMoE` (models/olmoe.build_olmoe_lm: RMSNorm, rotary attention with
 QK-norm, dropless top-k SwiGLU experts), `Phi4Flash`
@@ -30,13 +30,17 @@ holds a share beside a gated shared expert) and `OlmoHybrid`
 (models/olmo_hybrid.build_olmo_hybrid_lm: the delta rule with negative
 eigenvalues in three layers of four, plain multi-head attention with
 QK-norm and no rotation in the fourth, a dense gated feed-forward, every
-norm AFTER its sub-layer).
+norm AFTER its sub-layer) and `FalconH1`
+(models/falcon_h1.build_falcon_h1_lm: Mamba-2 heads with a matrix state
+and a convolution tail a sequence BESIDE grouped rotary attention on
+pages in every layer, both read from the layer's one norm, a dense gated
+feed-forward, scalar multipliers on the activations).
 
 What a description answers (docs/serving.md "What a description must
 answer"): the dimensions; per layer the MIXER KIND (`mixer(i)`: one of
-the nine names serve/mixers.py has a body for — "attn",
+the ten names serve/mixers.py has a body for — "attn",
 models/phi4flash's five, models/minicpm_sala's two, models/qwen3_next's
-one) and the
+one, models/falcon_h1's one, which runs TWO sequence mixers) and the
 projections that kind's body calls; the geometry of the K/V it
 pages (`kv_heads`, `kv_head_dim`, `paged_layers`, `attn_scale`); what a
 sequence holds besides pages (`hybrid_spec`, a serve/kv_cache.HybridSpec
@@ -50,6 +54,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..models.falcon_h1 import SSD_ATTN
 from ..models.minicpm_sala import LINEAR, SPARSE
 from ..models.phi4flash import CROSS, FULL, GMU, SSM, WINDOW
 from ..models.qwen3_next import DELTA
@@ -58,6 +63,7 @@ from ..ops import gated_attention as GA
 from ..ops import gated_delta as GD
 from ..ops import linear_attention as LA
 from ..ops import sparse_attention as SA
+from ..ops import ssd as SD
 from ..ops import ssm as S
 from ..ops.common import rms_norm, rotary
 from ..ops.gated import gated_ffn, gated_memory
@@ -1264,7 +1270,150 @@ class OlmoHybrid(_DeltaAndFull, Description):
                              positions=self.rope_theta > 0)
 
 
-SHAPES = (TransformerLM, OlmoHybrid, Qwen3Next, OLMoE, Phi4Flash,
+class FalconH1(Description):
+    """The build_falcon_h1_lm block (models/falcon_h1.py holds the
+    equations, ops/ssd.py the Mamba-2 heads'). Served by the mixed step
+    on one device.
+
+    EVERY layer is of the one kind SSD_ATTN, whose body
+    (serve/mixers.py) runs two sequence mixers off the layer's one norm
+    and adds both branches to the stream once — so every layer is paged
+    AND holds state. What it pages: each layer's K and V, `kv_heads`
+    grouped heads of `head_dim`, the keys scaled by `key_multiplier` and
+    rotated. What a sequence holds besides (`hybrid_spec`): for each
+    layer an f32 matrix state (N, H P) (ops/ssd.Dims.state_shape) and a
+    convolution tail of d_conv - 1 rows over the x, B and C channels; no
+    ring. The multipliers stand where the builder's ops put them."""
+
+    kind = "falcon_h1"
+    builder = "build_falcon_h1_lm"
+    reads = ("tok_embed", "embed_scale", "lm_head", "logit_scale",
+             "layer0_ssm", "layer0_attn", "layer0_mlp", "final_norm")
+    _state = Qwen3Next._state
+    refused = {
+        "tp": "single-device: the matrix states, their two groups and "
+              "the grouped heads are not split over a mesh (ROADMAP M1)",
+        "adapters": "no adapter pool for the two mixers of a layer and "
+                    "the gated feed-forward",
+        **{path: Qwen3Next.refused[path] for path in (
+            "speculation", "prefix_cache", "host_tier", "handoff")},
+    }
+
+    def __init__(self, model, ops):
+        self.model = model
+        self.vocab_size = ops["tok_embed"].num_entries
+        self.layer_norm = True
+        n = 0
+        while f"layer{n}_ssm" in ops:
+            n += 1
+        self.num_layers = n
+        attns = [ops.get(f"layer{i}_attn") for i in range(n)]
+        if not all(a is not None and a.causal and not a.window
+                   and not a.qk_norm and a.rotary_theta > 0
+                   for a in attns):
+            raise ValueError(
+                "ServeEngine reads a build_falcon_h1_lm-shaped model: "
+                "Mamba-2 heads AND causal rotary attention in every "
+                "layer")
+        # every layer writes pages AND a state slot and a tail
+        self.full_layers = self.ssd_layers = list(range(n))
+        attn, ssm = attns[0], ops["layer0_ssm"]
+        self.ssd = ssm                          # a layer's Mamba-2 op
+        self.num_heads, self.head_dim = attn.num_heads, attn.head_dim
+        self._kv_heads = attn.num_kv_heads
+        self.rope_theta = attn.rotary_theta
+        self.hidden = attn.embed_dim
+        self.ln_eps = ops["layer0_ln"].eps
+        self.act_dtype = jnp.dtype(ops["tok_embed"].out_dtype)
+        self.ff_dim = ops["layer0_mlp"].hidden_dim
+        self.embedding_multiplier = ops["embed_scale"].scalar
+        self.lm_head_multiplier = ops["logit_scale"].scalar
+        self.attention_in_multiplier = ops["layer0_attn_in"].scalar
+        self.attention_out_multiplier = ops["layer0_attn_scale"].scalar
+        self.key_multiplier = attn.key_multiplier
+        self.mlp_multipliers = ops["layer0_mlp"].multipliers
+        # no table is sized by them: the positions served are the graph's
+        self.max_positions = int(ops["tok_embed"].inputs[0].shape[1])
+
+    def mixer(self, i: int) -> str:
+        return SSD_ATTN
+
+    def hybrid_spec(self, chunk: int):
+        from .kv_cache import HybridSpec
+        return HybridSpec(
+            window_layers=0, window=0, chunk=int(chunk),
+            state_layers=self.num_layers,
+            state_shape=self.ssd.dims.state_shape,
+            tail_shape=(self.ssd.d_conv - 1, self.ssd.dims.channels),
+            tail_dtype=str(self.act_dtype))
+
+    @property
+    def kv_heads(self) -> int:
+        return self._kv_heads
+
+    def embed(self, params, tokens, positions):
+        return jnp.take(params["tok_embed"]["kernel"], tokens, axis=0,
+                        mode="clip").astype(self.act_dtype) \
+            * self.embedding_multiplier
+
+    def norm1(self, params, i, x):
+        """The layer's ONE norm: both mixers read it."""
+        return rms_norm(x, params[f"layer{i}_ln"]["scale"], self.ln_eps)
+
+    # the Mamba-2 branch, in the pieces the step scopes apart
+    def ssd_in(self, params, i, h):
+        """-> (z (T, d_ssm), the convolution's raw input [x | B | C],
+        the raw dt (T, H)), the multipliers applied."""
+        m = self.ssd
+        return SD.project(params[f"layer{i}_ssm"], h, m.dims,
+                          m.in_multiplier, m.multipliers)
+
+    def ssd_scan_inputs(self, params, i, u, dt):
+        return SD.scan_inputs(params[f"layer{i}_ssm"], u, dt,
+                              self.ssd.dims)
+
+    def ssd_out(self, params, i, y, z):
+        """The Mamba-2 BRANCH alone."""
+        m = self.ssd
+        return SD.gate_and_project(params[f"layer{i}_ssm"], y, z, m.dims,
+                                   m.eps, m.out_multiplier)
+
+    def qkv(self, params, i, h, positions, lora=None):
+        """h (T, E) -> q (T, H, D), k, v (T, Hk, D): the projections of
+        h * attention_in_multiplier, the keys times key_multiplier, q
+        and k rotated at the lanes' positions."""
+        if self.attention_in_multiplier != 1.0:
+            h = h * self.attention_in_multiplier
+        q, k, v = _project(params[f"layer{i}_attn"], h)
+        if self.key_multiplier != 1.0:
+            k = k * self.key_multiplier
+        return (rotary(q, positions, self.rope_theta),
+                rotary(k, positions, self.rope_theta), v)
+
+    def attn_out(self, params, i, o, x, psum_axis=None, lora=None):
+        """The attention BRANCH alone."""
+        p = params[f"layer{i}_attn"]
+        return jnp.einsum("...hd,hde->...e", o, p["wo"].astype(o.dtype)) \
+            * self.attention_out_multiplier
+
+    def ffn(self, params, i, x, live=None, psum_axis=None, lora=None):
+        with jax.named_scope("ffn"):
+            h = rms_norm(x, params[f"layer{i}_ln2"]["scale"], self.ln_eps)
+            return x + gated_ffn(params[f"layer{i}_mlp"], h,
+                                 self.mlp_multipliers), None
+
+    def final_norm(self, params, x):
+        return rms_norm(x, params["final_norm"]["scale"], self.ln_eps)
+
+    def head(self, params, x):
+        return _dense(params["lm_head"], self.final_norm(params, x)) \
+            * self.lm_head_multiplier
+
+    def forward_logits(self, params, tokens):
+        return _graph_logits(self.model, params, tokens, positions=True)
+
+
+SHAPES = (TransformerLM, FalconH1, OlmoHybrid, Qwen3Next, OLMoE, Phi4Flash,
           CommandAPlus, MiniCPMSala)
 
 
@@ -1272,7 +1421,7 @@ def describe(model):
     """The description of a compiled FFModel, chosen by the op names
     its builder wrote: the first of SHAPES whose names are all there
     (Qwen3Next's before OLMoE's, whose names it has too; OlmoHybrid's
-    are nobody else's)."""
+    and FalconH1's are nobody else's)."""
     ops = {op.name: op for op in model.ops}
     for cls in SHAPES:
         if all(n in ops for n in cls.reads):

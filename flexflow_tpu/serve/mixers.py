@@ -22,21 +22,22 @@ touches: docs/serving.md "What a new architecture touches".
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels import gated_delta_scan, ssm_scan
+from ..kernels import gated_delta_scan, ssd_scan, ssm_scan
 from ..kernels.paged_ragged_v2 import (JNP, PALLAS_INTERPRET, Q_ROWS,
                                        WorkList, build_work_list,
                                        kv_page_bytes, max_work_items,
                                        paged_attention_ragged_v2,
                                        window_block_bound, work_items)
-from ..ops import gated_delta, linear_attention, ssm
-from .arch import (ATTN, CROSS, DELTA, FULL, GMU, LINEAR, SPARSE, SSM,
-                   WINDOW)
+from ..ops import gated_delta, linear_attention, ssd, ssm
+from .arch import (ATTN, CROSS, DELTA, FULL, GMU, LINEAR, SPARSE, SSD_ATTN,
+                   SSM, WINDOW)
 from .kv_cache import KVCacheConfig, ring_tables
 from .sparse_paged import (LANE_TILE, STRAY_TILE, main_slots,
                            paged_sparse_attention, stray_batches,
@@ -59,12 +60,15 @@ HYBRID_COUNTS = ("state_bytes", "window_kv_bytes", "full_kv_bytes")
 # lane by lane and the blocks of lanes that take the chunk form
 # (ops/gated_delta.block_forms), a layer
 DELTA_COUNTS = ("delta_lanes", "delta_chunk_blocks")
+# ... where a layer runs Mamba-2 heads: the same two of its recurrence
+# (one rule, one plan: ops/ssd.py takes the delta rule's)
+SSD_COUNTS = ("ssd_lanes", "ssd_chunk_blocks")
 # what StepEvents takes of every step and the span does not (it has
 # the first three under older names)
 EVENT_COUNTS = ("kv_bytes_read", "attn_items", "attn_rows", "ssm_runs",
                 "lanes_past_window")
 STEP_COUNTS = EVENT_COUNTS + LIVE_COUNTS + HYBRID_COUNTS + SELECT_COUNTS \
-    + DELTA_COUNTS
+    + DELTA_COUNTS + SSD_COUNTS
 
 
 # ------------------------------------------------------------- geometry
@@ -90,8 +94,11 @@ class Geometry:
     counted: Tuple[str, ...]
     # how the delta layers' slab holds a state (ops/gated_delta.
     # state_layout: the layout's name, a state's rows, its resident
-    # bytes); {}: no such layer
+    # bytes) or the Mamba-2 layers' theirs (`ssd_state_*`); {}: no such
+    # layer
     delta_state: dict = dataclasses.field(default_factory=dict)
+    # a layer runs Mamba-2 heads: its lanes take the delta rule's plan
+    ssd: bool = False
 
     @property
     def attn_kw(self) -> dict:
@@ -101,12 +108,12 @@ class Geometry:
 
 def attn_calls(arch) -> Tuple[int, int]:
     """The paged calls a step makes, from the layers' kinds: one on the
-    full pages' list an ATTN, FULL or CROSS layer (a cross layer reads
-    the full layer's pages), a call a key/value head a SPARSE layer
+    full pages' list an ATTN, FULL, CROSS or SSD_ATTN layer (a cross
+    layer reads the full layer's pages), a call a key/value head a SPARSE layer
     (each head's pages are a pool layer of their own), one on the
     window layers' list a WINDOW layer."""
     kinds = [arch.mixer(i) for i in range(arch.num_layers)]
-    full = sum(kinds.count(k) for k in (ATTN, FULL, CROSS)) \
+    full = sum(kinds.count(k) for k in (ATTN, FULL, CROSS, SSD_ATTN)) \
         + arch.kv_heads * kinds.count(SPARSE)
     return full, kinds.count(WINDOW)
 
@@ -141,13 +148,23 @@ def geometry(arch, cfg: KVCacheConfig, *, width: int, attn_impl: str,
     delta_state = {}
     if hyb is not None and hyb.state_layers:
         scan_impl = attn_impl if SSM in kinds and ssm_scan.supported(
-            width, *hyb.state_shape) else JNP
+            width, *hyb.state_shape, rows=cfg.max_seqs + 1) else JNP
     if DELTA in kinds:
         d = arch.delta
         delta_impl = attn_impl if gated_delta_scan.supported(
             width, d.value_heads, d.key_dim, d.value_dim) else JNP
         delta_state = gated_delta.state_layout(
             d.value_heads, d.key_dim, d.value_dim)
+    if SSD_ATTN in kinds:
+        # Mamba-2's lanes resolve as the state-space scan does, under
+        # `scan_impl`: kernels/ssd_scan.py where it takes the step's
+        # shape, else its twin (ops/ssd.lane_pass)
+        d = arch.ssd.dims
+        scan_impl = attn_impl if ssd_scan.supported(width, *d) else JNP
+        delta_state = {"ssd_state_layout": "state_rows_by_head_lanes",
+                       "ssd_state_shape": d.state_shape,
+                       "ssd_state_slot_bytes":
+                           4 * d.state_shape[0] * d.state_shape[1]}
     block_pages = max(1, block_kv // cfg.page_size)
     # a model that SELECTS its context (arch.dense_len) walks pages in
     # the paged kernel only for its lanes under dense_len: the list is
@@ -179,7 +196,8 @@ def geometry(arch, cfg: KVCacheConfig, *, width: int, attn_impl: str,
     ) if arch.window else 0
     counted = LIVE_COUNTS + (HYBRID_COUNTS if hyb is not None else ()) \
         + (SELECT_COUNTS if dense_pages else ()) \
-        + (DELTA_COUNTS if DELTA in kinds else ())
+        + (DELTA_COUNTS if DELTA in kinds else ()) \
+        + (SSD_COUNTS if SSD_ATTN in kinds else ())
     return Geometry(
         arch=arch, cfg=cfg, width=width, attn_impl=attn_impl,
         block_kv=block_kv, block_pages=block_pages, scan_impl=scan_impl,
@@ -187,7 +205,7 @@ def geometry(arch, cfg: KVCacheConfig, *, width: int, attn_impl: str,
         dense_pages=dense_pages, attn_max_items=attn_max_items,
         window_max_items=window_max_items, attn_calls=attn_calls(arch),
         rings=ring_tables(cfg) if cfg.ring_pages else None,
-        counted=counted, delta_state=delta_state)
+        counted=counted, delta_state=delta_state, ssd=SSD_ATTN in kinds)
 
 
 def walked(g: Geometry, page_tables, positions, lane_lens, xp=np):
@@ -243,7 +261,8 @@ class Lanes(NamedTuple):
     ring_pages: Any = None
     window_work: Optional[WorkList] = None
     # the delta rule's lanes as its kernel walks them (ops/gated_delta.
-    # lane_plan); None: no such layer, or the twin sorts its own
+    # lane_plan), and Mamba-2's as either of its lane passes does; None:
+    # no such layer, or the delta rule's twin sorts its own
     delta_plan: Optional[gated_delta.LanePlan] = None
 
 
@@ -289,7 +308,7 @@ def step_lanes(g: Geometry, positions, write_pages, write_offs,
             if c.hybrid.tail_shape[0] > 0:
                 made["tail_lanes"] = ssm.run_tail_lanes(
                     made["wslots"], c.max_seqs)
-            if g.delta_impl not in (None, JNP):
+            if g.delta_impl not in (None, JNP) or g.ssd:
                 made["delta_plan"] = gated_delta.lane_plan(
                     lane_slots, positions, live, starts, made["live_lanes"])
         if ringed:
@@ -340,8 +359,9 @@ def _attention(g, params, i, x, h, lanes, pool, memory, lora=None,
     where `qkv` bore an output gate (arch.output_gate), `attn_out`. The
     kind says which pages it writes and reads: ATTN layer i of the one
     pool; WINDOW its own layer of the rings, under
-    the window's list; FULL its own of a hybrid pool's paged layers;
-    CROSS the first of those, writing nothing."""
+    the window's list; FULL (and the attention half of SSD_ATTN) its own
+    of a hybrid pool's paged layers; CROSS the first of those, writing
+    nothing."""
     scope = jax.named_scope
     arch = g.arch
     kind = arch.mixer(i)
@@ -358,7 +378,7 @@ def _attention(g, params, i, x, h, lanes, pool, memory, lora=None,
         kv, layer = pool.window, arch.window_layers.index(i)
         write_pages, page_tables = lanes.ring_pages, lanes.rings
         work, window = lanes.window_work, arch.window
-    elif kind == FULL:
+    elif kind in (FULL, SSD_ATTN):
         kv, layer = pool.full, arch.full_layers.index(i)
     else:
         kv, layer = pool.full, 0
@@ -367,7 +387,7 @@ def _attention(g, params, i, x, h, lanes, pool, memory, lora=None,
             kv = kv.write(layer, write_pages, lanes.write_offs, k, v)
         if kind == WINDOW:
             pool = dataclasses.replace(pool, window=kv)
-        elif kind == FULL:
+        elif kind in (FULL, SSD_ATTN):
             pool = dataclasses.replace(pool, full=kv)
         else:
             pool = kv
@@ -505,6 +525,61 @@ def _delta(g, params, i, x, h, lanes, pool, memory, lora=None,
     return x, pool, memory
 
 
+def _ssd(g, params, i, h, lanes, pool):
+    """Layer `i`'s Mamba-2 heads over `h` -> (their BRANCH alone, the
+    pool): `ssm_proj` (the in-projection and its multipliers; the gated
+    per-group norm and the out-projection), `ssm_conv` (the convolution
+    over a run and its slot's tail, silu), `ssm_scan` (the recurrence
+    from each run's slot state and the state's write-back, in f32 and in
+    place in the pool's slab: kernels/ssd_scan.py for the lanes that go
+    lane by lane, or its twin ops/ssd.lane_pass; whole blocks of one run
+    as products)."""
+    scope = jax.named_scope
+    arch = g.arch
+    j = arch.ssd_layers.index(i)
+    with scope("ssm_proj"):
+        # held as it is computed: at the published size (13.6 GiB of
+        # arguments) XLA's rematerialization otherwise recomputes the
+        # whole in-projection for each of its seven readers — z, dt and
+        # the convolution's shifted rows — 2.0 ms a layer for 0.3
+        # (PERF.md section 6, PR 56)
+        z, u, dt = jax.lax.optimization_barrier(arch.ssd_in(params, i, h))
+    with scope("ssm_conv"):
+        u, tail = ssm.segmented_conv(
+            params[f"layer{i}_ssm"], u, pool.tail[j], lanes.lane_slots,
+            lanes.positions, lanes.offsets, lanes.tail_lanes)
+        u = jax.nn.silu(u)
+    with scope("ssm_scan"):
+        forms, skip = arch.ssd_scan_inputs(params, i, u, dt)
+        # the kernel keeps an f32 slab in place; a slab of another
+        # dtype (no configuration's) keeps the twin
+        lane_pass = functools.partial(
+            ssd_scan.lane_pass, interpret=g.scan_impl == PALLAS_INTERPRET
+        ) if g.scan_impl != JNP and pool.state.dtype == jnp.float32 \
+            else ssd.lane_pass
+        y, state = ssd.segmented(
+            *forms, pool.state, j, lanes.lane_slots, lanes.positions,
+            lanes.delta_plan, lane_pass=lane_pass)
+        y = y + skip
+        pool = dataclasses.replace(
+            pool, state=state, tail=pool.tail.at[j].set(tail))
+    with scope("ssm_proj"):
+        return arch.ssd_out(params, i, y, z), pool
+
+
+def _ssd_and_attention(g, params, i, x, h, lanes, pool, memory, lora=None,
+                       tp_axis=None):
+    """TWO sequence mixers off the layer's one norm (Falcon-H1): the
+    Mamba-2 heads (`_ssd`), then the attention layer's scopes
+    (`_attention`) on the layer's own pages, both of `h`; each branch
+    comes back ALONE and `residual` adds them to x once."""
+    s, pool = _ssd(g, params, i, h, lanes, pool)
+    a, pool, memory = _attention(g, params, i, x, h, lanes, pool, memory,
+                                 lora, tp_axis)
+    with jax.named_scope("residual"):
+        return x + (s + a), pool, memory
+
+
 def _sparse(g, params, i, x, h, lanes, pool, memory, lora=None,
             tp_axis=None):
     """A block-sparse attention layer: `qkv`, `kv_write`,
@@ -560,7 +635,8 @@ def _sparse(g, params, i, x, h, lanes, pool, memory, lora=None,
 
 BODIES = {ATTN: _attention, WINDOW: _attention, FULL: _attention,
           CROSS: _attention, SSM: _state_space, GMU: _gated_memory,
-          LINEAR: _linear, SPARSE: _sparse, DELTA: _delta}
+          LINEAR: _linear, SPARSE: _sparse, DELTA: _delta,
+          SSD_ATTN: _ssd_and_attention}
 
 
 # --------------------------------------------------------------- counts
@@ -622,14 +698,15 @@ def step_counts(g: Geometry, page_tables, positions, lane_slots,
             if arch.window else 0,
             ssm_runs=runs if c.hybrid.state_layers else 0,
             state_bytes=2 * runs * c.hybrid.state_bytes)
-    if g.delta_impl is not None:
+    if g.delta_impl is not None or g.ssd:
         # which form each block of lanes takes, by the rule the step
         # itself follows (the same function over numpy)
         live = np.arange(g.width) < live_lanes
         as_chunk, count, _ = gated_delta.block_forms(
             ssm.run_starts(lane_slots, positions, np), live, live_lanes, np)
-        work.update(delta_lanes=int(count[~as_chunk].sum()),
-                    delta_chunk_blocks=int(as_chunk.sum()))
+        by_lane, by_block = SSD_COUNTS if g.ssd else DELTA_COUNTS
+        work.update({by_lane: int(count[~as_chunk].sum()),
+                     by_block: int(as_chunk.sum())})
     work["kv_bytes_read"] = full + ringed
     if g.dense_pages:
         # what the selection does for the live lanes past dense_len,

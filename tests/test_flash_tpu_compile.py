@@ -726,3 +726,126 @@ def test_the_convolution_s_tail_write_back_is_no_loop_and_no_scatter(
     assert len(re.findall(r"dynamic-update-slice\(", text)) == 1
     # the tail is updated where it lies
     assert compiled.memory_analysis().alias_size_in_bytes > 0
+
+
+def test_ssd_lane_kernel_compiles_in_place_for_a_v5e(topo, as_tpu):
+    """Mamba-2's lanes at Falcon-H1's served shape (PR 56) — 608 lanes,
+    32 heads of 128 x 256 in 2 groups, 97 slot rows, six layers in one
+    donated slab of (256, 4096) states, 4 MiB each: Mosaic takes the
+    kernel with three states in VMEM and B's and C's eight rows of one
+    transposed tile; it is called on either side of the chunk-form
+    blocks' loop, the slab is moved on where it lies (no copy of it or
+    of a layer's row), and the temporaries are the lanes' own rows."""
+    import functools
+    import re
+
+    from flexflow_tpu.kernels import ssd_scan as ks
+    from flexflow_tpu.ops import gated_delta as gd
+    from flexflow_tpu.ops import ssd
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    t, rows, h, p, g, n, layers = 608, 97, 32, 128, 2, 256, 6
+    assert ks.supported(t, h, p, g, n)
+    assert ssd.Dims(h, p, g, n).state_shape == (256, 4096)
+
+    def call(v, b, c, la, slab, slots, pos, live, starts, count):
+        plan = gd.lane_plan(slots, pos, live, starts, count)
+        return ssd.segmented(v, b, c, la, slab, 4, slots, pos, plan,
+                             lane_pass=functools.partial(ks.lane_pass))
+
+    lane, flag = sds((t,), jnp.int32), sds((t,), jnp.bool_)
+    compiled = jax.jit(call, donate_argnums=(4,)).lower(
+        sds((t, h, p)), sds((t, g, n)), sds((t, g, n)), sds((t, h)),
+        sds((layers, rows, n, h * p)), lane, lane, flag, flag,
+        sds((), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 2 and all("ssd_scan" in c for c in calls)
+    assert len(re.findall(r"\bwhile\(", text)) == 1
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"\b(copy|copy-start|reshape)\(", line)
+             and re.search(r"= f32\[(6,)?(1,)?97,", line)]
+    assert not moved, moved
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 4 * layers * rows * n * h * p
+    assert m.temp_size_in_bytes < 2**26, m.temp_size_in_bytes
+
+
+def test_falcon_h1_mixed_step_compiles_within_its_memory_plan(topo, as_tpu):
+    """Falcon-H1's mixed step at its served widths (608 lanes of 5120;
+    in EVERY layer 32 Mamba-2 heads of 128 x 256 on a slab of 97 rows
+    beside 20 / 4 attention heads of 128 on a head-packed pool of 8,193
+    pages, 2,048 B a token a layer; the dense feed-forward 21,504 wide;
+    8,192 positions, 96 slots; TWO layers and a small vocabulary, so the
+    parameters are quick to make; PR 56): it compiles for a v5e with the
+    paged kernel at 20 / 4 heads reading its leaf where it lies and
+    Mamba-2's lanes in their kernel, twice a layer; the pool — pages,
+    states, tails — is updated in place with no copy of the state slab
+    or of a pages' leaf, a layer's only loop is the chunk-form blocks'
+    (no scatter expanded to a loop of one-row updates), and the step's
+    temporaries stay under a quarter of ONE layer's slab of states."""
+    import re
+    from flexflow_tpu import FFConfig
+    from flexflow_tpu.config import CompMode
+    from flexflow_tpu.models.falcon_h1 import build_falcon_h1_lm
+    from flexflow_tpu.serve import ServeEngine, mixers
+    from flexflow_tpu.serve.kv_cache import HybridPool
+    cfg = FFConfig(batch_size=1, kv_page_size=16, kv_num_pages=8193,
+                   serve_max_seqs=96, serve_prefill_budget=512,
+                   serve_spec_decode=False, serve_prefix_cache=False,
+                   compute_dtype="bfloat16", param_dtype="bfloat16",
+                   kv_dtype="bfloat16")
+    lm = build_falcon_h1_lm(
+        cfg, vocab_size=2048, max_seq_len=8192, num_layers=2,
+        embedding_multiplier=5.657, lm_head_multiplier=0.0078125,
+        ssm_in_multiplier=0.25,
+        ssm_multipliers=(0.354, 0.25, 0.177, 0.5, 0.354),
+        ssm_out_multiplier=0.0884, attention_out_multiplier=0.0375,
+        key_multiplier=0.011, mlp_multipliers=(0.177, 0.0112))
+    lm.compile(comp_mode=CompMode.INFERENCE)
+    engine = ServeEngine(lm)
+    assert (engine.attn_impl, engine.scan_impl) == ("pallas", "pallas")
+    assert (engine.mixed_width, engine.head_rows) == (608, 96)
+    assert engine.geometry.delta_state["ssd_state_slot_bytes"] == 4194304
+    assert mixers.paged_calls(engine.geometry) == {
+        "paged_calls": 2, "paged_calls_in_place": 2}
+    one = SingleDeviceSharding(topo.devices[0])
+    c = engine.cache_cfg
+    assert (c.pages_per_seq, c.cache_bytes_per_token, c.packed_heads) == (
+        512, 4096, True)
+    pool = jax.eval_shape(lambda: HybridPool.alloc(c))
+    assert pool.state.shape == (2, 97, 256, 4096)
+    assert pool.tail.shape == (2, 97, 15360)
+    assert pool.full.k.shape == (2, 8193, 16, 512)
+    lane = jax.ShapeDtypeStruct((608,), jnp.int32, sharding=one)
+    rows = jax.ShapeDtypeStruct((96,), jnp.int32, sharding=one)
+    tables = jax.ShapeDtypeStruct((c.max_seqs, c.pages_per_seq), jnp.int32,
+                                  sharding=one)
+    compiled = jax.jit(engine._mixed_impl, donate_argnums=(1,)).lower(
+        _sds(engine._step_params, one), _sds(pool, one), lane, lane, lane,
+        lane, tables, lane, lane, rows, lane, rows).compile()
+    engine.close()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 6
+    assert sum("paged_ragged_v2" in c for c in calls) == 2
+    assert sum("ssd_scan" in c for c in calls) == 4
+    # a layer's ONLY loop is the chunk-form blocks'; nothing scatters
+    # into the tail or updates it inside a loop
+    assert [_scope_of(line) for line in _loops(text)] == ["ssm_scan"] * 2
+    assert "conditional(" not in text
+    assert not re.search(r"scatter\(\S*bf16\[(2,)?97,15360\]", text)
+    for body in _loop_bodies(text):
+        assert "bf16[97,15360]" not in body and "bf16[2,97,15360]" not in body
+    m = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(pool))
+    assert m.alias_size_in_bytes >= pool_bytes
+    assert m.temp_size_in_bytes < 97 * 4194304 // 4, m.temp_size_in_bytes
+    # neither the state slab nor a pages' leaf is copied or re-laid
+    assert not re.search(r"= f32\[2,97,\S* (copy|reshape)\(", text)
+    assert not re.search(r"= f32\[(1,)?97,256,4096\]\S* copy\(", text)
+    assert not re.search(r"= bf16\[(2,)?8193,16,512\]\S* copy\(", text)

@@ -20,10 +20,11 @@ from flexflow_tpu.serve import ServeEngine
 from flexflow_tpu.serve import engine as E
 from flexflow_tpu.serve import mixers as M
 from flexflow_tpu.serve.arch import (ATTN, CROSS, DELTA, FULL, GMU, LINEAR,
-                                     SPARSE, SSM, WINDOW)
+                                     SPARSE, SSD_ATTN, SSM, WINDOW)
 from flexflow_tpu.serve.kv_cache import ring_tables
 
-KINDS = (ATTN, WINDOW, FULL, CROSS, SSM, GMU, LINEAR, SPARSE, DELTA)
+KINDS = (ATTN, WINDOW, FULL, CROSS, SSM, GMU, LINEAR, SPARSE, DELTA,
+         SSD_ATTN)
 # description -> (the test module whose `_lm` builds it, the paged calls
 # a step of that build as the hand-written `attn_calls()` answered
 # before ISSUE 48)
@@ -96,8 +97,8 @@ def test_the_calls_a_step_at_the_served_depths(name, arch, calls):
     assert M.attn_calls(arch) == calls
 
 
-def test_the_table_is_the_nine_kinds():
-    assert set(M.BODIES) == set(KINDS) and len(set(KINDS)) == 9
+def test_the_table_is_the_ten_kinds():
+    assert set(M.BODIES) == set(KINDS) and len(set(KINDS)) == 10
 
 
 # ------------------------------------------------ (b) the arrows, one way
