@@ -382,7 +382,7 @@ SMEM_LIST_WORDS = 128 * 1024
 
 @functools.partial(
     jax.tree_util.register_dataclass,
-    data_fields=["tile", "blk", "meta", "pages", "lens", "count"],
+    data_fields=["tile", "blk", "meta", "pages", "lens", "count", "masks"],
     meta_fields=["q_rows", "block_pages"])
 @dataclasses.dataclass(frozen=True)
 class WorkList:
@@ -398,6 +398,11 @@ class WorkList:
     count: Any   # () the step's items, in [1, n]: the steps a call walks
     q_rows: int
     block_pages: int
+    # a list made of a SELECTION (`build_select_list`): ((n + 1) * S,)
+    # one word for each of the S selection blocks of an item's kv-block,
+    # bit r set where row r of the tile may see that block's keys. None:
+    # every row of the run sees every key up to its own length
+    masks: Any = None
 
 
 def _work_arrays(xp, cummax, page_tables, lane_slots, lane_lens, *,
@@ -584,6 +589,279 @@ def kv_read_bytes(lane_lens, lane_slots, page_tables, *, page_size: int,
                       quantized)
 
 
+# --------------------------------------------- a list made of a selection
+# A model that SELECTS its context (serve/sparse_paged.py) hands the
+# kernel a list whose items are CHOSEN: row r of the step attends the
+# `select_pages`-page selection blocks `blocks[r, :]` where `chosen`,
+# and no other key. The list keeps the dense one's shape — tiles, runs,
+# (run, kv-block) items, one fetch for all the rows of a run, `blk` the
+# kv-block's index in the run's table row — with two differences: a run
+# has an item only for a kv-block that holds a block SOME row of it
+# chose (the items are no longer consecutive blocks), and an item
+# carries a ROW-MASK WORD for each of the S = block_pages /
+# select_pages selection blocks of its kv-block: bit r set where row r
+# of the tile is in the run, may select at all (`rows`) and chose that
+# block. The kernel's masked variant lets a row see a key iff its bit
+# for the key's selection block is set and the key lies under the row's
+# own length. A tile none of whose rows chose anything has ONE item on
+# the sink page with no bit (what an inactive tile has in the dense
+# list), so every tile's output is written.
+#
+# BOUND (`max_select_items`): a run of r rows chooses at most r * topk
+# blocks and its table row has pages_per_seq / block_pages kv-blocks, so
+# it has at most min(kv-blocks, r * topk) items; over a call's lanes
+# that is at most min(runs * kv-blocks, rows * topk), and never under
+# one a tile. The list with its mask words has to fit SMEM_LIST_WORDS;
+# where the whole step's does not, the lanes go in several calls, whole
+# tiles each, cut STATICALLY (`select_call_tiles`).
+
+
+def max_select_items(num_lanes: int, pages_per_seq: int, block_pages: int,
+                     topk: int, q_rows: int = Q_ROWS,
+                     slot_changes: Optional[int] = None) -> int:
+    """The most items a selection list over `num_lanes` lanes can hold
+    (the section above; `slot_changes` as in `max_work_items`)."""
+    nbk = -(-pages_per_seq // block_pages)
+    tiles = -(-num_lanes // q_rows)
+    rows = tiles * q_rows
+    runs = rows if slot_changes is None else min(rows, tiles + slot_changes)
+    return max(tiles, min(runs * nbk, rows * topk))
+
+
+def select_call_tiles(num_lanes: int, pages_per_seq: int, block_pages: int,
+                      select_pages: int, topk: int, q_rows: int = Q_ROWS,
+                      slot_changes: Optional[int] = None):
+    """The static cut of a step's lanes into the selection's calls:
+    (tiles a call, a call's bound on its items) — as many whole tiles a
+    call as keep its list, 3 + block_pages + S words an item and one
+    entry more than the bound, inside SMEM_LIST_WORDS, in calls of EQUAL
+    size (the last one's lanes past the step's are dead: one sink item a
+    tile), so that one batch of operations builds every call's list."""
+    words = 3 + block_pages + block_pages // select_pages
+    tiles = -(-num_lanes // q_rows)
+
+    def bound(n_tiles):
+        return max_select_items(n_tiles * q_rows, pages_per_seq,
+                                block_pages, topk, q_rows, slot_changes)
+
+    each = max((n for n in range(1, tiles + 1)
+                if (bound(n) + 1) * words <= SMEM_LIST_WORDS), default=0)
+    if not each:
+        raise ValueError(
+            f"one tile's selection list ({bound(1)} items of {words} "
+            f"words) does not fit SMEM_LIST_WORDS ({SMEM_LIST_WORDS})")
+    each = -(-tiles // -(-tiles // each))       # as few calls, evened out
+    return each, bound(each)
+
+
+def _select_runs(xp, blocks, chosen, rows, lane_slots, *, num_blocks,
+                 mask_words, q_rows):
+    """The first half of a selection list, in numpy or jax.numpy: for
+    every lane that STARTS a run and every kv-block of its table row,
+    the S row-mask words of the run's rows (`words` (lanes, kv-blocks,
+    S) int32: 0 on a lane that starts no run); the pairs that are ITEMS
+    — some word is set, or it is the one sink item of a tile with none
+    — as running counts, `upto` (lanes, kv-blocks) along a lane's
+    kv-blocks and `ends` (lanes,) along the lanes; `run` (lanes,) =
+    row_lo | row_hi << 8 of the run a lane is in. blocks, chosen (T, K) one head's selection, rows (T,) bool
+    the lanes that select."""
+    t = lane_slots.shape[0]
+    qb, nb, s_words = q_rows, num_blocks, mask_words
+    tiles = -(-t // qb)
+    pad = tiles * qb - t
+    i32 = xp.int32
+    slots = xp.concatenate([lane_slots.astype(i32), xp.zeros(pad, i32)])
+    rows = xp.concatenate([rows, xp.zeros(pad, bool)])
+    blocks = xp.concatenate(
+        [blocks.astype(i32), xp.zeros((pad,) + blocks.shape[1:], i32)])
+    chosen = xp.concatenate(
+        [chosen, xp.zeros((pad,) + chosen.shape[1:], bool)])
+    # hit[lane, b]: the lane selects and chose block b
+    hit = xp.any((blocks[:, :, None] == xp.arange(nb, dtype=i32))
+                 & chosen[:, :, None], axis=1) & rows[:, None]
+    lane = xp.arange(tiles * qb, dtype=i32)
+    prev = xp.concatenate([slots[:1], slots[:-1]])
+    starts = (lane % qb == 0) | (slots != prev)
+    rid = xp.cumsum(starts.astype(i32)).reshape(tiles, qb)
+    same = rid[:, :, None] == rid[:, None, :]
+    row = xp.arange(qb, dtype=i32)
+    run_lo = xp.min(xp.where(same, row, qb), axis=-1).reshape(-1)
+    run_hi = xp.max(xp.where(same, row + 1, 0), axis=-1).reshape(-1)
+    # bit r of a word is row r of the tile (q_rows <= 32; the sums are
+    # of distinct bits, so they are the bitwise or)
+    bit = xp.left_shift(xp.ones(qb, i32), row)
+    of_tile = xp.sum(xp.where(hit.reshape(tiles, qb, nb),
+                              bit[None, :, None], 0), axis=1, dtype=i32)
+    of_run = xp.sum(xp.where(same, bit, 0), axis=-1,
+                    dtype=i32).reshape(-1)
+    words = xp.where(
+        starts[:, None],
+        xp.repeat(of_tile, qb, axis=0) & of_run[:, None], 0
+    ).reshape(tiles * qb, nb // s_words, s_words)
+    item = xp.any(words != 0, axis=-1)                  # (lanes, nbk)
+    empty = xp.repeat(xp.sum(item.reshape(tiles, -1), axis=1) == 0, qb) \
+        & (lane % qb == 0)
+    first_blk = xp.arange(item.shape[1]) == 0
+    item = item | (empty[:, None] & first_blk[None, :])
+    # each lane's items up to and with a kv-block, and the items up to
+    # and with a lane
+    upto = xp.cumsum(item, axis=1, dtype=i32)
+    return words, upto, xp.cumsum(upto[:, -1], dtype=i32), \
+        run_lo | (run_hi << 8)
+
+
+def _select_items(xp, words, upto, ends, run, lane_tables, *, q_rows,
+                  max_items, before=0):
+    """The second half: the items of `_select_runs` (over ANY whole
+    tiles of its lanes: a call's, `before` the items of the lanes ahead
+    of them) laid out as the kernel walks them.
+    lane_tables (lanes, pages) each lane's page-table row. `max_items`
+    None (numpy only): n = the list's own length. Dense comparisons and
+    ROW gathers only: a gather of single words costs the chip some 10
+    ns a word. -> (tile, blk, meta, pages (n, bp), masks (n, S), the
+    items' count, the items that hold a chosen block)."""
+    lanes, nbk, s_words = words.shape
+    bp = lane_tables.shape[1] // nbk
+    i32 = xp.int32
+    ends = ends - before
+    total = ends[-1]
+    n = int(total) if max_items is None else max_items
+    w = xp.arange(n, dtype=i32)
+    alive = w < total
+    wc = xp.minimum(w, total - 1)           # past the end: the last one
+    # the lane whose run holds item w, and the item's rank in that run
+    ahead = ends[None, :] <= wc[:, None]                    # (n, lanes)
+    head = xp.sum(ahead, axis=1, dtype=i32)
+    rank = wc - xp.max(xp.where(ahead, ends[None, :], 0), axis=1)
+    # ... which is the run's (rank + 1)-th kv-block that is an item
+    blk = xp.sum(xp.take(upto, head, axis=0) <= rank[:, None], axis=1,
+                 dtype=i32)
+    src = head * nbk + blk
+    masks = xp.take(words.reshape(lanes * nbk, s_words), src, axis=0)
+    real = xp.any(masks != 0, axis=1)
+    pages = xp.where(
+        real[:, None],
+        xp.take(lane_tables.astype(i32).reshape(lanes * nbk, bp), src,
+                axis=0), 0)
+    at = head[:, None] == xp.arange(lanes, dtype=i32)[None, :]
+    rows_of = xp.sum(xp.where(at, run[None, :], 0), axis=1, dtype=i32)
+    tile = head // q_rows
+    edge = xp.full(1, -1, i32)
+    first = alive & (tile != xp.concatenate([edge, tile[:-1]]))
+    last = alive & ((tile != xp.concatenate([tile[1:], edge]))
+                    | (w == total - 1))
+    meta = (rows_of | xp.where(first, _FIRST, 0) | xp.where(last, _LAST, 0)
+            | xp.where(alive & real, _LIVE, 0))
+    return (tile, blk, meta.astype(i32), pages, masks, total,
+            xp.sum(alive & real, dtype=i32))
+
+
+def _select_arrays(xp, blocks, chosen, rows, lane_slots, lane_tables, *,
+                   block_pages, select_pages, q_rows, max_items):
+    """The selection list's arrays over these lanes (whole tiles), in
+    numpy or jax.numpy — ONE definition in two halves, as `_work_arrays`
+    is the dense list's: `_select_runs`, then `_select_items`."""
+    pp = lane_tables.shape[1]
+    words, upto, ends, run = _select_runs(
+        xp, blocks, chosen, rows, lane_slots, num_blocks=pp // select_pages,
+        mask_words=block_pages // select_pages, q_rows=q_rows)
+    tables = xp.concatenate([lane_tables, xp.zeros(
+        (run.shape[0] - lane_tables.shape[0], pp), lane_tables.dtype)])
+    return _select_items(xp, words, upto, ends, run, tables, q_rows=q_rows,
+                         max_items=max_items)
+
+
+def _as_work(arrays, lane_lens, max_items, q_rows, block_pages) -> WorkList:
+    tile, blk, meta, pages, masks, total, _ = arrays
+    lens = jnp.concatenate([
+        lane_lens.astype(jnp.int32),
+        jnp.ones(-lane_lens.shape[0] % q_rows, jnp.int32)])
+    return WorkList(
+        tile=tile, blk=blk, meta=meta, pages=pages.reshape(-1),
+        lens=jnp.broadcast_to(lens[:, None], (lens.shape[0], 128)),
+        count=jnp.clip(total, 1, max_items).astype(jnp.int32),
+        q_rows=q_rows, block_pages=block_pages, masks=masks.reshape(-1))
+
+
+def _check_select(block_pages, select_pages, pages_per_seq, q_rows):
+    if (block_pages % select_pages or pages_per_seq % block_pages
+            or q_rows > 32):
+        raise ValueError(
+            f"a selection's kv-block ({block_pages} pages) is whole "
+            f"selection blocks ({select_pages}) and divides the table "
+            f"({pages_per_seq}); a mask word holds {q_rows} rows' bits")
+
+
+def build_select_list(blocks, chosen, rows, lane_slots, lane_tables,
+                      lane_lens, *, block_pages: int, select_pages: int,
+                      max_items: int, q_rows: int = Q_ROWS) -> WorkList:
+    """The list of one key/value head's selection, on the device
+    (jax.numpy), for ONE call's lanes (whole tiles): blocks, chosen (T,
+    K) the selection blocks (of `select_pages` pages) each lane chose;
+    rows (T,) bool the lanes that select at all (a serving step's live
+    lanes at or past the selector's dense_len); lane_tables (T, pages)
+    each lane's page-table row; lane_lens (T,) position + 1, the causal
+    edge inside a row's own block. `block_pages` is whole selection
+    blocks and divides the table; `max_items` the caller's proven bound
+    (`max_select_items` / `select_call_tiles`). -> the WorkList with
+    its `masks`; `count` is the grid's length (the sink items of tiles
+    without a selection too)."""
+    t, pp = lane_tables.shape
+    _check_select(block_pages, select_pages, pp, q_rows)
+    return _as_work(_select_arrays(
+        jnp, blocks, chosen, rows, lane_slots, lane_tables,
+        block_pages=block_pages, select_pages=select_pages, q_rows=q_rows,
+        max_items=max_items + 1), lane_lens, max_items, q_rows, block_pages)
+
+
+def build_select_lists(blocks, chosen, rows, lane_slots, lane_tables,
+                       lane_lens, *, block_pages: int, select_pages: int,
+                       call_lanes: int, max_items: int,
+                       q_rows: int = Q_ROWS):
+    """The lists of a layer's selection: blocks, chosen (T, G, K) every
+    key/value head's, T whole calls of `call_lanes` lanes each; the
+    other arguments `build_select_list`'s. The runs' mask words are made
+    ONCE over all the lanes and heads (a tile's do not depend on the
+    call it falls in), each call's items from its lanes' share. ->
+    [call][head] (the WorkList, the same `build_select_list` gives a
+    call's lanes; its items that hold a chosen block, int32: the
+    others are a tile's sink item)."""
+    t, pp = lane_tables.shape
+    _check_select(block_pages, select_pages, pp, q_rows)
+    if call_lanes % q_rows or t % call_lanes:
+        raise ValueError(f"{t} lanes are no whole calls of {call_lanes}, "
+                         f"or those no whole tiles of {q_rows}")
+    words, upto, ends, run = jax.vmap(
+        lambda b, c: _select_runs(
+            jnp, b, c, rows, lane_slots, num_blocks=pp // select_pages,
+            mask_words=block_pages // select_pages, q_rows=q_rows),
+        in_axes=1)(blocks, chosen)                  # (G, T[, nbk[, S]])
+
+    def of_call(j, lo):
+        cut = slice(lo, lo + call_lanes)
+        arrays = _select_items(
+            jnp, words[j, cut], upto[j, cut], ends[j, cut], run[j, cut],
+            lane_tables[cut], q_rows=q_rows, max_items=max_items + 1,
+            before=ends[j, lo - 1] if lo else 0)
+        return (_as_work(arrays, lane_lens[cut], max_items, q_rows,
+                         block_pages), arrays[-1])
+
+    return [[of_call(j, lo) for j in range(blocks.shape[1])]
+            for lo in range(0, t, call_lanes)]
+
+
+def select_counts(xp, blocks, chosen, rows, lane_slots, *, num_blocks: int,
+                  mask_words: int, q_rows: int = Q_ROWS):
+    """What a selection list over these lanes holds, without making it
+    (the jnp twin's count, and a test's walk): (its items — the grid
+    steps a call walks — and those of them that hold a chosen block)."""
+    words, _, ends, _ = _select_runs(
+        xp, blocks, chosen, rows, lane_slots, num_blocks=num_blocks,
+        mask_words=mask_words, q_rows=q_rows)
+    return (ends[-1],
+            xp.sum(xp.any(words != 0, axis=-1), dtype=xp.int32))
+
+
 # --------------------------------------------------------- Pallas kernel
 # Everything inside the kernel is a 2-D array: Mosaic refuses to split a
 # lane dimension (reshape (ps, H*D) -> (ps, H, D): "unsupported shape
@@ -680,7 +958,7 @@ def _by_head(x, q_rows, heads, head_dim):
 def _ragged_v2_kernel(tile_ref, blk_ref, meta_ref, pages_ref, *refs,
                       page_size, block_pages, q_rows, heads, head_dim,
                       slabs, scale, quantized, exact, group=1, window=0,
-                      short=False, based=False):
+                      short=False, based=False, mask_words=0):
     """One work item: the rows [lo, hi) of a tile attend one kv-block
     of their sequence. Page refs arrive head-PACKED as (1, ps, H*D)
     blocks (plus (1, ps, H) scale blocks when quantized). The grid runs
@@ -697,9 +975,17 @@ def _ragged_v2_kernel(tile_ref, blk_ref, meta_ref, pages_ref, *refs,
     that hold the lane's `group`, the tile's other rows untouched (as
     the whole-tile body leaves them: they see nothing of the item).
     `based`: a fifth scalar operand, the first row of the call's layer
-    in the pages' arrays, comes before q2."""
+    in the pages' arrays, comes before q2. `mask_words` S > 0 (a list
+    made of a selection, `build_select_list`): one more scalar operand
+    after it, S row-mask words an item — a row sees a key iff the bit
+    of its tile row is set in the word of the key's selection block
+    (the kv-block's S equal parts) AND the key lies under its length;
+    a row no item shows anything comes out 0, not 0 / 0."""
     del tile_ref, pages_ref                  # read by the index maps
-    q2_ref, lens_ref, *refs = refs[1:] if based else refs
+    refs = refs[1:] if based else refs
+    if mask_words:
+        masks_ref, *refs = refs
+    q2_ref, lens_ref, *refs = refs
     per_page = 4 if quantized else 2
     n_kv = per_page * block_pages
     kv_refs = refs[:n_kv]
@@ -782,10 +1068,23 @@ def _ragged_v2_kernel(tile_ref, blk_ref, meta_ref, pages_ref, *refs,
                         load(lens_ref, heads=1), 0)
         vis = jnp.concatenate([vis] * g, axis=0)[:, :1]
         pos = base + jax.lax.broadcasted_iota(jnp.int32, (g * nr, bs), 1)
+        if mask_words:
+            # a row's limit by selection block: its own length where the
+            # bit of its tile row is set in the block's word, else 0
+            tile_row = row >> (group.bit_length() - 1) \
+                if group & (group - 1) == 0 else row // group
+            bit = jnp.left_shift(1, tile_row)
+            bit = jnp.concatenate([bit] * g, axis=0)[:, :1]
+            each = bs // mask_words             # keys a selection block
+            col = jax.lax.broadcasted_iota(jnp.int32, (g * nr, bs), 1)
+            limits = [jnp.where((bit & masks_ref[w * mask_words + i]) != 0,
+                                vis, 0) for i in range(mask_words)]
+            vis = limits[-1]
+            for i in reversed(range(mask_words - 1)):
+                vis = jnp.where(col < (i + 1) * each, limits[i], vis)
         seen = pos < vis                                     # (G*nr, bs)
         if window:
             seen &= pos >= vis - window
-
         def scales_on_lanes(j, out_ref):
             """Page slot j's (bs, H) scale rows as (Hp, bs): a product
             with the identity at HIGHEST precision moves them exactly."""
@@ -872,8 +1171,11 @@ def _ragged_v2_kernel(tile_ref, blk_ref, meta_ref, pages_ref, *refs,
     @pl.when((meta & _LAST) != 0)
     def _emit():
         def one_slab(slab, cols, _):
-            o_ref[0, slab] = (acc_ref[slab] / _by_head(
-                l_ref[slab], qb, g, d)).astype(o_ref.dtype)
+            acc = acc_ref[slab]
+            l = _by_head(l_ref[slab], qb, g, d)
+            if mask_words:      # a row with no bit anywhere: 0 / 0
+                l = jnp.maximum(l, 1e-30)
+            o_ref[0, slab] = (acc / l).astype(o_ref.dtype)
 
         each_slab(one_slab)
 
@@ -890,10 +1192,11 @@ def _vmem_limit(block_bytes: int) -> int:
 # and tracing and lowering the kernel body is host time before the
 # compile cache can even be asked — a nested jit pays it once
 @functools.partial(jax.jit, static_argnames=("scale", "interpret",
-                                             "window", "short"))
+                                             "window", "short",
+                                             "out_dtype"))
 def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
                       interpret, k_scales=None, v_scales=None, window=0,
-                      short=False, page_base=None):
+                      short=False, page_base=None, out_dtype=None):
     t, hq, d = q.shape
     npages, ps = k_pages.shape[:2]
     h = _kv_heads(k_pages, d)
@@ -919,6 +1222,8 @@ def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
     kp = k_pages.reshape(npages, ps, hd)
     vp = v_pages.reshape(npages, ps, hd)
     based = page_base is not None
+    # a list made of a selection: its row-mask words an item
+    mask_words = 0 if work.masks is None else work.masks.shape[0] // (n + 1)
     # q2[tile, slab, (g * qb + r) * group + j, g' * D + c] = q[tile * qb
     # + r, (slab * G + g) * group + j, c] where g' == g, else 0: a
     # lane's `group` rows lie together, so a one-lane item finds them
@@ -942,10 +1247,10 @@ def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
               ).reshape(tiles, slabs, g * qe, w_lanes)
 
     def page_index(i):
-        def imap(w, tile, blk, meta, pages, *base):
+        def imap(w, tile, blk, meta, pages, *rest):
             page = pages[w * bp + i]
             # with a base: the page's row among the rows of all layers
-            return (base[0][0] + page if base else page, 0, 0)
+            return (rest[0][0] + page if based else page, 0, 0)
         return imap
 
     def tile_index(w, tile, *_):
@@ -977,12 +1282,13 @@ def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
         _ragged_v2_kernel, page_size=ps, block_pages=bp, q_rows=qb,
         heads=g, head_dim=d, slabs=slabs, scale=scale,
         quantized=quantized, exact=exact, group=group, window=window,
-        short=short, based=based)
+        short=short, based=based, mask_words=mask_words)
     # the work list, and the layer's first row where the pages are rows
     # of many layers: a device scalar, so every layer of a leaf shares
     # this trace and one Mosaic kernel
     prefetch = (work.tile, work.blk, work.meta, work.pages) + (
-        (jnp.asarray(page_base, jnp.int32).reshape(1),) if based else ())
+        (jnp.asarray(page_base, jnp.int32).reshape(1),) if based else ()
+    ) + ((work.masks,) if mask_words else ())
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         # the list's own length, a device scalar: a call walks its
@@ -1013,7 +1319,7 @@ def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
     out = pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((tiles, slabs, qe, w_lanes),
-                                       q.dtype),
+                                       out_dtype or q.dtype),
         # the grid axis carries the online-softmax scratch from one
         # work item of a tile to the next: it must run in order
         compiler_params=pltpu.CompilerParams(
@@ -1021,7 +1327,8 @@ def _ragged_v2_pallas(q, k_pages, v_pages, work: WorkList, scale,
             vmem_limit_bytes=_vmem_limit(block_bytes)),
         interpret=interpret,
         # the device trace tells the two lists' calls apart by name
-        name="paged_ragged_v2_window" if window else "paged_ragged_v2",
+        name="paged_ragged_v2_window" if window else
+        "paged_ragged_v2_select" if mask_words else "paged_ragged_v2",
     )(*prefetch, *args)
     # (tile, slab, (group, row), (head, dim)) -> (lane of the step,
     # query head, dim)
@@ -1071,7 +1378,8 @@ def paged_attention_ragged_v2(q, k_pages, v_pages, page_tables,
                               lane_slots, lane_lens, *, k_scales=None,
                               v_scales=None, scale=None, block_kv=None,
                               work=None, use_pallas=None,
-                              interpret=False, window=0, page_base=None):
+                              interpret=False, window=0, page_base=None,
+                              out_dtype=None):
     """Ragged batched attention through page tables — kernel v2.
 
     GROUPED HEADS: q may have `group` times the pages' heads; query
@@ -1110,6 +1418,9 @@ def paged_attention_ragged_v2(q, k_pages, v_pages, page_tables,
         static), so every layer of a leaf shares one trace of the
         kernel. None: the arrays hold one layer's pages, in either
         form.
+      out_dtype — the result's dtype where it is not q's: the kernel's
+        f32 accumulator over its f32 sum, rounded once to this (the jnp
+        path's result is cast).
       work — the step's WorkList (`build_work_list` over these very
         lane arrays, made once for all the calls that share them: its
         kv-block shape is the one used); None builds one here, with
@@ -1126,10 +1437,11 @@ def paged_attention_ragged_v2(q, k_pages, v_pages, page_tables,
         scale = 1.0 / math.sqrt(q.shape[-1])
     impl = resolve_paged_impl(use_pallas, interpret)
     if impl == JNP:
-        return _ragged_jnp(q, k_pages, v_pages, page_tables, lane_slots,
-                           lane_lens, scale, k_scales=k_scales,
-                           v_scales=v_scales, window=window,
-                           page_base=page_base)
+        o = _ragged_jnp(q, k_pages, v_pages, page_tables, lane_slots,
+                        lane_lens, scale, k_scales=k_scales,
+                        v_scales=v_scales, window=window,
+                        page_base=page_base)
+        return o if out_dtype is None else o.astype(out_dtype)
     ps = k_pages.shape[1]
     heads = _kv_heads(k_pages, q.shape[2])
     if work is None:
@@ -1151,7 +1463,7 @@ def paged_attention_ragged_v2(q, k_pages, v_pages, page_tables,
                     k_scales=k_scales, v_scales=v_scales, scale=scale,
                     block_kv=block_kv, use_pallas=use_pallas,
                     interpret=interpret, window=window,
-                    page_base=page_base)
+                    page_base=page_base, out_dtype=out_dtype)
                 for a in range(0, q.shape[0], step)], axis=0)
         work = build_work_list(page_tables, lane_slots, lane_lens,
                                page_size=ps, block_pages=bp,
@@ -1160,4 +1472,4 @@ def paged_attention_ragged_v2(q, k_pages, v_pages, page_tables,
         q, k_pages, v_pages, work, scale, impl == PALLAS_INTERPRET,
         k_scales=k_scales, v_scales=v_scales, window=int(window),
         short=has_short_body(q.shape[1] // heads, work.q_rows),
-        page_base=page_base)
+        page_base=page_base, out_dtype=out_dtype)

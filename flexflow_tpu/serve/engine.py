@@ -1106,15 +1106,16 @@ class ServeEngine:
                                   lane_lens)
         # what the description's memory layer hands the layers after it
         memory = None
-        expert_counts = []
+        expert_counts, selected = [], []
         for i in range(self.num_layers):
             with scope(f"layer{i}"):
-                x, pool, memory, counts = self._mixed_layer(
+                x, pool, memory, counts, walked = self._mixed_layer(
                     params, i, x, lanes, pool, memory,
                     None if ad is None else
                     {key: arr[:, i] for key, arr in ad.items()},
                     ad_s, tp_axis)
                 expert_counts.append(counts)
+                selected += walked
         with scope("head"):
             # only the lanes that emit have logits anyone reads: the
             # head, the argmax and the sort take their rows, not the
@@ -1128,12 +1129,17 @@ class ServeEngine:
                    topv.astype(jnp.float32), topi.astype(jnp.int32))
         if self.arch.experts:
             out += (jnp.stack(expert_counts),)           # (layers, E)
+        if selected:
+            # what the SPARSE layers' selections walked, summed
+            out += (sum(selected),)                      # (2,)
         return out, pool
 
     def _mixed_layer(self, params, i, x, lanes, pool, memory, la, ad_s,
                      tp_axis):
         """Layer `i` of the mixed step -> (x, pool, memory, the layer's
-        live slots per expert or None): `ln`, then the body of the
+        live slots per expert or None, what only the device counts of
+        the mixer's work — the body's fourth value in a list, [] of a
+        body without one): `ln`, then the body of the
         layer's mixer kind (serve/mixers.py BODIES, under its own
         scopes), then the description's feed-forward under its scopes
         (`ffn`, or `router`, `moe_dispatch`, `experts`, `moe_combine`,
@@ -1152,14 +1158,14 @@ class ServeEngine:
             h = arch.norm1(params, i, x)
         # x + the mixer's branch or, in a parallel block, the branch
         # alone
-        a, pool, memory = mixers.BODIES[arch.mixer(i)](
+        a, pool, memory, *walked = mixers.BODIES[arch.mixer(i)](
             self.geometry, params, i, x, h, lanes, pool, memory,
             None if la is None else (la, ad_s), tp_axis)
         if arch.parallel_block:
             f, counts = arch.ffn(params, i, x, h=h, live=lanes.ffn_live,
                                  psum_axis=tp_axis)
             with scope("residual"):
-                return x + (a + f), pool, memory, counts
+                return x + (a + f), pool, memory, counts, walked
         if arch.post_norm:
             # the norm stands AFTER a sub-layer: each branch comes back
             # alone and is normed before it is added
@@ -1169,12 +1175,12 @@ class ServeEngine:
                                  psum_axis=tp_axis)
             with scope("post_norm"):
                 return (x + arch.branch_norm(params, i, 2, f), pool,
-                        memory, counts)
+                        memory, counts, walked)
         x, counts = arch.ffn(
             params, i, a, live=lanes.ffn_live, psum_axis=tp_axis,
             lora=None if la is None else
             (la["a_ff1"], la["b_ff1"], la["a_ff2"], la["b_ff2"], ad_s))
-        return x, pool, memory, counts
+        return x, pool, memory, counts, walked
 
     # ---------------- disaggregated page handoff -----------------------
     # Device half of the prefill->decode transfer (serve/disagg.py;
@@ -1591,8 +1597,9 @@ class ServeEngine:
         lanes' token source. Returns
         (greedy, topv, topi: a row for each of those lanes, in their
         order; expert counts: the step's (layers, experts) live slots
-        per expert on a model with an expert layer, else None), all
-        still on the device, and
+        per expert on a model with an expert layer, else None; what
+        the selection's calls walked, (2,), on a model with a SPARSE
+        layer, else None), all still on the device, and
         keeps the returned pool as `self.pool`, so a mid-run audit
         (check_kv_scales from an `on_step` callback, when sequences are
         actually resident) reads THIS step's content. The token source
@@ -1623,10 +1630,13 @@ class ServeEngine:
             "mixed", self._mixed_jit, self._step_params,
             self._device_pool(), *args, self._greedy, lane_adapters,
             slabs)
-        greedy, topv, topi = out[:3]
+        greedy, topv, topi, *counts = out
         self._greedy = greedy
+        # the device's own counts, in the body's order: an expert
+        # layer's slots, a selection's walk
         return (greedy, topv, topi,
-                out[3] if self.arch.experts else None)
+                counts.pop(0) if self.arch.experts else None,
+                counts.pop(0) if self.geometry.select_call_lanes else None)
 
     def warmup(self) -> Dict[str, int]:
         """Ready the engine's programs once, on throwaway inputs
@@ -2730,16 +2740,19 @@ class StepEvents:
     its context (arch.selector_dim; SELECT_COUNTS) ``sparse_lanes`` is
     the live lanes past the selector's dense_len, ``blocks_visible`` /
     ``blocks_selected`` the blocks those lanes see and select over all
-    sparse layers and key/value heads; ``selected_kv_bytes`` and
-    ``selector_bytes`` are what the DEVICE gathers a step, of K and V
-    blocks and of compressed keys: every lane of the step's width
-    gathers its blocks, live and past dense_len or not, so the first
-    is a constant of the shapes; of the compressed keys every stretch
-    of lanes fetches one copy, its main sequence's, and the stray
-    lanes, those of another sequence, a copy each, a stretch of them a
-    trip (``score_tiles`` the stretches a layer, ``score_shared_tiles``
-    those with no stray lane: sparse_paged.main_slots;
-    ``kv_bytes_read`` stays the paged calls' page fetches);
+    sparse layers and key/value heads; ``selector_bytes`` is what the
+    DEVICE gathers a step of compressed keys: every stretch of lanes
+    fetches one copy, its main sequence's, and the stray lanes, those
+    of another sequence, a copy each, a stretch of them a trip
+    (``score_tiles`` the stretches a layer, ``score_shared_tiles``
+    those with no stray lane: sparse_paged.main_slots); what only the
+    device can count, fetched with the step's tokens and so set when a
+    step LANDS (mixers.SELECT_LANDED_COUNTS): ``select_items`` the grid
+    steps the selection's paged calls walk over all sparse layers and
+    key/value heads, ``select_block_fetches`` the selection blocks
+    those items fetch, ``selected_kv_bytes`` their K and V — what the
+    device moves of the selected context;
+    ``kv_bytes_read`` stays the paged calls' page fetches;
     ``dispatched``
     False for a call in which no step landed: a planning-only
     iteration (rung-4
@@ -2796,7 +2809,7 @@ class _Flight:
     def __init__(self, ev, outputs, lane, emitters, spec_emitters,
                  t_dispatch, rung, util, lands_first):
         self.ev = ev
-        self.outputs = outputs      # (greedy, topv, topi, counts)
+        self.outputs = outputs  # (greedy, topv, topi, counts, selected)
         self.lane = lane            # live lanes
         self.emitters = emitters
         self.spec_emitters = spec_emitters
@@ -3314,7 +3327,7 @@ class ServeSession:
         sched = self.sched
         timed, track = eng.telemetry.timed, eng._ENGINE_TRACK
         ev, plan = fl.ev, fl.ev.plan
-        greedy, topv, topi, counts = fl.outputs
+        greedy, topv, topi, counts, selected = fl.outputs
         with timed(track, "fetch"):
             greedy = np.asarray(greedy)
             topv = np.asarray(topv)
@@ -3322,16 +3335,27 @@ class ServeSession:
             ev.topv, ev.topi = topv, topi
             if counts is not None:
                 self._count_experts(ev, np.asarray(counts), fl.lane)
+            landed = {}
+            if selected is not None:
+                landed = mixers.select_landed(eng.geometry,
+                                              np.asarray(selected))
+                for key, n in landed.items():
+                    setattr(ev, key, n)
         now = time.perf_counter()
         t_start = max(fl.t_dispatch, self._t_landed)
         dt = now - t_start
         self._t_landed = now
-        with timed(track, "emit", None if not eng.arch.experts else {
-                "step": ev.step_index, "expert_slots": ev.expert_slots,
-                "experts_touched": ev.experts_touched,
-                "expert_bytes": ev.expert_bytes,
-                **({} if eng.arch.experts_held is None else {
-                    "shared_bytes": ev.shared_bytes})}):
+        # what the landed step counted on the device: an expert
+        # layer's slots, a selection's walk (a model may hold both)
+        counted = dict(landed)
+        if eng.arch.experts:
+            counted.update(expert_slots=ev.expert_slots,
+                           experts_touched=ev.experts_touched,
+                           expert_bytes=ev.expert_bytes)
+            if eng.arch.experts_held is not None:
+                counted["shared_bytes"] = ev.shared_bytes
+        with timed(track, "emit", {"step": ev.step_index, **counted}
+                   if counted else None):
             # every fetched row is a live lane's (the padding is lane
             # 0's)
             if not np.isfinite(topv).all():

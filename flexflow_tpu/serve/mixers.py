@@ -40,20 +40,27 @@ from .arch import (ATTN, CROSS, DELTA, FULL, GMU, LINEAR, SPARSE, SSD_ATTN,
                    SSM, WINDOW)
 from .kv_cache import KVCacheConfig, ring_tables
 from .sparse_paged import (LANE_TILE, STRAY_TILE, main_slots,
-                           paged_sparse_attention, stray_batches,
-                           stride_keys)
+                           paged_sparse_attention, selection_geometry,
+                           stray_batches, stride_keys)
 
 # the step's fixed shape against its live work: StepEvents attributes
 # and `dispatch` span arguments of every model
 LIVE_COUNTS = ("grid_steps", "live_steps", "short_steps", "live_rows",
                "lanes", "emitters", "paged_calls", "paged_calls_in_place")
 # what a step's selection did, on a model that selects its context
-# (a SPARSE layer); the last two: the stretches of lanes the scores
-# take a layer, and those of them whose live lanes all share the
-# stretch's one fetch of compressed keys (sparse_paged.main_slots)
+# (a SPARSE layer), counted where the lanes are made; the last two: the
+# stretches of lanes the scores take a layer, and those of them whose
+# live lanes all share the stretch's one fetch of compressed keys
+# (sparse_paged.main_slots)
 SELECT_COUNTS = ("sparse_lanes", "blocks_selected", "blocks_visible",
-                 "selected_kv_bytes", "selector_bytes", "score_tiles",
-                 "score_shared_tiles")
+                 "selector_bytes", "score_tiles", "score_shared_tiles")
+# ... and what only the DEVICE can count, because the selection is made
+# there: the grid steps the selection's paged calls walk, summed over
+# key/value heads and sparse layers, the selection blocks those items
+# fetch, and the K and V bytes that is. Fetched with the step's tokens,
+# so they are a landed step's (StepEvents, the `emit` span)
+SELECT_LANDED_COUNTS = ("select_items", "select_block_fetches",
+                        "selected_kv_bytes")
 # ... where a sequence holds state or a ring besides pages
 HYBRID_COUNTS = ("state_bytes", "window_kv_bytes", "full_kv_bytes")
 # ... where a layer runs the gated delta rule: the live lanes that go
@@ -68,7 +75,7 @@ SSD_COUNTS = ("ssd_lanes", "ssd_chunk_blocks")
 EVENT_COUNTS = ("kv_bytes_read", "attn_items", "attn_rows", "ssm_runs",
                 "lanes_past_window")
 STEP_COUNTS = EVENT_COUNTS + LIVE_COUNTS + HYBRID_COUNTS + SELECT_COUNTS \
-    + DELTA_COUNTS + SSD_COUNTS
+    + SELECT_LANDED_COUNTS + DELTA_COUNTS + SSD_COUNTS
 
 
 # ------------------------------------------------------------- geometry
@@ -99,6 +106,14 @@ class Geometry:
     delta_state: dict = dataclasses.field(default_factory=dict)
     # a layer runs Mamba-2 heads: its lanes take the delta rule's plan
     ssd: bool = False
+    # a selecting model's selected blocks through the paged kernel
+    # (sparse_paged.selection_geometry): the kv-block of those calls in
+    # pages, the lanes a call takes of the step's (the static cut that
+    # keeps a call's list in the kernel's SMEM budget) and a call's
+    # bound on its list; 0: no SPARSE layer
+    select_block_pages: int = 0
+    select_call_lanes: int = 0
+    select_max_items: int = 0
 
     @property
     def attn_kw(self) -> dict:
@@ -194,6 +209,13 @@ def geometry(arch, cfg: KVCacheConfig, *, width: int, attn_impl: str,
         window_blocks=window_block_bound(
             arch.window, block_pages * cfg.page_size)
     ) if arch.window else 0
+    # the selected blocks' calls: the kernel's kv-block in whole
+    # selection blocks, the lanes in as many calls as keep a call's list
+    # in the kernel's SMEM budget. The slot changes inside a call's
+    # lanes are at most the step's (the proof above)
+    select = selection_geometry(
+        arch.sparse, cfg.page_size, cfg.pages_per_seq, block_pages, width,
+        slot_changes=cfg.max_seqs) if dense_pages else (0, 0, 0)
     counted = LIVE_COUNTS + (HYBRID_COUNTS if hyb is not None else ()) \
         + (SELECT_COUNTS if dense_pages else ()) \
         + (DELTA_COUNTS if DELTA in kinds else ()) \
@@ -205,7 +227,9 @@ def geometry(arch, cfg: KVCacheConfig, *, width: int, attn_impl: str,
         dense_pages=dense_pages, attn_max_items=attn_max_items,
         window_max_items=window_max_items, attn_calls=attn_calls(arch),
         rings=ring_tables(cfg) if cfg.ring_pages else None,
-        counted=counted, delta_state=delta_state, ssd=SSD_ATTN in kinds)
+        counted=counted, delta_state=delta_state, ssd=SSD_ATTN in kinds,
+        select_block_pages=select[0], select_call_lanes=select[1],
+        select_max_items=select[2])
 
 
 def walked(g: Geometry, page_tables, positions, lane_lens, xp=np):
@@ -335,7 +359,9 @@ def step_lanes(g: Geometry, positions, write_pages, write_offs,
 # `tp_axis` the serve mesh's axis inside shard_map. The last two reach
 # the kinds whose descriptions serve them (arch.refused). Returns x
 # after the mixer and its residual or, in a parallel block
-# (arch.parallel_block), the mixer's branch alone.
+# (arch.parallel_block), the mixer's branch alone. A body whose device
+# work only the device can count returns those counts as a fourth value
+# (the SPARSE body: what its selection's calls walked).
 
 def _paged(g, q, kv, layer, tables, lanes, lens, work, window=0,
            heads=None):
@@ -479,6 +505,17 @@ def _linear(g, params, i, x, h, lanes, pool, memory, lora=None,
             q, k, v, arch.decays[i], pool.state[j].astype(jnp.float32),
             lanes.lane_slots, lanes.positions, lanes.live, lanes.starts,
             lanes.offsets)
+        # the write-back below updates the slab IN PLACE and `o` reads
+        # the layer's OLD state too: held together, so that every reader
+        # of the old state is done before the write. The barrier stands
+        # through the compiler's scheduler and rematerialization. Without
+        # it, short of memory at the published size, the compiler
+        # REMATERIALIZED the slice of the old state for o's product
+        # after the in-place write (the last lightning layer's): a
+        # sequence's second chunk left that layer 0.073 from the f32
+        # reference where 0.012 entered it, in whichever build of the
+        # step the schedule fell that way (PERF.md section 6, PR 57)
+        o, state = jax.lax.optimization_barrier((o, state))
         pool = dataclasses.replace(pool, state=pool.state.at[j].set(
             state.astype(pool.state.dtype)))
     with scope("linear_proj"):
@@ -594,8 +631,12 @@ def _sparse(g, params, i, x, h, lanes, pool, memory, lora=None,
     fetched once a stretch of lanes for its main sequence, a copy a
     lane for the stray lanes of another),
     `sparse_select` (block scores, forced blocks, top-k) and
-    `sparse_attn` (the selected blocks' pages, gathered a lane at a
-    time), `attn_out` (the gate and the output projection)."""
+    `sparse_attn` (the selected blocks through the paged kernel again,
+    from a list made of the selection: a masked call a key/value head
+    and `select_call_lanes` lanes of `geometry`; the per-lane
+    gathers under the jnp attention), `attn_out` (the gate and the
+    output projection). -> (x, pool, memory, what the selection's calls
+    walked: sparse_paged.paged_sparse_attention)."""
     scope = jax.named_scope
     arch = g.arch
     sc = arch.sparse
@@ -623,14 +664,28 @@ def _sparse(g, params, i, x, h, lanes, pool, memory, lora=None,
                    lanes.walked_lens, lanes.work,
                    heads=slice(head * each, (head + 1) * each))
             for head, layer in enumerate(layers)], axis=1)
-    o = paged_sparse_attention(q, kv, layers, lanes.page_tables, slots,
-                               positions, lanes.live, sc)
+    o, walked = paged_sparse_attention(
+        q, kv, layers, lanes.page_tables, slots, positions, lanes.live, sc,
+        impl=g.attn_impl, block_pages=g.select_block_pages,
+        call_lanes=g.select_call_lanes, max_items=g.select_max_items)
     with scope("sparse_attn"):
         o = jnp.where((positions < sc.dense_len)[:, None, None],
                       o_dense, o)
     with scope("attn_out"):
         x = arch.sparse_out(params, i, o, h, x)
-    return x, pool, memory
+    return x, pool, memory, walked
+
+
+def select_landed(g: Geometry, walked) -> dict:
+    """SELECT_LANDED_COUNTS of a step from what its SPARSE layers'
+    calls walked, (2,) summed over them on the device (`_sparse`):
+    their grid steps, the selection blocks their items fetch, and the
+    bytes of K and V those are — what the device moves of the selected
+    context, whoever reads it."""
+    items, fetches = (int(n) for n in walked)
+    return {"select_items": items, "select_block_fetches": fetches,
+            "selected_kv_bytes": fetches * 2 * g.arch.sparse.block_size
+            * g.arch.kv_head_dim * g.cfg.kv_itemsize}
 
 
 BODIES = {ATTN: _attention, WINDOW: _attention, FULL: _attention,
@@ -713,19 +768,24 @@ def step_counts(g: Geometry, page_tables, positions, lane_slots,
         # counted where the lanes are made: a lane at position t sees
         # t // block + 1 blocks and selects min(topk, that) of them a
         # key/value head, whatever the scores say. What the device
-        # MOVES for it: every lane of the step's width gathers
-        # min(topk, a table's blocks) blocks, read or not; of a table's
-        # compressed keys every stretch fetches one copy (its main
-        # sequence's) and the stray lanes a copy each, a stretch of
-        # them a trip, by the rule the step itself follows (the same
-        # functions over numpy)
+        # MOVES for the scores: of a table's compressed keys every
+        # stretch fetches one copy (its main sequence's) and the stray
+        # lanes a copy each, a stretch of them a trip, by the rule the
+        # step itself follows (the same functions over numpy). What it
+        # moves of the selected blocks depends on WHICH were chosen:
+        # the device counts that (`select_landed`)
         sc = arch.sparse
         past = positions[:live_lanes]
         past = past[past >= sc.dense_len]
         visible = past // sc.block_size + 1
         heads = arch.kv_heads * len(arch.sparse_layers)
-        gathered = g.width * heads * min(
-            sc.topk, c.pages_per_seq * ps // sc.block_size)
+        # the proof of the selection lists' bounds: the runs a call's
+        # lanes hold are its tiles and the slot changes among them
+        changes = int((lane_slots[1:] != lane_slots[:-1]).sum())
+        if changes > c.max_seqs:
+            raise RuntimeError(
+                f"the plan changes slot {changes} times; the selection's "
+                f"lists are bounded for {c.max_seqs}")
         _, stray = main_slots(
             lane_slots, np.arange(g.width) < live_lanes, np)
         tiles = -(-g.width // LANE_TILE)
@@ -736,8 +796,6 @@ def step_counts(g: Geometry, page_tables, positions, lane_slots,
             blocks_visible=int(visible.sum()) * heads,
             blocks_selected=int(np.minimum(visible, sc.topk).sum())
             * heads,
-            selected_kv_bytes=gathered * 2 * sc.block_size
-            * arch.kv_head_dim * c.kv_itemsize,
             selector_bytes=copies * heads * c.pages_per_seq
             * c.selector_dim * int(c.selector_dtype.itemsize),
             score_tiles=tiles, score_shared_tiles=tiles - mixed)

@@ -19,6 +19,10 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..kernels.paged_ragged_v2 import (JNP, PALLAS_INTERPRET, Q_ROWS,
+                                       build_select_lists,
+                                       paged_attention_ragged_v2,
+                                       select_call_tiles, select_counts)
 from ..ops.sparse_attention import (F32, _NEG, SparseConfig, group_probs,
                                     mean_keys, select_blocks)
 from .kv_cache import KVPool
@@ -152,43 +156,58 @@ def lane_probs(q, pool: KVPool, layers, page_tables, lane_slots, positions,
     return jax.lax.fori_loop(1, stray_batches(stray), own, own(0, probs))
 
 
-def paged_sparse_attention(q, pool: KVPool, layers, page_tables,
-                           lane_slots, positions, live, sc: SparseConfig):
-    """Steps 2-5 for the lanes of a serving step, through the pool
-    (pages (layer, page, slot, D), selector rows (layer, page, D)):
-    q (T, H, D); `layers` the G pool layers of this layer's key/value
-    heads; page_tables (slots, pages); live (T,) bool the lanes that
-    hold a token. Three scopes, each over all the lanes: `sparse_score`
-    (`lane_probs`), `sparse_select` (block scores, forced blocks,
-    top-k) and `sparse_attn` (each lane gathers its OWN selected
-    blocks' pages). The two that gather take `LANE_TILE` lanes at a
-    time, so the gathered rows of all lanes never stand in memory at
-    once. The scores share a sequence's compressed keys among the
-    lanes of a stretch that hold its main sequence; the selected
-    blocks' fetch is shared by no two lanes (a later change's lever;
-    a grouped product over the lanes of a run, `jax.lax.ragged_dot`,
-    was tried for the scores and is no shortcut on this compiler). A
-    lane under `dense_len` gets a finite answer nobody reads (the step
-    takes those lanes' from the dense call). -> o (T, H, D) in q's
-    dtype."""
+def selection_geometry(sc: SparseConfig, page_size: int, pages_per_seq: int,
+                       block_pages: int, num_lanes: int,
+                       slot_changes=None):
+    """How the selected blocks go through the paged kernel at a pool
+    geometry: (`block_pages`, the kernel's kv-block cut to whole
+    selection blocks that divide the table; the lanes a call takes and
+    a call's bound on its list — `select_call_tiles`, the proof)."""
+    sp = sc.block_size // page_size          # pages a selection block
+    bp = max(sp, block_pages // sp * sp)
+    while pages_per_seq % bp:
+        bp -= sp
+    tiles, bound = select_call_tiles(
+        num_lanes, pages_per_seq, bp, sp, min(sc.topk, pages_per_seq // sp),
+        slot_changes=slot_changes)
+    return bp, tiles * Q_ROWS, bound
+
+
+def whole_calls(xp, a, call_lanes: int):
+    """Lane array a (T, ...) padded to whole calls of `call_lanes`
+    lanes, the lanes past T zeros: dead lanes on slot 0, as a step's
+    inactive ones are."""
+    pad = -a.shape[0] % call_lanes
+    return xp.concatenate([a, xp.zeros((pad,) + a.shape[1:], a.dtype)])
+
+
+def attend_selected(q, pool: KVPool, layers, page_tables, lane_slots,
+                    positions, live, blocks, chosen, sc: SparseConfig, *,
+                    impl: str = JNP, block_pages: int, call_lanes: int,
+                    max_items: int):
+    """Step 5 for the lanes of a serving step: every live lane at or
+    past `dense_len` attends the tokens at or before its own in the
+    blocks it chose (blocks, chosen (T, G, K): `select_blocks`), in one
+    of two forms by `impl` (`paged_sparse_attention` says which; the
+    other arguments are its own). -> (o (T, H, D) in f32, (2,) int32
+    what the selection's calls walk: their grid steps summed over the
+    heads, and the selection blocks those items fetch). o is the f32
+    accumulator over the f32 sum as it is: the gate that follows reads
+    f32, so no rounding to bf16 stands between them (the served cell's
+    `logit_rms_err` read 0.0279 with one and 0.0270 without: PERF.md
+    section 6, PR 57)."""
     t, h, d = q.shape
     g = len(layers)
     i = h // g
     ps = pool.k.shape[2]
     pp = page_tables.shape[1]
     bp = sc.block_size // ps                 # pages a block
-    if ps != sc.kernel_stride or pp % bp or pool.heads != 1:
-        raise ValueError(
-            f"the selector's stride ({sc.kernel_stride}) is the page size "
-            f"({ps}), a table ({pp} pages) holds whole blocks, and a pool "
-            f"layer holds one key/value head ({pool.heads})")
-    scope = jax.named_scope
-
-    def by_tile(fn, *arrays):
-        """fn over the lanes, LANE_TILE of them at a time."""
-        return jnp.concatenate([
-            fn(*(a[lo:lo + LANE_TILE] for a in arrays))
-            for lo in range(0, t, LANE_TILE)])
+    s_words = block_pages // bp
+    # the lanes that select, and every lane array in whole calls
+    rows, slots, picked, chose = (
+        whole_calls(jnp, a, call_lanes) for a in (
+            live & (positions >= sc.dense_len), lane_slots, blocks, chosen))
+    calls = range(0, rows.shape[0], call_lanes)
 
     def attend_head(qh, layer, tables, pos, blk, ok):
         """One key/value head of a trip: qh (R, I, D), blk, ok (R, K)."""
@@ -216,19 +235,107 @@ def paged_sparse_attention(q, pool: KVPool, layers, page_tables,
                        for part in (hi, lo)) / l
         return jnp.einsum("rin,rnd->rid", p, vs.astype(F32)) / l
 
-    def attend(qt, slot, pos, blk, ok):
-        r = qt.shape[0]
-        tables = jnp.take(page_tables, slot, axis=0)
-        qg = qt.reshape(r, g, i, d)
-        o = jnp.stack([attend_head(qg[:, j], layer, tables, pos, blk[:, j],
-                                   ok[:, j])
+    def attend(lo):
+        """The twin's trip: LANE_TILE lanes, each on its own gathered
+        copy of its blocks, so the rows gathered for all lanes never
+        stand in memory at once."""
+        cut = slice(lo, lo + LANE_TILE)
+        qg = q[cut].reshape(-1, g, i, d)
+        tables = jnp.take(page_tables, lane_slots[cut], axis=0)
+        o = jnp.stack([attend_head(qg[:, j], layer, tables, positions[cut],
+                                   blocks[cut, j], chosen[cut, j])
                        for j, layer in enumerate(layers)], axis=1)
-        return o.reshape(r, h, d).astype(qt.dtype)
+        return o.reshape(-1, h, d)
 
+    if impl == JNP:
+        # the twin builds no list: it counts what one would hold
+        walked = sum(
+            jnp.stack(select_counts(
+                jnp, picked[lo:lo + call_lanes, j],
+                chose[lo:lo + call_lanes, j], rows[lo:lo + call_lanes],
+                slots[lo:lo + call_lanes], num_blocks=pp // bp,
+                mask_words=s_words))
+            for lo in calls for j in range(g))
+        return (jnp.concatenate([attend(lo)
+                                 for lo in range(0, t, LANE_TILE)]),
+                walked * jnp.array([1, s_words], jnp.int32))
+    # the list form: a call a key/value head and stretch of `call_lanes`
+    # lanes, each on a list of that head's selection over those lanes
+    lens = whole_calls(jnp, positions + 1, call_lanes)
+    works = build_select_lists(
+        picked, chose, rows, slots,
+        whole_calls(jnp, jnp.take(page_tables, lane_slots, axis=0),
+                    call_lanes),
+        lens, block_pages=block_pages, select_pages=bp,
+        call_lanes=call_lanes, max_items=max_items)
+    qs = whole_calls(jnp, q, call_lanes)
+    outs, walked = [], jnp.zeros(2, jnp.int32)
+    for lo, of_call in zip(calls, works):
+        cut = slice(lo, lo + call_lanes)
+        heads = []
+        for j, (layer, (work, real)) in enumerate(zip(layers, of_call)):
+            k_pages, v_pages, _, _, base = pool.layer(layer)
+            heads.append(paged_attention_ragged_v2(
+                qs[cut, j * i:(j + 1) * i], k_pages, v_pages, page_tables,
+                slots[cut], lens[cut], scale=1.0 / math.sqrt(d), work=work,
+                page_base=base, use_pallas=True,
+                interpret=impl == PALLAS_INTERPRET, out_dtype=F32))
+            walked = walked + jnp.stack([work.count, s_words * real])
+        outs.append(jnp.concatenate(heads, axis=1))
+    return jnp.concatenate(outs)[:t], walked
+
+
+def paged_sparse_attention(q, pool: KVPool, layers, page_tables,
+                           lane_slots, positions, live, sc: SparseConfig,
+                           *, impl: str = JNP, block_pages: int,
+                           call_lanes: int, max_items: int):
+    """Steps 2-5 for the lanes of a serving step, through the pool
+    (pages (layer, page, slot, D), selector rows (layer, page, D)):
+    q (T, H, D); `layers` the G pool layers of this layer's key/value
+    heads; page_tables (slots, pages); live (T,) bool the lanes that
+    hold a token. Three scopes, each over all the lanes: `sparse_score`
+    (`lane_probs`), `sparse_select` (block scores, forced blocks,
+    top-k) and `sparse_attn` (`attend_selected`), the selected blocks'
+    keys and values in one of two forms by `impl`:
+
+    the LIST form (the paged kernel, compiled or interpreted): for each
+    key/value head a work list MADE OF THE SELECTION
+    (kernels/paged_ragged_v2.build_select_list) — an item a run of a
+    tile's rows and a kv-block of `block_pages` pages that holds a
+    block some row of the run chose, fetched ONCE for all of them, a
+    row-mask word a selection block — and one masked call of the kernel
+    on that head's pool layer where it lies (`KVPool.layer`), the
+    step's lanes `call_lanes` a call (`selection_geometry`: a call's
+    list, as long as its proven bound `max_items`, has to fit the
+    kernel's SMEM budget). Only the live
+    lanes at or past `dense_len` have bits: a dead lane, a lane under
+    it and a tile of neither make one item on the sink page, and their
+    rows come out 0;
+
+    the per-lane TWIN (`impl` "jnp": the oracle the list form is held
+    to, as `_ragged_jnp` is the dense call's): each lane gathers its
+    OWN selected blocks' pages, `LANE_TILE` lanes at a time.
+
+    The scores share a sequence's compressed keys among the lanes of a
+    stretch that hold its main sequence (`lane_probs`). A lane under
+    `dense_len` gets a finite answer nobody reads (the step takes those
+    lanes' from the dense call). -> `attend_selected`'s two."""
+    ps = pool.k.shape[2]
+    pp = page_tables.shape[1]
+    bp = sc.block_size // ps                 # pages a block
+    if ps != sc.kernel_stride or pp % bp or pool.heads != 1:
+        raise ValueError(
+            f"the selector's stride ({sc.kernel_stride}) is the page size "
+            f"({ps}), a table ({pp} pages) holds whole blocks, and a pool "
+            f"layer holds one key/value head ({pool.heads})")
+    scope = jax.named_scope
     with scope("sparse_score"):
         probs = lane_probs(q, pool, layers, page_tables, lane_slots,
                            positions, live, sc)              # (T, G, pp)
     with scope("sparse_select"):
         blocks, chosen = select_blocks(probs, positions, sc)  # (T, G, K)
     with scope("sparse_attn"):
-        return by_tile(attend, q, lane_slots, positions, blocks, chosen)
+        return attend_selected(
+            q, pool, layers, page_tables, lane_slots, positions, live,
+            blocks, chosen, sc, impl=impl, block_pages=block_pages,
+            call_lanes=call_lanes, max_items=max_items)

@@ -317,16 +317,21 @@ def test_minicpm_sala_mixed_step_compiles_within_its_memory_plan(topo,
     slots; ONE sparse and ONE lightning layer and a small vocabulary,
     so the parameters are quick to make; PR 45): it compiles for a v5e
     with a paged call a key/value head for the lanes under dense_len
-    and no other Mosaic call (the selection and the matrix states are
-    XLA's), the pool is updated in place, no copy of a pool leaf is
-    laid out anew, and the step's temporaries stay under 1 GiB — the
-    16-layer configuration holds 12.3 GiB of weights and cache."""
+    and, under `sparse_attn`, a MASKED paged call a key/value head and
+    stretch of lanes on a list made of the selection (PR 57: no gather
+    of pool rows and no conditional there, each list inside the
+    kernel's SMEM budget) and no other Mosaic call (the scores, the
+    top-k and the matrix states are XLA's), the pool is updated in
+    place, no copy of a pool leaf is laid out anew, and the step's
+    temporaries stay under 1 GiB — the 16-layer configuration holds
+    12.3 GiB of weights and cache."""
     from flexflow_tpu import FFConfig
     from flexflow_tpu.config import CompMode
     from flexflow_tpu.models.minicpm_sala import (LIGHTNING, MINICPM4,
                                                   build_minicpm_sala_lm)
     from flexflow_tpu.serve import ServeEngine
     from flexflow_tpu.serve.kv_cache import HybridPool
+    from flexflow_tpu.kernels.paged_ragged_v2 import SMEM_LIST_WORDS
     from flexflow_tpu.serve.sparse_paged import STRAY_TILE
     cfg = FFConfig(batch_size=1, kv_page_size=16, kv_num_pages=32769,
                    serve_max_seqs=32, serve_prefill_budget=512,
@@ -351,13 +356,38 @@ def test_minicpm_sala_mixed_step_compiles_within_its_memory_plan(topo,
     rows = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one)
     tables = jax.ShapeDtypeStruct((c.max_seqs, c.pages_per_seq), jnp.int32,
                                   sharding=one)
-    compiled = jax.jit(engine._mixed_impl, donate_argnums=(1,)).lower(
+    lowered = jax.jit(engine._mixed_impl, donate_argnums=(1,)).lower(
         _sds(engine._step_params, one), _sds(pool, one), lane, lane, lane,
-        lane, tables, lane, lane, rows, lane, rows).compile()
+        lane, tables, lane, lane, rows, lane, rows)
+    compiled = lowered.compile()
     engine.close()
     text = compiled.as_text()
     calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
-    assert len(calls) == 2 and all("paged_ragged_v2" in c for c in calls)
+    assert all("paged_ragged_v2" in c for c in calls)
+    # the selected blocks go through the paged kernel from a list made
+    # of the selection: a masked call a key/value head and stretch of
+    # `select_call_lanes` lanes (the whole step's list with its mask
+    # words passes the kernel's SMEM budget, so the lanes are cut
+    # statically), every one under `sparse_attn`, on the pool's leaves
+    g = engine.geometry
+    stretches = -(-544 // g.select_call_lanes)
+    masked = [c for c in calls if "paged_ragged_v2_select" in c]
+    assert len(calls) - len(masked) == 2            # the dense lanes'
+    assert len(masked) == 2 * stretches
+    assert all("/sparse_attn/" in c for c in masked)
+    words = 3 + g.select_block_pages + g.select_block_pages // 4
+    assert (g.select_max_items + 1) * words <= SMEM_LIST_WORDS
+    assert all(
+        f"s32[{(g.select_max_items + 1) * g.select_block_pages}]" in c
+        for c in masked)
+    under = [line for line in text.splitlines() if "/sparse_attn/" in line]
+    # no lane gathers a copy of its blocks: the gathers under the scope
+    # are the lists' (rows of int32 words), none makes bf16 rows of the
+    # pool, and nothing in the step is conditional
+    gathers = [line for line in under if "gather" in line.split("(")[0]
+               or re.search(r"fusion\(.*kind=kCustom.*gather", line)]
+    assert gathers and all(re.search(r"= s32\[", line) for line in gathers)
+    assert " conditional(" not in text
     assert "ragged-dot" not in text
     m = compiled.memory_analysis()
     pool_bytes = sum(a.size * a.dtype.itemsize
@@ -365,7 +395,6 @@ def test_minicpm_sala_mixed_step_compiles_within_its_memory_plan(topo,
     assert m.alias_size_in_bytes >= pool_bytes
     assert m.temp_size_in_bytes < 2**30, m.temp_size_in_bytes
     # no leaf of the pool is copied into another layout
-    import re
     assert not re.search(r"= bf16\[2,32769,16,128\]\S* copy\(", text)
     # the scores' two passes (PR 55): the keys the lanes of a stretch
     # share are fetched in ONE gather of a (4096, 128) copy a stretch
@@ -389,6 +418,95 @@ def test_minicpm_sala_mixed_step_compiles_within_its_memory_plan(topo,
     assert not any("/while/" in line for line in fetch[17 * 4096])
     assert len(fetch[STRAY_TILE * 4096]) == 2 * 2
     assert sum("/while/" in line for line in fetch[STRAY_TILE * 4096]) == 2
+    # the lightning layer's slab is written in place: its readers of the
+    # OLD state and the new state go through one optimization barrier
+    # before the write (PR 57: at 16 layers the compiler rematerialized
+    # a slice of the old state AFTER the write; the barrier stands
+    # through its scheduler and its rematerialization and is expanded
+    # after them, so the compiled text no longer shows it), and nothing
+    # in the compiled step reads a buffer after an in-place update of it
+    assert len(re.findall(r"optimization_barrier", lowered.as_text())) == 1
+    assert not _reads_after_in_place_write(text)
+
+
+def _reads_after_in_place_write(text):
+    """A scheduled module's text -> [(reader, the in-place op, their
+    buffer)]: instructions of the entry computation that read a buffer
+    AFTER a dynamic-update-slice or scatter fusion that updates it in
+    place (its `aliasing_operands`: operand k with the output, whose
+    index is the operands' count) has run."""
+    import json
+    roots, cur = {}, None
+    for line in text.split("\n"):
+        m = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(.*\{\s*$", line)
+        if m and not line.startswith(" "):
+            cur = m.group(1)
+        elif cur and line.lstrip().startswith("ROOT "):
+            roots[cur] = line
+    ops = []
+    for line in text[text.index("\nENTRY "):].split("\n")[1:]:
+        if line.startswith("}"):
+            break
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (.*)", line)
+        if m:
+            body = re.split(r", (?:metadata|calls|backend_config)=",
+                            m.group(2))[0]
+            ops.append((m.group(1), m.group(2), re.findall(
+                r"%[\w.\-]+", body.split("(", 1)[1]) if "(" in body else []))
+    found = []
+    for i, (name, rest, args) in enumerate(ops):
+        at = rest.find('"aliasing_operands":')
+        calls = re.search(r"calls=(%[\w.\-]+)", rest)
+        root = roots.get(calls.group(1), "") if calls else ""
+        if at < 0 or not (" dynamic-update-slice(" in root
+                          or " scatter(" in root):
+            continue
+        lists = json.JSONDecoder().raw_decode(
+            rest[at + len('"aliasing_operands":'):])[0]["lists"]
+        for indices in ([int(k) for k in one["indices"]] for one in lists):
+            if len(args) in indices:
+                found += [(later, name, args[k]) for k in indices
+                          if k < len(args)
+                          for later, _, reads in ops[i + 1:]
+                          if args[k] in reads]
+    return found
+
+
+_IN_PLACE = """HloModule step, is_scheduled=true
+
+%fused_write (p0: f32[12,8], p1: f32[1,8]) -> f32[12,8] {
+  %p0 = f32[12,8]{1,0} parameter(0)
+  %p1 = f32[1,8]{1,0} parameter(1)
+  %at = s32[] constant(11)
+  %zero = s32[] constant(0)
+  ROOT %dus = f32[12,8]{1,0} dynamic-update-slice(%p0, %p1, %at, %zero)
+}
+
+ENTRY %main (slab: f32[12,8], q: f32[1,8]) -> (f32[12,8], f32[1,8]) {
+  %slab = f32[12,8]{1,0} parameter(0)
+  %q = f32[1,8]{1,0} parameter(1)
+  %old = f32[1,8]{1,0} slice(%slab), slice={[11:12], [0:8]}
+  %new = f32[1,8]{1,0} add(%old, %q)
+WRITE_AND_READ
+  ROOT %out = (f32[12,8]{1,0}, f32[1,8]{1,0}) tuple(%written, %o)
+}
+"""
+_WRITE = ('  %written = f32[12,8]{1,0} fusion(%slab, %new), kind=kLoop, '
+          'calls=%fused_write, backend_config={"flag_configs":[],'
+          '"aliasing_operands":{"lists":[{"indices":["0","2"]}]}}')
+_READ = ['  %old.remat = f32[1,8]{1,0} slice(%slab), slice={[11:12], [0:8]}',
+         '  %o = f32[1,8]{1,0} multiply(%old.remat, %q)']
+
+
+@pytest.mark.parametrize("order,found", [
+    # the read of the old slice rematerialized AFTER the in-place write:
+    # what the 16-layer MiniCPM-SALA step's last lightning layer held
+    ([_WRITE] + _READ, [("%old.remat", "%written", "%slab")]),
+    # every reader of the old state before the write
+    (_READ + [_WRITE], [])])
+def test_a_read_after_an_in_place_write_is_found(order, found):
+    text = _IN_PLACE.replace("WRITE_AND_READ", "\n".join(order))
+    assert _reads_after_in_place_write(text) == found
 
 
 def _loops(text):
@@ -459,6 +577,8 @@ def test_qwen3_next_mixed_step_compiles_within_its_memory_plan(topo, as_tpu):
         lane, tables, lane, lane, rows, lane, rows).compile()
     engine.close()
     text = compiled.as_text()
+    # no state slab is read after its in-place write (PR 57)
+    assert not _reads_after_in_place_write(text)
     calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
     assert len(calls) == 5
     assert sum("paged_ragged_v2" in c for c in calls) == 1
@@ -675,6 +795,8 @@ def test_olmo_hybrid_mixed_step_compiles_within_its_memory_plan(topo,
         lane, tables, lane, lane, rows, lane, rows).compile()
     engine.close()
     text = compiled.as_text()
+    # no state slab is read after its in-place write (PR 57)
+    assert not _reads_after_in_place_write(text)
     calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
     assert len(calls) == 3
     assert sum("paged_ragged_v2" in c for c in calls) == 1
@@ -829,6 +951,8 @@ def test_falcon_h1_mixed_step_compiles_within_its_memory_plan(topo, as_tpu):
         lane, tables, lane, lane, rows, lane, rows).compile()
     engine.close()
     text = compiled.as_text()
+    # no state slab is read after its in-place write (PR 57)
+    assert not _reads_after_in_place_write(text)
     calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
     assert len(calls) == 6
     assert sum("paged_ragged_v2" in c for c in calls) == 2

@@ -36,6 +36,7 @@ from flexflow_tpu.serve import ServeEngine  # noqa: E402
 from flexflow_tpu.serve.arch import MiniCPMSala, describe  # noqa: E402
 from flexflow_tpu.serve.kv_cache import (HybridPool, HybridSpec,  # noqa: E402
                                          KVCacheConfig, KVPool)
+from flexflow_tpu.kernels.paged_ragged_v2 import select_counts  # noqa: E402
 from flexflow_tpu.serve.sparse_paged import (LANE_TILE,  # noqa: E402
                                              STRAY_TILE, main_slots)
 
@@ -345,14 +346,43 @@ def test_the_step_counts_what_was_selected(engine):
     assert sum(ev.sparse_lanes for ev in evs) == len(past)
     assert sum(ev.blocks_visible for ev in evs) == visible
     assert sum(ev.blocks_selected for ev in evs) == selected
-    # what the device gathers of K and V is the shapes': EVERY lane of
-    # the step's width takes topk blocks, in both sparse layers and
-    # heads, live and past dense_len or not
+    # what the device MOVES of the selected blocks it counts itself, as
+    # it walks the lists made of the selection (PR 57): `select_items`
+    # the grid steps of the selection's calls over both sparse layers
+    # and heads, `select_block_fetches` the selection blocks those
+    # items fetch, `selected_kv_bytes` their K and V. At this geometry
+    # one kv-block holds a whole table row, so an item is a run with a
+    # lane that selects, whatever it chose, and the walk over numpy
+    # needs no scores: every such lane on block 0
     c = engine.cache_cfg
     heads = 2 * KV_HEADS
-    assert {ev.selected_kv_bytes for ev in evs} == {
-        engine.mixed_width * heads * SIZES["topk"] * 2 * block * HEAD_DIM
-        * 4}
+    g = engine.geometry
+    assert g.select_block_pages == c.pages_per_seq
+    words = c.pages_per_seq * PAGE // block
+    assert g.select_call_lanes >= engine.mixed_width      # one call
+
+    def walk(ev):
+        width = engine.mixed_width
+        slots, positions = np.zeros((2, width), np.int32)
+        lanes = [(ch.req.slot, p) for ch in ev.plan.chunks
+                 for p in range(ch.start, ch.end)]
+        slots[:len(lanes)], positions[:len(lanes)] = np.transpose(lanes)
+        rows = (np.arange(width) < len(lanes)) & (positions >= dense_len)
+        items, real = select_counts(
+            np, np.zeros((width, 1), np.int32), np.ones((width, 1), bool),
+            rows, slots, num_blocks=words, mask_words=words)
+        return heads * int(items), heads * int(real) * words
+
+    for ev in evs:
+        assert (ev.select_items, ev.select_block_fetches) == walk(ev)
+        assert ev.selected_kv_bytes == ev.select_block_fetches * 2 \
+            * block * HEAD_DIM * 4
+    # a step with no lane past dense_len walks the sink items alone: one
+    # a tile, head and sparse layer, and moves nothing
+    early = [ev for ev in evs if not ev.sparse_lanes]
+    assert early and {(ev.select_items, ev.selected_kv_bytes)
+                      for ev in early} == {(heads, 0)}
+    assert any(ev.select_block_fetches for ev in evs)
     # of the compressed keys every stretch fetches ONE copy of its main
     # sequence's strides and the stray lanes — those of another
     # sequence — a copy each, a stretch of them a trip, the first trip
@@ -369,8 +399,8 @@ def test_the_step_counts_what_was_selected(engine):
     # follows (the one helper, over numpy there and jax.numpy here)
     session = engine.start_session()
     for n in (70, 90):
-        session.submit(_tokens(n, n), 3)
-    strayed = 0
+        session.submit(_tokens(n, n), 8)
+    strayed = both = 0
     while session.has_work():
         ev = session.step()
         if not ev.dispatched:
@@ -388,9 +418,12 @@ def test_the_step_counts_what_was_selected(engine):
         assert (ev.score_tiles, ev.score_shared_tiles) == (1, fewer == 0)
         assert ev.selector_bytes == (
             1 + STRAY_TILE * max(1, -(-fewer // STRAY_TILE))) * table
+        # two sequences' lanes past dense_len: an item each
+        assert (ev.select_items, ev.select_block_fetches) == walk(ev)
+        both = max(both, ev.select_block_fetches // words)
         strayed += fewer
     session.close()
-    assert strayed
+    assert strayed and both == 2 * heads
     # the paged calls' fetches alone are `kv_bytes_read`
     assert all(ev.kv_bytes_read == ev.full_kv_bytes for ev in evs)
     # a state in and a state out for every run and layer
