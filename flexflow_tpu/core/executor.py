@@ -254,6 +254,7 @@ class Executor:
                     # store at param_dtype; an EXPLICIT non-f32 spec
                     # dtype (a builder's bf16 table) wins over the knob
                     if (self.param_dtype != jnp.float32
+                            and not spec.keep_dtype
                             and jnp.dtype(spec.dtype) == jnp.float32):
                         arr = arr.astype(self.param_dtype)
                     if self.mesh is not None:

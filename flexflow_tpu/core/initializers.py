@@ -57,7 +57,8 @@ def make_constant(value: float):
 
 
 def make_uniform(minv: float, maxv: float, seed: int = 0):
-    def init(key, shape, dtype=jnp.float32):
+    def init(key, shape, dtype=jnp.float32, **_fans):
+        # a range of its own: a spec's fan_in / fan_out do not move it
         return jax.random.uniform(key, shape, dtype, minv, maxv)
     return init
 
@@ -65,7 +66,7 @@ def make_uniform(minv: float, maxv: float, seed: int = 0):
 def make_signed_uniform(lo: float, hi: float):
     """Magnitudes uniform in [lo, hi], each with a random sign: values
     AWAY from zero on both sides of it."""
-    def init(key, shape, dtype=jnp.float32):
+    def init(key, shape, dtype=jnp.float32, **_fans):
         k1, k2 = jax.random.split(key)
         sign = jnp.where(jax.random.bernoulli(k1, 0.5, shape), 1.0, -1.0)
         return (sign * jax.random.uniform(k2, shape, jnp.float32, lo, hi)

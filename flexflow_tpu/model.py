@@ -245,7 +245,8 @@ class FFModel:
                             rotary_interleaved: bool = False,
                             head_dim: int = 0,
                             qk_norm_init=None,
-                            key_multiplier: float = 1.0) -> Tensor:
+                            key_multiplier: float = 1.0,
+                            qk_norm_per_head: bool = False) -> Tensor:
         """`positions` ((batch, seq) int32) with `rotary_theta` > 0
         rotates q and k per head at those absolute positions
         (`rotary_interleaved`: neighbouring pairs, GPT-J's);
@@ -256,7 +257,9 @@ class FFModel:
         (num_heads * head_dim wide inside, embed_dim out);
         `qk_norm_init` (lo, hi): where the QK-norm's scales start
         (core/initializers.range_init; None: at 1); `key_multiplier`:
-        a scalar on the key projection's output."""
+        a scalar on the key projection's output; `qk_norm_per_head`:
+        the QK-norm over EACH head's dims with one (head_dim,) weight
+        the heads share (LFM2's), which grouped heads may take."""
         inputs = [query, key, value] \
             + ([positions] if positions is not None else [])
         op = MultiHeadAttention(
@@ -264,7 +267,8 @@ class FFModel:
             embed_dim, num_heads, kdim, vdim, dropout, bias, add_bias_kv,
             add_zero_attn, causal, kernel_initializer, use_flash,
             rotary_theta, qk_norm, qk_norm_eps, num_kv_heads, window,
-            rotary_interleaved, head_dim, qk_norm_init, key_multiplier)
+            rotary_interleaved, head_dim, qk_norm_init, key_multiplier,
+            qk_norm_per_head)
         return self.add_op(op).output
 
     # elementwise unary (model.h exp/relu/sigmoid/tanh/elu/scalar ops)
@@ -391,22 +395,25 @@ class FFModel:
                 dropless: bool = False, score: str = "softmax",
                 shared_experts: int = 0, experts_held=None,
                 shared_gate: bool = False,
-                kernel_initializer="glorot") -> Tensor:
+                kernel_initializer="glorot",
+                expert_bias=None) -> Tensor:
         """Fused expert-parallel MoE FFN (TPU-first EP; the composable
         reference path softmax+topk+group_by+aggregate also exists).
         `dropless`: bias-free gated experts, (act(x wg) * (x wu)) wd,
         and every token reaches all its k experts whatever the load (no
         capacity); `norm_topk=False` keeps the k router probabilities
         as they are. A dropless layer's `score` ("softmax" |
-        "sigmoid"), `shared_experts`, `shared_gate` and `experts_held`
-        (first, count): ops/moe_ffn.py."""
+        "sigmoid"), `shared_experts`, `shared_gate`, `experts_held`
+        (first, count) and `expert_bias` (a selection bias; how its
+        leaf starts): ops/moe_ffn.py."""
         op = MoEFFN(self, name or self._fresh_name("moe_ffn"), [input],
                     num_experts, k, hidden_dim, out_dim, capacity_factor,
                     activation, aux_loss_weight,
                     kernel_initializer=kernel_initializer,
                     norm_topk=norm_topk, dropless=dropless, score=score,
                     shared_experts=shared_experts,
-                    experts_held=experts_held, shared_gate=shared_gate)
+                    experts_held=experts_held, shared_gate=shared_gate,
+                    expert_bias=expert_bias)
         return self.add_op(op).output
 
 
@@ -470,6 +477,16 @@ class FFModel:
             head_dim, groups, d_state, d_conv, eps, in_multiplier,
             multipliers, out_multiplier, dt_range, a_range, norm_init,
             kernel_initializer, out_initializer)
+        return self.add_op(op).output
+
+    def gated_short_conv(self, input: Tensor, taps: int = 3,
+                         kernel_initializer="glorot",
+                         name: Optional[str] = None) -> Tensor:
+        """The gated short convolution (ops/short_conv.py)."""
+        from .ops.short_conv import GatedShortConv
+        op = GatedShortConv(
+            self, name or self._fresh_name("short_conv"), [input], taps,
+            kernel_initializer)
         return self.add_op(op).output
 
     def gated_attention(self, input: Tensor, positions: Tensor,

@@ -11,6 +11,7 @@ from .candle_uno import build_candle_uno
 from .nmt_lstm import build_nmt_lstm, build_nmt_seq2seq
 from .cmdaplus import build_cmdaplus_lm
 from .falcon_h1 import build_falcon_h1_lm
+from .lfm2_moe import build_lfm2_moe_lm
 from .minicpm_sala import build_minicpm_sala_lm
 from .olmo_hybrid import build_olmo_hybrid_lm
 from .olmoe import build_olmoe_lm
@@ -28,6 +29,7 @@ __all__ = [
     "build_moe_fused",
     "build_cmdaplus_lm",
     "build_falcon_h1_lm",
+    "build_lfm2_moe_lm",
     "build_minicpm_sala_lm",
     "build_olmo_hybrid_lm",
     "build_olmoe_lm",

@@ -68,6 +68,9 @@ class WeightSpec:
     custom_init: Optional[Callable] = None  # overrides `initializer`
     fan_in: Optional[int] = None
     fan_out: Optional[int] = None
+    # stored as `dtype` whatever FFConfig.param_dtype says (a router's
+    # f32 selection bias beside bf16 masters)
+    keep_dtype: bool = False
 
     def __post_init__(self):
         if self.axes is None:

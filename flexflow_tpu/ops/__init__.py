@@ -22,6 +22,7 @@ from .attention import MultiHeadAttention
 from .moe import GroupBy, Aggregate
 from .moe_ffn import MoEFFN
 from .ssm import SelectiveScanMixer
+from .short_conv import GatedShortConv
 from .linear_attention import LightningAttention
 from .gated_delta import GatedDeltaNet
 from .gated_attention import GatedAttention
@@ -58,6 +59,7 @@ __all__ = [
     "Aggregate",
     "MoEFFN",
     "SelectiveScanMixer",
+    "GatedShortConv",
     "LightningAttention",
     "GatedDeltaNet",
     "GatedAttention",

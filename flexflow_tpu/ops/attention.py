@@ -49,7 +49,8 @@ class MultiHeadAttention(Op):
                  qk_norm: bool = False, qk_norm_eps: float = 1e-5,
                  num_kv_heads: int = 0, window: int = 0,
                  rotary_interleaved: bool = False, head_dim: int = 0,
-                 qk_norm_init=None, key_multiplier: float = 1.0):
+                 qk_norm_init=None, key_multiplier: float = 1.0,
+                 qk_norm_per_head: bool = False):
         super().__init__(model, name, inputs)
         # a fourth input, (batch, seq) int32 absolute positions, turns
         # the rotary embedding on (rotary_theta > 0 needs it)
@@ -57,6 +58,13 @@ class MultiHeadAttention(Op):
         self.rotary_theta = float(rotary_theta)
         self.qk_norm = bool(qk_norm)
         self.qk_norm_eps = float(qk_norm_eps)
+        # the QK-norm over EACH head's dims, one (head_dim,) weight the
+        # heads share (LFM2's q_layernorm / k_layernorm), not over the
+        # whole projection; before the rotation either way
+        self.qk_norm_per_head = bool(qk_norm_per_head)
+        if self.qk_norm_per_head and not self.qk_norm:
+            raise ValueError(f"{name}: qk_norm_per_head says how qk_norm "
+                             f"norms (qk_norm=True)")
         # where the QK-norm's scales start, (lo, hi); None: at 1
         self.qk_norm_init = None if qk_norm_init is None \
             else tuple(qk_norm_init)
@@ -74,11 +82,15 @@ class MultiHeadAttention(Op):
             raise ValueError(
                 f"{name}: {num_heads} query heads do not divide over "
                 f"{self.num_kv_heads} key/value heads")
+        # a whole-projection norm has an (heads, head_dim) weight, which
+        # the grouped keys do not span; a per-head one fits any count
         if (self.num_kv_heads != int(num_heads) or self.window) and (
-                qk_norm or add_bias_kv or add_zero_attn or not causal):
+                (qk_norm and not self.qk_norm_per_head) or add_bias_kv
+                or add_zero_attn or not causal):
             raise ValueError(
                 f"{name}: grouped heads and a window are built for plain "
-                f"causal attention (no qk_norm, bias_kv or zero_attn)")
+                f"causal attention (no whole-projection qk_norm, bias_kv "
+                f"or zero_attn)")
         if (self.rotary_theta > 0) != (len(inputs) == 4):
             raise ValueError(
                 f"{name}: rotary attention takes q, k, v AND positions "
@@ -122,6 +134,8 @@ class MultiHeadAttention(Op):
         if self.rotary_theta > 0 or self.qk_norm:
             self.attrs.update(rotary_theta=self.rotary_theta,
                               qk_norm=self.qk_norm)
+        if self.qk_norm_per_head:
+            self.attrs["qk_norm_per_head"] = True
         if self.num_kv_heads != self.num_heads or self.window \
                 or self.rotary_interleaved:
             self.attrs.update(num_kv_heads=self.num_kv_heads,
@@ -162,10 +176,13 @@ class MultiHeadAttention(Op):
             if self.qk_norm_init is not None:
                 from ..core.initializers import range_init
                 start["custom_init"] = range_init(self.qk_norm_init)
-            specs["q_norm"] = WeightSpec((h, d), initializer="ones",
-                                         axes=(HEAD, None), **start)
-            specs["k_norm"] = WeightSpec((h, d), initializer="ones",
-                                         axes=(HEAD, None), **start)
+            # ... or, per head, one (head_dim,) weight the heads share
+            shape, axes = ((d,), (None,)) if self.qk_norm_per_head \
+                else ((h, d), (HEAD, None))
+            specs["q_norm"] = WeightSpec(shape, initializer="ones",
+                                         axes=axes, **start)
+            specs["k_norm"] = WeightSpec(shape, initializer="ones",
+                                         axes=axes, **start)
         if self.add_bias_kv:
             # one learned extra kv position (torch MultiheadAttention
             # bias_k/bias_v semantics)

@@ -127,12 +127,16 @@ RAGGED_DOT = "ragged_dot"        # `grouped_ffn` as XLA's grouped matmuls
 
 # ---- dropless routing: every slot reaches its expert, whatever the load
 def route_top_k(tokens: jax.Array, gate_w: jax.Array, k: int,
-                norm_topk: bool, score: str = "softmax"):
+                norm_topk: bool, score: str = "softmax", bias=None):
     """The router: tokens (N, D) -> (scores (N, E) f32, the k largest
     (N, k) f32, their expert ids (N, k) int32). Logits accumulate in
     f32 and are never rounded; the scoring function (`score`: "softmax"
     over all the experts, or "sigmoid" of each logit alone) and top-k
-    run in f32. `norm_topk` renormalises the k weights to sum to 1."""
+    run in f32. `norm_topk` renormalises the k weights to sum to 1.
+    `bias` (E,): a SELECTION bias (LFM2's `expert_bias`) — the k experts
+    are those of the largest scores + bias, their weights the scores
+    alone, renormalised with the family's 1e-6 in the divisor: the bias
+    chooses and never weighs, and no gradient reaches it."""
     logits = jnp.dot(tokens, gate_w.astype(tokens.dtype),
                      preferred_element_type=jnp.float32)
     if score == "softmax":
@@ -141,6 +145,14 @@ def route_top_k(tokens: jax.Array, gate_w: jax.Array, k: int,
         probs = jax.nn.sigmoid(logits)
     else:
         raise ValueError(f"route_top_k: no scoring function {score!r}")
+    if bias is not None:
+        _, assign = jax.lax.top_k(
+            probs + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
+        gate_vals = jnp.take_along_axis(probs, assign, axis=-1)
+        if norm_topk:
+            gate_vals = gate_vals / (
+                jnp.sum(gate_vals, axis=-1, keepdims=True) + 1e-6)
+        return probs, gate_vals, assign.astype(jnp.int32)
     gate_vals, assign = jax.lax.top_k(probs, k)
     if norm_topk:
         gate_vals = gate_vals / jnp.clip(

@@ -67,8 +67,16 @@ class MoEFFN(Op):
                  kernel_initializer: str = "glorot",
                  norm_topk: bool = True, dropless: bool = False,
                  score: str = "softmax", shared_experts: int = 0,
-                 experts_held=None, shared_gate: bool = False):
+                 experts_held=None, shared_gate: bool = False,
+                 expert_bias=None):
         super().__init__(model, name, inputs)
+        # a SELECTION bias, leaf `expert_bias` (E,) f32 (route_top_k's
+        # `bias`): None: no such leaf; else how it starts — True: at 0,
+        # a number: normal at that deviation, a callable: that custom
+        # initializer. It enters the choice of experts alone, so its
+        # gradient is exactly 0 and a training step leaves it as it is
+        # (the family moves it outside the optimizer, by the load)
+        self.expert_bias = expert_bias
         self.norm_topk = bool(norm_topk)
         self.dropless = bool(dropless)
         self.score = str(score)
@@ -81,10 +89,11 @@ class MoEFFN(Op):
             else (int(experts_held[0]), int(experts_held[1]))
         if not self.dropless and (self.score != "softmax"
                                   or self.shared_experts
-                                  or self.experts_held):
+                                  or self.experts_held
+                                  or self.expert_bias is not None):
             raise ValueError(
-                f"{name}: score, shared_experts and experts_held are the "
-                f"dropless layer's (dropless=True)")
+                f"{name}: score, shared_experts, experts_held and "
+                f"expert_bias are the dropless layer's (dropless=True)")
         self.num_experts = int(num_experts)
         self.k = int(k)
         self.hidden_dim = int(hidden_dim)
@@ -116,23 +125,31 @@ class MoEFFN(Op):
                               experts_held=self.experts_held)
         if self.shared_gate:
             self.attrs["shared_gate"] = True
+        if self.expert_bias is not None:
+            self.attrs["expert_bias"] = True
 
     def output_shapes(self):
         return [tuple(self.inputs[0].shape[:-1]) + (self.out_dim,)]
 
     def weight_specs(self):
         e, d, h, o = self.num_experts, self.in_dim, self.hidden_dim, self.out_dim
-        gate = WeightSpec((d, e), initializer=self.kernel_initializer,
+        from ..core.initializers import named
+        # one initializer for every matrix, or a dict with one a leaf
+        # by name for the dropless layer's router and experts
+        init = lambda w: named(self.kernel_initializer, w) \
+            if self.dropless else self.kernel_initializer
+        gate = WeightSpec((d, e), initializer=init("gate"),
                           axes=(CHANNEL, None))
         if self.dropless:
-            def w(shape, fi, fo):
+            def w(name, shape, fi, fo):
                 return WeightSpec(shape, axes=(EXPERT, None, None),
-                                  initializer=self.kernel_initializer,
+                                  initializer=init(name),
                                   fan_in=fi, fan_out=fo)
             if self.experts_held:
                 e = self.experts_held[1]
-            specs = {"gate": gate, "wg": w((e, d, h), d, h),
-                     "wu": w((e, d, h), d, h), "wd": w((e, h, o), h, o)}
+            specs = {"gate": gate, "wg": w("wg", (e, d, h), d, h),
+                     "wu": w("wu", (e, d, h), d, h),
+                     "wd": w("wd", (e, h, o), h, o)}
             if self.shared_experts:
                 # the shared experts' matrices side by side (shared_ffn)
                 def sw(shape, fi, fo):
@@ -144,6 +161,14 @@ class MoEFFN(Op):
                              sd=sw((n, o), h, o))
                 if self.shared_gate:
                     specs["sgate"] = sw((d, 1), d, 1)
+            if self.expert_bias is not None:
+                from ..core.initializers import make_normal
+                start = self.expert_bias
+                specs["expert_bias"] = WeightSpec(
+                    (self.num_experts,), initializer="zeros",
+                    keep_dtype=True, custom_init=None if start is True
+                    else start if callable(start)
+                    else make_normal(0.0, float(start)))
             return specs
         return {
             "gate": gate,
@@ -166,8 +191,8 @@ class MoEFFN(Op):
         e, cap, k = self.num_experts, self.capacity, self.k
 
         probs, gate_vals, assign = route_top_k(
-            tokens, params["gate"], k, self.norm_topk,
-            self.score)                         # (N, E), (N, k) x 2
+            tokens, params["gate"], k, self.norm_topk, self.score,
+            params.get("expert_bias"))          # (N, E), (N, k) x 2
         if self.dropless:
             rows, order, counts = dropless_dispatch(
                 tokens, assign, e, held=self.experts_held)

@@ -11,7 +11,7 @@ description reads the parameters of a compiled FFModel through the op
 names its builder wrote, and mirrors those ops' numerics. This module
 imports neither the engine nor the scheduler.
 
-Eight clients: `TransformerLM` (models/transformer.build_transformer_lm:
+Nine clients: `TransformerLM` (models/transformer.build_transformer_lm:
 learned positions, LayerNorm, ReLU feed-forward — the OPT block),
 `OLMoE` (models/olmoe.build_olmoe_lm: RMSNorm, rotary attention with
 QK-norm, dropless top-k SwiGLU experts), `Phi4Flash`
@@ -34,13 +34,19 @@ norm AFTER its sub-layer) and `FalconH1`
 (models/falcon_h1.build_falcon_h1_lm: Mamba-2 heads with a matrix state
 and a convolution tail a sequence BESIDE grouped rotary attention on
 pages in every layer, both read from the layer's one norm, a dense gated
-feed-forward, scalar multipliers on the activations).
+feed-forward, scalar multipliers on the activations) and `LFM2MoE`
+(models/lfm2_moe.build_lfm2_moe_lm: gated short convolutions whose whole
+cache is a convolution tail a sequence in three layers of four, grouped
+rotary attention with per-HEAD QK-norm in the fourth, leading dense
+layers and then sigmoid-routed experts chosen under a selection bias,
+the token table as the head).
 
 What a description answers (docs/serving.md "What a description must
 answer"): the dimensions; per layer the MIXER KIND (`mixer(i)`: one of
-the ten names serve/mixers.py has a body for — "attn",
+the eleven names serve/mixers.py has a body for — "attn",
 models/phi4flash's five, models/minicpm_sala's two, models/qwen3_next's
-one, models/falcon_h1's one, which runs TWO sequence mixers) and the
+one, models/falcon_h1's one, which runs TWO sequence mixers,
+models/lfm2_moe's one, which holds a tail and no state) and the
 projections that kind's body calls; the geometry of the K/V it
 pages (`kv_heads`, `kv_head_dim`, `paged_layers`, `attn_scale`); what a
 sequence holds besides pages (`hybrid_spec`, a serve/kv_cache.HybridSpec
@@ -55,6 +61,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models.falcon_h1 import SSD_ATTN
+from ..models.lfm2_moe import CONV
 from ..models.minicpm_sala import LINEAR, SPARSE
 from ..models.phi4flash import CROSS, FULL, GMU, SSM, WINDOW
 from ..models.qwen3_next import DELTA
@@ -1413,15 +1420,199 @@ class FalconH1(Description):
         return _graph_logits(self.model, params, tokens, positions=True)
 
 
-SHAPES = (TransformerLM, FalconH1, OlmoHybrid, Qwen3Next, OLMoE, Phi4Flash,
-          CommandAPlus, MiniCPMSala)
+class LFM2MoE(Description):
+    """The build_lfm2_moe_lm block (models/lfm2_moe.py holds the
+    equations, ops/short_conv.py the convolution's). Served by the mixed
+    step on one device.
+
+    What it pages: the FULL layers' K and V, `kv_heads` grouped heads of
+    `head_dim`, q and k normed a HEAD at a time (one (head_dim,) weight
+    the heads share) and then rotated. What a sequence holds besides
+    (`hybrid_spec`): for each CONV layer a convolution tail of taps - 1
+    rows of the hidden size — and NOTHING else: no state, no ring
+    (kv_cache.HybridSpec.tail_layers). The first `dense_layers` layers'
+    feed-forward is dense (scope `ffn`, no counts); the others route
+    (`router`, `moe_dispatch`, `experts`, `moe_combine`), and the step's
+    expert counts are over those layers alone."""
+
+    kind = "lfm2_moe"
+    builder = "build_lfm2_moe_lm"
+    reads = ("tok_embed", "lm_head", "layer0_operator_norm",
+             "embedding_norm")
+    _tail = ("a sequence's convolution tails live in its slot, not in "
+             "pages: ")
+    refused = {
+        "tp": "single-device: the experts, the grouped heads and the "
+              "tails are not split over a mesh (ROADMAP M1)",
+        "adapters": "no adapter pool for the convolution's projections "
+                    "and the expert layer",
+        "speculation": _tail + "rolling back rejected tokens would need "
+                       "the tails as they were (serve_spec_decode must be "
+                       "off)",
+        "prefix_cache": _tail + "a prefix hit would need the tails at the "
+                        "prefix's end (serve_prefix_cache must be off)",
+        "host_tier": _tail + "the host tier spills pages only",
+        "handoff": _tail + "the disaggregated handoff ships pages only",
+    }
+
+    def __init__(self, model, ops):
+        self.model = model
+        self.vocab_size = ops["tok_embed"].num_entries
+        self.layer_norm = True
+        n = 0
+        while f"layer{n}_operator_norm" in ops:
+            n += 1
+        self.num_layers = n
+        self.kinds = [CONV if f"layer{i}_conv" in ops else FULL
+                      for i in range(n)]
+        self.conv_layers = [i for i, k in enumerate(self.kinds)
+                            if k == CONV]
+        self.full_layers = [i for i, k in enumerate(self.kinds)
+                            if k == FULL]
+        self.moe_layers = [i for i in range(n) if f"layer{i}_moe" in ops]
+        attns = [ops.get(f"layer{i}_attn") for i in self.full_layers]
+        moes = [ops[f"layer{i}_moe"] for i in self.moe_layers]
+        # the dense layers LEAD: the routing layers are the rest
+        self.dense_layers = n - len(moes)
+        if not (attns and moes and self.conv_layers and all(
+                a is not None and a.causal and a.qk_norm_per_head
+                and a.rotary_theta > 0 and not a.window for a in attns)
+                and self.moe_layers == list(range(self.dense_layers, n))
+                and all(f"layer{i}_mlp" in ops
+                        for i in range(self.dense_layers))
+                and all(m.dropless and not m.shared_experts
+                        and not m.experts_held for m in moes)):
+            raise ValueError(
+                "ServeEngine reads a build_lfm2_moe_lm-shaped model: gated "
+                "short convolutions AND causal rotary attention with "
+                "per-head QK-norm, leading dense layers, then dropless "
+                "MoEFFN layers that hold every expert")
+        attn, moe0 = attns[0], moes[0]
+        self.conv = ops[f"layer{self.conv_layers[0]}_conv"]
+        self.num_heads, self.head_dim = attn.num_heads, attn.head_dim
+        self._kv_heads = attn.num_kv_heads
+        self.rope_theta = attn.rotary_theta
+        self.hidden = attn.embed_dim
+        self.ln_eps = ops["layer0_operator_norm"].eps
+        self.act_dtype = jnp.dtype(ops["tok_embed"].out_dtype)
+        # rotary has no table: the positions served are the graph's own
+        self.max_positions = int(ops["tok_embed"].inputs[0].shape[1])
+        self.experts = moe0.num_experts
+        self.experts_per_token = moe0.k
+        self.norm_topk, self.score = moe0.norm_topk, moe0.score
+        self.expert_bias = moe0.expert_bias is not None
+        self.activation = moe0.activation
+        self.ff_dim = moe0.hidden_dim
+        w = model.state.params[f"layer{self.moe_layers[0]}_moe"]["wg"]
+        # what one expert's three matrices weigh as they are resident:
+        # the bytes the expert phase reads for every expert it touches
+        self.expert_bytes = int(3 * self.hidden * self.ff_dim
+                                * w.dtype.itemsize)
+        self._expert_weights = jax.ShapeDtypeStruct(w.shape, w.dtype)
+
+    def mixer(self, i: int) -> str:
+        return self.kinds[i]
+
+    def expert_impl(self, lanes: int):
+        rows = jax.ShapeDtypeStruct(
+            (lanes * self.experts_per_token, self.hidden), self.act_dtype)
+        return expert_impl(rows, self._expert_weights, **self.kernels)
+
+    def hybrid_spec(self, chunk: int):
+        """No window, no state: tails of (taps - 1, hidden) in the CONV
+        layers, and they are all a slot holds."""
+        from .kv_cache import HybridSpec
+        return HybridSpec(
+            window_layers=0, window=0, chunk=int(chunk),
+            tail_layers=len(self.conv_layers),
+            tail_shape=(self.conv.taps - 1, self.hidden),
+            tail_dtype=str(self.act_dtype))
+
+    @property
+    def kv_heads(self) -> int:
+        return self._kv_heads
+
+    @property
+    def paged_layers(self) -> int:
+        return len(self.full_layers)
+
+    def embed(self, params, tokens, positions):
+        return jnp.take(params["tok_embed"]["kernel"], tokens, axis=0,
+                        mode="clip").astype(self.act_dtype)
+
+    def norm1(self, params, i, x):
+        return rms_norm(x, params[f"layer{i}_operator_norm"]["scale"],
+                        self.ln_eps)
+
+    def qkv(self, params, i, h, positions, lora=None):
+        """h (T, E), positions (T,) -> q (T, H, D), k, v (T, Hk, D): the
+        projections, the RMS norm of q and k over EACH head's D dims
+        (one (D,) weight the heads share), then the rotation at the
+        lanes' absolute positions."""
+        p = params[f"layer{i}_attn"]
+        q, k, v = _project(p, h)
+        q = rotary(rms_norm(q, p["q_norm"], self.ln_eps), positions,
+                   self.rope_theta)
+        k = rotary(rms_norm(k, p["k_norm"], self.ln_eps), positions,
+                   self.rope_theta)
+        return q, k, v
+
+    def attn_out(self, params, i, o, x, psum_axis=None, lora=None):
+        p = params[f"layer{i}_attn"]
+        return x + jnp.einsum("...hd,hde->...e", o,
+                              p["wo"].astype(o.dtype))
+
+    def ffn(self, params, i, x, live=None, psum_axis=None, lora=None):
+        """A leading DENSE layer: one scope, `ffn` -> (x, None: the
+        layer routes nothing). An expert layer, four scopes: `router`
+        (the norm, f32 sigmoid, the k largest of score + expert_bias,
+        their scores renormalised), `moe_dispatch`, `experts`,
+        `moe_combine` -> (x, (E,) int32 live slots per expert)."""
+        scope = jax.named_scope
+        norm2 = lambda: rms_norm(
+            x, params[f"layer{i}_ffn_norm"]["scale"], self.ln_eps)
+        if i < self.dense_layers:
+            with scope("ffn"):
+                return x + gated_ffn(params[f"layer{i}_mlp"], norm2()), None
+        m = params[f"layer{i}_moe"]
+        with scope("router"):
+            h = norm2().reshape(-1, self.hidden)
+            _, gate_vals, assign = route_top_k(
+                h, m["gate"], self.experts_per_token, self.norm_topk,
+                self.score, m.get("expert_bias"))
+        with scope("moe_dispatch"):
+            rows, order, counts = dropless_dispatch(
+                h, assign, self.experts, live)
+        with scope("experts"):
+            ys = grouped_ffn(rows, counts, m["wg"], m["wu"], m["wd"],
+                             self.activation, **self.kernels)
+        with scope("moe_combine"):
+            y = dropless_combine(ys, order, gate_vals)
+            return x + y.astype(x.dtype).reshape(x.shape), counts
+
+    def final_norm(self, params, x):
+        return rms_norm(x, params["embedding_norm"]["scale"], self.ln_eps)
+
+    def head(self, params, x):
+        """Tied: the token table is the head."""
+        h = self.final_norm(params, x)
+        table = params["tok_embed"]["kernel"].astype(h.dtype)
+        return jnp.dot(h, table.T,
+                       preferred_element_type=jnp.float32).astype(h.dtype)
+
+    def forward_logits(self, params, tokens):
+        return _graph_logits(self.model, params, tokens, positions=True)
+
+
+SHAPES = (TransformerLM, LFM2MoE, FalconH1, OlmoHybrid, Qwen3Next, OLMoE,
+          Phi4Flash, CommandAPlus, MiniCPMSala)
 
 
 def describe(model):
     """The description of a compiled FFModel, chosen by the op names
     its builder wrote: the first of SHAPES whose names are all there
-    (Qwen3Next's before OLMoE's, whose names it has too; OlmoHybrid's
-    and FalconH1's are nobody else's)."""
+    (Qwen3Next's before OLMoE's, whose names it has too; OlmoHybrid's,
+    FalconH1's and LFM2MoE's are nobody else's)."""
     ops = {op.name: op for op in model.ops}
     for cls in SHAPES:
         if all(n in ops for n in cls.reads):

@@ -1114,7 +1114,9 @@ class ServeEngine:
                     None if ad is None else
                     {key: arr[:, i] for key, arr in ad.items()},
                     ad_s, tp_axis)
-                expert_counts.append(counts)
+                # a layer whose feed-forward is dense routes nothing
+                if counts is not None:
+                    expert_counts.append(counts)
                 selected += walked
         with scope("head"):
             # only the lanes that emit have logits anyone reads: the
@@ -1128,6 +1130,8 @@ class ServeEngine:
             out = (jnp.argmax(logits, axis=-1).astype(jnp.int32),
                    topv.astype(jnp.float32), topi.astype(jnp.int32))
         if self.arch.experts:
+            # over the layers that ROUTE: all of them, or those after a
+            # model's leading dense layers
             out += (jnp.stack(expert_counts),)           # (layers, E)
         if selected:
             # what the SPARSE layers' selections walked, summed

@@ -58,6 +58,10 @@ a sequence's SLOT holds a constant part that needs no allocator:
     layer, row s of two slabs whose last row is the write sink of the
     step's inactive lanes. A sequence (re-)admitted at position 0
     reads neither (the step starts it from zeros).
+  * a TAIL WITHOUT A STATE for each layer whose whole cache is its
+    convolution's last inputs (LFM2's gated short convolution: 2 rows
+    of the hidden size, 8 KB a layer a sequence whatever its length):
+    row s of the `tail` slab alone — no state slab, no page.
 
 Admission prices the constant part by the slot it takes: it exists for
 every slot from the start (`KVCacheConfig.constant_bytes_per_seq`,
@@ -132,8 +136,13 @@ class HybridSpec:
     (`state_layers` 0: rings alone, and nothing is allocated or
     computed for state; `window_layers` 0: states alone, and no ring; a
     `tail_shape` of no rows: a state with no tail, as a linear-attention
-    layer's matrix state is). `chunk` is the most tokens of ONE
-    sequence a step writes (the engine's prefill budget)."""
+    layer's matrix state is). A TAIL WITHOUT A STATE: `tail_layers`
+    layers hold `tail_shape` a sequence and nothing else (a short
+    convolution's last inputs) — the `tail` slab has that many layers
+    and there is no state slab; a model holds tails beside its states
+    (`state_layers`) or alone (`tail_layers`), never both. `chunk` is
+    the most tokens of ONE sequence a step writes (the engine's prefill
+    budget)."""
     window_layers: int
     window: int
     chunk: int
@@ -141,6 +150,29 @@ class HybridSpec:
     state_shape: Tuple[int, int] = (0, 0)   # (d_state, d_inner), f32
     tail_shape: Tuple[int, int] = (0, 0)    # (d_conv - 1, d_inner)
     tail_dtype: str = "bfloat16"
+    tail_layers: int = 0                    # tails that ride no state
+
+    def __post_init__(self):
+        if self.tail_layers and (self.state_layers
+                                 or not self.tail_bytes):
+            raise ValueError(
+                f"tail_layers={self.tail_layers} are tails WITHOUT a state "
+                f"(state_layers={self.state_layers}) of a shape with rows "
+                f"(tail_shape={self.tail_shape})")
+
+    @property
+    def tail_bytes(self) -> int:
+        """One sequence's tail of one layer."""
+        return self.tail_shape[0] * self.tail_shape[1] \
+            * jnp.dtype(self.tail_dtype).itemsize
+
+    @property
+    def tails(self) -> int:
+        """The `tail` slab's layers: one beside each state, or the
+        tails that ride none; 0: no layer holds a tail."""
+        if not self.tail_bytes:
+            return 0
+        return self.state_layers or self.tail_layers
 
     def ring_pages(self, page_size: int) -> int:
         """Pages that cover any window + chunk - 1 consecutive
@@ -152,11 +184,10 @@ class HybridSpec:
 
     @property
     def state_bytes(self) -> int:
-        """One sequence's scan states and tails, all layers."""
-        n = lambda shape: shape[0] * shape[1]
-        return self.state_layers * (
-            n(self.state_shape) * 4
-            + n(self.tail_shape) * jnp.dtype(self.tail_dtype).itemsize)
+        """One sequence's scan states and tails, all layers (a model
+        whose slots are tails alone: its tails)."""
+        return self.state_layers * self.state_shape[0] \
+            * self.state_shape[1] * 4 + self.tails * self.tail_bytes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -673,6 +704,9 @@ class HybridPool:
     flat: three rows would pad to a tile of 16), the last row of both
     the write sink; both None without a state-space layer, `tail` also
     where the state has none (no zero-sized leaf in the donated pool).
+    A model whose slots are TAILS ALONE (HybridSpec.tail_layers) has
+    `tail` (tail_layers, max_seqs + 1, rows * width) and `state` None:
+    no stand-in state of one row or none.
     Flows through the step like a KVPool (donated in, returned out)."""
     full: KVPool
     window: KVPool
@@ -694,15 +728,14 @@ class HybridPool:
         full = KVPool.alloc(dataclasses.replace(cfg, hybrid=None), sharding)
         window = KVPool.alloc(cls.ring_cfg(cfg), sharding) \
             if h.window_layers else None
-        if not h.state_layers:
-            return cls(full, window, None, None)
         tail = h.tail_shape[0] * h.tail_shape[1]
         return cls(
             full, window,
             jnp.zeros(rows + tuple(h.state_shape), jnp.float32,
-                      device=sharding),
-            jnp.zeros(rows + (tail,), jnp.dtype(h.tail_dtype),
-                      device=sharding) if tail else None)
+                      device=sharding) if h.state_layers else None,
+            jnp.zeros((h.tails, cfg.max_seqs + 1, tail),
+                      jnp.dtype(h.tail_dtype), device=sharding)
+            if h.tails else None)
 
     def check_geometry(self, cfg: KVCacheConfig) -> None:
         want = jax.eval_shape(lambda: HybridPool.alloc(cfg))
@@ -714,7 +747,8 @@ class HybridPool:
         for name in ("state", "tail"):
             a, w = getattr(self, name), getattr(want, name)
             if w is None:
-                assert a is None, f"pool leaf {name} without a state layer"
+                assert a is None, (f"pool leaf {name} that no layer of "
+                                   f"the configuration holds")
                 continue
             assert (a.shape, a.dtype) == (w.shape, w.dtype), (
                 f"pool leaf {name} is {a.shape} {a.dtype}; the "

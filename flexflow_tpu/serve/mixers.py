@@ -35,9 +35,9 @@ from ..kernels.paged_ragged_v2 import (JNP, PALLAS_INTERPRET, Q_ROWS,
                                        kv_page_bytes, max_work_items,
                                        paged_attention_ragged_v2,
                                        window_block_bound, work_items)
-from ..ops import gated_delta, linear_attention, ssd, ssm
-from .arch import (ATTN, CROSS, DELTA, FULL, GMU, LINEAR, SPARSE, SSD_ATTN,
-                   SSM, WINDOW)
+from ..ops import gated_delta, linear_attention, short_conv, ssd, ssm
+from .arch import (ATTN, CONV, CROSS, DELTA, FULL, GMU, LINEAR, SPARSE,
+                   SSD_ATTN, SSM, WINDOW)
 from .kv_cache import KVCacheConfig, ring_tables
 from .sparse_paged import (LANE_TILE, STRAY_TILE, main_slots,
                            paged_sparse_attention, selection_geometry,
@@ -70,12 +70,15 @@ DELTA_COUNTS = ("delta_lanes", "delta_chunk_blocks")
 # ... where a layer runs Mamba-2 heads: the same two of its recurrence
 # (one rule, one plan: ops/ssd.py takes the delta rule's)
 SSD_COUNTS = ("ssd_lanes", "ssd_chunk_blocks")
+# ... where a layer is a gated short convolution: the live lanes that
+# pass through those layers, a layer
+CONV_COUNTS = ("conv_lanes",)
 # what StepEvents takes of every step and the span does not (it has
 # the first three under older names)
 EVENT_COUNTS = ("kv_bytes_read", "attn_items", "attn_rows", "ssm_runs",
                 "lanes_past_window")
 STEP_COUNTS = EVENT_COUNTS + LIVE_COUNTS + HYBRID_COUNTS + SELECT_COUNTS \
-    + SELECT_LANDED_COUNTS + DELTA_COUNTS + SSD_COUNTS
+    + SELECT_LANDED_COUNTS + DELTA_COUNTS + SSD_COUNTS + CONV_COUNTS
 
 
 # ------------------------------------------------------------- geometry
@@ -101,11 +104,13 @@ class Geometry:
     counted: Tuple[str, ...]
     # how the delta layers' slab holds a state (ops/gated_delta.
     # state_layout: the layout's name, a state's rows, its resident
-    # bytes) or the Mamba-2 layers' theirs (`ssd_state_*`); {}: no such
-    # layer
+    # bytes) or the Mamba-2 layers' theirs (`ssd_state_*`) or the short
+    # convolutions' tails theirs (`conv_tail_*`); {}: no such layer
     delta_state: dict = dataclasses.field(default_factory=dict)
     # a layer runs Mamba-2 heads: its lanes take the delta rule's plan
     ssd: bool = False
+    # the layers that are gated short convolutions (CONV)
+    conv_layers: int = 0
     # a selecting model's selected blocks through the paged kernel
     # (sparse_paged.selection_geometry): the kv-block of those calls in
     # pages, the lanes a call takes of the step's (the static cut that
@@ -126,7 +131,7 @@ def attn_calls(arch) -> Tuple[int, int]:
     full pages' list an ATTN, FULL, CROSS or SSD_ATTN layer (a cross
     layer reads the full layer's pages), a call a key/value head a SPARSE layer
     (each head's pages are a pool layer of their own), one on the
-    window layers' list a WINDOW layer."""
+    window layers' list a WINDOW layer; a CONV layer makes none."""
     kinds = [arch.mixer(i) for i in range(arch.num_layers)]
     full = sum(kinds.count(k) for k in (ATTN, FULL, CROSS, SSD_ATTN)) \
         + arch.kv_heads * kinds.count(SPARSE)
@@ -180,6 +185,14 @@ def geometry(arch, cfg: KVCacheConfig, *, width: int, attn_impl: str,
                        "ssd_state_shape": d.state_shape,
                        "ssd_state_slot_bytes":
                            4 * d.state_shape[0] * d.state_shape[1]}
+    if CONV in kinds:
+        # the slots are tails alone: what a slot's row of the slab is
+        delta_state = {"conv_tail_layout": "layers_by_slots_by_flat_rows",
+                       "conv_tail_shape": (hyb.tail_layers,
+                                           cfg.max_seqs + 1,
+                                           hyb.tail_shape[0]
+                                           * hyb.tail_shape[1]),
+                       "conv_tail_slot_bytes": hyb.tail_bytes}
     block_pages = max(1, block_kv // cfg.page_size)
     # a model that SELECTS its context (arch.dense_len) walks pages in
     # the paged kernel only for its lanes under dense_len: the list is
@@ -219,7 +232,8 @@ def geometry(arch, cfg: KVCacheConfig, *, width: int, attn_impl: str,
     counted = LIVE_COUNTS + (HYBRID_COUNTS if hyb is not None else ()) \
         + (SELECT_COUNTS if dense_pages else ()) \
         + (DELTA_COUNTS if DELTA in kinds else ()) \
-        + (SSD_COUNTS if SSD_ATTN in kinds else ())
+        + (SSD_COUNTS if SSD_ATTN in kinds else ()) \
+        + (CONV_COUNTS if CONV in kinds else ())
     return Geometry(
         arch=arch, cfg=cfg, width=width, attn_impl=attn_impl,
         block_kv=block_kv, block_pages=block_pages, scan_impl=scan_impl,
@@ -228,6 +242,7 @@ def geometry(arch, cfg: KVCacheConfig, *, width: int, attn_impl: str,
         window_max_items=window_max_items, attn_calls=attn_calls(arch),
         rings=ring_tables(cfg) if cfg.ring_pages else None,
         counted=counted, delta_state=delta_state, ssd=SSD_ATTN in kinds,
+        conv_layers=kinds.count(CONV),
         select_block_pages=select[0], select_call_lanes=select[1],
         select_max_items=select[2])
 
@@ -277,7 +292,9 @@ class Lanes(NamedTuple):
     live_lanes: Any = None
     # for each slot the lane whose inputs replace its convolution tail,
     # -1 where none does (ops/ssm.run_tail_lanes); None: no layer holds
-    # a tail
+    # a tail. Where the slots hold tails ALONE (HybridSpec.tail_layers)
+    # `starts`, `offsets` and `wslots` are made for them and
+    # `live_lanes` is not
     tail_lanes: Any = None
     # the rings' page table, the ring page each lane writes and the
     # window layers' work list; None: no window layer
@@ -312,10 +329,11 @@ def step_lanes(g: Geometry, positions, write_pages, write_offs,
     with scope("work_list"):
         live = write_pages != 0
         lane = jnp.arange(1, live.shape[0] + 1, dtype=jnp.int32)
-        # runs are the scans' alone: nothing of them without a layer
-        # that holds a state
+        # runs are the scans' and the tails': nothing of them without a
+        # layer that holds a state or a tail
         state = c.hybrid.state_layers > 0
-        starts = ssm.run_starts(lane_slots, positions) if state else None
+        runs = state or c.hybrid.tail_layers > 0
+        starts = ssm.run_starts(lane_slots, positions) if runs else None
         # rings are the window layers' alone: no table, write addresses
         # or work list of them without one
         ringed = c.hybrid.window_layers > 0
@@ -323,13 +341,14 @@ def step_lanes(g: Geometry, positions, write_pages, write_offs,
             rings = ring_tables(c, jnp)
             page = positions // c.page_size
         made = {"live": live}
-        if state:
+        if runs:
             made.update(
                 starts=starts, offsets=ssm.run_offsets(starts),
                 wslots=ssm.run_write_slots(starts, live, lane_slots,
-                                           c.max_seqs),
-                live_lanes=jnp.max(jnp.where(live, lane, 0)))
-            if c.hybrid.tail_shape[0] > 0:
+                                           c.max_seqs))
+            if state:       # the scans' trips; the tails alone need none
+                made["live_lanes"] = jnp.max(jnp.where(live, lane, 0))
+            if c.hybrid.tails:
                 made["tail_lanes"] = ssm.run_tail_lanes(
                     made["wslots"], c.max_seqs)
             if g.delta_impl not in (None, JNP) or g.ssd:
@@ -562,6 +581,29 @@ def _delta(g, params, i, x, h, lanes, pool, memory, lora=None,
     return x, pool, memory
 
 
+def _short_conv(g, params, i, x, h, lanes, pool, memory, lora=None,
+                tp_axis=None):
+    """A gated short convolution (ops/short_conv.py): `conv_proj` (the
+    in-projection to B | C | z), `short_conv` (the gate B * z, the taps
+    over a run and its slot's tail, the tail's write-back by runs, the
+    second gate; the taps in f32), `conv_out` (the out-projection and
+    the residual). The layer's whole cache is its slot's tail: no state,
+    no page."""
+    scope = jax.named_scope
+    arch = g.arch
+    j = arch.conv_layers.index(i)
+    p = params[f"layer{i}_conv"]
+    with scope("conv_proj"):
+        b, c, z = short_conv.project(p, h)                # (T, E) each
+    with scope("short_conv"):
+        y, tail = short_conv.segmented(
+            p, b, c, z, pool.tail[j], lanes.lane_slots, lanes.positions,
+            lanes.offsets, lanes.tail_lanes)
+        pool = dataclasses.replace(pool, tail=pool.tail.at[j].set(tail))
+    with scope("conv_out"):
+        return x + short_conv.out_project(p, y), pool, memory
+
+
 def _ssd(g, params, i, h, lanes, pool):
     """Layer `i`'s Mamba-2 heads over `h` -> (their BRANCH alone, the
     pool): `ssm_proj` (the in-projection and its multipliers; the gated
@@ -691,7 +733,7 @@ def select_landed(g: Geometry, walked) -> dict:
 BODIES = {ATTN: _attention, WINDOW: _attention, FULL: _attention,
           CROSS: _attention, SSM: _state_space, GMU: _gated_memory,
           LINEAR: _linear, SPARSE: _sparse, DELTA: _delta,
-          SSD_ATTN: _ssd_and_attention}
+          SSD_ATTN: _ssd_and_attention, CONV: _short_conv}
 
 
 # --------------------------------------------------------------- counts
@@ -751,8 +793,11 @@ def step_counts(g: Geometry, page_tables, positions, lane_slots,
             lanes_past_window=int(
                 (lane_lens[:live_lanes] > arch.window).sum())
             if arch.window else 0,
-            ssm_runs=runs if c.hybrid.state_layers else 0,
+            ssm_runs=runs if c.hybrid.state_layers
+            or c.hybrid.tail_layers else 0,
             state_bytes=2 * runs * c.hybrid.state_bytes)
+    if g.conv_layers:
+        work["conv_lanes"] = live_lanes * g.conv_layers
     if g.delta_impl is not None or g.ssd:
         # which form each block of lanes takes, by the rule the step
         # itself follows (the same function over numpy)

@@ -19,12 +19,12 @@ from flexflow_tpu.kernels.paged_ragged_v2 import PALLAS_INTERPRET
 from flexflow_tpu.serve import ServeEngine
 from flexflow_tpu.serve import engine as E
 from flexflow_tpu.serve import mixers as M
-from flexflow_tpu.serve.arch import (ATTN, CROSS, DELTA, FULL, GMU, LINEAR,
-                                     SPARSE, SSD_ATTN, SSM, WINDOW)
+from flexflow_tpu.serve.arch import (ATTN, CONV, CROSS, DELTA, FULL, GMU,
+                                     LINEAR, SPARSE, SSD_ATTN, SSM, WINDOW)
 from flexflow_tpu.serve.kv_cache import ring_tables
 
 KINDS = (ATTN, WINDOW, FULL, CROSS, SSM, GMU, LINEAR, SPARSE, DELTA,
-         SSD_ATTN)
+         SSD_ATTN, CONV)
 # description -> (the test module whose `_lm` builds it, the paged calls
 # a step of that build as the hand-written `attn_calls()` answered
 # before ISSUE 48)
@@ -34,7 +34,9 @@ BUILDS = {"transformer_lm": ("test_paged_work_list", (3, 0)),
           "command_a_plus": ("test_cmdaplus", (1, 3)),
           "minicpm_sala": ("test_minicpm_sala", (4, 0)),
           # since PR 49: a description that never had such a method
-          "qwen3_next": ("test_qwen3_next", (1, 0))}
+          "qwen3_next": ("test_qwen3_next", (1, 0)),
+          # since PR 58: one attention layer of six, the others tails
+          "lfm2_moe": ("test_lfm2_moe", (1, 0))}
 _lms, _engines = {}, {}
 
 
@@ -89,6 +91,9 @@ class _Kinds:
     # four sparse layers of two key/value heads
     ("MiniCPM-SALA, 16 layers",
      _Kinds(([SPARSE] + [LINEAR] * 3) * 4, 2), (8, 0)),
+    # two attention layers of ten: a convolution layer makes no call
+    ("LFM2-24B-A2B, 10 layers",
+     _Kinds([CONV] * 2 + ([FULL] + [CONV] * 3) * 2, 8), (2, 0)),
 ])
 def test_the_calls_a_step_at_the_served_depths(name, arch, calls):
     if arch is None:
@@ -97,8 +102,8 @@ def test_the_calls_a_step_at_the_served_depths(name, arch, calls):
     assert M.attn_calls(arch) == calls
 
 
-def test_the_table_is_the_ten_kinds():
-    assert set(M.BODIES) == set(KINDS) and len(set(KINDS)) == 10
+def test_the_table_is_the_eleven_kinds():
+    assert set(M.BODIES) == set(KINDS) and len(set(KINDS)) == 11
 
 
 # ------------------------------------------------ (b) the arrows, one way
@@ -280,6 +285,19 @@ EXPECTED["qwen3_next"] = [
     (2, 1, 1, 1, 135168, 1, 1, 33792, 0, 1, 0, 0, 0),
     (2, 1, 1, 1, 135168, 1, 1, 33792, 0, 1, 0, 0, 0),
 ]
+# no parent's: as PR 58 counted them (one paged call a step, on the one
+# attention layer; a tail in and out a run and a convolution layer: 2 x
+# 5 x 2 x 32 x 4 B a run, and no state)
+EXPECTED["lfm2_moe"] = [
+    (1, 1, 0, 24, 32768, 1, 24, 2560, 0, 1, 0, 0, 0),
+    (4, 3, 0, 24, 36864, 3, 24, 7680, 0, 3, 0, 0, 0),
+    (4, 3, 0, 26, 39936, 3, 26, 7680, 0, 3, 0, 0, 0),
+    (4, 3, 0, 26, 43008, 3, 26, 7680, 0, 3, 0, 0, 0),
+    (4, 3, 0, 9, 44032, 3, 9, 7680, 0, 3, 0, 0, 0),
+    (2, 1, 0, 1, 33792, 1, 1, 2560, 0, 1, 0, 0, 0),
+    (2, 1, 0, 1, 33792, 1, 1, 2560, 0, 1, 0, 0, 0),
+    (2, 1, 0, 1, 33792, 1, 1, 2560, 0, 1, 0, 0, 0),
+]
 
 
 @pytest.mark.parametrize("kind", list(BUILDS))
@@ -292,7 +310,8 @@ def test_the_host_s_counts_are_the_parent_s(kind):
     assert g.counted == M.LIVE_COUNTS + (
         M.HYBRID_COUNTS if eng.cache_cfg.hybrid is not None else ()) + (
         M.SELECT_COUNTS if g.dense_pages else ()) + (
-        M.DELTA_COUNTS if g.delta_impl is not None else ())
+        M.DELTA_COUNTS if g.delta_impl is not None else ()) + (
+        M.CONV_COUNTS if g.conv_layers else ())
     assert not set(M.EVENT_COUNTS) & set(g.counted)
     assert set(M.STEP_COUNTS) < set(E.StepEvents.__slots__)
 
